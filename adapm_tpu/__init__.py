@@ -17,8 +17,12 @@ def setup(num_keys: int, value_lengths, opts=None, num_shards=None,
     """Convenience: build a mesh + Server (reference `ps::Setup` +
     `ServerT server(...)`, apps/simple.cc:107-133). Under the launcher
     (ADAPM_COORDINATOR set), this also joins the multi-process runtime —
-    the reference's Postoffice::Start + scheduler rendezvous."""
+    the reference's Postoffice::Start + scheduler rendezvous. Compiled
+    programs persist in the placeable compile cache
+    (utils/compile_cache.py; `JAX_COMPILATION_CACHE_DIR`, docs/env.md)."""
     from .parallel import control
+    from .utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     control.init_from_env()
     ctx = make_mesh(num_shards)
     return Server(num_keys, value_lengths, opts=opts, ctx=ctx,

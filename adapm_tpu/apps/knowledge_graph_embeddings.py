@@ -95,6 +95,28 @@ class KgeRun:
                         "o": self.ent_class, "neg": self.ent_class},
             role_dim={"s": self.ent_dim, "r": self.rel_dim,
                       "o": self.ent_dim, "neg": self.ent_dim})
+        self.truth_mrr = None    # lowrank generator's ceiling (open_run)
+        self.neg_alias = None    # --neg_sampling freq alias table
+        self._dev_runners = {}   # shard -> DeviceRoutedRunner
+
+    def device_runner(self, shard: int) -> DeviceRoutedRunner:
+        """--device_routes: the production TPU hot path — routing tables
+        and negative sampling (Local scheme, uniform or alias-table
+        freq) live on device; one runner per worker shard
+        (docs/PERF.md: ~2.4x over host routing)."""
+        if shard not in self._dev_runners:
+            a = self.args
+            self._dev_runners[shard] = DeviceRoutedRunner(
+                self.srv, make_kge_loss(a.model, a.self_adv_temp, a.l2),
+                role_class={"s": self.ent_class, "r": self.rel_class,
+                            "o": self.ent_class, "neg": self.ent_class},
+                role_dim={"s": self.ent_dim, "r": self.rel_dim,
+                          "o": self.ent_dim, "neg": self.ent_dim},
+                shard=shard, neg_role="neg",
+                neg_shape=(a.batch_size, a.neg_ratio),
+                neg_population=self.ekey(np.arange(self.E)),
+                neg_alias=self.neg_alias, seed=a.seed + shard)
+        return self._dev_runners[shard]
 
     # -- key helpers ---------------------------------------------------------
 
@@ -453,7 +475,11 @@ def _eval_global(run: KgeRun, triples: np.ndarray) -> np.ndarray:
     return agg
 
 
-def run_app(args) -> dict:
+def open_run(args) -> KgeRun:
+    """Set-up: dataset, server, initialized model, sampling support. The
+    returned run's server is live; the caller shuts it down
+    (`run.srv.shutdown()`) — `run_app` does, a caller that goes on to
+    serve the trained store does so when it is finished."""
     truth_mrr = None
     if args.train:
         ds = kgeio.load_dataset(args.train, args.valid, args.test,
@@ -474,28 +500,27 @@ def run_app(args) -> dict:
             num_relations=args.synthetic_relations,
             n_train=args.synthetic_triples, seed=args.seed)
     run = KgeRun(args, ds)
+    run.truth_mrr = truth_mrr
     run.init_model()
     if args.enforce_full_replication:
         enforce_full_replication(run.workers, run.E + run.R)
 
-    B, N = args.batch_size, args.neg_ratio
-    srv, workers = run.srv, run.workers
+    srv = run.srv
     # negative sampling over entities. uniform = the reference's scheme
     # (kge.cc draws uniform entities); freq = unigram^pow over the
     # training-triple entity frequencies (word2vec's noise distribution
     # applied to KGE — hits the populated region of the entity space,
     # part of the mid-scale fix alongside --self_adv_temp). The Local
     # scheme may only snap within the entity key population.
-    neg_alias = None
     if args.neg_sampling == "freq":
         from ..models.sgns import build_alias_table
         counts = (np.bincount(ds.train[:, 0], minlength=run.E)
                   + np.bincount(ds.train[:, 2], minlength=run.E)
                   + 1.0)
-        neg_alias = build_alias_table(counts, power=args.neg_freq_pow)
+        run.neg_alias = build_alias_table(counts, power=args.neg_freq_pow)
 
         def host_neg(n, r):
-            prob, alias = neg_alias
+            prob, alias = run.neg_alias
             u = r.integers(0, run.E, n)
             keep = r.random(n) < prob[u]
             return run.ekey(np.where(keep, u, alias[u]))
@@ -506,35 +531,26 @@ def run_app(args) -> dict:
         srv.enable_sampling_support(
             lambda n, r: run.ekey(r.integers(0, run.E, n)),
             allowed_keys=run.ekey(np.arange(run.E)))
+    return run
 
-    # --device_routes: the production TPU hot path — routing tables and
-    # negative sampling (Local scheme, uniform or alias-table freq) live
-    # on device; one runner per worker shard (docs/PERF.md: ~2.4x over
-    # host routing)
-    dev_runners = {}
 
-    def device_runner(shard: int) -> DeviceRoutedRunner:
-        if shard not in dev_runners:
-            dev_runners[shard] = DeviceRoutedRunner(
-                srv, make_kge_loss(args.model, args.self_adv_temp, args.l2),
-                role_class={"s": run.ent_class, "r": run.rel_class,
-                            "o": run.ent_class, "neg": run.ent_class},
-                role_dim={"s": run.ent_dim, "r": run.rel_dim,
-                          "o": run.ent_dim, "neg": run.ent_dim},
-                shard=shard, neg_role="neg", neg_shape=(B, N),
-                neg_population=run.ekey(np.arange(run.E)),
-                neg_alias=neg_alias, seed=args.seed + shard)
-        return dev_runners[shard]
+def train(run: KgeRun) -> dict:
+    """The training loop + evals over an opened run; leaves the server
+    up (see open_run)."""
+    args, ds = run.args, run.ds
+    B, N = args.batch_size, args.neg_ratio
+    srv, workers = run.srv, run.workers
+    device_runner = run.device_runner
 
-    train = ds.train
+    triples = ds.train
     # data parallelism over ALL workers of ALL processes (kge.cc:968-970)
-    parts = global_worker_slices(len(train), run.num_workers)
+    parts = global_worker_slices(len(triples), run.num_workers)
     rng = np.random.default_rng(args.seed)
     guard = RuntimeGuard(args.max_runtime)
     watch = Stopwatch(start=True)
     result = {}
-    if truth_mrr is not None:
-        result["truth_mrr"] = truth_mrr
+    if run.truth_mrr is not None:
+        result["truth_mrr"] = run.truth_mrr
         result["truth_mrr_o"] = ds.truth_mrr_o
         result["truth_mrr_s"] = ds.truth_mrr_s
 
@@ -570,7 +586,7 @@ def run_app(args) -> dict:
                 if bi <= prepared_hi:
                     return
                 prepared_hi = bi
-                t = train[batches[bi]]
+                t = triples[batches[bi]]
                 roles = triple_roles(t)
                 ks = np.unique(np.concatenate(
                     [roles["s"], roles["r"], roles["o"]]))
@@ -599,7 +615,7 @@ def run_app(args) -> dict:
                     for bi in range(lo + look,
                                     min(lo + look + K, len(batches))):
                         prepare(bi, ahead=bi - lo)
-                    window = [train[batches[lo + j]] for j in range(K)]
+                    window = [triples[batches[lo + j]] for j in range(K)]
                     roles = [triple_roles(t) for t in window]
                     epoch_losses.append(
                         device_runner(w.shard).run_scan(
@@ -622,9 +638,9 @@ def run_app(args) -> dict:
                                                       lr_epoch, staged=stg)
                     else:
                         loss = device_runner(w.shard)(
-                            triple_roles(train[idx]), None, lr_epoch)
+                            triple_roles(triples[idx]), None, lr_epoch)
                 else:
-                    roles = triple_roles(train[idx])
+                    roles = triple_roles(triples[idx])
                     neg = np.asarray(
                         w.pull_sample_keys(handles[bi], B * N)).reshape(B, N)
                     w.finish_sample(handles.pop(bi))
@@ -689,7 +705,13 @@ def run_app(args) -> dict:
         -1, 2 * run.ent_dim)[:, : run.ent_dim]
     result["ent_norm"] = float(np.sqrt((ent * ent).sum(axis=1)).mean())
     alog("[kge]", srv.sync.report())
-    srv.shutdown()
+    return result
+
+
+def run_app(args) -> dict:
+    run = open_run(args)
+    result = train(run)
+    run.srv.shutdown()
     return result
 
 

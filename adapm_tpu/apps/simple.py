@@ -41,6 +41,7 @@ def run(args) -> bool:
         np.allclose(main, expect)
     alog(f"[simple] expect={expect.tolist()} main={main.tolist()} "
          f"{'PASSED' if ok else 'FAILED'}")
+    alog("[simple]", srv.sync.report())
     srv.shutdown()
     return ok
 

@@ -3,8 +3,8 @@
 Every jitted data-plane program the parameter manager dispatches lives
 HERE — moved from core/store.py, tier/coldpath.py, tier/promote.py and
 ops/dequant.py, bit-for-bit unchanged — together with the donation-aware
-pool allocation, the restore launder, and the program constructors the
-fused-step and collective layers use. Programs are module-level so the
+pool allocation and the program constructors the fused-step and
+collective layers use. Programs are module-level so the
 jit cache is shared across stores and port instances; the port wraps
 each dispatch in the process-wide sharded-dispatch gate
 (docs/EXECUTOR.md) so per-device enqueue orders stay identical under
@@ -17,7 +17,7 @@ positive out-of-range values are safe sentinels (docs/MEMORY.md).
 """
 from __future__ import annotations
 
-from functools import partial
+from functools import lru_cache, partial
 
 import jax
 import jax.numpy as jnp
@@ -385,9 +385,12 @@ def _write_main_rows_int8(main, sh, row, qvals, scales):
     return main.at[sh, row].set(vals, mode="drop")
 
 
-# the restore launder (utils/checkpoint.py restore path): jnp.copy, NOT
-# `a + 0` — addition maps -0.0 to +0.0, breaking the exact round-trip
-_launder_fn = jax.jit(lambda a: jnp.copy(a))
+@lru_cache(maxsize=64)
+def _zeros_program(shape, dtype, sharding):
+    """Pool allocation: one zero-fill program per (shape, dtype,
+    sharding), compiled with the pool's sharding as its OUTPUT sharding
+    so no device ever holds more than its own shard."""
+    return jax.jit(lambda: jnp.zeros(shape, dtype), out_shardings=sharding)
 
 
 # ---------------------------------------------------------------------------
@@ -629,20 +632,18 @@ class JaxDevicePort(DevicePort):
     # -- buffer allocation / transfer ----------------------------------------
 
     def alloc_pool(self, shape, dtype, sharding):
-        return jax.device_put(jnp.zeros(shape, dtype), sharding)
+        # allocated IN the sharding: every device zero-fills only its
+        # own [1, slots, L] shard. A device_put of jnp.zeros(shape)
+        # would materialize the whole pool on device 0 first.
+        self.programs += 1
+        with _GATE:
+            return _zeros_program(tuple(shape), np.dtype(dtype),
+                                  sharding)()
 
     def install_pool(self, arr, sharding):
-        return self.launder(jax.device_put(arr, sharding))
-
-    def launder(self, x):
-        """Route a transfer-produced buffer through one XLA program
-        before it re-enters the donated chain: this image's XLA CPU
-        intermittently SEGFAULTS when a donating program consumes a raw
-        host->device transfer (r6; observed ~50% of checkpoint
-        sessions). Bit-exact (jnp.copy)."""
-        self.programs += 1
-        with _GATE:  # sharded program: one enqueue order per device set
-            return _launder_fn(x)
+        # a host array goes to the devices shard by shard (jax slices
+        # it on the host), so no device stages the whole pool
+        return jax.device_put(arr, sharding)
 
     def put_replicated(self, arr, sharding):
         # numpy in, asynchronous device_put out — the staging rule
@@ -658,10 +659,5 @@ class JaxDevicePort(DevicePort):
         return jax.jit(fn, **jit_kwargs)
 
     def compile_collective(self, fn, mesh, in_specs, out_specs):
-        # jax.shard_map graduated from jax.experimental.shard_map; this
-        # image's jax predates the top-level alias
-        shard_map = getattr(jax, "shard_map", None)
-        if shard_map is None:
-            from jax.experimental.shard_map import shard_map
-        return jax.jit(partial(shard_map, mesh=mesh, in_specs=in_specs,
-                               out_specs=out_specs)(fn))
+        return jax.jit(jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                                     out_specs=out_specs))

@@ -160,18 +160,13 @@ class DevicePort:
 
     def alloc_pool(self, shape, dtype, sharding):
         """A zeroed device pool in `sharding` — the donated-chain root.
-        Implementations must return a buffer that is SAFE to enter the
-        donating program chain immediately (see launder)."""
+        Allocated shard by shard: no device may hold more than its own
+        part of the pool, even transiently."""
         raise NotImplementedError
 
     def install_pool(self, arr, sharding):
-        """Host array -> device pool, laundered for the donated chain
-        (checkpoint restore)."""
-        raise NotImplementedError
-
-    def launder(self, x):
-        """Bit-exact copy through a device program: a transfer-produced
-        buffer must not enter the donated chain raw (r6 lesson)."""
+        """Host array -> device pool in `sharding` (checkpoint
+        restore); the result enters the donated chain."""
         raise NotImplementedError
 
     def put_replicated(self, arr, sharding):

@@ -345,10 +345,6 @@ class NumpyRefPort(DevicePort):
     def install_pool(self, arr, sharding):
         return np.array(arr, copy=True)
 
-    def launder(self, x):
-        self.programs += 1
-        return np.array(x, copy=True)
-
     def put_replicated(self, arr, sharding):
         return np.asarray(arr)
 

@@ -11,7 +11,10 @@ jax.distributed) plays that role.
 Modes:
   local  N subprocesses on this machine (reference dmlc_local.py), with the
          keepalive contract: a process exiting with code 254 is restarted
-         (dmlc_local.py:15-25).
+         (dmlc_local.py:15-25). On a TPU host this mode runs ONE rank: the
+         local ranks are given nothing that divides the chips, and a chip
+         belongs to one process at a time (`local_tpu_chips`). One process
+         drives all the chips of a host as kv shards.
   ssh    fan out over ssh using a hostfile, one process per line
          (reference dmlc_ssh.py).
   mpi    delegate process placement to mpirun (reference dmlc_mpi.py).
@@ -21,6 +24,7 @@ Usage: python -m adapm_tpu.launcher -n 2 -- python my_app.py --epochs 4
 from __future__ import annotations
 
 import argparse
+import glob
 import os
 import shlex
 import socket
@@ -37,6 +41,36 @@ def free_port() -> int:
     with socket.socket() as s:
         s.bind(("", 0))
         return s.getsockname()[1]
+
+
+# PCI ids of Google TPU chips (vendor 0x1ae0; device ids v2/v3, v4,
+# v5p, v5e, v6e — the table jax's own TPU detection reads)
+_TPU_PCI_VENDOR = "0x1ae0"
+_TPU_PCI_DEVICES = frozenset(
+    {"0x0027", "0x0056", "0x005e", "0x0062", "0x0063", "0x006f"})
+
+
+def local_tpu_chips() -> int:
+    """TPU chips the children's jax would claim on THIS host: 0 when
+    JAX_PLATFORMS excludes the tpu backend, else the number of TPU
+    functions on the PCI bus. Reads sysfs only — the launcher must never
+    start a jax backend (a parent that touched the chips would hold
+    them against its children)."""
+    platforms = os.environ.get("JAX_PLATFORMS", "")
+    if platforms and "tpu" not in platforms.split(","):
+        return 0
+    chips = 0
+    for vendor_path in glob.glob("/sys/bus/pci/devices/*/vendor"):
+        try:
+            with open(vendor_path) as f:
+                if f.read().strip() != _TPU_PCI_VENDOR:
+                    continue
+            with open(os.path.join(os.path.dirname(vendor_path),
+                                   "device")) as f:
+                chips += f.read().strip() in _TPU_PCI_DEVICES
+        except OSError:
+            continue
+    return chips
 
 
 def make_env(rank: int, num: int, coordinator: str,
@@ -61,6 +95,14 @@ def launch_local(n: int, cmd: List[str], keepalive: bool = True,
     indefinitely; here restart k waits min(backoff_base * 2^k,
     backoff_max) and after `max_restarts` restarts the rank's 254 is
     propagated as the job's failure code instead of looping)."""
+    chips = local_tpu_chips()
+    if n > 1 and chips:
+        raise RuntimeError(
+            f"launch_local: {n} ranks on one TPU host ({chips} chips): "
+            f"every rank would try to own every chip, and a chip belongs "
+            f"to one process at a time. Run ONE process per host — it "
+            f"takes all local chips as kv shards — or set "
+            f"JAX_PLATFORMS=cpu for a CPU-mesh run.")
     coordinator = coordinator or f"localhost:{free_port()}"
     codes = [0] * n
     threads = []

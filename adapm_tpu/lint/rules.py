@@ -72,7 +72,7 @@ def _enclosing_with(mod: ModuleInfo, node: ast.AST, names) -> bool:
 def _callee_program_name(mod: ModuleInfo,
                          call: ast.Call) -> Optional[str]:
     """Name of the called module-level program, for calls that can
-    target one: a bare name (`_gather(...)`, `_launder_fn(...)`) or an
+    target one: a bare name (`_gather(...)`, `_relocate(...)`) or an
     imported-module attribute (`dequant._write_main_rows_fp16(...)`).
     Method calls (`self._sync_replicas(...)`) return None — Server
     methods legitimately share names with the store programs they
@@ -126,7 +126,7 @@ def _terminates(stmts: List[ast.stmt]) -> bool:
 # The sharded-program site manifest: module-level jitted programs whose
 # dispatch enqueues onto every per-device execution queue. Each is
 # defined next to its callers and dispatched by NAME (store/coldpath/
-# dequant/promote programs, the checkpoint launder) — fused step fns
+# dequant/promote programs) — fused step fns
 # dispatch through runner-held variables and are covered by their own
 # `with srv.exec.track("main"), _GATE:` blocks, which this rule cannot
 # (and need not) see through. Grow this list when a new program class
@@ -146,8 +146,6 @@ SHARDED_DISPATCH_SITES = frozenset({
     # fused embedding-bag reads (device/jaxport.py, ISSUE 16)
     "_gather_pool", "_gather_pool_cold", "_gather_pool_cold_fp16",
     "_gather_pool_cold_int8",
-    # utils/checkpoint.py (restore launder)
-    "_launder_fn",
 })
 
 # context managers that ARE the gate at a dispatch site
@@ -939,8 +937,8 @@ class DeviceApiConfinementRule(Rule):
 
     @staticmethod
     def _attr_root(node: ast.AST) -> Optional[str]:
-        """Root Name of an attribute chain (`jax.experimental.
-        shard_map.shard_map` -> "jax"); None for non-Name roots."""
+        """Root Name of an attribute chain (`jax.lax.psum` ->
+        "jax"); None for non-Name roots."""
         while isinstance(node, ast.Attribute):
             node = node.value
         return node.id if isinstance(node, ast.Name) else None
@@ -952,8 +950,8 @@ class DeviceApiConfinementRule(Rule):
             return []
         banned_attrs = _DEVICE_API_ATTRS | _DEVICE_API_NAMES
         out = []
-        seen = set()  # (line, attr): a nested chain like
-        # jax.experimental.shard_map.shard_map matches twice
+        seen = set()  # (line, attr): a nested attribute chain ending
+        # in `.shard_map.shard_map` matches twice
         for node in ast.walk(mod.tree):
             if isinstance(node, ast.Attribute) and \
                     node.attr in banned_attrs and \
@@ -993,8 +991,8 @@ class DeviceApiConfinementRule(Rule):
                         f"reach the device stack through the "
                         f"DevicePort (docs/INVARIANTS.md#apm008)"))
             elif isinstance(node, ast.Import):
-                # plain `import jax.experimental.shard_map` — the
-                # evasion form the attribute check alone would miss
+                # plain `import jax.<...>.shard_map` — the evasion
+                # form the attribute check alone would miss
                 mods = [a.name for a in node.names
                         if set(a.name.split(".")) & banned_attrs]
                 if mods:

@@ -1,6 +1,9 @@
 """Native runtime loader: compiles router.cpp once (g++ -O3 -shared) into a
-cache directory and binds it with ctypes. Falls back to None when no
-compiler is available — callers keep a numpy path.
+cache directory and binds it with ctypes. `get_lib()` returns None when
+the build or load fails — callers keep a numpy path — but never
+silently: the failure is reported once on stderr with the compiler's own
+output (`ADAPM_NO_NATIVE=1` is the quiet, deliberate way to run without
+it).
 
 The reference ships its host runtime as C++ (libadapm.a); here the host-side
 hot loops (route resolution per fused step, stat counters, intent/replica
@@ -12,6 +15,7 @@ import ctypes
 import hashlib
 import os
 import subprocess
+import sys
 import threading
 from typing import Optional
 
@@ -48,7 +52,10 @@ def _host_tag() -> str:
     return hashlib.sha256(" ".join(parts).encode()).hexdigest()[:8]
 
 
-def _build() -> Optional[str]:
+def _build() -> str:
+    """Path of the compiled library (built on first use). Raises
+    RuntimeError carrying the compiler's stderr when it cannot be
+    built."""
     with open(_SRC, "rb") as f:
         tag = hashlib.sha256(f.read()).hexdigest()[:16]
     out = os.path.join(_cache_dir(),
@@ -58,20 +65,28 @@ def _build() -> Optional[str]:
     tmp = out + f".tmp{os.getpid()}"
     cmd = ["g++", "-O3", "-march=native", "-shared", "-fPIC", "-std=c++17",
            _SRC, "-o", tmp]
-    try:
-        subprocess.run(cmd, check=True, capture_output=True, timeout=120)
-    except Exception:
-        try:  # -march=native can be unsupported in exotic environments
-            cmd.remove("-march=native")
-            subprocess.run(cmd, check=True, capture_output=True, timeout=120)
-        except Exception:
-            return None
-    os.replace(tmp, out)  # atomic vs concurrent builders
-    return out
+    errors = []
+    # -march=native can be unsupported in exotic environments: one
+    # retry without it
+    for attempt in (cmd, [c for c in cmd if c != "-march=native"]):
+        try:
+            subprocess.run(attempt, check=True, capture_output=True,
+                           timeout=120)
+        except subprocess.CalledProcessError as e:
+            errors.append(f"$ {' '.join(attempt)}\n"
+                          f"{e.stderr.decode(errors='replace').strip()}")
+        except (OSError, subprocess.TimeoutExpired) as e:
+            errors.append(f"$ {' '.join(attempt)}\n{e!r}")
+            break  # no compiler at all / hung: the retry cannot help
+        else:
+            os.replace(tmp, out)  # atomic vs concurrent builders
+            return out
+    raise RuntimeError("\n".join(errors))
 
 
 def get_lib() -> Optional[ctypes.CDLL]:
-    """The compiled router library, or None if unavailable."""
+    """The compiled router library, or None if unavailable (reported
+    once on stderr unless ADAPM_NO_NATIVE asked for it)."""
     global _lib, _tried
     if _lib is not None or _tried:
         return _lib
@@ -81,17 +96,22 @@ def get_lib() -> Optional[ctypes.CDLL]:
         _tried = True
         if os.environ.get("ADAPM_NO_NATIVE"):
             return None
-        path = _build()
-        if path is None:
-            return None
+        path = None
         try:
+            path = _build()
             lib = ctypes.CDLL(path)
-        except OSError:
-            # stale/incompatible cached binary: fall back to numpy
-            try:
-                os.remove(path)
-            except OSError:
-                pass
+        except (RuntimeError, OSError) as e:
+            if path is not None:
+                # stale/incompatible cached binary: drop it so the next
+                # process rebuilds
+                try:
+                    os.remove(path)
+                except OSError:
+                    pass
+            print(f"[adapm native] router library unavailable, host "
+                  f"routing falls back to numpy "
+                  f"(ADAPM_NO_NATIVE=1 silences this):\n{e}",
+                  file=sys.stderr, flush=True)
             return None
         i64p = np.ctypeslib.ndpointer(np.int64, flags="C_CONTIGUOUS")
         i32p = np.ctypeslib.ndpointer(np.int32, flags="C_CONTIGUOUS")

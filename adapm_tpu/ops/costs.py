@@ -25,8 +25,8 @@ Variants probed by `calibrate_store`:
   - `cold_wire_<m>`  — the tiered cold path through the quantized wire
                        (only on tiered stores with a non-fp32 cold
                        dtype);
-  - `pallas_gather`  — ops/pallas_kernels.gather_rows, where the stack
-                       supports it (TPU; skipped silently elsewhere).
+  - `pallas_gather`  — ops/pallas_kernels.gather_rows, on TPU stores
+                       (the kernel has no lowering elsewhere).
 
 Dispatch-time consult: `prefer_fused(L, n, dtype, pooling)` compares
 the measured fused vs host-pool entries at the nearest calibrated
@@ -295,10 +295,12 @@ def calibrate_store(store, table: KernelCostTable,
                         lambda: np.asarray(store.gather(
                             o_sh, cold_sl, c_sh, c_sl, use_c))[:n],
                         repeats))
-        # Pallas block gather (ops/pallas_kernels.py): TPU-only — on
-        # stacks without Pallas lowering the first call raises and the
-        # variant is simply absent from the table
-        try:
+        # Pallas block gather (ops/pallas_kernels.py): the kernel only
+        # lowers for TPU, so the variant is measured on TPU stores and
+        # absent from the table everywhere else — decided from the
+        # platform, so a failure ON a TPU propagates
+        if store.port.name == "jax" and \
+                store.ctx.devices[0].platform == "tpu":
             import jax.numpy as jnp
             from .pallas_kernels import gather_rows
             pool2d = jnp.zeros((max(8 * 8, store.main_slots), L),
@@ -311,8 +313,6 @@ def calibrate_store(store, table: KernelCostTable,
                 _time_median(
                     lambda: np.asarray(gather_rows(pool2d, idx)),
                     repeats))
-        except Exception:  # noqa: BLE001 — unsupported stack, not an error
-            pass
 
 
 def calibrate_server(server, buckets: Iterable[int] = (64, 512),

@@ -1,19 +1,21 @@
 """Pallas TPU kernels: alternative data-plane primitives.
 
-Status (measured on TPU v5e, 2026-07; see docs/PERF.md): for the random
-row-access patterns that dominate this framework (embedding gather /
-scatter-add of ~2KB rows), XLA's native gather/scatter is the fastest
-primitive available on this stack — a scalar-prefetch index-map Pallas
-gather reaches ~0.7x of XLA's row rate, and manual-DMA kernels
-(make_async_copy from HBM refs) are not supported by the deployment
-compiler. The fused training step therefore rides XLA (ops/fused.py),
-and these kernels are kept as (a) working, tested templates for future
-kernel work, and (b) the fallback path should a target stack invert the
-tradeoff.
+Status: both kernels compile under the installed Mosaic compiler (jax
+0.9.0 / libtpu 0.0.34) and match numpy on a v5e at the store's row width
+— `chip_smoke.py`, part "kernels", checks that on every chip run; the
+CPU tests run them in interpret mode only. Their SPEED has not been
+measured on the current installation. On an earlier one (2026-07, see
+docs/PERF.md "Pallas findings") XLA's native gather/scatter was the
+fastest primitive for the random ~2 KB row accesses that dominate this
+framework — the scalar-prefetch index-map gather below reached ~0.7x of
+XLA's row rate — so the fused training step rides XLA (ops/fused.py) and
+these are kept as working templates, reached only from cost calibration
+(ops/costs.py). Manual-DMA kernels (make_async_copy from HBM refs) have
+never been tried on this compiler (ROADMAP A3, C5).
 
-The kernels use only the widely-supported Pallas subset: BlockSpec grid
-pipelines + scalar prefetch (compiler-generated, double-buffered DMA), no
-manual semaphores.
+The kernels use only the BlockSpec subset: grid pipelines + scalar
+prefetch (compiler-generated, double-buffered DMA), no manual
+semaphores.
 """
 from __future__ import annotations
 

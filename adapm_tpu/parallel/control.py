@@ -57,13 +57,8 @@ def init_from_env() -> bool:
     hb = int(round(float(os.environ.get("ADAPM_COORD_HEARTBEAT_S", "0"))))
     if hb > 0:
         kw["heartbeat_timeout_seconds"] = hb
-    try:
-        jax.distributed.initialize(coordinator_address=coord,
-                                   num_processes=n, process_id=pid, **kw)
-    except TypeError:
-        # older jax without the heartbeat kwarg: fall back to bare init
-        jax.distributed.initialize(coordinator_address=coord,
-                                   num_processes=n, process_id=pid)
+    jax.distributed.initialize(coordinator_address=coord,
+                               num_processes=n, process_id=pid, **kw)
     return True
 
 

@@ -16,7 +16,6 @@ store only needs "kv".
 from __future__ import annotations
 
 import dataclasses
-import os
 from typing import Optional, Sequence
 
 import jax
@@ -51,11 +50,10 @@ class MeshContext:
         """Stage a host array for jitted programs: committed + replicated.
         This is THE staging rule (docs/PERF.md "Host-array staging"): a
         device-0 `jnp.asarray` gets host-resharded by every mesh-compiled
-        executable per call, and a bare numpy arg uploads synchronously
-        inside dispatch on remote-attached backends; a replicated
-        device_put is asynchronous and already in the sharding
-        executables expect. Routed through the DevicePort (ISSUE 14) —
-        late import: the device plane sits above the mesh layer."""
+        executable per call; a replicated device_put is asynchronous
+        and already in the sharding executables expect. Routed through
+        the DevicePort (ISSUE 14) — late import: the device plane sits
+        above the mesh layer."""
         from ..device import default_port
         return default_port().put_replicated(arr, self.replicated())
 
@@ -63,26 +61,12 @@ class MeshContext:
 def make_mesh(num_shards: Optional[int] = None,
               devices: Optional[Sequence[jax.Device]] = None) -> MeshContext:
     if devices is None:
-        # ADAPM_PLATFORM forces a backend (tests use cpu + virtual devices
-        # even when a TPU plugin claimed the default platform). Also make it
-        # the *default* backend when possible: remote-attached default
-        # backends add per-dispatch round trips even for arrays living on
-        # the forced platform's devices.
-        platform = os.environ.get("ADAPM_PLATFORM")
-        if platform:
-            try:
-                jax.config.update("jax_platforms", platform)
-            except Exception:
-                pass  # backends already initialized differently: still
-                # usable via the explicit device list below
-        if jax.process_count() > 1:
-            # multi-host: each process's Server owns pools on ITS devices
-            # only (the cross-process plane is the DCN channel + global
-            # sync rounds, core/kv.py); jax.devices() would include
-            # non-addressable peers
-            devices = jax.local_devices()
-        else:
-            devices = jax.devices(platform) if platform else jax.devices()
+        # the platform is jax's own choice (JAX_PLATFORMS); multi-host:
+        # each process's Server owns pools on ITS devices only (the
+        # cross-process plane is the DCN channel + global sync rounds,
+        # core/kv.py) — jax.devices() would include non-addressable peers
+        devices = jax.local_devices() if jax.process_count() > 1 \
+            else jax.devices()
     if num_shards is None:
         num_shards = len(devices)
     if num_shards > len(devices):

@@ -5,7 +5,7 @@ Every function here is the tiered twin of a `ShardedStore` device
 program and preserves its BIT-EXACT semantics (the tentpole contract):
 
   - reads select the cold row's bits verbatim (`jnp.where` merge, never
-    `+ 0` — addition maps -0.0 to +0.0, the checkpoint-launder lesson);
+    `+ 0` — addition maps -0.0 to +0.0);
   - additive writes are single f32 adds on either side (IEEE f32
     addition is deterministic; in-batch duplicates accumulate in batch
     order on both the XLA scatter and `np.add.at`);

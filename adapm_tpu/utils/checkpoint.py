@@ -32,19 +32,6 @@ import numpy as np
 FORMAT_VERSION = 3
 
 
-def _launder(x):
-    """Bit-exact copy through a jitted XLA program (see restore_server:
-    a transfer-produced buffer entering the donated chain intermittently
-    segfaults this image's XLA CPU; one extra pool copy at restore
-    frequency is free). jnp.copy, NOT `a + 0`: addition maps -0.0 to
-    +0.0, which would break the exact state round-trip this module
-    promises. Lives on the DevicePort since ISSUE 14 (one compiled
-    executable per pool shape, shared process-wide; the port holds the
-    dispatch gate internally)."""
-    from ..device import default_port
-    return default_port().launder(x)
-
-
 def rank_path(path: str, rank: int) -> str:
     return f"{path}.rank{rank}.npz"
 
@@ -187,14 +174,6 @@ def restore_server(server, path: str) -> None:
                     assert arr.shape == cur.shape, (
                         f"pool {name}_{cid} geometry mismatch: "
                         f"checkpoint {arr.shape} vs server {cur.shape}")
-                # install_pool routes the restored pool through an XLA
-                # program before it re-enters the donated-buffer chain:
-                # this image's XLA CPU intermittently SEGFAULTS when a
-                # later donating program (e.g. the first post-restore
-                # sync_replicas) consumes a buffer produced directly by
-                # a host->device transfer (observed ~50% of
-                # test_checkpoint sessions, also on pre-r6 code); an
-                # XLA-produced buffer dodges it
                 setattr(st, name, st.port.install_pool(arr, sh))
 
         # rebuild free lists from table occupancy

@@ -38,7 +38,6 @@ ROUNDS = 20
 
 
 def child() -> None:
-    os.environ.setdefault("ADAPM_PLATFORM", "cpu")
     import adapm_tpu
     from adapm_tpu.config import SystemOptions
     from adapm_tpu.parallel import control
@@ -199,7 +198,6 @@ def main() -> None:
     n = int(sys.argv[1]) if len(sys.argv) > 1 else 2
     from adapm_tpu import launcher
     env = dict(os.environ)
-    env["ADAPM_PLATFORM"] = "cpu"
     env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = os.path.dirname(os.path.dirname(
         os.path.abspath(__file__)))

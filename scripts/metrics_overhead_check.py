@@ -31,7 +31,6 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
-os.environ.setdefault("ADAPM_PLATFORM", "cpu")
 
 import numpy as np  # noqa: E402
 

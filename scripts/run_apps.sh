@@ -1,8 +1,10 @@
 #!/usr/bin/env bash
 # App smoke runs on toy data (reference tests/run_apps.sh: MF dsgd +
-# columnwise, KGE, word2vec). Uses the CPU mesh unless run on TPU.
+# columnwise, KGE, word2vec): a functional check at toy sizes, pinned to
+# the CPU below. It says nothing about the chip — chip_smoke.py does.
 set -euo pipefail
 cd "$(dirname "$0")/.."
+export JAX_PLATFORMS=cpu
 
 FAST="--sys.sync.max_per_sec 0"
 
@@ -33,7 +35,6 @@ python -m adapm_tpu.apps.knowledge_graph_embeddings --dim 8 \
 echo "=== knowledge_graph_embeddings, 2 launched processes ==="
 # the reference smoke-runs every app under `dmlc_local.py -s 2`
 # (tests/run_apps.sh); same shape here via the launcher
-JAX_PLATFORMS=cpu ADAPM_PLATFORM=cpu \
 XLA_FLAGS="--xla_force_host_platform_device_count=2" \
 python -m adapm_tpu.launcher -n 2 --no-keepalive -- \
   python -m adapm_tpu.apps.knowledge_graph_embeddings --dim 8 \

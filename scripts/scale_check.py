@@ -1,5 +1,5 @@
 import os, sys, time
-os.environ["ADAPM_PLATFORM"] = "cpu"
+os.environ["JAX_PLATFORMS"] = "cpu"
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
                                 ".."))
 from xla_compat import mesh_flags  # noqa: E402

@@ -26,14 +26,13 @@ import sys
 import time
 from concurrent.futures import ThreadPoolExecutor
 
-os.environ.setdefault("ADAPM_PLATFORM", "cpu")
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
                                 ".."))
 flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in flags:
     from xla_compat import mesh_flags
     os.environ["XLA_FLAGS"] = (flags + " " + mesh_flags(8)).strip()
-os.environ.setdefault("JAX_COMPILATION_CACHE_DIR", "/tmp/jax_cache")
 
 import numpy as np  # noqa: E402
 

@@ -17,14 +17,11 @@ faulthandler.dump_traceback_later(
     int(os.environ.get("ADAPM_FAULT_T", "280")), exit=True)
 
 os.environ["JAX_PLATFORMS"] = "cpu"
-os.environ["ADAPM_PLATFORM"] = "cpu"
 sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 from xla_compat import mesh_flags  # noqa: E402
 
 os.environ.setdefault("XLA_FLAGS", mesh_flags(2))
-os.environ.setdefault("JAX_COMPILATION_CACHE_DIR", "/tmp/jax_cache")
-os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "0.1")
 os.environ.pop("PYTHONPATH", None)
 
 import jax  # noqa: E402

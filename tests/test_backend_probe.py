@@ -1,10 +1,9 @@
 """Device-backend probe (ISSUE 14 satellite; xla_compat.py).
 
-The bench r04 death mode was the TPU path dying AT SETUP — client
-construction aborting before any phase ran, taking the artifact with
-it. `probe_device_backend` detects that in a throwaway subprocess and
-`require_device_backend` turns it into the NAMED
-AcceleratorUnavailableError; bench.py records `backend: skipped`.
+A TPU path can die AT SETUP — client construction aborting before any
+phase runs. `probe_device_backend` detects that in a throwaway
+subprocess and `require_device_backend` turns it into the NAMED
+AcceleratorUnavailableError; bench.py fails its device phases with it.
 """
 import os
 import sys

@@ -1,5 +1,7 @@
-"""Pallas kernel correctness (interpret mode on the CPU mesh; the same
-kernels compile for TPU — measured results in docs/PERF.md)."""
+"""Pallas kernel correctness in INTERPRET mode on the CPU mesh: this
+checks the kernels' arithmetic and indexing only. That the installed
+Mosaic compiler accepts them (interpret=False, at the store's row width)
+is checked on the chip by `chip_smoke.py`, part "kernels"."""
 import numpy as np
 import pytest
 

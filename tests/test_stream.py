@@ -183,14 +183,17 @@ def test_cursor_restore_into_planeless_server():
 
 # -- freshness controller law ----------------------------------------------
 
-def _fresh_srv(slo_ms=50.0, **kw):
+def _fresh_srv(tmp_path, slo_ms=50.0, **kw):
+    # trace_flight exports at shutdown: into tmp_path, not the cwd
     return adapm_tpu.setup(NK, VLEN, opts=SystemOptions(
         sync_max_per_sec=2.0, prefetch=False, metrics=True,
-        trace_flight=True, stream_freshness_slo_ms=slo_ms, **kw))
+        trace_flight=True,
+        trace_flight_out=str(tmp_path / "flight.trace.json"),
+        stream_freshness_slo_ms=slo_ms, **kw))
 
 
-def test_freshness_law_direction_and_bounds():
-    srv = _fresh_srv()
+def test_freshness_law_direction_and_bounds(tmp_path):
+    srv = _fresh_srv(tmp_path)
     ctl = srv.stream.freshness
     assert ctl is not None and ctl.target_s == 0.05
     h = srv.flight.freshness.h_freshness
@@ -230,8 +233,9 @@ def test_freshness_law_direction_and_bounds():
     srv.shutdown()
 
 
-def test_freshness_steers_to_tightest_class_target():
-    srv = _fresh_srv(slo_ms=400.0, stream_freshness_slo_class="1=200")
+def test_freshness_steers_to_tightest_class_target(tmp_path):
+    srv = _fresh_srv(tmp_path, slo_ms=400.0,
+                     stream_freshness_slo_class="1=200")
     ctl = srv.stream.freshness
     # per-class freshness is a write-path property: the controller
     # honestly steers to the TIGHTEST class (docs/STREAMING.md)

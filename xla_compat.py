@@ -1,129 +1,46 @@
-"""XLA_FLAGS compatibility probing.
+"""XLA environment helpers for the harness: the CPU-mesh XLA_FLAGS value
+and the device-backend pre-check.
 
 XLA's flag parser ABORTS the whole process (parse_flags_from_env.cc
 SIGABRT, not a Python exception) when XLA_FLAGS contains a flag the
-installed jaxlib does not know. The tuning flags this repo sets for the
-CPU test/bench harness (the in-process collective watchdog timeouts) do
-not exist in every jaxlib vintage, so baking them into XLA_FLAGS
-unconditionally kills EVERY test and bench process on such an install —
-observed in this image: `make_cpu_client` aborts before the first test
-runs.
-
-`filter_xla_flags` vets optional flags in a throwaway subprocess (the
-only way to survive the abort) and caches the verdict per jaxlib
-version, so the probe costs one interpreter start per environment, not
-per run.
+installed jaxlib does not know, so harness code never hand-writes the
+flag string: `mesh_flags(n)` is the one place that spells it, for the
+one installation this repo targets (jax/jaxlib 0.9.0, which accepts all
+three flags).
 """
 from __future__ import annotations
 
-import hashlib
 import os
 import subprocess
 import sys
-import tempfile
-from typing import List, Sequence
-
-# flags old enough to be universally safe are not probed
-_ALWAYS_SAFE_PREFIXES = ("--xla_force_host_platform_device_count",)
-
-
-def _cache_path(flags: Sequence[str]) -> str:
-    try:
-        from importlib.metadata import version
-        ver = version("jaxlib")
-    except Exception:  # pragma: no cover - jaxlib always installed here
-        ver = "unknown"
-    h = hashlib.sha1((" ".join(flags)).encode()).hexdigest()[:12]
-    return os.path.join(tempfile.gettempdir(),
-                        f"adapm_xla_flags_{ver}_{h}")
-
-
-def _probe(flags: Sequence[str], timeout: float = 120.0):
-    """True/False: a fresh interpreter could / could not build the CPU
-    client with `flags` in XLA_FLAGS (an unknown flag ABORTS that
-    subprocess, so rc != 0 is a definitive rejection). None: the probe
-    itself failed to produce a verdict (timeout on a loaded host, spawn
-    error) — the caller must not CACHE that as a rejection."""
-    env = dict(os.environ)
-    env["JAX_PLATFORMS"] = "cpu"
-    env["ADAPM_PLATFORM"] = "cpu"
-    env["XLA_FLAGS"] = " ".join(flags)
-    try:
-        r = subprocess.run(
-            [sys.executable, "-c",
-             "import jax; jax.config.update('jax_platforms', 'cpu'); "
-             "jax.devices()"],
-            env=env, capture_output=True, timeout=timeout)
-        return r.returncode == 0
-    except Exception:
-        return None
-
-
-def filter_xla_flags(flags: Sequence[str]) -> List[str]:
-    """Return the subset of `flags` the installed jaxlib accepts.
-
-    Probes all candidate flags at once (the common case: all supported
-    or the whole same-vintage group missing); on a definitive rejection
-    retries each flag individually. Definitive verdicts are cached under
-    the system temp dir, keyed by jaxlib version + flag set; an
-    inconclusive probe (timeout on a loaded host) conservatively omits
-    the flags for THIS run only — caching it would strip supported
-    watchdog flags forever.
-    """
-    need_probe = [f for f in flags
-                  if not f.startswith(_ALWAYS_SAFE_PREFIXES)]
-    safe = [f for f in flags if f.startswith(_ALWAYS_SAFE_PREFIXES)]
-    if not need_probe:
-        return list(flags)
-    cache = _cache_path(need_probe)
-    if os.path.exists(cache):
-        with open(cache) as f:
-            kept = f.read().split()
-        return safe + [f for f in need_probe if f in kept]
-    verdict = _probe(safe + need_probe)
-    if verdict is None:
-        return safe  # inconclusive: omit but do not cache
-    if verdict:
-        kept = need_probe
-    else:
-        per_flag = {f: _probe(safe + [f]) for f in need_probe}
-        if None in per_flag.values():
-            return safe + [f for f, ok in per_flag.items() if ok]
-        kept = [f for f, ok in per_flag.items() if ok]
-    tmp = cache + f".tmp{os.getpid()}"
-    with open(tmp, "w") as f:  # atomic: concurrent pytest workers race
-        f.write(" ".join(kept))
-    os.replace(tmp, cache)
-    return safe + kept
 
 
 class AcceleratorUnavailableError(RuntimeError):
     """An accelerator backend cannot be used in this environment —
-    NAMED (ISSUE 14 satellite). The bench r04 death mode was the TPU
-    path dying AT SETUP (client construction aborts / hangs before the
-    first program); callers that see this error skip the backend and
-    record it (`bench.py` writes `backend: skipped`) instead of taking
-    the whole run down or silently degrading."""
+    NAMED (ISSUE 14 satellite): the TPU path can die AT SETUP (client
+    construction aborts / hangs before the first program). `bench.py`
+    fails its device phases with this name; nothing degrades to
+    another backend."""
 
 
 def probe_device_backend(platform=None, timeout: float = 180.0):
     """Can `platform` (None = the environment's default backend)
     initialize and enumerate devices? Probed in a throwaway subprocess
     — an unusable backend often ABORTS or wedges client construction,
-    which no in-process try/except survives (the filter_xla_flags
-    lesson, applied to backends).
+    which no in-process try/except survives. The child has exited by
+    the time this returns, so it never holds the chip against the
+    caller's next process.
 
     Returns (verdict, detail):
       True,  "tpu x4"      — usable; detail names platform + count
       False, "...rc=134.." — definitively unusable (died at setup)
-      None,  "...timeout"  — inconclusive (wedged relay / loaded host);
-                             treat as unusable for THIS run, but do not
-                             record it as a permanent verdict.
+      None,  "...timeout"  — inconclusive (loaded host); treat as
+                             unusable for THIS run, but do not record
+                             it as a permanent verdict.
     """
     env = dict(os.environ)
     if platform:
         env["JAX_PLATFORMS"] = platform
-        env["ADAPM_PLATFORM"] = platform
     try:
         r = subprocess.run(
             [sys.executable, "-c",
@@ -131,8 +48,7 @@ def probe_device_backend(platform=None, timeout: float = 180.0):
              "print(ds[0].platform, len(ds))"],
             env=env, capture_output=True, timeout=timeout, text=True)
     except subprocess.TimeoutExpired:
-        return None, (f"backend probe timed out after {timeout:.0f}s "
-                      f"(wedged relay / loaded host)")
+        return None, f"backend probe timed out after {timeout:.0f}s"
     except Exception as e:  # pragma: no cover - spawn failure
         return None, f"backend probe failed to spawn: {e}"
     if r.returncode != 0:
@@ -147,8 +63,7 @@ def probe_device_backend(platform=None, timeout: float = 180.0):
 def require_device_backend(platform=None, timeout: float = 180.0) -> str:
     """Raise AcceleratorUnavailableError unless `platform` probes
     usable; returns the probe detail on success. The setup-death guard
-    for scripts that would otherwise die mid-construction (the bench
-    r04 mode)."""
+    for scripts that would otherwise die mid-construction."""
     verdict, detail = probe_device_backend(platform, timeout=timeout)
     if verdict is not True:
         raise AcceleratorUnavailableError(
@@ -160,14 +75,10 @@ def require_device_backend(platform=None, timeout: float = 180.0) -> str:
 
 def mesh_flags(devices: int) -> str:
     """The harness's XLA_FLAGS value for an N-virtual-device CPU mesh:
-    the device-count flag plus — only when the installed jaxlib knows
-    them — the in-process collective watchdog timeouts (XLA CPU kills
-    the process after 40 s if rendezvous participants straggle, which N
-    participants serialized on a 1-2 core host legitimately do on big
-    programs). One probe per environment; every caller (conftest,
-    bench.py, the mp test harness, scripts) shares the cached verdict."""
-    return " ".join(filter_xla_flags([
-        f"--xla_force_host_platform_device_count={devices}",
-        "--xla_cpu_collective_call_warn_stuck_timeout_seconds=120",
-        "--xla_cpu_collective_call_terminate_timeout_seconds=900",
-    ]))
+    the device-count flag plus the in-process collective watchdog
+    timeouts (XLA CPU kills the process after 40 s if rendezvous
+    participants straggle, which N participants serialized on a 1-2
+    core host legitimately do on big programs)."""
+    return (f"--xla_force_host_platform_device_count={devices} "
+            "--xla_cpu_collective_call_warn_stuck_timeout_seconds=120 "
+            "--xla_cpu_collective_call_terminate_timeout_seconds=900")

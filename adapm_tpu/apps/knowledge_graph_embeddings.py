@@ -549,6 +549,11 @@ def train(run: KgeRun) -> dict:
     guard = RuntimeGuard(args.max_runtime)
     watch = Stopwatch(start=True)
     result = {}
+    # host time of the loop's own two phases (Server._span; the step's
+    # other phases are bracketed where they live: kv.intent,
+    # fused.dispatch, kv.drive_rounds, kv.advance_clock)
+    h_prepare = srv.obs.histogram("app.prepare_s", shared=True)
+    h_pass_end = srv.obs.histogram("app.pass_end_s", shared=True)
     if run.truth_mrr is not None:
         result["truth_mrr"] = run.truth_mrr
         result["truth_mrr_o"] = ds.truth_mrr_o
@@ -586,20 +591,23 @@ def train(run: KgeRun) -> dict:
                 if bi <= prepared_hi:
                     return
                 prepared_hi = bi
-                t = triples[batches[bi]]
-                roles = triple_roles(t)
-                ks = np.unique(np.concatenate(
-                    [roles["s"], roles["r"], roles["o"]]))
-                fut = w.current_clock + ahead
-                w.intent(ks, fut, fut + 1)
-                if not args.device_routes:
-                    handles[bi] = w.prepare_sample(B * N, fut, fut + 1)
-                elif srv.prefetch is not None and K == 1:
-                    # prefetch pipeline on: the batch's key upload rides
-                    # the prepare path (DeviceRoutedRunner.prefetch_keys)
-                    # instead of the dispatch critical section
-                    staged[bi] = (roles, device_runner(w.shard)
-                                  .prefetch_keys(roles))
+                with srv._span("app.prepare", h_prepare):
+                    t = triples[batches[bi]]
+                    roles = triple_roles(t)
+                    ks = np.unique(np.concatenate(
+                        [roles["s"], roles["r"], roles["o"]]))
+                    fut = w.current_clock + ahead
+                    w.intent(ks, fut, fut + 1)
+                    if not args.device_routes:
+                        handles[bi] = w.prepare_sample(B * N, fut,
+                                                       fut + 1)
+                    elif srv.prefetch is not None and K == 1:
+                        # prefetch pipeline on: the batch's key upload
+                        # rides the prepare path
+                        # (DeviceRoutedRunner.prefetch_keys) instead of
+                        # the dispatch critical section
+                        staged[bi] = (roles, device_runner(w.shard)
+                                      .prefetch_keys(roles))
 
             K = max(1, args.scan_steps) if args.device_routes else 1
             for bi in range(min(max(args.lookahead, K), len(batches))):
@@ -650,16 +658,22 @@ def train(run: KgeRun) -> dict:
                 epoch_losses.append(loss)
                 srv.drive_rounds(args.sync_rounds_per_step)
                 w.advance_clock()
-        srv.quiesce()
-
-        # scan windows contribute [K] loss vectors, per-step path scalars
-        epoch_loss = float(np.sum([np.asarray(l).sum()
-                                   for l in epoch_losses]))
-        nbatches = int(np.sum([np.asarray(l).size for l in epoch_losses]))
-        # loss aggregation through the PS loss key (ps_allreduce idiom)
-        total = run.allreduce(run.loss_key_l,
-                              np.array([epoch_loss / max(nbatches, 1)]))
-        run.reset_key(run.loss_key_l, 1)
+        with srv._span("app.pass_end", h_pass_end):
+            srv.quiesce()
+            with srv._span("app.loss_fetch"):
+                # scan windows contribute [K] loss vectors, per-step
+                # path scalars
+                epoch_loss = float(np.sum([np.asarray(l).sum()
+                                           for l in epoch_losses]))
+                nbatches = int(np.sum([np.asarray(l).size
+                                       for l in epoch_losses]))
+            with srv._span("app.loss_allreduce"):
+                # loss aggregation through the PS loss key
+                # (ps_allreduce idiom)
+                total = run.allreduce(
+                    run.loss_key_l,
+                    np.array([epoch_loss / max(nbatches, 1)]))
+                run.reset_key(run.loss_key_l, 1)
         epoch_report("kge", epoch, float(total[0]), watch)
         result["loss"] = float(total[0])
 

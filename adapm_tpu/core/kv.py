@@ -35,7 +35,7 @@ import numpy as np
 from ..base import CLOCK_MAX, LOCAL, WORKER_FINISHED, MgmtTechniques
 from ..config import SystemOptions
 from ..exec.executor import dispatch_gate
-from ..obs.spans import NULL_SPAN
+from ..obs.spans import Span
 from ..parallel.mesh import MeshContext, get_mesh_context
 from .addressbook import Addressbook
 from .store import OOB, ShardedStore
@@ -252,6 +252,12 @@ class Server:
         self.obs.gauge("kv.topology_version",
                        fn=lambda: self.topology_version)
         self.obs.gauge("kv.workers", fn=lambda: len(self._workers))
+        # host time of a training step's server-side phases, one
+        # observation per call (Server._span; PERF.md section 3)
+        self._h_drive = self.obs.histogram("kv.drive_rounds_s")
+        self._h_quiesce = self.obs.histogram("kv.quiesce_s")
+        self._h_intent = self.obs.histogram("kv.intent_s")
+        self._h_clock = self.obs.histogram("kv.advance_clock_s")
         # collective wait-time histograms, observed by the (server-less)
         # control plane via observe_global (parallel/control.py) and by
         # Server.barrier below
@@ -553,11 +559,12 @@ class Server:
                     self._c_topo_bumps.inc()
                     self._ab_mut_acked = self.ab.mutations
 
-    def _span(self, name: str):
-        """Span context for phase `name` — the shared no-op when span
-        tracing is off (one attribute check on the hot path)."""
-        sp = self.spans
-        return NULL_SPAN if sp is None else sp.span(name)
+    def _span(self, name: str, hist=None) -> Span:
+        """THE phase bracket (obs/spans.py): a host event
+        `adapm.<name>` on the profiler's clock whenever a profiler
+        session runs, the elapsed seconds into `hist` when given, and a
+        SpanTracer record under --sys.trace.spans."""
+        return Span(name, hist, self.spans)
 
     # -- worker management ---------------------------------------------------
 
@@ -1135,7 +1142,7 @@ class Server:
         caller's classification of the next channel instead of
         serializing behind the lock."""
         mode = self.opts.sync_compress if compress else "off"
-        with self._lock:
+        with self._span("kv.sync_replicas"), self._lock:
             ab = self.ab
             karr = np.ascontiguousarray(keys, dtype=np.int64)
             sarr = np.ascontiguousarray(shards, dtype=np.int32)
@@ -1227,7 +1234,7 @@ class Server:
                 pol.guard_blocked("reloc")
         demoted = np.empty(0, dtype=np.int64)
         n_moved = 0
-        with self._lock:
+        with self._span("kv.relocate"), self._lock:
             ab = self.ab
             # dedup: a duplicate key would double-free its old main slot in
             # relocate_batch (the drain path dedups in Worker.intent, but
@@ -1541,11 +1548,12 @@ class Server:
         relocations, replica churn, and the device-table re-uploads they
         trigger — overlaps the in-flight device step instead of
         serializing after it."""
-        if self.prefetch is not None:
-            self.prefetch.pump(n)
-        else:
-            for _ in range(n):
-                self.sync.run_round()
+        with self._span("kv.drive_rounds", self._h_drive):
+            if self.prefetch is not None:
+                self.prefetch.pump(n)
+            else:
+                for _ in range(n):
+                    self.sync.run_round()
 
     def shutdown(self) -> None:
         """Deterministic teardown (ISSUE 5 satellite). Order matters —
@@ -1693,7 +1701,7 @@ class Server:
                           "serve", "tier", "exec", "flight", "slo",
                           "fault", "ckpt", "device", "episode",
                           "wtrace", "replay", "decision", "policy",
-                          "net", "stream")
+                          "net", "stream", "app")
 
     def metrics_snapshot(self, drain_device: bool = True) -> Dict:
         """One structured, JSON-serializable telemetry dict for this
@@ -1863,8 +1871,16 @@ class Server:
         FreshnessSLO controller report (effective target, lever
         positions vs their static knobs, adjustment log); `{}` when no
         `--sys.stream.*` knob is set (no plane object, zero stream.*
-        names — metrics_overhead_check.py pins default-off)."""
-        out: Dict = {"schema_version": 16,
+        names — metrics_overhead_check.py pins default-off).
+
+        schema_version 17 (PR 24): always-present `app` section — the
+        apps' own phase histograms (`app.prepare_s` / `app.pass_end_s`,
+        KGE `train()`); `{}` until an app loop ran on this server. The
+        train-step and serve phase histograms of the same PR land in
+        the existing `kv` / `fused` / `serve` sections, and the
+        `flight` section loses its four breakdown histograms to
+        `serve.*_s`."""
+        out: Dict = {"schema_version": 17,
                      "metrics_enabled": bool(self.obs.enabled)}
         for s in self._SNAPSHOT_SECTIONS:
             out[s] = {}
@@ -1992,7 +2008,7 @@ class Server:
             # recorded at entry so replay re-drives the quiesce at the
             # same point in the op stream (docs/REPLAY.md)
             wt.record_quiesce()
-        with self._round_lock:
+        with self._span("kv.quiesce", self._h_quiesce), self._round_lock:
             self.sync.quiesce()
 
     def collective_pull(self, keys) -> np.ndarray:
@@ -2116,23 +2132,15 @@ class Worker:
         return list(self._write_futs)
 
     def _instrumented(self, name: str, h, impl, *args):
-        """Latency-histogram + span + flight bracket for a worker op;
-        degrades to a plain call when metrics, spans, and flight
-        tracing are all off (the skip-wrapper discipline: each disabled
-        layer costs one `is None` check here)."""
-        sp = self.server.spans
+        """A worker op inside the phase bracket (Server._span: latency
+        histogram `h`, None with --sys.metrics 0), plus its
+        single-segment flight under --sys.trace.flight."""
         fl = self.server.flight
-        if h is None and sp is None and fl is None:
-            return impl(*args)
-        t0 = _time.perf_counter()
-        tok = sp.begin(name) if sp is not None else None
+        t0 = _time.perf_counter() if fl is not None else 0.0
         try:
-            return impl(*args)
+            with self.server._span(name, h):
+                return impl(*args)
         finally:
-            if h is not None:
-                h.observe(_time.perf_counter() - t0)
-            if tok is not None:
-                sp.end(name, tok)
             if fl is not None:
                 # a plain Worker op is a single-segment flight: one
                 # minted id, one slice on the caller's thread
@@ -2386,23 +2394,26 @@ class Worker:
         queues background staging: a later `pull` of exactly this
         (unique, sorted) key batch inside the window can be served from
         a pre-gathered staged buffer."""
-        keys = np.unique(self._keys(keys))
-        end = start if end is None else end
         srv = self.server
-        wt = srv.wtrace
-        if wt is not None:
-            wt.record_intent(self.worker_id, self._clock, keys,
-                             int(start), int(end))
-        self._intent_queue.push(keys, int(start), int(end))
-        if srv.prefetch is not None:
-            srv.prefetch.on_intent(self, keys, int(start), int(end))
+        with srv._span("kv.intent", srv._h_intent):
+            keys = np.unique(self._keys(keys))
+            end = start if end is None else end
+            wt = srv.wtrace
+            if wt is not None:
+                wt.record_intent(self.worker_id, self._clock, keys,
+                                 int(start), int(end))
+            self._intent_queue.push(keys, int(start), int(end))
+            if srv.prefetch is not None:
+                srv.prefetch.on_intent(self, keys, int(start), int(end))
 
     def advance_clock(self) -> int:
-        self._clock += 1
-        self.server._clocks[self.worker_id] = self._clock
-        wt = self.server.wtrace
-        if wt is not None:
-            wt.record_clock(self.worker_id, self._clock)
+        srv = self.server
+        with srv._span("kv.advance_clock", srv._h_clock):
+            self._clock += 1
+            srv._clocks[self.worker_id] = self._clock
+            wt = srv.wtrace
+            if wt is not None:
+                wt.record_clock(self.worker_id, self._clock)
         return self._clock
 
     @property

@@ -666,8 +666,7 @@ class SyncManager:
                 # (force) still acts.
                 return
             # round latency measured AFTER the throttle (sleep is policy,
-            # not work) — sync.round_s + the "sync.round" span
-            from ..obs.metrics import timed
+            # not work): the "sync.round" span observes sync.round_s
             # wire bytes this ROUND ships (keep syncs in the
             # --sys.sync.compress format + drop flushes, which go
             # exact) — sync.bytes_per_round. Measured here, under the
@@ -677,7 +676,7 @@ class SyncManager:
             # multi-process rounds issue channels concurrently.
             bytes_before = sum(st.sync_bytes_shipped
                                for st in self.server.stores)
-            with timed(self._h_round), self.server._span("sync.round"):
+            with self.server._span("sync.round", self._h_round):
                 self.drain_intents(force=force_intents)
                 if all_channels:
                     self._sync_all_channels()

@@ -17,7 +17,7 @@ positive out-of-range values are safe sentinels (docs/MEMORY.md).
 """
 from __future__ import annotations
 
-from functools import lru_cache, partial
+from functools import lru_cache, partial, wraps
 
 import jax
 import jax.numpy as jnp
@@ -48,7 +48,21 @@ F16_MAX = 65504.0
 # jitted data-plane programs (module level: jit cache shared process-wide)
 # ---------------------------------------------------------------------------
 
+def _scoped(name: str):
+    """Trace the program's body under `jax.named_scope(name)`: a stable
+    name on its operations in a device trace (compile-time metadata;
+    nothing at run time). PERF.md section 3 lists the names."""
+    def wrap(fn):
+        @wraps(fn)
+        def scoped(*args, **kwargs):
+            with jax.named_scope(name):
+                return fn(*args, **kwargs)
+        return scoped
+    return wrap
+
+
 @jax.jit
+@_scoped("adapm_gather")
 def _gather(main, cache, delta, o_shard, o_slot, c_shard, c_slot, use_cache):
     """Pull: main rows for owner-served keys, cache+delta for replica-served
     keys (o_slot is OOB for the latter to avoid pointless remote traffic)."""
@@ -76,6 +90,7 @@ def _pool_rows(rows, seg, out, pooling):
 
 
 @partial(jax.jit, static_argnames=("pooling",))
+@_scoped("adapm_gather_pool")
 def _gather_pool(main, cache, delta, o_shard, o_slot, c_shard, c_slot,
                  use_cache, seg, out, *, pooling):
     """Fused embedding-bag read (ISSUE 16): `_gather`'s member-row read
@@ -92,6 +107,7 @@ def _gather_pool(main, cache, delta, o_shard, o_slot, c_shard, c_slot,
 
 
 @partial(jax.jit, donate_argnums=(0, 1))
+@_scoped("adapm_scatter_add")
 def _scatter_add(main, delta, o_shard, o_slot, d_shard, d_slot, vals):
     """Push: each row routed either to main (owner path; d_slot=OOB) or to a
     local replica's delta row (o_slot=OOB). Duplicate keys accumulate."""
@@ -123,6 +139,7 @@ def _replica_create(main, cache, delta, o_shard, o_slot, c_shard, c_slot):
 
 
 @partial(jax.jit, donate_argnums=(0, 1, 2))
+@_scoped("adapm_sync_replicas")
 def _sync_replicas(main, cache, delta, r_shard, r_cslot, o_shard, o_slot):
     """One sync round over a batch of replicas (reference SyncManager
     startSync/ProcessSyncMessage, sync_manager.h:291-382, 553-799): extract
@@ -137,6 +154,7 @@ def _sync_replicas(main, cache, delta, r_shard, r_cslot, o_shard, o_slot):
 
 
 @partial(jax.jit, donate_argnums=(0, 1, 2), static_argnames=("mode",))
+@_scoped("adapm_sync_replicas")
 def _sync_replicas_compressed(main, cache, delta, r_shard, r_cslot,
                               o_shard, o_slot, threshold, *, mode):
     """_sync_replicas shipping QUANTIZED deltas with per-key error
@@ -185,6 +203,7 @@ def _sync_replicas_compressed(main, cache, delta, r_shard, r_cslot,
 
 
 @partial(jax.jit, donate_argnums=(0, 1, 2))
+@_scoped("adapm_sync_replicas")
 def _sync_replicas_thresholded(main, cache, delta, r_shard, r_cslot,
                                o_shard, o_slot, threshold):
     """_sync_replicas with the reference's sync threshold
@@ -234,6 +253,7 @@ def _refresh_after_sync(cache, delta, c_shard, c_slot, fresh, shipped):
 
 
 @partial(jax.jit, donate_argnums=(0, 1))
+@_scoped("adapm_relocate")
 def _relocate(main, delta, old_shard, old_slot, new_shard, new_slot,
               rc_shard, rc_slot):
     """Relocation: move rows old->new; if the destination shard held a

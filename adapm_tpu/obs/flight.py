@@ -7,18 +7,17 @@ Three pieces, each independently cheap:
   - **FlightTracer** (`--sys.trace.flight`, default **off**): a
     per-request trace id minted at `ServeSession.lookup` (and at
     `Worker.pull|push`, which are single-segment flights), carried on
-    the `AdmissionQueue` entry, recorded when the batcher coalesces
-    requests into a fused gather, and stamped onto the dispatched
-    program. Exported as Chrome trace-event JSON with Perfetto **flow
-    events** (`ph: s/t/f`, bound by id), so ONE served lookup renders
-    as a single connected chain: client wait -> queue -> batch window
-    -> dispatch -> device gather -> reply. Per-request breakdown
-    histograms (`flight.queue_s` / `batch_wait_s` / `dispatch_s` /
-    `device_s`) quantify where each millisecond went — the
-    "Dissecting Embedding Bag Performance" attribution, per request.
-    Default-off discipline (same as r7 spans): when off the Server
-    holds no tracer and every instrumented site pays one `is None`
-    check; the registry holds zero `flight.*` metric names.
+    the `AdmissionQueue` entry and recorded when the batcher coalesces
+    requests into a fused gather. The phase timestamps are the
+    request's own (`serve/admission.py LookupRequest`: the stamps the
+    always-on `serve.*_s` breakdown histograms read), so the tracer
+    keeps no second copy. Exported as Chrome trace-event JSON with
+    Perfetto **flow events** (`ph: s/t/f`, bound by id), so ONE served
+    lookup renders as a single connected chain: client wait -> queue
+    -> batch window -> dispatch -> device gather -> reply.
+    Default-off discipline: when off the Server holds no tracer and
+    every instrumented site pays one `is None` check; the registry
+    holds zero `flight.*` metric names.
 
   - **FreshnessProbe** (rides the tracer): event-to-servable staleness
     — the wall time from a `Worker.push` of a key to the FIRST serve
@@ -58,32 +57,6 @@ _PHASE_IDX = {n: i for i, n in enumerate(FLIGHT_PHASES)}
 # virtual Perfetto tracks for phases that happen on no one thread
 # (queue residence) or across threads (the coalescing window)
 _VIRTUAL_TRACKS = ("serve.queue", "serve.batch-window")
-
-
-class FlightTrace:
-    """One request's causal context: the minted id plus the phase
-    timestamps stamped along the way (perf_counter values; 0.0 = the
-    phase never happened, e.g. a shed request has no claim)."""
-
-    __slots__ = ("id", "t_mint", "t_claim", "t_dispatch", "t_enqueued",
-                 "t_done", "t_deliver")
-
-    def __init__(self, trace_id: int, t_mint: float):
-        self.id = trace_id
-        self.t_mint = t_mint
-        self.t_claim = 0.0      # AdmissionQueue try_claim (dispatcher side)
-        self.t_dispatch = 0.0   # batcher starts the coalesced lookup
-        self.t_enqueued = 0.0   # device gather programs enqueued
-        self.t_done = 0.0       # union values materialized on host
-        self.t_deliver = 0.0    # result handed to the waiting client
-
-    def breakdown_s(self) -> Dict[str, float]:
-        """queue / batch_wait / dispatch / device split in seconds
-        (only meaningful for a completed trace)."""
-        return {"queue_s": max(0.0, self.t_claim - self.t_mint),
-                "batch_wait_s": max(0.0, self.t_dispatch - self.t_claim),
-                "dispatch_s": max(0.0, self.t_enqueued - self.t_dispatch),
-                "device_s": max(0.0, self.t_done - self.t_enqueued)}
 
 
 class FreshnessProbe:
@@ -182,8 +155,7 @@ class FlightTracer:
     def __init__(self, registry=None, rank: int = 0,
                  max_slices: int = 200_000,
                  freshness_bound: int = 1024):
-        from .metrics import (Counter, Histogram,
-                              SERVE_LATENCY_BOUNDS_S)
+        from .metrics import Counter
         self.rank = rank
         self.max_slices = max_slices
         self.dropped = 0
@@ -195,7 +167,6 @@ class FlightTracer:
         # are derived from the sharded registry counter instead.
         self._stats_lock = threading.Lock()
         self._complete = 0
-        self._last_complete: Optional[FlightTrace] = None
         # (name, tid_key, t0, t1, ids, args) — tid_key is a real thread
         # ident (int) or a virtual-track name (str)
         self._slices: List[Tuple] = []
@@ -204,20 +175,7 @@ class FlightTracer:
         # every tick, so the table must be deep enough that the hot
         # head's probes aren't all evicted between serve reads)
         self.freshness = FreshnessProbe(registry, bound=freshness_bound)
-        use_reg = registry is not None and registry.enabled
-
-        def _hist(name):
-            return registry.histogram(name, bounds=SERVE_LATENCY_BOUNDS_S) \
-                if use_reg else Histogram(name,
-                                          bounds=SERVE_LATENCY_BOUNDS_S)
-
-        # the per-request breakdown ladder (x2 serve ladder: this is
-        # where the SLO lives, docs/OBSERVABILITY.md)
-        self.h_queue = _hist("flight.queue_s")
-        self.h_batch_wait = _hist("flight.batch_wait_s")
-        self.h_dispatch = _hist("flight.dispatch_s")
-        self.h_device = _hist("flight.device_s")
-        if use_reg:
+        if registry is not None and registry.enabled:
             self.c_traces = registry.counter("flight.traces_total")
             self.c_programs = registry.counter("flight.programs_total")
         else:
@@ -226,10 +184,11 @@ class FlightTracer:
 
     # -- recording -----------------------------------------------------------
 
-    def mint(self) -> FlightTrace:
-        """New per-request trace id (ServeSession.lookup)."""
+    def mint(self) -> int:
+        """New per-request trace id (ServeSession.lookup); rides the
+        request as `LookupRequest.trace`."""
         self.c_traces.inc()
-        return FlightTrace(next(self._next_id), time.perf_counter())
+        return next(self._next_id)
 
     def _slice(self, name: str, tid_key, t0: float, t1: float,
                ids: Tuple[int, ...], args: Optional[Dict]) -> None:
@@ -249,62 +208,52 @@ class FlightTracer:
                     time.perf_counter(), (i,), None)
         return i
 
-    def record_serve_batch(self, traces: Sequence[FlightTrace],
-                           t_dispatch: float, t_enqueued: float,
-                           t_done: float, n_requests: int, n_keys: int,
-                           n_unique: int) -> None:
-        """One coalesced micro-batch: stamps the program timestamps on
-        every member trace, records the queue slice per member, the
-        batch-window slice (which N requests rode this program — the
-        membership attribution), the program slice on the dispatching
-        thread with a nested device slice, and observes the breakdown
-        histograms."""
-        if not traces:
+    def record_serve_batch(self, reqs: Sequence, n_requests: int,
+                           n_keys: int, n_unique: int) -> None:
+        """One coalesced micro-batch, after its members were stamped
+        (`LookupRequest.stamp_batch`) and before any is delivered:
+        records the queue slice per traced member, the batch-window
+        slice (which N requests rode this program — the membership
+        attribution), and the program slice on the dispatching thread
+        with a nested device slice."""
+        reqs = [r for r in reqs if r.trace is not None]
+        if not reqs:
             return
         self.c_programs.inc()
-        ids = tuple(t.id for t in traces)
-        claims = [t.t_claim for t in traces if t.t_claim > 0.0]
-        t_first_claim = min(claims) if claims else t_dispatch
+        first = reqs[0]
+        ids = tuple(r.trace for r in reqs)
         tid = threading.get_ident()
         args = {"requests": int(n_requests), "keys": int(n_keys),
                 "unique_keys": int(n_unique)}
-        self._slice("flight.batch", "serve.batch-window", t_first_claim,
-                    t_dispatch, ids, args)
-        self._slice("flight.program", tid, t_dispatch, t_done, ids,
-                    {"stream": "serve"})
-        self._slice("flight.device", tid, t_enqueued, t_done, ids, None)
-        for tr in traces:
-            tr.t_dispatch = t_dispatch
-            tr.t_enqueued = t_enqueued
-            tr.t_done = t_done
-            if tr.t_claim > 0.0:
-                self._slice("flight.queue", "serve.queue", tr.t_mint,
-                            tr.t_claim, (tr.id,), None)
-                self.h_queue.observe(max(0.0, tr.t_claim - tr.t_mint))
-                self.h_batch_wait.observe(
-                    max(0.0, t_dispatch - tr.t_claim))
-            self.h_dispatch.observe(max(0.0, t_enqueued - t_dispatch))
-            self.h_device.observe(max(0.0, t_done - t_enqueued))
+        self._slice("flight.batch", "serve.batch-window",
+                    min(r.t_claim for r in reqs), first.t_dispatch, ids,
+                    args)
+        self._slice("flight.program", tid, first.t_dispatch,
+                    first.t_copied, ids, {"stream": "serve"})
+        self._slice("flight.device", tid, first.t_enqueued,
+                    first.t_copied, ids, None)
+        for r in reqs:
+            self._slice("flight.queue", "serve.queue", r.t0, r.t_claim,
+                        (r.trace,), None)
 
-    def finish_lookup(self, tr: FlightTrace, ok: bool) -> None:
+    def finish_lookup(self, req, ok: bool) -> None:
         """Client side, at lookup return (success or shed/error): the
         reply + lookup slices close the flow; a request that never got
         served records a terminal lookup slice with its status so no
         trace dangles silently."""
         now = time.perf_counter()
         tid = threading.get_ident()
-        if ok and tr.t_deliver > 0.0:
-            self._slice("flight.reply", tid, tr.t_deliver, now,
-                        (tr.id,), None)
-            self._slice("flight.lookup", tid, tr.t_mint, now,
-                        (tr.id,), None)
-            if tr.t_claim > 0.0 and tr.t_dispatch > 0.0:
+        if ok and req.t_deliver > 0.0:
+            self._slice("flight.reply", tid, req.t_deliver, now,
+                        (req.trace,), None)
+            self._slice("flight.lookup", tid, req.t_call, now,
+                        (req.trace,), None)
+            if req.t_claim > 0.0 and req.t_dispatch > 0.0:
                 with self._stats_lock:
                     self._complete += 1
-                    self._last_complete = tr
         else:
-            self._slice("flight.lookup", tid, tr.t_mint, now, (tr.id,),
-                        {"status": "shed"})
+            self._slice("flight.lookup", tid, req.t_call, now,
+                        (req.trace,), {"status": "shed"})
 
     # -- summaries -----------------------------------------------------------
 
@@ -312,18 +261,6 @@ class FlightTracer:
         return {"traces": int(self.c_traces.value),
                 "slices": len(self._slices),
                 "complete": self._complete, "dropped": self.dropped}
-
-    def exemplar(self) -> Optional[Dict[str, float]]:
-        """One sampled complete trace's queue/batch/dispatch/device
-        split (ms) — the bench artifact's 'where did the time go'
-        exhibit. None until a lookup completed under tracing."""
-        tr = self._last_complete
-        if tr is None:
-            return None
-        out = {"trace_id": tr.id}
-        out.update({k.replace("_s", "_ms"): round(v * 1e3, 4)
-                    for k, v in tr.breakdown_s().items()})
-        return out
 
     # -- export --------------------------------------------------------------
 

@@ -384,16 +384,16 @@ def observe_global(name: str, value: float) -> None:
 
 
 class timed:
-    """THE wall-time histogram bracket (one implementation, not a
-    per-site perf_counter/try-finally copy): observes elapsed seconds
-    into `target` on exit — a Histogram (or the null metric), or a
-    metric NAME resolved through the process-default registry at exit
-    (observe_global semantics, for call sites with no server handle)."""
+    """The wall-time bracket for call sites with NO server handle
+    (parallel/control.py; everything else goes through
+    `Server._span`, obs/spans.py): observes elapsed seconds on exit
+    into the metric NAMED `name`, resolved through the process-default
+    registry (observe_global semantics)."""
 
-    __slots__ = ("target", "_t0")
+    __slots__ = ("name", "_t0")
 
-    def __init__(self, target):
-        self.target = target
+    def __init__(self, name: str):
+        self.name = name
         self._t0 = 0.0
 
     def __enter__(self):
@@ -403,9 +403,5 @@ class timed:
 
     def __exit__(self, *exc):
         import time
-        dt = time.perf_counter() - self._t0
-        if isinstance(self.target, str):
-            observe_global(self.target, dt)
-        else:
-            self.target.observe(dt)
+        observe_global(self.name, time.perf_counter() - self._t0)
         return False

@@ -1,13 +1,31 @@
-"""Span tracer: begin/end events for named phases, Perfetto-loadable.
+"""Phase spans: ONE bracket, on the profiler's clock.
 
-`SpanTracer.span(name)` brackets a phase; completed spans are stored as
-(thread, name, start_us, dur_us) tuples and exported as Chrome
-trace-event JSON (`ph: "X"` complete events + thread-name metadata),
-which chrome://tracing and https://ui.perfetto.dev load directly.
+`Span` (handed out by `Server._span(name, hist=None)`) is the only way
+the program times a phase. Entering it
 
-Off by default (`--sys.trace.spans`); when off the Server holds no
-tracer and instrumented sites pay one `is None` check (or enter
-`NULL_SPAN`, a shared no-op context manager).
+  1. enters `jax.profiler.TraceAnnotation("adapm." + name)`: with no
+     profiler session that is a level check; inside one (any
+     `jax.profiler.start_trace`, or `benchmarks/run.py --trace 1`) it
+     is an event on the host plane of the same `.xplane.pb` as the
+     device's `XLA Ops` — host phases and device operations on one
+     clock. The profiler session is the switch: there is no flag;
+  2. if `hist` is given, observes the elapsed seconds into that
+     registry histogram on exit (the null metric under
+     `--sys.metrics 0`);
+  3. if `--sys.trace.spans` is on, records into the `SpanTracer` below
+     (breadcrumb + Chrome JSON for operators, docs/OBSERVABILITY.md).
+
+Rule for names and nesting: no span may enclose a whole pass, epoch or
+step loop — the outermost span on a thread is ONE phase of a step or of
+a micro-batch (an idle gap is attributed to the host event that
+overlaps it most, and an enclosing span would swallow every gap under
+it).
+
+`SpanTracer` stores completed spans as (thread, name, start_us, dur_us)
+tuples and exports them as Chrome trace-event JSON (`ph: "X"` complete
+events + thread-name metadata), which chrome://tracing and
+https://ui.perfetto.dev load directly. Off by default
+(`--sys.trace.spans`); when off the Server holds no tracer.
 
 Crash breadcrumb (ISSUE 2 satellite): when given a breadcrumb path, the
 tracer overwrites a small fixed-size file with the span name + wall time
@@ -32,40 +50,40 @@ import threading
 import time
 from typing import Dict, List, Optional, Tuple
 
+from jax.profiler import TraceAnnotation
+
 _BREADCRUMB_WIDTH = 256
 
 
-class _NullSpan:
-    """Shared no-op context manager for disabled tracing."""
+class Span(TraceAnnotation):
+    """The phase bracket (module docstring): profiler annotation always,
+    histogram observation when `hist` is given, `SpanTracer` record
+    when the server holds a tracer."""
+
+    __slots__ = ("_name", "_hist", "_tracer", "_t0")
+
+    def __init__(self, name: str, hist=None,
+                 tracer: Optional["SpanTracer"] = None):
+        super().__init__("adapm." + name)
+        self._name = name
+        self._hist = hist
+        self._tracer = tracer
+        self._t0 = 0.0
 
     def __enter__(self):
+        super().__enter__()
+        if self._tracer is not None:
+            self._t0 = self._tracer.begin(self._name)
+        elif self._hist is not None:
+            self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
-        return False
-
-
-NULL_SPAN = _NullSpan()
-
-
-class _Span:
-    __slots__ = ("tracer", "name", "t0")
-
-    def __init__(self, tracer: "SpanTracer", name: str):
-        self.tracer = tracer
-        self.name = name
-        self.t0 = 0.0
-
-    def __enter__(self):
-        # apm-lint: disable=APM003 a _Span is only ever constructed BY
-        # a live SpanTracer (disabled tracing hands out NULL_SPAN), so
-        # this tracer attribute is never the optional server handle
-        self.t0 = self.tracer.begin(self.name)
-        return self
-
-    def __exit__(self, *exc):
-        # apm-lint: disable=APM003 same invariant as __enter__ above
-        self.tracer.end(self.name, self.t0)
+        if self._hist is not None:
+            self._hist.observe(time.perf_counter() - self._t0)
+        if self._tracer is not None:
+            self._tracer.end(self._name, self._t0)
+        super().__exit__(*exc)
         return False
 
 
@@ -93,9 +111,6 @@ class SpanTracer:
                                   os.O_CREAT | os.O_WRONLY, 0o644)
 
     # -- recording -----------------------------------------------------------
-
-    def span(self, name: str) -> _Span:
-        return _Span(self, name)
 
     def begin(self, name: str) -> float:
         if self._bc_fd is not None:

@@ -166,6 +166,35 @@ def _scatter_update(main, delta, route, upd):
     return main, delta
 
 
+# The parts of a fused step carry stable `jax.named_scope` names
+# (adapm_route, adapm_sampler, adapm_gather, adapm_loss_grad,
+# adapm_adagrad, adapm_scatter_add): compile-time metadata on the
+# step's operations, so a device trace can be reduced by part whatever
+# the compiler numbers its fusions (PERF.md section 3).
+
+
+def _loss_and_grads(loss_fn, embs, trainable, aux):
+    """Mean loss and its gradients w.r.t. the trainable roles' rows."""
+    def objective(train_embs):
+        merged = dict(embs)
+        merged.update(train_embs)
+        return loss_fn(merged, aux)
+
+    with jax.named_scope("adapm_loss_grad"):
+        return jax.value_and_grad(objective)(
+            {r: embs[r] for r in trainable})
+
+
+def _adagrad_update(g, acc, lr, eps):
+    """The additive row update [d emb | d acc]: AdaGrad with the
+    accumulator carried in the value row (reference
+    UpdateNsqlL2Adagrad, apps/mf/update.h:23-79)."""
+    with jax.named_scope("adapm_adagrad"):
+        g2 = g * g
+        upd_emb = -lr * g * jax.lax.rsqrt(acc + g2 + eps)
+        return jnp.concatenate([upd_emb, g2], axis=-1)
+
+
 def make_fused_adagrad_step(
         loss_fn: Callable[..., jnp.ndarray],
         role_class: Dict[str, int],
@@ -190,29 +219,19 @@ def make_fused_adagrad_step(
         rows = {}
         for r in roles:
             main, cache, delta = pools[role_class[r]]
-            rows[r] = _read_rows(main, cache, delta, routes[r])
+            with jax.named_scope("adapm_gather"):
+                rows[r] = _read_rows(main, cache, delta, routes[r])
         embs = {r: rows[r][..., : role_dim[r]] for r in roles}
         accs = {r: rows[r][..., role_dim[r]:] for r in roles}
-
-        def objective(train_embs):
-            merged = dict(embs)
-            merged.update(train_embs)
-            return loss_fn(merged, aux)
-
-        loss, grads = jax.value_and_grad(objective)(
-            {r: embs[r] for r in trainable})
+        loss, grads = _loss_and_grads(loss_fn, embs, trainable, aux)
 
         new_pools = list(pools)
         for r in trainable:
-            g = grads[r]
-            g2 = g * g
-            # AdaGrad with the accumulator carried in the value row
-            # (reference UpdateNsqlL2Adagrad, apps/mf/update.h:23-79)
-            upd_emb = -lr * g * jax.lax.rsqrt(accs[r] + g2 + eps)
-            upd = jnp.concatenate([upd_emb, g2], axis=-1)
+            upd = _adagrad_update(grads[r], accs[r], lr, eps)
             cid = role_class[r]
             main, cache, delta = new_pools[cid]
-            main, delta = _scatter_update(main, delta, routes[r], upd)
+            with jax.named_scope("adapm_scatter_add"):
+                main, delta = _scatter_update(main, delta, routes[r], upd)
             new_pools[cid] = (main, cache, delta)
         return tuple(new_pools), loss
 
@@ -379,25 +398,26 @@ def _build_device_routed_body(loss_fn, role_class, role_dim, shard,
     def step(pools, locstat, tables, keys, local_index, alias, rng_key,
              aux, lr, eps):
         keys = dict(keys)
-        if neg_role is not None and neg_alias:
-            prob, alias_t, key_table = alias
-            k1, k2 = jax.random.split(rng_key)
-            u = jax.random.randint(k1, neg_shape, 0, prob.shape[0])
-            v = jax.random.uniform(k2, neg_shape)
-            cand = key_table[jnp.where(v < prob[u], u, alias_t[u])]
-            if local_index is not None:
-                # Local-scheme snap: padded index is sorted with an
-                # int-max sentinel tail, so searchsorted lands in
-                # [0, count] and wraps (sampling.h:494)
-                idx, count = local_index
-                pos = jnp.searchsorted(idx, cand)
-                pos = jnp.where(pos >= count, 0, pos)
-                cand = idx[pos]
-            keys[neg_role] = cand
-        elif neg_role is not None and local_index is not None:
-            idx, count = local_index  # padded index + valid count
-            pos = jax.random.randint(rng_key, neg_shape, 0, count)
-            keys[neg_role] = idx[pos]
+        with jax.named_scope("adapm_sampler"):
+            if neg_role is not None and neg_alias:
+                prob, alias_t, key_table = alias
+                k1, k2 = jax.random.split(rng_key)
+                u = jax.random.randint(k1, neg_shape, 0, prob.shape[0])
+                v = jax.random.uniform(k2, neg_shape)
+                cand = key_table[jnp.where(v < prob[u], u, alias_t[u])]
+                if local_index is not None:
+                    # Local-scheme snap: padded index is sorted with an
+                    # int-max sentinel tail, so searchsorted lands in
+                    # [0, count] and wraps (sampling.h:494)
+                    idx, count = local_index
+                    pos = jnp.searchsorted(idx, cand)
+                    pos = jnp.where(pos >= count, 0, pos)
+                    cand = idx[pos]
+                keys[neg_role] = cand
+            elif neg_role is not None and local_index is not None:
+                idx, count = local_index  # padded index + valid count
+                pos = jax.random.randint(rng_key, neg_shape, 0, count)
+                keys[neg_role] = idx[pos]
         rows = {}
         routes = {}
         # device-side locality counters (reference coloc_kv_server.h:147-157
@@ -412,13 +432,18 @@ def _build_device_routed_body(loss_fn, role_class, role_dim, shard,
             n_total += keys[r].size
             if no_replicas:
                 owner, slot, _ = tables
-                o_sh, o_sl = owner[keys[r]], slot[keys[r]]
+                with jax.named_scope("adapm_route"):
+                    o_sh, o_sl = owner[keys[r]], slot[keys[r]]
                 routes[r] = (o_sh, o_sl)
-                rows[r] = main.at[o_sh, o_sl].get(mode="fill", fill_value=0)
+                with jax.named_scope("adapm_gather"):
+                    rows[r] = main.at[o_sh, o_sl].get(mode="fill",
+                                                      fill_value=0)
                 n_local += jnp.sum(o_sh == shard, dtype=jnp.int32)
                 continue
-            routes[r] = _route_on_device(tables, keys[r], shard)
-            rows[r] = _read_rows(main, cache, delta, routes[r])
+            with jax.named_scope("adapm_route"):
+                routes[r] = _route_on_device(tables, keys[r], shard)
+            with jax.named_scope("adapm_gather"):
+                rows[r] = _read_rows(main, cache, delta, routes[r])
             o_sh, use_c = routes[r][0], routes[r][4]
             n_local += jnp.sum(use_c | (o_sh == shard), dtype=jnp.int32)
         # one step = one (batched) pull op + one push op of the same keys;
@@ -428,28 +453,20 @@ def _build_device_routed_body(loss_fn, role_class, role_dim, shard,
             [jnp.int32(n_total), n_local, jnp.int32(1), all_local])
         embs = {r: rows[r][..., : role_dim[r]] for r in roles}
         accs = {r: rows[r][..., role_dim[r]:] for r in roles}
-
-        def objective(train_embs):
-            merged = dict(embs)
-            merged.update(train_embs)
-            return loss_fn(merged, aux)
-
-        loss, grads = jax.value_and_grad(objective)(
-            {r: embs[r] for r in trainable})
+        loss, grads = _loss_and_grads(loss_fn, embs, trainable, aux)
 
         new_pools = list(pools)
         for r in trainable:
-            g = grads[r]
-            g2 = g * g
-            upd_emb = -lr * g * jax.lax.rsqrt(accs[r] + g2 + eps)
-            upd = jnp.concatenate([upd_emb, g2], axis=-1)
+            upd = _adagrad_update(grads[r], accs[r], lr, eps)
             cid = role_class[r]
             main, cache, delta = new_pools[cid]
-            if no_replicas:
-                o_sh, o_sl = routes[r]
-                main = main.at[o_sh, o_sl].add(upd, mode="drop")
-            else:
-                main, delta = _scatter_update(main, delta, routes[r], upd)
+            with jax.named_scope("adapm_scatter_add"):
+                if no_replicas:
+                    o_sh, o_sl = routes[r]
+                    main = main.at[o_sh, o_sl].add(upd, mode="drop")
+                else:
+                    main, delta = _scatter_update(main, delta, routes[r],
+                                                  upd)
             new_pools[cid] = (main, cache, delta)
         return tuple(new_pools), locstat, loss
 
@@ -554,6 +571,12 @@ class DeviceRoutedRunner:
                                             shared=True)
         self._g_drain_every = server.obs.gauge(
             "fused.locstat_drain_every", unit="steps", shared=True)
+        # host time of one dispatch (the whole __call__/run_scan) and of
+        # the key upload inside it (or ahead of it, prefetch_keys)
+        self._h_dispatch = server.obs.histogram("fused.dispatch_s",
+                                                shared=True)
+        self._h_key_upload = server.obs.histogram("fused.key_upload_s",
+                                                  shared=True)
         self._mk_kwargs = dict(
             loss_fn=loss_fn, role_class=role_class, role_dim=role_dim,
             shard=shard, frozen_roles=frozen_roles, neg_role=neg_role,
@@ -626,18 +649,24 @@ class DeviceRoutedRunner:
         rule, docs/PERF.md): the upload runs now — on the app's
         intent/prepare path — instead of inside the next dispatch.
         Returns the handle for __call__'s `staged` parameter."""
-        srv = self.server
         self._check_batch(role_keys)
-        kdtype = _key_dtype(srv.num_keys)
-        put = srv.ctx.put_replicated
-        host = {r: np.asarray(k, dtype=kdtype)
+        host = {r: np.asarray(k, dtype=_key_dtype(self.server.num_keys))
                 for r, k in role_keys.items()}
-        return StagedKeys(host, {r: put(v) for r, v in host.items()})
+        return StagedKeys(host, self._upload_keys(host))
+
+    def _upload_keys(self, host_keys: Dict[str, np.ndarray]):
+        """Host -> device upload of one dispatch's key arrays (already
+        in the key dtype), replicated: the staging rule, mesh.py."""
+        srv = self.server
+        with srv._span("fused.key_upload", self._h_key_upload):
+            put = srv.ctx.put_replicated
+            return {r: put(k) for r, k in host_keys.items()}
 
     def _next_rng(self):
         if not self._rng_pool:
-            self._rng, *pool = jax.random.split(self._rng, 65)
-            self._rng_pool = pool
+            with self.server._span("fused.rng_refill"):
+                self._rng, *pool = jax.random.split(self._rng, 65)
+                self._rng_pool = pool
         return self._rng_pool.pop()
 
     def _scalar(self, v: float):
@@ -665,11 +694,12 @@ class DeviceRoutedRunner:
         time and every _drain_every steps —
         chosen so the int32 params counter stays below 2^30 between
         drains."""
-        vals = np.asarray(self._locstat, dtype=np.int64)
-        self._loc_host += vals
-        self._locstat = self.server.ctx.put_replicated(
-            np.zeros(4, np.int32))
-        self._c_drains.inc()
+        with self.server._span("fused.locstat_drain"):
+            vals = np.asarray(self._locstat, dtype=np.int64)
+            self._loc_host += vals
+            self._locstat = self.server.ctx.put_replicated(
+                np.zeros(4, np.int32))
+            self._c_drains.inc()
 
     def locality_counts(self) -> Dict[str, int]:
         """Cumulative step-program access counts, host-side (the device-
@@ -792,6 +822,10 @@ class DeviceRoutedRunner:
     def __call__(self, role_keys: Dict[str, np.ndarray], aux, lr: float,
                  eps: float = 1e-10,
                  staged: Optional[StagedKeys] = None) -> jnp.ndarray:
+        with self.server._span("fused.dispatch", self._h_dispatch):
+            return self._dispatch_step(role_keys, aux, lr, eps, staged)
+
+    def _dispatch_step(self, role_keys, aux, lr, eps, staged):
         srv = self.server
         self._check_batch(role_keys)
         if staged is not None and not staged.matches(role_keys):
@@ -813,10 +847,9 @@ class DeviceRoutedRunner:
             sub = self._next_rng()
             # keys validated above to be inside [0, num_keys)
             kdtype = _key_dtype(srv.num_keys)
-            put = srv.ctx.put_replicated  # the staging rule, mesh.py
             keys = staged.dev if staged is not None else \
-                {r: put(np.asarray(k, dtype=kdtype))
-                 for r, k in role_keys.items()}
+                self._upload_keys({r: np.asarray(k, dtype=kdtype)
+                                   for r, k in role_keys.items()})
             pools = tuple((s.main, s.cache, s.delta) for s in srv.stores)
             fn = self.step_fn if self._shard_has_replicas() \
                 else self._step_fn_norep
@@ -854,6 +887,10 @@ class DeviceRoutedRunner:
         planner's changes apply between scans, matching the apps'
         lookahead contract. `auxes` is a list of per-step aux pytrees, or
         None when the loss takes no aux."""
+        with self.server._span("fused.dispatch", self._h_dispatch):
+            return self._dispatch_scan(batches, auxes, lr, eps)
+
+    def _dispatch_scan(self, batches, auxes, lr, eps):
         srv = self.server
         K = len(batches)
         assert K >= 1, "empty scan window"
@@ -886,9 +923,9 @@ class DeviceRoutedRunner:
             rngs = jnp.stack([self._next_rng() for _ in range(K)])
             kdtype = _key_dtype(srv.num_keys)
             put = srv.ctx.put_replicated  # the staging rule, mesh.py
-            keys = {r: put(np.stack([np.asarray(b[r], dtype=kdtype)
-                                     for b in batches]))
-                    for r in batches[0]}
+            keys = self._upload_keys(
+                {r: np.stack([np.asarray(b[r], dtype=kdtype)
+                              for b in batches]) for r in batches[0]})
             aux = None
             if has_aux:
                 import jax.tree_util as jtu

@@ -133,8 +133,7 @@ class CollectiveSync:
         """All-to-all a pytree of [P, B, ...] buffers (leaf[d] = payload
         for process d). Returns same-shaped leaves with leaf[s] = payload
         process s sent here. EVERY process must call this together."""
-        from ..obs.metrics import timed
-        with timed(self._h_xchg):
+        with self.pm.server._span("collective.exchange", self._h_xchg):
             return self._exchange_impl(local_tree)
 
     def _exchange_impl(self, local_tree):

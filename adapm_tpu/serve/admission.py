@@ -159,19 +159,26 @@ class TenantState:
 
 class LookupRequest:
     """One client lookup: the key batch, optional read-your-writes
-    ordering futures, a deadline, tenancy, and the delivery
-    rendezvous."""
+    ordering futures, a deadline, tenancy, the delivery rendezvous, and
+    the phase stamps (perf_counter values; 0.0 = never reached) that
+    the always-on `serve.*_s` breakdown histograms and the flight
+    export both read: `t_call` lookup() entered, `t0` in its lane,
+    `t_claim` claimed by a dispatcher, then per micro-batch
+    `t_dispatch` window closed, `t_enqueued` gathers enqueued,
+    `t_copied` union rows in host memory, and `t_deliver` this
+    request's result handed over."""
 
-    __slots__ = ("keys", "after", "deadline", "t0", "result", "error",
-                 "trace", "tenant", "priority", "lane", "_state",
-                 "_lock", "_done")
+    __slots__ = ("keys", "after", "deadline", "t_call", "t0", "t_claim",
+                 "t_dispatch", "t_enqueued", "t_copied", "t_deliver",
+                 "result", "error", "trace", "tenant", "priority",
+                 "lane", "_state", "_lock", "_done")
 
     def __init__(self, keys: np.ndarray, after: Sequence = (),
                  deadline_s: Optional[float] = None, trace=None,
                  tenant: Optional[TenantState] = None,
-                 priority: int = 0, lane: int = 0):
+                 priority: int = 0, lane: int = 0, t_call: float = 0.0):
         self.keys = keys
-        # request-flight trace context (obs/flight.py FlightTrace),
+        # request-flight trace id (obs/flight.py FlightTracer.mint),
         # minted by the session when --sys.trace.flight is on; None —
         # the common case — costs nothing anywhere below
         self.trace = trace
@@ -184,7 +191,12 @@ class LookupRequest:
         self.after: Tuple = tuple(after)
         self.deadline = None if deadline_s is None \
             else time.monotonic() + deadline_s
-        self.t0 = time.perf_counter()   # serve.latency_s start
+        # serve.latency_s start; AdmissionQueue.submit restamps it at
+        # the instant the request enters its lane
+        self.t0 = time.perf_counter()
+        self.t_call = t_call or self.t0
+        self.t_claim = self.t_dispatch = self.t_enqueued = 0.0
+        self.t_copied = self.t_deliver = 0.0
         self.tenant = tenant
         self.priority = int(priority)
         self.lane = int(lane)
@@ -206,10 +218,9 @@ class LookupRequest:
             if self._state != _PENDING:
                 return False
             self._state = _CLAIMED
-            if self.trace is not None:
-                # end of queue residence: the flight's queue_s segment
-                # closes here, batch_wait_s starts
-                self.trace.t_claim = time.perf_counter()
+            # end of queue residence: serve.queue_s closes here,
+            # serve.batch_wait_s starts
+            self.t_claim = time.perf_counter()
             return True
 
     def try_shed(self) -> bool:
@@ -228,8 +239,15 @@ class LookupRequest:
 
     # -- delivery ------------------------------------------------------------
 
+    def stamp_batch(self, t_dispatch: float, t_enqueued: float,
+                    t_copied: float) -> None:
+        """The micro-batch's three stamps, shared by its members."""
+        self.t_dispatch, self.t_enqueued = t_dispatch, t_enqueued
+        self.t_copied = t_copied
+
     def deliver(self, flat: np.ndarray) -> None:
         self.result = flat
+        self.t_deliver = time.perf_counter()
         self._done.set()
 
     def fail(self, exc: BaseException) -> None:
@@ -258,8 +276,9 @@ class AdmissionQueue:
     Metrics (registered in the server's registry, `shared=True` so a
     plane torn down and rebuilt on the same server reuses them):
     `serve.queue_depth` gauge, per-lane `serve.lane_depth.<i>` gauges,
-    `serve.rejected_total` / `serve.shed_total` counters, and the
-    per-tenant `serve.tenant.<name>.*` counters."""
+    `serve.claim_depth` histogram (requests still pending in the lane
+    at each claim), `serve.rejected_total` / `serve.shed_total`
+    counters, and the per-tenant `serve.tenant.<name>.*` counters."""
 
     def __init__(self, bound: int, registry=None, lanes: int = 1,
                  lockorder: bool = False):
@@ -289,8 +308,11 @@ class AdmissionQueue:
         # that queues a drain program on the lane's executor stream —
         # event-driven dispatch instead of a thread parked in take()
         self._kick = None
-        from ..obs.metrics import Counter
+        from ..obs.metrics import BATCH_SIZE_BOUNDS, Counter, Histogram
         if registry is not None and registry.enabled:
+            self.h_claim_depth = registry.histogram(
+                "serve.claim_depth", unit="requests",
+                bounds=BATCH_SIZE_BOUNDS, shared=True)
             self.c_rejected = registry.counter("serve.rejected_total",
                                                shared=True)
             self.c_shed = registry.counter("serve.shed_total", shared=True)
@@ -308,6 +330,9 @@ class AdmissionQueue:
             self.c_rejected = Counter("serve.rejected_total")
             self.c_shed = Counter("serve.shed_total")
             self.c_degraded = Counter("serve.degraded_shed_total")
+            self.h_claim_depth = Histogram("serve.claim_depth",
+                                           unit="requests",
+                                           bounds=BATCH_SIZE_BOUNDS)
 
     # -- tenancy -------------------------------------------------------------
 
@@ -433,6 +458,7 @@ class AdmissionQueue:
                     f"{req.priority} submission (this request's "
                     f"priority: {victim.priority})"))
                 self._compact_locked()
+            req.t0 = time.perf_counter()  # in its lane
             self._lanes[lane].append(req)
             self._cond.notify_all()
             kick = self._kick
@@ -507,7 +533,13 @@ class AdmissionQueue:
         half of the QoS contract (the next drain iteration serves the
         lower class). Caller holds _cond."""
         if not self._has_qos:
-            return self._pop_live_locked(dq)
+            r = self._pop_live_locked(dq)
+            if r is not None:
+                # FIFO claims pop their request: what is left is the
+                # lane's backlog (client-shed corpses included, until
+                # the next pop skips them)
+                self.h_claim_depth.observe(float(len(dq)))
+            return r
         now = time.monotonic()
         best = None
         for r in dq:
@@ -541,6 +573,8 @@ class AdmissionQueue:
         if best is not None and best.try_claim():
             tname = best.tenant.name if best.tenant is not None else ""
             taken[tname] = taken.get(tname, 0) + 1
+            self.h_claim_depth.observe(float(sum(
+                1 for r in dq if r._state == _PENDING)))
             # leave the claimed corpse in place; the periodic
             # compaction (and FIFO popleft skip) removes it
             return best

@@ -135,6 +135,25 @@ class LookupBatcher:
                                        shared=True)
         self.h_batch = reg.histogram("serve.batch_size", unit="requests",
                                      bounds=BATCH_SIZE_BOUNDS, shared=True)
+
+        def _hist(name):
+            return reg.histogram(name, bounds=SERVE_LATENCY_BOUNDS_S,
+                                 shared=True)
+
+        # where a served lookup's time went, always on: the seven
+        # consecutive phases between the stamps every LookupRequest
+        # carries, one observation each per DELIVERED request, so the
+        # phases' sums add up to serve.lookup_s exactly (PERF.md
+        # section 3). admit/wake/lookup are observed by the client
+        # (ServeSession), the five between by the dispatcher.
+        self.h_admit = _hist("serve.admit_s")
+        self.h_queue = _hist("serve.queue_s")
+        self.h_batch_wait = _hist("serve.batch_wait_s")
+        self.h_dispatch = _hist("serve.dispatch_s")
+        self.h_copy_out = _hist("serve.copy_out_s")
+        self.h_deliver = _hist("serve.deliver_s")
+        self.h_wake = _hist("serve.wake_s")
+        self.h_lookup = _hist("serve.lookup_s")
         # bag-read accounting (ISSUE 16; schema v12): requests and
         # pooled vectors delivered, plus which path produced the bits —
         # fused device gather+pool batches vs host-pooled batches
@@ -266,11 +285,12 @@ class LookupBatcher:
             # between batches and the next window must honor it
             max_wait_s = self.max_wait_us * 1e-6
             cw = self.class_wait_us
-            reqs = self.queue.take(
-                max_batch, max_wait_s, block=False, lane=lane,
-                wait_s_by_prio=(
-                    {p: w * 1e-6 for p, w in cw.items()}
-                    if cw is not None else None))
+            with srv._span("serve.take"):
+                reqs = self.queue.take(
+                    max_batch, max_wait_s, block=False, lane=lane,
+                    wait_s_by_prio=(
+                        {p: w * 1e-6 for p, w in cw.items()}
+                        if cw is not None else None))
             if not reqs:
                 return  # empty (or closed): park until the next kick
             self._busy_since[lane] = time.monotonic()
@@ -331,7 +351,7 @@ class LookupBatcher:
             return
         fl = srv.flight
         t_dispatch = time.perf_counter()  # batch window closes, the
-        # coalesced lookup starts (flight.batch -> flight.program edge)
+        # coalesced lookup starts (serve.batch_wait_s -> serve.dispatch_s)
         self.c_batches.inc()
         self.h_batch.observe(float(len(reqs)))
         # bag reads (ISSUE 16) coalesce separately: their reply is
@@ -381,15 +401,18 @@ class LookupBatcher:
         if served is not None:
             flat, t_cutoff = served
             self.c_replica_hits.inc()
-            # lock-free hit: no dispatch/device segment — the flight
-            # breakdown's enqueue stamp collapses onto the dispatch
-            # point; the freshness probe keeps the SNAPSHOT's
-            # under-lock stamp as its read-order cutoff (the served
-            # bits are exactly as fresh as the snapshot's gather)
-            t_enqueued = t_dispatch
+            # lock-free hit: the union's rows were in host memory when
+            # the window closed, so dispatch and copy_out are 0 (their
+            # stamps collapse onto the dispatch point) and the
+            # selection from the snapshot counts as delivery; the
+            # freshness probe keeps the SNAPSHOT's under-lock stamp as
+            # its read-order cutoff (the served bits are exactly as
+            # fresh as the snapshot's gather)
+            t_enqueued = t_copied = t_dispatch
         else:
             try:
-                flat, t_enqueued = self._lookup_union(union, after)
+                flat, t_enqueued, t_copied = \
+                    self._lookup_union(union, after)
                 t_cutoff = t_enqueued
             except (KeyboardInterrupt, SystemExit):
                 for r in reqs:
@@ -409,45 +432,65 @@ class LookupBatcher:
         lens_u = srv.value_lengths[union]
         offs_u = _offsets(lens_u)
         self.c_keys_unique.inc(len(union))
+        now = self._stamp_batch(reqs, fl, t_dispatch, t_enqueued, t_copied,
+                                len(allk), union, t_cutoff)
+        with srv._span("serve.deliver"):
+            for r in reqs:
+                pos = np.searchsorted(union, r.keys)
+                r.deliver(_select_flat(flat, offs_u, lens_u, pos))
+                self.c_lookups.inc()
+                self.c_keys.inc(len(r.keys))
+                self._note_delivered(r, now)
+
+    def _stamp_batch(self, reqs, fl, t_dispatch, t_enqueued, t_copied,
+                     n_keys, union, t_cutoff) -> float:
+        """Put the micro-batch's stamps on every member BEFORE any is
+        delivered: deliver wakes the client, which reads them (the
+        phase histograms; the flight flow's close). Returns the
+        instant the batch's values were in hand (serve.latency_s's
+        end)."""
         now = time.perf_counter()
+        for r in reqs:
+            r.stamp_batch(t_dispatch, t_enqueued, t_copied)
         if fl is not None:
-            # stamp the program timestamps on every member trace and
-            # record the batch-membership slices BEFORE delivering:
-            # deliver wakes the client, whose finish_lookup closes the
-            # flow and must see a fully-stamped trace
-            fl.record_serve_batch(
-                [r.trace for r in reqs if r.trace is not None],
-                t_dispatch, t_enqueued, now, n_requests=len(reqs),
-                n_keys=len(allk), n_unique=len(union))
+            fl.record_serve_batch(reqs, n_requests=len(reqs),
+                                  n_keys=n_keys, n_unique=len(union))
             # freshness probe: this union is a servable read of any
             # probed key whose push was enqueued before this gather —
             # or, on the replica path, before the SNAPSHOT's gather
             # (obs/flight.py; t_cutoff orders the two either way)
             fl.freshness.note_read(union, t_cutoff)
-        for r in reqs:
-            pos = np.searchsorted(union, r.keys)
-            if r.trace is not None:
-                r.trace.t_deliver = time.perf_counter()
-            r.deliver(_select_flat(flat, offs_u, lens_u, pos))
-            self.c_lookups.inc()
-            self.c_keys.inc(len(r.keys))
-            if r.tenant is not None:
-                r.tenant.c_served.inc()
-            self.h_latency.observe(now - r.t0)
-            cs = self._class_samples
-            if cs is not None:
-                cs.append((now, now - r.t0, r.priority))
+        return now
+
+    def _note_delivered(self, r: LookupRequest, now: float) -> None:
+        """Per delivered request, after its deliver(): the dispatcher's
+        five phase observations (consecutive stamps of the request;
+        shed/failed requests observe none) and the latency samples."""
+        self.h_queue.observe(r.t_claim - r.t0)
+        self.h_batch_wait.observe(r.t_dispatch - r.t_claim)
+        self.h_dispatch.observe(r.t_enqueued - r.t_dispatch)
+        self.h_copy_out.observe(r.t_copied - r.t_enqueued)
+        self.h_deliver.observe(r.t_deliver - r.t_copied)
+        if r.tenant is not None:
+            r.tenant.c_served.inc()
+        self.h_latency.observe(now - r.t0)
+        cs = self._class_samples
+        if cs is not None:
+            cs.append((now, now - r.t0, r.priority))
 
     def _lookup_union(self, keys: np.ndarray, after):
         """One coalesced pull of the (unique, sorted) union batch — the
         `Worker._pull_op` sequence minus per-worker staging: optimistic
         plan via the shared routing-plan cache, topology_version
-        revalidation under the lock, `Server._pull` dispatch. Returns
-        `(flat, t_enqueued)`: the perf_counter stamp taken right after
-        the device gather programs are ENQUEUED (the flight breakdown's
-        dispatch/device split; assembly below it blocks on the device)."""
+        revalidation under the lock, `Server._pull` dispatch; then the
+        copy to the host, which waits for the rows on the device in the
+        same blocking call (a wait of its own first, to split the two,
+        cost 0.3 ms a lookup: PERF.md section 6, PR 24). Returns
+        `(flat, t_enqueued, t_copied)`: perf_counter stamps taken right
+        after the device gather programs are ENQUEUED and when the
+        union is assembled in host memory."""
         srv = self.server
-        with srv._span("serve.lookup"):
+        with srv._span("serve.dispatch"):
             plan, tv = None, -1
             if srv.opts.optimistic_routing:
                 tv = srv.topology_version
@@ -462,8 +505,10 @@ class LookupBatcher:
                 # stamped under the lock so it totally orders against
                 # FreshnessProbe.push_visible stamps (same lock)
                 t_enqueued = time.perf_counter()
-            return (srv._assemble_flat(keys, groups, remote=remote),
-                    t_enqueued)
+        with srv._span("serve.copy_out"):
+            flat = srv._assemble_flat(keys, groups, remote=remote)
+            t_copied = time.perf_counter()
+        return flat, t_enqueued, t_copied
 
     # -- bag reads (ISSUE 16) ------------------------------------------------
 
@@ -502,7 +547,7 @@ class LookupBatcher:
             self.c_bag_replica_hits.inc()
             self.c_bag_hostpool.inc()
             pooled = self._pool_from_flat(flat, union, groups)
-            t_enqueued = t_dispatch
+            t_enqueued = t_copied = t_dispatch
         else:
             fused = (bool(getattr(self.opts, "serve_bags", True))
                      and srv.glob is None and not after)
@@ -531,37 +576,28 @@ class LookupBatcher:
                         sum(1 for v in verdicts if v is None))
             if fused:
                 dev, t_enqueued = self._lookup_bags_fused(groups)
-                pooled = {k: np.asarray(v)[:groups[k]["nbags"]]
-                          for k, v in dev.items()}
-                t_cutoff = t_enqueued
+                with srv._span("serve.copy_out"):
+                    pooled = {k: np.asarray(v)[:groups[k]["nbags"]]
+                              for k, v in dev.items()}
+                    t_copied = time.perf_counter()
                 self.c_bag_fused.inc()
             else:
-                flat, t_enqueued = self._lookup_union(union, after)
-                t_cutoff = t_enqueued
+                flat, t_enqueued, t_copied = \
+                    self._lookup_union(union, after)
                 self.c_bag_hostpool.inc()
                 pooled = self._pool_from_flat(flat, union, groups)
-        now = time.perf_counter()
-        if fl is not None:
-            fl.record_serve_batch(
-                [r.trace for r in reqs if r.trace is not None],
-                t_dispatch, t_enqueued, now, n_requests=len(reqs),
-                n_keys=len(allk), n_unique=len(union))
-            fl.freshness.note_read(union, t_cutoff)
-        for r, rs in zip(reqs, slices):
-            parts = [np.ascontiguousarray(
-                pooled[g][s:s + nb]).ravel() for g, s, nb in rs]
-            if r.trace is not None:
-                r.trace.t_deliver = time.perf_counter()
-            r.deliver(np.concatenate(parts)
-                      if len(parts) > 1 else parts[0])
-            self.c_bag_lookups.inc()
-            self.c_bag_pooled.inc(sum(nb for _, _, nb in rs))
-            if r.tenant is not None:
-                r.tenant.c_served.inc()
-            self.h_latency.observe(now - r.t0)
-            cs = self._class_samples
-            if cs is not None:
-                cs.append((now, now - r.t0, r.priority))
+            t_cutoff = t_enqueued
+        now = self._stamp_batch(reqs, fl, t_dispatch, t_enqueued, t_copied,
+                                len(allk), union, t_cutoff)
+        with srv._span("serve.deliver"):
+            for r, rs in zip(reqs, slices):
+                parts = [np.ascontiguousarray(
+                    pooled[g][s:s + nb]).ravel() for g, s, nb in rs]
+                r.deliver(np.concatenate(parts)
+                          if len(parts) > 1 else parts[0])
+                self.c_bag_lookups.inc()
+                self.c_bag_pooled.inc(sum(nb for _, _, nb in rs))
+                self._note_delivered(r, now)
 
     def _lookup_bags_fused(self, groups):
         """Dispatch one fused gather_pool per (length class, pooling)
@@ -575,7 +611,7 @@ class LookupBatcher:
         the lock."""
         srv = self.server
         from ..core.store import OOB
-        with srv._span("serve.bag_lookup"):
+        with srv._span("serve.dispatch"):
             with srv._lock:
                 dev = {}
                 with dispatch_gate():

@@ -41,6 +41,7 @@ Deadline semantics (docs/SERVING.md "Deadlines"):
 """
 from __future__ import annotations
 
+import time
 from typing import Optional
 
 import numpy as np
@@ -88,6 +89,7 @@ class ServeSession:
         server is restoring/degraded — retry once readiness recovers),
         or `RuntimeError` (plane closed / dispatcher wedged). Never
         hangs."""
+        t_call = time.perf_counter()
         keys = np.ascontiguousarray(
             np.asarray(keys, dtype=np.int64).ravel())
         srv = self.server
@@ -127,13 +129,12 @@ class ServeSession:
         # micro-batch claims and dispatches it, and closes below at
         # reply time; off costs exactly this one `is None` check
         fl = srv.flight
-        tr = fl.mint() if fl is not None else None
         req = LookupRequest(keys, after=after, deadline_s=deadline_s,
-                            trace=tr, tenant=self.tenant,
-                            priority=self.priority,
-                            lane=self.plane.batcher.assign_lane(keys))
-        flat = self._submit_and_wait(req, deadline_s, deadline_ms,
-                                     fl, tr)
+                            trace=fl.mint() if fl is not None else None,
+                            tenant=self.tenant, priority=self.priority,
+                            lane=self.plane.batcher.assign_lane(keys),
+                            t_call=t_call)
+        flat = self._submit_and_wait(req, deadline_s, deadline_ms)
         if out is not None:
             # reshape(-1) on a non-contiguous view would COPY and the
             # caller's buffer would silently stay unfilled; a too-small
@@ -151,42 +152,55 @@ class ServeSession:
             return flat.reshape(len(keys), int(lens[0]))
         return flat
 
-    def _submit_and_wait(self, req, deadline_s, deadline_ms, fl, tr):
+    def _submit_and_wait(self, req, deadline_s, deadline_ms):
         """The submit/wait/shed/grace dance shared by `lookup` and
         `lookup_bags`: submit into the admission queue, wait out the
         deadline, shed if still unclaimed, bounded grace if claimed.
-        Returns the delivered flat result; closes the flight trace on
+        Returns the delivered flat result and observes the client's
+        three of the `serve.*_s` phase histograms (admit, wake, lookup;
+        delivered requests only); closes the flight trace on
         any failure so no trace dangles."""
+        srv = self.server
+        fl = srv.flight
         try:
-            self.plane.queue.submit(req)  # may raise ServeOverloadError
-            if not req.wait(deadline_s):
-                # deadline passed while we waited: shed if still
-                # unclaimed
-                if req.try_shed():
-                    self.plane.queue.c_shed.inc()
-                    if self.tenant is not None:
-                        self.tenant.c_shed.inc()
-                    raise DeadlineExceededError(
-                        f"lookup deadline ({deadline_ms} ms) expired "
-                        f"before a micro-batch claimed the request "
-                        f"(queue depth {self.plane.queue.depth()})")
-                # claimed: an in-flight batch will deliver — bounded
-                # grace
-                if not req.wait(_CLAIMED_GRACE_S):
-                    raise RuntimeError(
-                        "serve dispatcher failed to deliver a claimed "
-                        f"request within {_CLAIMED_GRACE_S}s — wedged "
-                        "dispatcher (fail-stop, "
-                        "docs/failure_handling.md)")
+            with srv._span("serve.admit"):
+                # may raise ServeOverloadError
+                self.plane.queue.submit(req)
+            with srv._span("serve.wait"):
+                delivered = req.wait(deadline_s)
+                if not delivered:
+                    # deadline passed while we waited: shed if still
+                    # unclaimed
+                    if req.try_shed():
+                        self.plane.queue.c_shed.inc()
+                        if self.tenant is not None:
+                            self.tenant.c_shed.inc()
+                        raise DeadlineExceededError(
+                            f"lookup deadline ({deadline_ms} ms) expired "
+                            f"before a micro-batch claimed the request "
+                            f"(queue depth {self.plane.queue.depth()})")
+                    # claimed: an in-flight batch will deliver — bounded
+                    # grace
+                    if not req.wait(_CLAIMED_GRACE_S):
+                        raise RuntimeError(
+                            "serve dispatcher failed to deliver a "
+                            f"claimed request within {_CLAIMED_GRACE_S}s"
+                            " — wedged dispatcher (fail-stop, "
+                            "docs/failure_handling.md)")
             flat = req.take_result()  # raises the shed/close error
         except BaseException:
             if fl is not None:
                 # shed/overload/close: a terminal lookup slice records
                 # the abandoned flight so no trace dangles silently
-                fl.finish_lookup(tr, ok=False)
+                fl.finish_lookup(req, ok=False)
             raise
+        t_return = time.perf_counter()
+        b = self.plane.batcher
+        b.h_admit.observe(req.t0 - req.t_call)
+        b.h_wake.observe(t_return - req.t_deliver)
+        b.h_lookup.observe(t_return - req.t_call)
         if fl is not None:
-            fl.finish_lookup(tr, ok=True)
+            fl.finish_lookup(req, ok=True)
         return flat
 
     def lookup_bags(self, tables, bags, pooling: str = "sum",
@@ -213,6 +227,7 @@ class ServeSession:
                 "lookup_bags needs parallel, non-empty tables/bags "
                 f"lists (got {len(tables)} tables, {len(bags)} bag "
                 "offset arrays)")
+        t_call = time.perf_counter()
         srv = self.server
         from ..base import check_key_range
         tks, tbg, lens_t = [], [], []
@@ -263,14 +278,13 @@ class ServeSession:
         if self.worker is not None and srv.glob is not None:
             after = tuple(self.worker._live_write_futs())
         fl = srv.flight
-        tr = fl.mint() if fl is not None else None
         from .bags import BagLookupRequest
         req = BagLookupRequest(
             tks, tbg, pooling, allk, after=after, deadline_s=deadline_s,
-            trace=tr, tenant=self.tenant, priority=self.priority,
-            lane=self.plane.batcher.assign_lane(allk))
-        flat = self._submit_and_wait(req, deadline_s, deadline_ms,
-                                     fl, tr)
+            trace=fl.mint() if fl is not None else None,
+            tenant=self.tenant, priority=self.priority,
+            lane=self.plane.batcher.assign_lane(allk), t_call=t_call)
+        flat = self._submit_and_wait(req, deadline_s, deadline_ms)
         out, off = [], 0
         for bg, L in zip(tbg, lens_t):
             nb = len(bg) - 1

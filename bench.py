@@ -623,13 +623,12 @@ def bench_serve(E=20_000, vlen=32, clients=32, lookups_per_client=40,
             pass
     shed = srv.obs.find("serve.shed_total").value - shed_before
 
-    # -- SLO autopilot + per-request breakdown exemplar (ISSUE 7) -----
+    # -- SLO autopilot (ISSUE 7) --------------------------------------
     # Rebuild the plane with flight tracing attached, an SLO target,
     # and a deliberately oversized micro-batch window (4x the target):
     # the artifact then carries the controller's convergence
-    # (wait_us_adjustments, achieved P99 vs target) and one sampled
-    # request's queue/batch/dispatch/device split — where the
-    # milliseconds actually went, not just totals.
+    # (wait_us_adjustments, achieved P99 vs target). Where a lookup's
+    # milliseconds went is in the snapshot's serve.*_s phases.
     _progress("serve phase: slo autopilot segment")
     plane.close()
     from adapm_tpu.obs.flight import FlightTracer
@@ -669,7 +668,6 @@ def bench_serve(E=20_000, vlen=32, clients=32, lookups_per_client=40,
                                              lat_a["buckets"])]}
     achieved_p99_ms = round(1e3 * hist_percentile(win, 0.99), 3)
     slo_rep = plane2.slo.report()
-    exemplar = srv.flight.exemplar()
     # snapshot while the plane is live: serve.readiness and the slo
     # section are filled from the open plane, close() empties them
     snap = srv.metrics_snapshot()
@@ -837,9 +835,6 @@ def bench_serve(E=20_000, vlen=32, clients=32, lookups_per_client=40,
                    "initial_wait_us": int(slo_target_ms * 4e3),
                    "final_wait_us": slo_rep["wait_us"],
                    "recent_adjustments": slo_rep["recent_adjustments"]},
-           # one sampled request's queue/batch/dispatch/device split
-           # (ms) — where a lookup's time went (obs/flight.py)
-           "flight_exemplar": exemplar,
            # the mixed-tenant open-loop segment (ISSUE 9): per-tenant
            # qps/P99/shed under concurrent training pushes, and the
            # fraction of batches the read-only replica served lock-free

@@ -89,7 +89,7 @@ def test_capture_off_pin(ctx):
     assert not [n for n in srv.obs.names()
                 if n.startswith("decision.")]
     snap = srv.metrics_snapshot()
-    assert snap["schema_version"] == 16
+    assert snap["schema_version"] == 17
     assert snap["decision"] == {}
     srv.shutdown()
 

@@ -219,7 +219,7 @@ def test_device_and_episode_snapshot_sections_v10(rng):
           for _ in range(4)]
     EpisodicRunner(_runner(srv), episode_batches=2).run(bs, lr=0.05)
     snap = srv.metrics_snapshot()
-    assert snap["schema_version"] == 16
+    assert snap["schema_version"] == 17
     dev = snap["device"]
     assert dev["backend"] == "jax"
     assert dev["programs_total"] > 0

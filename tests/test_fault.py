@@ -105,7 +105,7 @@ def test_fault_off_by_default_zero_cost_shape():
         assert not [n for n in srv.obs.names()
                     if n.startswith("fault.")]
         snap = srv.metrics_snapshot()
-        assert snap["schema_version"] == 16
+        assert snap["schema_version"] == 17
         assert snap["fault"] == {} and snap["ckpt"] == {}
     finally:
         srv.shutdown()

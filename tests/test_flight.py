@@ -238,10 +238,13 @@ def test_flight_storm_every_chain_complete(ctx, tmp_path):
             shed_ids |= ids
     orphans = phase_ids - set(chains) - shed_ids
     assert not orphans, f"orphaned trace ids: {sorted(orphans)[:8]}"
-    # the per-request breakdown ladder observed every served lookup
+    # the per-request breakdown ladder (the always-on serve.*_s phase
+    # histograms, read from the same stamps the flow export draws)
+    # observed every served lookup
     snap = s.metrics_snapshot()
-    for h in ("queue_s", "batch_wait_s", "dispatch_s", "device_s"):
-        assert snap["flight"][h]["count"] == n_served, h
+    for h in ("admit_s", "queue_s", "batch_wait_s", "dispatch_s",
+              "copy_out_s", "deliver_s", "wake_s", "lookup_s"):
+        assert snap["serve"][h]["count"] == n_served, h
     assert snap["flight"]["complete"] == n_served
     plane.close()
     s.shutdown()

@@ -436,7 +436,7 @@ def test_loopback_net_section_and_metrics_names():
         srv = cl.servers[0]
         assert srv.net is not None
         snap = srv.metrics_snapshot(drain_device=False)
-        assert snap["schema_version"] == 16
+        assert snap["schema_version"] == 17
         net = snap["net"]
         assert net["peers_total"] == 2 and net["backend"] == "loopback"
         for k in ("msgs_out", "bytes_out", "retransmits",

@@ -205,7 +205,7 @@ def test_metrics_snapshot_schema_stable():
     # (request-flight tracing + the SLO autopilot; flight carries only
     # the crash-ride flight-recorder summary until --sys.trace.flight,
     # slo is {} until --sys.serve.slo_ms)
-    assert snap["schema_version"] == 16 and snap["metrics_enabled"]
+    assert snap["schema_version"] == 17 and snap["metrics_enabled"]
     assert snap["serve"] == {}  # no ServePlane on this server
     assert snap["tier"] == {}   # --sys.tier off on this server
     assert snap["slo"] == {}    # no --sys.serve.slo_ms target set

@@ -96,7 +96,7 @@ def test_policy_off_pin(ctx):
     assert srv.policy is None
     assert not [n for n in srv.obs.names() if n.startswith("policy.")]
     snap = srv.metrics_snapshot()
-    assert snap["schema_version"] == 16
+    assert snap["schema_version"] == 17
     assert snap["policy"] == {}
     srv.shutdown()
 

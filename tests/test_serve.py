@@ -349,7 +349,7 @@ def test_serve_snapshot_section_and_lifecycle(ctx):
     _seed(w)
     # before any plane: the section exists (schema stability) but is {}
     snap = s.metrics_snapshot()
-    assert snap["schema_version"] == 16 and snap["serve"] == {}
+    assert snap["schema_version"] == 17 and snap["serve"] == {}
     plane = ServePlane(s)
     # one live plane per server
     with pytest.raises(RuntimeError):

@@ -55,7 +55,7 @@ def test_stream_default_off():
     assert srv.stream is None
     assert not [n for n in srv.obs.names() if n.startswith("stream.")]
     snap = srv.metrics_snapshot()
-    assert snap["schema_version"] == 16 and snap["stream"] == {}
+    assert snap["schema_version"] == 17 and snap["stream"] == {}
     # no plane -> a trainer cannot exist (loud, not a silent no-op)
     from adapm_tpu.stream import EventLog, StreamTrainer
     with pytest.raises(RuntimeError):

@@ -191,7 +191,7 @@ def test_tier_metrics_section_schema_v4(rng):
     w.pull_sync(np.arange(0, 64))
     srv.tier.promote_keys(np.arange(0, 16))
     snap = srv.metrics_snapshot()
-    assert snap["schema_version"] == 16
+    assert snap["schema_version"] == 17
     t = snap["tier"]
     assert t["promotions"] >= 16
     assert 0.0 <= t["hot_hit_rate"] <= 1.0

@@ -119,7 +119,7 @@ def test_capture_off_pin(ctx):
     assert srv.wtrace is None and srv.replay_stats is None
     assert not [n for n in srv.obs.names() if n.startswith("wtrace.")]
     snap = srv.metrics_snapshot()
-    assert snap["schema_version"] == 16
+    assert snap["schema_version"] == 17
     assert snap["wtrace"] == {} and snap["replay"] == {}
     srv.shutdown()
 

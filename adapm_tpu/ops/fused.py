@@ -42,6 +42,7 @@ eliminating the per-step sample key transfer too.
 """
 from __future__ import annotations
 
+import functools
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -51,6 +52,7 @@ import numpy as np
 from ..core.store import OOB
 from ..device import default_port
 from ..exec import dispatch_gate
+from . import writeback
 
 # sharded-dispatch serialization (adapm_tpu/exec, docs/EXECUTOR.md):
 # the fused step is a sharded program like every store op — its
@@ -164,6 +166,49 @@ def _scatter_update(main, delta, route, upd):
     # replica path: c_sl already carries OOB at owner positions
     delta = delta.at[c_sh, c_sl].add(upd, mode="drop")
     return main, delta
+
+
+def writeback_uses_kernel(main, backend: str = None) -> bool:
+    """Which row-mover the replica-free write-back into this pool is
+    compiled with: the Pallas kernel (pallas_kernels
+    .scatter_add_sorted_rows) where the backend (jax's default unless
+    given) is a TPU and the pool is ONE float32 shard on the step's
+    device (`[1, slots, L]`: a mesh has as many devices as shards, and a
+    Pallas call does not partition under GSPMD) whose rows the kernel
+    can copy (L a multiple of the 128 lanes, slots of the 8 rows of a
+    tile); XLA's scatter-add everywhere else. A static property of the
+    compiled variant, read from the pool's shape and dtype."""
+    backend = jax.default_backend() if backend is None else backend
+    return (backend == "tpu" and main.ndim == 3
+            and main.shape[0] == 1 and main.dtype == jnp.float32
+            and main.shape[2] % 128 == 0 and main.shape[1] % 8 == 0)
+
+
+def _kernel_writeback(main, o_sh, o_sl, g, acc, lr, eps):
+    """The replica-free write-back of one role through the kernel: the
+    same rows as `main.at[o_sh, o_sl].add(_adagrad_update(g, acc),
+    mode="drop")`, computed in the order of their slots (gradients and
+    accumulators are brought into it, so the update rows never exist in
+    batch order: no second copy of them), one kernel call for each
+    `writeback.MAX_POSITIONS` of them. The sort and the permuting
+    gathers are the write-back's cost and carry its scope."""
+    n_slots, L = main.shape[1:]
+    scope = functools.partial(jax.named_scope, "adapm_scatter_add")
+    # one shard: a row lands iff its shard index is 0 (or wraps to it)
+    o_sl = jnp.where((o_sh == 0) | (o_sh == -1), o_sl, OOB)
+    rows = writeback.chunk_rows_for(L)
+    g, acc = (x.reshape(-1, x.shape[-1]) for x in (g, acc))
+    with scope():
+        slices = writeback.sorted_slices(o_sl.reshape(-1), n_slots, rows)
+    pool = main[0]
+    for codes, perm in slices:
+        with scope():
+            g_sorted, acc_sorted = g[perm], acc[perm]
+        upd = _adagrad_update(g_sorted, acc_sorted, lr, eps)
+        with scope():
+            pool = writeback.kernel(n_slots, L, codes.shape[0], rows)(
+                pool, codes, upd)
+    return pool[None]
 
 
 # The parts of a fused step carry stable `jax.named_scope` names
@@ -457,16 +502,20 @@ def _build_device_routed_body(loss_fn, role_class, role_dim, shard,
 
         new_pools = list(pools)
         for r in trainable:
-            upd = _adagrad_update(grads[r], accs[r], lr, eps)
             cid = role_class[r]
             main, cache, delta = new_pools[cid]
-            with jax.named_scope("adapm_scatter_add"):
-                if no_replicas:
-                    o_sh, o_sl = routes[r]
-                    main = main.at[o_sh, o_sl].add(upd, mode="drop")
-                else:
-                    main, delta = _scatter_update(main, delta, routes[r],
-                                                  upd)
+            if no_replicas and writeback_uses_kernel(main):
+                main = _kernel_writeback(main, *routes[r], grads[r],
+                                         accs[r], lr, eps)
+            else:
+                upd = _adagrad_update(grads[r], accs[r], lr, eps)
+                with jax.named_scope("adapm_scatter_add"):
+                    if no_replicas:
+                        o_sh, o_sl = routes[r]
+                        main = main.at[o_sh, o_sl].add(upd, mode="drop")
+                    else:
+                        main, delta = _scatter_update(main, delta,
+                                                      routes[r], upd)
             new_pools[cid] = (main, cache, delta)
         return tuple(new_pools), locstat, loss
 
@@ -577,6 +626,14 @@ class DeviceRoutedRunner:
                                                 shared=True)
         self._h_key_upload = server.obs.histogram("fused.key_upload_s",
                                                   shared=True)
+        # rows the dispatched steps write back, and those of them in a
+        # variant compiled with the Pallas write-back kernel
+        # (writeback_uses_kernel): how often the kernel engages
+        self._c_wb_rows = server.obs.counter(
+            "fused.writeback_rows_total", unit="rows", shared=True)
+        self._c_wb_kernel_rows = server.obs.counter(
+            "fused.writeback_kernel_rows_total", unit="rows", shared=True)
+        self._wb_rows = None  # (all, kernel's) a step; set on first step
         self._mk_kwargs = dict(
             loss_fn=loss_fn, role_class=role_class, role_dim=role_dim,
             shard=shard, frozen_roles=frozen_roles, neg_role=neg_role,
@@ -687,6 +744,26 @@ class DeviceRoutedRunner:
                 pps += int(np.prod(self._neg_shape))
             self._drain_every = max(1, 2**30 // max(1, pps))
             self._g_drain_every.set(self._drain_every)
+
+    def _count_writeback(self, role_keys: Dict[str, np.ndarray],
+                         steps: int, no_replicas: bool) -> None:
+        """Count the rows `steps` dispatched steps write back (from the
+        first batch's key shapes, fixed per runner, like the drain
+        interval above)."""
+        if self._wb_rows is None:
+            rows = {r: np.asarray(k).size for r, k in role_keys.items()}
+            if self.neg_role is not None:
+                rows[self.neg_role] = int(np.prod(self._neg_shape))
+            rows = {r: n for r, n in rows.items()
+                    if r not in self.frozen_roles}
+            stores = self.server.stores
+            self._wb_rows = (sum(rows.values()), sum(
+                n for r, n in rows.items() if writeback_uses_kernel(
+                    stores[self.role_class[r]].main)))
+        rows, kernel_rows = self._wb_rows
+        self._c_wb_rows.inc(rows * steps)
+        if no_replicas:  # the only variant the kernel is compiled into
+            self._c_wb_kernel_rows.inc(kernel_rows * steps)
 
     def _drain_locstat(self) -> None:
         """Fold the device accumulator into the host int64 totals and reset
@@ -851,8 +928,8 @@ class DeviceRoutedRunner:
                 self._upload_keys({r: np.asarray(k, dtype=kdtype)
                                    for r, k in role_keys.items()})
             pools = tuple((s.main, s.cache, s.delta) for s in srv.stores)
-            fn = self.step_fn if self._shard_has_replicas() \
-                else self._step_fn_norep
+            no_replicas = not self._shard_has_replicas()
+            fn = self._step_fn_norep if no_replicas else self.step_fn
             # dispatch under the gate, tracked on the "main" stream for
             # the executor's overlap accounting (enqueue-only: the jit
             # call returns as soon as the program is queued)
@@ -864,6 +941,7 @@ class DeviceRoutedRunner:
                 for st, (m, c, d) in zip(srv.stores, pools):
                     st.main, st.cache, st.delta = m, c, d
             self.steps += 1
+            self._count_writeback(role_keys, 1, no_replicas)
             self._ensure_drain_every(role_keys)
             if self.steps % self._drain_every == 0:
                 self._drain_locstat()
@@ -933,8 +1011,8 @@ class DeviceRoutedRunner:
                     lambda *xs: put(np.stack([np.asarray(x) for x in xs])),
                     *auxes)
             pools = tuple((s.main, s.cache, s.delta) for s in srv.stores)
-            fn = self._scan_fn(no_replicas=not self._shard_has_replicas(),
-                               has_aux=has_aux)
+            no_replicas = not self._shard_has_replicas()
+            fn = self._scan_fn(no_replicas=no_replicas, has_aux=has_aux)
             with srv.exec.track("main"), _GATE:
                 pools, self._locstat, losses = fn(
                     pools, self._locstat, tables, keys, local_index,
@@ -943,6 +1021,7 @@ class DeviceRoutedRunner:
                 for st, (m, c, d) in zip(srv.stores, pools):
                     st.main, st.cache, st.delta = m, c, d
             self.steps += K
+            self._count_writeback(batches[0], K, no_replicas)
             self._ensure_drain_every(batches[0])
             if self.steps // self._drain_every != \
                     (self.steps - K) // self._drain_every:

@@ -1,21 +1,34 @@
-"""Pallas TPU kernels: alternative data-plane primitives.
+"""Pallas TPU kernels: data-plane primitives.
 
-Status: both kernels compile under the installed Mosaic compiler (jax
-0.9.0 / libtpu 0.0.34) and match numpy on a v5e at the store's row width
-— `chip_smoke.py`, part "kernels", checks that on every chip run; the
-CPU tests run them in interpret mode only. Their SPEED has not been
-measured on the current installation. On an earlier one (2026-07, see
-docs/PERF.md "Pallas findings") XLA's native gather/scatter was the
-fastest primitive for the random ~2 KB row accesses that dominate this
-framework — the scalar-prefetch index-map gather below reached ~0.7x of
-XLA's row rate — so the fused training step rides XLA (ops/fused.py) and
-these are kept as working templates, reached only from cost calibration
-(ops/costs.py). Manual-DMA kernels (make_async_copy from HBM refs) have
-never been tried on this compiler (ROADMAP A3, C5).
+Status (jax 0.9.0 / libtpu 0.0.34, v5e): all three compile under the
+installed Mosaic compiler and match numpy on the chip — `chip_smoke.py`,
+part "kernels", checks that on every chip run; the CPU tests run them in
+interpret mode only.
 
-The kernels use only the BlockSpec subset: grid pipelines + scalar
-prefetch (compiler-generated, double-buffered DMA), no manual
-semaphores.
+`scatter_add_rows` / `scatter_add_sorted_rows` is on the main path: the
+fused step's replica-free write-back on one chip (ops/fused.py
+`writeback_uses_kernel`; the step sorts the slots and takes the kernel
+through ops/writeback.py, exported once, so that a process whose step
+comes from the compile cache does not import this module or Pallas at
+all; one call for each 131,072 sorted positions, because a call's codes
+are one SMEM operand). It is the first manual-DMA kernel here
+(`make_async_copy` from HBM refs, own semaphores, copies of three chunks
+in flight). What it could NOT be is a row-wise copy: the pool's layout
+is XLA's (8, 128) tiling, where an 8 KB row is 16 pieces of 512 B, and
+Mosaic refuses a one-row slice of a tiled memref ("must be aligned to
+tiling (8)"), in HBM and in VMEM alike. So it moves whole 8-row groups.
+Measured on the chip (PERF.md section 6, PR 25): 141-189 ns a row with
+the sort and the permutation against 280 for XLA's scatter-add, bound by
+HBM bandwidth at 8 rows moved for each one changed.
+
+`gather_rows` and `adagrad_apply` use only the BlockSpec subset (grid
+pipelines + scalar prefetch, compiler-generated double-buffered DMA, no
+manual semaphores) and are templates, reached only from cost
+calibration (ops/costs.py): XLA's native gather was the fastest
+primitive for random row reads when they were last timed (2026-07,
+docs/PERF.md "Pallas findings": the index-map gather reached ~0.7x of
+XLA's row rate), and a manual-DMA gather meets the same tiling rule
+(ROADMAP A3).
 """
 from __future__ import annotations
 
@@ -25,6 +38,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from .writeback import CLOSES, GROUP, OPENS, SLOT_MASK, sorted_slices
 
 
 def _copy_kernel(idx_ref, blk_ref, o_ref):
@@ -98,3 +113,191 @@ def adagrad_apply(grads: jnp.ndarray, emb: jnp.ndarray, acc: jnp.ndarray,
                    jax.ShapeDtypeStruct((n, L), acc.dtype)),
         interpret=interpret,
     )(grads, emb, acc, lr_arr, eps_arr)
+
+
+# ---------------------------------------------------------------------------
+# scatter_add_rows: the fused step's write-back as a sorted, pipelined
+# read-modify-write with many copies in flight (manual DMA)
+# ---------------------------------------------------------------------------
+
+_GROUP_BUFFERS = 3  # chunk c's groups live in buffer c % 3
+
+
+def _scatter_add_kernel(code_ref, _pool_in, upd_hbm, pool_hbm,
+                        gbuf, ubuf, counts, gsem, usem, wsem, *,
+                        rows: int, n_chunks: int):
+    """One pass over `n_chunks` chunks of `rows` sorted positions.
+
+    code_ref: `sort_slots`'s codes, SMEM (scalar prefetch). `_pool_in` is
+    the same HBM buffer as `pool_hbm` (input_output_aliases), the one
+    name the kernel reads and writes the pool by. `upd_hbm` holds the
+    update rows in sorted order. A pool of (8, 128)-tiled rows can only
+    be copied in whole groups of 8 rows (Mosaic refuses a one-row slice
+    of a tiled memref), so the unit read, summed into and written back
+    is the 8-row group, 8 * L * 4 contiguous bytes. gbuf: [3, rows, 8, L]
+    VMEM, one group per position that opens a run of its group; ubuf:
+    [2, rows, L]; counts: SMEM, the reads and writes started per buffer.
+
+    Only the first position of a run of one group reads it and only the
+    last writes it, so no two copies in flight touch one group. A run
+    that crosses a chunk boundary moves its group, as summed so far, to
+    position 0 of the next chunk's buffer."""
+    nb = _GROUP_BUFFERS
+
+    def group_copy(code, b, j, sem, to_pool: bool):
+        g0 = pl.multiple_of(code & (SLOT_MASK & ~(GROUP - 1)), GROUP)
+        hbm = pool_hbm.at[pl.ds(g0, GROUP)]
+        buf = gbuf.at[b, j]
+        return pltpu.make_async_copy(buf, hbm, sem) if to_pool else \
+            pltpu.make_async_copy(hbm, buf, sem)
+
+    def upd_copy(c, s):
+        r0 = pl.multiple_of(c * rows, rows)
+        return pltpu.make_async_copy(upd_hbm.at[pl.ds(r0, rows)],
+                                     ubuf.at[s], usem.at[s])
+
+    def start_reads(c):
+        b, s = c % nb, c % 2
+        upd_copy(c, s).start()
+
+        def row(j, started):
+            code = code_ref[c * rows + j]
+            # (a run that continues from the chunk before is moved here
+            # by compute_and_write, not read)
+            go = (code >= 0) & ((code & OPENS) != 0)
+
+            @pl.when(go)
+            def _():
+                group_copy(code, b, j, gsem.at[b], to_pool=False).start()
+            return started + go.astype(jnp.int32)
+        counts[0, b] = jax.lax.fori_loop(0, rows, row, jnp.int32(0))
+
+    def wait_all(count, b, sem, to_pool: bool):
+        def one(_, carry):
+            group_copy(jnp.int32(0), b, 0, sem, to_pool=to_pool).wait()
+            return carry
+        jax.lax.fori_loop(0, count, one, None)
+
+    def compute_and_write(c, tgt_prev):
+        """Sum chunk c's update rows into their groups, in sorted order,
+        and start the write of each group whose run ends here. Returns
+        the buffer position of the group the chunk's last run sums into
+        (the next chunk may continue it)."""
+        b, s = c % nb, c % 2
+
+        def row(j, carry):
+            tgt, written = carry
+            code = code_ref[c * rows + j]
+            valid = code >= 0
+            tgt = jnp.where((code & OPENS) != 0, j, tgt)
+
+            @pl.when(valid)
+            def _():
+                sub = code & (GROUP - 1)
+                gbuf[b, tgt, pl.ds(sub, 1), :] = (
+                    gbuf[b, tgt, pl.ds(sub, 1), :]
+                    + ubuf[s, pl.ds(j, 1), :])
+            done = valid & ((code & CLOSES) != 0)
+
+            @pl.when(done)
+            def _():
+                group_copy(code, b, tgt, wsem.at[b], to_pool=True).start()
+            return tgt, written + done.astype(jnp.int32)
+
+        # position 0 continues the previous chunk's last run: take over
+        # its group as summed so far
+        first = code_ref[c * rows]
+
+        @pl.when((first >= 0) & ((first & OPENS) == 0))
+        def _():
+            gbuf[b, 0] = gbuf[(c + nb - 1) % nb, tgt_prev]
+        tgt, written = jax.lax.fori_loop(
+            0, rows, row, (jnp.int32(0), jnp.int32(0)))
+        counts[1, b] = written
+        return tgt
+
+    start_reads(0)
+    if n_chunks > 1:
+        start_reads(1)
+
+    def chunk(c, tgt_prev):
+        b = c % nb
+        upd_copy(c, c % 2).wait()
+        wait_all(counts[0, b], b, gsem.at[b], to_pool=False)
+        tgt = compute_and_write(c, tgt_prev)
+
+        @pl.when(c >= 1)
+        def _():
+            pb = (c + nb - 1) % nb
+            wait_all(counts[1, pb], pb, wsem.at[pb], to_pool=True)
+
+        @pl.when(c + 2 < n_chunks)
+        def _():
+            start_reads(c + 2)
+        return tgt
+    jax.lax.fori_loop(0, n_chunks, chunk, jnp.int32(0))
+    lb = (n_chunks - 1) % nb
+    wait_all(counts[1, lb], lb, wsem.at[lb], to_pool=True)
+
+
+# apm-lint: disable=APM008 Pallas TPU kernel (backend-specific by
+# definition; the fused step calls it only where writeback_uses_kernel
+# holds); the jit makes roles of one shape share one trace of the kernel
+@functools.partial(jax.jit, static_argnames=("chunk_rows", "interpret"))
+def scatter_add_sorted_rows(pool: jnp.ndarray, codes: jnp.ndarray,
+                            upd_sorted: jnp.ndarray, chunk_rows: int = 32,
+                            interpret: bool = False) -> jnp.ndarray:
+    """Add `upd_sorted[i]` to the row of `codes[i]` of a float32
+    [slots, L] pool in HBM (slots a multiple of 8, L of 128), with many
+    copies in flight; `codes` from `sort_slots`, the rows in its order.
+
+    Duplicates are adjacent: a run of equal slots is summed in VMEM in
+    the batch's own order (the sort is stable) onto the pool row, which
+    is read once and written once, so every row ends as
+    `pool + u1 + u2 + ...` with the u's in batch order. The pool is
+    updated in place when the caller donates it."""
+    n_slots, L = pool.shape
+    assert n_slots % GROUP == 0 and L % 128 == 0 and \
+        chunk_rows % GROUP == 0 and \
+        codes.shape[0] % chunk_rows == 0, (pool.shape, chunk_rows)
+    return pl.pallas_call(
+        functools.partial(_scatter_add_kernel, rows=chunk_rows,
+                          n_chunks=codes.shape[0] // chunk_rows),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(1,),
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec(memory_space=pl.ANY),
+            scratch_shapes=[
+                pltpu.VMEM((_GROUP_BUFFERS, chunk_rows, GROUP, L),
+                           pool.dtype),
+                pltpu.VMEM((2, chunk_rows, L), pool.dtype),
+                pltpu.SMEM((2, _GROUP_BUFFERS), jnp.int32),
+                pltpu.SemaphoreType.DMA((_GROUP_BUFFERS,)),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.SemaphoreType.DMA((_GROUP_BUFFERS,))]),
+        out_shape=jax.ShapeDtypeStruct(pool.shape, pool.dtype),
+        input_output_aliases={1: 0},
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=(_GROUP_BUFFERS * GROUP + 2) * chunk_rows
+            * L * 4 + (4 << 20)),
+        interpret=interpret,
+    )(codes, pool, upd_sorted)
+
+
+def scatter_add_rows(pool: jnp.ndarray, slots: jnp.ndarray,
+                     upd: jnp.ndarray, chunk_rows: int = 32,
+                     interpret: bool = False,
+                     max_positions: int = None) -> jnp.ndarray:
+    """`pool.at[slots].add(upd, mode="drop")` through
+    `scatter_add_sorted_rows`: sort the slots, bring the update rows
+    into that order (a gather), add; one call of the kernel for each
+    `max_positions` (writeback.MAX_POSITIONS unless given) sorted
+    positions."""
+    for codes, perm in sorted_slices(slots, pool.shape[0], chunk_rows,
+                                     max_positions):
+        pool = scatter_add_sorted_rows(pool, codes, upd[perm],
+                                       chunk_rows=chunk_rows,
+                                       interpret=interpret)
+    return pool

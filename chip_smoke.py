@@ -12,7 +12,9 @@ jax reports (one chip, or the four chips of a host as four kv shards,
 with the same arguments):
 
   kernels   ops/pallas_kernels.py gather_rows + adagrad_apply COMPILED
-            (interpret=False) at the store's row width, against numpy
+            (interpret=False) at the store's row width, against numpy;
+            scatter_add_rows (the fused step's write-back on one chip)
+            at rows of 2048 floats with a Zipf batch, against np.add.at
   contract  adapm_tpu.apps.simple: intent -> push -> clock -> sync round
             -> quiesce -> every worker's pull == main == pushed total
   trainer   adapm_tpu.apps.knowledge_graph_embeddings (open_run/train):
@@ -206,13 +208,16 @@ def _sync_report(lines, tag: str) -> dict:
 # ------------------------------------------------------------------ parts
 
 def part_kernels(ctx) -> None:
-    """Both Pallas kernels through the installed Mosaic compiler at the
+    """The Pallas kernels through the installed Mosaic compiler at the
     store's row width (adagrad also with a row count that is not a
-    multiple of the block: the pl.cdiv edge)."""
+    multiple of the block: the pl.cdiv edge; the write-back kernel at
+    the benchmark's row width, where the fused step uses it)."""
     import jax.numpy as jnp
     import numpy as np
 
-    from adapm_tpu.ops.pallas_kernels import adagrad_apply, gather_rows
+    from adapm_tpu.ops.pallas_kernels import (adagrad_apply, gather_rows,
+                                              scatter_add_rows)
+    from adapm_tpu.ops.writeback import MAX_POSITIONS
     interpret = ctx["rehearsal"]
     L = ctx["sz"]["L"]
     rng = np.random.default_rng(0)
@@ -242,6 +247,31 @@ def part_kernels(ctx) -> None:
                f"adagrad_apply emb mismatch at n={n}")
         print(f"  adagrad_apply interpret={interpret} n={n} L={L} "
               f"block=256: matches numpy")
+    # the write-back: Zipf slots (long runs of one slot, many rows of
+    # one 8-row group), some outside the pool, n not a whole chunk; then
+    # more rows than one kernel call takes (three calls on the pool)
+    for N, Lw, n, per_call in (
+            [(256, 128, 200, None), (256, 128, 200, 64)] if interpret else
+            [(4096, 2048, 3000, None), (4096, 128, 300_000, None)]):
+        p = 1.0 / np.arange(1, N + 1)
+        slots = rng.permutation(N)[rng.choice(N, n, p=p / p.sum())] \
+            .astype(np.int32)
+        slots[::97] = N + 5
+        pool = rng.normal(size=(N, Lw)).astype(np.float32)
+        upd = rng.normal(size=(n, Lw)).astype(np.float32)
+        got = np.asarray(scatter_add_rows(
+            jnp.asarray(pool), jnp.asarray(slots), jnp.asarray(upd),
+            interpret=interpret, max_positions=per_call))
+        keep = slots < N
+        np.add.at(pool, slots[keep], upd[keep])
+        _check(got.tobytes() == pool.tobytes(),
+               f"scatter_add_rows differs from np.add.at at n={n} (max "
+               f"abs {np.abs(got - pool).max():.3g})")
+        print(f"  scatter_add_rows interpret={interpret} L={Lw} n={n} "
+              f"({len(np.unique(slots[keep]))} slots, "
+              f"{int((~keep).sum())} dropped, "
+              f"{per_call or MAX_POSITIONS} positions a call): bitwise "
+              f"equal to np.add.at")
 
 
 def part_contract(ctx) -> None:

@@ -1,0 +1,148 @@
+"""The fused step's write-back in isolation, on the chip (ISSUE 25).
+
+    chiprun --chips 1 -- python scripts/writeback_probe.py
+
+At the KGE cell's shape (a float32 [1, 1172432, 2048] pool, donated, and
+131,072 update rows) it times, for uniform slots and for Zipf(1.0)
+slots: (a) today's `.at[sh, sl].add(mode="drop")`; (b) the same on
+sorted slots with `indices_are_sorted=True`, the update rows permuted
+beforehand, and that permutation alone; (c) on sorted, duplicate-free
+slots with `unique_indices=True` too; (d) the gather of the same rows;
+and `ops/pallas_kernels.scatter_add_rows`. One line a reading:
+`probe <name> <draw>: <ms> ms, <ns> ns a row`. TPU only.
+"""
+from __future__ import annotations
+
+import argparse
+import functools
+import os
+import sys
+import time
+
+import numpy as np
+
+sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                                ".."))
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--slots", type=int, default=1_172_432)
+    ap.add_argument("--row", type=int, default=2048)
+    ap.add_argument("--n", type=int, default=131_072)
+    ap.add_argument("--reps", type=int, default=5)
+    ap.add_argument("--rehearse-cpu", action="store_true",
+                    help="debug this script without a chip (interpret-"
+                         "mode kernel; pass tiny sizes; no device number)")
+    ap.add_argument("--kernel-rows", default=[32],
+                    type=lambda v: [int(x) for x in v.split(",")],
+                    help="chunk_rows to time the kernel at: 16,32,64")
+    ap.add_argument("--only", default="",
+                    help="comma-separated reading names (default: all)")
+    args = ap.parse_args(argv)
+
+    import jax
+    import jax.numpy as jnp
+    dev = jax.devices()[0]
+    cpu = args.rehearse_cpu
+    tag = "platform=cpu | " if cpu else ""
+    if dev.platform != ("cpu" if cpu else "tpu"):
+        print(f"writeback_probe.py: no TPU ({dev.platform})",
+              file=sys.stderr)
+        return 2
+    from adapm_tpu.ops import pallas_kernels
+    scatter_add_rows = functools.partial(pallas_kernels.scatter_add_rows,
+                                         interpret=cpu)
+    N, L, n = args.slots, args.row, args.n
+    only = set(filter(None, args.only.split(",")))
+    rng = np.random.default_rng(25)
+    p = 1.0 / np.arange(1, N + 1)
+    draws = {
+        "uniform": rng.integers(0, N, n).astype(np.int32),
+        "zipf": rng.permutation(N)[
+            rng.choice(N, n, p=p / p.sum())].astype(np.int32),
+        "unique": rng.permutation(N)[:n].astype(np.int32),
+    }
+    print(f"platform={dev.platform} device={dev.device_kind} "
+          f"pool=f32[1,{N},{L}] n={n}")
+    pool = jnp.zeros((1, N, L), jnp.float32)
+    upd = jax.random.normal(jax.random.PRNGKey(0), (n, L), jnp.float32)
+    sh = jnp.zeros((n,), jnp.int32)
+
+    def timed(name, draw, fn, *xs, on_pool=True):
+        """ms a call of fn(pool, *xs) -> pool (the pool donated) or of
+        fn(*xs) -> a value; per row of xs[0]."""
+        nonlocal pool
+        if only and not any(name.startswith(o) for o in only):
+            return
+        try:
+            f = jax.jit(fn, donate_argnums=(0,) if on_pool else ())
+
+            def call():
+                nonlocal pool
+                if on_pool:
+                    pool = f(pool, *xs)
+                    return pool
+                return f(*xs)
+            jax.block_until_ready(call())
+            t0 = time.perf_counter()
+            for _ in range(args.reps):
+                out = call()
+            jax.block_until_ready(out)
+            ms = (time.perf_counter() - t0) / args.reps * 1e3
+            print(f"{tag}probe {name} {draw}: {ms:.3f} ms, "
+                  f"{ms * 1e6 / xs[0].shape[0]:.1f} ns a row", flush=True)
+        except Exception as e:  # one reading failing must not lose the rest
+            print(f"{tag}probe {name} {draw}: FAILED {type(e).__name__}: "
+                  f"{str(e)[:300]}", flush=True)
+
+    def xla_add(m, s, u, **flags):
+        return m.at[sh[:s.shape[0]], s].add(u, mode="drop", **flags)
+
+    # the kernel against XLA on a pool small enough to hold twice
+    k = min(n, 8192)
+    small = jnp.ones((1, min(N, 65_536), L), jnp.float32)
+    sl = jnp.asarray(draws["zipf"][:k] % small.shape[1])
+    want = xla_add(small, sl, upd[:k])
+    got = scatter_add_rows(small[0], sl, upd[:k])[None]
+    print(f"{tag}check kernel against XLA, zipf n={k}: max abs difference "
+          f"{float(jnp.max(jnp.abs(got - want))):.3g}", flush=True)
+    del small, want, got
+
+    for draw, sl_np in draws.items():
+        sl = jnp.asarray(sl_np)
+        order = np.argsort(sl_np, kind="stable").astype(np.int32)
+        sl_sorted, perm = jnp.asarray(sl_np[order]), jnp.asarray(order)
+        timed("a_scatter_add", draw, xla_add, sl, upd)
+        timed("b_permute_upd", draw, lambda p_, u: u[p_], perm, upd,
+              on_pool=False)
+        timed("b_sorted", draw, functools.partial(
+            xla_add, indices_are_sorted=True), sl_sorted, upd)
+        if draw == "unique":
+            timed("c_sorted_unique", draw, functools.partial(
+                xla_add, indices_are_sorted=True, unique_indices=True),
+                sl_sorted, upd)
+            timed("c_unsorted_unique", draw, functools.partial(
+                xla_add, unique_indices=True), sl, upd)
+            timed("c_set_sorted_unique", draw,
+                  lambda m, s, u: m.at[sh, s].set(
+                      u, mode="drop", indices_are_sorted=True,
+                      unique_indices=True), sl_sorted, upd)
+            # nine rows of ten dropped: what a dropped row costs
+            timed("a_scatter_add_90pct_dropped", draw, xla_add,
+                  jnp.where(jnp.arange(n) % 10 == 0, sl, 2**30), upd)
+        timed("sort_slots", draw, lambda s: jax.lax.sort(
+            (s, jax.lax.iota(jnp.int32, n)), num_keys=1, is_stable=True),
+            sl, on_pool=False)
+        timed("d_gather", draw,
+              lambda s, m: m.at[sh, s].get(mode="fill", fill_value=0),
+              sl, pool, on_pool=False)
+        for rows in args.kernel_rows:
+            timed(f"kernel_R{rows}", draw,
+                  lambda m, s, u, rows=rows: scatter_add_rows(
+                      m[0], s, u, chunk_rows=rows)[None], sl, upd)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -43,3 +43,19 @@ def _drop_lockorder_sentinel():
     yield
     from adapm_tpu.lint import lockorder
     lockorder.disable_sentinel()
+
+
+@pytest.fixture
+def kernel_cache(tmp_path):
+    """jax's compilation cache directory, where `ops/writeback.py` keeps
+    the exported write-back kernel, pointed at an empty one; yields the
+    kernels' directory in it."""
+    import jax
+
+    from adapm_tpu.ops import writeback
+    before = jax.config.jax_compilation_cache_dir
+    jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+    writeback.exported_kernel.cache_clear()
+    yield tmp_path / "adapm_kernels"
+    writeback.exported_kernel.cache_clear()
+    jax.config.update("jax_compilation_cache_dir", before)

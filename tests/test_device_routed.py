@@ -295,3 +295,107 @@ def test_mf_device_routes_matches_host():
     dev = mf.run(mf.build_parser().parse_args(base + ["--device_routes"]))
     assert np.isfinite(dev)
     assert dev < 1.3 * host + 1e-6, (dev, host)
+
+
+# ---- the replica-free write-back's two row-movers (ISSUE 25) --------------
+
+def _neg_loss(embs, aux):
+    import jax
+    pos = (embs["a"] * embs["b"]).sum(-1)
+    neg = (embs["a"][:, None, :] * embs["neg"]).sum(-1)
+    return (jax.nn.softplus(-pos) + jax.nn.softplus(neg).sum(-1)).mean()
+
+
+def _one_shard_runner(num_keys=80, L=256):
+    """One kv shard, rows of 256 float32 (so the kernel could move
+    them), a seeded pool, device-drawn negatives: duplicates within a
+    role and across roles."""
+    srv = adapm_tpu.setup(num_keys, L, num_shards=1,
+                          opts=SystemOptions(sync_max_per_sec=0,
+                                             cache_slots_per_shard=8))
+    w = srv.make_worker(0)
+    rng = np.random.default_rng(3)
+    init = rng.normal(size=(num_keys, L)).astype(np.float32)
+    init[:, L // 2:] = 1e-6
+    w.set(np.arange(num_keys), init)
+    dev = DeviceRoutedRunner(
+        srv, _neg_loss, role_class={"a": 0, "b": 0, "neg": 0},
+        role_dim={"a": L // 2, "b": L // 2, "neg": L // 2}, shard=0,
+        neg_role="neg", neg_shape=(16, 3),
+        neg_population=np.arange(num_keys), seed=5)
+    return srv, dev
+
+
+def _counter(srv, name):
+    return int(srv.obs.find(name).value)
+
+
+def test_writeback_kernel_step_equals_xla_step(monkeypatch, kernel_cache):
+    """One fused step of the replica-free variant with the write-back
+    kernel (forced here; off a TPU the step takes its interpret build,
+    by the same export, cache directory and call as on one) against the
+    XLA variant, same seeded pool and batch: loss bitwise, touched rows
+    to 1 ulp, untouched rows bitwise; and the counters say which ran.
+    The negatives' 48 positions are more than one kernel call takes
+    here (32), so that role is written by two calls in turn."""
+    import functools
+
+    from adapm_tpu.ops import fused, writeback
+    rng = np.random.default_rng(4)
+    hot = rng.integers(0, 80, 4)
+    batch = {"a": hot[rng.integers(0, 4, 16)].astype(np.int64),
+             "b": rng.integers(0, 80, 16).astype(np.int64)}
+    def rows(srv):
+        return np.asarray(srv.read_main(np.arange(80))).reshape(80, -1)
+
+    srv_x, dev_x = _one_shard_runner()
+    before = rows(srv_x)
+    loss_x = float(dev_x(batch, None, 0.1))
+    loss_x2 = float(dev_x(batch, None, 0.1))
+    rows_x = rows(srv_x)
+    total = _counter(srv_x, "fused.writeback_rows_total")
+    assert total == 2 * (16 + 16 + 16 * 3)
+    assert _counter(srv_x, "fused.writeback_kernel_rows_total") == 0
+    srv_x.shutdown()
+
+    monkeypatch.setattr(fused, "writeback_uses_kernel", functools.partial(
+        fused.writeback_uses_kernel, backend="tpu"))
+    monkeypatch.setattr(writeback, "MAX_POSITIONS", 32)
+    srv_k, dev_k = _one_shard_runner()
+    assert rows(srv_k).tobytes() == before.tobytes()
+    loss_k = float(dev_k(batch, None, 0.1))
+    rows_k1 = rows(srv_k)
+    loss_k2 = float(dev_k(batch, None, 0.1))
+    rows_k = rows(srv_k)
+    assert _counter(srv_k, "fused.writeback_rows_total") == total
+    assert _counter(srv_k, "fused.writeback_kernel_rows_total") == total
+    srv_k.shutdown()
+    # every call is one chunk of 32 positions: one kernel, exported once
+    assert len(list(kernel_cache.iterdir())) == 1
+
+    assert loss_k == loss_x
+    assert np.isclose(loss_k2, loss_x2, rtol=1e-6)
+    touched = (rows_k1 != before).any(axis=1)
+    assert touched[np.unique(batch["a"])].all() and not touched.all()
+    assert rows_k1[~touched].tobytes() == before[~touched].tobytes()
+    ulp = np.spacing(np.maximum(np.abs(rows_k), np.abs(rows_x)))
+    assert (np.abs(rows_k - rows_x) <= 2 * ulp).all()  # two steps
+    assert (rows_x != before).any(axis=1).tolist() == \
+        (rows_k != before).any(axis=1).tolist()
+
+
+@pytest.mark.parametrize("shape, dtype, backend, kernel", [
+    ((1, 64, 256), np.float32, "tpu", True),
+    ((1, 64, 256), np.float32, None, False),       # CPU: all of tier-1
+    ((1, 64, 256), np.float32, "gpu", False),
+    ((4, 64, 256), np.float32, "tpu", False),      # more than one shard
+    ((1, 64, 256), np.float16, "tpu", False),      # not a float32 pool
+    ((1, 64, 200), np.float32, "tpu", False),      # rows not of 128 lanes
+    ((1, 60, 256), np.float32, "tpu", False),      # slots not of 8 rows
+])
+def test_writeback_row_mover_is_a_static_rule(shape, dtype, backend, kernel):
+    import jax
+
+    from adapm_tpu.ops.fused import writeback_uses_kernel
+    main = jax.ShapeDtypeStruct(shape, dtype)
+    assert writeback_uses_kernel(main, backend=backend) is kernel

@@ -5,6 +5,7 @@ is checked on the chip by `chip_smoke.py`, part "kernels"."""
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
 
@@ -36,3 +37,147 @@ def test_adagrad_apply_matches_numpy():
     ref_emb = emb - lr * g / np.sqrt(ref_acc + eps)
     assert np.allclose(np.asarray(new_acc), ref_acc, rtol=1e-5)
     assert np.allclose(np.asarray(new_emb), ref_emb, rtol=1e-4, atol=1e-6)
+
+
+def _scatter_case(name):
+    """(pool slots, chunk rows, slots of the batch[, positions a kernel
+    call]) for one case of test_scatter_add_rows_matches_numpy."""
+    rng = np.random.default_rng(25)
+    N, R = 64, 8
+    if name == "no_duplicates":
+        return N, R, rng.permutation(N)[:24]
+    if name == "uniform_duplicates":
+        return N, R, rng.integers(0, N, 40)
+    if name == "one_slot_half_the_batch":  # a run over many chunks
+        s = rng.integers(0, N, 48)
+        s[rng.permutation(48)[:24]] = 13
+        return N, R, s
+    if name == "run_ends_on_chunk_boundary":
+        # sorted: [3]*8 | [5]*4, [9]*4 | ...: the run of 3 fills chunk 0
+        return N, R, rng.permutation(
+            np.array([3] * 8 + [5] * 4 + [9] * 4 + [17] * 8))
+    if name == "n_not_a_multiple_of_chunk":
+        return N, R, rng.integers(0, N, 21)
+    if name == "out_of_range_dropped":
+        s = rng.integers(0, N, 24)
+        s[[1, 7, 20]] = [N, 2**31 - 2, -N - 1]
+        s[4] = -1  # wraps to the last row, as jnp indexing does
+        return N, R, s
+    if name == "n_smaller_than_chunk":
+        return N, 16, np.array([5, 5, 2])
+    if name == "one_group_many_rows":  # 8 rows of one tile, interleaved
+        return N, R, rng.integers(8, 16, 30)
+    # more positions than one kernel call takes (here 16, two chunks; in
+    # the step writeback.MAX_POSITIONS): successive calls on the pool
+    if name == "many_calls_run_cut_by_each":  # one slot over 3 calls
+        s = rng.integers(0, N, 70)  # and a last call of one chunk
+        s[rng.permutation(70)[:40]] = 29
+        return N, R, s, 16
+    if name == "many_calls_group_cut_between_rows":
+        # sorted: 14 positions of slot 8, then slots 9, 10 of the same
+        # group: the call's end falls inside the group, not inside a row
+        return N, R, rng.permutation(
+            np.array([8] * 14 + [9] * 3 + [10] * 3 + [40] * 12)), 16
+    if name == "many_calls_dropped_tail":  # a whole call of dropped slots
+        s = rng.integers(0, N, 48)
+        s[rng.permutation(48)[:20]] = N + 3
+        return N, R, s, 16
+    if name == "many_calls_chunk_not_a_divisor":  # calls of 24, not 30
+        return N, 24, rng.integers(0, N, 100), 30
+    raise KeyError(name)
+
+
+@pytest.mark.parametrize("case", [
+    "no_duplicates", "uniform_duplicates", "one_slot_half_the_batch",
+    "run_ends_on_chunk_boundary", "n_not_a_multiple_of_chunk",
+    "out_of_range_dropped", "n_smaller_than_chunk", "one_group_many_rows",
+    "many_calls_run_cut_by_each", "many_calls_group_cut_between_rows",
+    "many_calls_dropped_tail", "many_calls_chunk_not_a_divisor"])
+def test_scatter_add_rows_matches_numpy(case):
+    """The additions are the batch's, in the batch's order within a row
+    (the sort is stable), so the result is `np.add.at`'s bit for bit."""
+    from adapm_tpu.ops.pallas_kernels import scatter_add_rows
+    N, R, slots, per_call = (*_scatter_case(case), None)[:4]
+    slots = np.asarray(slots, dtype=np.int32)
+    rng = np.random.default_rng(7)
+    L = 128
+    pool = rng.normal(size=(N, L)).astype(np.float32)
+    upd = rng.normal(size=(len(slots), L)).astype(np.float32)
+    got = np.asarray(scatter_add_rows(
+        jnp.asarray(pool), jnp.asarray(slots), jnp.asarray(upd),
+        chunk_rows=R, interpret=True, max_positions=per_call))
+    wrapped = np.where(slots < 0, slots.astype(np.int64) + N, slots)
+    keep = (wrapped >= 0) & (wrapped < N)
+    ref = pool.copy()
+    np.add.at(ref, wrapped[keep], upd[keep])
+    assert got.tobytes() == ref.tobytes()
+    untouched = np.setdiff1d(np.arange(N), wrapped[keep])
+    assert got[untouched].tobytes() == pool[untouched].tobytes()
+
+
+def test_sorted_slices_are_whole_calls():
+    """Each slice of the sorted positions is a call the kernel can make
+    alone: whole chunks, its first run opened and its last closed inside
+    it, and together they hold every position that lands, once."""
+    from adapm_tpu.ops.writeback import (CLOSES, OPENS, SLOT_MASK,
+                                         sorted_slices)
+    rng = np.random.default_rng(11)
+    slots = rng.integers(0, 64, 200).astype(np.int32)
+    slots[:90] = 17            # one run over several slices
+    slots[190:] = 64           # dropped
+    slices = sorted_slices(jnp.asarray(slots), 64, 8, max_positions=44)
+    assert [int(c.shape[0]) for c, _ in slices] == [40] * 5
+    seen = []
+    for codes, perm in slices:
+        codes, perm = np.asarray(codes), np.asarray(perm)
+        live = codes >= 0
+        if live.any():
+            assert codes[live][0] & OPENS and codes[live][-1] & CLOSES
+        assert (slots[perm[live]] == codes[live] & SLOT_MASK).all()
+        seen.extend(perm[live])
+    assert sorted(seen) == list(range(190))
+    # the batch's order within a slot survives the cut (stable sort)
+    assert [p for p in seen if p < 90] == list(range(90))
+
+
+def test_exported_kernel_is_kept_read_back_and_remade(kernel_cache,
+                                                      monkeypatch):
+    """The way the step takes the kernel on any backend (here its
+    interpret build): exported once into the compile cache directory,
+    deserialised by the next process, the same rows either way; made
+    anew, not raised over, when the file is cut short; and under
+    another name as soon as either source file changes."""
+    from adapm_tpu.ops import writeback
+    N, L, R = 64, 128, 8
+    rng = np.random.default_rng(12)
+    slots = rng.integers(0, N, 24).astype(np.int32)
+    pool = rng.normal(size=(N, L)).astype(np.float32)
+    upd = rng.normal(size=(24, L)).astype(np.float32)
+    ref = pool.copy()
+    np.add.at(ref, slots, upd)
+    ((codes, perm),) = writeback.sorted_slices(jnp.asarray(slots), N, R)
+
+    def run(exported):
+        return np.asarray(jax.jit(exported.call)(
+            jnp.asarray(pool), codes, jnp.asarray(upd)[perm]))
+
+    made = writeback.exported_kernel(N, L, 24, R, "cpu")
+    assert run(made).tobytes() == ref.tobytes()
+    (kept,) = kernel_cache.iterdir()
+    whole = kept.read_bytes()
+    writeback.exported_kernel.cache_clear()  # as a later process
+    read = writeback.exported_kernel(N, L, 24, R, "cpu")
+    assert read is not made
+    assert read.mlir_module_serialized == made.mlir_module_serialized
+    assert run(read).tobytes() == ref.tobytes()
+
+    kept.write_bytes(whole[:len(whole) // 2])
+    writeback.exported_kernel.cache_clear()
+    remade = writeback.exported_kernel(N, L, 24, R, "cpu")
+    assert run(remade).tobytes() == ref.tobytes()
+    assert kept.read_bytes() == whole
+
+    monkeypatch.setattr(writeback, "_sources_sha", lambda: b"edited")
+    writeback.exported_kernel.cache_clear()
+    writeback.exported_kernel(N, L, 24, R, "cpu")
+    assert len(list(kernel_cache.iterdir())) == 2

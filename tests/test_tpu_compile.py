@@ -1,0 +1,137 @@
+"""Compile for a v5e that is described, not attached: what the chip's
+compiler (Mosaic included) accepts and how much memory the program
+wants, at the benchmark cells' own sizes, with no chip time. Nothing
+runs, so nothing here is a result or a time. The topology is described
+inside a fixture (never at import: only one process may load the TPU's
+library, and every pytest worker imports this file), and all such tests
+live in this one file."""
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+L = 2048  # both cells: rows of 8 KB
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import os
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def shape(topo):
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    return lambda dims, dtype: jax.ShapeDtypeStruct(dims, dtype,
+                                                    sharding=one_chip)
+
+
+@pytest.fixture(autouse=True)
+def _no_compile_cache():
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without one (it would warn every time)
+    from jax.experimental.compilation_cache import compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", True)
+    compilation_cache.reset_cache()
+
+
+def test_writeback_kernel_compiles_in_place(shape, kernel_cache):
+    """The manual-DMA write-back at the KGE cell's shape, as the step
+    takes it (exported once, read back from the cache directory by the
+    next process): Mosaic takes it, and the donated pool is updated in
+    place (no pool-sized copy)."""
+    from adapm_tpu.ops import writeback
+    n_slots, n = 1_172_432, 131_072
+    made = writeback.exported_kernel(n_slots, L, n, 32)
+    (kept,) = kernel_cache.iterdir()
+    writeback.exported_kernel.cache_clear()  # as a later process
+    read = writeback.exported_kernel(n_slots, L, n, 32)
+    assert read is not made
+    assert read.mlir_module_serialized == made.mlir_module_serialized
+    assert [f.name for f in kernel_cache.iterdir()] == [kept.name]
+    compiled = jax.jit(read.call, donate_argnums=(0,)).lower(
+        shape((n_slots, L), jnp.float32), shape((n,), jnp.int32),
+        shape((n, L), jnp.float32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == n_slots * L * 4
+    assert mem.temp_size_in_bytes < (64 << 20)
+
+
+@pytest.mark.parametrize("n_slots, row, batch, calls", [
+    (1_172_432, L, (8192, 32), 2),      # the KGE cell at twice its batch
+    (1 << 20, 256, ((1 << 20) + 40,), 9),  # 1M rows and a last short call
+])
+def test_writeback_of_any_batch_compiles(n_slots, row, batch, calls, shape,
+                                         kernel_cache, monkeypatch):
+    """The step's write-back of one role with more rows than one kernel
+    call takes (writeback.MAX_POSITIONS: the codes of a call are one
+    SMEM operand, and 262,144 of them no longer fit a v5e's): as many
+    calls as slices, the pool still updated in place."""
+    from adapm_tpu.ops import fused
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    compiled = jax.jit(fused._kernel_writeback, donate_argnums=(0,)).lower(
+        shape((1, n_slots, row), jnp.float32), shape(batch, jnp.int32),
+        shape(batch, jnp.int32), shape((*batch, row // 2), jnp.float32),
+        shape((*batch, row // 2), jnp.float32), shape((), jnp.float32),
+        shape((), jnp.float32)).compile()
+    assert compiled.as_text().count("custom_call_target=\"tpu_custom_call\"") \
+        == calls
+    assert compiled.memory_analysis().alias_size_in_bytes == \
+        n_slots * row * 4
+
+
+# (model, main pools' slots, keys, batch, negatives a row, the parent's
+# temporaries in bytes: v5e compile of PR 24's step, PERF.md section 4)
+CELLS = {
+    "kge-wikidata5m": ((1_172_432, 840), 1_149_443, 4096, 32, 3.44e9),
+    "w2v-1bw": ((1_618_688,), 1_586_942, 8192, 5, 1.245e9),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(CELLS))
+def test_step_with_kernel_has_no_pool_sized_temporary(cell, shape,
+                                                      kernel_cache,
+                                                      monkeypatch):
+    """The replica-free fused step of each training cell, built as on a
+    TPU (the write-back kernel, exported): the pools stay aliased and
+    the temporaries do not grow by more than 64 MB over the parent's."""
+    from adapm_tpu.ops import fused
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    slots, num_keys, B, N, parent_temp = CELLS[cell]
+    if cell == "kge-wikidata5m":
+        from adapm_tpu.models.kge import make_kge_loss
+        loss, roles = make_kge_loss("complex", 0.0, 0.0), \
+            {"s": 0, "r": 1, "o": 0, "neg": 0}
+        alias = None
+    else:
+        from adapm_tpu.models.sgns import sgns_loss
+        loss, roles = sgns_loss, {"center": 0, "ctx": 0, "neg": 0}
+        alias = (shape((793_471,), jnp.float32),
+                 shape((793_471,), jnp.int32), shape((793_471,), jnp.int32))
+    body = fused._build_device_routed_body(
+        loss, roles, {r: L // 2 for r in roles}, 0, (), "neg", (B, N),
+        True, alias is not None)
+    small = shape((1, 8, L), jnp.float32)
+    pools = tuple((shape((1, n, L), jnp.float32), small, small)
+                  for n in slots)
+    compiled = jax.jit(body, donate_argnums=(0,)).lower(
+        pools, shape((4,), jnp.int32),
+        tuple(shape((num_keys,), jnp.int32) for _ in range(3)),
+        {r: shape((B,), jnp.int32) for r in roles if r != "neg"},
+        (shape((1 << 21,), jnp.int32), shape((), jnp.int32)), alias,
+        shape((2,), jnp.uint32), None, shape((), jnp.float32),
+        shape((), jnp.float32)).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= len(roles)
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= sum(slots) * L * 4
+    assert mem.temp_size_in_bytes <= parent_temp + (64 << 20)

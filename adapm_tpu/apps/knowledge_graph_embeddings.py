@@ -89,8 +89,14 @@ class KgeRun:
         self._pool_eval_topo = -1    # owned-tile cache topology version
         self._pool_eval_n = 0        # this rank's owned-entity count
         self._true_score = None
+        # the workers' device runners are built alike but for their
+        # shard, an operand of the step: they share their compiled
+        # programs (ops/fused.py DeviceRoutedRunner, `programs`)
+        self.loss_fn = make_kge_loss(args.model, args.self_adv_temp,
+                                     args.l2)
+        self._step_programs = {}
         self.runner = FusedStepRunner(
-            self.srv, make_kge_loss(args.model, args.self_adv_temp, args.l2),
+            self.srv, self.loss_fn,
             role_class={"s": self.ent_class, "r": self.rel_class,
                         "o": self.ent_class, "neg": self.ent_class},
             role_dim={"s": self.ent_dim, "r": self.rel_dim,
@@ -107,7 +113,7 @@ class KgeRun:
         if shard not in self._dev_runners:
             a = self.args
             self._dev_runners[shard] = DeviceRoutedRunner(
-                self.srv, make_kge_loss(a.model, a.self_adv_temp, a.l2),
+                self.srv, self.loss_fn,
                 role_class={"s": self.ent_class, "r": self.rel_class,
                             "o": self.ent_class, "neg": self.ent_class},
                 role_dim={"s": self.ent_dim, "r": self.rel_dim,
@@ -115,8 +121,27 @@ class KgeRun:
                 shard=shard, neg_role="neg",
                 neg_shape=(a.batch_size, a.neg_ratio),
                 neg_population=self.ekey(np.arange(self.E)),
-                neg_alias=self.neg_alias, seed=a.seed + shard)
+                neg_alias=self.neg_alias, seed=a.seed + shard,
+                programs=self._step_programs)
         return self._dev_runners[shard]
+
+    def precompile(self) -> int:
+        """`Server.precompile` with this app's sizes: an intent names at
+        most 2B entities and B relations; under --device_routes the
+        loop drives one kind of runner, a batch of B triples a step (a
+        --scan_steps window still compiles at its first use). Returns
+        how many planner programs ran."""
+        B = self.args.batch_size
+        need = {}
+        for cid, n in ((self.ent_class, 2 * B),
+                       (self.rel_class, min(B, self.R))):
+            need[cid] = need.get(cid, 0) + n
+        steps = []
+        if self.args.device_routes:
+            z = np.zeros(B, dtype=np.int64)
+            steps.append((self.device_runner(self.workers[0].shard),
+                          {"s": z, "r": z, "o": z}, None))
+        return self.srv.precompile(need, steps)
 
     # -- key helpers ---------------------------------------------------------
 
@@ -531,6 +556,7 @@ def open_run(args) -> KgeRun:
         srv.enable_sampling_support(
             lambda n, r: run.ekey(r.integers(0, run.E, n)),
             allowed_keys=run.ekey(np.arange(run.E)))
+    run.precompile()
     return run
 
 

@@ -639,6 +639,12 @@ class SystemOptions:
                 "the sync.ef_residual_norm gauge — running a lossy "
                 "grid a quarter of fp32 wide with no metrics-visible "
                 "residual is a silent-quality-loss trap")
+        if self.cache_slots_per_shard < 0:
+            raise ValueError(
+                f"--sys.cache_slots_per_shard must be >= 0 (got "
+                f"{self.cache_slots_per_shard}): 0 sizes the replica "
+                f"pools like the main pool, a positive count is taken "
+                f"as given")
         if self.tier and self.tier_hot_rows < 8:
             raise ValueError(
                 f"--sys.tier.hot_rows must be >= 8 (got "
@@ -820,6 +826,12 @@ class SystemOptions:
                        dest="sys_collective_cadence", type=int, default=0)
         g.add_argument("--sys.main_over_alloc", dest="sys_main_over_alloc",
                        type=float, default=1.25)
+        g.add_argument("--sys.cache_slots_per_shard",
+                       dest="sys_cache_slots_per_shard", type=int,
+                       default=0,
+                       help="replica (cache + delta) slots a shard holds "
+                            "per length class; 0 = as many as it has "
+                            "main-pool keys")
         g.add_argument("--sys.optimistic_routing",
                        dest="sys_optimistic_routing", type=int, default=1)
         g.add_argument("--sys.prefetch", dest="sys_prefetch", type=int,
@@ -996,6 +1008,7 @@ class SystemOptions:
             collective_bucket=args.sys_collective_bucket,
             collective_cadence=args.sys_collective_cadence,
             main_over_alloc=args.sys_main_over_alloc,
+            cache_slots_per_shard=args.sys_cache_slots_per_shard,
             optimistic_routing=bool(args.sys_optimistic_routing),
             prefetch=bool(args.sys_prefetch),
             prefetch_max_batches=args.sys_prefetch_max_batches,

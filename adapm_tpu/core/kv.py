@@ -258,6 +258,13 @@ class Server:
         self._h_quiesce = self.obs.histogram("kv.quiesce_s")
         self._h_intent = self.obs.histogram("kv.intent_s")
         self._h_clock = self.obs.histogram("kv.advance_clock_s")
+        # the planner's two device-program phases (one observation per
+        # call of _relocate_to / _sync_replicas), and the wire bytes the
+        # sync programs shipped, as a counter
+        self._h_relocate = self.obs.histogram("kv.relocate_s")
+        self._h_sync_replicas = self.obs.histogram("kv.sync_replicas_s")
+        self._c_sync_bytes = self.obs.counter("sync.bytes_shipped_total",
+                                              unit="bytes")
         # collective wait-time histograms, observed by the (server-less)
         # control plane via observe_global (parallel/control.py) and by
         # Server.barrier below
@@ -1142,7 +1149,8 @@ class Server:
         caller's classification of the next channel instead of
         serializing behind the lock."""
         mode = self.opts.sync_compress if compress else "off"
-        with self._span("kv.sync_replicas"), self._lock:
+        with self._span("kv.sync_replicas", self._h_sync_replicas), \
+                self._lock:
             ab = self.ab
             karr = np.ascontiguousarray(keys, dtype=np.int64)
             sarr = np.ascontiguousarray(shards, dtype=np.int32)
@@ -1165,9 +1173,11 @@ class Server:
                     o_sh, o_sl = o_sh[ok], o_sl[ok]
                     if not ok.any():
                         continue
-                self.stores[cid].sync_replicas(ss, r_cs, o_sh, o_sl,
-                                               threshold=threshold,
-                                               compress=mode)
+                st = self.stores[cid]
+                shipped = st.sync_bytes_shipped
+                st.sync_replicas(ss, r_cs, o_sh, o_sl,
+                                 threshold=threshold, compress=mode)
+                self._c_sync_bytes.inc(st.sync_bytes_shipped - shipped)
 
     def _drop_replicas(self, keys: np.ndarray,
                        shards: np.ndarray) -> None:
@@ -1234,7 +1244,7 @@ class Server:
                 pol.guard_blocked("reloc")
         demoted = np.empty(0, dtype=np.int64)
         n_moved = 0
-        with self._span("kv.relocate"), self._lock:
+        with self._span("kv.relocate", self._h_relocate), self._lock:
             ab = self.ab
             # dedup: a duplicate key would double-free its old main slot in
             # relocate_batch (the drain path dedups in Worker.intent, but
@@ -1298,6 +1308,43 @@ class Server:
             dc.record_move(int(dest), n_moved, int(len(demoted)),
                            moved_keys)
         return n_moved
+
+    def precompile(self, intent_keys: Dict[int, int], steps=()) -> int:
+        """Compile every program a run can reach before its timed loop
+        does: the ONE call an app makes once its server, workers and
+        runners stand (the KGE app's `open_run`).
+
+        The planner's bucketed programs (ShardedStore.precompile_planner):
+        for each length class `cid` whose keys the application signals
+        intent for, `intent_keys[cid]` is the most keys of it one intent
+        names: that bounds a relocation and a replica creation; a sync
+        ships at most the live replicas of the class, one for each
+        shard, cache slot and key. One shard never relocates or
+        replicates: none of them runs.
+
+        `steps`: one `(runner, role_keys, aux)` for each KIND of
+        fused-step runner the loop drives (runners built alike share
+        their programs: one of them stands for all); each compiles its
+        per-step variants (DeviceRoutedRunner.precompile).
+
+        Returns how many planner programs ran."""
+        ran = 0
+        if self.num_shards > 1:
+            o = self.opts
+            variants = sorted({(0.0, "off"),
+                               (float(o.sync_threshold), o.sync_compress)})
+            with self._lock:
+                for cid, n in sorted(intent_keys.items()):
+                    st = self.stores[cid]
+                    in_class = int((self.ab.key_class == cid).sum())
+                    ran += st.precompile_planner(
+                        moved=min(n, in_class),
+                        synced=self.num_shards * min(st.cache_slots,
+                                                     in_class),
+                        sync_variants=variants)
+        for runner, role_keys, aux in steps:
+            runner.precompile(role_keys, aux)
+        return ran
 
     # -- lifecycle -----------------------------------------------------------
 

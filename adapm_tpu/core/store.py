@@ -527,6 +527,46 @@ class ShardedStore:
         self.main, self.delta = self.port.relocate(
             self.main, self.delta, *a)
 
+    def precompile_planner(self, moved: int, synced: int,
+                           sync_variants=((0.0, "off"),)) -> int:
+        """Run the planner's three bucketed programs once at every bucket
+        size they can be called with, so that none compiles later, inside
+        a timed loop: `replica_create` and `relocate_rows` up to `moved`
+        rows a call, `sync_replicas` (each (threshold, compress) variant
+        given) up to `synced`. The ladder is `bucket_size`'s: the floor,
+        then the powers of two above it. Every coordinate is out of
+        bounds, as a padded tail's is, so each program reads fill and
+        writes nothing: the pools come back bit for bit. Returns how
+        many programs ran. A tiered store dispatches other programs
+        (tier/coldpath.py) and is left alone."""
+        if self.res is not None:
+            return 0
+
+        def ladder(top: int):
+            sizes, n = set(), 1
+            while n < 2 * max(1, top):
+                sizes.add(bucket_size(min(n, top), self.bucket_min))
+                n *= 2
+            return sorted(sizes)
+
+        ran = 0
+        for b in ladder(moved):
+            sh, oob = np.zeros(b, np.int32), np.full(b, OOB, np.int32)
+            self.cache, self.delta = self.port.replica_create(
+                self.main, self.cache, self.delta, sh, oob, sh, oob)
+            self.main, self.delta = self.port.relocate(
+                self.main, self.delta, sh, oob, sh, oob, sh, oob)
+            ran += 2
+        for b in ladder(synced):
+            sh, oob = np.zeros(b, np.int32), np.full(b, OOB, np.int32)
+            for threshold, compress in sync_variants:
+                out = self.port.sync_replicas(
+                    self.main, self.cache, self.delta, sh, oob, sh, oob,
+                    threshold=threshold, compress=compress)
+                self.main, self.cache, self.delta = out[:3]
+                ran += 1
+        return ran
+
     # -- cross-process helpers (parallel/pm.py GlobalPM) ---------------------
 
     def read_rows(self, which: str, sh, sl) -> np.ndarray:

@@ -193,9 +193,13 @@ class SyncStats:
               "relocations", "keys_synced", "keys_considered",
               "intents_processed")
 
-    def __init__(self):
+    def __init__(self, counters=None):
+        """`counters`: field -> registry counter moved with the field (a
+        gauge of a field is a level; a counter's growth over a window
+        is a rate a metric can read)."""
         import threading
         self.lock = threading.Lock()
+        self._counters = counters or {}
         # keys_considered: replicas examined by sync rounds (intent-live,
         # keep-partition); keys_synced: replicas actually SHIPPED to a
         # sync program after the dirty-delta filter. With sync_threshold
@@ -209,6 +213,8 @@ class SyncStats:
         with self.lock:
             for name, n in deltas.items():
                 setattr(self, name, getattr(self, name) + n)
+                if name in self._counters:
+                    self._counters[name].inc(n)
 
 
 class SyncManager:
@@ -249,12 +255,20 @@ class SyncManager:
             quantile=opts.timing_quantile,
             rounds_lookahead=opts.timing_rounds_lookahead,
             enabled=opts.time_intent_actions)
-        self.stats = SyncStats()
+        reg = server.obs
+        self.stats = SyncStats({
+            "relocations": reg.counter("sync.relocations_total",
+                                       unit="keys"),
+            "replicas_created": reg.counter("sync.replicas_created_total",
+                                            unit="replicas"),
+            "replicas_dropped": reg.counter("sync.replicas_dropped_total",
+                                            unit="replicas"),
+            "keys_synced": reg.counter("sync.keys_shipped_total",
+                                       unit="keys")})
         # obs wiring (docs/OBSERVABILITY.md): round latency, replica
         # staleness in clocks, and SyncStats mirrored as callable gauges
         # so metrics_snapshot()'s sync section is complete without
         # touching the counters the rest of this file maintains
-        reg = server.obs
         self._h_round = reg.histogram("sync.round_s")
         # staleness = worker clocks elapsed since the channel's previous
         # sync round, observed once per round that refreshed replicas

@@ -296,6 +296,23 @@ class DeviceRouter:
         self.owner = None      # [num_keys] int32
         self.slot = None       # [num_keys] int32
         self.cache_row = None  # [num_keys] int32 (this shard's replica slots)
+        # what a placement change costs this worker on the host: every
+        # re-upload of the mirrors (and of the runner's local sampling
+        # index) is one observation; the bytes count every device a
+        # replicated array is put to
+        self._h_refresh = server.obs.histogram("fused.route_refresh_s",
+                                               shared=True)
+        self._c_refresh = server.obs.counter("fused.route_refresh_total",
+                                             shared=True)
+        self._c_upload = server.obs.counter(
+            "fused.route_upload_bytes_total", unit="bytes", shared=True)
+
+    def _put_counted(self, arr):
+        """`put_replicated` of one mirror, its bytes counted once for
+        each device it lands on."""
+        ctx = self.server.ctx
+        self._c_upload.inc(arr.nbytes * ctx.num_shards)
+        return ctx.put_replicated(arr)
 
     def refresh(self):
         srv = self.server
@@ -303,8 +320,20 @@ class DeviceRouter:
                srv.tier.epoch if srv.tier is not None else -1)
         if self._version == ver and self.owner is not None:
             return
+        with srv._span("fused.route_refresh", self._h_refresh):
+            self._refresh(ver)
+        self._c_refresh.inc()
+
+    def _refresh(self, ver):
+        srv = self.server
         ab = srv.ab
-        put = srv.ctx.put_replicated  # the staging rule, mesh.py
+        # SNAPSHOTS of the addressbook's tables, taken here under the
+        # server lock: the planner changes those arrays in place, a
+        # device_put reads its host buffer until the transfer is done,
+        # and with rounds on the prefetch thread the next relocation can
+        # land before that. A step then routed by placement it was not
+        # ordered after, and read a row's old (or still empty) place
+        put = lambda arr: self._put_counted(np.array(arr))  # noqa: E731
         self.owner = put(ab.owner)
         # tiered storage: the step indexes the DEVICE hot pool, so the
         # slot mirror carries hot-pool ROWS (composed against the
@@ -322,10 +351,11 @@ class DeviceRouter:
         return self.owner, self.slot, self.cache_row
 
 
-def _route_on_device(tables, keys, shard: int):
+def _route_on_device(tables, keys):
     """In-jit route resolution: the device-side twin of Server._route
-    (and native adapm_route). keys int32/int64 device array."""
-    owner, slot, cache_row = tables
+    (and native adapm_route). keys int32/int64 device array; `tables`
+    ends in the worker's shard, an int32 scalar operand."""
+    owner, slot, cache_row, shard = tables
     o_sh = owner[keys]
     cs = cache_row[keys]
     use_c = cs >= 0
@@ -338,7 +368,6 @@ def _route_on_device(tables, keys, shard: int):
 def make_device_routed_step(loss_fn: Callable[..., jnp.ndarray],
                             role_class: Dict[str, int],
                             role_dim: Dict[str, int],
-                            shard: int,
                             frozen_roles: Sequence[str] = (),
                             neg_role: str = None,
                             neg_shape: Tuple[int, ...] = None,
@@ -347,10 +376,15 @@ def make_device_routed_step(loss_fn: Callable[..., jnp.ndarray],
     """Fused step that resolves routing in-program from device table
     mirrors. Signature of the returned step:
 
-        step(pools, tables, keys, local_index, rng_key, aux, lr, eps)
+        step(pools, locstat, tables, keys, local_index, alias, rng_key,
+             aux, lr, eps)
           pools       tuple per class of (main, cache, delta)  [donated]
-          tables      (owner, slot, cache_row) device mirrors — key-indexed
-                      global arrays, shared by all length classes
+          tables      (owner, slot, cache_row, shard): the device mirrors
+                      (key-indexed global arrays, shared by all length
+                      classes; cache_row is the worker shard's) and the
+                      worker's shard as an int32 scalar. The shard is an
+                      OPERAND, so the workers of a server run one
+                      compiled step (DeviceRoutedRunner shares it)
           keys        dict role -> device int array (raw PM keys)
           local_index [L] int32 device array of locally-resident keys for
                       on-device negative sampling (None disables)
@@ -376,7 +410,7 @@ def make_device_routed_step(loss_fn: Callable[..., jnp.ndarray],
     planner hasn't replicated anything here).
     """
     body = _build_device_routed_body(
-        loss_fn, role_class, role_dim, shard, frozen_roles, neg_role,
+        loss_fn, role_class, role_dim, frozen_roles, neg_role,
         neg_shape, no_replicas, neg_alias)
     # donate the pools only: donating the 4-scalar locstat accumulator
     # saves nothing and its aliased buffer has been observed returning
@@ -388,7 +422,6 @@ def make_device_routed_step(loss_fn: Callable[..., jnp.ndarray],
 def make_device_routed_scan(loss_fn: Callable[..., jnp.ndarray],
                             role_class: Dict[str, int],
                             role_dim: Dict[str, int],
-                            shard: int,
                             frozen_roles: Sequence[str] = (),
                             neg_role: str = None,
                             neg_shape: Tuple[int, ...] = None,
@@ -406,7 +439,7 @@ def make_device_routed_scan(loss_fn: Callable[..., jnp.ndarray],
     alias, rng_keys[K], aux[K,...]|None, lr, eps)
     -> (pools, locstat, losses[K])."""
     body = _build_device_routed_body(
-        loss_fn, role_class, role_dim, shard, frozen_roles, neg_role,
+        loss_fn, role_class, role_dim, frozen_roles, neg_role,
         neg_shape, no_replicas, neg_alias)
 
     # pools-only donation, same rationale as make_device_routed_step
@@ -431,7 +464,7 @@ def make_device_routed_scan(loss_fn: Callable[..., jnp.ndarray],
     return default_port().compile(scan, donate_argnums=(0,))
 
 
-def _build_device_routed_body(loss_fn, role_class, role_dim, shard,
+def _build_device_routed_body(loss_fn, role_class, role_dim,
                               frozen_roles, neg_role, neg_shape,
                               no_replicas, neg_alias):
     """The un-jitted single-step body shared by make_device_routed_step
@@ -471,12 +504,13 @@ def _build_device_routed_body(loss_fn, role_class, role_dim, shard,
         # local when this worker's shard owns the row or holds a replica
         n_total = 0
         n_local = jnp.int32(0)
+        shard = tables[3]  # the worker's, an int32 scalar operand
         for r in roles:
             cid = role_class[r]
             main, cache, delta = pools[cid]
             n_total += keys[r].size
             if no_replicas:
-                owner, slot, _ = tables
+                owner, slot = tables[:2]
                 with jax.named_scope("adapm_route"):
                     o_sh, o_sl = owner[keys[r]], slot[keys[r]]
                 routes[r] = (o_sh, o_sl)
@@ -486,7 +520,7 @@ def _build_device_routed_body(loss_fn, role_class, role_dim, shard,
                 n_local += jnp.sum(o_sh == shard, dtype=jnp.int32)
                 continue
             with jax.named_scope("adapm_route"):
-                routes[r] = _route_on_device(tables, keys[r], shard)
+                routes[r] = _route_on_device(tables, keys[r])
             with jax.named_scope("adapm_gather"):
                 rows[r] = _read_rows(main, cache, delta, routes[r])
             o_sh, use_c = routes[r][0], routes[r][4]
@@ -563,13 +597,23 @@ class DeviceRoutedRunner:
                  role_dim: Dict[str, int], shard: int = 0,
                  frozen_roles: Sequence[str] = (), neg_role: str = None,
                  neg_shape: Tuple[int, ...] = None,
-                 neg_population=None, neg_alias=None, seed: int = 0):
+                 neg_population=None, neg_alias=None, seed: int = 0,
+                 programs: Optional[Dict] = None):
         """`neg_alias=(prob, alias)` (models/sgns.py build_alias_table)
         switches on-device negative sampling to the app's non-uniform
         distribution over `neg_population` (position i of the population
         is drawn with prob ~ weight i), with a Local-scheme snap to
-        locally-resident keys."""
+        locally-resident keys.
+
+        `programs`: a dict the caller hands to every runner it builds
+        ALIKE (same loss function, roles, shapes and sampler; only
+        `shard` and `seed` may differ). The worker's shard is an operand
+        of the compiled step, so such runners run the same programs: the
+        first to need a variant compiles it into the dict and the others
+        find it there, and a server with four workers compiles each
+        variant once and not four times. None: this runner's own."""
         self.server = server
+        self._programs = {} if programs is None else programs
         self.shard = shard
         self.role_class = role_class
         self.frozen_roles = frozenset(frozen_roles)
@@ -634,23 +678,87 @@ class DeviceRoutedRunner:
         self._c_wb_kernel_rows = server.obs.counter(
             "fused.writeback_kernel_rows_total", unit="rows", shared=True)
         self._wb_rows = None  # (all, kernel's) a step; set on first step
+        # the locality accumulator's first two entries as counters (rows
+        # the steps touched / found on the worker's shard), moved at each
+        # drain: what a per-layer metric reads (PERF.md section 3)
+        self._c_rows = server.obs.counter("fused.rows_total", unit="rows",
+                                          shared=True)
+        self._c_rows_local = server.obs.counter(
+            "fused.rows_local_total", unit="rows", shared=True)
+        # of both, the rows of the role the step samples from the
+        # worker's own local index: local by construction, so the share
+        # that placement earns is (local - sampled) / (total - sampled)
+        self._c_rows_sampled = server.obs.counter(
+            "fused.rows_sampled_total", unit="rows", shared=True)
+        self._sampled_pending = 0  # since the last drain
+        # the worker's shard as the step's operand (the last of `tables`)
+        self._shard_dev = server.ctx.put_replicated(np.int32(shard))
         self._mk_kwargs = dict(
             loss_fn=loss_fn, role_class=role_class, role_dim=role_dim,
-            shard=shard, frozen_roles=frozen_roles, neg_role=neg_role,
+            frozen_roles=frozen_roles, neg_role=neg_role,
             neg_shape=neg_shape, neg_alias=self._alias is not None)
-        mk = lambda nr: make_device_routed_step(  # noqa: E731
-            no_replicas=nr, **self._mk_kwargs)
-        self.step_fn = mk(False)
+        self.step_fn = self._program(make_device_routed_step,
+                                     no_replicas=False)
         # replica-free specialization: 1/3 the gather traffic; selected per
         # step while this shard holds no replicas
-        self._step_fn_norep = mk(True)
-        # K-step scan variants, built lazily per (no_replicas, has_aux)
-        self._scan_fns: Dict[Tuple[bool, bool], Callable] = {}
+        self._step_fn_norep = self._program(make_device_routed_step,
+                                            no_replicas=True)
         self._rep_version = -1
         self._has_replicas = True
         self.steps = 0
         if getattr(server, "prefetch", None) is not None:
             server.prefetch.register_refresher(self._prefetch_refresh)
+
+    def _program(self, make, **variant):
+        """The compiled program `make(**variant, **self._mk_kwargs)`,
+        kept in `programs` (see __init__) under the variant's name."""
+        key = (make.__name__,) + tuple(sorted(variant.items()))
+        if key not in self._programs:
+            self._programs[key] = make(**variant, **self._mk_kwargs)
+        return self._programs[key]
+
+    def _tables(self):
+        """The step's `tables` operand: the router's mirrors and this
+        worker's shard."""
+        return self.router.tables() + (self._shard_dev,)
+
+    def precompile(self, role_keys: Dict[str, np.ndarray],
+                   aux=None) -> None:
+        """Compile (or fetch from the compile cache) the step variants
+        this runner can reach, before a timed loop meets them: the
+        replica-free variant, and on a server of several shards the
+        replica variant too (one shard never holds a replica). Each runs
+        once, on a batch shaped like `role_keys` and an `aux` like the
+        steps', against a slot table that is out of bounds everywhere:
+        every gather fills zeros and every write-back is dropped, so the
+        pools come back bit for bit, and neither the RNG sequence nor
+        the locality counts move."""
+        srv = self.server
+        with srv._lock:
+            owner, _, cache_row, shard = self._tables()
+            nowhere = srv.ctx.put_replicated(
+                np.full(srv.num_keys, OOB, np.int32))
+            no_cache = cache_row if srv.num_shards == 1 else \
+                srv.ctx.put_replicated(np.full(srv.num_keys, -1, np.int32))
+            local_index = self._local_neg_index() \
+                if self.neg_role is not None else None
+            keys = self._upload_keys(
+                {r: np.zeros(np.shape(k), _key_dtype(srv.num_keys))
+                 for r, k in role_keys.items()})
+            fns = [self._step_fn_norep]
+            if srv.num_shards > 1:
+                fns.append(self.step_fn)
+            for fn in fns:
+                pools = tuple((s.main, s.cache, s.delta)
+                              for s in srv.stores)
+                with srv.exec.track("main"), _GATE:
+                    pools, _, _ = fn(
+                        pools, self._locstat,
+                        (owner, nowhere, no_cache, shard), keys,
+                        local_index, self._alias, self._rng, aux,
+                        self._scalar(0.0), self._scalar(1e-10))
+                    for st, (m, c, d) in zip(srv.stores, pools):
+                        st.main, st.cache, st.delta = m, c, d
 
     def _prefetch_refresh(self) -> None:
         """Called by the prefetch pipeline (under the server lock) after
@@ -765,6 +873,13 @@ class DeviceRoutedRunner:
         if no_replicas:  # the only variant the kernel is compiled into
             self._c_wb_kernel_rows.inc(kernel_rows * steps)
 
+    def _count_sampled(self, steps: int) -> None:
+        """Count the rows `steps` dispatched steps drew from the local
+        index. A fallback draw (nothing local) is over the whole
+        population: its rows count like any other role's."""
+        if self.neg_role is not None and not self._li_fallback:
+            self._sampled_pending += int(np.prod(self._neg_shape)) * steps
+
     def _drain_locstat(self) -> None:
         """Fold the device accumulator into the host int64 totals and reset
         it. A fetch syncs the device, so this runs only at reporting
@@ -774,6 +889,10 @@ class DeviceRoutedRunner:
         with self.server._span("fused.locstat_drain"):
             vals = np.asarray(self._locstat, dtype=np.int64)
             self._loc_host += vals
+            self._c_rows.inc(int(vals[0]))
+            self._c_rows_local.inc(int(vals[1]))
+            self._c_rows_sampled.inc(self._sampled_pending)
+            self._sampled_pending = 0
             self._locstat = self.server.ctx.put_replicated(
                 np.zeros(4, np.int32))
             self._c_drains.inc()
@@ -808,6 +927,16 @@ class DeviceRoutedRunner:
         if self._li_version == li_ver and \
                 self._local_index is not None:
             return self._local_index
+        # part of what a placement change costs: the same span and
+        # counters as the table mirrors (DeviceRouter.refresh)
+        router = self.router
+        with srv._span("fused.route_refresh", router._h_refresh):
+            self._build_local_neg_index(li_ver)
+        router._c_refresh.inc()
+        return self._local_index
+
+    def _build_local_neg_index(self, li_ver) -> None:
+        srv = self.server
         ab = srv.ab
         pop = self._neg_population if self._neg_population is not None \
             else np.arange(srv.num_keys, dtype=np.int64)
@@ -868,10 +997,9 @@ class DeviceRoutedRunner:
         kdt = _key_dtype(srv.num_keys)
         padded = np.full(cap, np.iinfo(kdt).max, dtype=kdt)
         padded[: len(idx)] = idx
-        self._local_index = (srv.ctx.put_replicated(padded),
+        self._local_index = (self.router._put_counted(padded),
                              jnp.int32(len(idx)))
         self._li_version = li_ver
-        return self._local_index
 
     def _check_batch(self, role_keys: Dict[str, np.ndarray]) -> None:
         srv = self.server
@@ -917,7 +1045,7 @@ class DeviceRoutedRunner:
                 # epoch, which router.tables() below picks up)
                 srv.tier.pin_step_keys(self.role_class, role_keys)
             self._note_step_writes(role_keys)
-            tables = self.router.tables()
+            tables = self._tables()
             local_index = self._local_neg_index() \
                 if self.neg_role is not None else None
             self._mark_neg_writes()
@@ -942,19 +1070,15 @@ class DeviceRoutedRunner:
                     st.main, st.cache, st.delta = m, c, d
             self.steps += 1
             self._count_writeback(role_keys, 1, no_replicas)
+            self._count_sampled(1)
             self._ensure_drain_every(role_keys)
             if self.steps % self._drain_every == 0:
                 self._drain_locstat()
         return loss
 
     def _scan_fn(self, no_replicas: bool, has_aux: bool):
-        key = (no_replicas, has_aux)
-        fn = self._scan_fns.get(key)
-        if fn is None:
-            fn = self._scan_fns[key] = make_device_routed_scan(
-                no_replicas=no_replicas, has_aux=has_aux,
-                **self._mk_kwargs)
-        return fn
+        return self._program(make_device_routed_scan,
+                             no_replicas=no_replicas, has_aux=has_aux)
 
     def run_scan(self, batches: Sequence[Dict[str, np.ndarray]], auxes,
                  lr: float, eps: float = 1e-10) -> np.ndarray:
@@ -990,7 +1114,7 @@ class DeviceRoutedRunner:
                 srv.tier.pin_step_keys(self.role_class, union)
             for b in batches:
                 self._note_step_writes(b)
-            tables = self.router.tables()
+            tables = self._tables()
             local_index = self._local_neg_index() \
                 if self.neg_role is not None else None
             self._mark_neg_writes()
@@ -1022,6 +1146,7 @@ class DeviceRoutedRunner:
                     st.main, st.cache, st.delta = m, c, d
             self.steps += K
             self._count_writeback(batches[0], K, no_replicas)
+            self._count_sampled(K)
             self._ensure_drain_every(batches[0])
             if self.steps // self._drain_every != \
                     (self.steps - K) // self._drain_every:

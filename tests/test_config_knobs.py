@@ -230,6 +230,35 @@ def test_tier_knobs_round_trip_and_rejection():
     SystemOptions.from_args(p.parse_args(["--sys.tier.hot_rows", "4"]))
 
 
+def test_cache_slots_flag_round_trip_and_rejection():
+    """--sys.cache_slots_per_shard reaches the option the stores size
+    their replica pools by, the app's make_server passes it on, and a
+    negative count fails at parse time."""
+    import argparse
+
+    import pytest
+
+    from adapm_tpu.apps import knowledge_graph_embeddings as kge
+    from adapm_tpu.config import SystemOptions
+    p = argparse.ArgumentParser()
+    SystemOptions.add_arguments(p)
+    assert SystemOptions.from_args(
+        p.parse_args([])).cache_slots_per_shard == 0
+    on = SystemOptions.from_args(
+        p.parse_args(["--sys.cache_slots_per_shard", "24"]))
+    assert on.cache_slots_per_shard == 24
+    with pytest.raises(ValueError, match="cache_slots_per_shard"):
+        SystemOptions.from_args(
+            p.parse_args(["--sys.cache_slots_per_shard", "-1"]))
+    args = kge.build_parser().parse_args(
+        ["--num_shards", "2", "--sys.cache_slots_per_shard", "24"])
+    srv = kge.make_server(args, 64, 4, num_workers=2)
+    try:
+        assert [st.cache_slots for st in srv.stores] == [24]
+    finally:
+        srv.shutdown()
+
+
 def test_compression_knobs_round_trip_and_rejection():
     """--sys.tier.cold_dtype / --sys.sync.compress parse into the
     options the compression plane consumes, and invalid names or

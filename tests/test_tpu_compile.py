@@ -119,14 +119,15 @@ def test_step_with_kernel_has_no_pool_sized_temporary(cell, shape,
         alias = (shape((793_471,), jnp.float32),
                  shape((793_471,), jnp.int32), shape((793_471,), jnp.int32))
     body = fused._build_device_routed_body(
-        loss, roles, {r: L // 2 for r in roles}, 0, (), "neg", (B, N),
+        loss, roles, {r: L // 2 for r in roles}, (), "neg", (B, N),
         True, alias is not None)
     small = shape((1, 8, L), jnp.float32)
     pools = tuple((shape((1, n, L), jnp.float32), small, small)
                   for n in slots)
     compiled = jax.jit(body, donate_argnums=(0,)).lower(
         pools, shape((4,), jnp.int32),
-        tuple(shape((num_keys,), jnp.int32) for _ in range(3)),
+        tuple(shape((num_keys,), jnp.int32) for _ in range(3))
+        + (shape((), jnp.int32),),
         {r: shape((B,), jnp.int32) for r in roles if r != "neg"},
         (shape((1 << 21,), jnp.int32), shape((), jnp.int32)), alias,
         shape((2,), jnp.uint32), None, shape((), jnp.float32),
@@ -135,3 +136,4 @@ def test_step_with_kernel_has_no_pool_sized_temporary(cell, shape,
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= sum(slots) * L * 4
     assert mem.temp_size_in_bytes <= parent_temp + (64 << 20)
+

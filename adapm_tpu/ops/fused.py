@@ -386,21 +386,26 @@ def make_device_routed_step(loss_fn: Callable[..., jnp.ndarray],
                       OPERAND, so the workers of a server run one
                       compiled step (DeviceRoutedRunner shares it)
           keys        dict role -> device int array (raw PM keys)
-          local_index [L] int32 device array of locally-resident keys for
-                      on-device negative sampling (None disables)
+          local_index (padded sorted index [capacity], valid count) of the
+                      locally-resident keys for uniform on-device
+                      negative sampling (None disables; None with
+                      `neg_alias`, which reads `alias` instead)
           rng_key     jax PRNG key for the device-side sampler
 
-    When `neg_role` is set and local_index is non-empty, that role's keys
+    When `neg_role` is set and local_index is given, that role's keys
     are DRAWN in-program: uniform positions into local_index — the Local
     sampling scheme (core/sampling.py LocalSampling) executed on device.
 
     `neg_alias=True` switches the draw to a NON-uniform app distribution:
-    the step takes an extra `alias` argument (prob[V], alias[V], key[V]
+    the step takes an extra `alias` argument (prob[V], alias[V], snap[V]
     device arrays — a Vose table, models/sgns.py build_alias_table, e.g.
-    unigram^0.75 for word2vec) and draws candidate keys from it, then
-    SNAPS each to the nearest locally-resident key via a searchsorted
-    probe — the device twin of LocalSampling._snap (binary search replaces
-    the reference's linear probe, sampling.h:476-505).
+    unigram^0.75 for word2vec), draws an alias position and reads its key
+    out of `snap`: ONE gather. `snap[j]` is position j's key of the
+    population already SNAPPED to the nearest locally-resident key (the
+    Local scheme's LocalSampling._snap, sampling.h:476-505). The snap
+    depends on placement only, not on the draw, so the host computes it
+    where placement changes (DeviceRoutedRunner._snap_table) and the
+    program searches nothing.
 
     `no_replicas=True` compiles the replica-free specialization: reads touch
     only the main pool (1/3 of the gather traffic) and updates scatter only
@@ -478,20 +483,14 @@ def _build_device_routed_body(loss_fn, role_class, role_dim,
         keys = dict(keys)
         with jax.named_scope("adapm_sampler"):
             if neg_role is not None and neg_alias:
-                prob, alias_t, key_table = alias
+                # snap_table[j]: alias position j's key, snapped to the
+                # locally-resident population by the host
+                prob, alias_t, snap_table = alias
                 k1, k2 = jax.random.split(rng_key)
                 u = jax.random.randint(k1, neg_shape, 0, prob.shape[0])
                 v = jax.random.uniform(k2, neg_shape)
-                cand = key_table[jnp.where(v < prob[u], u, alias_t[u])]
-                if local_index is not None:
-                    # Local-scheme snap: padded index is sorted with an
-                    # int-max sentinel tail, so searchsorted lands in
-                    # [0, count] and wraps (sampling.h:494)
-                    idx, count = local_index
-                    pos = jnp.searchsorted(idx, cand)
-                    pos = jnp.where(pos >= count, 0, pos)
-                    cand = idx[pos]
-                keys[neg_role] = cand
+                keys[neg_role] = snap_table[
+                    jnp.where(v < prob[u], u, alias_t[u])]
             elif neg_role is not None and local_index is not None:
                 idx, count = local_index  # padded index + valid count
                 pos = jax.random.randint(rng_key, neg_shape, 0, count)
@@ -603,7 +602,9 @@ class DeviceRoutedRunner:
         switches on-device negative sampling to the app's non-uniform
         distribution over `neg_population` (position i of the population
         is drawn with prob ~ weight i), with a Local-scheme snap to
-        locally-resident keys.
+        locally-resident keys. The snap is a table over the alias
+        positions that this runner rebuilds where placement changes
+        (`_snap_table`); the compiled step reads it and searches nothing.
 
         `programs`: a dict the caller hands to every runner it builds
         ALIKE (same loss function, roles, shapes and sampler; only
@@ -622,29 +623,47 @@ class DeviceRoutedRunner:
         self._li_fallback = False  # set by _local_neg_index
         self._neg_shape = neg_shape
         self._rng = jax.random.PRNGKey(seed)
-        self._alias = None
+        # population the device sampler may draw from (Local scheme: the
+        # locally-resident slice of the allowed keys); None -> all keys
+        self._neg_population = self._key_pos = None
+        if neg_population is not None:
+            pop = np.asarray(neg_population, dtype=np.int64)
+            if neg_alias is None:
+                self._neg_population = np.unique(pop)
+            else:
+                # _key_pos: where each entry of the population as given
+                # (the key table, in any order) sits in the sorted one
+                self._neg_population, self._key_pos = np.unique(
+                    pop, return_inverse=True)
+        # the step's `alias` operand: (prob, alias, snap table). The snap
+        # table is the key table itself until _local_neg_index says that
+        # some key of the population is not resident here
+        self._alias = self._key_table = None
         if neg_alias is not None:
             assert neg_role is not None and neg_population is not None, \
                 "neg_alias needs neg_role and neg_population"
             prob, alias = neg_alias
-            key_table = np.asarray(neg_population,
-                                   dtype=_key_dtype(server.num_keys))
-            assert len(prob) == len(key_table), \
+            assert len(prob) == len(self._key_pos), \
                 "alias table must cover the population"
             put = server.ctx.put_replicated
-            self._alias = (put(prob), put(alias), put(key_table))
-        # population the device sampler may draw from (Local scheme: the
-        # locally-resident slice of the allowed keys); None -> all keys
-        self._neg_population = None if neg_population is None else \
-            np.unique(np.asarray(neg_population, dtype=np.int64))
+            self._key_table = put(np.asarray(
+                neg_population, dtype=_key_dtype(server.num_keys)))
+            self._alias = (put(prob), put(alias), self._key_table)
+            # snap tables built, and of the alias positions the share
+            # whose snapped key is not their own: whether the snap does
+            # anything in this deployment (0 and 0.0 on one shard)
+            self._c_snap_rebuilds = server.obs.counter(
+                "fused.neg_snap_rebuilds_total", shared=True)
+            self._g_snap_moved = server.obs.gauge(
+                "fused.neg_snap_moved_share", shared=True)
         if self._neg_population is not None and neg_role is not None:
             kc = server.ab.key_class[self._neg_population]
             assert (kc == role_class[neg_role]).all(), (
                 "neg_population spans length classes "
                 f"{np.unique(kc)} but role {neg_role} is class "
                 f"{role_class[neg_role]}")
-        self._local_index = None
-        self._li_version = -1
+        self._local_index = None  # uniform path: (padded index, count)
+        self._li_version = None
         # per-step RNG keys come from a batched split (one tiny device
         # dispatch per 64 steps instead of per step) and device scalars
         # are cached per value
@@ -916,32 +935,73 @@ class DeviceRoutedRunner:
         return self._has_replicas
 
     def _local_neg_index(self):
-        """(padded index [capacity], valid count) — padded to a power-of-two
-        capacity so placement changes don't change the jit shape (only a
-        capacity doubling recompiles). The index is sorted and the padding
-        tail carries the dtype max so the alias path's searchsorted snap
-        stays within the valid prefix."""
+        """The step's `local_index` operand, brought up to date with
+        placement (and tier residency). Uniform draws: (padded index
+        [capacity], valid count) of the locally-resident keys, sorted,
+        padded to a power-of-two capacity so placement changes don't
+        change the jit shape (only a capacity doubling recompiles).
+        Alias draws: None; the placement goes into the snap table, the
+        last of `self._alias`, which callers read AFTER this call."""
         srv = self.server
         li_ver = (srv.topology_version,
                   srv.tier.epoch if srv.tier is not None else -1)
-        if self._li_version == li_ver and \
-                self._local_index is not None:
-            return self._local_index
-        # part of what a placement change costs: the same span and
-        # counters as the table mirrors (DeviceRouter.refresh)
-        router = self.router
-        with srv._span("fused.route_refresh", router._h_refresh):
-            self._build_local_neg_index(li_ver)
-        router._c_refresh.inc()
+        if self._li_version != li_ver:
+            # part of what a placement change costs: the same span and
+            # counters as the table mirrors (DeviceRouter.refresh)
+            router = self.router
+            with srv._span("fused.route_refresh", router._h_refresh):
+                self._build_local_neg_index()
+            self._li_version = li_ver
+            router._c_refresh.inc()
         return self._local_index
 
-    def _build_local_neg_index(self, li_ver) -> None:
+    def _build_local_neg_index(self) -> None:
         srv = self.server
-        ab = srv.ab
         pop = self._neg_population if self._neg_population is not None \
             else np.arange(srv.num_keys, dtype=np.int64)
-        from ..base import NO_SLOT
+        local = self._local_neg_mask(pop)
+        if self._alias is not None:
+            self._alias = self._alias[:2] + (self._snap_table(pop, local),)
+            return
         from ..core.store import bucket_size
+        idx = pop[local]
+        cap = bucket_size(len(idx), minimum=64)
+        kdt = _key_dtype(srv.num_keys)
+        padded = np.full(cap, np.iinfo(kdt).max, dtype=kdt)
+        padded[: len(idx)] = idx
+        self._local_index = (self.router._put_counted(padded),
+                             jnp.int32(len(idx)))
+
+    def _snap_table(self, pop: np.ndarray, local: np.ndarray):
+        """The alias path's Local-scheme snap, for every alias position
+        at once: entry j is the smallest key of `pop[local]` that is >=
+        position j's key, wrapping to the smallest (sampling.h:494: what
+        a searchsorted into the sorted local keys would give each draw).
+        It depends on placement alone, so it is built here, where
+        placement changes, and the compiled step reads `snap[j]`.
+        `local` is a mask over the sorted population, so nothing is
+        searched: a reverse running minimum and one take, O(V). Where
+        every key is local (always so on one shard) the snap moves
+        nothing and the table is the key table the device holds already."""
+        if local.all():
+            self._g_snap_moved.set(0.0)
+            return self._key_table
+        self._g_snap_moved.set(1.0 - float(local[self._key_pos].mean()))
+        n = len(pop)
+        nxt = np.where(local, np.arange(n), n)
+        nxt = np.minimum.accumulate(nxt[::-1])[::-1]
+        nxt[nxt == n] = nxt[0]  # past the largest local key: wrap
+        self._c_snap_rebuilds.inc()
+        return self.router._put_counted(pop[nxt][self._key_pos].astype(
+            _key_dtype(self.server.num_keys)))
+
+    def _local_neg_mask(self, pop: np.ndarray) -> np.ndarray:
+        """Which keys of the sorted population `pop` the device sampler
+        may draw: those resident on this shard, or a fallback where none
+        is (never all False). Sets `_li_fallback`."""
+        srv = self.server
+        ab = srv.ab
+        from ..base import NO_SLOT
         local = (ab.owner[pop] == self.shard) | (
             ab.cache_slot[self.shard, pop] != NO_SLOT)
         if srv.tier is not None:
@@ -961,45 +1021,38 @@ class DeviceRoutedRunner:
                 owner_hot[m] = res.dev_row[o_sh[m], o_sl[m]] >= 0
             local = owner_hot | (
                 ab.cache_slot[self.shard, pop] != NO_SLOT)
-        idx = pop[local]
         # fallback flag feeds _mark_neg_writes: full-population draws can
         # scatter into OTHER shards' main rows, so write tracking must
         # widen beyond this shard
-        self._li_fallback = len(idx) == 0
-        if len(idx) == 0:
-            if srv.tier is not None:
-                # tiered: the untiered fallback (draw from the FULL
-                # population) would sample cold keys, whose mirror rows
-                # are OOB — reads would silently return zeros and
-                # scatters drop. Promote a bounded slice of the
-                # population (wherever its rows are owned) and draw
-                # from the device-resident subset; fail loudly if even
-                # that cannot produce one resident key.
-                cid = self.role_class[self.neg_role]
-                res = srv.stores[cid].res
-                take = pop[: 4096]
-                srv.tier.ensure_hot(cid, ab.owner[take], ab.slot[take])
-                o_sh, o_sl = ab.owner[pop], ab.slot[pop]
-                ok = o_sl >= 0
-                resident = np.zeros(len(pop), dtype=bool)
-                resident[ok] = res.dev_row[o_sh[ok], o_sl[ok]] >= 0
-                idx = pop[resident]
-                if len(idx) == 0:
-                    raise RuntimeError(
-                        "tiered negative sampling: no device-resident "
-                        "key in the population and promotion could not "
-                        "produce one (hot pool full of pinned rows?) — "
-                        "raise --sys.tier.hot_rows or signal intent on "
-                        "the sampling population")
-            else:
-                idx = pop  # nothing local: draw from the full population
-        cap = bucket_size(len(idx), minimum=64)
-        kdt = _key_dtype(srv.num_keys)
-        padded = np.full(cap, np.iinfo(kdt).max, dtype=kdt)
-        padded[: len(idx)] = idx
-        self._local_index = (self.router._put_counted(padded),
-                             jnp.int32(len(idx)))
-        self._li_version = li_ver
+        self._li_fallback = not local.any()
+        if not self._li_fallback:
+            return local
+        if srv.tier is None:
+            # nothing local: draw from the full population
+            return np.ones(len(pop), dtype=bool)
+        # tiered: the untiered fallback (draw from the FULL
+        # population) would sample cold keys, whose mirror rows
+        # are OOB — reads would silently return zeros and
+        # scatters drop. Promote a bounded slice of the
+        # population (wherever its rows are owned) and draw
+        # from the device-resident subset; fail loudly if even
+        # that cannot produce one resident key.
+        cid = self.role_class[self.neg_role]
+        res = srv.stores[cid].res
+        take = pop[: 4096]
+        srv.tier.ensure_hot(cid, ab.owner[take], ab.slot[take])
+        o_sh, o_sl = ab.owner[pop], ab.slot[pop]
+        ok = o_sl >= 0
+        resident = np.zeros(len(pop), dtype=bool)
+        resident[ok] = res.dev_row[o_sh[ok], o_sl[ok]] >= 0
+        if not resident.any():
+            raise RuntimeError(
+                "tiered negative sampling: no device-resident "
+                "key in the population and promotion could not "
+                "produce one (hot pool full of pinned rows?) — "
+                "raise --sys.tier.hot_rows or signal intent on "
+                "the sampling population")
+        return resident
 
     def _check_batch(self, role_keys: Dict[str, np.ndarray]) -> None:
         srv = self.server

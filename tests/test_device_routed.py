@@ -121,46 +121,236 @@ def test_alias_table_distribution():
     assert np.allclose(freq, p, atol=0.01), (freq, p)
 
 
-def test_device_alias_negative_sampling():
-    """Non-uniform on-device negatives: alias draw + Local-scheme snap
-    stays inside the locally-resident population and skews toward the
-    heavy head of the distribution."""
+def _old_snap(local_keys, cand):
+    """The Local-scheme snap as the compiled step used to search it, per
+    draw: the smallest local key >= the candidate, wrapping to the
+    smallest (`local_keys` sorted)."""
+    pos = np.searchsorted(local_keys, cand)
+    return local_keys[np.where(pos >= len(local_keys), 0, pos)]
+
+
+def _local_keys(srv, population, shard=0):
+    """The sorted keys of `population` resident on `shard` (the whole
+    population where none is: the sampler's fallback)."""
+    pop = np.unique(np.asarray(population, dtype=np.int64))
+    local = pop[(srv.ab.owner[pop] == shard) |
+                (srv.ab.cache_slot[shard, pop] >= 0)]
+    return local if len(local) else pop
+
+
+def _alias_draw(rng_key, shape, prob, alias_t):
+    """Alias positions as the compiled step draws them from `rng_key`."""
     import jax
-    srv, w = _make()
+    k1, k2 = jax.random.split(rng_key)
+    u = np.asarray(jax.random.randint(k1, shape, 0, len(prob)))
+    v = np.asarray(jax.random.uniform(k2, shape))
+    return np.where(v < np.asarray(prob)[u], u, np.asarray(alias_t)[u])
+
+
+def _alias_runner(num_keys, population, counts=None, num_shards=2, loss=None,
+                  seed=3):
+    """A server whose row k holds k in its first column (so a loss can
+    say which negatives it was handed), and shard 0's runner with alias
+    negatives over `population`. Keys start on shard key % num_shards."""
+    srv = adapm_tpu.setup(num_keys, 8, num_shards=num_shards,
+                          opts=SystemOptions(sync_max_per_sec=0,
+                                             cache_slots_per_shard=8))
+    w = srv.make_worker(0)
+    init = np.full((num_keys, 8), 1e-6, np.float32)
+    init[:, 0] = np.arange(num_keys)
+    w.set(np.arange(num_keys), init)
+    from adapm_tpu.models.sgns import build_alias_table
+    if counts is None:
+        counts = 1.0 + np.arange(len(population))[::-1]
+    dev = DeviceRoutedRunner(
+        srv, loss or _neg_loss, role_class={"a": 0, "b": 0, "neg": 0},
+        role_dim={"a": 4, "b": 4, "neg": 4}, shard=0,
+        neg_role="neg", neg_shape=(16, 3), neg_population=population,
+        neg_alias=build_alias_table(counts), seed=seed)
+    return srv, w, dev
+
+
+def _tell_negatives(seen):
+    """A loss that hands `seen` (aux, the negatives' keys) of every step
+    it computes, read from the rows' first column, which it keeps out
+    of the loss: no gradient, so no step changes it."""
+    import jax
 
     def loss(embs, aux):
-        pos = (embs["a"] * embs["b"]).sum(-1)
-        neg = (embs["a"][:, None, :] * embs["neg"]).sum(-1)
-        return (jax.nn.softplus(-pos) + jax.nn.softplus(neg).sum(-1)).mean()
+        jax.debug.callback(
+            lambda a, k: seen.append((int(a[0]), np.asarray(k, np.int64))),
+            aux, embs["neg"][..., 0])
+        return _neg_loss({r: v[..., 1:] for r, v in embs.items()}, aux)
+    return loss
 
-    from adapm_tpu.models.sgns import build_alias_table
-    counts = np.zeros(24)
+
+def test_device_alias_negative_sampling():
+    """Non-uniform on-device negatives: the alias path takes no local
+    index; its draws go through the snap table the runner holds, stay
+    inside the locally-resident population, and are the keys a search
+    of the local keys would give each draw."""
+    import jax
+    counts = np.ones(24)
     counts[:4] = 1000            # heavy head
-    counts[4:] = 1
-    dev = DeviceRoutedRunner(
-        srv, loss, role_class={"a": 0, "b": 0, "neg": 0},
-        role_dim={"a": 4, "b": 4, "neg": 4}, shard=0,
-        neg_role="neg", neg_shape=(16, 3),
-        neg_population=np.arange(24),
-        neg_alias=build_alias_table(counts))
+    srv, w, dev = _alias_runner(24, np.arange(24), counts, num_shards=8)
     rng = np.random.default_rng(2)
     batch = {"a": rng.integers(0, 24, 16).astype(np.int64),
              "b": rng.integers(0, 24, 16).astype(np.int64)}
     assert np.isfinite(float(dev(batch, None, 0.1)))
-    # draw through the step's sampler logic directly for the skew check
-    padded, count = dev._local_neg_index()
-    prob, alias_t, key_table = dev._alias
-    k1, k2 = jax.random.split(jax.random.PRNGKey(0))
-    u = jax.random.randint(k1, (4000,), 0, prob.shape[0])
-    v = jax.random.uniform(k2, (4000,))
-    import jax.numpy as jnp
-    cand = key_table[jnp.where(v < prob[u], u, alias_t[u])]
-    pos = jnp.searchsorted(padded, cand)
-    pos = jnp.where(pos >= count, 0, pos)
-    drawn = np.asarray(padded)[np.asarray(pos)]
-    idx = np.asarray(padded)[: int(count)]
-    assert np.isin(drawn, idx).all(), "snap left the local population"
+    assert dev._local_neg_index() is None
+    prob, alias_t, snap_table = dev._alias
+    j = _alias_draw(jax.random.PRNGKey(0), (4000,), prob, alias_t)
+    drawn = np.asarray(snap_table)[j]
+    local = _local_keys(srv, np.arange(24))
+    assert len(local) == 3  # keys 0, 8, 16 of 24 on 8 shards
+    assert np.isin(drawn, local).all(), "snap left the local population"
+    assert np.array_equal(drawn, _old_snap(local, np.arange(24)[j]))
     srv.shutdown()
+
+
+# population -> local keys on shard 0 of 2 are its EVEN members
+_EVEN, _ODD = np.arange(0, 400, 2), np.arange(1, 400, 2)
+SNAP_CASES = {
+    "all_local": _EVEN,
+    "quarter_local": np.concatenate([_EVEN[:50], _ODD[:150]]),
+    "one_local": np.concatenate([_ODD, [200]]),
+    "largest_only_wraps": np.concatenate([_ODD[:-1], [398]]),
+    "nothing_local_falls_back": _ODD,
+    "unsorted_key_table": np.random.default_rng(6).permutation(
+        np.concatenate([_EVEN[:60], _ODD[:120]])),
+}
+
+
+@pytest.mark.parametrize("case", sorted(SNAP_CASES))
+def test_snap_table_is_the_searched_snap(case):
+    """The snap table built where placement changes equals, for every
+    alias position, what the compiled step used to search for a draw of
+    that position: idx[wrap(searchsorted(idx, key_table))]."""
+    population = SNAP_CASES[case]
+    srv, w, dev = _alias_runner(400, population)
+    assert dev._local_neg_index() is None
+    local = _local_keys(srv, population)
+    n_local = int((population % 2 == 0).sum())
+    assert len(local) == (n_local or len(population))
+    want = _old_snap(local, population)
+    assert np.array_equal(np.asarray(dev._alias[2]), want)
+    moved = float((want != population).mean())
+    assert srv.obs.find("fused.neg_snap_moved_share").snap() == \
+        pytest.approx(moved)
+    # a table is built (and uploaded) only where the snap moves a key
+    assert (dev._alias[2] is dev._key_table) == (moved == 0.0)
+    assert srv.obs.find("fused.neg_snap_rebuilds_total").snap() == \
+        (moved > 0.0)
+    assert dev._li_fallback == (n_local == 0)
+    srv.shutdown()
+
+
+def _record_steps(dev):
+    """Wrap both compiled step variants as the benchmark's recorder
+    does: ten positional arguments; keeps (local_index, alias, rng_key)."""
+    steps = []
+    for name in ("step_fn", "_step_fn_norep"):
+        def recorded(pools, locstat, tables, keys, local_index, alias,
+                     rng_key, aux, lr, eps, _fn=getattr(dev, name)):
+            steps.append((local_index, alias, rng_key))
+            return _fn(pools, locstat, tables, keys, local_index, alias,
+                       rng_key, aux, lr, eps)
+        setattr(dev, name, recorded)
+    return steps
+
+
+def test_step_negatives_equal_the_searched_snap_after_relocation():
+    """Two shards, some of the other shard's keys relocated here: the
+    negatives a step draws from its PRNG key are those the old
+    in-program formula (alias draw, search of the local keys, wrap)
+    gives in numpy, and all of them are local."""
+    import jax
+    seen = []
+    E = 64
+    srv, w, dev = _alias_runner(E, np.arange(E), loss=_tell_negatives(seen))
+    moved = np.array([1, 5, 9, 33, 63], dtype=np.int64)
+    w.intent(moved, 0, CLOCK_MAX)
+    srv.wait_sync()
+    assert (srv.ab.owner[moved] == 0).all()
+    steps = _record_steps(dev)
+    rng = np.random.default_rng(0)
+    batch = {"a": rng.integers(0, E, 16).astype(np.int64),
+             "b": rng.integers(0, E, 16).astype(np.int64)}
+    dev(batch, np.zeros(1, np.int32), 0.0)
+    jax.effects_barrier()
+    (local_index, alias, rng_key), = steps
+    assert local_index is None and alias[2] is dev._alias[2]
+    local = _local_keys(srv, np.arange(E))
+    assert len(local) == E // 2 + len(moved)
+    j = _alias_draw(rng_key, (16, 3), alias[0], alias[1])
+    want = _old_snap(local, np.arange(E)[j])
+    assert seen and all(np.array_equal(neg, want) for _, neg in seen)
+    assert np.isin(want, local).all()
+    srv.shutdown()
+
+
+def test_snap_table_follows_placement():
+    """A relocation rebuilds the table once (counter +1, the moved share
+    changes), a step with placement unchanged does not; on one shard
+    nothing is ever built and the step is handed the key table itself."""
+    E = 64
+    srv, w, dev = _alias_runner(E, np.arange(E))
+    rebuilds = srv.obs.find("fused.neg_snap_rebuilds_total")
+    share = srv.obs.find("fused.neg_snap_moved_share")
+    batch = {"a": np.arange(16, dtype=np.int64),
+             "b": np.arange(16, 32, dtype=np.int64)}
+    dev(batch, None, 0.1)
+    assert (rebuilds.snap(), share.snap()) == (1, 0.5)
+    table = dev._alias[2]
+    dev(batch, None, 0.1)
+    assert rebuilds.snap() == 1 and dev._alias[2] is table
+    w.intent(np.arange(1, 33, 2), 0, CLOCK_MAX)
+    srv.wait_sync()
+    dev(batch, None, 0.1)
+    assert (rebuilds.snap(), share.snap()) == (2, 0.25)
+    assert "neg_snap_moved_share" in srv.metrics_snapshot()["fused"]
+    srv.shutdown()
+
+    srv, w, dev = _alias_runner(E, np.arange(E)[::-1].copy(), num_shards=1)
+    steps = _record_steps(dev)
+    dev(batch, None, 0.1)
+    dev(batch, None, 0.1)
+    assert [alias[2] is dev._key_table for _, alias, _ in steps] == \
+        [True, True]
+    assert srv.obs.find("fused.neg_snap_rebuilds_total").snap() == 0
+    assert srv.obs.find("fused.neg_snap_moved_share").snap() == 0.0
+    srv.shutdown()
+
+
+def test_run_scan_draws_the_alias_negatives_of_sequential_steps():
+    """`run_scan` shares the step's body: from the same seed it draws,
+    step for step, the negatives that sequential calls draw (snap table
+    included: 8 shards, 3 of 24 keys local)."""
+    import jax
+    rng = np.random.default_rng(9)
+    batches = [{"a": rng.integers(0, 24, 16).astype(np.int64),
+                "b": rng.integers(0, 24, 16).astype(np.int64)}
+               for _ in range(3)]
+    auxes = [np.full(1, i, np.int32) for i in range(3)]
+    drawn, losses = [], []
+    for scan in (False, True):
+        seen = []
+        srv, w, dev = _alias_runner(24, np.arange(24), num_shards=8,
+                                    loss=_tell_negatives(seen))
+        if scan:
+            losses.append(np.asarray(dev.run_scan(batches, auxes, 0.1)))
+        else:
+            losses.append(np.array([float(dev(b, a, 0.1))
+                                    for b, a in zip(batches, auxes)]))
+        jax.effects_barrier()
+        srv.shutdown()
+        by_step = {}
+        for step, neg in seen:  # one call a device, all alike
+            assert np.array_equal(by_step.setdefault(step, neg), neg)
+        drawn.append([by_step[i] for i in range(3)])
+        assert np.isin(np.stack(drawn[-1]), [0, 8, 16]).all()
+    assert all(np.array_equal(a, b) for a, b in zip(*drawn))
+    assert np.allclose(losses[0], losses[1], rtol=1e-5)
 
 
 def test_w2v_device_routes_matches_host(tmp_path):

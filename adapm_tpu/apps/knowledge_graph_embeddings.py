@@ -2,10 +2,11 @@
 MRR/Hits@k eval, checkpoints (reference apps/knowledge_graph_embeddings.cc).
 
 Pipeline parity (kge.cc:1059-1122): for each future triple batch the worker
-signals `Intent({s, r, o})` and `PrepareSample(2*neg_ratio*B)` at the future
-clock; negatives arrive via PullSample (managed sampling). Clock advances per
-batch. Loss and eval statistics aggregate through PS keys — the reference's
-`ps_allreduce` / eval_key idiom (utils.h:163-197, kge.cc:544-775) — a loss
+signals `Intent({s, r, o})` at the future clock; where the reference calls
+PrepareSample/PullSample, the fused step draws the negatives itself by the
+Local scheme (ops/fused.py). Clock advances per batch. Loss and eval
+statistics aggregate through PS keys — the reference's `ps_allreduce` /
+eval_key idiom (utils.h:163-197, kge.cc:544-775) — a loss
 key (length 1) and an eval key (length 8) live at the end of the key space.
 
 Key layout (kge.cc:1296-1306): entities [0, E) with embedding length 2*dim
@@ -30,7 +31,7 @@ import numpy as np
 
 from ..io import kge as kgeio
 from ..models.kge import make_eval_scores, make_kge_loss
-from ..ops import DeviceRoutedRunner, FusedStepRunner
+from ..ops import DeviceRoutedRunner
 from ..utils import Stopwatch, alog
 from .common import (KeyMapper, RuntimeGuard, add_common_arguments,
                      enforce_full_replication, epoch_report,
@@ -39,8 +40,9 @@ from .common import (KeyMapper, RuntimeGuard, add_common_arguments,
 
 # eval stats layout: [0:4] object side (mrr_sum, h1, h10, count),
 # [4:8] subject side — separated because the generators/datasets can have
-# asymmetric sides (the lowrank synthetic's subject is information-free,
-# docs/PERF.md); reported combined plus per-side (reference eval_key len 20)
+# asymmetric sides (the lowrank synthetic's subject is
+# information-free); reported combined plus per-side (reference eval_key
+# len 20)
 EVAL_LEN = 8
 
 
@@ -95,21 +97,15 @@ class KgeRun:
         self.loss_fn = make_kge_loss(args.model, args.self_adv_temp,
                                      args.l2)
         self._step_programs = {}
-        self.runner = FusedStepRunner(
-            self.srv, self.loss_fn,
-            role_class={"s": self.ent_class, "r": self.rel_class,
-                        "o": self.ent_class, "neg": self.ent_class},
-            role_dim={"s": self.ent_dim, "r": self.rel_dim,
-                      "o": self.ent_dim, "neg": self.ent_dim})
         self.truth_mrr = None    # lowrank generator's ceiling (open_run)
         self.neg_alias = None    # --neg_sampling freq alias table
         self._dev_runners = {}   # shard -> DeviceRoutedRunner
 
     def device_runner(self, shard: int) -> DeviceRoutedRunner:
-        """--device_routes: the production TPU hot path — routing tables
-        and negative sampling (Local scheme, uniform or alias-table
-        freq) live on device; one runner per worker shard
-        (docs/PERF.md: ~2.4x over host routing)."""
+        """The fused step's runner for the worker on `shard`, built at
+        first use: routing tables and negative sampling (Local scheme,
+        uniform or alias-table freq) live on device; one runner per
+        worker shard, all sharing their compiled programs."""
         if shard not in self._dev_runners:
             a = self.args
             self._dev_runners[shard] = DeviceRoutedRunner(
@@ -127,20 +123,18 @@ class KgeRun:
 
     def precompile(self) -> int:
         """`Server.precompile` with this app's sizes: an intent names at
-        most 2B entities and B relations; under --device_routes the
-        loop drives one kind of runner, a batch of B triples a step (a
-        --scan_steps window still compiles at its first use). Returns
-        how many planner programs ran."""
+        most 2B entities and B relations; the loop drives one kind of
+        runner, a batch of B triples a step (a --scan_steps window
+        still compiles at its first use). Returns how many planner
+        programs ran."""
         B = self.args.batch_size
         need = {}
         for cid, n in ((self.ent_class, 2 * B),
                        (self.rel_class, min(B, self.R))):
             need[cid] = need.get(cid, 0) + n
-        steps = []
-        if self.args.device_routes:
-            z = np.zeros(B, dtype=np.int64)
-            steps.append((self.device_runner(self.workers[0].shard),
-                          {"s": z, "r": z, "o": z}, None))
+        z = np.zeros(B, dtype=np.int64)
+        steps = [(self.device_runner(self.workers[0].shard),
+                  {"s": z, "r": z, "o": z}, None)]
         return self.srv.precompile(need, steps)
 
     # -- key helpers ---------------------------------------------------------
@@ -501,7 +495,8 @@ def _eval_global(run: KgeRun, triples: np.ndarray) -> np.ndarray:
 
 
 def open_run(args) -> KgeRun:
-    """Set-up: dataset, server, initialized model, sampling support. The
+    """Set-up: dataset, server, initialized model, the negatives'
+    distribution, compiled programs. The
     returned run's server is live; the caller shuts it down
     (`run.srv.shutdown()`) — `run_app` does, a caller that goes on to
     serve the trained store does so when it is finished."""
@@ -530,7 +525,6 @@ def open_run(args) -> KgeRun:
     if args.enforce_full_replication:
         enforce_full_replication(run.workers, run.E + run.R)
 
-    srv = run.srv
     # negative sampling over entities. uniform = the reference's scheme
     # (kge.cc draws uniform entities); freq = unigram^pow over the
     # training-triple entity frequencies (word2vec's noise distribution
@@ -543,19 +537,6 @@ def open_run(args) -> KgeRun:
                   + np.bincount(ds.train[:, 2], minlength=run.E)
                   + 1.0)
         run.neg_alias = build_alias_table(counts, power=args.neg_freq_pow)
-
-        def host_neg(n, r):
-            prob, alias = run.neg_alias
-            u = r.integers(0, run.E, n)
-            keep = r.random(n) < prob[u]
-            return run.ekey(np.where(keep, u, alias[u]))
-
-        srv.enable_sampling_support(
-            host_neg, allowed_keys=run.ekey(np.arange(run.E)))
-    else:
-        srv.enable_sampling_support(
-            lambda n, r: run.ekey(r.integers(0, run.E, n)),
-            allowed_keys=run.ekey(np.arange(run.E)))
     run.precompile()
     return run
 
@@ -588,24 +569,24 @@ def train(run: KgeRun) -> dict:
     for epoch in range(args.epochs):
         # per-epoch step size: AdaGrad already decays effective rates, but
         # an explicit multiplicative schedule helps late-stage ranking
-        # quality on the lowrank harness (docs/PERF.md "Quality");
+        # quality on the lowrank harness (tests/test_apps.py
+        # test_kge_lr_decay_beats_constant);
         # --lr_decay 1.0 = the reference's constant-lr behavior
         lr_epoch = args.lr * (args.lr_decay ** epoch)
         # losses stay device scalars until epoch end: a float() per step
-        # would serialize host and device (docs/PERF.md gap analysis)
+        # would serialize host and device
         epoch_losses = []
         for wi, w in enumerate(workers):
             mine = parts[wi]
             batches = [mine[idx] for idx in
                        wrap_batches(len(mine), B, rng)]
-            handles = {}
             staged = {}  # bi -> (roles, StagedKeys) pre-uploaded batches
             prepared_hi = -1  # highest batch index already prepared
 
             def triple_roles(t):
                 # the ONE logical->physical role mapping for a triple
-                # batch (prepare, staged-miss fallback, and host path
-                # must all agree)
+                # batch (prepare, the scan window and the staged-miss
+                # fallback must all agree)
                 return {"s": run.ekey(t[:, 0]), "r": run.rkey(t[:, 1]),
                         "o": run.ekey(t[:, 2])}
 
@@ -624,10 +605,7 @@ def train(run: KgeRun) -> dict:
                         [roles["s"], roles["r"], roles["o"]]))
                     fut = w.current_clock + ahead
                     w.intent(ks, fut, fut + 1)
-                    if not args.device_routes:
-                        handles[bi] = w.prepare_sample(B * N, fut,
-                                                       fut + 1)
-                    elif srv.prefetch is not None and K == 1:
+                    if srv.prefetch is not None and K == 1:
                         # prefetch pipeline on: the batch's key upload
                         # rides the prepare path
                         # (DeviceRoutedRunner.prefetch_keys) instead of
@@ -635,7 +613,7 @@ def train(run: KgeRun) -> dict:
                         staged[bi] = (roles, device_runner(w.shard)
                                       .prefetch_keys(roles))
 
-            K = max(1, args.scan_steps) if args.device_routes else 1
+            K = max(1, args.scan_steps)
             for bi in range(min(max(args.lookahead, K), len(batches))):
                 prepare(bi, ahead=bi)
             if K > 1:
@@ -664,23 +642,14 @@ def train(run: KgeRun) -> dict:
                 idx = batches[bi]
                 if bi + args.lookahead < len(batches):
                     prepare(bi + args.lookahead, ahead=args.lookahead)
-                if args.device_routes:
-                    pre = staged.pop(bi, None)
-                    if pre is not None:  # keys already on device
-                        roles, stg = pre
-                        loss = device_runner(w.shard)(roles, None,
-                                                      lr_epoch, staged=stg)
-                    else:
-                        loss = device_runner(w.shard)(
-                            triple_roles(triples[idx]), None, lr_epoch)
+                pre = staged.pop(bi, None)
+                if pre is not None:  # keys already on device
+                    roles, stg = pre
+                    loss = device_runner(w.shard)(roles, None, lr_epoch,
+                                                  staged=stg)
                 else:
-                    roles = triple_roles(triples[idx])
-                    neg = np.asarray(
-                        w.pull_sample_keys(handles[bi], B * N)).reshape(B, N)
-                    w.finish_sample(handles.pop(bi))
-                    roles["neg"] = neg
-                    loss = run.runner(roles, None, lr_epoch,
-                                      shard=w.shard)
+                    loss = device_runner(w.shard)(
+                        triple_roles(triples[idx]), None, lr_epoch)
                 epoch_losses.append(loss)
                 srv.drive_rounds(args.sync_rounds_per_step)
                 w.advance_clock()
@@ -787,18 +756,13 @@ def build_parser() -> argparse.ArgumentParser:
                              "(1.0 = constant, the reference behavior)")
     parser.add_argument("--scan_steps", type=int, default=1,
                         help="K>1: train K batches per device dispatch "
-                             "(lax.scan window, runner.run_scan; device "
-                             "routing only — amortizes dispatch overhead)")
-    parser.add_argument("--device_routes",
-                        action=argparse.BooleanOptionalAction, default=True,
-                        help="device-routed fused step + on-device "
-                             "negative sampling (TPU hot path; default on,"
-                             " --no-device_routes for host routing)")
+                             "(lax.scan window, runner.run_scan; "
+                             "amortizes dispatch overhead)")
     parser.add_argument("--neg_sampling", default="uniform",
                         choices=["uniform", "freq"],
                         help="negative entity distribution: uniform "
                              "(kge.cc) or unigram^pow over train-triple "
-                             "frequencies (mid-scale fix, docs/PERF.md)")
+                             "frequencies (the mid-scale fix)")
     parser.add_argument("--neg_freq_pow", type=float, default=0.75,
                         help="power for --neg_sampling freq")
     parser.add_argument("--self_adv_temp", type=float, default=0.0,

@@ -24,7 +24,7 @@ import numpy as np
 
 from ..io import mf as mfio
 from ..models.mf import make_mf_loss
-from ..ops import DeviceRoutedRunner, FusedStepRunner
+from ..ops import DeviceRoutedRunner
 from ..utils import Stopwatch, alog
 from .common import (KeyMapper, RuntimeGuard, ScanWindow,
                      add_common_arguments, enforce_full_replication,
@@ -72,12 +72,8 @@ def run(args) -> float:
     if args.enforce_full_replication:
         enforce_full_replication(workers, num_keys)
 
-    runner = FusedStepRunner(
-        srv, make_mf_loss(args.l2), role_class={"w": 0, "h": 0},
-        role_dim={"w": rank, "h": rank})
-
-    # --device_routes: routing tables mirrored into HBM, host ships only
-    # the raw key batch per step (TPU hot path; ops/fused.py)
+    # routing tables mirrored into HBM, host ships only the raw key batch
+    # per step (ops/fused.py)
     dev_runners = {}
 
     def device_runner(shard: int) -> DeviceRoutedRunner:
@@ -103,7 +99,7 @@ def run(args) -> float:
     guard = RuntimeGuard(args.max_runtime)
     watch = Stopwatch(start=True)
 
-    # --scan_steps K (device-routed only): buffer K batches and train
+    # --scan_steps K: buffer K batches and train
     # them in ONE lax.scan dispatch (ScanWindow — the shared app
     # contract; placement frozen per window). The clock still advances
     # per batch at buffering time; intent windows are extended by K-1
@@ -111,7 +107,7 @@ def run(args) -> float:
     # worker/block boundary (shards must not mix in one window) and
     # before each barrier/quiesce. lr changes per epoch (bold driver), so
     # the CURRENT lr is passed at every add/flush.
-    K = max(1, args.scan_steps) if args.device_routes else 1
+    K = max(1, args.scan_steps)
     scan_win = ScanWindow(srv, K, args.sync_rounds_per_step)
 
     def flush_scan():
@@ -119,15 +115,12 @@ def run(args) -> float:
 
     def train_batch(w, idx):
         roles = {"w": kmap(rows[idx]), "h": kmap(cols[idx] + m)}
-        if args.device_routes and K > 1:
+        if K > 1:
             scan_win.add(device_runner(w.shard), roles,
                          np.asarray(vals[idx]), lr)
             w.advance_clock()
             return None
-        if args.device_routes:
-            loss = device_runner(w.shard)(roles, np.asarray(vals[idx]), lr)
-        else:
-            loss = runner(roles, np.asarray(vals[idx]), lr, shard=w.shard)
+        loss = device_runner(w.shard)(roles, np.asarray(vals[idx]), lr)
         # inline rounds, or delegated to the prefetch pipeline so
         # planner work overlaps the in-flight step
         srv.drive_rounds(args.sync_rounds_per_step)
@@ -240,19 +233,13 @@ def build_parser() -> argparse.ArgumentParser:
                         choices=["dsgd", "columnwise", "plain"])
     parser.add_argument("--scan_steps", type=int, default=1,
                         help="batches trained per device dispatch "
-                             "(lax.scan window, runner.run_scan; device "
-                             "routing only — same contract as the KGE "
-                             "app's --scan_steps)")
+                             "(lax.scan window, runner.run_scan; same "
+                             "contract as the KGE app's --scan_steps)")
     parser.add_argument("--lookahead", type=int, default=2,
                         help="intent batches ahead (columnwise/plain)")
     parser.add_argument("--adagrad_init", type=float, default=1e-6)
     parser.add_argument("--bold_inc", type=float, default=1.05)
     parser.add_argument("--bold_dec", type=float, default=0.5)
-    parser.add_argument("--device_routes",
-                        action=argparse.BooleanOptionalAction, default=True,
-                        help="device-routed fused step (TPU hot path; "
-                             "default on, --no-device_routes for host "
-                             "routing)")
     parser.add_argument("--init_w", default=None)
     parser.add_argument("--init_h", default=None)
     parser.add_argument("--export_prefix", default=None)

@@ -3,7 +3,9 @@
 Two PM keys per word — syn0 (input) = 2w, syn1 (output) = 2w+1
 (word2vec.cc:83-105); unigram^0.75 negative table (:125-144); AdaGrad; the
 logical clock advances per sentence and a read-ahead pipeline (default 1000
-sentences, :561-626) signals `Intent` + `PrepareSample` for future sentences.
+sentences, :561-626) signals `Intent` for future sentences; where the
+reference calls PrepareSample/PullSample, the fused step draws the negatives
+itself (ops/fused.py: the alias table with a Local-scheme snap).
 Pair generation for a future sentence is precomputed with a per-sentence
 seeded RNG — the moral equivalent of the reference's PeekableRandom
 (:445-491), which pre-draws future window sizes.
@@ -23,9 +25,9 @@ from typing import List
 import numpy as np
 
 from ..io import text as textio
-from ..models.sgns import (build_alias_table, build_unigram_table,
-                           sgns_loss, subsample_mask, syn0_key, syn1_key)
-from ..ops import DeviceRoutedRunner, FusedStepRunner
+from ..models.sgns import (build_alias_table, sgns_loss, subsample_mask,
+                           syn0_key, syn1_key)
+from ..ops import DeviceRoutedRunner
 from ..utils import Stopwatch, alog
 from .common import (KeyMapper, RuntimeGuard, ScanWindow,
                      add_common_arguments, enforce_full_replication,
@@ -77,21 +79,11 @@ def run(args) -> float:
     if args.enforce_full_replication:
         enforce_full_replication(workers, num_keys)
 
-    # negative sampling: unigram^0.75 over words -> syn1 physical keys; the
-    # Local scheme may only snap to other syn1 keys (never syn0)
-    word_sampler = build_unigram_table(counts)
-    srv.enable_sampling_support(
-        lambda n, r: kmap(syn1_key(word_sampler(n, r))),
-        allowed_keys=kmap(syn1_key(np.arange(V))))
-
-    runner = FusedStepRunner(
-        srv, sgns_loss, role_class={"center": 0, "ctx": 0, "neg": 0},
-        role_dim={k: d for k in ("center", "ctx", "neg")})
-
     B, N = args.batch_size, args.negative
 
-    # --device_routes: negatives drawn IN-PROGRAM from the unigram^0.75
-    # alias table with a Local-scheme snap (the reference's negative table,
+    # negatives drawn IN-PROGRAM from the unigram^0.75 alias table over the
+    # syn1 physical keys, with a Local-scheme snap that may only land on
+    # other syn1 keys, never syn0 (the reference's negative table,
     # word2vec.cc:125-144, as two O(V) HBM arrays); per step the host ships
     # only the center/context key batch
     dev_runners = {}
@@ -115,7 +107,7 @@ def run(args) -> float:
     # workers (reference :524-531)
     slices = global_worker_slices(len(sents), num_workers)
 
-    # --scan_steps K (device-routed only): buffer K materialized batches
+    # --scan_steps K: buffer K materialized batches
     # and train them in ONE lax.scan dispatch (runner.run_scan — same
     # contract as the KGE app: placement frozen per window, negative RNG
     # identical to K sequential steps). Clocks still advance per
@@ -123,7 +115,7 @@ def run(args) -> float:
     # clocks before dispatch, so intent windows are extended by a slack
     # estimated from the corpus (otherwise replicas could expire while a
     # batch sits in the window).
-    K = max(1, args.scan_steps) if args.device_routes else 1
+    K = max(1, args.scan_steps)
     scan_slack = 0
     if K > 1:
         probe = [len(_pairs_for(sents[si], si, args.window, args.seed,
@@ -136,28 +128,26 @@ def run(args) -> float:
         losses = []
         for wi, w in enumerate(workers):
             my = slices[wi].tolist()
-            # (sent position, sample handle) for prepared future sentences
+            # (sent position, centers, contexts) of prepared future
+            # sentences
             prepared: deque = deque()
             buf_c: List[np.ndarray] = []
             buf_x: List[np.ndarray] = []
-            buf_n: List[np.ndarray] = []
 
             def prepare(pos: int, ahead: int) -> None:
-                """Signal intent + prepare negatives for the sentence that
-                will be trained `ahead` clocks from now."""
+                """Signal intent for the sentence that will be trained
+                `ahead` clocks from now."""
                 si = my[pos]
                 c, x = _pairs_for(sents[si], si, args.window, args.seed,
                                   counts, total_words, args.sample)
                 if len(c) == 0:
-                    prepared.append((pos, None, c, x))
+                    prepared.append((pos, c, x))
                     return
                 fut = w.current_clock + ahead
                 ks = np.unique(np.concatenate(
                     [kmap(syn0_key(c)), kmap(syn1_key(x))]))
                 w.intent(ks, fut, fut + 1 + scan_slack)
-                h = None if args.device_routes else \
-                    w.prepare_sample(len(c) * N, fut, fut + 1)
-                prepared.append((pos, h, c, x))
+                prepared.append((pos, c, x))
 
             # prime the pipeline
             for pos in range(min(args.readahead, len(my))):
@@ -170,39 +160,27 @@ def run(args) -> float:
             for pos in range(len(my)):
                 if pos + args.readahead < len(my):
                     prepare(pos + args.readahead, ahead=args.readahead)
-                _, h, c, x = prepared.popleft()
+                _, c, x = prepared.popleft()
                 if len(c):
-                    if h is not None:
-                        negk = w.pull_sample_keys(h, len(c) * N)
-                        w.finish_sample(h)
-                        buf_n.append(np.asarray(negk).reshape(len(c), N))
                     buf_c.append(kmap(syn0_key(c)))
                     buf_x.append(kmap(syn1_key(x)))
                     n_buf += len(c)
 
-                def step(cc, xx, nn):
-                    if args.device_routes:
-                        return device_runner(w.shard)(
-                            {"center": cc, "ctx": xx}, None, args.lr)
-                    return runner({"center": cc, "ctx": xx, "neg": nn},
-                                  None, args.lr, shard=w.shard)
-
                 while n_buf >= B:
                     cc = np.concatenate(buf_c)
                     xx = np.concatenate(buf_x)
-                    nn = np.concatenate(buf_n) if buf_n else None
                     if K > 1:
                         scan_win.add(device_runner(w.shard),
                                      {"center": cc[:B], "ctx": xx[:B]},
                                      None, args.lr)
                     else:
-                        losses.append(step(cc[:B], xx[:B],
-                                           None if nn is None else nn[:B]))
+                        losses.append(device_runner(w.shard)(
+                            {"center": cc[:B], "ctx": xx[:B]}, None,
+                            args.lr))
                         # inline rounds, or delegated to the prefetch
                         # pipeline so planner work overlaps the step
                         srv.drive_rounds(args.sync_rounds_per_step)
                     buf_c, buf_x = [cc[B:]], [xx[B:]]
-                    buf_n = [] if nn is None else [nn[B:]]
                     n_buf -= B
                 w.advance_clock()
             scan_win.flush(args.lr)  # partial window at worker end
@@ -210,11 +188,10 @@ def run(args) -> float:
             if n_buf > 0:
                 cc = np.concatenate(buf_c)
                 xx = np.concatenate(buf_x)
-                nn = np.concatenate(buf_n) if buf_n else None
                 reps = -(-B // len(cc))
-                losses.append(step(
-                    np.tile(cc, reps)[:B], np.tile(xx, reps)[:B],
-                    None if nn is None else np.tile(nn, (reps, 1))[:B]))
+                losses.append(device_runner(w.shard)(
+                    {"center": np.tile(cc, reps)[:B],
+                     "ctx": np.tile(xx, reps)[:B]}, None, args.lr))
         srv.quiesce()
         # scan windows contribute [K] loss vectors, per-step path scalars
         mean_loss = float(np.mean(np.concatenate(
@@ -263,14 +240,8 @@ def build_parser() -> argparse.ArgumentParser:
                         help="sentences of intent/sample lookahead")
     parser.add_argument("--scan_steps", type=int, default=1,
                         help="batches trained per device dispatch "
-                             "(lax.scan window, runner.run_scan; device "
-                             "routing only — same contract as the KGE "
-                             "app's --scan_steps)")
-    parser.add_argument("--device_routes",
-                        action=argparse.BooleanOptionalAction, default=True,
-                        help="device-routed fused step + in-program "
-                             "unigram^0.75 negatives (TPU hot path; default "
-                             "on, --no-device_routes for host routing)")
+                             "(lax.scan window, runner.run_scan; same "
+                             "contract as the KGE app's --scan_steps)")
     parser.add_argument("--adagrad_init", type=float, default=1e-6)
     parser.add_argument("--export_prefix", default=None)
     add_common_arguments(parser)

@@ -442,7 +442,7 @@ class SystemOptions:
     stream_freshness_slo_class: str = ""
 
     # -- measured kernel cost table (sys.costs.*; adapm_tpu/ops/
-    #    costs.py, docs/PERF.md "Kernel cost table"): per-(variant,
+    #    costs.py): per-(variant,
     #    length class, batch bucket, dtype, pooling) measured dispatch
     #    costs, persisted as versioned JSON at costs_table. The serve
     #    batcher consults it to pick fused vs host-pool bag dispatch;

@@ -63,10 +63,10 @@ def pad_bucket(n: int, *arrays_and_fills, minimum: int = 8):
     # them to device 0, and every mesh-jitted op would then RESHARD them
     # host-side per call (measured: ~10x slowdown of planner device ops on
     # an 8-device mesh, profile dominated by Array._value readbacks).
-    # Caveat (docs/PERF.md staging rule): a bare numpy arg uploads
-    # inside dispatch; fine for planner-frequency ops and the bindings'
+    # Caveat (the staging rule, parallel/mesh.py): a bare numpy arg
+    # uploads inside dispatch; fine for planner-frequency ops and the bindings'
     # per-op pull/push, but anything per-STEP hot must pre-stage via
-    # MeshContext.put_replicated the way ops/fused.py build_routes does.
+    # MeshContext.put_replicated the way ops/fused.py _upload_keys does.
     return [pad_to(a, b, fill) for a, fill in arrays_and_fills]
 
 

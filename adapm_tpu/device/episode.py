@@ -74,10 +74,10 @@ def plan_episodes(batches: Sequence[Dict[str, np.ndarray]],
 
 
 class EpisodicRunner:
-    """Drives a fused-step runner (ops/fused.py DeviceRoutedRunner or
-    FusedStepRunner) episodically. `run(batches, auxes, lr)` returns
-    the per-step losses in step order, bit-identical to calling the
-    runner sequentially on the same batches."""
+    """Drives the fused-step runner (ops/fused.py DeviceRoutedRunner)
+    episodically. `run(batches, auxes, lr)` returns the per-step losses
+    in step order, bit-identical to calling the runner sequentially on
+    the same batches."""
 
     _COMMIT_TIMEOUT_S = 600.0
 
@@ -98,10 +98,7 @@ class EpisodicRunner:
                 eb, [st.value_length for st in srv.stores])
         self.episode_batches = int(eb)
         assert self.episode_batches >= 1
-        # key staging is a DeviceRoutedRunner capability; the host-routed
-        # FusedStepRunner still gets episodic pin/promote prep
-        self._stage = getattr(runner, "prefetch_keys", None)
-        self._staged_ok = self._stage is not None
+        self._stage = runner.prefetch_keys
         reg = srv.obs
         # shared=True: several runners may drive one server
         self._c_episodes = reg.counter("episode.episodes_total",
@@ -169,10 +166,8 @@ class EpisodicRunner:
                                                  pin_end=end)
                     if n:
                         self._c_pinned.inc(n)
-            staged = None
-            if self._staged_ok:
-                staged = [self._stage(b) for b in ep.batches]
-                self._c_staged.inc(len(staged))
+            staged = [self._stage(b) for b in ep.batches]
+            self._c_staged.inc(len(staged))
         self._h_prep.observe(time.perf_counter() - t0)
         return staged
 
@@ -180,16 +175,12 @@ class EpisodicRunner:
 
     def _commit(self, ep: Episode, staged, lr: float, eps: float):
         """Run the episode's steps in order — exactly what a sequential
-        caller would execute, staged key uploads aside."""
+        caller would execute, the key uploads staged by `_prep` aside."""
         t0 = time.perf_counter()
         losses = []
         for i, b in enumerate(ep.batches):
             aux = None if ep.auxes is None else ep.auxes[i]
-            if staged is not None:
-                losses.append(self.runner(b, aux, lr, eps,
-                                          staged=staged[i]))
-            else:
-                losses.append(self.runner(b, aux, lr, eps))
+            losses.append(self.runner(b, aux, lr, eps, staged=staged[i]))
         self._c_episodes.inc()
         self._h_commit.observe(time.perf_counter() - t0)
         return losses

@@ -667,7 +667,7 @@ class JaxDevicePort(DevicePort):
 
     def put_replicated(self, arr, sharding):
         # numpy in, asynchronous device_put out — the staging rule
-        # (docs/PERF.md "Host-array staging")
+        # (parallel/mesh.py put_replicated)
         return jax.device_put(np.asarray(arr), sharding)
 
     def put_single(self, arr, device):

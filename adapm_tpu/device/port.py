@@ -171,7 +171,7 @@ class DevicePort:
 
     def put_replicated(self, arr, sharding):
         """Stage a host array committed + replicated (the staging rule,
-        docs/PERF.md)."""
+        parallel/mesh.py put_replicated)."""
         raise NotImplementedError
 
     def put_single(self, arr, device):

@@ -21,9 +21,7 @@ Three coupled layers over one seeded injection substrate
     (`ServeDegradedError` sheds) while it applies — never a torn or
     half-restored read.
 
-Drilled end to end by scripts/fault_drill_check.py (run_tests.sh) and
-measured by bench.py's `fault` phase (recovery_s, incremental-vs-full
-bytes).
+Drilled end to end by scripts/fault_drill_check.py (run_tests.sh).
 """
 from .ckpt import (CheckpointChainError,  # noqa: F401
                    CheckpointCorruptError, IncrementalCheckpointer,

@@ -101,7 +101,7 @@ def generate_lowrank(num_entities: int = 120, num_relations: int = 8,
     right ceiling, returned as the second element: sampling at finite
     temperature means even the truth cannot rank every sampled object
     first. The mid-scale quality harness asserts trained-MRR as a
-    fraction of truth-MRR (docs/PERF.md).
+    fraction of truth-MRR.
 
     `device` moves the per-chunk score matmul + Gumbel-max onto the JAX
     default device (auto at num_entities >= 20000): the [chunk, E]
@@ -117,7 +117,7 @@ def generate_lowrank(num_entities: int = 120, num_relations: int = 8,
     inverse-transform (`-log(-log(rng.random(float32)))`), which changes
     how the generator consumes the numpy bit stream. Host-path datasets
     at a given seed therefore differ from those generated by pre-r5
-    builds — numbers pinned against older datasets (docs/PERF.md) are
+    builds — numbers pinned against older datasets are
     not bit-reproducible across that boundary, though the ratio-based
     tests tolerate it. Within any post-r5 build the host stream is
     deterministic as usual."""

@@ -46,9 +46,9 @@ def _nll_loss(pos: jnp.ndarray, neg_s: jnp.ndarray, neg_o: jnp.ndarray,
     weighting (Sun et al. 2019, RotatE eq. 5): each negative is weighted
     by softmax(temp * score) with a stopped gradient, so the hardest
     negatives in the batch dominate the update. This addresses the
-    measured mid-scale failure of uniform negatives (docs/PERF.md
-    "Quality": at 14k entities uniform draws almost never hit the
-    runner-up entities that carry the signal)."""
+    mid-scale failure of uniform negatives (at 14k entities uniform
+    draws almost never hit the runner-up entities that carry the
+    signal; tests/test_apps.py test_kge_midscale_levers_beat_uniform)."""
     pos_l = jax.nn.softplus(-pos)
     if self_adv_temp > 0.0:
         ws = jax.nn.softmax(
@@ -75,7 +75,7 @@ def make_kge_loss(model: str = "complex", self_adv_temp: float = 0.0,
     sigmoid-loss trainer (kge.cc :437-531) but load-bearing once train
     coverage of the (s, r) pair space is sparse: unregularized NS-SGD
     then memorizes train triples (loss falls) while test ranking stays
-    random (measured, docs/PERF.md 'Quality at 14.5k'). Lazy = only rows
+    random (seen at 14.5k entities). Lazy = only rows
     touched by the step decay, which is exactly AdaGrad-compatible."""
     score = {"complex": complex_score, "rescal": rescal_score}[model]
 

@@ -32,20 +32,6 @@ def sgns_loss(embs, aux):
     return (jax.nn.softplus(-pos) + jax.nn.softplus(negs).sum(-1)).mean()
 
 
-def build_unigram_table(counts: np.ndarray, power: float = 0.75):
-    """Noise distribution over words: count^0.75 / Z (word2vec.cc:125-144).
-    Returns a sampler closure `fn(n, rng) -> word ids` suitable for
-    Server.enable_sampling_support (drawing *syn1 keys* is the caller's
-    concern via syn1_key)."""
-    p = counts.astype(np.float64) ** power
-    p /= p.sum()
-
-    def sample(n: int, rng: np.random.Generator) -> np.ndarray:
-        return rng.choice(len(p), size=n, p=p).astype(np.int64)
-
-    return sample
-
-
 def build_alias_table(counts: np.ndarray, power: float = 0.75):
     """Vose alias table for the unigram^power noise distribution — the
     device-sampler form of the reference's pre-materialized 1e8-entry

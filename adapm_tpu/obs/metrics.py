@@ -319,8 +319,8 @@ class CounterGroup:
 def hist_percentile(snap: Dict, q: float) -> float:
     """Approximate quantile `q` (0..1) from a `Histogram.snap()` dict by
     linear interpolation inside the containing bucket — the consumer-side
-    P50/P90 extraction for bounded-bucket histograms (bench.py `mgmt`
-    phase, staleness reporting). Observations in the +inf overflow bucket
+    P50/P90 extraction for bounded-bucket histograms (staleness
+    reporting). Observations in the +inf overflow bucket
     clamp to the last finite bound; an empty histogram returns 0."""
     count = snap.get("count", 0)
     if not count:

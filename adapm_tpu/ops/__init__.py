@@ -1,5 +1,3 @@
 """Hot-path device programs: fused gather->grad->AdaGrad->scatter steps."""
-from .fused import (DeviceRoutedRunner, DeviceRouter,  # noqa
-                    FusedStepRunner, Routes, StagedKeys, build_routes,
-                    make_device_routed_scan, make_device_routed_step,
-                    make_fused_adagrad_step)
+from .fused import (DeviceRoutedRunner, DeviceRouter, StagedKeys,  # noqa
+                    make_device_routed_scan, make_device_routed_step)

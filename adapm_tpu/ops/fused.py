@@ -17,28 +17,22 @@ Value-row layout follows the reference convention of carrying optimizer
 state inside the value (`param_len = 2*rank = [factor | adagrad]`,
 matrix_factorization.cc:695-697): row = [emb (D) | adagrad acc (D)].
 
-Routing (which shard/slot serves each key) is resolved on the host from the
-Addressbook — exactly what `Server._pull`/`_push` do — and handed to the
-program as index arrays, so relocation/replication decisions made by the
-planner between steps are transparently picked up.
+Routing (which shard/slot serves each key) is resolved IN the program
+(DeviceRouter): the Addressbook tables (owner, slot, the worker shard's
+cache-slot row) are mirrored into HBM, re-uploaded lazily when the planner
+changes placement (topology_version), and the jitted step resolves routes
+itself by the policy of `Server._route` (prefer a local replica, else the
+owner row) — per step the host ships only raw keys, and relocation and
+replication decisions made by the planner between steps are picked up with
+the next refresh. Table lookups are trivial device gathers, and placement
+changes are rare relative to steps.
 
-Two routing modes:
-  host routes (build_routes):  the host resolves every key and ships five
-      index arrays per role. Simple, but at bench scale the host pays
-      ~milliseconds per step in table lookups + host->device transfers
-      while the device step takes microseconds.
-  device routes (DeviceRouter): the Addressbook tables (owner, slot, the
-      worker shard's cache-slot row) are mirrored into HBM, re-uploaded
-      lazily when the planner changes placement (topology_version), and the
-      jitted step resolves routes itself — per step the host ships only raw
-      keys. This is the TPU-idiomatic shape: table lookups are trivial
-      device gathers, and placement changes are rare relative to steps.
-
-Negative sampling can also run on device (the `neg_role`/`neg_shape`
+Negative sampling can run on device too (the `neg_role`/`neg_shape`
 parameters of DeviceRoutedRunner / make_device_routed_step): drawing uniform
 positions into a device mirror of the locally-resident key index is exactly
 the Local sampling scheme (core/sampling.py) executed in-program,
-eliminating the per-step sample key transfer too.
+so no sample keys cross to the device either. A runner built
+without `neg_role` takes its negatives from the caller like any role.
 """
 from __future__ import annotations
 
@@ -65,73 +59,6 @@ def _key_dtype(num_keys: int):
     """Key-upload dtype: int32 halves the transfer and is exact as long as
     every key fits; beyond 2^31 keys fall back to int64."""
     return np.int32 if num_keys <= 2**31 else np.int64
-
-
-class Routes:
-    """Device index arrays routing one role's key batch to pool rows.
-
-    gather:  main[g_sh, g_sl] for owner-served keys, (cache+delta)[c_sh, c_sl]
-             for replica-served keys (use_c mask).
-    scatter: derived inside jit — owner path drops replica positions (OOB),
-             delta path drops owner positions (mirrors Server._push).
-    """
-
-    __slots__ = ("g_sh", "g_sl", "c_sh", "c_sl", "use_c", "n_remote")
-
-    def __init__(self, g_sh, g_sl, c_sh, c_sl, use_c, n_remote: int):
-        self.g_sh, self.g_sl = g_sh, g_sl
-        self.c_sh, self.c_sl = c_sh, c_sl
-        self.use_c = use_c
-        self.n_remote = n_remote
-
-    def as_tuple(self):
-        return (self.g_sh, self.g_sl, self.c_sh, self.c_sl, self.use_c)
-
-
-def build_routes(server, keys: np.ndarray, shard: int,
-                 expect_class: int = None) -> Routes:
-    """Resolve keys (any shape) to pool coordinates for a worker on `shard`,
-    via the one shared routing policy (Server._route: prefer a local replica,
-    else the owner row). All keys must share a length class; pass
-    `expect_class` to fail fast on a wrong role->class mapping (slots are
-    per-class row indices, so a mismatch would corrupt another pool's rows).
-    """
-    keys = np.asarray(keys, dtype=np.int64)
-    if expect_class is not None:
-        kc = server.ab.key_class[keys]
-        assert (kc == expect_class).all(), (
-            f"keys span length classes {np.unique(kc)} but role is mapped "
-            f"to class {expect_class}")
-    # multi-process: a key owned by another process cannot be gathered by
-    # the local program — make it local first (miss = fetch)
-    server.ensure_local(keys, shard)
-    o_sh, o_sl, c_sh, c_sl, use_c, n_remote, _ = server._route(keys, shard)
-    g_sl = np.where(use_c, OOB, o_sl).astype(np.int32)
-    if server.tier is not None:
-        # tiered storage: the step program indexes the DEVICE hot pool,
-        # so every owner-served key must be hot before dispatch. The
-        # runners pin their whole batch as one union up front
-        # (pin_step_keys), so the translation below normally finds
-        # everything hot — the forced ensure only runs for rows still
-        # cold (direct build_routes callers that skipped the union pin)
-        cid = expect_class if expect_class is not None else \
-            int(server.ab.key_class[keys.ravel()[0]])
-        res = server.stores[cid].res
-        slot_flat = g_sl.ravel()            # slots; OOB where replica-served
-        o_flat = o_sh.ravel()
-        m = slot_flat != OOB
-        row = slot_flat.copy()
-        row[m] = res.dev_row[o_flat[m], slot_flat[m]]
-        if (row[m] < 0).any():
-            server.tier.ensure_hot(cid, o_flat[m], slot_flat[m],
-                                   pin_end=server.tier.step_pin_end(),
-                                   force=True)
-            row[m] = res.dev_row[o_flat[m], slot_flat[m]]
-        g_sl = np.where(row < 0, OOB, row).reshape(
-            g_sl.shape).astype(np.int32)
-    put = server.ctx.put_replicated  # the staging rule, mesh.py
-    return Routes(put(o_sh), put(g_sl), put(c_sh), put(c_sl), put(use_c),
-                  n_remote)
 
 
 def _mark_fused_writes(server, shard: int, role_class, role_keys,
@@ -238,51 +165,6 @@ def _adagrad_update(g, acc, lr, eps):
         g2 = g * g
         upd_emb = -lr * g * jax.lax.rsqrt(acc + g2 + eps)
         return jnp.concatenate([upd_emb, g2], axis=-1)
-
-
-def make_fused_adagrad_step(
-        loss_fn: Callable[..., jnp.ndarray],
-        role_class: Dict[str, int],
-        role_dim: Dict[str, int],
-        frozen_roles: Sequence[str] = ()):
-    """Build the jitted fused step.
-
-    loss_fn(embs: dict role -> [..., D_role] array, aux) -> scalar mean loss.
-    role_class: role -> length-class id (index into the pools argument).
-    role_dim:   role -> embedding dim D (row length must be 2*D: [emb|acc]).
-    frozen_roles: gathered for the forward pass but never updated.
-
-    Returns step(pools, routes, aux, lr, eps) -> (pools, loss) where
-      pools  = tuple over classes of (main, cache, delta)   [donated]
-      routes = dict role -> Routes.as_tuple()
-      aux    = arbitrary pytree handed to loss_fn (labels, weights, rng keys)
-    """
-    roles = sorted(role_class)
-    trainable = [r for r in roles if r not in frozen_roles]
-
-    def step(pools, routes, aux, lr, eps):
-        rows = {}
-        for r in roles:
-            main, cache, delta = pools[role_class[r]]
-            with jax.named_scope("adapm_gather"):
-                rows[r] = _read_rows(main, cache, delta, routes[r])
-        embs = {r: rows[r][..., : role_dim[r]] for r in roles}
-        accs = {r: rows[r][..., role_dim[r]:] for r in roles}
-        loss, grads = _loss_and_grads(loss_fn, embs, trainable, aux)
-
-        new_pools = list(pools)
-        for r in trainable:
-            upd = _adagrad_update(grads[r], accs[r], lr, eps)
-            cid = role_class[r]
-            main, cache, delta = new_pools[cid]
-            with jax.named_scope("adapm_scatter_add"):
-                main, delta = _scatter_update(main, delta, routes[r], upd)
-            new_pools[cid] = (main, cache, delta)
-        return tuple(new_pools), loss
-
-    # program construction through the DevicePort (ISSUE 14): the body
-    # is model math; the port owns how it becomes a device program
-    return default_port().compile(step, donate_argnums=(0,))
 
 
 class DeviceRouter:
@@ -498,8 +380,8 @@ def _build_device_routed_body(loss_fn, role_class, role_dim,
         rows = {}
         routes = {}
         # device-side locality counters (reference coloc_kv_server.h:147-157
-        # prints % accesses served locally; the host path records this in
-        # Server._route, which this path never visits): a key access is
+        # prints % accesses served locally; Pull/Push record this in
+        # Server._route, which a step never visits): a key access is
         # local when this worker's shard owns the row or holds a replica
         n_total = 0
         n_local = jnp.int32(0)
@@ -576,9 +458,12 @@ class StagedKeys:
 
 
 class DeviceRoutedRunner:
-    """FusedStepRunner's fast sibling: routing (and optionally negative
-    sampling) happens on device. Per step the host ships only the raw key
-    batch; table mirrors refresh lazily when the planner moves parameters.
+    """Binds the fused step to a Server: swaps the pools in and out of
+    the ShardedStores, so the PM view (Pull/Push/sync rounds) and the
+    fused hot loop always see the same buffers. Routing (and optionally
+    negative sampling) happens on device: per step the host ships only
+    the raw key batch, and the table mirrors refresh lazily when the
+    planner moves parameters.
     With the prefetch pipeline on (SystemOptions.prefetch), the mirrors
     are instead re-staged by the pipeline's background thread right after
     planner rounds, and `prefetch_keys` lets the app upload a future
@@ -588,8 +473,11 @@ class DeviceRoutedRunner:
     step program (params seen / params local / steps / all-local steps) and
     drained to the host lazily — at `locality_counts()` (which
     Server.locality_summary calls) and often enough that the int32 counters
-    cannot wrap. Per-KEY counters (--sys.stats.locality tsv dumps) still
-    need host routing: routing never returns to the host here.
+    cannot wrap. Per-KEY counters (--sys.stats.locality tsv dumps) are
+    kept on the host, for the keys the host knows: with the option on,
+    each dispatch records its batch through `Server._route`
+    (`_record_key_locality`); negatives drawn in the program are local by
+    construction and not recorded per key.
     """
 
     def __init__(self, server, loss_fn, role_class: Dict[str, int],
@@ -812,6 +700,14 @@ class DeviceRoutedRunner:
             [np.asarray(k, dtype=np.int64).ravel()
              for k in role_keys.values()]))
 
+    def _record_key_locality(self, role_keys) -> None:
+        """--sys.stats.locality: the per-key access counts of a step's
+        host-known keys (caller holds the server lock), by `Server
+        ._route`'s definition of local; its coordinates are dropped, the
+        step routes for itself."""
+        for k in role_keys.values():
+            self.server._route(np.asarray(k, dtype=np.int64), self.shard)
+
     def _mark_neg_writes(self) -> None:
         """Write tracking for device-drawn negatives (caller holds the
         server lock, AFTER _local_neg_index refreshed for this step):
@@ -830,8 +726,9 @@ class DeviceRoutedRunner:
 
     def prefetch_keys(self, role_keys: Dict[str, np.ndarray]) -> StagedKeys:
         """Pre-stage a future step's key batch on device (the staging
-        rule, docs/PERF.md): the upload runs now — on the app's
-        intent/prepare path — instead of inside the next dispatch.
+        rule, parallel/mesh.py put_replicated): the upload runs now — on
+        the app's intent/prepare path — instead of inside the next
+        dispatch.
         Returns the handle for __call__'s `staged` parameter."""
         self._check_batch(role_keys)
         host = {r: np.asarray(k, dtype=_key_dtype(self.server.num_keys))
@@ -1067,7 +964,7 @@ class DeviceRoutedRunner:
             # on device, XLA clamps bad indices instead of raising — reject
             # out-of-range keys here, then fail fast on a wrong role->class
             # mapping (per-class slot indices gathered for the wrong pool
-            # would corrupt rows; same check as build_routes)
+            # would corrupt rows)
             check_key_range(k64, srv.num_keys, f"role {r} key")
             kc = srv.ab.key_class[k64]
             assert (kc == self.role_class[r]).all(), (
@@ -1098,6 +995,8 @@ class DeviceRoutedRunner:
                 # epoch, which router.tables() below picks up)
                 srv.tier.pin_step_keys(self.role_class, role_keys)
             self._note_step_writes(role_keys)
+            if srv.locality is not None:
+                self._record_key_locality(role_keys)
             tables = self._tables()
             local_index = self._local_neg_index() \
                 if self.neg_role is not None else None
@@ -1167,6 +1066,8 @@ class DeviceRoutedRunner:
                 srv.tier.pin_step_keys(self.role_class, union)
             for b in batches:
                 self._note_step_writes(b)
+                if srv.locality is not None:
+                    self._record_key_locality(b)
             tables = self._tables()
             local_index = self._local_neg_index() \
                 if self.neg_role is not None else None
@@ -1205,68 +1106,3 @@ class DeviceRoutedRunner:
                     (self.steps - K) // self._drain_every:
                 self._drain_locstat()
         return losses
-
-
-class FusedStepRunner:
-    """Binds a fused step to a Server: swaps pools in/out of the ShardedStores
-    so the PM view (Pull/Push/sync rounds) and the fused hot loop always see
-    the same buffers."""
-
-    def __init__(self, server, loss_fn, role_class: Dict[str, int],
-                 role_dim: Dict[str, int], frozen_roles: Sequence[str] = ()):
-        self.server = server
-        self.role_class = role_class
-        self.frozen_roles = frozenset(frozen_roles)
-        self.step_fn = make_fused_adagrad_step(
-            loss_fn, role_class, role_dim, frozen_roles)
-        self.n_remote = 0
-        self.steps = 0
-
-    def routes_for(self, role_keys: Dict[str, np.ndarray],
-                   shard: int) -> Dict[str, tuple]:
-        out = {}
-        for r, keys in role_keys.items():
-            rt = build_routes(self.server, keys, shard,
-                              expect_class=self.role_class[r])
-            self.n_remote += rt.n_remote
-            out[r] = rt.as_tuple()
-        return out
-
-    def __call__(self, role_keys: Dict[str, np.ndarray], aux, lr: float,
-                 eps: float = 1e-10, shard: int = 0) -> jnp.ndarray:
-        srv = self.server
-        with srv._lock:
-            # a fused step is a batched Push: invalidate staged pull
-            # buffers of the trained keys (all roles are host-provided
-            # here, so the written key set is exact)
-            if srv.prefetch is not None and srv.prefetch._staged:
-                srv._prefetch_note(np.concatenate(
-                    [np.asarray(k, dtype=np.int64).ravel()
-                     for k in role_keys.values()]))
-            if srv.tier is not None:
-                # pin the whole batch's rows hot as ONE union before any
-                # role's routes are translated: build_routes resolves
-                # slot->hot-row per role, and a later role's forced
-                # eviction must never invalidate an earlier role's
-                # already-translated rows. Localize process-remote keys
-                # FIRST — pin_step_keys skips slot<0 entries, so a key
-                # localized later (inside build_routes) would fall
-                # outside the union's eviction protection
-                for r, k in role_keys.items():
-                    srv.ensure_local(np.asarray(k, dtype=np.int64)
-                                     .ravel(), shard)
-                srv.tier.pin_step_keys(self.role_class, role_keys)
-            routes = self.routes_for(role_keys, shard)
-            # mark the stores' dirty-delta tracking AFTER routes_for:
-            # its ensure_local may localize keys, and the marking must
-            # see the placement the step scatters into
-            _mark_fused_writes(srv, shard, self.role_class, role_keys,
-                               skip_roles=self.frozen_roles)
-            pools = tuple((s.main, s.cache, s.delta) for s in srv.stores)
-            with srv.exec.track("main"), _GATE:
-                pools, loss = self.step_fn(
-                    pools, routes, aux, jnp.float32(lr), jnp.float32(eps))
-                for st, (m, c, d) in zip(srv.stores, pools):
-                    st.main, st.cache, st.delta = m, c, d
-        self.steps += 1
-        return loss

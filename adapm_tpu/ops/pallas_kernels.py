@@ -26,9 +26,8 @@ pipelines + scalar prefetch, compiler-generated double-buffered DMA, no
 manual semaphores) and are templates, reached only from cost
 calibration (ops/costs.py): XLA's native gather was the fastest
 primitive for random row reads when they were last timed (2026-07,
-docs/PERF.md "Pallas findings": the index-map gather reached ~0.7x of
-XLA's row rate), and a manual-DMA gather meets the same tiling rule
-(ROADMAP A3).
+before the chip's ledger: PERF.md section 7, "Not measured"), and a
+manual-DMA gather meets the same tiling rule (ROADMAP A3).
 """
 from __future__ import annotations
 

@@ -48,7 +48,7 @@ class MeshContext:
 
     def put_replicated(self, arr):
         """Stage a host array for jitted programs: committed + replicated.
-        This is THE staging rule (docs/PERF.md "Host-array staging"): a
+        This is THE staging rule: a
         device-0 `jnp.asarray` gets host-resharded by every mesh-compiled
         executable per call; a replicated device_put is asynchronous
         and already in the sharding executables expect. Routed through

@@ -153,9 +153,8 @@ def run_northstar(num_keys: int = 8192, vlen: int = 16,
     """Run the full scenario (module docstring). `workdir` (a fresh
     directory; a tempdir when None) receives the checkpoint chain and
     the captured `northstar.wtrace`; the returned artifact carries
-    `wtrace_path` so the caller can replay it
-    (`bench.py --phase northstar` asserts the reads digest is stable
-    across two replays)."""
+    `wtrace_path` so the caller can replay it (the reads digest is
+    stable across two replays)."""
     import tempfile
 
     from ..fault.ckpt import IncrementalCheckpointer, restore_chain

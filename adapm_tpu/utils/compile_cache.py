@@ -1,7 +1,7 @@
 """Where XLA's persistent compilation cache lives.
 
-One rule for every entry point (`adapm_tpu.setup`, `chip_smoke.py`, the
-`bench.py` phases, `tests/conftest.py`): a directory given from outside
+One rule for every entry point (`adapm_tpu.setup`, `chip_smoke.py`,
+`tests/conftest.py`): a directory given from outside
 through `JAX_COMPILATION_CACHE_DIR` (or set on `jax.config` by the
 caller) is left alone; otherwise the cache goes to `.jax_cache/` at the
 root of the checkout. The default is a FIXED path on purpose: a later

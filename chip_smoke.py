@@ -61,7 +61,7 @@ import sys
 import time
 import traceback
 
-# full width of the one model this smoke drives (the bench.py shape)
+# full width of the one model this smoke drives
 FULL = dict(E=200_000, R=1_000, dim=128, B=4096, N=32, triples=131_072,
             L=512)
 # --rehearse-cpu only
@@ -372,7 +372,7 @@ def _timed_loops(ctx, run) -> None:
     """The app's per-step body, TIMED_STEPS times, ending once in
     block_until_ready and once in a value fetch (twice each,
     interleaved): the pair says whether block_until_ready is honest
-    here, i.e. whether bench.py still needs slope timing."""
+    here, i.e. whether a timing still needs a slope."""
     import jax
     import numpy as np
     srv, w, comp = run.srv, run.workers[0], ctx["compiles"]

@@ -15,9 +15,8 @@ Each rank times, against keys homed on the next rank:
     rows and raw-f32 vs --sys.sync.compress (fp16/int8) wire bytes per
     round (ISSUE 8 — the compressed program's future-DCN bytes)
 
-Rank 0 prints one JSON line. Results recorded in docs/PERF.md ("DCN
-data plane"). CPU platform: this path is host+DCN-bound by design — the
-numbers transfer to TPU hosts, whose data plane is the same code.
+Rank 0 prints one JSON line. CPU platform: this path is host+DCN-bound
+by design; no TPU host has run it (PERF.md section 7, "Not measured").
 """
 from __future__ import annotations
 

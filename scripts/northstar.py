@@ -1,4 +1,4 @@
-"""North-star scale runs on one TPU chip (driver targets, BASELINE.json):
+"""North-star scale runs on one TPU chip:
 
   kge  — Wikidata5M-sized ComplEx: 4.6M entities / 822 relations, d=128,
          B=4096, 32 negatives; reports ms/step and the derived epoch time
@@ -10,12 +10,13 @@
          B=16384 ratings; reports updates/s and derived epoch time over
          25M ratings.
 
-Each run drives the same PM loop as bench.py (intent for the next batch +
-a planner round per step, device-routed fused step) at full table scale —
+Each run drives the apps' PM loop (intent for the next batch + a
+planner round per step, the fused step) at full table scale —
 the point is the table SIZE (the KGE table fills most of a v5e chip's
 HBM; `--sys.main_over_alloc` close to 1 trades relocation headroom for
-fitting), not new machinery. Timing is slope-based (docs/PERF.md
-"Measurement methodology"). Prints one JSON line per workload.
+fitting), not new machinery. Timing is slope-based (`slope_time`); its
+numbers are read by nothing (PERF.md, head). Prints one JSON line per
+workload.
 
 Usage: python scripts/northstar.py [kge w2v mf]
 """
@@ -122,7 +123,7 @@ def skewed(rng, n, size):
 
 def slope_time(step, steps: int):
     """(T_long - T_short) / (steps - steps//4); step(i) must end in a
-    host-visible value only when asked (see bench.py)."""
+    host-visible value only when asked."""
     assert steps >= 4, "slope timing needs steps >= 4 (two loop lengths)"
 
     def timed(n):
@@ -140,7 +141,7 @@ def slope_time(step, steps: int):
 
 
 def pm_loop(srv, w, runner, batches, aux, lr, steps, warmup):
-    """The bench.py PM step shape: intent for the NEXT batch, fused step,
+    """The apps' PM step shape: intent for the NEXT batch, fused step,
     one planner round, clock tick."""
     nb = len(batches)
     intent_keys = [np.unique(np.concatenate([v.ravel() for v in b.values()]))

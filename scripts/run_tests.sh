@@ -17,8 +17,7 @@ cd "$(dirname "$0")/.."
 # ADAPM_LINT_BASELINE is the incremental-adoption escape hatch)
 python scripts/invariant_lint_check.py
 # fast prefetch-pipeline smoke next: a staged-pull/plan-cache regression
-# should fail in seconds, not after the full matrix (the pipeline is also
-# exercised by bench.py's prefetch phase under ADAPM_BENCH_SMALL=1)
+# should fail in seconds, not after the full matrix
 python -m pytest tests/test_prefetch.py -q
 # metrics-overhead guard + duplicate-metric-name check (ISSUE 2): the
 # registry must stay under its hot-path budget and no two subsystems may

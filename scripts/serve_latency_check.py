@@ -309,8 +309,8 @@ def main() -> int:
     srv, w, plane, rng = build()
 
     def make_batches():
-        # power-law key skew (embedding serving is zipfian, bench.py
-        # _skewed_keys): concurrent clients hit the same hot rows, which
+        # power-law key skew (embedding serving is zipfian): concurrent
+        # clients hit the same hot rows, which
         # is exactly the union-dedup case the coalescer exists for
         return [[(NK * rng.random(B) ** 3).astype(np.int64)
                  .clip(0, NK - 1) for _ in range(LOOKUPS)]

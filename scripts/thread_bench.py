@@ -12,7 +12,7 @@ pull and push ops/s at 1/2/4/8 threads hammering disjoint key slices
 
     python scripts/thread_bench.py            # prints one JSON line
 
-Interpretation caveats, recorded with the numbers in docs/PERF.md:
+Interpretation caveats:
   - on a 1-2 core host NOTHING scales (no parallelism to expose); run on
     a multi-core host to see the lock's cost, not the core count's
   - numpy routing and XLA dispatch release the GIL, so the RLock is the

@@ -1,8 +1,8 @@
 """End-to-end app smoke tests (reference tests/run_apps.sh: MF dsgd +
 columnwise, KGE, word2vec on toy datasets). Each app trains on tiny
-synthetic data and must (a) exercise the full pipeline — intent + sampling
-+ fused steps + sync rounds + quiesce — and (b) learn: loss decreases /
-MRR beats random."""
+synthetic data and must (a) exercise the full pipeline — intent + fused
+steps (which draw their own negatives) + sync rounds + quiesce — and
+(b) learn: loss decreases / MRR beats random."""
 import numpy as np
 import pytest
 
@@ -142,32 +142,45 @@ def test_word2vec_subsampling(tmp_path):
 
 @pytest.mark.parametrize("model", ["complex", "rescal"])
 def test_kge_app(model):
-    """Host-routed path (--no-device_routes): exercises the full
-    prepare_sample/pull_sample machinery; device routing is the default."""
+    """Both models train through the one fused-step path (RESCAL's
+    relation rows are a second length class)."""
     from adapm_tpu.apps import knowledge_graph_embeddings as kge
     args = kge.build_parser().parse_args(
         ["--model", model, "--dim", "8", "--neg_ratio", "2",
          "--synthetic_entities", "60", "--synthetic_relations", "4",
          "--synthetic_triples", "400", "--epochs", "6", "--batch_size", "32",
-         "--lr", "0.2", "--eval_every", "6", "--eval_triples", "60",
-         "--no-device_routes"] + FAST)
+         "--lr", "0.2", "--eval_every", "6", "--eval_triples", "60"]
+        + FAST)
     result = kge.run_app(args)
     # random MRR over 60 entities ~ 0.07; the synthetic KG is near-functional
     # (s, r) -> o, so even 2 epochs must clearly beat random
     assert result["mrr"] > 0.15, result
 
 
-def test_kge_device_routes_default():
-    """Device routing (the default): in-program routing + on-device
-    Local-scheme negative sampling trains to the same quality."""
+def test_kge_two_workers_share_one_compiled_step():
+    """Two workers on two shards: each drives its own runner, the
+    runners share ONE set of compiled programs (the worker's shard is an
+    operand of the step), every step's negatives are drawn in the
+    program (Local scheme), and the run trains to the quality bar."""
     from adapm_tpu.apps import knowledge_graph_embeddings as kge
     args = kge.build_parser().parse_args(
         ["--dim", "8", "--neg_ratio", "2", "--synthetic_entities", "60",
          "--synthetic_relations", "4", "--synthetic_triples", "400",
          "--epochs", "4", "--batch_size", "32", "--lr", "0.2",
-         "--eval_every", "4", "--eval_triples", "60"] + FAST)
-    assert args.device_routes, "device routing must be the KGE default"
-    result = kge.run_app(args)
+         "--eval_every", "4", "--eval_triples", "60",
+         "--num_shards", "2", "--num_workers", "2"] + FAST)
+    run = kge.open_run(args)
+    result = kge.train(run)
+    runners = [run.device_runner(w.shard) for w in run.workers]
+    assert len({r.shard for r in runners}) == 2
+    assert all(r.steps > 0 for r in runners)
+    assert all(r._programs is runners[0]._programs for r in runners)
+    steps = sum(r.steps for r in runners)
+    for r in runners:
+        r.locality_counts()  # folds the device counters into the registry
+    assert run.srv.obs.find("fused.rows_sampled_total").value == \
+        steps * 32 * 2
+    run.srv.shutdown()
     assert result["mrr"] > 0.12, result
 
 
@@ -194,7 +207,7 @@ def test_kge_pool_eval_matches_dense():
 def test_kge_freq_negatives_and_self_adversarial():
     """--neg_sampling freq + --self_adv_temp (the mid-scale levers,
     VERDICT r3 item 3) train the small synthetic KG at least as well as
-    uniform negatives, on both routing paths."""
+    uniform negatives."""
     from adapm_tpu.apps import knowledge_graph_embeddings as kge
     base = ["--dim", "8", "--neg_ratio", "4", "--synthetic_entities", "60",
             "--synthetic_relations", "4", "--synthetic_triples", "400",
@@ -203,9 +216,6 @@ def test_kge_freq_negatives_and_self_adversarial():
             "--neg_sampling", "freq", "--self_adv_temp", "1.0"] + FAST
     result = kge.run_app(kge.build_parser().parse_args(base))
     assert result["mrr"] > 0.12, result
-    host = kge.run_app(kge.build_parser().parse_args(
-        base + ["--no-device_routes"]))
-    assert host["mrr"] > 0.12, host
 
 
 def test_kge_scan_steps_trains():
@@ -231,7 +241,7 @@ def test_kge_scan_steps_trains():
                            "multi-core CI/judge hosts")
 def test_kge_midscale_levers_beat_uniform():
     """Mid-scale lowrank (5k entities, 60k triples — the scale where
-    uniform negatives saturate, docs/PERF.md 'Quality'): frequency-based
+    uniform negatives saturate): frequency-based
     negatives + self-adversarial weighting must clearly beat uniform at
     an identical budget (VERDICT r3 item 3). Measured at this config:
     uniform test-MRR 0.022, freq+selfadv 0.044, ceiling 0.34 (o=0.49)."""
@@ -260,8 +270,8 @@ def test_kge_lr_decay_beats_constant():
     """--lr_decay breaks into the round-4 quality plateau (VERDICT r4
     item 8): at an identical 25-epoch budget on the mid-scale lowrank
     harness, a 0.93/epoch schedule must clearly beat constant lr.
-    Measured at exactly this config incl. --num_shards 2 (round 5,
-    docs/PERF.md 'Quality'): constant 0.036 (10.6% of ceiling) vs
+    Measured at exactly this config incl. --num_shards 2 (round 5):
+    constant 0.036 (10.6% of ceiling) vs
     decayed 0.056 (16.4%) — a 1.56x margin against the 1.2x bar."""
     from adapm_tpu.apps import knowledge_graph_embeddings as kge
     base = ["--dim", "32", "--neg_ratio", "64",
@@ -287,9 +297,8 @@ def test_kge_midscale_ceiling_fraction():
     generator's dim_truth, lr 0.7 x 0.93/epoch, freq + self-adv 3.0)
     must reach >= 25% of the generating model's own filtered-MRR
     ceiling on the 5k-entity lowrank harness in 20 epochs. Measured
-    0.150 / 0.340 = 44.1% at exactly this config (docs/PERF.md
-    'Breaking the plateau'); the floor leaves ~1.75x margin for seed
-    and scheduling noise."""
+    0.150 / 0.340 = 44.1% at exactly this config; the floor leaves
+    ~1.75x margin for seed and scheduling noise."""
     from adapm_tpu.apps import knowledge_graph_embeddings as kge
     res = kge.run_app(kge.build_parser().parse_args(
         ["--dim", "64", "--neg_ratio", "64",
@@ -350,7 +359,7 @@ def test_kge_lowrank_reaches_truth_ceiling_fraction():
     model and reports that model's own filtered MRR as the ceiling; a
     trained model must reach a solid fraction of it (quality evidence on
     a graph that is learnable BY CONSTRUCTION, unlike the adversarial
-    permutation KG — docs/PERF.md 'Quality on a learnable synthetic')."""
+    permutation KG)."""
     from adapm_tpu.apps import knowledge_graph_embeddings as kge
     args = kge.build_parser().parse_args(
         ["--dim", "32", "--neg_ratio", "4", "--synthetic_entities", "200",
@@ -389,9 +398,8 @@ def test_lowrank_generator_device_matches_host():
 
 def test_kge_l2_regularizer_shrinks_norms():
     """--l2 (lazy ComplEx-paper L2 on the positive triple's rows; the
-    lever that first broke the 237-relation wall, docs/PERF.md 'The
-    axis isolated') must actually shrink embedding norms vs the
-    reference-parity unregularized loss at identical budget/seed."""
+    lever that first broke the 237-relation wall) must actually shrink
+    embedding norms vs the reference-parity unregularized loss at identical budget/seed."""
     import numpy as np
     from adapm_tpu.apps import knowledge_graph_embeddings as kge
     base = ["--dim", "8", "--neg_ratio", "4",
@@ -403,3 +411,21 @@ def test_kge_l2_regularizer_shrinks_norms():
     r1 = kge.run_app(kge.build_parser().parse_args(base + ["--l2", "0.1"]))
     assert np.isfinite(r1["loss"])
     assert r1["ent_norm"] < 0.9 * r0["ent_norm"], (r1, r0)
+
+
+@pytest.mark.parametrize("app", ["knowledge_graph_embeddings", "word2vec",
+                                 "matrix_factorization"])
+def test_apps_have_one_step_path(app):
+    """The pin (PR 28): an app has no switch between step runners, and
+    `adapm_tpu.ops` has no second runner to switch to."""
+    import importlib
+
+    import adapm_tpu.ops
+    parser = importlib.import_module(f"adapm_tpu.apps.{app}").build_parser()
+    for flag in ("--no-device_routes", "--device_routes"):
+        with pytest.raises(SystemExit):
+            parser.parse_args([flag])
+    for name in ("FusedStepRunner", "build_routes",
+                 "make_fused_adagrad_step"):
+        assert not hasattr(adapm_tpu.ops, name)
+        assert not hasattr(adapm_tpu.ops.fused, name)

@@ -3,7 +3,7 @@
 A TPU path can die AT SETUP — client construction aborting before any
 phase runs. `probe_device_backend` detects that in a throwaway
 subprocess and `require_device_backend` turns it into the NAMED
-AcceleratorUnavailableError; bench.py fails its device phases with it.
+AcceleratorUnavailableError.
 """
 import os
 import sys

@@ -5,8 +5,8 @@ can pass without the chip, checked on the CPU.
   JAX_COMPILATION_CACHE_DIR is left alone; otherwise one fixed directory
   inside the checkout, whatever the working directory;
 - pools are allocated IN their sharding (device/jaxport.py alloc_pool);
-- `chip_smoke.py` and the bench device phases refuse a CPU by name, and
-  the explicit rehearsal still runs end to end;
+- `chip_smoke.py` refuses a CPU by name, and the explicit rehearsal
+  still runs end to end;
 - a failed native-router build is reported, not silent.
 """
 import json
@@ -99,15 +99,6 @@ def test_chip_smoke_rehearsal_runs_end_to_end():
     assert out == {"ok": True, "rehearsal": True,
                    "device": {"platform": "cpu", "kind": "cpu", "count": 1}}
     assert all(ln.startswith("platform=cpu | ") for ln in lines[:-1])
-
-
-def test_bench_device_phase_refuses_cpu():
-    """No ADAPM_BENCH_SMALL, no TPU: the phase fails by name and prints
-    no timing."""
-    p = _run(["bench.py", "--phase", "kge"], drop=("ADAPM_BENCH_SMALL",))
-    assert p.returncode != 0
-    assert "AcceleratorUnavailableError" in p.stderr
-    assert "tput" not in p.stdout
 
 
 def test_native_build_failure_is_reported(tmp_path):

@@ -1,13 +1,15 @@
-"""Device-routed fused step: must produce the same training updates as the
-host-routed FusedStepRunner (same routing policy, resolved in-program), and
-its table mirrors must track planner placement changes."""
+"""Device-routed fused step: must produce the training updates of the
+numpy AdaGrad reference (routing resolved in-program by the policy of
+Server._route), and its table mirrors must track planner placement
+changes."""
 import numpy as np
 import pytest
 
 import adapm_tpu
 from adapm_tpu.base import CLOCK_MAX
 from adapm_tpu.config import SystemOptions
-from adapm_tpu.ops import DeviceRoutedRunner, FusedStepRunner
+from adapm_tpu.ops import DeviceRoutedRunner
+from test_fused_ops import numpy_adagrad
 
 
 def _loss(embs, aux):
@@ -26,25 +28,28 @@ def _make(num_keys=24, L=8):
     return srv, w
 
 
-def test_matches_host_routed():
+def test_matches_numpy_adagrad():
+    """24 keys x 5 steps against the numpy AdaGrad reference: losses and
+    every row of the table."""
     kw = dict(role_class={"a": 0, "b": 0}, role_dim={"a": 4, "b": 4})
-    srv1, w1 = _make()
-    host = FusedStepRunner(srv1, _loss, **kw)
-    srv2, w2 = _make()
-    dev = DeviceRoutedRunner(srv2, _loss, shard=0, **kw)
+    srv, w = _make()
+    dev = DeviceRoutedRunner(srv, _loss, shard=0, **kw)
+    want = srv.read_main(np.arange(24)).reshape(24, 8).copy()
 
     rng = np.random.default_rng(1)
     for _ in range(5):
         batch = {"a": rng.integers(0, 24, 16).astype(np.int64),
                  "b": rng.integers(0, 24, 16).astype(np.int64)}
-        l1 = host(batch, None, 0.1)
-        l2 = dev(batch, None, 0.1)
-        assert np.allclose(float(l1), float(l2), rtol=1e-5)
-    v1 = srv1.read_main(np.arange(24))
-    v2 = srv2.read_main(np.arange(24))
-    assert np.allclose(v1, v2, atol=1e-5)
-    srv1.shutdown()
-    srv2.shutdown()
+        got = float(dev(batch, None, 0.1))
+        a, b = want[batch["a"], :4], want[batch["b"], :4]
+        dot = (a * b).sum(-1)
+        assert np.isclose(got, (dot ** 2).mean(), rtol=1e-5)
+        d_dot = 2 * dot[:, None] / len(dot)
+        numpy_adagrad(want, 4, batch, {"a": d_dot * b, "b": d_dot * a},
+                      0.1)
+    got = srv.read_main(np.arange(24)).reshape(24, 8)
+    np.testing.assert_allclose(got, want, atol=1e-6, rtol=0)
+    srv.shutdown()
 
 
 def test_tracks_placement_changes():
@@ -353,23 +358,23 @@ def test_run_scan_draws_the_alias_negatives_of_sequential_steps():
     assert np.allclose(losses[0], losses[1], rtol=1e-5)
 
 
-def test_w2v_device_routes_matches_host(tmp_path):
-    """The w2v app trains with on-device unigram^0.75 negatives and lands
-    at a loss comparable to the host-routed run on the same fixed seed
-    (VERDICT r2 item 5 'done' criterion)."""
+def test_w2v_app_learns_past_its_first_epoch(tmp_path):
+    """The w2v app trains with on-device unigram^0.75 negatives: on a
+    fixed seed the third epoch's loss is well under the first's (0.770
+    of it at the parent of PR 28; `--lr 0` gives 1.0 and fails) and
+    under the untrained loss."""
     from adapm_tpu.apps import word2vec as w2v
     base = ["--synthetic_vocab", "80", "--synthetic_sentences", "120",
             "--synthetic_path", str(tmp_path / "c.txt"),
             "--dim", "8", "--window", "3", "--negative", "4",
-            "--epochs", "3", "--batch_size", "256", "--lr", "0.03",
+            "--batch_size", "256", "--lr", "0.03",
             "--readahead", "30", "--seed", "11",
             "--sys.sync.max_per_sec", "0", "--sys.prefetch", "0"]
-    host = w2v.run(w2v.build_parser().parse_args(
-        base + ["--no-device_routes"]))
-    dev = w2v.run(w2v.build_parser().parse_args(base + ["--device_routes"]))
+    first, last = (w2v.run(w2v.build_parser().parse_args(
+        base + ["--epochs", n])) for n in ("1", "3"))
     untrained = np.log(2.0) * 5
-    assert dev < 0.9 * untrained, f"device path did not learn: {dev}"
-    assert abs(dev - host) < 0.35 * max(host, 1e-6), (dev, host)
+    assert last < 0.9 * untrained, f"did not learn: {last}"
+    assert last < 0.85 * first, (last, first)
 
 
 def test_run_scan_matches_sequential_steps():
@@ -473,18 +478,19 @@ def test_device_routed_locality_stats():
     srv.shutdown()
 
 
-def test_mf_device_routes_matches_host():
-    """MF app with --device_routes converges like the host-routed run."""
+def test_mf_app_learns_past_its_first_epoch():
+    """MF app: on a fixed seed the fifth epoch's loss is well under the
+    first's (0.276 of it at the parent of PR 28; `--lr 0` gives 1.0 and
+    fails)."""
     from adapm_tpu.apps import matrix_factorization as mf
     base = ["--rows", "48", "--cols", "32", "--nnz", "600", "--rank", "4",
-            "--epochs", "5", "--batch_size", "16", "--lr", "0.1",
+            "--batch_size", "16", "--lr", "0.1",
             "--algorithm", "plain", "--seed", "5",
             "--sys.sync.max_per_sec", "0", "--sys.prefetch", "0"]
-    host = mf.run(mf.build_parser().parse_args(
-        base + ["--no-device_routes"]))
-    dev = mf.run(mf.build_parser().parse_args(base + ["--device_routes"]))
-    assert np.isfinite(dev)
-    assert dev < 1.3 * host + 1e-6, (dev, host)
+    first, last = (mf.run(mf.build_parser().parse_args(
+        base + ["--epochs", n])) for n in ("1", "5"))
+    assert np.isfinite(last)
+    assert last < 0.5 * first, (last, first)
 
 
 # ---- the replica-free write-back's two row-movers (ISSUE 25) --------------

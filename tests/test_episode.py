@@ -11,9 +11,8 @@ serve lookups) bit-identical at every step and after quiesce. Episodic
 execution changes WHEN values move, never WHAT a read returns.
 
 Plus: the DevicePort surface (programs counted, pool swap-out), the
-partition helper, the serialized/inline degradation, FusedStepRunner
-support (pin-only prep, no key staging), and the v10 device/episode
-snapshot sections.
+partition helper, the serialized/inline degradation, and the v10
+device/episode snapshot sections.
 """
 import numpy as np
 import jax.numpy as jnp
@@ -180,31 +179,6 @@ def test_episodic_single_stream_degrades_inline(rng):
         losses = EpisodicRunner(_runner(srv),
                                 episode_batches=2).run(bs, lr=0.05)
         assert len(losses) == len(bs)
-        outs.append(_read_all(srv))
-        srv.shutdown()
-    assert np.array_equal(outs[0], outs[1])
-
-
-def test_episodic_fused_step_runner_pin_only_prep(rng):
-    """FusedStepRunner (host routes, no prefetch_keys): episodic prep
-    degrades to pin/promote only and stays bit-identical."""
-    from adapm_tpu.ops import FusedStepRunner
-    vals = _init_vals(rng)
-    kb = np.random.default_rng(13)
-    bs = [{"a": kb.integers(0, E, 16), "b": kb.integers(0, E, 16)}
-          for _ in range(6)]
-    outs = []
-    for episodic in (True, False):
-        srv = _mk(True, hot_rows=16)
-        w = srv.make_worker(0)
-        w.set(np.arange(E), vals)
-        run = FusedStepRunner(srv, _loss, {"a": 0, "b": 0},
-                              {"a": D, "b": D})
-        if episodic:
-            EpisodicRunner(run, episode_batches=2).run(bs, lr=0.05)
-        else:
-            for b in bs:
-                run(b, None, 0.05)
         outs.append(_read_all(srv))
         srv.shutdown()
     assert np.array_equal(outs[0], outs[1])

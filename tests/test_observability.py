@@ -68,25 +68,58 @@ def test_trace_events_and_locality_files(tmp_path):
         assert acc >= local
 
 
-def test_locality_counts_fused_path(tmp_path):
-    """The fused-step routing records locality too (the hot loop is where
-    the reference counts most accesses)."""
-    import jax.numpy as jnp
-    from adapm_tpu.ops import FusedStepRunner
+@pytest.mark.parametrize("path", ["step", "staged", "scan"])
+def test_per_key_locality_counts_device_routed_step(path):
+    """--sys.stats.locality: a fused step records its host-known keys in
+    the per-key counters (the hot loop is where the reference counts
+    most accesses), once for each occurrence on every dispatch path
+    (staging a batch ahead records nothing), and `local` by the mask of
+    Server._route, here after a relocation. With the option off there
+    are no per-key counters at all."""
+    from adapm_tpu.ops import DeviceRoutedRunner
+
+    off = adapm_tpu.setup(16, 8, opts=SystemOptions(sync_max_per_sec=0))
+    assert off.locality is None
+    off.shutdown()
 
     opts = SystemOptions(locality_stats=True, sync_max_per_sec=0)
     srv = adapm_tpu.setup(16, 8, opts=opts)
     w = srv.make_worker(0)
     w.set(np.arange(16), np.ones((16, 8), np.float32))
+    moved = np.array([k for k in range(16)
+                      if srv.ab.owner[k] != w.shard][:3], dtype=np.int64)
+    w.intent(moved, 0, CLOCK_MAX)
+    srv.wait_sync()
+    assert (srv.ab.owner[moved] == w.shard).all()  # relocated here
 
     def loss_fn(embs, aux):
-        return (embs["x"] ** 2).mean()
+        return (embs["x"] ** 2).mean() + (embs["y"] ** 2).mean()
 
-    runner = FusedStepRunner(srv, loss_fn, role_class={"x": 0},
-                             role_dim={"x": 4})
-    runner({"x": np.arange(8, dtype=np.int64)}, None, 0.1)
-    assert int(srv.locality.accesses.sum()) >= 8
-    summ = srv.locality_summary()
+    runner = DeviceRoutedRunner(srv, loss_fn, role_class={"x": 0, "y": 0},
+                                role_dim={"x": 4, "y": 4}, shard=w.shard)
+    rng = np.random.default_rng(5)
+    batches = [{"x": np.concatenate([moved, rng.integers(0, 16, 5)]),
+                "y": rng.integers(0, 16, (4, 2)).astype(np.int64)}
+               for _ in range(2 if path == "scan" else 1)]
+    acc0, loc0 = srv.locality.accesses.copy(), srv.locality.local.copy()
+    if path == "scan":
+        runner.run_scan(batches, None, 0.1)
+    else:
+        stg = runner.prefetch_keys(batches[0]) if path == "staged" else None
+        runner(batches[0], None, 0.1, staged=stg)
+
+    flat = np.concatenate([k.ravel() for b in batches for k in b.values()])
+    local = srv._route(flat, w.shard, record=False)[-1].astype(bool)
+    assert local[np.isin(flat, moved)].all() and not local.all()
+    assert np.array_equal(srv.locality.accesses - acc0,
+                          np.bincount(flat, minlength=16))
+    assert np.array_equal(srv.locality.local - loc0,
+                          np.bincount(flat[local], minlength=16))
+    # the same accesses, counted in the program, feed the summary
+    counts = runner.locality_counts()
+    assert counts["params"] == len(flat)
+    assert counts["params_local"] == int(local.sum())
+    srv.locality_summary()
     srv.shutdown()
 
 

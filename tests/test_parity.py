@@ -1,6 +1,6 @@
 """Reproducible quality-parity harness on the reference's OWN bundled
 datasets (/root/reference/apps/data — the exact files its CI trains on,
-tests/run_apps.sh:3-13). Pins the quality floors recorded in BASELINE.md so
+tests/run_apps.sh:3-13). Pins the quality floors of those runs so
 the parity evidence is one `pytest -m parity` away instead of a manual run
 (VERDICT r2 item 4).
 
@@ -28,7 +28,7 @@ pytestmark = [
 
 def test_parity_kge_complex_toy():
     """Reference CI config (run_apps.sh): 280 entities, 112 relations,
-    dim 10, 4 epochs. BASELINE.md records test filtered MRR 0.445 /
+    dim 10, 4 epochs. A typical run reads test filtered MRR 0.445 /
     Hits@10 0.727 (random ~0.02); floor at MRR >= 0.30, Hits@10 >= 0.55."""
     from adapm_tpu.apps import knowledge_graph_embeddings as kge
     args = kge.build_parser().parse_args(
@@ -50,7 +50,7 @@ def test_parity_kge_complex_toy():
 def test_parity_mf_toy(algorithm):
     """Reference CI config: 6x4 toy matrix, both access orders. The data
     file carries large entries (loss starts ~750); training must cut the
-    squared error well below the untrained start (BASELINE.md: 751 -> 652
+    squared error well below the untrained start (typically 751 -> 652
     in 4 epochs at rank 10; with more epochs it keeps falling)."""
     from adapm_tpu.apps import matrix_factorization as mf
     from adapm_tpu.io.mf import read_coo
@@ -67,9 +67,9 @@ def test_parity_mf_toy(algorithm):
 
 def test_parity_word2vec_small():
     """Reference CI config: lm/small.txt, SGNS. The pipeline (readahead
-    intent + PrepareSample negatives) must run on the real corpus and the
+    intent + in-program negatives) must run on the real corpus and the
     sigmoid-CE loss must fall below the untrained level (~ln2 * (1+neg)
-    per token pair ~ 4.16 for neg=5; BASELINE.md records 2.79 after one
+    per token pair ~ 4.16 for neg=5; a typical run reads 2.79 after one
     epoch)."""
     from adapm_tpu.apps import word2vec as w2v
     args = w2v.build_parser().parse_args(

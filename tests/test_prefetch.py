@@ -286,20 +286,19 @@ def test_fused_step_invalidates_staged(ctx):
     """The fused step is a batched Push in PM terms: it must invalidate
     staged pull buffers covering the trained keys (review finding r6)."""
     from adapm_tpu.models import make_kge_loss
-    from adapm_tpu.ops import FusedStepRunner
+    from adapm_tpu.ops import DeviceRoutedRunner
 
     s = make_server(ctx, num_keys=40, vlen=8)  # row = [emb 4 | acc 4]
     w = s.make_worker(0)
     w.wait(w.set(np.arange(40), np.full((40, 8), 0.1, np.float32)))
-    runner = FusedStepRunner(
+    runner = DeviceRoutedRunner(
         s, make_kge_loss("complex"),
         role_class={"s": 0, "r": 0, "o": 0, "neg": 0},
-        role_dim={k: 4 for k in ("s", "r", "o", "neg")})
+        role_dim={k: 4 for k in ("s", "r", "o", "neg")}, shard=w.shard)
     uk = np.unique(np.array([1, 2, 3, 4]))
     _stage(s, w, uk)
     assert s.prefetch.report()["live"] == 1
-    runner({"s": uk, "r": uk, "o": uk,
-            "neg": uk}, None, 0.5, shard=w.shard)
+    runner({"s": uk, "r": uk, "o": uk, "neg": uk}, None, 0.5)
     assert s.prefetch.stats["invalidated_write"] >= 1
     got = w.pull_sync(uk)
     expect = s.read_main(uk).reshape(4, 8)

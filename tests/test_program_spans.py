@@ -334,18 +334,6 @@ def _lowered_device_step(ctx):
     return texts[0]
 
 
-def _lowered_legacy_step():
-    step = fused.make_fused_adagrad_step(
-        lambda e, aux: (e["a"] * e["b"]).sum(), {"a": 0, "b": 0},
-        {"a": VL // 2, "b": VL // 2})
-    pool = jnp.ones((2, 8, VL))
-    idx = jnp.zeros(4, jnp.int32)
-    route = (idx, idx, idx, idx, jnp.zeros(4, bool))
-    return step.lower(((pool, pool, pool),), {"a": route, "b": route},
-                      None, jnp.float32(0.1),
-                      jnp.float32(1e-10)).as_text(debug_info=True)
-
-
 def _lowered_port_programs():
     from adapm_tpu.device import jaxport as jp
     pool = jnp.ones((2, 8, VL))
@@ -371,13 +359,6 @@ def test_device_routed_step_carries_scope_names(ctx):
     text = _lowered_device_step(ctx)
     for scope in ("adapm_route", "adapm_sampler", "adapm_gather",
                   "adapm_loss_grad", "adapm_adagrad",
-                  "adapm_scatter_add"):
-        assert scope in text, scope
-
-
-def test_legacy_step_carries_scope_names():
-    text = _lowered_legacy_step()
-    for scope in ("adapm_gather", "adapm_loss_grad", "adapm_adagrad",
                   "adapm_scatter_add"):
         assert scope in text, scope
 

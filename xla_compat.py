@@ -18,9 +18,8 @@ import sys
 class AcceleratorUnavailableError(RuntimeError):
     """An accelerator backend cannot be used in this environment —
     NAMED (ISSUE 14 satellite): the TPU path can die AT SETUP (client
-    construction aborts / hangs before the first program). `bench.py`
-    fails its device phases with this name; nothing degrades to
-    another backend."""
+    construction aborts / hangs before the first program). Nothing
+    degrades to another backend."""
 
 
 def probe_device_backend(platform=None, timeout: float = 180.0):

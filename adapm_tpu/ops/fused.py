@@ -36,7 +36,6 @@ without `neg_role` takes its negatives from the caller like any role.
 """
 from __future__ import annotations
 
-import functools
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -98,43 +97,45 @@ def _scatter_update(main, delta, route, upd):
 def writeback_uses_kernel(main, backend: str = None) -> bool:
     """Which row-mover the replica-free write-back into this pool is
     compiled with: the Pallas kernel (pallas_kernels
-    .scatter_add_sorted_rows) where the backend (jax's default unless
-    given) is a TPU and the pool is ONE float32 shard on the step's
-    device (`[1, slots, L]`: a mesh has as many devices as shards, and a
-    Pallas call does not partition under GSPMD) whose rows the kernel
-    can copy (L a multiple of the 128 lanes, slots of the 8 rows of a
-    tile); XLA's scatter-add everywhere else. A static property of the
-    compiled variant, read from the pool's shape and dtype."""
+    .scatter_adagrad_sorted_rows, which forms the AdaGrad update rows
+    itself) where the backend (jax's default unless given) is a TPU and
+    the pool is ONE float32 shard on the step's device (`[1, slots, L]`:
+    a mesh has as many devices as shards, and a Pallas call does not
+    partition under GSPMD) whose rows the kernel can copy and form: the
+    row's two halves, [emb | accumulator], each a whole number of the
+    128 lanes (L a multiple of 256), slots of the 8 rows of a tile;
+    `_adagrad_update` and XLA's scatter-add everywhere else. A static
+    property of the compiled variant, read from the pool's shape and
+    dtype."""
     backend = jax.default_backend() if backend is None else backend
     return (backend == "tpu" and main.ndim == 3
             and main.shape[0] == 1 and main.dtype == jnp.float32
-            and main.shape[2] % 128 == 0 and main.shape[1] % 8 == 0)
+            and main.shape[2] % 256 == 0 and main.shape[1] % 8 == 0)
 
 
 def _kernel_writeback(main, o_sh, o_sl, g, acc, lr, eps):
     """The replica-free write-back of one role through the kernel: the
-    same rows as `main.at[o_sh, o_sl].add(_adagrad_update(g, acc),
-    mode="drop")`, computed in the order of their slots (gradients and
-    accumulators are brought into it, so the update rows never exist in
-    batch order: no second copy of them), one kernel call for each
-    `writeback.MAX_POSITIONS` of them. The sort and the permuting
-    gathers are the write-back's cost and carry its scope."""
+    same rows as `main.at[o_sh, o_sl].add(_adagrad_update(g, acc, lr,
+    eps), mode="drop")`, in the order of their slots. Gradients and
+    accumulators (as gathered before any write-back of the step) are
+    brought into that order and handed to the kernel, which forms each
+    chunk's update rows in VMEM: the update rows are never an array.
+    One kernel call for each `writeback.MAX_POSITIONS` positions. The
+    sort and the permuting gathers are the write-back's cost and carry
+    its scope."""
     n_slots, L = main.shape[1:]
-    scope = functools.partial(jax.named_scope, "adapm_scatter_add")
     # one shard: a row lands iff its shard index is 0 (or wraps to it)
     o_sl = jnp.where((o_sh == 0) | (o_sh == -1), o_sl, OOB)
     rows = writeback.chunk_rows_for(L)
-    g, acc = (x.reshape(-1, x.shape[-1]) for x in (g, acc))
-    with scope():
-        slices = writeback.sorted_slices(o_sl.reshape(-1), n_slots, rows)
+    g, acc = (x.reshape(-1, L // 2) for x in (g, acc))
+    lr, eps = (jnp.asarray(x, jnp.float32) for x in (lr, eps))
     pool = main[0]
-    for codes, perm in slices:
-        with scope():
-            g_sorted, acc_sorted = g[perm], acc[perm]
-        upd = _adagrad_update(g_sorted, acc_sorted, lr, eps)
-        with scope():
-            pool = writeback.kernel(n_slots, L, codes.shape[0], rows)(
-                pool, codes, upd)
+    with jax.named_scope("adapm_scatter_add"):
+        for codes, perm in writeback.sorted_slices(o_sl.reshape(-1),
+                                                   n_slots, rows):
+            pool = writeback.kernel(n_slots, L, codes.shape[0], rows,
+                                    adagrad=True)(
+                pool, codes, g[perm], acc[perm], lr, eps)
     return pool[None]
 
 
@@ -142,7 +143,9 @@ def _kernel_writeback(main, o_sh, o_sl, g, acc, lr, eps):
 # (adapm_route, adapm_sampler, adapm_gather, adapm_loss_grad,
 # adapm_adagrad, adapm_scatter_add): compile-time metadata on the
 # step's operations, so a device trace can be reduced by part whatever
-# the compiler numbers its fusions (PERF.md section 3).
+# the compiler numbers its fusions (PERF.md section 3). Where the
+# write-back kernel runs, AdaGrad is inside its custom call, under
+# adapm_scatter_add, and no operation carries adapm_adagrad.
 
 
 def _loss_and_grads(loss_fn, embs, trainable, aux):

@@ -5,21 +5,30 @@ installed Mosaic compiler and match numpy on the chip — `chip_smoke.py`,
 part "kernels", checks that on every chip run; the CPU tests run them in
 interpret mode only.
 
-`scatter_add_rows` / `scatter_add_sorted_rows` is on the main path: the
-fused step's replica-free write-back on one chip (ops/fused.py
-`writeback_uses_kernel`; the step sorts the slots and takes the kernel
-through ops/writeback.py, exported once, so that a process whose step
-comes from the compile cache does not import this module or Pallas at
-all; one call for each 131,072 sorted positions, because a call's codes
-are one SMEM operand). It is the first manual-DMA kernel here
-(`make_async_copy` from HBM refs, own semaphores, copies of three chunks
-in flight). What it could NOT be is a row-wise copy: the pool's layout
-is XLA's (8, 128) tiling, where an 8 KB row is 16 pieces of 512 B, and
-Mosaic refuses a one-row slice of a tiled memref ("must be aligned to
-tiling (8)"), in HBM and in VMEM alike. So it moves whole 8-row groups.
-Measured on the chip (PERF.md section 6, PR 25): 141-189 ns a row with
-the sort and the permutation against 280 for XLA's scatter-add, bound by
-HBM bandwidth at 8 rows moved for each one changed.
+The write-back kernel is on the main path, in two forms of ONE body
+(`_scatter_add_kernel`). `scatter_adagrad_sorted_rows` is the fused
+step's replica-free write-back on one chip (ops/fused.py
+`writeback_uses_kernel`, `_kernel_writeback`): the step sorts the slots,
+brings gradients and gathered accumulators into that order, and the
+kernel forms each chunk's AdaGrad update rows
+`[-lr * g * rsqrt(acc + g*g + eps) | g*g]` in VMEM and adds them to
+their pool rows, so the update rows are never written to HBM nor read
+back (PR 29; `lr`, `eps` are SMEM operands). `scatter_add_rows` /
+`scatter_add_sorted_rows` is the plain form: rows given, added
+(`chip_smoke.py`, `scripts/writeback_probe.py`, the tests). The step
+takes the kernel through ops/writeback.py, exported once, so that a
+process whose step comes from the compile cache does not import this
+module or Pallas at all; one call for each 131,072 sorted positions,
+because a call's codes are one SMEM operand. It is the first manual-DMA
+kernel here (`make_async_copy` from HBM refs, own semaphores, copies of
+three chunks in flight). What it could NOT be is a row-wise copy: the
+pool's layout is XLA's (8, 128) tiling, where an 8 KB row is 16 pieces
+of 512 B, and Mosaic refuses a one-row slice of a tiled memref ("must be
+aligned to tiling (8)"), in HBM and in VMEM alike. So it moves whole
+8-row groups. Measured on the chip (PERF.md section 6, PR 25): 141-189
+ns a row with the sort and the permutation against 280 for XLA's
+scatter-add, bound by HBM bandwidth at 8 rows moved for each one
+changed.
 
 `gather_rows` and `adagrad_apply` use only the BlockSpec subset (grid
 pipelines + scalar prefetch, compiler-generated double-buffered DMA, no
@@ -122,26 +131,40 @@ def adagrad_apply(grads: jnp.ndarray, emb: jnp.ndarray, acc: jnp.ndarray,
 _GROUP_BUFFERS = 3  # chunk c's groups live in buffer c % 3
 
 
-def _scatter_add_kernel(code_ref, _pool_in, upd_hbm, pool_hbm,
-                        gbuf, ubuf, counts, gsem, usem, wsem, *,
-                        rows: int, n_chunks: int):
+def _scatter_add_kernel(code_ref, _pool_in, *refs, rows: int, n_chunks: int,
+                        adagrad: bool):
     """One pass over `n_chunks` chunks of `rows` sorted positions.
 
     code_ref: `sort_slots`'s codes, SMEM (scalar prefetch). `_pool_in` is
     the same HBM buffer as `pool_hbm` (input_output_aliases), the one
-    name the kernel reads and writes the pool by. `upd_hbm` holds the
-    update rows in sorted order. A pool of (8, 128)-tiled rows can only
-    be copied in whole groups of 8 rows (Mosaic refuses a one-row slice
-    of a tiled memref), so the unit read, summed into and written back
-    is the 8-row group, 8 * L * 4 contiguous bytes. gbuf: [3, rows, 8, L]
-    VMEM, one group per position that opens a run of its group; ubuf:
-    [2, rows, L]; counts: SMEM, the reads and writes started per buffer.
+    name the kernel reads and writes the pool by. A pool of (8, 128)-tiled
+    rows can only be copied in whole groups of 8 rows (Mosaic refuses a
+    one-row slice of a tiled memref), so the unit read, summed into and
+    written back is the 8-row group, 8 * L * 4 contiguous bytes. gbuf:
+    [3, rows, 8, L] VMEM, one group per position that opens a run of its
+    group; ubuf: [2, rows, L], the chunk's update rows; counts: SMEM, the
+    reads and writes started per buffer.
+
+    Where a chunk's update rows come from is the one thing the kernel's
+    two forms differ in (`adagrad`, a static). Plain: `refs` starts with
+    `upd_hbm` [n, L], the rows themselves in sorted order, copied to
+    ubuf. AdaGrad: with `g_hbm`, `acc_hbm` ([n, L/2] each: gradients and
+    accumulators as gathered, in sorted order) and `hyper` (SMEM,
+    float32 [lr, eps]): the two halves are copied side by side into ubuf
+    and the chunk's rows [-lr * g * rsqrt(acc + g*g + eps) | g*g] formed
+    there in place, once a chunk, so the update rows are never in HBM.
 
     Only the first position of a run of one group reads it and only the
     last writes it, so no two copies in flight touch one group. A run
     that crosses a chunk boundary moves its group, as summed so far, to
     position 0 of the next chunk's buffer."""
     nb = _GROUP_BUFFERS
+    if adagrad:
+        g_hbm, acc_hbm, hyper, pool_hbm, gbuf, ubuf, counts, gsem, usem, \
+            wsem = refs
+        half = ubuf.shape[2] // 2
+    else:
+        upd_hbm, pool_hbm, gbuf, ubuf, counts, gsem, usem, wsem = refs
 
     def group_copy(code, b, j, sem, to_pool: bool):
         g0 = pl.multiple_of(code & (SLOT_MASK & ~(GROUP - 1)), GROUP)
@@ -150,14 +173,34 @@ def _scatter_add_kernel(code_ref, _pool_in, upd_hbm, pool_hbm,
         return pltpu.make_async_copy(buf, hbm, sem) if to_pool else \
             pltpu.make_async_copy(hbm, buf, sem)
 
-    def upd_copy(c, s):
-        r0 = pl.multiple_of(c * rows, rows)
-        return pltpu.make_async_copy(upd_hbm.at[pl.ds(r0, rows)],
-                                     ubuf.at[s], usem.at[s])
+    def upd_copies(c, s):
+        """Chunk c's copies into ubuf[s], all on usem[s]."""
+        at = pl.ds(pl.multiple_of(c * rows, rows), rows)
+        if not adagrad:
+            return [pltpu.make_async_copy(upd_hbm.at[at], ubuf.at[s],
+                                          usem.at[s])]
+        return [pltpu.make_async_copy(
+            src.at[at], ubuf.at[s, :, pl.ds(lane, half)], usem.at[s])
+            for src, lane in ((g_hbm, 0), (acc_hbm, half))]
+
+    def form_chunk(c, s):
+        """Wait for chunk c's copies; ubuf[s] then holds its update
+        rows (the AdaGrad form computes them from the two halves, all
+        `rows` positions at once, dropped and padding ones too: their
+        rows are finite and never added)."""
+        for copy in upd_copies(c, s):
+            copy.wait()
+        if adagrad:
+            g = ubuf[s, :, pl.ds(0, half)]
+            g2 = g * g
+            ubuf[s, :, pl.ds(0, half)] = -hyper[0] * g * jax.lax.rsqrt(
+                ubuf[s, :, pl.ds(half, half)] + g2 + hyper[1])
+            ubuf[s, :, pl.ds(half, half)] = g2
 
     def start_reads(c):
         b, s = c % nb, c % 2
-        upd_copy(c, s).start()
+        for copy in upd_copies(c, s):
+            copy.start()
 
         def row(j, started):
             code = code_ref[c * rows + j]
@@ -221,7 +264,7 @@ def _scatter_add_kernel(code_ref, _pool_in, upd_hbm, pool_hbm,
 
     def chunk(c, tgt_prev):
         b = c % nb
-        upd_copy(c, c % 2).wait()
+        form_chunk(c, c % 2)
         wait_all(counts[0, b], b, gsem.at[b], to_pool=False)
         tgt = compute_and_write(c, tgt_prev)
 
@@ -237,6 +280,43 @@ def _scatter_add_kernel(code_ref, _pool_in, upd_hbm, pool_hbm,
     jax.lax.fori_loop(0, n_chunks, chunk, jnp.int32(0))
     lb = (n_chunks - 1) % nb
     wait_all(counts[1, lb], lb, wsem.at[lb], to_pool=True)
+
+
+def _sorted_rows_call(pool, codes, *operands, chunk_rows: int,
+                      interpret: bool, adagrad: bool):
+    """The one `pallas_call` of `_scatter_add_kernel`: `operands` are
+    (upd_sorted,) or, in the AdaGrad form, (g_sorted, acc_sorted,
+    hyper)."""
+    n_slots, L = pool.shape
+    assert n_slots % GROUP == 0 and L % (256 if adagrad else 128) == 0 \
+        and chunk_rows % GROUP == 0 and \
+        codes.shape[0] % chunk_rows == 0, (pool.shape, chunk_rows)
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    return pl.pallas_call(
+        functools.partial(_scatter_add_kernel, rows=chunk_rows,
+                          n_chunks=codes.shape[0] // chunk_rows,
+                          adagrad=adagrad),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(1,),
+            in_specs=[hbm, hbm, hbm, pl.BlockSpec(memory_space=pltpu.SMEM)]
+            if adagrad else [hbm, hbm],
+            out_specs=hbm,
+            scratch_shapes=[
+                pltpu.VMEM((_GROUP_BUFFERS, chunk_rows, GROUP, L),
+                           pool.dtype),
+                pltpu.VMEM((2, chunk_rows, L), pool.dtype),
+                pltpu.SMEM((2, _GROUP_BUFFERS), jnp.int32),
+                pltpu.SemaphoreType.DMA((_GROUP_BUFFERS,)),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.SemaphoreType.DMA((_GROUP_BUFFERS,))]),
+        out_shape=jax.ShapeDtypeStruct(pool.shape, pool.dtype),
+        input_output_aliases={1: 0},
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=(_GROUP_BUFFERS * GROUP + 2) * chunk_rows
+            * L * 4 + (4 << 20)),
+        interpret=interpret,
+    )(codes, pool, *operands)
 
 
 # apm-lint: disable=APM008 Pallas TPU kernel (backend-specific by
@@ -255,34 +335,41 @@ def scatter_add_sorted_rows(pool: jnp.ndarray, codes: jnp.ndarray,
     is read once and written once, so every row ends as
     `pool + u1 + u2 + ...` with the u's in batch order. The pool is
     updated in place when the caller donates it."""
-    n_slots, L = pool.shape
-    assert n_slots % GROUP == 0 and L % 128 == 0 and \
-        chunk_rows % GROUP == 0 and \
-        codes.shape[0] % chunk_rows == 0, (pool.shape, chunk_rows)
-    return pl.pallas_call(
-        functools.partial(_scatter_add_kernel, rows=chunk_rows,
-                          n_chunks=codes.shape[0] // chunk_rows),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(1,),
-            in_specs=[pl.BlockSpec(memory_space=pl.ANY),
-                      pl.BlockSpec(memory_space=pl.ANY)],
-            out_specs=pl.BlockSpec(memory_space=pl.ANY),
-            scratch_shapes=[
-                pltpu.VMEM((_GROUP_BUFFERS, chunk_rows, GROUP, L),
-                           pool.dtype),
-                pltpu.VMEM((2, chunk_rows, L), pool.dtype),
-                pltpu.SMEM((2, _GROUP_BUFFERS), jnp.int32),
-                pltpu.SemaphoreType.DMA((_GROUP_BUFFERS,)),
-                pltpu.SemaphoreType.DMA((2,)),
-                pltpu.SemaphoreType.DMA((_GROUP_BUFFERS,))]),
-        out_shape=jax.ShapeDtypeStruct(pool.shape, pool.dtype),
-        input_output_aliases={1: 0},
-        compiler_params=pltpu.CompilerParams(
-            vmem_limit_bytes=(_GROUP_BUFFERS * GROUP + 2) * chunk_rows
-            * L * 4 + (4 << 20)),
-        interpret=interpret,
-    )(codes, pool, upd_sorted)
+    return _sorted_rows_call(pool, codes, upd_sorted, chunk_rows=chunk_rows,
+                             interpret=interpret, adagrad=False)
+
+
+# apm-lint: disable=APM008 the same kernel, same rationale
+@functools.partial(jax.jit, static_argnames=("chunk_rows", "interpret"))
+def scatter_adagrad_sorted_rows(pool: jnp.ndarray, codes: jnp.ndarray,
+                                g_sorted: jnp.ndarray,
+                                acc_sorted: jnp.ndarray, lr, eps,
+                                chunk_rows: int = 32,
+                                interpret: bool = False) -> jnp.ndarray:
+    """`scatter_add_sorted_rows` of the AdaGrad update rows
+    `[-lr * g * rsqrt(acc + g*g + eps) | g*g]` of value rows
+    [emb | accumulator] (L a multiple of 256: both halves whole lanes),
+    formed inside the kernel from `g_sorted` and `acc_sorted` ([n, L/2]
+    float32, in the order of `codes`). `acc_sorted` is the accumulator
+    AS GATHERED, not the pool's: a key written before in the same step
+    (another role of the pool, a call before this one) is still updated
+    from the value its gradient was computed at. `lr`, `eps`: traced
+    float32 scalars."""
+    hyper = jnp.stack([jnp.asarray(lr, jnp.float32),
+                       jnp.asarray(eps, jnp.float32)])
+    return _sorted_rows_call(pool, codes, g_sorted, acc_sorted, hyper,
+                             chunk_rows=chunk_rows, interpret=interpret,
+                             adagrad=True)
+
+
+def _slice_by_slice(kernel, pool, slots, rows, chunk_rows, max_positions):
+    """`kernel(pool, codes, *rows in sorted order) -> pool`, one call for
+    each `max_positions` (writeback.MAX_POSITIONS unless given) sorted
+    positions of `slots`, each on the pool the call before returned."""
+    for codes, perm in sorted_slices(slots, pool.shape[0], chunk_rows,
+                                     max_positions):
+        pool = kernel(pool, codes, *(r[perm] for r in rows))
+    return pool
 
 
 def scatter_add_rows(pool: jnp.ndarray, slots: jnp.ndarray,
@@ -291,12 +378,22 @@ def scatter_add_rows(pool: jnp.ndarray, slots: jnp.ndarray,
                      max_positions: int = None) -> jnp.ndarray:
     """`pool.at[slots].add(upd, mode="drop")` through
     `scatter_add_sorted_rows`: sort the slots, bring the update rows
-    into that order (a gather), add; one call of the kernel for each
-    `max_positions` (writeback.MAX_POSITIONS unless given) sorted
-    positions."""
-    for codes, perm in sorted_slices(slots, pool.shape[0], chunk_rows,
-                                     max_positions):
-        pool = scatter_add_sorted_rows(pool, codes, upd[perm],
-                                       chunk_rows=chunk_rows,
-                                       interpret=interpret)
-    return pool
+    into that order (a gather), add."""
+    return _slice_by_slice(
+        functools.partial(scatter_add_sorted_rows, chunk_rows=chunk_rows,
+                          interpret=interpret),
+        pool, slots, (upd,), chunk_rows, max_positions)
+
+
+def scatter_adagrad_rows(pool: jnp.ndarray, slots: jnp.ndarray,
+                         g: jnp.ndarray, acc: jnp.ndarray, lr, eps,
+                         chunk_rows: int = 32, interpret: bool = False,
+                         max_positions: int = None) -> jnp.ndarray:
+    """`scatter_add_rows` of the AdaGrad update rows of gradients `g`
+    and gathered accumulators `acc` ([n, L/2] each), formed in the
+    kernel (`scatter_adagrad_sorted_rows`): the fused step's write-back
+    of one role (ops/fused.py `_kernel_writeback`)."""
+    return _slice_by_slice(
+        functools.partial(scatter_adagrad_sorted_rows, lr=lr, eps=eps,
+                          chunk_rows=chunk_rows, interpret=interpret),
+        pool, slots, (g, acc), chunk_rows, max_positions)

@@ -1,6 +1,7 @@
 """The fused step's write-back through the Pallas kernel
-(`pallas_kernels.scatter_add_sorted_rows`): what the step needs of it
-without importing `jax.experimental.pallas`.
+(`pallas_kernels.scatter_adagrad_sorted_rows`, and its plain form
+`scatter_add_sorted_rows`): what the step needs of it without importing
+`jax.experimental.pallas`.
 
 The slots are sorted here, in plain XLA, and cut into slices of at most
 `MAX_POSITIONS` sorted positions, one kernel call each (the kernel keeps
@@ -106,36 +107,46 @@ def _sources_sha() -> bytes:
 
 @functools.lru_cache(maxsize=None)
 def exported_kernel(n_slots: int, row_length: int, n: int, chunk_rows: int,
-                    platform: str = "tpu"):
-    """`scatter_add_sorted_rows` for a float32 [n_slots, row_length]
-    pool and `n` sorted positions, as a `jax.export.Exported` for one
+                    platform: str = "tpu", adagrad: bool = False):
+    """The write-back kernel for a float32 [n_slots, row_length] pool
+    and `n` sorted positions, as a `jax.export.Exported` for one
     platform (anything but a TPU gets the kernel in interpret mode: the
-    tests): `.call(pool, codes, upd_sorted)` inside a jitted program is
-    the kernel's custom call (the pool aliased to the result as in the
-    kernel). Read from `<compile cache>/adapm_kernels/` where a process
-    before this one left it (the file's name carries a hash of the
-    sizes, jax's version and both source files), else made and left; a
-    file that cannot be read is made anew."""
+    tests), in one of its two forms. Plain
+    (`scatter_add_sorted_rows`): `.call(pool, codes, upd_sorted)`.
+    AdaGrad (`scatter_adagrad_sorted_rows`, what the fused step takes):
+    `.call(pool, codes, g_sorted, acc_sorted, lr, eps)`, the halves
+    [n, row_length / 2] and the two rates float32 scalars, operands and
+    no statics. Inside a jitted program either is the kernel's custom
+    call (the pool aliased to the result as in the kernel). Read from
+    `<compile cache>/adapm_kernels/` where a process before this one
+    left it (the file's name carries the form and a hash of the
+    operands' shapes, jax's version and both source files), else made
+    and left; a file that cannot be read is made anew."""
     from jax import export
+    shape = jax.ShapeDtypeStruct
+    f32 = functools.partial(shape, dtype=jnp.float32)
+    operands = (f32((n, row_length // 2)), f32((n, row_length // 2)),
+                f32(()), f32(())) if adagrad else (f32((n, row_length)),)
+    name = "scatter_adagrad_sorted_rows" if adagrad else \
+        "scatter_add_sorted_rows"
     path = None
     if jax.config.jax_compilation_cache_dir:
         key = hashlib.sha256(_sources_sha() + repr(
             (n_slots, row_length, n, chunk_rows, platform, jax.__version__,
-             jax.lib.__version__)).encode()).hexdigest()[:24]
+             jax.lib.__version__, name,
+             [x.shape for x in operands])).encode()).hexdigest()[:24]
         path = os.path.join(jax.config.jax_compilation_cache_dir,
-                            "adapm_kernels",
-                            f"scatter_add_sorted_rows-{key}.jaxexport")
+                            "adapm_kernels", f"{name}-{key}.jaxexport")
         try:
             with open(path, "rb") as f:
                 return export.deserialize(f.read())
         except Exception:  # absent, cut short or another jax's: make it
             pass
-    from .pallas_kernels import scatter_add_sorted_rows
-    shape = jax.ShapeDtypeStruct
-    exported = export.export(scatter_add_sorted_rows, platforms=(platform,))(
-        shape((n_slots, row_length), jnp.float32), shape((n,), jnp.int32),
-        shape((n, row_length), jnp.float32), chunk_rows=chunk_rows,
-        interpret=platform != "tpu")
+    from . import pallas_kernels
+    exported = export.export(getattr(pallas_kernels, name),
+                             platforms=(platform,))(
+        f32((n_slots, row_length)), shape((n,), jnp.int32), *operands,
+        chunk_rows=chunk_rows, interpret=platform != "tpu")
     if path is not None:
         os.makedirs(os.path.dirname(path), exist_ok=True)
         tmp = f"{path}.{os.getpid()}.tmp"
@@ -145,8 +156,11 @@ def exported_kernel(n_slots: int, row_length: int, n: int, chunk_rows: int,
     return exported
 
 
-def kernel(n_slots: int, row_length: int, n: int, chunk_rows: int):
-    """The kernel at these sizes as `f(pool, codes, upd_sorted) -> pool`
-    for a program being traced for jax's default backend."""
+def kernel(n_slots: int, row_length: int, n: int, chunk_rows: int,
+           adagrad: bool = False):
+    """The kernel at these sizes, for a program being traced for jax's
+    default backend: `f(pool, codes, upd_sorted) -> pool`, or in the
+    AdaGrad form `f(pool, codes, g_sorted, acc_sorted, lr, eps) ->
+    pool`."""
     return exported_kernel(n_slots, row_length, n, chunk_rows,
-                           jax.default_backend()).call
+                           jax.default_backend(), adagrad).call

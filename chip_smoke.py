@@ -216,7 +216,8 @@ def part_kernels(ctx) -> None:
     import numpy as np
 
     from adapm_tpu.ops.pallas_kernels import (adagrad_apply, gather_rows,
-                                              scatter_add_rows)
+                                              scatter_add_rows,
+                                              scatter_adagrad_rows)
     from adapm_tpu.ops.writeback import MAX_POSITIONS
     interpret = ctx["rehearsal"]
     L = ctx["sz"]["L"]
@@ -272,6 +273,33 @@ def part_kernels(ctx) -> None:
               f"{int((~keep).sum())} dropped, "
               f"{per_call or MAX_POSITIONS} positions a call): bitwise "
               f"equal to np.add.at")
+    # its AdaGrad form, what the fused step runs: the update rows
+    # [-lr g rsqrt(acc + g^2 + eps) | g^2] formed inside the kernel
+    N, Lw, n = (256, 256, 200) if interpret else (4096, 2048, 3000)
+    H = Lw // 2
+    slots = rng.integers(0, N + 8, n).astype(np.int32)  # some dropped
+    pool = np.abs(rng.normal(size=(N, Lw))).astype(np.float32)
+    g = rng.normal(size=(n, H)).astype(np.float32)
+    acc = np.abs(rng.normal(size=(n, H))).astype(np.float32)
+    got = np.asarray(scatter_adagrad_rows(
+        jnp.asarray(pool), jnp.asarray(slots), jnp.asarray(g),
+        jnp.asarray(acc), lr, eps, interpret=interpret))
+    keep = slots < N
+    g2 = g * g
+    upd = np.concatenate([-np.float32(lr) * g / np.sqrt(
+        acc + g2 + np.float32(eps)), g2], axis=1)
+    mag = pool.copy()
+    np.add.at(mag, slots[keep], np.abs(upd[keep]))
+    np.add.at(pool, slots[keep], upd[keep])
+    _check(got[:, H:].tobytes() == pool[:, H:].tobytes(),
+           "scatter_adagrad_rows: the accumulator halves differ from "
+           "np.add.at of g*g")
+    worst = float((np.abs(got - pool) / np.spacing(mag)).max())
+    _check(worst <= 4, f"scatter_adagrad_rows: embedding halves differ "
+           f"from numpy by {worst:.1f} ulp of the summed magnitudes")
+    print(f"  scatter_adagrad_rows interpret={interpret} L={Lw} n={n}: "
+          f"accumulators bitwise equal, embeddings within {worst:.1f} ulp "
+          f"of numpy's 1/sqrt")
 
 
 def part_contract(ctx) -> None:

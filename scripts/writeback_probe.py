@@ -8,7 +8,11 @@ slots: (a) today's `.at[sh, sl].add(mode="drop")`; (b) the same on
 sorted slots with `indices_are_sorted=True`, the update rows permuted
 beforehand, and that permutation alone; (c) on sorted, duplicate-free
 slots with `unique_indices=True` too; (d) the gather of the same rows;
-and `ops/pallas_kernels.scatter_add_rows`. One line a reading:
+`ops/pallas_kernels.scatter_add_rows` (`kernel_R<rows>`: the sort, the
+permutation of the 8 KB update rows and the kernel's plain form); and
+`scatter_adagrad_rows` (`kernel_adagrad_R<rows>`, ISSUE 29: the sort, the
+permutation of the two 4 KB halves and the kernel's AdaGrad form, which
+is what the fused step runs). One line a reading:
 `probe <name> <draw>: <ms> ms, <ns> ns a row`. TPU only.
 """
 from __future__ import annotations
@@ -53,6 +57,8 @@ def main(argv=None) -> int:
     from adapm_tpu.ops import pallas_kernels
     scatter_add_rows = functools.partial(pallas_kernels.scatter_add_rows,
                                          interpret=cpu)
+    scatter_adagrad_rows = functools.partial(
+        pallas_kernels.scatter_adagrad_rows, interpret=cpu)
     N, L, n = args.slots, args.row, args.n
     only = set(filter(None, args.only.split(",")))
     rng = np.random.default_rng(25)
@@ -68,6 +74,9 @@ def main(argv=None) -> int:
     pool = jnp.zeros((1, N, L), jnp.float32)
     upd = jax.random.normal(jax.random.PRNGKey(0), (n, L), jnp.float32)
     sh = jnp.zeros((n,), jnp.int32)
+    # the AdaGrad form's operands: gradients and gathered accumulators
+    g, acc = upd[:, :L // 2], jnp.abs(upd[:, L // 2:])
+    lr, eps = jnp.float32(0.1), jnp.float32(1e-10)
 
     def timed(name, draw, fn, *xs, on_pool=True):
         """ms a call of fn(pool, *xs) -> pool (the pool donated) or of
@@ -107,6 +116,12 @@ def main(argv=None) -> int:
     got = scatter_add_rows(small[0], sl, upd[:k])[None]
     print(f"{tag}check kernel against XLA, zipf n={k}: max abs difference "
           f"{float(jnp.max(jnp.abs(got - want))):.3g}", flush=True)
+    g2 = g[:k] * g[:k]
+    want = xla_add(small, sl, jnp.concatenate(
+        [-lr * g[:k] * jax.lax.rsqrt(acc[:k] + g2 + eps), g2], axis=-1))
+    got = scatter_adagrad_rows(small[0], sl, g[:k], acc[:k], lr, eps)[None]
+    print(f"{tag}check AdaGrad kernel against XLA, zipf n={k}: max abs "
+          f"difference {float(jnp.max(jnp.abs(got - want))):.3g}", flush=True)
     del small, want, got
 
     for draw, sl_np in draws.items():
@@ -141,6 +156,10 @@ def main(argv=None) -> int:
             timed(f"kernel_R{rows}", draw,
                   lambda m, s, u, rows=rows: scatter_add_rows(
                       m[0], s, u, chunk_rows=rows)[None], sl, upd)
+            timed(f"kernel_adagrad_R{rows}", draw,
+                  lambda m, s, g_, a, rows=rows: scatter_adagrad_rows(
+                      m[0], s, g_, a, lr, eps, chunk_rows=rows)[None],
+                  sl, g, acc)
     return 0
 
 

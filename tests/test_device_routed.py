@@ -582,6 +582,9 @@ def test_writeback_kernel_step_equals_xla_step(monkeypatch, kernel_cache):
 
 @pytest.mark.parametrize("shape, dtype, backend, kernel", [
     ((1, 64, 256), np.float32, "tpu", True),
+    ((1, 64, 2048), np.float32, "tpu", True),      # both cells' rows
+    ((1, 64, 128), np.float32, "tpu", False),      # halves of 64 lanes
+    ((1, 64, 384), np.float32, "tpu", False),      # halves of 192 lanes
     ((1, 64, 256), np.float32, None, False),       # CPU: all of tier-1
     ((1, 64, 256), np.float32, "gpu", False),
     ((4, 64, 256), np.float32, "tpu", False),      # more than one shard

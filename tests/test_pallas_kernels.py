@@ -87,12 +87,15 @@ def _scatter_case(name):
     raise KeyError(name)
 
 
-@pytest.mark.parametrize("case", [
+SCATTER_CASES = [
     "no_duplicates", "uniform_duplicates", "one_slot_half_the_batch",
     "run_ends_on_chunk_boundary", "n_not_a_multiple_of_chunk",
     "out_of_range_dropped", "n_smaller_than_chunk", "one_group_many_rows",
     "many_calls_run_cut_by_each", "many_calls_group_cut_between_rows",
-    "many_calls_dropped_tail", "many_calls_chunk_not_a_divisor"])
+    "many_calls_dropped_tail", "many_calls_chunk_not_a_divisor"]
+
+
+@pytest.mark.parametrize("case", SCATTER_CASES)
 def test_scatter_add_rows_matches_numpy(case):
     """The additions are the batch's, in the batch's order within a row
     (the sort is stable), so the result is `np.add.at`'s bit for bit."""
@@ -113,6 +116,96 @@ def test_scatter_add_rows_matches_numpy(case):
     assert got.tobytes() == ref.tobytes()
     untouched = np.setdiff1d(np.arange(N), wrapped[keep])
     assert got[untouched].tobytes() == pool[untouched].tobytes()
+
+
+def _adagrad_rows(g, acc, lr, eps):
+    """numpy float32 AdaGrad update rows [d emb | d acc], from the
+    equations (apps/mf/update.h): nothing of the program's."""
+    g2 = g * g
+    return np.concatenate(
+        [-np.float32(lr) * g / np.sqrt(acc + g2 + np.float32(eps)), g2],
+        axis=1).astype(np.float32)
+
+
+def _assert_adagrad_rows_landed(got, pool, landed, upd):
+    """`got` is `pool` with `upd` rows added at `landed` slots, batch
+    order: the accumulator half (g*g: exact in any implementation) bit
+    for bit, the embedding half within 2 ulp of the magnitudes summed
+    (rsqrt against numpy's 1/sqrt: the two differ by up to 2 ulp an
+    update row), untouched rows bit for bit."""
+    H = pool.shape[1] // 2
+    ref = pool.copy()
+    np.add.at(ref, landed, upd)
+    assert got[:, H:].tobytes() == ref[:, H:].tobytes()
+    mag = np.abs(pool)
+    np.add.at(mag, landed, np.abs(upd))
+    assert (np.abs(got - ref) <= 2 * np.spacing(mag)).all()
+    untouched = np.setdiff1d(np.arange(len(pool)), landed)
+    assert got[untouched].tobytes() == pool[untouched].tobytes()
+
+
+@pytest.mark.parametrize("case", SCATTER_CASES)
+def test_scatter_adagrad_rows_matches_numpy(case):
+    """The kernel's AdaGrad form on the plain form's cases (duplicates
+    inside a chunk, runs cut by a chunk's and by each call's end,
+    dropped and padding positions, a whole call of dropped slots): the
+    update rows formed in the kernel from gradients and gathered
+    accumulators are numpy's, added in the batch's order."""
+    from adapm_tpu.ops.pallas_kernels import scatter_adagrad_rows
+    N, R, slots, per_call = (*_scatter_case(case), None)[:4]
+    slots = np.asarray(slots, dtype=np.int32)
+    rng = np.random.default_rng(29)
+    L, lr, eps = 256, 0.1, 1e-10
+    pool = rng.normal(size=(N, L)).astype(np.float32)
+    pool[:, L // 2:] = np.abs(pool[:, L // 2:])
+    g = rng.normal(size=(len(slots), L // 2)).astype(np.float32)
+    acc = np.abs(rng.normal(size=(len(slots), L // 2))).astype(np.float32)
+    got = np.asarray(scatter_adagrad_rows(
+        jnp.asarray(pool), jnp.asarray(slots), jnp.asarray(g),
+        jnp.asarray(acc), lr, eps, chunk_rows=R, interpret=True,
+        max_positions=per_call))
+    wrapped = np.where(slots < 0, slots.astype(np.int64) + N, slots)
+    keep = (wrapped >= 0) & (wrapped < N)
+    _assert_adagrad_rows_landed(got, pool, wrapped[keep],
+                                _adagrad_rows(g, acc, lr, eps)[keep])
+
+
+def test_scatter_adagrad_accumulator_is_the_operand():
+    """One key in two roles of one pool, written back one role after
+    the other, each with the accumulator ITS gradient was gathered with
+    (and they differ): every update is formed from the operand, never
+    from the pool's row, which by the second role holds the first's
+    g*g; inside one call (the key twice in a role) the same."""
+    from adapm_tpu.ops.pallas_kernels import scatter_adagrad_rows
+    rng = np.random.default_rng(30)
+    N, L, R, lr, eps = 64, 256, 8, 0.1, 1e-10
+    pool = rng.normal(size=(N, L)).astype(np.float32)
+    pool[:, L // 2:] = np.abs(pool[:, L // 2:])
+    roles = []
+    for slots in ([13, 5, 13, 40], [7, 13, 22]):  # the key of slot 13
+        slots = np.array(slots, dtype=np.int32)
+        g = rng.normal(size=(len(slots), L // 2)).astype(np.float32)
+        acc = (rng.uniform(0.5, 4.0, size=(len(slots), 1)) * np.abs(
+            rng.normal(size=(len(slots), L // 2)))).astype(np.float32)
+        roles.append((slots, g, acc))
+    got = jnp.asarray(pool)
+    for slots, g, acc in roles:
+        got = scatter_adagrad_rows(got, jnp.asarray(slots), jnp.asarray(g),
+                                   jnp.asarray(acc), lr, eps, chunk_rows=R,
+                                   interpret=True)
+    got = np.asarray(got)
+    landed = np.concatenate([r[0] for r in roles])
+    _assert_adagrad_rows_landed(got, pool, landed, np.concatenate(
+        [_adagrad_rows(g, acc, lr, eps) for _, g, acc in roles]))
+    # and not what the pool's accumulator would have given: role two's
+    # update of slot 13 from the pool's row as role one left it
+    H = L // 2
+    (slots, g, acc), (_, g2, _) = roles
+    from_pool = pool[13].copy()
+    for j in (0, 2):
+        from_pool += _adagrad_rows(g[j:j + 1], acc[j:j + 1], lr, eps)[0]
+    from_pool += _adagrad_rows(g2[1:2], from_pool[None, H:], lr, eps)[0]
+    assert not np.allclose(got[13, :H], from_pool[:H], rtol=1e-3, atol=0)
 
 
 def test_sorted_slices_are_whole_calls():

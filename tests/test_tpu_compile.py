@@ -44,23 +44,35 @@ def _no_compile_cache():
     compilation_cache.reset_cache()
 
 
-def test_writeback_kernel_compiles_in_place(shape, kernel_cache):
-    """The manual-DMA write-back at the KGE cell's shape, as the step
-    takes it (exported once, read back from the cache directory by the
-    next process): Mosaic takes it, and the donated pool is updated in
-    place (no pool-sized copy)."""
+@pytest.mark.parametrize("adagrad, n_slots, n", [
+    (False, 1_172_432, 131_072),   # the plain form at the KGE cell's shape
+    # the AdaGrad form at every size the one-chip training cells call it
+    (True, 1_172_432, 131_072), (True, 1_172_432, 4096),  # negs; s, r, o
+    (True, 1_618_688, 40_960), (True, 1_618_688, 8192),   # noise; ctr, ctx
+])
+def test_writeback_kernel_compiles_in_place(adagrad, n_slots, n, shape,
+                                            kernel_cache):
+    """The manual-DMA write-back as the step takes it (exported once
+    under its form's name, read back from the cache directory by the
+    next process), in its plain form (the rows given) and its AdaGrad
+    form (the update rows formed in VMEM from two half-row operands,
+    `lr` and `eps` SMEM operands): Mosaic takes it, and the donated
+    pool is updated in place (no pool-sized copy)."""
     from adapm_tpu.ops import writeback
-    n_slots, n = 1_172_432, 131_072
-    made = writeback.exported_kernel(n_slots, L, n, 32)
+    made = writeback.exported_kernel(n_slots, L, n, 32, adagrad=adagrad)
     (kept,) = kernel_cache.iterdir()
+    assert kept.name.startswith("scatter_adagrad_sorted_rows-" if adagrad
+                                else "scatter_add_sorted_rows-")
     writeback.exported_kernel.cache_clear()  # as a later process
-    read = writeback.exported_kernel(n_slots, L, n, 32)
+    read = writeback.exported_kernel(n_slots, L, n, 32, adagrad=adagrad)
     assert read is not made
     assert read.mlir_module_serialized == made.mlir_module_serialized
     assert [f.name for f in kernel_cache.iterdir()] == [kept.name]
+    f32 = lambda *dims: shape(dims, jnp.float32)  # noqa: E731
+    operands = (f32(n, L // 2), f32(n, L // 2), f32(), f32()) if adagrad \
+        else (f32(n, L),)
     compiled = jax.jit(read.call, donate_argnums=(0,)).lower(
-        shape((n_slots, L), jnp.float32), shape((n,), jnp.int32),
-        shape((n, L), jnp.float32)).compile()
+        f32(n_slots, L), shape((n,), jnp.int32), *operands).compile()
     assert "tpu_custom_call" in compiled.as_text()
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes == n_slots * L * 4
@@ -90,11 +102,45 @@ def test_writeback_of_any_batch_compiles(n_slots, row, batch, calls, shape,
         n_slots * row * 4
 
 
+@pytest.mark.parametrize("kernel", [True, False])
+def test_adagrad_pass_is_in_the_xla_variant_only(kernel, shape,
+                                                 kernel_cache, monkeypatch):
+    """The lowered replica-free step: with the write-back kernel no
+    operation carries the scope `adapm_adagrad` (the kernel forms the
+    update rows; `_adagrad_update` is not traced), without it (as on a
+    CPU, several shards or with replicas) the pass is still there."""
+    from adapm_tpu.models.sgns import sgns_loss
+    from adapm_tpu.ops import fused
+    if kernel:
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    roles = {"center": 0, "ctx": 0, "neg": 0}
+    B, N, keys, row = 64, 5, 4096, 256
+    body = fused._build_device_routed_body(
+        sgns_loss, roles, {r: row // 2 for r in roles}, (), "neg", (B, N),
+        True, True)
+    small = shape((1, 8, row), jnp.float32)
+    lowered = jax.jit(body, donate_argnums=(0,)).lower(
+        ((shape((1, keys, row), jnp.float32), small, small),),
+        shape((4,), jnp.int32),
+        tuple(shape((keys,), jnp.int32) for _ in range(3))
+        + (shape((), jnp.int32),),
+        {r: shape((B,), jnp.int32) for r in roles if r != "neg"}, None,
+        (shape((keys,), jnp.float32), shape((keys,), jnp.int32),
+         shape((keys,), jnp.int32)),
+        shape((2,), jnp.uint32), None, shape((), jnp.float32),
+        shape((), jnp.float32))
+    text = lowered.as_text(debug_info=True)
+    assert "adapm_scatter_add" in text and "adapm_loss_grad" in text
+    assert ("adapm_adagrad" in text) is (not kernel)
+    assert ("tpu_custom_call" in text) is kernel
+
+
 # (model, main pools' slots, keys, batch, negatives a row, the parent's
-# temporaries in bytes: v5e compile of PR 24's step, PERF.md section 4)
+# temporaries in bytes: v5e compile of PR 28's step, PERF.md section 4;
+# PR 29's own read 2.87e9 and 1.015e9)
 CELLS = {
-    "kge-wikidata5m": ((1_172_432, 840), 1_149_443, 4096, 32, 3.44e9),
-    "w2v-1bw": ((1_618_688,), 1_586_942, 8192, 5, 1.245e9),
+    "kge-wikidata5m": ((1_172_432, 840), 1_149_443, 4096, 32, 2.96e9),
+    "w2v-1bw": ((1_618_688,), 1_586_942, 8192, 5, 1.02e9),
 }
 
 

@@ -13,6 +13,18 @@ Key layout (reference :692-693): row keys [0, m), column keys [m, m+n);
 value row = [factor (rank) | AdaGrad (rank)] (:695-697). Batches run as one
 fused gather -> grad -> AdaGrad -> scatter-add program (ops/fused.py).
 
+The pass-end loss (it feeds the bold driver) is computed where the table
+lives (reference apps/mf/loss.h, the form without a full model pull): each
+worker walks its own points batch by batch through the fused step's
+gather-only score program (DeviceRoutedRunner.score), the squared errors add
+up on the device, the L2 term is one reduction over the factor columns of
+the main pool, and two numbers cross to the host. The whole table is read
+to the host (`Server.read_main`) only by `--export_prefix`.
+
+`open_run(args)` sets a run up, `train(run)` trains `--epochs` passes on it
+(and can be called again: the step size and the bold driver's last loss live
+on the run), `run(args)` is both and shuts the server down.
+
 Run: python -m adapm_tpu.apps.matrix_factorization --synthetic ...
 """
 from __future__ import annotations
@@ -20,16 +32,21 @@ from __future__ import annotations
 import argparse
 import sys
 
+import jax.numpy as jnp
 import numpy as np
 
+from ..device import default_port
+from ..exec import dispatch_gate
 from ..io import mf as mfio
-from ..models.mf import make_mf_loss
+from ..models.mf import make_mf_loss, mf_sq_error
 from ..ops import DeviceRoutedRunner
 from ..utils import Stopwatch, alog
 from .common import (KeyMapper, RuntimeGuard, ScanWindow,
                      add_common_arguments, enforce_full_replication,
                      epoch_report, make_server, wrap_batches,
                      worker0_init)
+
+_GATE = dispatch_gate()
 
 
 def _load_data(args):
@@ -52,176 +69,393 @@ def _init_factors(args, m, n, rank, rng):
     return W, H
 
 
-def run(args) -> float:
-    rows, cols, vals, m, n = _load_data(args)
-    rank = args.rank
-    num_keys = m + n
-    rng = np.random.default_rng(args.seed)
+def _masked_sq_sum(main, occupied, rank: int):
+    """Sum of squares of the first `rank` columns of the rows of `main`
+    [S, M, L] whose slot is occupied [S, M]: a vacated slot keeps its
+    last row."""
+    f = main[..., :rank]
+    return jnp.sum(jnp.where(occupied[..., None], f * f, 0))
 
-    kmap = KeyMapper(num_keys, args.enforce_random_keys, seed=args.seed)
-    srv = make_server(args, num_keys, value_lengths=2 * rank,
-                      num_workers=args.num_workers or None)
-    num_workers = args.num_workers or srv.num_shards
-    workers = [srv.make_worker(i) for i in range(num_workers)]
 
-    W, H = _init_factors(args, m, n, rank, rng)
-    init = np.concatenate(
-        [np.concatenate([W, np.full_like(W, args.adagrad_init)], axis=1),
-         np.concatenate([H, np.full_like(H, args.adagrad_init)], axis=1)])
-    worker0_init(workers, kmap(np.arange(num_keys)), init)
-    if args.enforce_full_replication:
-        enforce_full_replication(workers, num_keys)
+class _Batch:
+    """A batch as the loops hand it on: role keys, the step's (or the
+    score's) aux, the distinct keys among the role keys (where an intent
+    needs them) and the keys' upload (where one was made)."""
 
-    # routing tables mirrored into HBM, host ships only the raw key batch
-    # per step (ops/fused.py)
-    dev_runners = {}
+    __slots__ = ("roles", "aux", "keys", "staged")
 
-    def device_runner(shard: int) -> DeviceRoutedRunner:
-        if shard not in dev_runners:
-            dev_runners[shard] = DeviceRoutedRunner(
-                srv, make_mf_loss(args.l2), role_class={"w": 0, "h": 0},
-                role_dim={"w": rank, "h": rank}, shard=shard,
-                seed=args.seed + shard)
-        return dev_runners[shard]
+    def __init__(self, roles, aux, keys=None, staged=None):
+        self.roles, self.aux, self.keys, self.staged = \
+            roles, aux, keys, staged
 
-    # row-block data partition over ALL workers of ALL processes
-    # (reference mf/io.h:125+; DSGD's block schedule spans them too)
-    from ..parallel import control
-    P, pid = control.num_processes(), control.process_id()
-    total_workers = P * num_workers
-    part = mfio.partition_points(rows, total_workers, m)
-    by_worker = [np.nonzero(part == pid * num_workers + wi)[0]
-                 for wi in range(num_workers)]
-    B = args.batch_size
-    lr = args.lr
-    prev_loss = np.inf
-    best_loss = np.inf
-    guard = RuntimeGuard(args.max_runtime)
-    watch = Stopwatch(start=True)
 
-    # --scan_steps K: buffer K batches and train
-    # them in ONE lax.scan dispatch (ScanWindow — the shared app
-    # contract; placement frozen per window). The clock still advances
-    # per batch at buffering time; intent windows are extended by K-1
-    # clocks to cover the dispatch delay. The window is flushed at every
-    # worker/block boundary (shards must not mix in one window) and
-    # before each barrier/quiesce. lr changes per epoch (bold driver), so
-    # the CURRENT lr is passed at every add/flush.
-    K = max(1, args.scan_steps)
-    scan_win = ScanWindow(srv, K, args.sync_rounds_per_step)
+class MfRun:
+    """One training run: the server, its workers and their fused runners,
+    the data points with their keys, and what carries over from pass to
+    pass (step size, the bold driver's last loss, the shuffling
+    generator, the pass count)."""
 
-    def flush_scan():
-        scan_win.flush(lr)
+    def __init__(self, args, data):
+        rows, cols, vals, m, n = data
+        self.args = args
+        self.m, self.n, self.rank = m, n, args.rank
+        num_keys = m + n
+        self.rng = np.random.default_rng(args.seed)
+        self.kmap = KeyMapper(num_keys, args.enforce_random_keys,
+                              seed=args.seed)
+        self.srv = make_server(args, num_keys, value_lengths=2 * self.rank,
+                               num_workers=args.num_workers or None)
+        self.num_workers = args.num_workers or self.srv.num_shards
+        self.workers = [self.srv.make_worker(i)
+                        for i in range(self.num_workers)]
+        from ..parallel import control
+        self.pid = control.process_id()
+        self.total_workers = control.num_processes() * self.num_workers
+        self.set_points(rows, cols, vals)
 
-    def train_batch(w, idx):
-        roles = {"w": kmap(rows[idx]), "h": kmap(cols[idx] + m)}
-        if K > 1:
-            scan_win.add(device_runner(w.shard), roles,
-                         np.asarray(vals[idx]), lr)
+        self.lr = args.lr
+        self.prev_loss = np.inf
+        self.best_loss = np.inf
+        self.epoch = 0      # passes trained so far, over all train() calls
+
+        # --scan_steps K: buffer K batches and train them in ONE lax.scan
+        # dispatch (ScanWindow, the shared app contract; placement frozen
+        # per window). The clock still advances per batch at buffering
+        # time; intent windows are extended by K-1 clocks to cover the
+        # dispatch delay. The window is flushed at every worker/block
+        # boundary (shards must not mix in one window) and before each
+        # barrier/quiesce. lr changes per pass (bold driver), so the
+        # CURRENT lr is passed at every add/flush.
+        self.K = max(1, args.scan_steps)
+        self.scan_win = ScanWindow(self.srv, self.K,
+                                   args.sync_rounds_per_step)
+
+        # routing tables mirrored into HBM, host ships only the raw key
+        # batch per step (ops/fused.py); runners built alike share their
+        # compiled programs
+        self._programs = {}
+        self._dev_runners = {}
+        self._sq_sum = default_port().compile(_masked_sq_sum,
+                                              static_argnums=2)
+        self._occupied = None       # device mask of the pool's live slots
+        self._occupied_version = None
+
+        # host time of the loop's own phases (Server._span; the step's
+        # other phases are bracketed where they live: kv.intent,
+        # fused.dispatch, kv.drive_rounds, kv.advance_clock), and how
+        # many of a batch's keys are distinct
+        obs = self.srv.obs
+        self._h_prepare = obs.histogram("app.prepare_s", shared=True)
+        self._h_pass_end = obs.histogram("app.pass_end_s", shared=True)
+        self._h_loss_pass = obs.histogram("app.loss_pass_s", shared=True)
+        self._c_keys = obs.counter("app.batch_keys_total", unit="keys",
+                                   shared=True)
+        self._c_unique = obs.counter("app.batch_unique_keys_total",
+                                     unit="keys", shared=True)
+
+    def set_points(self, rows, cols, vals) -> None:
+        """The revealed cells this run trains on. Every point's two keys
+        and its value are worked out once: a batch is an index array
+        into these."""
+        m = self.m
+        self.cols = cols
+        self.wkey = self.kmap(rows)
+        self.hkey = self.kmap(cols + m)
+        self.vals = np.asarray(vals, dtype=np.float32)
+        # row-block data partition over ALL workers of ALL processes
+        # (reference mf/io.h:125+; DSGD's block schedule spans them too)
+        part = mfio.partition_points(rows, self.total_workers, m)
+        self.by_worker = [
+            np.nonzero(part == self.pid * self.num_workers + wi)[0]
+            for wi in range(self.num_workers)]
+        if self.args.algorithm == "columnwise":
+            # each worker walks its points sorted by column, every pass
+            self.by_worker = [mine[np.argsort(cols[mine], kind="stable")]
+                              for mine in self.by_worker]
+        # what a walk that is the same every pass works out once (built
+        # at first use): worker -> its prepared batches
+        self._train_plans = {}
+        self._loss_plans = {}
+
+    def device_runner(self, shard: int) -> DeviceRoutedRunner:
+        if shard not in self._dev_runners:
+            a = self.args
+            self._dev_runners[shard] = DeviceRoutedRunner(
+                self.srv, make_mf_loss(a.l2), role_class={"w": 0, "h": 0},
+                role_dim={"w": self.rank, "h": self.rank}, shard=shard,
+                seed=a.seed + shard, programs=self._programs,
+                score_fn=mf_sq_error)
+        return self._dev_runners[shard]
+
+    def precompile(self) -> int:
+        """`Server.precompile` with this app's sizes: an intent names at
+        most 2B keys (columnwise, plain; a DSGD block's intent can name
+        more and compiles its bucket at first use); the loop drives one
+        kind of runner, a batch of B points a step and a score dispatch
+        (a --scan_steps window still compiles at its first use). Returns
+        how many planner programs ran."""
+        B = self.args.batch_size
+        z = np.zeros(B, dtype=np.int64)
+        x = np.zeros(B, dtype=np.float32)
+        put = self.srv.ctx.put_replicated   # as `_loss_plan` hands it
+        steps = [(self.device_runner(self.workers[0].shard),
+                  {"w": z, "h": z}, x, (put(x), put(np.int32(0))))]
+        return self.srv.precompile({0: min(2 * B, self.m + self.n)}, steps)
+
+    def init_model(self) -> None:
+        """Worker 0 sets every row from the host: uniform factors (or
+        --init_w/--init_h), the AdaGrad columns at --adagrad_init."""
+        a = self.args
+        W, H = _init_factors(a, self.m, self.n, self.rank, self.rng)
+        init = np.concatenate(
+            [np.concatenate([W, np.full_like(W, a.adagrad_init)], axis=1),
+             np.concatenate([H, np.full_like(H, a.adagrad_init)], axis=1)])
+        worker0_init(self.workers, self.kmap(np.arange(self.m + self.n)),
+                     init)
+
+    def current_factors(self):
+        """(W, H) on the host: the whole table through `read_main`. For
+        --export_prefix (and a tiered store's L2 term) only."""
+        flat = self.srv.read_main(self.kmap(np.arange(self.m + self.n)))
+        M = flat.reshape(self.m + self.n, 2 * self.rank)[:, :self.rank]
+        return M[:self.m], M[self.m:]
+
+    # -- a training step -----------------------------------------------------
+
+    def batch(self, idx: np.ndarray):
+        """(role keys, observed values) of the points `idx`."""
+        return {"w": self.wkey[idx], "h": self.hkey[idx]}, self.vals[idx]
+
+    def prepared(self, idx: np.ndarray) -> "_Batch":
+        """The batch of the points `idx` with what its intent needs: the
+        distinct keys among its 2B."""
+        roles, aux = self.batch(idx)
+        return _Batch(roles, aux,
+                      np.unique(np.concatenate([roles["w"], roles["h"]])))
+
+    def signal_intent(self, w, batch: "_Batch", start: int,
+                      end: int) -> None:
+        self._c_keys.inc(len(batch.roles["w"]) + len(batch.roles["h"]))
+        self._c_unique.inc(len(batch.keys))
+        w.intent(batch.keys, start, end + (self.K - 1))
+
+    def train_batch(self, w, roles, aux, staged=None) -> None:
+        runner = self.device_runner(w.shard)
+        if self.K > 1:
+            self.scan_win.add(runner, roles, aux, self.lr)
             w.advance_clock()
-            return None
-        loss = device_runner(w.shard)(roles, np.asarray(vals[idx]), lr)
+            return
+        runner(roles, aux, self.lr, staged=staged)
         # inline rounds, or delegated to the prefetch pipeline so
         # planner work overlaps the in-flight step
-        srv.drive_rounds(args.sync_rounds_per_step)
+        self.srv.drive_rounds(self.args.sync_rounds_per_step)
         w.advance_clock()
-        return loss
 
-    def signal_intent(w, idx, start, end):
-        ks = np.concatenate([kmap(rows[idx]), kmap(cols[idx] + m)])
-        w.intent(np.unique(ks), start, end + (K - 1))
+    def _walk(self, wi: int, rng) -> None:
+        """Worker `wi`'s pass over its points in batches of B, intent
+        `--lookahead` batches ahead. Shuffled by `rng`, every batch is
+        prepared where its intent is signalled; unshuffled (columnwise)
+        the pass is the same every time, so its batches (keys, values,
+        distinct keys, and with the prefetch pipeline on their upload)
+        are prepared at the first pass and kept."""
+        srv, a = self.srv, self.args
+        w, mine = self.workers[wi], self.by_worker[wi]
+        if rng is not None:
+            index = list(wrap_batches(len(mine), a.batch_size, rng))
+            get = lambda bi: self.prepared(mine[index[bi]])  # noqa: E731
+            n = len(index)
+        else:
+            if wi not in self._train_plans:
+                self._train_plans[wi] = [
+                    self.prepared(mine[idx])
+                    for idx in wrap_batches(len(mine), a.batch_size)]
+            get = self._train_plans[wi].__getitem__
+            n = len(self._train_plans[wi])
+        stage = srv.prefetch is not None and self.K == 1
+        ready = {}
 
-    for epoch in range(args.epochs):
-        if args.algorithm == "dsgd":
-            sched = mfio.dsgd_schedule(total_workers, epoch, seed=args.seed)
-            cblock = mfio.column_block(cols, total_workers, n)
-            for s in range(total_workers):
-                for wi, w in enumerate(workers):
-                    gwi = pid * num_workers + wi  # global worker id
-                    mine = by_worker[wi]
-                    blk = mine[cblock[mine] == sched[s, gwi]]
-                    # intent for the *next* subepoch's block; the clock
-                    # advances once per batch, so the window starts after
-                    # this block's batches and spans the next block's
-                    nb_cur = max(-(-len(blk) // B), 1)
-                    if s + 1 < total_workers:
-                        nxt = mine[cblock[mine] == sched[s + 1, gwi]]
-                        if len(nxt):
-                            nb_nxt = max(-(-len(nxt) // B), 1)
-                            signal_intent(w, nxt, w.current_clock + nb_cur,
-                                          w.current_clock + nb_cur + nb_nxt)
-                    # fixed batch size B: wrap_batches tiles small blocks so
-                    # every fused step has one static shape (one XLA compile)
-                    for idx in wrap_batches(len(blk), B, rng):
-                        train_batch(w, blk[idx])
-                    flush_scan()
-                srv.barrier()  # per-subepoch barrier (reference :409-458)
-        elif args.algorithm == "columnwise":
+        def prepare(bi: int) -> None:
+            with srv._span("app.prepare", self._h_prepare):
+                b = ready[bi] = get(bi)
+                fut = w.current_clock + a.lookahead
+                self.signal_intent(w, b, fut, fut + 1)
+                if stage and b.staged is None:
+                    b.staged = self.device_runner(w.shard).prefetch_keys(
+                        b.roles)
+
+        for bi in range(n):
+            if bi + a.lookahead < n:
+                prepare(bi + a.lookahead)
+            # the first batches: no intent ran ahead
+            b = ready.pop(bi, None) or get(bi)
+            self.train_batch(w, b.roles, b.aux, b.staged)
+        self.scan_win.flush(self.lr)
+
+    def train_pass(self) -> None:
+        """One pass over this process's points in --algorithm's order."""
+        a, srv, workers = self.args, self.srv, self.workers
+        B, T = a.batch_size, self.total_workers
+        if a.algorithm != "dsgd":
+            rng = self.rng if a.algorithm == "plain" else None
+            for wi in range(len(workers)):
+                self._walk(wi, rng)
+            return
+        sched = mfio.dsgd_schedule(T, self.epoch, seed=a.seed)
+        cblock = mfio.column_block(self.cols, T, self.n)
+        for s in range(T):
             for wi, w in enumerate(workers):
-                mine = by_worker[wi][np.argsort(cols[by_worker[wi]],
-                                                kind="stable")]
-                batches = list(wrap_batches(len(mine), B))
-                for bi, idx in enumerate(batches):
-                    la = bi + args.lookahead
-                    if la < len(batches):
-                        signal_intent(w, mine[batches[la]],
-                                      w.current_clock + args.lookahead,
-                                      w.current_clock + args.lookahead + 1)
-                    train_batch(w, mine[idx])
-                flush_scan()
-        else:  # plain SGD
-            for wi, w in enumerate(workers):
-                mine = by_worker[wi]
-                batches = list(wrap_batches(len(mine), B, rng))
-                for bi, idx in enumerate(batches):
-                    la = bi + args.lookahead
-                    if la < len(batches):
-                        signal_intent(w, mine[batches[la]],
-                                      w.current_clock + args.lookahead,
-                                      w.current_clock + args.lookahead + 1)
-                    train_batch(w, mine[idx])
-                flush_scan()
+                gwi = self.pid * self.num_workers + wi  # global worker id
+                mine = self.by_worker[wi]
+                blk = mine[cblock[mine] == sched[s, gwi]]
+                # intent for the *next* subepoch's block; the clock
+                # advances once per batch, so the window starts after
+                # this block's batches and spans the next block's
+                nb_cur = max(-(-len(blk) // B), 1)
+                if s + 1 < T:
+                    nxt = mine[cblock[mine] == sched[s + 1, gwi]]
+                    if len(nxt):
+                        nb_nxt = max(-(-len(nxt) // B), 1)
+                        with srv._span("app.prepare", self._h_prepare):
+                            self.signal_intent(
+                                w, self.prepared(nxt),
+                                w.current_clock + nb_cur,
+                                w.current_clock + nb_cur + nb_nxt)
+                # fixed batch size B: wrap_batches tiles small blocks so
+                # every fused step has one static shape (one XLA compile)
+                for idx in wrap_batches(len(blk), B, self.rng):
+                    self.train_batch(w, *self.batch(blk[idx]))
+                self.scan_win.flush(self.lr)
+            srv.barrier()  # per-subepoch barrier (reference :409-458)
 
-        srv.quiesce()
-        Wc, Hc = _current_factors(srv, kmap, m, n, rank)
-        loss = _full_loss(Wc, Hc, rows, cols, vals, args.l2)
-        epoch_report("mf", epoch, loss, watch, extra=f"lr={lr:.4f}")
-        # bold driver (reference matrix_factorization.cc): grow on success,
-        # shrink on divergence — compared to the *previous* epoch, so a
-        # recovery after one bad epoch counts as success again
-        lr = lr * args.bold_inc if loss <= prev_loss else lr * args.bold_dec
-        prev_loss = loss
-        best_loss = min(best_loss, loss)
+    # -- the pass-end loss ---------------------------------------------------
+
+    def _factor_sq_sum(self):
+        """|W|^2 + |H|^2 as a device scalar: one reduction over the
+        factor columns of the main pool in slot order, vacated slots
+        masked (after quiesce() the main copies are the table)."""
+        srv = self.srv
+        store = srv.stores[0]
+        with srv._lock:
+            if self._occupied_version != srv.topology_version:
+                ab = srv.ab
+                keys = np.nonzero(ab.owner >= 0)[0]   # this process's
+                occ = np.zeros(store.main.shape[:2], dtype=bool)
+                occ[ab.owner[keys], ab.slot[keys]] = True
+                self._occupied = default_port().put_replicated(
+                    occ, srv.ctx.shard0())
+                self._occupied_version = srv.topology_version
+            with srv.exec.track("main"), _GATE:
+                return self._sq_sum(store.main, self._occupied, self.rank)
+
+    def _loss_plan(self, wi: int) -> list:
+        """Worker `wi`'s points in batches of B for the loss walk, the
+        same every pass: keys and values go to the device at the first
+        walk and stay. The last batch is filled up and says how many of
+        its cells count."""
+        if wi not in self._loss_plans:
+            B = self.args.batch_size
+            mine = self.by_worker[wi]
+            runner = self.device_runner(self.workers[wi].shard)
+            put = self.srv.ctx.put_replicated
+            plan = self._loss_plans[wi] = []
+            for lo in range(0, len(mine), B):
+                idx = mine[lo:lo + B]
+                n = len(idx)
+                if n < B:
+                    idx = np.concatenate([idx, np.repeat(idx[-1:], B - n)])
+                roles, x = self.batch(idx)
+                plan.append(_Batch(roles, (put(x), put(np.int32(n))), None,
+                                   runner.prefetch_keys(roles)))
+        return self._loss_plans[wi]
+
+    def pass_loss(self) -> float:
+        """Sum of squared errors over all points + l2 (|W|^2 + |H|^2),
+        the reference's full loss, without the table on the host: every
+        worker scores its own points batch by batch
+        (DeviceRoutedRunner.score over `_loss_plan`'s staged batches),
+        the sums stay on the device, and one fetch brings two numbers
+        back. Several processes add theirs up."""
+        srv, a = self.srv, self.args
+        with srv._span("app.loss_pass", self._h_loss_pass):
+            err = None
+            for wi, w in enumerate(self.workers):
+                runner = self.device_runner(w.shard)
+                for b in self._loss_plan(wi):
+                    err = runner.score(b.roles, b.aux, err,
+                                       staged=b.staged)
+            on_device = bool(a.l2) and srv.tier is None
+            sq = self._factor_sq_sum() if on_device else 0.0
+            with srv._span("app.loss_fetch"):
+                err = 0.0 if err is None else float(err)
+                sq = float(sq)
+        if a.l2 and not on_device:
+            # a tiered table is not wholly on the device
+            W, H = self.current_factors()
+            sq = float((W * W).sum() + (H * H).sum())
+        if self.total_workers > self.num_workers:
+            from ..parallel import control
+            err, sq = control.allreduce([err, sq], "sum", site="mf_loss")
+        return float(err) + a.l2 * float(sq)
+
+
+def open_run(args) -> MfRun:
+    """Set-up: data, server, initialized factors, compiled programs. The
+    returned run's server is live; the caller shuts it down
+    (`run.srv.shutdown()`), as `run` does."""
+    mrun = MfRun(args, _load_data(args))
+    mrun.init_model()
+    if args.enforce_full_replication:
+        enforce_full_replication(mrun.workers, mrun.m + mrun.n)
+    mrun.precompile()
+    return mrun
+
+
+def train(mrun: MfRun) -> float:
+    """`--epochs` passes over an opened run, each ended by the pass-end
+    loss and the bold driver's new step size; stops at the first pass end
+    after `--max_runtime`. Leaves the server up (see open_run) and can be
+    called again on the same run. Returns the best pass loss so far."""
+    args, srv = mrun.args, mrun.srv
+    guard = RuntimeGuard(args.max_runtime)
+    watch = Stopwatch(start=True)
+    for _ in range(args.epochs):
+        mrun.train_pass()
+        with srv._span("app.pass_end", mrun._h_pass_end):
+            srv.quiesce()
+            loss = mrun.pass_loss()
+            lr = mrun.lr
+            # bold driver (reference matrix_factorization.cc): grow on
+            # success, shrink on divergence, compared to the *previous*
+            # pass, so a recovery after one bad pass counts as success
+            mrun.lr = lr * args.bold_inc if loss <= mrun.prev_loss \
+                else lr * args.bold_dec
+        epoch_report("mf", mrun.epoch, loss, watch, extra=f"lr={lr:.4f}")
+        mrun.prev_loss = loss
+        mrun.best_loss = min(mrun.best_loss, loss)
+        mrun.epoch += 1
         if guard.expired():
             alog("[mf] max_runtime reached")
             break
 
-    if args.export_prefix and pid == 0:
-        Wc, Hc = _current_factors(srv, kmap, m, n, rank)
+    if args.export_prefix and mrun.pid == 0:
+        Wc, Hc = mrun.current_factors()
         mfio.write_dense(args.export_prefix + "W.mma", Wc)
         mfio.write_dense(args.export_prefix + "H.mma", Hc)
     alog("[mf]", srv.sync.report())
-    srv.shutdown()
-    return float(best_loss)
+    return float(mrun.best_loss)
 
 
-def _current_factors(srv, kmap, m, n, rank):
-    flat = srv.read_main(kmap(np.arange(m + n)))
-    rowsz = 2 * rank
-    M = flat.reshape(m + n, rowsz)[:, :rank]
-    return M[:m], M[m:]
-
-
-def _full_loss(W, H, rows, cols, vals, l2):
-    pred = (W[rows] * H[cols]).sum(-1)
-    loss = float(((pred - vals) ** 2).sum())
-    if l2:
-        loss += l2 * float((W * W).sum() + (H * H).sum())
-    return loss
+def run(args) -> float:
+    mrun = open_run(args)
+    best = train(mrun)
+    mrun.srv.shutdown()
+    return best
 
 
 def build_parser() -> argparse.ArgumentParser:
-    parser = argparse.ArgumentParser(description=__doc__)
+    parser = argparse.ArgumentParser(
+        description=__doc__,
+        formatter_class=argparse.RawDescriptionHelpFormatter)
     parser.add_argument("--data", default=None,
                         help="MatrixMarket coordinate file (else synthetic)")
     parser.add_argument("--rows", type=int, default=200)
@@ -238,11 +472,19 @@ def build_parser() -> argparse.ArgumentParser:
     parser.add_argument("--lookahead", type=int, default=2,
                         help="intent batches ahead (columnwise/plain)")
     parser.add_argument("--adagrad_init", type=float, default=1e-6)
-    parser.add_argument("--bold_inc", type=float, default=1.05)
-    parser.add_argument("--bold_dec", type=float, default=0.5)
+    parser.add_argument("--bold_inc", type=float, default=1.05,
+                        help="step-size factor after a pass whose loss "
+                             "(computed on the device, see above) did "
+                             "not rise")
+    parser.add_argument("--bold_dec", type=float, default=0.5,
+                        help="step-size factor after a pass whose loss "
+                             "rose")
     parser.add_argument("--init_w", default=None)
     parser.add_argument("--init_h", default=None)
-    parser.add_argument("--export_prefix", default=None)
+    parser.add_argument("--export_prefix", default=None,
+                        help="write W.mma / H.mma under this prefix at "
+                             "the end of train(): the one reader of the "
+                             "whole table on the host")
     add_common_arguments(parser)
     return parser
 

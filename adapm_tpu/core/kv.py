@@ -1325,7 +1325,8 @@ class Server:
         `steps`: one `(runner, role_keys, aux)` for each KIND of
         fused-step runner the loop drives (runners built alike share
         their programs: one of them stands for all); each compiles its
-        per-step variants (DeviceRoutedRunner.precompile).
+        per-step variants (DeviceRoutedRunner.precompile). A fourth
+        entry is the `score_aux` of a runner that has a score program.
 
         Returns how many planner programs ran."""
         ran = 0
@@ -1342,8 +1343,8 @@ class Server:
                         synced=self.num_shards * min(st.cache_slots,
                                                      in_class),
                         sync_variants=variants)
-        for runner, role_keys, aux in steps:
-            runner.precompile(role_keys, aux)
+        for runner, role_keys, aux, *score_aux in steps:
+            runner.precompile(role_keys, aux, *score_aux)
         return ran
 
     # -- lifecycle -----------------------------------------------------------

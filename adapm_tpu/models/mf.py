@@ -27,6 +27,16 @@ def make_mf_loss(l2: float = 0.0):
     return loss_fn
 
 
+def mf_sq_error(embs, aux):
+    """The read-only objective of the pass-end loss (reference
+    apps/mf/loss.h): the squared residuals of a batch, summed. Roles as
+    in `make_mf_loss`; aux = (x [B], n): only the first n examples count
+    (the last batch of a walk is filled up to B)."""
+    x, n = aux
+    err = ((embs["w"] * embs["h"]).sum(-1) - x) ** 2
+    return jnp.where(jnp.arange(err.shape[0]) < n, err, 0).sum()
+
+
 def row_key(i: np.ndarray):
     return np.asarray(i, dtype=np.int64)
 

@@ -354,6 +354,72 @@ def make_device_routed_scan(loss_fn: Callable[..., jnp.ndarray],
     return default_port().compile(scan, donate_argnums=(0,))
 
 
+def _route_and_gather(pools, tables, keys, roles, role_class, no_replicas):
+    """Route every role's keys and gather their rows: the read half of a
+    fused step, shared with the gather-only score program
+    (`make_device_routed_score`). Returns (rows, routes, n_total,
+    n_local); the last two are the device-side locality counts
+    (reference coloc_kv_server.h:147-157 prints % accesses served
+    locally; Pull/Push record this in Server._route, which a step never
+    visits): a key access is local when this worker's shard owns the row
+    or holds a replica."""
+    rows = {}
+    routes = {}
+    n_total = 0
+    n_local = jnp.int32(0)
+    shard = tables[3]  # the worker's, an int32 scalar operand
+    for r in roles:
+        cid = role_class[r]
+        main, cache, delta = pools[cid]
+        n_total += keys[r].size
+        if no_replicas:
+            owner, slot = tables[:2]
+            with jax.named_scope("adapm_route"):
+                o_sh, o_sl = owner[keys[r]], slot[keys[r]]
+            routes[r] = (o_sh, o_sl)
+            with jax.named_scope("adapm_gather"):
+                rows[r] = main.at[o_sh, o_sl].get(mode="fill",
+                                                  fill_value=0)
+            n_local += jnp.sum(o_sh == shard, dtype=jnp.int32)
+            continue
+        with jax.named_scope("adapm_route"):
+            routes[r] = _route_on_device(tables, keys[r])
+        with jax.named_scope("adapm_gather"):
+            rows[r] = _read_rows(main, cache, delta, routes[r])
+        o_sh, use_c = routes[r][0], routes[r][4]
+        n_local += jnp.sum(use_c | (o_sh == shard), dtype=jnp.int32)
+    return rows, routes, n_total, n_local
+
+
+def make_device_routed_score(score_fn: Callable[..., jnp.ndarray],
+                             role_class: Dict[str, int],
+                             role_dim: Dict[str, int],
+                             roles: Sequence[str],
+                             no_replicas: bool = False):
+    """The read half of the fused step as a program of its own: route and
+    gather `roles` exactly as the step does (`_route_and_gather`), hand
+    the embedding columns to `score_fn(embs, aux) -> scalar`, and add the
+    result to an accumulator. No gradient, no write-back, and the pools
+    are not donated: they come back untouched. Signature of the returned
+    program (named `jit_score` in a device trace):
+
+        score(pools, tables, keys, aux, acc) -> acc + score_fn(embs, aux)
+
+    A pass-end objective (apps/matrix_factorization.py: the squared error
+    over the worker's own points) is then a walk of such dispatches whose
+    sum stays on the device until one fetch."""
+    roles = sorted(roles)
+
+    def score(pools, tables, keys, aux, acc):
+        rows, _, _, _ = _route_and_gather(pools, tables, dict(keys), roles,
+                                          role_class, no_replicas)
+        embs = {r: rows[r][..., : role_dim[r]] for r in roles}
+        with jax.named_scope("adapm_loss_grad"):
+            return acc + score_fn(embs, aux)
+
+    return default_port().compile(score)
+
+
 def _build_device_routed_body(loss_fn, role_class, role_dim,
                               frozen_roles, neg_role, neg_shape,
                               no_replicas, neg_alias):
@@ -380,35 +446,8 @@ def _build_device_routed_body(loss_fn, role_class, role_dim,
                 idx, count = local_index  # padded index + valid count
                 pos = jax.random.randint(rng_key, neg_shape, 0, count)
                 keys[neg_role] = idx[pos]
-        rows = {}
-        routes = {}
-        # device-side locality counters (reference coloc_kv_server.h:147-157
-        # prints % accesses served locally; Pull/Push record this in
-        # Server._route, which a step never visits): a key access is
-        # local when this worker's shard owns the row or holds a replica
-        n_total = 0
-        n_local = jnp.int32(0)
-        shard = tables[3]  # the worker's, an int32 scalar operand
-        for r in roles:
-            cid = role_class[r]
-            main, cache, delta = pools[cid]
-            n_total += keys[r].size
-            if no_replicas:
-                owner, slot = tables[:2]
-                with jax.named_scope("adapm_route"):
-                    o_sh, o_sl = owner[keys[r]], slot[keys[r]]
-                routes[r] = (o_sh, o_sl)
-                with jax.named_scope("adapm_gather"):
-                    rows[r] = main.at[o_sh, o_sl].get(mode="fill",
-                                                      fill_value=0)
-                n_local += jnp.sum(o_sh == shard, dtype=jnp.int32)
-                continue
-            with jax.named_scope("adapm_route"):
-                routes[r] = _route_on_device(tables, keys[r])
-            with jax.named_scope("adapm_gather"):
-                rows[r] = _read_rows(main, cache, delta, routes[r])
-            o_sh, use_c = routes[r][0], routes[r][4]
-            n_local += jnp.sum(use_c | (o_sh == shard), dtype=jnp.int32)
+        rows, routes, n_total, n_local = _route_and_gather(
+            pools, tables, keys, roles, role_class, no_replicas)
         # one step = one (batched) pull op + one push op of the same keys;
         # the op counts local iff every key it touched was local
         all_local = (n_local == n_total).astype(jnp.int32)
@@ -488,7 +527,7 @@ class DeviceRoutedRunner:
                  frozen_roles: Sequence[str] = (), neg_role: str = None,
                  neg_shape: Tuple[int, ...] = None,
                  neg_population=None, neg_alias=None, seed: int = 0,
-                 programs: Optional[Dict] = None):
+                 programs: Optional[Dict] = None, score_fn=None):
         """`neg_alias=(prob, alias)` (models/sgns.py build_alias_table)
         switches on-device negative sampling to the app's non-uniform
         distribution over `neg_population` (position i of the population
@@ -503,7 +542,11 @@ class DeviceRoutedRunner:
         of the compiled step, so such runners run the same programs: the
         first to need a variant compiles it into the dict and the others
         find it there, and a server with four workers compiles each
-        variant once and not four times. None: this runner's own."""
+        variant once and not four times. None: this runner's own.
+
+        `score_fn(embs, aux) -> scalar`: the read-only objective that
+        `score` evaluates over the host-named roles (None: the runner
+        has no score program)."""
         self.server = server
         self._programs = {} if programs is None else programs
         self.shard = shard
@@ -588,6 +631,10 @@ class DeviceRoutedRunner:
         self._c_wb_kernel_rows = server.obs.counter(
             "fused.writeback_kernel_rows_total", unit="rows", shared=True)
         self._wb_rows = None  # (all, kernel's) a step; set on first step
+        # rows the gather-only score program read (`score`)
+        self._c_score_rows = server.obs.counter(
+            "fused.score_rows_total", unit="rows", shared=True)
+        self._score_fn = score_fn
         # the locality accumulator's first two entries as counters (rows
         # the steps touched / found on the worker's shard), moved at each
         # drain: what a per-layer metric reads (PERF.md section 3)
@@ -633,7 +680,7 @@ class DeviceRoutedRunner:
         return self.router.tables() + (self._shard_dev,)
 
     def precompile(self, role_keys: Dict[str, np.ndarray],
-                   aux=None) -> None:
+                   aux=None, score_aux=None) -> None:
         """Compile (or fetch from the compile cache) the step variants
         this runner can reach, before a timed loop meets them: the
         replica-free variant, and on a server of several shards the
@@ -642,7 +689,8 @@ class DeviceRoutedRunner:
         steps', against a slot table that is out of bounds everywhere:
         every gather fills zeros and every write-back is dropped, so the
         pools come back bit for bit, and neither the RNG sequence nor
-        the locality counts move."""
+        the locality counts move. A runner with a `score_fn` compiles
+        its score program's variants the same way, on `score_aux`."""
         srv = self.server
         with srv._lock:
             owner, _, cache_row, shard = self._tables()
@@ -669,6 +717,15 @@ class DeviceRoutedRunner:
                         self._scalar(0.0), self._scalar(1e-10))
                     for st, (m, c, d) in zip(srv.stores, pools):
                         st.main, st.cache, st.delta = m, c, d
+            if self._score_fn is None:
+                return
+            pools = tuple((s.main, s.cache, s.delta) for s in srv.stores)
+            variants = [True] if srv.num_shards == 1 else [True, False]
+            for no_replicas in variants:
+                with srv.exec.track("main"), _GATE:
+                    self._score_program(no_replicas)(
+                        pools, (owner, nowhere, no_cache, shard), keys,
+                        score_aux, self._scalar(0.0))
 
     def _prefetch_refresh(self) -> None:
         """Called by the prefetch pipeline (under the server lock) after
@@ -1030,6 +1087,52 @@ class DeviceRoutedRunner:
             if self.steps % self._drain_every == 0:
                 self._drain_locstat()
         return loss
+
+    def _score_program(self, no_replicas: bool):
+        """The compiled score program of one variant, kept in `programs`
+        beside the step's."""
+        key = ("make_device_routed_score", no_replicas)
+        if key not in self._programs:
+            roles = [r for r in self.role_class if r != self.neg_role]
+            self._programs[key] = make_device_routed_score(
+                self._score_fn, self.role_class,
+                self._mk_kwargs["role_dim"], roles,
+                no_replicas=no_replicas)
+        return self._programs[key]
+
+    def score(self, role_keys: Dict[str, np.ndarray], aux, acc=None,
+              staged: Optional[StagedKeys] = None) -> jnp.ndarray:
+        """`acc + score_fn(embs, aux)` over the rows `role_keys` name,
+        as a device scalar: the fused step's route and gather without
+        its gradient and write-back (make_device_routed_score). A Pull
+        in PM terms: nothing is written, no pool is donated, no clock
+        moves, and neither the RNG sequence nor the locality counts are
+        touched. `acc` (a device scalar from an earlier call, or None
+        for zero) lets a walk over many batches keep its sum on the
+        device and fetch it once. `staged`: the handle `prefetch_keys`
+        returned for THIS batch (it was checked there); a walk that
+        scores the same batches again and again uploads them once."""
+        srv = self.server
+        with srv._span("fused.score"):
+            if staged is None or srv.glob is not None:
+                self._check_batch(role_keys)  # several processes: fetch
+            with srv._lock:
+                if srv.tier is not None:
+                    srv.tier.pin_step_keys(self.role_class, role_keys)
+                tables = self._tables()
+                kdtype = _key_dtype(srv.num_keys)
+                keys = staged.dev if staged is not None else \
+                    self._upload_keys({r: np.asarray(k, dtype=kdtype)
+                                       for r, k in role_keys.items()})
+                pools = tuple((s.main, s.cache, s.delta)
+                              for s in srv.stores)
+                fn = self._score_program(not self._shard_has_replicas())
+                with srv.exec.track("main"), _GATE:
+                    acc = fn(pools, tables, keys, aux,
+                             self._scalar(0.0) if acc is None else acc)
+                self._c_score_rows.inc(
+                    sum(np.asarray(k).size for k in role_keys.values()))
+        return acc
 
     def _scan_fn(self, no_replicas: bool, has_aux: bool):
         return self._program(make_device_routed_scan,

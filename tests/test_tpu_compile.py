@@ -186,3 +186,63 @@ def test_step_with_kernel_has_no_pool_sized_temporary(cell, shape,
     assert mem.alias_size_in_bytes >= sum(slots) * L * 4
     assert mem.temp_size_in_bytes <= parent_temp + (64 << 20)
 
+
+
+# the MF cell (mf-10mx1m): main pool slots, keys, batch
+MF_SLOTS, MF_KEYS, MF_B = 1_402_504, 1_375_000, 8192
+
+
+def _mf_operands(shape):
+    small = shape((1, 8, L), jnp.float32)
+    pools = ((shape((1, MF_SLOTS, L), jnp.float32), small, small),)
+    tables = tuple(shape((MF_KEYS,), jnp.int32) for _ in range(3)) \
+        + (shape((), jnp.int32),)
+    keys = {r: shape((MF_B,), jnp.int32) for r in ("w", "h")}
+    return pools, tables, keys
+
+
+def test_mf_cell_programs_fit_beside_the_table(shape, kernel_cache,
+                                               monkeypatch, capsys):
+    """The three programs of the MF cell at its own sizes (an 11.49 GB
+    pool of 1,402,504 slots, 16,384 rows a step): the replica-free step
+    with the write-back kernel keeps the pool aliased and under 1 GB of
+    temporaries; the gather-only score program and the L2 reduction over
+    the pool's factor columns read the pool where it lies (no pool-sized
+    temporary: neither the sliced columns nor their squares are an
+    array)."""
+    from adapm_tpu.apps.matrix_factorization import _masked_sq_sum
+    from adapm_tpu.models.mf import make_mf_loss, mf_sq_error
+    from adapm_tpu.ops import fused
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    pools, tables, keys = _mf_operands(shape)
+    pool_bytes = MF_SLOTS * L * 4
+    roles = {"w": 0, "h": 0}
+    dims = {r: L // 2 for r in roles}
+    f32, x = shape((), jnp.float32), shape((MF_B,), jnp.float32)
+
+    body = fused._build_device_routed_body(
+        make_mf_loss(0.01), roles, dims, (), None, None, True, False)
+    step = jax.jit(body, donate_argnums=(0,)).lower(
+        pools, shape((4,), jnp.int32), tables, keys, None, None,
+        shape((2,), jnp.uint32), x, f32, f32).compile()
+    assert step.as_text().count("tpu_custom_call") >= 2
+    mem = step.memory_analysis()
+    assert mem.alias_size_in_bytes >= pool_bytes
+    assert mem.temp_size_in_bytes < 1 << 30
+    sizes = {"step": mem.temp_size_in_bytes}
+
+    score = fused.make_device_routed_score(
+        mf_sq_error, roles, dims, roles, no_replicas=True).lower(
+        pools, tables, keys, (x, shape((), jnp.int32)), f32).compile()
+    assert "tpu_custom_call" not in score.as_text()
+    sizes["score"] = score.memory_analysis().temp_size_in_bytes
+    assert sizes["score"] < 256 << 20
+
+    sq = jax.jit(_masked_sq_sum, static_argnums=2).lower(
+        pools[0][0], shape((1, MF_SLOTS), jnp.bool_), L // 2).compile()
+    sizes["sq_sum"] = sq.memory_analysis().temp_size_in_bytes
+    assert sizes["sq_sum"] < 256 << 20
+    with capsys.disabled():
+        print(f"\nmf-10mx1m v5e compile: pool {pool_bytes / 1e9:.3f} GB, "
+              f"temporaries (MB) " + ", ".join(
+                  f"{k} {v / 1e6:.1f}" for k, v in sizes.items()))

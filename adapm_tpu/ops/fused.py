@@ -280,6 +280,11 @@ def make_device_routed_step(loss_fn: Callable[..., jnp.ndarray],
     When `neg_role` is set and local_index is given, that role's keys
     are DRAWN in-program: uniform positions into local_index — the Local
     sampling scheme (core/sampling.py LocalSampling) executed on device.
+    The draw has the shape `neg_shape` = `[B, N]` and the loss is handed
+    `[B, N, dim]` rows; in between the replica-free variant lays the
+    role out SAMPLE-MAJOR (`[N, B, .]`: keys, routes, gathered rows,
+    accumulators, gradients), so that no reshape of its rows is a copy
+    whatever N is (`_build_device_routed_body`).
 
     `neg_alias=True` switches the draw to a NON-uniform app distribution:
     the step takes an extra `alias` argument (prob[V], alias[V], snap[V]
@@ -297,7 +302,10 @@ def make_device_routed_step(loss_fn: Callable[..., jnp.ndarray],
     into main. Legal exactly while this shard holds zero replicas — the
     runner re-checks per step and switches variants (HBM bandwidth is the
     roofline for embedding workloads, so this is a large win whenever the
-    planner hasn't replicated anything here).
+    planner hasn't replicated anything here). Its gather is clamped and
+    its out-of-bounds mask covers the embedding columns only
+    (`_route_and_gather`); the replica variant masks whole rows, three
+    times (`_read_rows`).
     """
     body = _build_device_routed_body(
         loss_fn, role_class, role_dim, frozen_roles, neg_role,
@@ -354,23 +362,43 @@ def make_device_routed_scan(loss_fn: Callable[..., jnp.ndarray],
     return default_port().compile(scan, donate_argnums=(0,))
 
 
-def _route_and_gather(pools, tables, keys, roles, role_class, no_replicas):
+def _in_bounds(idx, n: int):
+    """Whether jnp's indexing finds `idx` among `n` entries: a negative
+    index wraps once (-1 is the last entry), anything further out is out
+    of bounds, which `mode="fill"` fills and `mode="drop"` drops."""
+    return (idx >= -n) & (idx < n)
+
+
+def _route_and_gather(pools, tables, keys, roles, role_class, role_dim,
+                      no_replicas):
     """Route every role's keys and gather their rows: the read half of a
     fused step, shared with the gather-only score program
-    (`make_device_routed_score`). Returns (rows, routes, n_total,
-    n_local); the last two are the device-side locality counts
+    (`make_device_routed_score`). Returns (embs, accs, routes, n_total,
+    n_local): each role's embedding columns and accumulator columns in
+    the shape of its keys (a sampled role's are sample-major, `[N, B]`,
+    in the replica-free variant: `_build_device_routed_body`), and the
+    device-side locality counts
     (reference coloc_kv_server.h:147-157 prints % accesses served
     locally; Pull/Push record this in Server._route, which a step never
     visits): a key access is local when this worker's shard owns the row
-    or holds a replica."""
-    rows = {}
-    routes = {}
+    or holds a replica.
+
+    A position whose route is out of bounds (`OOB`: a cold tier row, a
+    padding position) reads as a ZERO embedding. The replica-free
+    variant gathers with `mode="clip"` and zeroes the embedding columns
+    alone, where the loss reads them, so no row-wide mask crosses HBM;
+    the accumulator columns of such a position are whatever row the
+    clamp found, and nobody reads them: the write-back drops the
+    position (its code is -1 in the kernel, `mode="drop"` in XLA's
+    scatter-add)."""
+    embs, accs, routes = {}, {}, {}
     n_total = 0
     n_local = jnp.int32(0)
     shard = tables[3]  # the worker's, an int32 scalar operand
     for r in roles:
         cid = role_class[r]
         main, cache, delta = pools[cid]
+        dim = role_dim[r]
         n_total += keys[r].size
         if no_replicas:
             owner, slot = tables[:2]
@@ -378,17 +406,21 @@ def _route_and_gather(pools, tables, keys, roles, role_class, no_replicas):
                 o_sh, o_sl = owner[keys[r]], slot[keys[r]]
             routes[r] = (o_sh, o_sl)
             with jax.named_scope("adapm_gather"):
-                rows[r] = main.at[o_sh, o_sl].get(mode="fill",
-                                                  fill_value=0)
-            n_local += jnp.sum(o_sh == shard, dtype=jnp.int32)
-            continue
-        with jax.named_scope("adapm_route"):
-            routes[r] = _route_on_device(tables, keys[r])
-        with jax.named_scope("adapm_gather"):
-            rows[r] = _read_rows(main, cache, delta, routes[r])
-        o_sh, use_c = routes[r][0], routes[r][4]
-        n_local += jnp.sum(use_c | (o_sh == shard), dtype=jnp.int32)
-    return rows, routes, n_total, n_local
+                rows = main.at[o_sh, o_sl].get(mode="clip")
+                valid = _in_bounds(o_sh, main.shape[0]) \
+                    & _in_bounds(o_sl, main.shape[1])
+                embs[r] = jnp.where(valid[..., None], rows[..., :dim], 0)
+            local = o_sh == shard
+        else:
+            with jax.named_scope("adapm_route"):
+                routes[r] = _route_on_device(tables, keys[r])
+            with jax.named_scope("adapm_gather"):
+                rows = _read_rows(main, cache, delta, routes[r])
+            embs[r] = rows[..., :dim]
+            local = routes[r][4] | (routes[r][0] == shard)  # use_c, o_sh
+        accs[r] = rows[..., dim:]
+        n_local += jnp.sum(local, dtype=jnp.int32)
+    return embs, accs, routes, n_total, n_local
 
 
 def make_device_routed_score(score_fn: Callable[..., jnp.ndarray],
@@ -411,9 +443,9 @@ def make_device_routed_score(score_fn: Callable[..., jnp.ndarray],
     roles = sorted(roles)
 
     def score(pools, tables, keys, aux, acc):
-        rows, _, _, _ = _route_and_gather(pools, tables, dict(keys), roles,
-                                          role_class, no_replicas)
-        embs = {r: rows[r][..., : role_dim[r]] for r in roles}
+        embs, _, _, _, _ = _route_and_gather(
+            pools, tables, dict(keys), roles, role_class, role_dim,
+            no_replicas)
         with jax.named_scope("adapm_loss_grad"):
             return acc + score_fn(embs, aux)
 
@@ -425,9 +457,35 @@ def _build_device_routed_body(loss_fn, role_class, role_dim,
                               no_replicas, neg_alias):
     """The un-jitted single-step body shared by make_device_routed_step
     (one dispatch per step) and make_device_routed_scan (K steps per
-    dispatch)."""
+    dispatch).
+
+    In the replica-free variant no row-wide array is copied, padded or
+    masked as a whole between the gather and its readers. The sampled
+    role's keys are drawn in `neg_shape` = `[B, N]` (the draw is the
+    caller's to mirror) and TRANSPOSED to `[N, B]` before they are
+    routed: routes, gathered rows, accumulators and gradients of that
+    role are sample-major, `[N, B, .]`, whose tiled dims `[B, .]` are
+    whole tiles for any N, so every reshape to and from the flat
+    `[N * B, .]` that the gather gives and the write-back takes is a
+    bitcast (a `[B, 5, .]` array pads its 5 to the 8 rows of a tile and
+    is copied each time). The loss is handed the `[B, N, dim]` view it
+    was written for, and its gradient comes back through the same view.
+    Among positions that name ONE row the write-back adds in the order
+    of the flattened routes: `(k, b)` for the sampled role. The
+    out-of-bounds mask sits on the embedding columns
+    (`_route_and_gather`). The replica variant keeps the batch-major
+    order: it masks and selects whole rows three times over
+    (`_read_rows`), flattens nothing for XLA's scatter-add, and on the
+    chip the view cost it a half-row copy the loss had read in place."""
     roles = sorted(role_class)
     trainable = [r for r in roles if r not in frozen_roles]
+    sample_major = no_replicas and neg_role is not None
+
+    def batch_major_loss(embs, aux):
+        if sample_major:
+            embs = dict(embs)
+            embs[neg_role] = jnp.moveaxis(embs[neg_role], 0, -2)
+        return loss_fn(embs, aux)
 
     def step(pools, locstat, tables, keys, local_index, alias, rng_key,
              aux, lr, eps):
@@ -446,16 +504,18 @@ def _build_device_routed_body(loss_fn, role_class, role_dim,
                 idx, count = local_index  # padded index + valid count
                 pos = jax.random.randint(rng_key, neg_shape, 0, count)
                 keys[neg_role] = idx[pos]
-        rows, routes, n_total, n_local = _route_and_gather(
-            pools, tables, keys, roles, role_class, no_replicas)
+            if sample_major:
+                # sample-major from here on; the values at [b, k] stay
+                keys[neg_role] = jnp.moveaxis(keys[neg_role], -1, 0)
+        embs, accs, routes, n_total, n_local = _route_and_gather(
+            pools, tables, keys, roles, role_class, role_dim, no_replicas)
         # one step = one (batched) pull op + one push op of the same keys;
         # the op counts local iff every key it touched was local
         all_local = (n_local == n_total).astype(jnp.int32)
         locstat = locstat + jnp.stack(
             [jnp.int32(n_total), n_local, jnp.int32(1), all_local])
-        embs = {r: rows[r][..., : role_dim[r]] for r in roles}
-        accs = {r: rows[r][..., role_dim[r]:] for r in roles}
-        loss, grads = _loss_and_grads(loss_fn, embs, trainable, aux)
+        loss, grads = _loss_and_grads(batch_major_loss, embs, trainable,
+                                      aux)
 
         new_pools = list(pools)
         for r in trainable:

@@ -5,6 +5,9 @@ runs, so nothing here is a result or a time. The topology is described
 inside a fixture (never at import: only one process may load the TPU's
 library, and every pytest worker imports this file), and all such tests
 live in this one file."""
+import math
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -136,24 +139,31 @@ def test_adagrad_pass_is_in_the_xla_variant_only(kernel, shape,
 
 
 # (model, main pools' slots, keys, batch, negatives a row, the parent's
-# temporaries in bytes: v5e compile of PR 28's step, PERF.md section 4;
-# PR 29's own read 2.87e9 and 1.015e9)
+# temporaries in bytes: SGNS by the v5e compile of PR 28's step, PERF.md
+# section 4 (PR 29's read 1.015e9, PR 31's 0.842e9); KGE by PR 31's own
+# reading: `memory_analysis` says 3.256e9 for any form of the step that
+# hands the sampled rows on sample-major or masks the embedding half
+# (PR 29's step read 2.87e9), while XLA's buffer assignment of the same
+# compile FELL, 2.866e9 -> 2.790e9 preallocated; 9.6 GB of table beside
+# either leaves 2.8 GB of the chip's 15.75 GiB: PERF.md section 6)
 CELLS = {
-    "kge-wikidata5m": ((1_172_432, 840), 1_149_443, 4096, 32, 2.96e9),
+    "kge-wikidata5m": ((1_172_432, 840), 1_149_443, 4096, 32, 3.26e9),
     "w2v-1bw": ((1_618_688,), 1_586_942, 8192, 5, 1.02e9),
 }
 
 
-@pytest.mark.parametrize("cell", sorted(CELLS))
-def test_step_with_kernel_has_no_pool_sized_temporary(cell, shape,
-                                                      kernel_cache,
-                                                      monkeypatch):
-    """The replica-free fused step of each training cell, built as on a
-    TPU (the write-back kernel, exported): the pools stay aliased and
-    the temporaries do not grow by more than 64 MB over the parent's."""
+_COMPILED = {}  # cell -> its compiled step, for the tests of one worker
+
+
+def _cell_step(cell, shape, monkeypatch):
+    """The replica-free fused step of a training cell at its own sizes,
+    built as on a TPU (the write-back kernel, exported) and compiled
+    for the described chip, and how many roles it writes back."""
+    if cell in _COMPILED:
+        return _COMPILED[cell]
     from adapm_tpu.ops import fused
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    slots, num_keys, B, N, parent_temp = CELLS[cell]
+    slots, num_keys, B, N, _ = CELLS[cell]
     if cell == "kge-wikidata5m":
         from adapm_tpu.models.kge import make_kge_loss
         loss, roles = make_kge_loss("complex", 0.0, 0.0), \
@@ -181,10 +191,63 @@ def test_step_with_kernel_has_no_pool_sized_temporary(cell, shape,
         if alias is None else None, alias,
         shape((2,), jnp.uint32), None, shape((), jnp.float32),
         shape((), jnp.float32)).compile()
-    assert compiled.as_text().count("tpu_custom_call") >= len(roles)
+    _COMPILED[cell] = compiled, len(roles)
+    return _COMPILED[cell]
+
+
+@pytest.mark.parametrize("cell", sorted(CELLS))
+def test_step_with_kernel_has_no_pool_sized_temporary(cell, shape,
+                                                      kernel_cache,
+                                                      monkeypatch):
+    """The replica-free fused step of each training cell, built as on a
+    TPU (the write-back kernel, exported): the pools stay aliased and
+    the temporaries do not grow by more than 64 MB over the parent's."""
+    slots, _, _, _, parent_temp = CELLS[cell]
+    compiled, n_roles = _cell_step(cell, shape, monkeypatch)
+    assert compiled.as_text().count("tpu_custom_call") >= n_roles
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= sum(slots) * L * 4
     assert mem.temp_size_in_bytes <= parent_temp + (64 << 20)
+
+
+_HLO_OP = re.compile(
+    r"^\s*(?:ROOT )?%(\S+) = (\(.*?\)|\S+) ([a-z][a-z\-]*)\(")
+_F32 = re.compile(r"f32\[([\d,]+)\]")
+
+
+def _entry_ops(text: str):
+    """(name, opcode, [dims of each float32 result]) of the top-level
+    operations of a compiled program: the ENTRY computation's lines."""
+    entry = text[text.index("\nENTRY "):]
+    for line in entry[1:entry.index("\n}")].split("\n")[1:]:
+        m = _HLO_OP.match(line)
+        if m:
+            yield m.group(1), m.group(3), [
+                tuple(int(d) for d in dims.split(","))
+                for dims in _F32.findall(m.group(2))]
+
+
+@pytest.mark.parametrize("cell", sorted(CELLS))
+def test_step_copies_no_sampled_rows(cell, shape, kernel_cache,
+                                     monkeypatch):
+    """Between the gather and its readers the compiled step neither
+    copies the sampled role's rows (a top-level `reshape`, `copy` or
+    `transpose` over all B * N of them, of whole or half rows: the
+    batch-major `[B, 5, .]` was padded to 8 and copied three times a
+    step) nor masks whole rows (a `select` fusion of row width)."""
+    _, _, B, N, _ = CELLS[cell]
+    compiled, n_roles = _cell_step(cell, shape, monkeypatch)
+    ops = list(_entry_ops(compiled.as_text()))
+    assert sum(op == "custom-call" for _, op, _ in ops) >= n_roles
+    copies = [(name, op, res) for name, op, res in ops
+              if op in ("reshape", "copy", "transpose") and any(
+                  dims[-1] in (L // 2, L)
+                  and math.prod(dims[:-1]) == B * N for dims in res)]
+    assert not copies, copies
+    masks = [(name, res) for name, op, res in ops
+             if op == "fusion" and "select" in name
+             and any(dims[-1] == L for dims in res)]
+    assert not masks, masks
 
 
 

@@ -1,0 +1,363 @@
+"""Click-through-rate training app: DLRM with a low-rank DCNv2 interaction
+(models/dlrm.py; MLPerf Training's recommendation model, torchrec
+`dlrm_main.py`) over multi-hot categorical features, every trainable
+parameter in the parameter manager. The fused-step form of the task that
+`examples/ctr_example.py` runs through the bindings and torch.
+
+Two length classes meet in one fused step (ops/fused.py): the embedding
+tables' rows, [embedding (dim) | AdaGrad (dim)], of which a step names
+`sum(multi_hot_sizes)` an example, pooled into one bag a feature; and the
+dense network's tensors flattened into rows [weights | AdaGrad] of
+`--dense_row` weights, ALL of which every step names (a key that every
+worker reads in every step is what the planner replicates on every node).
+The step gathers both, the loss reshapes the gathered dense rows into its
+matrices and multiplies, and AdaGrad writes both classes back.
+
+Key layout: the tables' held rows in table order, then the dense tensors
+in network order (models/dlrm.py DenseLayout).
+
+A pass is the same `--examples` examples every pass, in batches of
+`--batch_size`, so its batches (keys member-major `[members, B]`, the
+distinct keys of the intent, the keys' and the dense features' upload) are
+prepared once and kept. Per step: the intent `--lookahead` batches ahead,
+the dispatch, the planner's rounds, the clock. A pass ends with
+`quiesce()` and one fetch of the mean of its steps' losses.
+
+`open_run(args)` sets a run up, `train(run)` trains `--epochs` passes on it
+(and can be called again), `run(args)` is both and shuts the server down.
+
+Run: python -m adapm_tpu.apps.ctr --examples 4096 ...
+"""
+from __future__ import annotations
+
+import argparse
+import sys
+
+import jax.numpy as jnp
+import numpy as np
+
+from ..models.dlrm import DenseLayout, dense_tensors, make_dlrm_loss
+from ..ops import DeviceRoutedRunner
+from ..utils import Stopwatch, alog
+from .common import (RuntimeGuard, add_common_arguments,
+                     enforce_full_replication, epoch_report,
+                     global_worker_slices, make_server, wrap_batches)
+
+
+# AdaGrad's damping: a position's update is -lr g / sqrt(acc + g*g + eps).
+# The other apps start their accumulators at 1e-6 under the runner's eps
+# of 1e-10; this network's gradients are too small for that (the mean
+# over a batch through eight layers: a feature row's g*g is 1e-16, which
+# float32 cannot add to 1e-6), so the accumulators start at 0, where
+# every g*g registers, and the same 1e-6 damps from here
+ADAGRAD_EPS = 1e-6
+
+
+def _ints(text: str) -> list:
+    return [int(x) for x in text.split(",")]
+
+
+def generate_synthetic(table_rows, multi_hot_sizes, num_dense: int, n: int,
+                       zipf: float, click_rate: float, seed: int):
+    """n examples (members [n, M] table-local row ids, dense features
+    [n, num_dense], labels [n]): per table ids Zipf(`zipf`) over its rows,
+    a bag's members drawn independently, dense features N(0, 1), labels
+    Bernoulli of a logistic ground truth over the dense features whose
+    offset puts the click rate near `click_rate`."""
+    rng = np.random.default_rng(seed)
+    cols = []
+    for rows, hot in zip(table_rows, multi_hot_sizes):
+        cdf = np.cumsum(1.0 / np.arange(1, rows + 1) ** zipf)
+        u = rng.random((n, hot)) * cdf[-1]
+        cols.append(np.minimum(np.searchsorted(cdf, u, side="right"),
+                               rows - 1))
+    x = rng.standard_normal((n, num_dense)).astype(np.float32)
+    w = rng.standard_normal(num_dense) / np.sqrt(num_dense)
+    logit = x @ w + np.log(click_rate / (1.0 - click_rate))
+    y = rng.random(n) < 1.0 / (1.0 + np.exp(-logit))
+    return (np.concatenate(cols, axis=1).astype(np.int64), x,
+            y.astype(np.float32))
+
+
+class _Batch:
+    """A prepared batch: role keys, the step's aux on the device, the
+    distinct keys among the role keys (the intent's) and the keys'
+    upload."""
+
+    __slots__ = ("roles", "aux", "keys", "staged")
+
+    def __init__(self, roles, aux, keys, staged):
+        self.roles, self.aux, self.keys, self.staged = \
+            roles, aux, keys, staged
+
+
+class CtrRun:
+    """One training run: the server, its workers and their fused runners,
+    the examples with their keys, and the pass count."""
+
+    def __init__(self, args, data):
+        self.args = args
+        self.table_rows = _ints(args.table_rows)
+        self.hot = _ints(args.multi_hot_sizes)
+        assert len(self.table_rows) == len(self.hot), \
+            "one multi-hot size a table"
+        self.dim = args.embedding_dim
+        bottom, top = _ints(args.bottom_mlp), _ints(args.top_mlp)
+        self.layout = DenseLayout(
+            dense_tensors(args.dense_features, self.dim, len(self.hot),
+                          bottom, top, args.dcn_layers, args.dcn_rank),
+            args.dense_row)
+        self._loss = make_dlrm_loss(self.layout, self.hot, len(bottom),
+                                    args.dcn_layers, len(top))
+        # keys: the tables' rows in table order, then the dense rows
+        self.table_first = np.concatenate(
+            [[0], np.cumsum(self.table_rows)]).astype(np.int64)
+        self.n_feat = int(self.table_first[-1])
+        self.n_dense = self.layout.num_rows
+        num_keys = self.n_feat + self.n_dense
+        value_lengths = np.empty(num_keys, dtype=np.int64)
+        value_lengths[:self.n_feat] = 2 * self.dim
+        value_lengths[self.n_feat:] = 2 * args.dense_row
+        self.srv = make_server(args, num_keys, value_lengths,
+                               num_workers=args.num_workers or None)
+        self.num_workers = args.num_workers or self.srv.num_shards
+        self.workers = [self.srv.make_worker(i)
+                        for i in range(self.num_workers)]
+        kc = self.srv.ab.key_class
+        self.c_feat, self.c_dense = int(kc[0]), int(kc[self.n_feat])
+        assert self.c_feat != self.c_dense, \
+            "feature rows and dense rows need different lengths"
+        self.dense_keys = np.arange(self.n_feat, num_keys, dtype=np.int64)
+        # member m of an example belongs to table member_table[m]
+        self.member_first = self.table_first[
+            np.repeat(np.arange(len(self.hot)), self.hot)]
+        self.epoch = 0      # passes trained so far, over all train() calls
+        self.mean_loss = 0.0
+        self._programs = {}
+        self._dev_runners = {}
+        self.set_examples(*data)
+
+        # host time of the loop's own phases (Server._span; the step's
+        # other phases are bracketed where they live: kv.intent,
+        # fused.dispatch, kv.drive_rounds, kv.advance_clock), and how
+        # many of a batch's keys are distinct
+        obs = self.srv.obs
+        self._h_prepare = obs.histogram("app.prepare_s", shared=True)
+        self._h_pass_end = obs.histogram("app.pass_end_s", shared=True)
+        self._c_keys = obs.counter("app.batch_keys_total", unit="keys",
+                                   shared=True)
+        self._c_unique = obs.counter("app.batch_unique_keys_total",
+                                     unit="keys", shared=True)
+
+    def set_examples(self, members, x, y) -> None:
+        """The examples this run trains on: `members` [n, M] table-local
+        row ids (M = sum of the multi-hot sizes, bags in table order),
+        dense features `x` [n, num_dense], labels `y` [n]. Partitioned
+        contiguously over all processes' workers; a worker's batches are
+        prepared at its first pass and kept (`_plan`)."""
+        self.members = np.asarray(members, dtype=np.int64)
+        assert self.members.shape[1] == len(self.member_first), \
+            self.members.shape
+        self.x = np.asarray(x, dtype=np.float32)
+        self.y = np.asarray(y, dtype=np.float32)
+        self.by_worker = global_worker_slices(len(self.members),
+                                              self.num_workers)
+        self._plans = {}
+
+    def device_runner(self, shard: int) -> DeviceRoutedRunner:
+        if shard not in self._dev_runners:
+            self._dev_runners[shard] = DeviceRoutedRunner(
+                self.srv, self._loss,
+                role_class={"feat": self.c_feat, "dense": self.c_dense},
+                role_dim={"feat": self.dim, "dense": self.args.dense_row},
+                shard=shard, seed=self.args.seed + shard,
+                programs=self._programs)
+        return self._dev_runners[shard]
+
+    def precompile(self) -> int:
+        """`Server.precompile` with this app's sizes: an intent names at
+        most M * B feature keys and every dense key; the loop drives one
+        kind of runner, a batch of B examples a step. Returns how many
+        planner programs ran."""
+        a, M = self.args, len(self.member_first)
+        B = a.batch_size
+        roles = {"feat": np.zeros((M, B), dtype=np.int64),
+                 "dense": self.dense_keys}
+        put = self.srv.ctx.put_replicated
+        aux = (put(np.zeros((B, a.dense_features), np.float32)),
+               put(np.zeros(B, np.float32)))
+        steps = [(self.device_runner(self.workers[0].shard), roles, aux)]
+        return self.srv.precompile(
+            {self.c_feat: min(M * B, self.n_feat),
+             self.c_dense: self.n_dense}, steps)
+
+    def init_model(self) -> None:
+        """Worker 0 sets every row from the host: embeddings uniform in
+        +-`--init_scale`, every dense tensor uniform in +-1/sqrt(fan_in),
+        the AdaGrad columns at --adagrad_init."""
+        a = self.args
+        rng = np.random.default_rng(a.seed)
+        from ..parallel import control
+        w0 = self.workers[0]
+        w0.begin_setup()
+        if control.process_id() == 0:
+            slab = max(1, (1 << 24) // self.dim)
+            for lo in range(0, self.n_feat, slab):
+                n = min(slab, self.n_feat - lo)
+                emb = (rng.random((n, self.dim), dtype=np.float32)
+                       - 0.5) * (2 * a.init_scale)
+                w0.set(np.arange(lo, lo + n), np.concatenate(
+                    [emb, np.full_like(emb, a.adagrad_init)], axis=1))
+            r = a.dense_row
+            wts = (rng.random((self.n_dense, r), dtype=np.float32) - 0.5) \
+                * (2 * self.layout.row_scale()[:, None])
+            w0.set(self.dense_keys, np.concatenate(
+                [wts, np.full_like(wts, a.adagrad_init)], axis=1))
+            w0.wait_all()
+        w0.end_setup()
+
+    # -- a pass ----------------------------------------------------------------
+
+    def feat_keys(self, idx: np.ndarray) -> np.ndarray:
+        """The feature keys of the examples `idx`, member-major [M, B]."""
+        return (self.members[idx] + self.member_first).T.copy()
+
+    def _plan(self, wi: int) -> list:
+        """Worker `wi`'s batches, the same every pass, prepared once: the
+        role keys, their distinct keys, and the uploads of the keys and
+        of the dense features and labels, kept on the device."""
+        if wi not in self._plans:
+            mine = self.by_worker[wi]
+            runner = self.device_runner(self.workers[wi].shard)
+            put = self.srv.ctx.put_replicated
+            plan = self._plans[wi] = []
+            for idx in wrap_batches(len(mine), self.args.batch_size):
+                idx = mine[idx]
+                roles = {"feat": self.feat_keys(idx),
+                         "dense": self.dense_keys}
+                keys = np.concatenate([np.unique(roles["feat"]),
+                                       self.dense_keys])
+                plan.append(_Batch(roles,
+                                   (put(self.x[idx]), put(self.y[idx])),
+                                   keys, runner.prefetch_keys(roles)))
+        return self._plans[wi]
+
+    def train_pass(self) -> list:
+        """One pass over this process's examples; returns the steps'
+        losses (device scalars)."""
+        a, srv = self.args, self.srv
+        losses = []
+        for wi, w in enumerate(self.workers):
+            plan = self._plan(wi)
+            runner = self.device_runner(w.shard)
+            for bi, b in enumerate(plan):
+                if bi + a.lookahead < len(plan):
+                    with srv._span("app.prepare", self._h_prepare):
+                        nxt = plan[bi + a.lookahead]
+                        fut = w.current_clock + a.lookahead
+                        w.intent(nxt.keys, fut, fut + 1)
+                self._c_keys.inc(b.roles["feat"].size + self.n_dense)
+                self._c_unique.inc(len(b.keys))
+                losses.append(runner(b.roles, b.aux, a.lr, eps=ADAGRAD_EPS,
+                                     staged=b.staged))
+                # inline rounds, or delegated to the prefetch pipeline so
+                # planner work overlaps the in-flight step
+                srv.drive_rounds(a.sync_rounds_per_step)
+                w.advance_clock()
+        return losses
+
+
+def open_run(args) -> CtrRun:
+    """Set-up: data, server, initialized parameters, compiled programs.
+    The returned run's server is live; the caller shuts it down
+    (`run.srv.shutdown()`), as `run` does."""
+    data = generate_synthetic(
+        _ints(args.table_rows), _ints(args.multi_hot_sizes),
+        args.dense_features, args.examples, args.zipf, args.click_rate,
+        args.seed)
+    crun = CtrRun(args, data)
+    crun.init_model()
+    if args.enforce_full_replication:
+        enforce_full_replication(crun.workers, crun.n_feat + crun.n_dense)
+    crun.precompile()
+    return crun
+
+
+def train(crun: CtrRun) -> float:
+    """`--epochs` passes over an opened run, each ended by `quiesce()` and
+    the mean of its steps' losses, fetched once; stops at the first pass
+    end after `--max_runtime`. Leaves the server up (see open_run) and
+    can be called again on the same run. Returns the last pass's mean
+    loss."""
+    args, srv = crun.args, crun.srv
+    guard = RuntimeGuard(args.max_runtime)
+    watch = Stopwatch(start=True)
+    from ..parallel import control
+    for _ in range(args.epochs):
+        losses = crun.train_pass()
+        with srv._span("app.pass_end", crun._h_pass_end):
+            srv.quiesce()
+            with srv._span("app.loss_fetch"):
+                mean_loss = float(jnp.mean(jnp.stack(losses))) \
+                    if losses else 0.0
+            mean_loss = float(control.allreduce(mean_loss, "mean")[0])
+        epoch_report("ctr", crun.epoch, mean_loss, watch)
+        crun.mean_loss = mean_loss
+        crun.epoch += 1
+        if guard.expired():
+            alog("[ctr] max_runtime reached")
+            break
+    alog("[ctr]", srv.sync.report())
+    return crun.mean_loss
+
+
+def run(args) -> float:
+    crun = open_run(args)
+    mean_loss = train(crun)
+    crun.srv.shutdown()
+    return mean_loss
+
+
+def build_parser() -> argparse.ArgumentParser:
+    parser = argparse.ArgumentParser(
+        description=__doc__,
+        formatter_class=argparse.RawDescriptionHelpFormatter)
+    parser.add_argument("--table_rows", default="2000,300,40,3,1",
+                        help="rows held of each embedding table, comma-"
+                             "separated")
+    parser.add_argument("--multi_hot_sizes", default="3,2,1,4,1",
+                        help="members of each table's bag an example")
+    parser.add_argument("--embedding_dim", type=int, default=16)
+    parser.add_argument("--dense_features", type=int, default=13)
+    parser.add_argument("--bottom_mlp", default="32,16",
+                        help="bottom MLP layer sizes; the last is the "
+                             "embedding dim")
+    parser.add_argument("--top_mlp", default="64,32,1")
+    parser.add_argument("--dcn_layers", type=int, default=3)
+    parser.add_argument("--dcn_rank", type=int, default=16)
+    parser.add_argument("--dense_row", type=int, default=64,
+                        help="weights of one dense-network row (the row "
+                             "is twice that: AdaGrad's state beside "
+                             "them); differs from the embedding dim")
+    parser.add_argument("--examples", type=int, default=2048,
+                        help="synthetic examples a pass")
+    parser.add_argument("--zipf", type=float, default=1.0,
+                        help="exponent of the ids' popularity per table")
+    parser.add_argument("--click_rate", type=float, default=0.03)
+    parser.add_argument("--lookahead", type=int, default=2,
+                        help="intent batches ahead")
+    parser.add_argument("--init_scale", type=float, default=0.0625)
+    parser.add_argument("--adagrad_init", type=float, default=0.0,
+                        help="AdaGrad's accumulators at the start; the "
+                             "damping of the first steps is ADAGRAD_EPS")
+    add_common_arguments(parser)
+    return parser
+
+
+def main(argv=None) -> int:
+    run(build_parser().parse_args(argv))
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -13,6 +13,11 @@ seeded RNG — the moral equivalent of the reference's PeekableRandom
 Training pairs accumulate into fixed-size batches for the fused
 gather -> SGNS loss -> AdaGrad -> scatter-add program (ops/fused.py).
 
+`open_run(args)` sets a run up (corpus, vocabulary, server, compiled
+programs), `train(run)` trains `--epochs` passes on it (and can be called
+again: the pass count lives on the run), `run(args)` is both and shuts the
+server down.
+
 Run: python -m adapm_tpu.apps.word2vec --synthetic ...
 """
 from __future__ import annotations
@@ -47,87 +52,121 @@ def _pairs_for(sent: np.ndarray, sent_idx: int, window: int, seed: int,
     return textio.skipgram_pairs(sent, window, rng)
 
 
-def run(args) -> float:
-    if args.data:
-        corpus = args.data
-    else:
-        corpus = args.synthetic_path or "/tmp/adapm_w2v_corpus.txt"
-        textio.generate_synthetic_corpus(
-            corpus, vocab_size=args.synthetic_vocab,
-            num_sentences=args.synthetic_sentences, seed=args.seed)
-    words, counts, vocab = textio.build_vocab(corpus, args.min_count)
-    total_words = int(counts.sum())
-    V, d = len(words), args.dim
-    if V == 0:
-        raise SystemExit("empty vocabulary")
-    sents: List[np.ndarray] = list(textio.sentences(corpus, vocab))
-    num_keys = 2 * V
+class W2vRun:
+    """One training run: the server, its workers and their fused runners,
+    the vocabulary with its counts, the tokenised sentences, and what
+    carries over from pass to pass (the pass count, the last mean
+    loss)."""
 
-    kmap = KeyMapper(num_keys, args.enforce_random_keys, seed=args.seed)
-    srv = make_server(args, num_keys, value_lengths=2 * d,
-                      num_workers=args.num_workers or None)
-    num_workers = args.num_workers or srv.num_shards
-    workers = [srv.make_worker(i) for i in range(num_workers)]
+    def __init__(self, args, words, counts, sents):
+        self.args = args
+        self.words, self.counts = words, counts
+        self.total_words = int(counts.sum())
+        self.V, self.d = len(counts), args.dim
+        if self.V == 0:
+            raise SystemExit("empty vocabulary")
+        num_keys = 2 * self.V
+        self.kmap = KeyMapper(num_keys, args.enforce_random_keys,
+                              seed=args.seed)
+        self.srv = make_server(args, num_keys, value_lengths=2 * self.d,
+                               num_workers=args.num_workers or None)
+        self.num_workers = args.num_workers or self.srv.num_shards
+        self.workers = [self.srv.make_worker(i)
+                        for i in range(self.num_workers)]
+        # negatives drawn IN-PROGRAM from the unigram^0.75 alias table
+        # over the syn1 physical keys, with a Local-scheme snap that may
+        # only land on other syn1 keys, never syn0 (the reference's
+        # negative table, word2vec.cc:125-144, as two O(V) HBM arrays);
+        # per step the host ships only the center/context key batch
+        self._dev_runners = {}
+        self._neg_alias = build_alias_table(counts)
+        self.epoch = 0      # passes trained so far, over all train() calls
+        self.mean_loss = 0.0
+        # per-worker contiguous sentence partition over all processes'
+        # workers (reference :524-531)
+        self.sents: List[np.ndarray] = sents
+        self.slices = global_worker_slices(len(sents), self.num_workers)
+        # --scan_steps K: buffer K materialized batches and train them in
+        # ONE lax.scan dispatch (runner.run_scan, same contract as the
+        # KGE app: placement frozen per window, negative RNG identical to
+        # K sequential steps). Clocks still advance per SENTENCE; a
+        # buffered batch waits up to ~K*B/pairs-per-sentence clocks
+        # before dispatch, so intent windows are extended by a slack
+        # estimated from the corpus (otherwise replicas could expire
+        # while a batch sits in the window).
+        self.K = max(1, args.scan_steps)
+        self.scan_slack = 0
+        if self.K > 1:
+            probe = [len(self.pairs(si)[0])
+                     for si in range(min(50, len(sents)))]
+            est_pairs = max(1.0, float(np.mean(probe)) if probe else 1.0)
+            self.scan_slack = int(np.ceil(
+                self.K * args.batch_size / est_pairs)) * 2 + self.K
 
-    # init: syn0 ~ U[-.5/d, .5/d], syn1 = 0 (classic w2v); [emb | adagrad]
-    rng = np.random.default_rng(args.seed)
-    init = np.zeros((num_keys, 2 * d), dtype=np.float32)
-    init[syn0_key(np.arange(V)), :d] = \
-        (rng.random((V, d)).astype(np.float32) - 0.5) / d
-    init[:, d:] = args.adagrad_init
-    worker0_init(workers, kmap(np.arange(num_keys)), init)
-    if args.enforce_full_replication:
-        enforce_full_replication(workers, num_keys)
+        # host time of the loop's own phases (Server._span; the step's
+        # other phases are bracketed where they live: kv.intent,
+        # fused.dispatch, kv.drive_rounds, kv.advance_clock), and what a
+        # pass is made of
+        obs = self.srv.obs
+        self._h_prepare = obs.histogram("app.prepare_s", shared=True)
+        self._h_pass_end = obs.histogram("app.pass_end_s", shared=True)
+        self._c_sentences = obs.counter("app.sentences_total",
+                                        unit="sentences", shared=True)
+        self._c_pairs = obs.counter("app.pairs_total", unit="pairs",
+                                    shared=True)
 
-    B, N = args.batch_size, args.negative
+    def pairs(self, si: int):
+        """(centers, contexts) of sentence `si`, the same at intent time
+        and at train time."""
+        a = self.args
+        return _pairs_for(self.sents[si], si, a.window, a.seed,
+                          self.counts, self.total_words, a.sample)
 
-    # negatives drawn IN-PROGRAM from the unigram^0.75 alias table over the
-    # syn1 physical keys, with a Local-scheme snap that may only land on
-    # other syn1 keys, never syn0 (the reference's negative table,
-    # word2vec.cc:125-144, as two O(V) HBM arrays); per step the host ships
-    # only the center/context key batch
-    dev_runners = {}
-
-    def device_runner(shard: int) -> DeviceRoutedRunner:
-        if shard not in dev_runners:
-            dev_runners[shard] = DeviceRoutedRunner(
-                srv, sgns_loss,
+    def device_runner(self, shard: int) -> DeviceRoutedRunner:
+        if shard not in self._dev_runners:
+            a, d, V = self.args, self.d, self.V
+            self._dev_runners[shard] = DeviceRoutedRunner(
+                self.srv, sgns_loss,
                 role_class={"center": 0, "ctx": 0, "neg": 0},
                 role_dim={k: d for k in ("center", "ctx", "neg")},
-                shard=shard, neg_role="neg", neg_shape=(B, N),
-                neg_population=kmap(syn1_key(np.arange(V))),
-                neg_alias=build_alias_table(counts),
-                seed=args.seed + shard)
-        return dev_runners[shard]
-    guard = RuntimeGuard(args.max_runtime)
-    watch = Stopwatch(start=True)
-    mean_loss = 0.0
+                shard=shard, neg_role="neg",
+                neg_shape=(a.batch_size, a.negative),
+                neg_population=self.kmap(syn1_key(np.arange(V))),
+                neg_alias=self._neg_alias, seed=a.seed + shard)
+        return self._dev_runners[shard]
 
-    # per-worker contiguous sentence partition over all processes'
-    # workers (reference :524-531)
-    slices = global_worker_slices(len(sents), num_workers)
+    def precompile(self) -> int:
+        """`Server.precompile` with this app's sizes: an intent names one
+        sentence's keys, at most two a pair and never more than 2B; the
+        loop drives one kind of runner, a batch of B pairs a step (a
+        --scan_steps window still compiles at its first use). Returns
+        how many planner programs ran."""
+        B = self.args.batch_size
+        z = np.zeros(B, dtype=np.int64)
+        steps = [(self.device_runner(self.workers[0].shard),
+                  {"center": z, "ctx": z}, None)]
+        return self.srv.precompile({0: min(2 * B, 2 * self.V)}, steps)
 
-    # --scan_steps K: buffer K materialized batches
-    # and train them in ONE lax.scan dispatch (runner.run_scan — same
-    # contract as the KGE app: placement frozen per window, negative RNG
-    # identical to K sequential steps). Clocks still advance per
-    # SENTENCE; a buffered batch waits up to ~K*B/pairs-per-sentence
-    # clocks before dispatch, so intent windows are extended by a slack
-    # estimated from the corpus (otherwise replicas could expire while a
-    # batch sits in the window).
-    K = max(1, args.scan_steps)
-    scan_slack = 0
-    if K > 1:
-        probe = [len(_pairs_for(sents[si], si, args.window, args.seed,
-                                counts, total_words, args.sample)[0])
-                 for si in range(min(50, len(sents)))]
-        est_pairs = max(1.0, float(np.mean(probe)) if probe else 1.0)
-        scan_slack = int(np.ceil(K * B / est_pairs)) * 2 + K
+    def init_model(self) -> None:
+        """Worker 0 sets every row from the host: syn0 ~ U[-.5/d, .5/d],
+        syn1 = 0 (classic w2v); rows [emb | adagrad]."""
+        a, V, d = self.args, self.V, self.d
+        rng = np.random.default_rng(a.seed)
+        init = np.zeros((2 * V, 2 * d), dtype=np.float32)
+        init[syn0_key(np.arange(V)), :d] = \
+            (rng.random((V, d)).astype(np.float32) - 0.5) / d
+        init[:, d:] = a.adagrad_init
+        worker0_init(self.workers, self.kmap(np.arange(2 * V)), init)
 
-    for epoch in range(args.epochs):
+    def train_pass(self) -> list:
+        """One pass over this process's sentences; returns the steps'
+        losses (device scalars; a scan window's are a [K] vector)."""
+        args, srv = self.args, self.srv
+        B, K = args.batch_size, self.K
         losses = []
-        for wi, w in enumerate(workers):
-            my = slices[wi].tolist()
+        for wi, w in enumerate(self.workers):
+            my = self.slices[wi].tolist()
+            runner = self.device_runner(w.shard)
             # (sent position, centers, contexts) of prepared future
             # sentences
             prepared: deque = deque()
@@ -137,17 +176,17 @@ def run(args) -> float:
             def prepare(pos: int, ahead: int) -> None:
                 """Signal intent for the sentence that will be trained
                 `ahead` clocks from now."""
-                si = my[pos]
-                c, x = _pairs_for(sents[si], si, args.window, args.seed,
-                                  counts, total_words, args.sample)
-                if len(c) == 0:
+                with srv._span("app.prepare", self._h_prepare):
+                    c, x = self.pairs(my[pos])
+                    self._c_sentences.inc()
+                    self._c_pairs.inc(len(c))
                     prepared.append((pos, c, x))
-                    return
-                fut = w.current_clock + ahead
-                ks = np.unique(np.concatenate(
-                    [kmap(syn0_key(c)), kmap(syn1_key(x))]))
-                w.intent(ks, fut, fut + 1 + scan_slack)
-                prepared.append((pos, c, x))
+                    if len(c) == 0:
+                        return
+                    fut = w.current_clock + ahead
+                    ks = np.unique(np.concatenate(
+                        [self.kmap(syn0_key(c)), self.kmap(syn1_key(x))]))
+                    w.intent(ks, fut, fut + 1 + self.scan_slack)
 
             # prime the pipeline
             for pos in range(min(args.readahead, len(my))):
@@ -162,19 +201,19 @@ def run(args) -> float:
                     prepare(pos + args.readahead, ahead=args.readahead)
                 _, c, x = prepared.popleft()
                 if len(c):
-                    buf_c.append(kmap(syn0_key(c)))
-                    buf_x.append(kmap(syn1_key(x)))
+                    buf_c.append(self.kmap(syn0_key(c)))
+                    buf_x.append(self.kmap(syn1_key(x)))
                     n_buf += len(c)
 
                 while n_buf >= B:
                     cc = np.concatenate(buf_c)
                     xx = np.concatenate(buf_x)
                     if K > 1:
-                        scan_win.add(device_runner(w.shard),
+                        scan_win.add(runner,
                                      {"center": cc[:B], "ctx": xx[:B]},
                                      None, args.lr)
                     else:
-                        losses.append(device_runner(w.shard)(
+                        losses.append(runner(
                             {"center": cc[:B], "ctx": xx[:B]}, None,
                             args.lr))
                         # inline rounds, or delegated to the prefetch
@@ -189,25 +228,76 @@ def run(args) -> float:
                 cc = np.concatenate(buf_c)
                 xx = np.concatenate(buf_x)
                 reps = -(-B // len(cc))
-                losses.append(device_runner(w.shard)(
+                losses.append(runner(
                     {"center": np.tile(cc, reps)[:B],
                      "ctx": np.tile(xx, reps)[:B]}, None, args.lr))
-        srv.quiesce()
-        # scan windows contribute [K] loss vectors, per-step path scalars
-        mean_loss = float(np.mean(np.concatenate(
-            [np.ravel(np.asarray(l)) for l in losses]))) if losses else 0.0
-        from ..parallel import control
-        mean_loss = float(control.allreduce(mean_loss, "mean")[0])
+        return losses
+
+
+def _load_corpus(args):
+    """(words, counts, sentences as arrays of word ids) of --data, or of
+    a synthetic corpus written to --synthetic_path."""
+    if args.data:
+        corpus = args.data
+    else:
+        corpus = args.synthetic_path or "/tmp/adapm_w2v_corpus.txt"
+        textio.generate_synthetic_corpus(
+            corpus, vocab_size=args.synthetic_vocab,
+            num_sentences=args.synthetic_sentences, seed=args.seed)
+    words, counts, vocab = textio.build_vocab(corpus, args.min_count)
+    return words, counts, list(textio.sentences(corpus, vocab))
+
+
+def open_run(args) -> W2vRun:
+    """Set-up: corpus, vocabulary, server, initialized vectors, compiled
+    programs. The returned run's server is live; the caller shuts it
+    down (`run.srv.shutdown()`), as `run` does."""
+    wrun = W2vRun(args, *_load_corpus(args))
+    wrun.init_model()
+    if args.enforce_full_replication:
+        enforce_full_replication(wrun.workers, 2 * wrun.V)
+    wrun.precompile()
+    return wrun
+
+
+def train(wrun: W2vRun) -> float:
+    """`--epochs` passes over an opened run, each ended by `quiesce()` and
+    the mean of its steps' losses; stops at the first pass end after
+    `--max_runtime`. Leaves the server up (see open_run) and can be
+    called again on the same run. Returns the last pass's mean loss."""
+    args, srv = wrun.args, wrun.srv
+    guard = RuntimeGuard(args.max_runtime)
+    watch = Stopwatch(start=True)
+    from ..parallel import control
+    for _ in range(args.epochs):
+        losses = wrun.train_pass()
+        with srv._span("app.pass_end", wrun._h_pass_end):
+            srv.quiesce()
+            with srv._span("app.loss_fetch"):
+                # scan windows contribute [K] loss vectors, per-step
+                # path scalars
+                mean_loss = float(np.mean(np.concatenate(
+                    [np.ravel(np.asarray(l)) for l in losses]))) \
+                    if losses else 0.0
+            mean_loss = float(control.allreduce(mean_loss, "mean")[0])
+        epoch, wrun.mean_loss = wrun.epoch, mean_loss
+        wrun.epoch += 1
         epoch_report("w2v", epoch, mean_loss, watch)
         if args.export_prefix and control.process_id() == 0:
-            _export(srv, kmap, words, d,
+            _export(srv, wrun.kmap, wrun.words, wrun.d,
                     f"{args.export_prefix}epoch{epoch}.txt")
         if guard.expired():
             alog("[w2v] max_runtime reached")
             break
 
     alog("[w2v]", srv.sync.report())
-    srv.shutdown()
+    return wrun.mean_loss
+
+
+def run(args) -> float:
+    wrun = open_run(args)
+    mean_loss = train(wrun)
+    wrun.srv.shutdown()
     return mean_loss
 
 

@@ -26,6 +26,10 @@ train-then-serve shape of a production CTR system — and both reads are
 checked bit-identical against each other and against the training-path
 pull (the serving plane's consistency contract).
 
+The same task as a FUSED step (no torch, no per-key pull and push: the
+tables and a DLRM-DCNv2 dense network in the store, gather -> loss ->
+AdaGrad -> write-back in one compiled program) is `adapm_tpu/apps/ctr.py`.
+
 Run: PYTHONPATH=. python examples/ctr_example.py
 """
 import threading

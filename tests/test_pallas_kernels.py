@@ -274,3 +274,42 @@ def test_exported_kernel_is_kept_read_back_and_remade(kernel_cache,
     writeback.exported_kernel.cache_clear()
     writeback.exported_kernel(N, L, 24, R, "cpu")
     assert len(list(kernel_cache.iterdir())) == 2
+
+
+@pytest.mark.parametrize("n, max_positions, calls", [
+    (1000, 256, 4),      # four calls, as the DLRM cell's 438,272 positions
+    (1000, 1 << 17, 1),  # the same positions in one
+])
+def test_role_larger_than_one_call_at_narrow_rows(n, max_positions, calls,
+                                                  kernel_cache):
+    """A role of more positions than one kernel call takes, at rows of
+    256 floats (1 KB, chunks of 32 positions): the sorted positions cut
+    into `calls` slices, each call on the pool the call before returned,
+    against `.at[].add`. Runs of one slot (a one-row table named by every
+    example) and of one 8-row group cross the slices' ends."""
+    from adapm_tpu.ops import writeback
+    N, L = 96, 256
+    R = writeback.chunk_rows_for(L)
+    assert R == 32
+    rng = np.random.default_rng(21)
+    slots = rng.integers(0, N, n).astype(np.int32)
+    slots[100:500] = 40               # one slot 400 times: over two cuts
+    slots[500:520] = N + 3            # out of the pool: dropped
+    pool = rng.normal(size=(N, L)).astype(np.float32)
+    upd = rng.normal(size=(n, L)).astype(np.float32)
+    ref = np.asarray(jnp.asarray(pool).at[jnp.asarray(slots)].add(
+        jnp.asarray(upd), mode="drop"))
+    slices = writeback.sorted_slices(jnp.asarray(slots), N, R,
+                                     max_positions=max_positions)
+    assert len(slices) == calls
+    got = jnp.asarray(pool)
+    for codes, perm in slices:
+        got = writeback.exported_kernel(N, L, int(codes.shape[0]), R,
+                                        "cpu").call(
+            got, codes, jnp.asarray(upd)[perm])
+    got = np.asarray(got)
+    # up to 400 float32 additions into one row, in the batch's order by
+    # the kernel and in XLA's by the scatter: a few ulp of the sum
+    np.testing.assert_allclose(got, ref, rtol=2e-5, atol=2e-5)
+    untouched = np.setdiff1d(np.arange(N), slots)
+    assert got[untouched].tobytes() == pool[untouched].tobytes()

@@ -309,3 +309,66 @@ def test_mf_cell_programs_fit_beside_the_table(shape, kernel_cache,
         print(f"\nmf-10mx1m v5e compile: pool {pool_bytes / 1e9:.3f} GB, "
               f"temporaries (MB) " + ", ".join(
                   f"{k} {v / 1e6:.1f}" for k, v in sizes.items()))
+
+
+# the DLRM cell (dlrm-dcnv2-criteo1tb): the two main pools' slots, keys of
+# both classes, members an example, batch
+DLRM_SLOTS, DLRM_KEYS, DLRM_M, DLRM_B = (6_508_400, 15_992), \
+    6_380_781 + 15_676, 214, 2048
+
+
+def _dlrm_step(shape, monkeypatch):
+    """The DLRM cell's replica-free step at its own sizes, compiled for
+    the described chip: (compiled, bytes of both pools)."""
+    from adapm_tpu.models import dlrm
+    from adapm_tpu.ops import fused
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    layout = dlrm.DenseLayout(dlrm.dense_tensors(
+        13, 128, 26, [512, 256, 128], [1024, 1024, 512, 256, 1], 3, 512),
+        1024)
+    assert (layout.num_rows, layout.num_params) == (15_676, 16_044_545)
+    hot = [3, 2, 1, 2, 6, 1, 1, 1, 1, 7, 3, 8, 1, 6, 9, 5, 1, 1, 1, 12, 100,
+           27, 10, 3, 1, 1]
+    assert sum(hot) == DLRM_M
+    loss = dlrm.make_dlrm_loss(layout, hot, 3, 3, 5)
+    pools = tuple((shape((1, n, row), jnp.float32),
+                   shape((1, 8, row), jnp.float32),
+                   shape((1, 8, row), jnp.float32))
+                  for n, row in zip(DLRM_SLOTS, (256, L)))
+    tables = tuple(shape((DLRM_KEYS,), jnp.int32) for _ in range(3)) \
+        + (shape((), jnp.int32),)
+    keys = {"feat": shape((DLRM_M, DLRM_B), jnp.int32),
+            "dense": shape((layout.num_rows,), jnp.int32)}
+    aux = (shape((DLRM_B, 13), jnp.float32), shape((DLRM_B,), jnp.float32))
+    f32 = shape((), jnp.float32)
+    body = fused._build_device_routed_body(
+        loss, {"feat": 0, "dense": 1}, {"feat": 128, "dense": 1024}, (),
+        None, None, True, False)
+    compiled = jax.jit(body, donate_argnums=(0,)).lower(
+        pools, shape((4,), jnp.int32), tables, keys, None, None,
+        shape((2,), jnp.uint32), aux, f32, f32).compile()
+    return compiled, sum(n * row * 4 for n, row in zip(DLRM_SLOTS, (256, L)))
+
+
+def test_dlrm_cell_step_fits_beside_the_tables(shape, kernel_cache,
+                                               monkeypatch, capsys):
+    """The DLRM cell's step at its own sizes (6,508,400 slots of 1 KB and
+    15,992 of 8 KB; 438,272 feature positions and 15,676 dense rows a
+    step): both pools stay aliased, the feature role is written back by
+    FOUR kernel calls at L = 256 (438,272 positions, 131,072 a call) and
+    the dense role by one at L = 2,048, the matrix products are
+    convolutions of the compiled step, and pools + temporaries stay
+    under 15.0 GiB."""
+    compiled, pool_bytes = _dlrm_step(shape, monkeypatch)
+    text = compiled.as_text()
+    assert text.count("custom_call_target=\"tpu_custom_call\"") == 5
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= pool_bytes
+    fullest = pool_bytes + mem.temp_size_in_bytes
+    with capsys.disabled():
+        print(f"\ndlrm-dcnv2-criteo1tb v5e compile: pools "
+              f"{pool_bytes / 1e9:.3f} GB, the step's temporaries "
+              f"{mem.temp_size_in_bytes / 1e9:.3f} GB, fullest program "
+              f"{fullest / 2**30:.2f} GiB")
+    assert fullest < 15.0 * 2**30
+    assert mem.temp_size_in_bytes < 4 << 30

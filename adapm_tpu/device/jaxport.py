@@ -678,6 +678,8 @@ class JaxDevicePort(DevicePort):
     def compile(self, fn, **jit_kwargs):
         return jax.jit(fn, **jit_kwargs)
 
-    def compile_collective(self, fn, mesh, in_specs, out_specs):
+    def compile_collective(self, fn, mesh, in_specs, out_specs,
+                           check_vma: bool = True, **jit_kwargs):
         return jax.jit(jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                                     out_specs=out_specs))
+                                     out_specs=out_specs,
+                                     check_vma=check_vma), **jit_kwargs)

@@ -186,9 +186,15 @@ class DevicePort:
         (donate_argnums, static_argnames, ...)."""
         raise NotImplementedError
 
-    def compile_collective(self, fn, mesh, in_specs, out_specs):
+    def compile_collective(self, fn, mesh, in_specs, out_specs,
+                           check_vma: bool = True, **jit_kwargs):
         """Construct a per-shard collective program (shard_map + jit):
-        `fn` runs per mesh shard with collective primitives available."""
+        `fn` runs per mesh shard with collective primitives available.
+        `check_vma=False` leaves unchecked that a result given a
+        replicated out_spec is the same on every shard (a body that
+        calls a Pallas kernel cannot be checked: the fused step).
+        Accepts jax.jit keywords like `compile` (the fused step donates
+        its pools: `donate_argnums`)."""
         raise NotImplementedError
 
 

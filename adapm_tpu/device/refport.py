@@ -358,7 +358,8 @@ class NumpyRefPort(DevicePort):
             "NumpyRefPort is a data-plane reference backend; fused-step "
             "program compilation is jax-only (use JaxDevicePort)")
 
-    def compile_collective(self, fn, mesh, in_specs, out_specs):
+    def compile_collective(self, fn, mesh, in_specs, out_specs,
+                           check_vma=True, **jit_kwargs):
         raise NotImplementedError(
             "NumpyRefPort has no collective backend (single-process "
             "data plane only)")

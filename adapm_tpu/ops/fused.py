@@ -41,10 +41,12 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import PartitionSpec as P
 
 from ..core.store import OOB
 from ..device import default_port
 from ..exec import dispatch_gate
+from ..parallel.mesh import KV_AXIS
 from . import writeback
 
 # sharded-dispatch serialization (adapm_tpu/exec, docs/EXECUTOR.md):
@@ -85,23 +87,16 @@ def _read_rows(main, cache, delta, route):
     return jnp.where(use_c[..., None], c, m)
 
 
-def _scatter_update(main, delta, route, upd):
-    g_sh, g_sl, c_sh, c_sl, use_c = route
-    # owner path: g_sl already carries OOB at replica positions
-    main = main.at[g_sh, g_sl].add(upd, mode="drop")
-    # replica path: c_sl already carries OOB at owner positions
-    delta = delta.at[c_sh, c_sl].add(upd, mode="drop")
-    return main, delta
-
-
 def writeback_uses_kernel(main, backend: str = None) -> bool:
     """Which row-mover the replica-free write-back into this pool is
     compiled with: the Pallas kernel (pallas_kernels
     .scatter_adagrad_sorted_rows, which forms the AdaGrad update rows
     itself) where the backend (jax's default unless given) is a TPU and
     the pool is ONE float32 shard on the step's device (`[1, slots, L]`:
-    a mesh has as many devices as shards, and a Pallas call does not
-    partition under GSPMD) whose rows the kernel can copy and form: the
+    a pool of one shard, or a chip's own block of a pool of several
+    inside the per-chip step, `_PoolProgram`; a Pallas call does not
+    partition under GSPMD, so a program over a global pool of several
+    shards never takes it) whose rows the kernel can copy and form: the
     row's two halves, [emb | accumulator], each a whole number of the
     128 lanes (L a multiple of 256), slots of the 8 rows of a tile;
     `_adagrad_update` and XLA's scatter-add everywhere else. A static
@@ -250,6 +245,65 @@ def _route_on_device(tables, keys):
     return (o_sh, g_sl, c_sh, c_sl, use_c)
 
 
+class _PoolProgram:
+    """A fused program (step, scan or score: `pools` is its first
+    operand) compiled in the form its pools call for, read from their
+    leading dimension when it is first called or lowered with them:
+
+      - one shard: `build(None)` as ONE program (`port.compile`);
+      - several shards, laid over the mesh's kv axis one a chip:
+        `build(KV_AXIS)` as a PER-CHIP program (`port
+        .compile_collective`: shard_map over that axis). Each chip is
+        handed its own `[1, slots, L]` blocks of the pools, every other
+        operand whole; it returns its blocks (where `pools_out`) and
+        values that are the same on every chip. Its collectives are the
+        sums the body asks for, and a chip's block is one shard, so the
+        write-back kernel applies (`writeback_uses_kernel`).
+
+    `per_chip=False` keeps several shards ONE program over the global
+    pools too, partitioned by GSPMD: for a step whose rows may lie
+    anywhere (`make_device_routed_step`, `neg_local`)."""
+
+    def __init__(self, build, pools_out: bool, per_chip: bool = True,
+                 **jit_kwargs):
+        self._build = build
+        self._pools_out = pools_out
+        self._per_chip = per_chip
+        self._jit_kwargs = jit_kwargs
+        self._forms = {}  # mesh of the per-chip form (None: one program)
+
+    def _form(self, pools, rest):
+        main = pools[0][0]
+        mesh = getattr(main.sharding, "mesh", None) \
+            if self._per_chip and main.shape[0] > 1 else None
+        if mesh not in self._forms:
+            port = default_port()
+            if mesh is None:
+                form = port.compile(self._build(None), **self._jit_kwargs)
+            else:
+                whole = P()
+                form = port.compile_collective(
+                    self._build(KV_AXIS), mesh,
+                    in_specs=(P(KV_AXIS),) + (whole,) * len(rest),
+                    out_specs=(P(KV_AXIS), whole, whole)
+                    if self._pools_out else whole,
+                    # unchecked, as a body with a Pallas call (the
+                    # write-back kernel) has to be. The body counts on
+                    # it: a checked map's gradient w.r.t. a value it
+                    # knows to be the same on every chip (the summed
+                    # rows) is summed over the axis, and the body does
+                    # that sum itself, of the worker chip's alone
+                    check_vma=False, **self._jit_kwargs)
+            self._forms[mesh] = form
+        return self._forms[mesh]
+
+    def __call__(self, pools, *rest):
+        return self._form(pools, rest)(pools, *rest)
+
+    def lower(self, pools, *rest):
+        return self._form(pools, rest).lower(pools, *rest)
+
+
 def make_device_routed_step(loss_fn: Callable[..., jnp.ndarray],
                             role_class: Dict[str, int],
                             role_dim: Dict[str, int],
@@ -257,7 +311,8 @@ def make_device_routed_step(loss_fn: Callable[..., jnp.ndarray],
                             neg_role: str = None,
                             neg_shape: Tuple[int, ...] = None,
                             no_replicas: bool = False,
-                            neg_alias: bool = False):
+                            neg_alias: bool = False,
+                            neg_local: bool = True):
     """Fused step that resolves routing in-program from device table
     mirrors. Signature of the returned step:
 
@@ -306,15 +361,24 @@ def make_device_routed_step(loss_fn: Callable[..., jnp.ndarray],
     its out-of-bounds mask covers the embedding columns only
     (`_route_and_gather`); the replica variant masks whole rows, three
     times (`_read_rows`).
+
+    Pools of several shards get the step as a per-chip program
+    (`_PoolProgram`, `_build_device_routed_body`), which counts on the
+    drawn negatives lying on the worker's shard. `neg_local=False` says
+    they may lie anywhere (the runner's local index found no resident
+    key and holds the whole population): such a step stays one program
+    over the global pools.
     """
-    body = _build_device_routed_body(
-        loss_fn, role_class, role_dim, frozen_roles, neg_role,
-        neg_shape, no_replicas, neg_alias)
+    def build(axis):
+        return _build_device_routed_body(
+            loss_fn, role_class, role_dim, frozen_roles, neg_role,
+            neg_shape, no_replicas, neg_alias, axis=axis)
     # donate the pools only: donating the 4-scalar locstat accumulator
     # saves nothing and its aliased buffer has been observed returning
     # stale/garbage counts on the multi-device CPU backend (flaky
     # locality_counts mismatches in test_device_routed)
-    return default_port().compile(body, donate_argnums=(0,))
+    return _PoolProgram(build, True, per_chip=neg_local,
+                        donate_argnums=(0,))
 
 
 def make_device_routed_scan(loss_fn: Callable[..., jnp.ndarray],
@@ -325,7 +389,8 @@ def make_device_routed_scan(loss_fn: Callable[..., jnp.ndarray],
                             neg_shape: Tuple[int, ...] = None,
                             no_replicas: bool = False,
                             neg_alias: bool = False,
-                            has_aux: bool = True):
+                            has_aux: bool = True,
+                            neg_local: bool = True):
     """K training steps in ONE dispatch: `lax.scan` over stacked batches
     (VERDICT r3 item 2 — the per-step host dispatch is the residual over
     the HBM row-rate floor; amortizing it over a K-step window reclaims
@@ -336,30 +401,35 @@ def make_device_routed_scan(loss_fn: Callable[..., jnp.ndarray],
     Signature: scan(pools, locstat, tables, keys[K,...], local_index,
     alias, rng_keys[K], aux[K,...]|None, lr, eps)
     -> (pools, locstat, losses[K])."""
-    body = _build_device_routed_body(
-        loss_fn, role_class, role_dim, frozen_roles, neg_role,
-        neg_shape, no_replicas, neg_alias)
+    def build(axis):
+        body = _build_device_routed_body(
+            loss_fn, role_class, role_dim, frozen_roles, neg_role,
+            neg_shape, no_replicas, neg_alias, axis=axis)
 
-    # pools-only donation, same rationale as make_device_routed_step
-    def scan(pools, locstat, tables, keys, local_index, alias, rng_keys,
-             aux, lr, eps):
-        def f(carry, xs):
-            pools, locstat = carry
-            if has_aux:
-                k, rkey, a = xs
-            else:
-                k, rkey = xs
-                a = None
-            pools, locstat, loss = body(
-                pools, locstat, tables, k, local_index, alias, rkey, a,
-                lr, eps)
-            return (pools, locstat), loss
+        def scan(pools, locstat, tables, keys, local_index, alias,
+                 rng_keys, aux, lr, eps):
+            def f(carry, xs):
+                pools, locstat = carry
+                if has_aux:
+                    k, rkey, a = xs
+                else:
+                    k, rkey = xs
+                    a = None
+                pools, locstat, loss = body(
+                    pools, locstat, tables, k, local_index, alias, rkey, a,
+                    lr, eps)
+                return (pools, locstat), loss
 
-        xs = (keys, rng_keys, aux) if has_aux else (keys, rng_keys)
-        (pools, locstat), losses = jax.lax.scan(f, (pools, locstat), xs)
-        return pools, locstat, losses
+            xs = (keys, rng_keys, aux) if has_aux else (keys, rng_keys)
+            (pools, locstat), losses = jax.lax.scan(f, (pools, locstat),
+                                                    xs)
+            return pools, locstat, losses
+        return scan
 
-    return default_port().compile(scan, donate_argnums=(0,))
+    # pools-only donation, and the per-chip form for pools of several
+    # shards: same rationale as make_device_routed_step
+    return _PoolProgram(build, True, per_chip=neg_local,
+                        donate_argnums=(0,))
 
 
 def _in_bounds(idx, n: int):
@@ -369,8 +439,31 @@ def _in_bounds(idx, n: int):
     return (idx >= -n) & (idx < n)
 
 
+def _here(sh, axis):
+    """Whether the shard index `sh` names the chip this per-chip program
+    runs on (a negative index wraps once, as jnp's indexing of the
+    global pool does)."""
+    me = jax.lax.axis_index(axis)
+    return (sh == me) | (sh == me - jax.lax.axis_size(axis))
+
+
+def _on_this_chip(route, axis):
+    """A route into the global pools as a route into this chip's own
+    blocks (`[1, slots, L]` each): shard 0, and the slot out of bounds
+    where the row lies on another chip, so that the gather reads zeros
+    there and the write-back drops the position. Every row of the
+    global route lies on exactly one chip."""
+    if len(route) == 2:  # the replica-free variant's (o_sh, o_sl)
+        o_sh, o_sl = route
+        return jnp.zeros_like(o_sh), jnp.where(_here(o_sh, axis), o_sl, OOB)
+    o_sh, g_sl, c_sh, c_sl, use_c = route
+    zero = jnp.zeros_like(o_sh)
+    return (zero, jnp.where(_here(o_sh, axis), g_sl, OOB),
+            zero, jnp.where(_here(c_sh, axis), c_sl, OOB), use_c)
+
+
 def _route_and_gather(pools, tables, keys, roles, role_class, role_dim,
-                      no_replicas):
+                      no_replicas, axis=None):
     """Route every role's keys and gather their rows: the read half of a
     fused step, shared with the gather-only score program
     (`make_device_routed_score`). Returns (embs, accs, routes, n_total,
@@ -390,7 +483,14 @@ def _route_and_gather(pools, tables, keys, roles, role_class, role_dim,
     the accumulator columns of such a position are whatever row the
     clamp found, and nobody reads them: the write-back drops the
     position (its code is -1 in the kernel, `mode="drop"` in XLA's
-    scatter-add)."""
+    scatter-add).
+
+    With `axis` (the per-chip step, `_build_device_routed_body`) the
+    pools are this chip's blocks and each route is brought onto the chip
+    (`_on_this_chip`) before the same gather: the rows and the returned
+    routes are the chip's own, zeros and out of bounds for what lies
+    elsewhere. The locality counts come from the global routes, so every
+    chip holds the same."""
     embs, accs, routes = {}, {}, {}
     n_total = 0
     n_local = jnp.int32(0)
@@ -403,21 +503,25 @@ def _route_and_gather(pools, tables, keys, roles, role_class, role_dim,
         if no_replicas:
             owner, slot = tables[:2]
             with jax.named_scope("adapm_route"):
-                o_sh, o_sl = owner[keys[r]], slot[keys[r]]
+                o_sh, o_sl = whole = owner[keys[r]], slot[keys[r]]
+                if axis is not None:
+                    o_sh, o_sl = _on_this_chip((o_sh, o_sl), axis)
             routes[r] = (o_sh, o_sl)
             with jax.named_scope("adapm_gather"):
                 rows = main.at[o_sh, o_sl].get(mode="clip")
                 valid = _in_bounds(o_sh, main.shape[0]) \
                     & _in_bounds(o_sl, main.shape[1])
                 embs[r] = jnp.where(valid[..., None], rows[..., :dim], 0)
-            local = o_sh == shard
+            local = whole[0] == shard
         else:
             with jax.named_scope("adapm_route"):
-                routes[r] = _route_on_device(tables, keys[r])
+                routes[r] = whole = _route_on_device(tables, keys[r])
+                if axis is not None:
+                    routes[r] = _on_this_chip(whole, axis)
             with jax.named_scope("adapm_gather"):
                 rows = _read_rows(main, cache, delta, routes[r])
             embs[r] = rows[..., :dim]
-            local = routes[r][4] | (routes[r][0] == shard)  # use_c, o_sh
+            local = whole[4] | (whole[0] == shard)  # use_c, o_sh
         accs[r] = rows[..., dim:]
         n_local += jnp.sum(local, dtype=jnp.int32)
     return embs, accs, routes, n_total, n_local
@@ -442,22 +546,56 @@ def make_device_routed_score(score_fn: Callable[..., jnp.ndarray],
     sum stays on the device until one fetch."""
     roles = sorted(roles)
 
-    def score(pools, tables, keys, aux, acc):
-        embs, _, _, _, _ = _route_and_gather(
-            pools, tables, dict(keys), roles, role_class, role_dim,
-            no_replicas)
-        with jax.named_scope("adapm_loss_grad"):
-            return acc + score_fn(embs, aux)
+    def build(axis):
+        def score(pools, tables, keys, aux, acc):
+            embs, _, _, _, _ = _route_and_gather(
+                pools, tables, dict(keys), roles, role_class, role_dim,
+                no_replicas, axis)
+            if axis is not None:  # per chip: the rows it holds, summed
+                with jax.named_scope("adapm_exchange"):
+                    embs = jax.lax.psum(embs, axis)
+            with jax.named_scope("adapm_loss_grad"):
+                return acc + score_fn(embs, aux)
+        return score
 
-    return default_port().compile(score)
+    return _PoolProgram(build, False)
 
 
 def _build_device_routed_body(loss_fn, role_class, role_dim,
                               frozen_roles, neg_role, neg_shape,
-                              no_replicas, neg_alias):
+                              no_replicas, neg_alias, axis=None):
     """The un-jitted single-step body shared by make_device_routed_step
     (one dispatch per step) and make_device_routed_scan (K steps per
     dispatch).
+
+    `axis` names the mesh axis the body is mapped over (`_PoolProgram`:
+    pools of several shards): it is then the PER-CHIP step. `pools` are
+    the chip's own `[1, slots, L]` blocks of main, cache and delta;
+    everything else is the same on every chip. It is the one-shard step
+    on routes brought onto the chip (`_on_this_chip`), with two sums
+    over the axis between its parts:
+
+      - the sampled role is local by construction (drawn from the
+        worker shard's own resident keys: main rows it owns, or its
+        replicas), so its rows are gathered, differentiated and written
+        back on the worker's chip alone and cross no wire. The other
+        chips run the same operations on routes that are out of bounds
+        everywhere: clamped or zero-filled gathers, dropped write-backs;
+      - the roles named by key are gathered where they lie (the main
+        copy on its owner's chip, a replica of the worker's shard on the
+        worker's chip, zeros elsewhere) and ONE sum over the axis gives
+        every chip their embedding columns. Their gradients, formed on
+        the worker's chip, go back through one more sum with zeros from
+        the other chips, and each chip writes back the rows it holds,
+        from the accumulators it gathered itself;
+      - the loss is the worker chip's, summed with zeros likewise. The
+        locality counts are computed from the global routes, alike on
+        every chip.
+
+    Inside the map a chip's main block is one shard, so the write-back
+    kernel applies (`writeback_uses_kernel`) in both variants; the
+    replica variant's delta rows, few and on the worker's chip, keep
+    XLA's scatter-add.
 
     In the replica-free variant no row-wide array is copied, padded or
     masked as a whole between the gather and its readers. The sampled
@@ -490,6 +628,11 @@ def _build_device_routed_body(loss_fn, role_class, role_dim,
     def step(pools, locstat, tables, keys, local_index, alias, rng_key,
              aux, lr, eps):
         keys = dict(keys)
+        # the roles whose keys this program draws (from the worker
+        # shard's resident keys: local by construction), the others are
+        # named by key
+        drawn = (neg_role,) if neg_role is not None and (
+            neg_alias or local_index is not None) else ()
         with jax.named_scope("adapm_sampler"):
             if neg_role is not None and neg_alias:
                 # snap_table[j]: alias position j's key, snapped to the
@@ -508,7 +651,12 @@ def _build_device_routed_body(loss_fn, role_class, role_dim,
                 # sample-major from here on; the values at [b, k] stay
                 keys[neg_role] = jnp.moveaxis(keys[neg_role], -1, 0)
         embs, accs, routes, n_total, n_local = _route_and_gather(
-            pools, tables, keys, roles, role_class, role_dim, no_replicas)
+            pools, tables, keys, roles, role_class, role_dim, no_replicas,
+            axis)
+        if axis is not None:
+            with jax.named_scope("adapm_exchange"):
+                embs.update(jax.lax.psum(
+                    {r: embs[r] for r in roles if r not in drawn}, axis))
         # one step = one (batched) pull op + one push op of the same keys;
         # the op counts local iff every key it touched was local
         all_local = (n_local == n_total).astype(jnp.int32)
@@ -516,23 +664,36 @@ def _build_device_routed_body(loss_fn, role_class, role_dim,
             [jnp.int32(n_total), n_local, jnp.int32(1), all_local])
         loss, grads = _loss_and_grads(batch_major_loss, embs, trainable,
                                       aux)
+        if axis is not None:
+            # the worker chip's loss and gradients: zeros from the others
+            worker = _here(tables[3], axis)
+            with jax.named_scope("adapm_exchange"):
+                loss, named = jax.lax.psum(jax.tree_util.tree_map(
+                    lambda x: jnp.where(worker, x, 0),
+                    (loss, {r: grads[r] for r in trainable
+                            if r not in drawn})), axis)
+            grads.update(named)
 
         new_pools = list(pools)
         for r in trainable:
             cid = role_class[r]
             main, cache, delta = new_pools[cid]
-            if no_replicas and writeback_uses_kernel(main):
-                main = _kernel_writeback(main, *routes[r], grads[r],
+            # main's coordinates in either variant (the replica
+            # variant's slot is out of bounds at replica positions, and
+            # its replica slot c_sl at the others)
+            o_sh, o_sl = routes[r][:2]
+            kernel = writeback_uses_kernel(main)
+            if kernel:
+                main = _kernel_writeback(main, o_sh, o_sl, grads[r],
                                          accs[r], lr, eps)
-            else:
+            if not (kernel and no_replicas):
                 upd = _adagrad_update(grads[r], accs[r], lr, eps)
                 with jax.named_scope("adapm_scatter_add"):
-                    if no_replicas:
-                        o_sh, o_sl = routes[r]
+                    if not kernel:
                         main = main.at[o_sh, o_sl].add(upd, mode="drop")
-                    else:
-                        main, delta = _scatter_update(main, delta,
-                                                      routes[r], upd)
+                    if not no_replicas:
+                        _, _, c_sh, c_sl, _ = routes[r]
+                        delta = delta.at[c_sh, c_sl].add(upd, mode="drop")
             new_pools[cid] = (main, cache, delta)
         return tuple(new_pools), locstat, loss
 
@@ -690,7 +851,14 @@ class DeviceRoutedRunner:
             "fused.writeback_rows_total", unit="rows", shared=True)
         self._c_wb_kernel_rows = server.obs.counter(
             "fused.writeback_kernel_rows_total", unit="rows", shared=True)
-        self._wb_rows = None  # (all, kernel's) a step; set on first step
+        # bytes the per-chip steps summed over the mesh's kv axis (the
+        # rows named by key out, their gradients back, the loss): 0 on
+        # one shard, and GSPMD's own collectives are not counted
+        self._c_exchange = server.obs.counter(
+            "fused.exchange_bytes_total", unit="bytes", shared=True)
+        # a step's (rows written back, those of them in pools the kernel
+        # takes, bytes exchanged); set on first step
+        self._per_step = None
         # rows the gather-only score program read (`score`)
         self._c_score_rows = server.obs.counter(
             "fused.score_rows_total", unit="rows", shared=True)
@@ -889,25 +1057,50 @@ class DeviceRoutedRunner:
             self._drain_every = max(1, 2**30 // max(1, pps))
             self._g_drain_every.set(self._drain_every)
 
-    def _count_writeback(self, role_keys: Dict[str, np.ndarray],
-                         steps: int, no_replicas: bool) -> None:
-        """Count the rows `steps` dispatched steps write back (from the
-        first batch's key shapes, fixed per runner, like the drain
-        interval above)."""
-        if self._wb_rows is None:
-            rows = {r: np.asarray(k).size for r, k in role_keys.items()}
+    def _step_program(self, no_replicas: bool):
+        """The compiled step of one variant. On several shards a runner
+        whose local index fell back to the whole population
+        (`_li_fallback`: its negatives may lie on any shard) takes the
+        step as one program over the global pools, not the per-chip
+        one (`make_device_routed_step`, `neg_local`)."""
+        if self._one_program_over_shards():
+            return self._program(make_device_routed_step,
+                                 no_replicas=no_replicas, neg_local=False)
+        return self._step_fn_norep if no_replicas else self.step_fn
+
+    def _one_program_over_shards(self) -> bool:
+        return self._li_fallback and self.server.num_shards > 1
+
+    def _count_step(self, role_keys: Dict[str, np.ndarray],
+                    steps: int) -> None:
+        """Count the rows `steps` dispatched steps write back and the
+        bytes they exchange (from the first batch's key shapes, fixed
+        per runner, like the drain interval above)."""
+        if self._per_step is None:
+            named = {r: np.asarray(k).size for r, k in role_keys.items()}
+            rows = dict(named)
             if self.neg_role is not None:
                 rows[self.neg_role] = int(np.prod(self._neg_shape))
             rows = {r: n for r, n in rows.items()
                     if r not in self.frozen_roles}
-            stores = self.server.stores
-            self._wb_rows = (sum(rows.values()), sum(
-                n for r, n in rows.items() if writeback_uses_kernel(
-                    stores[self.role_class[r]].main)))
-        rows, kernel_rows = self._wb_rows
+
+            def takes_kernel(r):  # a pool's one shard, or a chip's block
+                main = self.server.stores[self.role_class[r]].main
+                return writeback_uses_kernel(jax.ShapeDtypeStruct(
+                    (1,) + main.shape[1:], main.dtype))
+            dim = self._mk_kwargs["role_dim"]
+            self._per_step = (
+                sum(rows.values()),
+                sum(n for r, n in rows.items() if takes_kernel(r)),
+                4 + 4 * sum(n * dim[r] * (1 + (r in rows))
+                            for r, n in named.items()))
+        rows, kernel_rows, exchanged = self._per_step
         self._c_wb_rows.inc(rows * steps)
-        if no_replicas:  # the only variant the kernel is compiled into
-            self._c_wb_kernel_rows.inc(kernel_rows * steps)
+        if self._one_program_over_shards():
+            return  # GSPMD: no kernel, and its collectives are its own
+        self._c_wb_kernel_rows.inc(kernel_rows * steps)
+        if self.server.num_shards > 1:
+            self._c_exchange.inc(exchanged * steps)
 
     def _count_sampled(self, steps: int) -> None:
         """Count the rows `steps` dispatched steps drew from the local
@@ -1128,8 +1321,7 @@ class DeviceRoutedRunner:
                 self._upload_keys({r: np.asarray(k, dtype=kdtype)
                                    for r, k in role_keys.items()})
             pools = tuple((s.main, s.cache, s.delta) for s in srv.stores)
-            no_replicas = not self._shard_has_replicas()
-            fn = self._step_fn_norep if no_replicas else self.step_fn
+            fn = self._step_program(not self._shard_has_replicas())
             # dispatch under the gate, tracked on the "main" stream for
             # the executor's overlap accounting (enqueue-only: the jit
             # call returns as soon as the program is queued)
@@ -1141,7 +1333,7 @@ class DeviceRoutedRunner:
                 for st, (m, c, d) in zip(srv.stores, pools):
                     st.main, st.cache, st.delta = m, c, d
             self.steps += 1
-            self._count_writeback(role_keys, 1, no_replicas)
+            self._count_step(role_keys, 1)
             self._count_sampled(1)
             self._ensure_drain_every(role_keys)
             if self.steps % self._drain_every == 0:
@@ -1194,9 +1386,8 @@ class DeviceRoutedRunner:
                     sum(np.asarray(k).size for k in role_keys.values()))
         return acc
 
-    def _scan_fn(self, no_replicas: bool, has_aux: bool):
-        return self._program(make_device_routed_scan,
-                             no_replicas=no_replicas, has_aux=has_aux)
+    def _scan_fn(self, **variant):
+        return self._program(make_device_routed_scan, **variant)
 
     def run_scan(self, batches: Sequence[Dict[str, np.ndarray]], auxes,
                  lr: float, eps: float = 1e-10) -> np.ndarray:
@@ -1255,8 +1446,11 @@ class DeviceRoutedRunner:
                     lambda *xs: put(np.stack([np.asarray(x) for x in xs])),
                     *auxes)
             pools = tuple((s.main, s.cache, s.delta) for s in srv.stores)
-            no_replicas = not self._shard_has_replicas()
-            fn = self._scan_fn(no_replicas=no_replicas, has_aux=has_aux)
+            variant = dict(no_replicas=not self._shard_has_replicas(),
+                           has_aux=has_aux)
+            if self._one_program_over_shards():  # as _step_program
+                variant["neg_local"] = False
+            fn = self._scan_fn(**variant)
             with srv.exec.track("main"), _GATE:
                 pools, self._locstat, losses = fn(
                     pools, self._locstat, tables, keys, local_index,
@@ -1265,7 +1459,7 @@ class DeviceRoutedRunner:
                 for st, (m, c, d) in zip(srv.stores, pools):
                     st.main, st.cache, st.delta = m, c, d
             self.steps += K
-            self._count_writeback(batches[0], K, no_replicas)
+            self._count_step(batches[0], K)
             self._count_sampled(K)
             self._ensure_drain_every(batches[0])
             if self.steps // self._drain_every != \

@@ -8,6 +8,7 @@ chip beside the test suite's workers stalled them):
     JAX_PLATFORMS=cpu python scripts/kv4_memory.py [cache_slots_per_shard]
 """
 import os
+import re
 import sys
 import time
 
@@ -51,21 +52,30 @@ def main(cache: int) -> None:
             shape((4, cache, L), jnp.float32, rows),
             shape((4, cache, L), jnp.float32, rows))
     roles = {"s": 0, "r": 0, "o": 0, "neg": 0}
+    # the step as the runner builds it: pools of four shards make it the
+    # per-chip program, built as on a TPU (the write-back kernel inside)
+    default_backend, jax.default_backend = jax.default_backend, \
+        lambda: "tpu"
     for no_replicas in (True, False):
-        body = fused._build_device_routed_body(
+        step = fused.make_device_routed_step(
             make_kge_loss("complex", 0.0, 0.0), roles,
-            {r: L // 2 for r in roles}, (), "neg", (B, N), no_replicas,
-            False)
+            {r: L // 2 for r in roles}, (), "neg", (B, N), no_replicas)
         t0 = time.time()
-        report(f"step, no_replicas={no_replicas}", jax.jit(
-            body, donate_argnums=(0,)).lower(
+        compiled = step.lower(
             (pool,), shape((4,), jnp.int32),
             tuple(shape((NUM_KEYS,), jnp.int32) for _ in range(3))
             + (shape((), jnp.int32),),
             {r: shape((B,), jnp.int32) for r in roles if r != "neg"},
             (shape((1 << 21,), jnp.int32), shape((), jnp.int32)), None,
             shape((2,), jnp.uint32), None, shape((), jnp.float32),
-            shape((), jnp.float32)).compile(), t0)
+            shape((), jnp.float32)).compile()
+        report(f"step, no_replicas={no_replicas}", compiled, t0)
+        text = compiled.as_text()
+        print("  write-back kernel calls:",
+              text.count("custom_call_target=\"tpu_custom_call\""),
+              "; all-reduces:", sorted(set(re.findall(
+                  r"= (\(.*?\)|\S+) all-reduce(?:-start)?\(", text))), flush=True)
+    jax.default_backend = default_backend
 
     def index(n):
         return jax.ShapeDtypeStruct((n,), jnp.int32)

@@ -247,7 +247,14 @@ def test_four_workers_additive_read_back_is_exact_from_every_holder():
 def test_shared_step_equals_the_per_shard_programs(no_replicas):
     """The step with the worker's shard as an operand gives, for each of
     the four shard values, bitwise what a program compiled with that
-    shard as a constant gives (the per-shard programs this replaced)."""
+    shard as a constant gives (the per-shard programs this replaced):
+    as one program over the global pools. The per-chip step that pools
+    of four shards get (`make_device_routed_step`), the shard its
+    operand too, gives the same counts, and its loss and rows inside the
+    probe's limits (another program: an ulp apart on this CPU). Every
+    shard holds replicas here, which the replica-free variant is never
+    run beside: its per-chip form is held to the global program in
+    tests/test_kv_per_chip_step.py."""
     from adapm_tpu.models.kge import make_kge_loss
     from adapm_tpu.ops import DeviceRouter, fused
     run = _open("all")
@@ -269,6 +276,9 @@ def test_shared_step_equals_the_per_shard_programs(no_replicas):
             make_kge_loss("complex", 0.0, 0.0), roles,
             {r: W for r in roles}, (), "neg", (B, N), no_replicas, False)
         shared = jax.jit(body)
+        per_chip = fused.make_device_routed_step(
+            make_kge_loss("complex", 0.0, 0.0), roles,
+            {r: W for r in roles}, (), "neg", (B, N), no_replicas)
         rng = np.random.default_rng(8)
         put = srv.ctx.put_replicated
         keys = {"s": put(run.ekey(rng.integers(0, E, B)).astype(np.int32)),
@@ -292,6 +302,20 @@ def test_shared_step_equals_the_per_shard_programs(no_replicas):
             for a, b in zip(jax.tree_util.tree_leaves(got),
                             jax.tree_util.tree_leaves(want)):
                 assert np.asarray(a).tobytes() == np.asarray(b).tobytes()
+            if no_replicas:
+                continue
+            # (not donated here: a copy of the pools, which it consumes)
+            got = per_chip(jax.tree_util.tree_map(lambda x: x + 0, pools),
+                           locstat,
+                           tables + (put(np.int32(shard)),), *rest)
+            assert list(per_chip._forms) == [srv.ctx.mesh]
+            assert np.array_equal(got[1], want[1])
+            assert abs(float(got[2]) - float(want[2])) <= \
+                LOSS_GAP * abs(float(want[2]))
+            for a, b in zip(jax.tree_util.tree_leaves(got[0]),
+                            jax.tree_util.tree_leaves(want[0])):
+                np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                           rtol=0, atol=1e-6)
     finally:
         run.srv.shutdown()
 

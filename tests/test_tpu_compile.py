@@ -372,3 +372,174 @@ def test_dlrm_cell_step_fits_beside_the_tables(shape, kernel_cache,
               f"{fullest / 2**30:.2f} GiB")
     assert fullest < 15.0 * 2**30
     assert mem.temp_size_in_bytes < 4 << 30
+
+
+# the four-shard cell (kge-wikidata5m-kv4): a shard's main and replica
+# pools' slots, keys, batch, negatives a triple
+KV4_SLOTS, KV4_CACHE, KV4_KEYS, KV4_B, KV4_N = \
+    1_194_784, 32_768, 4_595_309, 4096, 32
+_ALL_REDUCE = re.compile(r"= (\(.*?\)|\S+) all-reduce(?:-start)?\(")
+
+
+@pytest.mark.parametrize("no_replicas", [True, False])
+def test_four_shard_step_is_a_per_chip_program(no_replicas, topo,
+                                               kernel_cache, monkeypatch,
+                                               capsys):
+    """The four-shard cell's step at its own sizes, compiled for the
+    described v5e 2x2 as the runner builds it (`make_device_routed_step`:
+    pools of four shards make it the per-chip program): each chip's
+    block keeps the write-back kernel's custom call, one for each role
+    (131,072 negatives are one call); what is summed over the chips is
+    the named roles' `[4096, 1024]` halves and the loss, never an array
+    of the negatives' 131,072 rows; the pools stay aliased and the
+    temporaries far under a pool's size."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from adapm_tpu.models.kge import make_kge_loss
+    from adapm_tpu.ops import fused
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    mesh = Mesh(np.asarray(topo.devices[:4]), ("kv",))
+
+    def shape(dims, dtype, spec=P()):
+        return jax.ShapeDtypeStruct(dims, dtype,
+                                    sharding=NamedSharding(mesh, spec))
+    pool = tuple(shape((4, n, L), jnp.float32, P("kv"))
+                 for n in (KV4_SLOTS, KV4_CACHE, KV4_CACHE))
+    roles = {"s": 0, "r": 0, "o": 0, "neg": 0}
+    step = fused.make_device_routed_step(
+        make_kge_loss("complex", 0.0, 0.0), roles,
+        {r: L // 2 for r in roles}, (), "neg", (KV4_B, KV4_N), no_replicas)
+    compiled = step.lower(
+        (pool,), shape((4,), jnp.int32),
+        tuple(shape((KV4_KEYS,), jnp.int32) for _ in range(3))
+        + (shape((), jnp.int32),),
+        {r: shape((KV4_B,), jnp.int32) for r in roles if r != "neg"},
+        (shape((1 << 21,), jnp.int32), shape((), jnp.int32)), None,
+        shape((2,), jnp.uint32), None, shape((), jnp.float32),
+        shape((), jnp.float32)).compile()
+    assert list(step._forms) == [mesh]
+    text = compiled.as_text()
+    assert text.count("custom_call_target=\"tpu_custom_call\"") == 4
+    summed = [tuple(int(d) for d in dims.split(","))
+              for res in _ALL_REDUCE.findall(text)
+              for dims in _F32.findall(res)]
+    # (the loss, a scalar, has no dims for _F32 to find)
+    assert summed and set(summed) == {(KV4_B, L // 2)}, summed
+    mem = compiled.memory_analysis()
+    pool_bytes = KV4_SLOTS * L * 4
+    assert mem.alias_size_in_bytes >= pool_bytes
+    assert mem.temp_size_in_bytes < pool_bytes // 2
+    live = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    with capsys.disabled():
+        print(f"\nkge-wikidata5m-kv4 v5e 2x2 compile, no_replicas="
+              f"{no_replicas}: a chip's temporaries "
+              f"{mem.temp_size_in_bytes / 1e9:.3f} GB, live "
+              f"{live / 2**30:.2f} GiB of 15.75")
+    assert live < 15.0 * 2**30
+
+
+# sha256 of the lowered text of the one-chip cells' programs as the
+# PARENT of PR 34 lowered them (commit 21ae0c6, this jax): that PR made
+# the fused programs adapt to their pools' shard count and left pools of
+# one shard their program. A PR that means to change a one-chip program
+# records its own text here (`_one_chip_lowered` under `pytest -s` prints
+# what it finds when a hash differs).
+PARENT_LOWERED = {
+    "kge.jit_step":
+        "0a4a4bdc65170f9cecb6a203bc83ee7a540c46a0fa36c0943b4cad4119e848e2",
+    "kge.jit_scan":
+        "36c3fc948c944080f440a2bee735f5926dbe784dc68f477c2e6b00a555e0a083",
+    "sgns.jit_step":
+        "1382bc8cb88780b4a65dc2d511c37e00f3a97a90f6cfe7aaca84f6bfeeed13a7",
+    "mf.jit_step":
+        "6d0aaec54d5563dba0d80188a29424f82a100495384325f387735cfe60231cb8",
+    "mf.jit_score":
+        "491ab883cabaed67acfe83894c660a1a1b46f3c168db61a54336c6ac13362e8a",
+}
+
+
+_MOSAIC_BODY = re.compile(r'\\22body\\22: \\22[A-Za-z0-9+/=]*\\22')
+
+
+def _one_chip_lowered(shape, monkeypatch):
+    """name -> lowered text (StableHLO, no locations) of the replica-free
+    programs the one-chip cells run, at the cells' own sizes, built as on
+    a TPU (the write-back kernel, exported): `jit_step` of KGE, SGNS, MF
+    and DLRM, KGE's `jit_scan` of 8 steps, MF's `jit_score`."""
+    from adapm_tpu.models.kge import make_kge_loss
+    from adapm_tpu.models.mf import make_mf_loss, mf_sq_error
+    from adapm_tpu.models.sgns import sgns_loss
+    from adapm_tpu.ops import fused
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    i32, f32 = shape((), jnp.int32), shape((), jnp.float32)
+    small = shape((1, 8, L), jnp.float32)
+    out = {}
+
+    def pools_tables(slots, num_keys):
+        return (tuple((shape((1, n, L), jnp.float32), small, small)
+                      for n in slots), shape((4,), jnp.int32),
+                tuple(shape((num_keys,), jnp.int32) for _ in range(3))
+                + (i32,))
+
+    slots, num_keys, B, N, _ = CELLS["kge-wikidata5m"]
+    roles = {"s": 0, "r": 1, "o": 0, "neg": 0}
+    kge = (make_kge_loss("complex", 0.0, 0.0), roles,
+           {r: L // 2 for r in roles}, (), "neg", (B, N), True, False)
+    local_index = (shape((1 << 21,), jnp.int32), i32)
+    keys = {r: shape((B,), jnp.int32) for r in roles if r != "neg"}
+    out["kge.jit_step"] = fused.make_device_routed_step(*kge).lower(
+        *pools_tables(slots, num_keys), keys, local_index, None,
+        shape((2,), jnp.uint32), None, f32, f32)
+    out["kge.jit_scan"] = fused.make_device_routed_scan(
+        *kge, has_aux=False).lower(
+        *pools_tables(slots, num_keys),
+        {r: shape((8, B), jnp.int32) for r in keys}, local_index, None,
+        shape((8, 2), jnp.uint32), None, f32, f32)
+
+    slots, num_keys, B, N, _ = CELLS["w2v-1bw"]
+    roles = {"center": 0, "ctx": 0, "neg": 0}
+    alias = (shape((793_471,), jnp.float32), shape((793_471,), jnp.int32),
+             shape((793_471,), jnp.int32))
+    out["sgns.jit_step"] = fused.make_device_routed_step(
+        sgns_loss, roles, {r: L // 2 for r in roles}, (), "neg", (B, N),
+        True, True).lower(
+        *pools_tables(slots, num_keys),
+        {r: shape((B,), jnp.int32) for r in roles if r != "neg"}, None,
+        alias, shape((2,), jnp.uint32), None, f32, f32)
+
+    pools, tables, keys = _mf_operands(shape)
+    roles = {"w": 0, "h": 0}
+    dims = {r: L // 2 for r in roles}
+    x = shape((MF_B,), jnp.float32)
+    out["mf.jit_step"] = fused.make_device_routed_step(
+        make_mf_loss(0.01), roles, dims, (), None, None, True,
+        False).lower(pools, shape((4,), jnp.int32), tables, keys, None,
+                     None, shape((2,), jnp.uint32), x, f32, f32)
+    out["mf.jit_score"] = fused.make_device_routed_score(
+        mf_sq_error, roles, dims, roles, no_replicas=True).lower(
+        pools, tables, keys, (x, i32), f32)
+    # the kernel's Mosaic body carries its source's path (the locations
+    # of pallas_kernels.py, which no program here changes): left out
+    return {name: _MOSAIC_BODY.sub("", lowered.as_text())
+            for name, lowered in out.items()}
+
+
+def test_one_chip_programs_lower_as_on_the_parent(shape, kernel_cache,
+                                                  monkeypatch, capsys):
+    """Pools of one shard bypass the per-chip form entirely: the
+    one-chip cells' programs lower to the text the parent's lowered to,
+    to the character."""
+    import hashlib
+    texts = _one_chip_lowered(shape, monkeypatch)
+    for name in ("kge.jit_step", "sgns.jit_step", "mf.jit_step"):
+        assert "@jit_step" in texts[name]
+    assert "@jit_scan" in texts["kge.jit_scan"]
+    assert "@jit_score" in texts["mf.jit_score"]
+    got = {name: hashlib.sha256(text.encode()).hexdigest()
+           for name, text in texts.items()}
+    if got != PARENT_LOWERED:
+        with capsys.disabled():
+            print("\nlowered one-chip programs:", got)
+    assert got == PARENT_LOWERED

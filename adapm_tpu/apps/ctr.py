@@ -144,6 +144,11 @@ class CtrRun:
         obs = self.srv.obs
         self._h_prepare = obs.histogram("app.prepare_s", shared=True)
         self._h_pass_end = obs.histogram("app.pass_end_s", shared=True)
+        # the same less the waits for the device beneath them (`work=`)
+        self._h_prepare_work = obs.histogram("app.prepare_work_s",
+                                             shared=True)
+        self._h_pass_end_work = obs.histogram("app.pass_end_work_s",
+                                              shared=True)
         self._c_keys = obs.counter("app.batch_keys_total", unit="keys",
                                    shared=True)
         self._c_unique = obs.counter("app.batch_unique_keys_total",
@@ -252,7 +257,8 @@ class CtrRun:
             runner = self.device_runner(w.shard)
             for bi, b in enumerate(plan):
                 if bi + a.lookahead < len(plan):
-                    with srv._span("app.prepare", self._h_prepare):
+                    with srv._span("app.prepare", self._h_prepare,
+                                   work=self._h_prepare_work):
                         nxt = plan[bi + a.lookahead]
                         fut = w.current_clock + a.lookahead
                         w.intent(nxt.keys, fut, fut + 1)
@@ -295,9 +301,10 @@ def train(crun: CtrRun) -> float:
     from ..parallel import control
     for _ in range(args.epochs):
         losses = crun.train_pass()
-        with srv._span("app.pass_end", crun._h_pass_end):
+        with srv._span("app.pass_end", crun._h_pass_end,
+                       work=crun._h_pass_end_work):
             srv.quiesce()
-            with srv._span("app.loss_fetch"):
+            with srv._span("app.loss_fetch", wait=True):
                 mean_loss = float(jnp.mean(jnp.stack(losses))) \
                     if losses else 0.0
             mean_loss = float(control.allreduce(mean_loss, "mean")[0])

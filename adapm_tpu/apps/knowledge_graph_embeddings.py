@@ -561,6 +561,10 @@ def train(run: KgeRun) -> dict:
     # fused.dispatch, kv.drive_rounds, kv.advance_clock)
     h_prepare = srv.obs.histogram("app.prepare_s", shared=True)
     h_pass_end = srv.obs.histogram("app.pass_end_s", shared=True)
+    # the same less the waits for the device beneath them (`work=`)
+    h_prepare_work = srv.obs.histogram("app.prepare_work_s", shared=True)
+    h_pass_end_work = srv.obs.histogram("app.pass_end_work_s",
+                                        shared=True)
     if run.truth_mrr is not None:
         result["truth_mrr"] = run.truth_mrr
         result["truth_mrr_o"] = ds.truth_mrr_o
@@ -598,7 +602,8 @@ def train(run: KgeRun) -> dict:
                 if bi <= prepared_hi:
                     return
                 prepared_hi = bi
-                with srv._span("app.prepare", h_prepare):
+                with srv._span("app.prepare", h_prepare,
+                               work=h_prepare_work):
                     t = triples[batches[bi]]
                     roles = triple_roles(t)
                     ks = np.unique(np.concatenate(
@@ -653,9 +658,9 @@ def train(run: KgeRun) -> dict:
                 epoch_losses.append(loss)
                 srv.drive_rounds(args.sync_rounds_per_step)
                 w.advance_clock()
-        with srv._span("app.pass_end", h_pass_end):
+        with srv._span("app.pass_end", h_pass_end, work=h_pass_end_work):
             srv.quiesce()
-            with srv._span("app.loss_fetch"):
+            with srv._span("app.loss_fetch", wait=True):
                 # scan windows contribute [K] loss vectors, per-step
                 # path scalars
                 epoch_loss = float(np.sum([np.asarray(l).sum()

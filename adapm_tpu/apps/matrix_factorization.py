@@ -147,6 +147,11 @@ class MfRun:
         obs = self.srv.obs
         self._h_prepare = obs.histogram("app.prepare_s", shared=True)
         self._h_pass_end = obs.histogram("app.pass_end_s", shared=True)
+        # the same less the waits for the device beneath them (`work=`)
+        self._h_prepare_work = obs.histogram("app.prepare_work_s",
+                                             shared=True)
+        self._h_pass_end_work = obs.histogram("app.pass_end_work_s",
+                                              shared=True)
         self._h_loss_pass = obs.histogram("app.loss_pass_s", shared=True)
         self._c_keys = obs.counter("app.batch_keys_total", unit="keys",
                                    shared=True)
@@ -275,7 +280,8 @@ class MfRun:
         ready = {}
 
         def prepare(bi: int) -> None:
-            with srv._span("app.prepare", self._h_prepare):
+            with srv._span("app.prepare", self._h_prepare,
+                           work=self._h_prepare_work):
                 b = ready[bi] = get(bi)
                 fut = w.current_clock + a.lookahead
                 self.signal_intent(w, b, fut, fut + 1)
@@ -315,7 +321,8 @@ class MfRun:
                     nxt = mine[cblock[mine] == sched[s + 1, gwi]]
                     if len(nxt):
                         nb_nxt = max(-(-len(nxt) // B), 1)
-                        with srv._span("app.prepare", self._h_prepare):
+                        with srv._span("app.prepare", self._h_prepare,
+                                       work=self._h_prepare_work):
                             self.signal_intent(
                                 w, self.prepared(nxt),
                                 w.current_clock + nb_cur,
@@ -385,7 +392,7 @@ class MfRun:
                                        staged=b.staged)
             on_device = bool(a.l2) and srv.tier is None
             sq = self._factor_sq_sum() if on_device else 0.0
-            with srv._span("app.loss_fetch"):
+            with srv._span("app.loss_fetch", wait=True):
                 err = 0.0 if err is None else float(err)
                 sq = float(sq)
         if a.l2 and not on_device:
@@ -420,7 +427,8 @@ def train(mrun: MfRun) -> float:
     watch = Stopwatch(start=True)
     for _ in range(args.epochs):
         mrun.train_pass()
-        with srv._span("app.pass_end", mrun._h_pass_end):
+        with srv._span("app.pass_end", mrun._h_pass_end,
+                       work=mrun._h_pass_end_work):
             srv.quiesce()
             loss = mrun.pass_loss()
             lr = mrun.lr

@@ -110,6 +110,11 @@ class W2vRun:
         obs = self.srv.obs
         self._h_prepare = obs.histogram("app.prepare_s", shared=True)
         self._h_pass_end = obs.histogram("app.pass_end_s", shared=True)
+        # the same less the waits for the device beneath them (`work=`)
+        self._h_prepare_work = obs.histogram("app.prepare_work_s",
+                                             shared=True)
+        self._h_pass_end_work = obs.histogram("app.pass_end_work_s",
+                                              shared=True)
         self._c_sentences = obs.counter("app.sentences_total",
                                         unit="sentences", shared=True)
         self._c_pairs = obs.counter("app.pairs_total", unit="pairs",
@@ -176,7 +181,8 @@ class W2vRun:
             def prepare(pos: int, ahead: int) -> None:
                 """Signal intent for the sentence that will be trained
                 `ahead` clocks from now."""
-                with srv._span("app.prepare", self._h_prepare):
+                with srv._span("app.prepare", self._h_prepare,
+                               work=self._h_prepare_work):
                     c, x = self.pairs(my[pos])
                     self._c_sentences.inc()
                     self._c_pairs.inc(len(c))
@@ -271,9 +277,10 @@ def train(wrun: W2vRun) -> float:
     from ..parallel import control
     for _ in range(args.epochs):
         losses = wrun.train_pass()
-        with srv._span("app.pass_end", wrun._h_pass_end):
+        with srv._span("app.pass_end", wrun._h_pass_end,
+                       work=wrun._h_pass_end_work):
             srv.quiesce()
-            with srv._span("app.loss_fetch"):
+            with srv._span("app.loss_fetch", wait=True):
                 # scan windows contribute [K] loss vectors, per-step
                 # path scalars
                 mean_loss = float(np.mean(np.concatenate(

@@ -24,6 +24,7 @@ Design notes (see ARCHITECTURE.md):
 """
 from __future__ import annotations
 
+import collections
 import contextlib
 import threading
 import time as _time
@@ -258,11 +259,23 @@ class Server:
         self._h_quiesce = self.obs.histogram("kv.quiesce_s")
         self._h_intent = self.obs.histogram("kv.intent_s")
         self._h_clock = self.obs.histogram("kv.advance_clock_s")
+        # the same phases less the waits for the device beneath them
+        # (obs/spans.py: `work=`): the host's own time
+        self._h_drive_work = self.obs.histogram("kv.drive_rounds_work_s")
+        self._h_intent_work = self.obs.histogram("kv.intent_work_s")
+        self._h_clock_work = self.obs.histogram("kv.advance_clock_work_s")
         # the planner's two device-program phases (one observation per
         # call of _relocate_to / _sync_replicas), and the wire bytes the
         # sync programs shipped, as a counter
         self._h_relocate = self.obs.histogram("kv.relocate_s")
         self._h_sync_replicas = self.obs.histogram("kv.sync_replicas_s")
+        self._h_relocate_work = self.obs.histogram("kv.relocate_work_s")
+        self._h_sync_replicas_work = self.obs.histogram(
+            "kv.sync_replicas_work_s")
+        # the stores' calls of the planner's programs (the wait span
+        # `store.enqueue`, core/store.py): what a call holds beyond its
+        # enqueue is the wait for a free dispatch slot
+        self._h_store_enqueue = self.obs.histogram("kv.store_enqueue_s")
         self._c_sync_bytes = self.obs.counter("sync.bytes_shipped_total",
                                               unit="bytes")
         # collective wait-time histograms, observed by the (server-less)
@@ -291,7 +304,8 @@ class Server:
                 tier_hot_rows=(self.opts.tier_hot_rows
                                if self.opts.tier else 0),
                 tier_cold_dtype=(self.opts.tier_cold_dtype
-                                 if self.opts.tier else "fp32")))
+                                 if self.opts.tier else "fp32"),
+                wait=self._store_wait))
         # device-plane accounting (ISSUE 14; schema v10): the stores
         # share one process-wide DevicePort — surface its program /
         # wire-ingest counters. shared=True: several servers in one
@@ -488,6 +502,11 @@ class Server:
         # device-routed runners register a counts callback here so the
         # production path feeds locality_summary too (ops/fused.py)
         self._locality_sources: List = []
+        # ... and share this queue of their dispatched steps' losses,
+        # oldest first: `fused.inflight_steps` reads its length at each
+        # dispatch (ops/fused.py; not kept with the registry off)
+        self._steps_in_flight = collections.deque() \
+            if self.obs.enabled else None
         if self.tracer is not None:
             # initial allocation events, grouped by home shard (one record
             # call per shard, not per key)
@@ -566,12 +585,25 @@ class Server:
                     self._c_topo_bumps.inc()
                     self._ab_mut_acked = self.ab.mutations
 
-    def _span(self, name: str, hist=None) -> Span:
+    def _span(self, name: str, hist=None, wait: bool = False,
+              work=None) -> Span:
         """THE phase bracket (obs/spans.py): a host event
         `adapm.<name>` on the profiler's clock whenever a profiler
         session runs, the elapsed seconds into `hist` when given, and a
-        SpanTracer record under --sys.trace.spans."""
-        return Span(name, hist, self.spans)
+        SpanTracer record under --sys.trace.spans. `wait`: the span
+        holds ONE call that can block on the device or a transfer, and
+        its seconds join the thread's wait tally; `work`: a histogram
+        for the span's seconds outside every wait beneath it. Neither
+        is kept under --sys.metrics 0."""
+        if (wait or work is not None) and not self.obs.enabled:
+            wait, work = False, None
+        return Span(name, hist, self.spans, wait, work)
+
+    def _store_wait(self) -> Span:
+        """The bracket the stores put around a planner program's call
+        (core/store.py): the wait span `store.enqueue`."""
+        return self._span("store.enqueue", self._h_store_enqueue,
+                          wait=True)
 
     # -- worker management ---------------------------------------------------
 
@@ -1149,8 +1181,8 @@ class Server:
         caller's classification of the next channel instead of
         serializing behind the lock."""
         mode = self.opts.sync_compress if compress else "off"
-        with self._span("kv.sync_replicas", self._h_sync_replicas), \
-                self._lock:
+        with self._span("kv.sync_replicas", self._h_sync_replicas,
+                        work=self._h_sync_replicas_work), self._lock:
             ab = self.ab
             karr = np.ascontiguousarray(keys, dtype=np.int64)
             sarr = np.ascontiguousarray(shards, dtype=np.int32)
@@ -1244,7 +1276,8 @@ class Server:
                 pol.guard_blocked("reloc")
         demoted = np.empty(0, dtype=np.int64)
         n_moved = 0
-        with self._span("kv.relocate", self._h_relocate), self._lock:
+        with self._span("kv.relocate", self._h_relocate,
+                        work=self._h_relocate_work), self._lock:
             ab = self.ab
             # dedup: a duplicate key would double-free its old main slot in
             # relocate_batch (the drain path dedups in Worker.intent, but
@@ -1548,7 +1581,7 @@ class Server:
     def block(self) -> None:
         # under the server lock: pool buffers are donated+replaced by ops
         # running in other threads, and blocking on a donated buffer raises
-        with self._lock:
+        with self._lock, self._span("kv.block", wait=True):
             for s in self.stores:
                 # apm-lint: disable=APM002 quiesce point BY DESIGN: the
                 # lock must be held across the device wait here, or a
@@ -1596,7 +1629,8 @@ class Server:
         relocations, replica churn, and the device-table re-uploads they
         trigger — overlaps the in-flight device step instead of
         serializing after it."""
-        with self._span("kv.drive_rounds", self._h_drive):
+        with self._span("kv.drive_rounds", self._h_drive,
+                        work=self._h_drive_work):
             if self.prefetch is not None:
                 self.prefetch.pump(n)
             else:
@@ -2443,7 +2477,8 @@ class Worker:
         (unique, sorted) key batch inside the window can be served from
         a pre-gathered staged buffer."""
         srv = self.server
-        with srv._span("kv.intent", srv._h_intent):
+        with srv._span("kv.intent", srv._h_intent,
+                       work=srv._h_intent_work):
             keys = np.unique(self._keys(keys))
             end = start if end is None else end
             wt = srv.wtrace
@@ -2456,7 +2491,8 @@ class Worker:
 
     def advance_clock(self) -> int:
         srv = self.server
-        with srv._span("kv.advance_clock", srv._h_clock):
+        with srv._span("kv.advance_clock", srv._h_clock,
+                       work=srv._h_clock_work):
             self._clock += 1
             srv._clocks[self.worker_id] = self._clock
             wt = srv.wtrace

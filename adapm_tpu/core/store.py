@@ -31,6 +31,7 @@ device-API-free (adapm-lint APM008).
 """
 from __future__ import annotations
 
+import contextlib
 import math
 
 import jax
@@ -130,13 +131,19 @@ class ShardedStore:
                  ctx: MeshContext, dtype=np.float32, over_alloc: float = 1.25,
                  cache_slots_per_shard: int = 0, bucket_min: int = 8,
                  tier_hot_rows: int = 0, tier_cold_dtype: str = "fp32",
-                 port=None):
+                 port=None, wait=contextlib.nullcontext):
         self.value_length = value_length
         self.ctx = ctx
         self.dtype = dtype
         # the device plane (ISSUE 14): every program dispatch below goes
         # through this narrow port — swap it to target a new backend
         self.port = port if port is not None else default_port()
+        # the server's wait bracket (Server._store_wait: the span
+        # `store.enqueue`), handed over like the port: around each call
+        # of a planner program, which returns once the program is
+        # queued and so holds the wait for a free dispatch slot. The
+        # epoch bookkeeping around the calls stays outside it
+        self._wait = wait
         # min padded batch size (--sys equivalent: remote_bucket_min) — a
         # larger floor means fewer distinct bucket shapes, i.e. fewer XLA
         # compilations, at the cost of padding work on tiny batches
@@ -442,8 +449,9 @@ class ShardedStore:
             return
         a = pad_bucket(n, (o_shard, 0), (o_slot, OOB), (c_shard, 0),
                        (c_slot, OOB), minimum=self.bucket_min)
-        self.cache, self.delta = self.port.replica_create(
-            self.main, self.cache, self.delta, *a)
+        with self._wait():
+            self.cache, self.delta = self.port.replica_create(
+                self.main, self.cache, self.delta, *a)
 
     def sync_replicas(self, r_shard, r_cslot, o_shard, o_slot,
                       threshold: float = 0.0, compress: str = "off"):
@@ -483,9 +491,11 @@ class ShardedStore:
             return
         a = pad_bucket(n, (r_shard, 0), (r_cslot, OOB), (o_shard, 0),
                        (o_slot, OOB), minimum=self.bucket_min)
-        out = self.port.sync_replicas(self.main, self.cache, self.delta,
-                                      *a, threshold=threshold,
-                                      compress=compress)
+        with self._wait():
+            out = self.port.sync_replicas(self.main, self.cache,
+                                          self.delta, *a,
+                                          threshold=threshold,
+                                          compress=compress)
         if compress != "off":
             (self.main, self.cache, self.delta,
              self._ef_resid_dev) = out
@@ -524,8 +534,9 @@ class ShardedStore:
         a = pad_bucket(n, (old_shard, 0), (old_slot, OOB), (new_shard, 0),
                        (new_slot, OOB), (rc_shard, 0), (rc_slot, OOB),
                        minimum=self.bucket_min)
-        self.main, self.delta = self.port.relocate(
-            self.main, self.delta, *a)
+        with self._wait():
+            self.main, self.delta = self.port.relocate(
+                self.main, self.delta, *a)
 
     def precompile_planner(self, moved: int, synced: int,
                            sync_variants=((0.0, "off"),)) -> int:
@@ -581,8 +592,9 @@ class ShardedStore:
         a = pad_bucket(n, (sh, 0), (sl, OOB), minimum=self.bucket_min)
         arr = {"main": self.main, "cache": self.cache,
                "delta": self.delta}[which]
-        rows = self.port.read_rows_at(arr, *a)
-        return np.asarray(rows)[:n]
+        with self._wait():  # the program's call and the read-back
+            rows = np.asarray(self.port.read_rows_at(arr, *a))
+        return rows[:n]
 
     # -- tiered-residency helpers (adapm_tpu/tier; no-ops untiered) ----------
 

@@ -270,6 +270,9 @@ class SyncManager:
         # so metrics_snapshot()'s sync section is complete without
         # touching the counters the rest of this file maintains
         self._h_round = reg.histogram("sync.round_s")
+        # the round less the waits for the device beneath it (the
+        # stores' program calls): the planner's own host time
+        self._h_round_work = reg.histogram("sync.round_work_s")
         # staleness = worker clocks elapsed since the channel's previous
         # sync round, observed once per round that refreshed replicas
         # (i.e. how stale those replicas had been allowed to grow)
@@ -690,7 +693,8 @@ class SyncManager:
             # multi-process rounds issue channels concurrently.
             bytes_before = sum(st.sync_bytes_shipped
                                for st in self.server.stores)
-            with self.server._span("sync.round", self._h_round):
+            with self.server._span("sync.round", self._h_round,
+                                   work=self._h_round_work):
                 self.drain_intents(force=force_intents)
                 if all_channels:
                     self._sync_all_channels()

@@ -11,7 +11,9 @@ One layer every subsystem reports into (see docs/OBSERVABILITY.md):
     runs (no flag: the session is the switch), a registry histogram
     observation when given one, and a `spans.SpanTracer` record
     (Chrome trace-event JSON loadable in Perfetto) under
-    `--sys.trace.spans` (default off).
+    `--sys.trace.spans` (default off). `wait=True` marks a span as one
+    blocking call, `work=` gives a span a second histogram for its
+    time outside every wait beneath it (the host's own).
   - `crash.enable_crash_dumps`: faulthandler with a per-rank dump file,
     plus a last-open-span breadcrumb so an abort is attributable.
   - `flight.FlightTracer`: per-request causal traces across admission

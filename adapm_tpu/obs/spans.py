@@ -1,7 +1,7 @@
 """Phase spans: ONE bracket, on the profiler's clock.
 
-`Span` (handed out by `Server._span(name, hist=None)`) is the only way
-the program times a phase. Entering it
+`Span` (handed out by `Server._span(name, hist=None, wait=False,
+work=None)`) is the only way the program times a phase. Entering it
 
   1. enters `jax.profiler.TraceAnnotation("adapm." + name)`: with no
      profiler session that is a level check; inside one (any
@@ -14,6 +14,28 @@ the program times a phase. Entering it
      `--sys.metrics 0`);
   3. if `--sys.trace.spans` is on, records into the `SpanTracer` below
      (breadcrumb + Chrome JSON for operators, docs/OBSERVABILITY.md).
+
+Waits and self time (ISSUE 35). A span's length is not host work while
+the device sets the pace: a dispatch waits for a free slot, an upload
+for the transfer, a read-back for the step before it. So
+
+  - a span marked `wait=True` brackets ONE call through which the host
+    can block on the device or on a transfer, and no work of the
+    program's own. On exit it adds its elapsed seconds to a per-thread
+    tally (`_WAITED.s`, monotone);
+  - a span given a `work` histogram reads that tally at entry and at
+    exit and observes `elapsed - growth` into `work`: its time outside
+    every wait beneath it, at any depth, on its own thread. `hist`
+    keeps the whole elapsed time, from the same two stamps, so
+    `hist.sum - work.sum` is exactly the waits beneath it;
+  - a span with neither touches no tally (the serve path's brackets);
+    under `--sys.metrics 0` the server hands out neither.
+
+A bracketed call also does host work that Python cannot split off (a
+`device_put` copies into a staging buffer before it returns, a jit call
+flattens its operands), so a wait span is an UPPER bound of waiting and
+`*_work_s` a LOWER bound of the host's own (PERF.md section 3 has the
+floor, measured with nothing in flight).
 
 Rule for names and nesting: no span may enclose a whole pass, epoch or
 step loop — the outermost span on a thread is ONE phase of a step or of
@@ -55,32 +77,55 @@ from jax.profiler import TraceAnnotation
 _BREADCRUMB_WIDTH = 256
 
 
+class _Waited(threading.local):
+    """Seconds this thread has spent inside wait spans (monotone)."""
+    s = 0.0
+
+
+_WAITED = _Waited()
+
+
 class Span(TraceAnnotation):
     """The phase bracket (module docstring): profiler annotation always,
     histogram observation when `hist` is given, `SpanTracer` record
-    when the server holds a tracer."""
+    when the server holds a tracer; `wait` adds the elapsed seconds to
+    the thread's wait tally, `work` observes the elapsed seconds less
+    the tally's growth."""
 
-    __slots__ = ("_name", "_hist", "_tracer", "_t0")
+    __slots__ = ("_name", "_hist", "_tracer", "_t0", "_wait", "_work",
+                 "_w0", "_timed")
 
     def __init__(self, name: str, hist=None,
-                 tracer: Optional["SpanTracer"] = None):
+                 tracer: Optional["SpanTracer"] = None,
+                 wait: bool = False, work=None):
         super().__init__("adapm." + name)
         self._name = name
         self._hist = hist
         self._tracer = tracer
-        self._t0 = 0.0
+        self._wait = wait
+        self._work = work
+        self._timed = hist is not None or wait or work is not None
+        self._t0 = self._w0 = 0.0
 
     def __enter__(self):
         super().__enter__()
         if self._tracer is not None:
             self._t0 = self._tracer.begin(self._name)
-        elif self._hist is not None:
+        elif self._timed:
             self._t0 = time.perf_counter()
+        if self._work is not None:
+            self._w0 = _WAITED.s
         return self
 
     def __exit__(self, *exc):
-        if self._hist is not None:
-            self._hist.observe(time.perf_counter() - self._t0)
+        if self._timed:
+            dt = time.perf_counter() - self._t0
+            if self._hist is not None:
+                self._hist.observe(dt)
+            if self._wait:
+                _WAITED.s += dt
+            if self._work is not None:
+                self._work.observe(dt - (_WAITED.s - self._w0))
         if self._tracer is not None:
             self._tracer.end(self._name, self._t0)
         super().__exit__(*exc)

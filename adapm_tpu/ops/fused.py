@@ -186,13 +186,21 @@ class DeviceRouter:
                                              shared=True)
         self._c_upload = server.obs.counter(
             "fused.route_upload_bytes_total", unit="bytes", shared=True)
+        # of a refresh, the uploads (the wait span `fused.route_upload`
+        # around each `put_replicated`) and the rest: the host's own
+        # snapshots and index building (`work=`, obs/spans.py)
+        self._h_upload = server.obs.histogram("fused.route_upload_s",
+                                              shared=True)
+        self._h_refresh_work = server.obs.histogram(
+            "fused.route_refresh_work_s", shared=True)
 
     def _put_counted(self, arr):
         """`put_replicated` of one mirror, its bytes counted once for
         each device it lands on."""
-        ctx = self.server.ctx
-        self._c_upload.inc(arr.nbytes * ctx.num_shards)
-        return ctx.put_replicated(arr)
+        srv = self.server
+        self._c_upload.inc(arr.nbytes * srv.ctx.num_shards)
+        with srv._span("fused.route_upload", self._h_upload, wait=True):
+            return srv.ctx.put_replicated(arr)
 
     def refresh(self):
         srv = self.server
@@ -200,7 +208,8 @@ class DeviceRouter:
                srv.tier.epoch if srv.tier is not None else -1)
         if self._version == ver and self.owner is not None:
             return
-        with srv._span("fused.route_refresh", self._h_refresh):
+        with srv._span("fused.route_refresh", self._h_refresh,
+                       work=self._h_refresh_work):
             self._refresh(ver)
         self._c_refresh.inc()
 
@@ -212,7 +221,9 @@ class DeviceRouter:
         # device_put reads its host buffer until the transfer is done,
         # and with rounds on the prefetch thread the next relocation can
         # land before that. A step then routed by placement it was not
-        # ordered after, and read a row's old (or still empty) place
+        # ordered after, and read a row's old (or still empty) place.
+        # The copy is host work: it is made before `_put_counted`'s
+        # wait span opens
         put = lambda arr: self._put_counted(np.array(arr))  # noqa: E731
         self.owner = put(ab.owner)
         # tiered storage: the step indexes the DEVICE hot pool, so the
@@ -844,6 +855,23 @@ class DeviceRoutedRunner:
                                                 shared=True)
         self._h_key_upload = server.obs.histogram("fused.key_upload_s",
                                                   shared=True)
+        # of a dispatch: the compiled program's call (the wait span
+        # `fused.enqueue`: it returns once the program is queued, so
+        # what it holds beyond that is the wait for a free dispatch
+        # slot) and the dispatch less every wait beneath it (`work=`)
+        self._h_enqueue = server.obs.histogram("fused.enqueue_s",
+                                               shared=True)
+        self._h_dispatch_work = server.obs.histogram(
+            "fused.dispatch_work_s", shared=True)
+        # the server's dispatched steps not yet done on the device, read
+        # at each step or scan dispatch before its enqueue: near 0 with
+        # a busy host, the host paces the run (a scan dispatch counts
+        # once). The handles are the steps' losses, shared by the
+        # server's runners (Server._steps_in_flight; None: registry off)
+        self._h_inflight = server.obs.histogram(
+            "fused.inflight_steps", unit="steps",
+            bounds=(0, 1, 2, 4, 8, 16, 32, 64, 128), shared=True)
+        self._inflight = server._steps_in_flight
         # rows the dispatched steps write back, and those of them in a
         # variant compiled with the Pallas write-back kernel
         # (writeback_uses_kernel): how often the kernel engages
@@ -1027,8 +1055,8 @@ class DeviceRoutedRunner:
         """Host -> device upload of one dispatch's key arrays (already
         in the key dtype), replicated: the staging rule, mesh.py."""
         srv = self.server
-        with srv._span("fused.key_upload", self._h_key_upload):
-            put = srv.ctx.put_replicated
+        put = srv.ctx.put_replicated
+        with srv._span("fused.key_upload", self._h_key_upload, wait=True):
             return {r: put(k) for r, k in host_keys.items()}
 
     def _next_rng(self):
@@ -1115,16 +1143,16 @@ class DeviceRoutedRunner:
         time and every _drain_every steps —
         chosen so the int32 params counter stays below 2^30 between
         drains."""
-        with self.server._span("fused.locstat_drain"):
+        with self.server._span("fused.locstat_drain", wait=True):
             vals = np.asarray(self._locstat, dtype=np.int64)
-            self._loc_host += vals
-            self._c_rows.inc(int(vals[0]))
-            self._c_rows_local.inc(int(vals[1]))
-            self._c_rows_sampled.inc(self._sampled_pending)
-            self._sampled_pending = 0
-            self._locstat = self.server.ctx.put_replicated(
-                np.zeros(4, np.int32))
-            self._c_drains.inc()
+        self._loc_host += vals
+        self._c_rows.inc(int(vals[0]))
+        self._c_rows_local.inc(int(vals[1]))
+        self._c_rows_sampled.inc(self._sampled_pending)
+        self._sampled_pending = 0
+        self._locstat = self.server.ctx.put_replicated(
+            np.zeros(4, np.int32))
+        self._c_drains.inc()
 
     def locality_counts(self) -> Dict[str, int]:
         """Cumulative step-program access counts, host-side (the device-
@@ -1159,7 +1187,8 @@ class DeviceRoutedRunner:
             # part of what a placement change costs: the same span and
             # counters as the table mirrors (DeviceRouter.refresh)
             router = self.router
-            with srv._span("fused.route_refresh", router._h_refresh):
+            with srv._span("fused.route_refresh", router._h_refresh,
+                           work=router._h_refresh_work):
                 self._build_local_neg_index()
             self._li_version = li_ver
             router._c_refresh.inc()
@@ -1290,7 +1319,8 @@ class DeviceRoutedRunner:
     def __call__(self, role_keys: Dict[str, np.ndarray], aux, lr: float,
                  eps: float = 1e-10,
                  staged: Optional[StagedKeys] = None) -> jnp.ndarray:
-        with self.server._span("fused.dispatch", self._h_dispatch):
+        with self.server._span("fused.dispatch", self._h_dispatch,
+                               work=self._h_dispatch_work):
             return self._dispatch_step(role_keys, aux, lr, eps, staged)
 
     def _dispatch_step(self, role_keys, aux, lr, eps, staged):
@@ -1325,13 +1355,16 @@ class DeviceRoutedRunner:
             # dispatch under the gate, tracked on the "main" stream for
             # the executor's overlap accounting (enqueue-only: the jit
             # call returns as soon as the program is queued)
+            lr, eps = self._scalar(lr), self._scalar(eps)
+            self._observe_in_flight()
             with srv.exec.track("main"), _GATE:
-                pools, self._locstat, loss = fn(
-                    pools, self._locstat, tables, keys, local_index,
-                    self._alias, sub, aux, self._scalar(lr),
-                    self._scalar(eps))
+                with srv._span("fused.enqueue", self._h_enqueue, wait=True):
+                    pools, self._locstat, loss = fn(
+                        pools, self._locstat, tables, keys, local_index,
+                        self._alias, sub, aux, lr, eps)
                 for st, (m, c, d) in zip(srv.stores, pools):
                     st.main, st.cache, st.delta = m, c, d
+            self._note_in_flight(loss)
             self.steps += 1
             self._count_step(role_keys, 1)
             self._count_sampled(1)
@@ -1339,6 +1372,23 @@ class DeviceRoutedRunner:
             if self.steps % self._drain_every == 0:
                 self._drain_locstat()
         return loss
+
+    def _observe_in_flight(self) -> None:
+        """`fused.inflight_steps`: of the server's dispatched steps,
+        those the device has not finished (caller holds the server
+        lock, before its own enqueue). Steps finish in dispatch order,
+        so the done ones are popped from the left; `is_ready` never
+        blocks."""
+        dq = self._inflight
+        if dq is None:
+            return
+        while dq and dq[0].is_ready():
+            dq.popleft()
+        self._h_inflight.observe(len(dq))
+
+    def _note_in_flight(self, loss) -> None:
+        if self._inflight is not None:
+            self._inflight.append(loss)
 
     def _score_program(self, no_replicas: bool):
         """The compiled score program of one variant, kept in `programs`
@@ -1379,9 +1429,13 @@ class DeviceRoutedRunner:
                 pools = tuple((s.main, s.cache, s.delta)
                               for s in srv.stores)
                 fn = self._score_program(not self._shard_has_replicas())
-                with srv.exec.track("main"), _GATE:
-                    acc = fn(pools, tables, keys, aux,
-                             self._scalar(0.0) if acc is None else acc)
+                if acc is None:
+                    acc = self._scalar(0.0)
+                # no histogram: `fused.enqueue_s` counts step and scan
+                # dispatches, as `fused.dispatch_s` does
+                with srv.exec.track("main"), _GATE, \
+                        srv._span("fused.enqueue", wait=True):
+                    acc = fn(pools, tables, keys, aux, acc)
                 self._c_score_rows.inc(
                     sum(np.asarray(k).size for k in role_keys.values()))
         return acc
@@ -1398,7 +1452,8 @@ class DeviceRoutedRunner:
         planner's changes apply between scans, matching the apps'
         lookahead contract. `auxes` is a list of per-step aux pytrees, or
         None when the loss takes no aux."""
-        with self.server._span("fused.dispatch", self._h_dispatch):
+        with self.server._span("fused.dispatch", self._h_dispatch,
+                               work=self._h_dispatch_work):
             return self._dispatch_scan(batches, auxes, lr, eps)
 
     def _dispatch_scan(self, batches, auxes, lr, eps):
@@ -1451,13 +1506,16 @@ class DeviceRoutedRunner:
             if self._one_program_over_shards():  # as _step_program
                 variant["neg_local"] = False
             fn = self._scan_fn(**variant)
+            lr, eps = self._scalar(lr), self._scalar(eps)
+            self._observe_in_flight()
             with srv.exec.track("main"), _GATE:
-                pools, self._locstat, losses = fn(
-                    pools, self._locstat, tables, keys, local_index,
-                    self._alias, rngs, aux, self._scalar(lr),
-                    self._scalar(eps))
+                with srv._span("fused.enqueue", self._h_enqueue, wait=True):
+                    pools, self._locstat, losses = fn(
+                        pools, self._locstat, tables, keys, local_index,
+                        self._alias, rngs, aux, lr, eps)
                 for st, (m, c, d) in zip(srv.stores, pools):
                     st.main, st.cache, st.delta = m, c, d
+            self._note_in_flight(losses)
             self.steps += K
             self._count_step(batches[0], K)
             self._count_sampled(K)

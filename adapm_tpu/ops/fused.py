@@ -36,7 +36,8 @@ without `neg_role` takes its negatives from the caller like any role.
 """
 from __future__ import annotations
 
-from typing import Callable, Dict, List, Optional, Sequence, Tuple
+from typing import (Callable, Dict, List, NamedTuple, Optional, Sequence,
+                    Tuple)
 
 import jax
 import jax.numpy as jnp
@@ -79,17 +80,111 @@ def _mark_fused_writes(server, shard: int, role_class, role_keys,
             shard, ab.cache_slot[shard, k], ab.owner[k], ab.slot[k])
 
 
-def _read_rows(main, cache, delta, route):
-    g_sh, g_sl, c_sh, c_sl, use_c = route
-    m = main.at[g_sh, g_sl].get(mode="fill", fill_value=0)
-    c = (cache.at[c_sh, c_sl].get(mode="fill", fill_value=0)
-         + delta.at[c_sh, c_sl].get(mode="fill", fill_value=0))
-    return jnp.where(use_c[..., None], c, m)
+# Replica positions one chunk of the replica variant's side path takes
+# (`_replica_side`): a role of no more positions than this is one chunk
+# whatever it holds, the 131,072 negatives of a KGE step whose replicas
+# number several hundred are one chunk of this many rows beside the
+# row-wide gather from main. A chunk costs by its rows, replicas or
+# padding alike, and one chunk more costs nothing measurable (on a v5e
+# at 8 KB rows 1,024 beat 2,048 and 4,096: PERF.md section 6, PR 36).
+SIDE_ROWS = 1024
+
+
+def _chunk_rows(n: int) -> int:
+    """Positions one chunk of the side path takes, of a role of `n`."""
+    return min(n, SIDE_ROWS)
+
+
+class _ReplicaSide(NamedTuple):
+    """The replica positions of one role in one step, compacted: what
+    the replica variant's side path walks (`_for_replica_chunks`), the
+    only code of a step that indexes the cache and delta pools."""
+    sh: jnp.ndarray     # [n] the replicas' shard, by flat position
+    sl: jnp.ndarray     # [n] their slot; out of bounds: no replica here
+    here: jnp.ndarray   # [n] whether the position reads its replica
+    order: jnp.ndarray  # the replica positions ascending, then `n`s
+    count: jnp.ndarray  # how many there are, an int32 scalar
+
+
+def _replica_side(cache, c_sh, c_sl) -> _ReplicaSide:
+    """Compact a role's replica positions: those whose replica
+    coordinates `(c_sh, c_sl)` lie inside the pool `cache` (and so
+    inside `delta`, its twin). `_route_on_device` sends every other
+    position's slot out of bounds, and `_on_this_chip` those of a
+    replica that another chip holds."""
+    sh, sl = c_sh.reshape(-1), c_sl.reshape(-1)
+    n = sl.shape[0]
+    here = _in_bounds(sh, cache.shape[0]) & _in_bounds(sl, cache.shape[1])
+    order = jnp.sort(jnp.where(here, jax.lax.iota(jnp.int32, n), n))
+    # whole chunks: a slice of the last one must not slide back
+    order = jnp.pad(order, (0, -n % _chunk_rows(n)), constant_values=n)
+    return _ReplicaSide(sh, sl, here, order,
+                        jnp.sum(here, dtype=jnp.int32))
+
+
+def _replica_chunks(count, n: int):
+    """Chunks of the side path that `count` replica positions among `n`
+    take."""
+    return (count + (_chunk_rows(n) - 1)) // _chunk_rows(n)
+
+
+def _for_replica_chunks(side: _ReplicaSide, fn, carry):
+    """`carry = fn(idx, sh, sl, carry)` for each chunk of the role's
+    replica positions, in their order: `idx` are `_chunk_rows(n)`
+    flat positions and `(sh, sl)` their replica coordinates; past the
+    last replica position `idx` is `n` and `sl` out of bounds, for `fn`
+    to drop. A loop whose count the program reads from its input: none
+    where the role holds no replica here (every chip but the worker's),
+    one while the replica positions fit a chunk, as many as it takes
+    otherwise. The carry (the gathered rows, the delta pool) is updated
+    in place."""
+    n = side.sl.shape[0]
+    k = _chunk_rows(n)
+
+    def chunk(t, carry):
+        idx = jax.lax.dynamic_slice(side.order, (t * k,), (k,))
+        sh = side.sh.at[idx].get(mode="clip")
+        sl = side.sl.at[idx].get(mode="fill", fill_value=OOB)
+        return fn(idx, sh, sl, carry)
+
+    return jax.lax.fori_loop(0, _replica_chunks(side.count, n), chunk,
+                             carry)
+
+
+def _patch_replica_rows(rows, cache, delta, side: _ReplicaSide):
+    """The rows gathered from main with `cache + delta` at the role's
+    replica positions: the replica's value as a Pull reads it."""
+    L = rows.shape[-1]
+
+    def patch(idx, sh, sl, flat):
+        value = cache.at[sh, sl].get(mode="fill", fill_value=0) \
+            + delta.at[sh, sl].get(mode="fill", fill_value=0)
+        return flat.at[idx].set(value, mode="drop")
+
+    return _for_replica_chunks(side, patch, rows.reshape(-1, L)) \
+        .reshape(rows.shape)
+
+
+def _replica_writeback(delta, side: _ReplicaSide, g, acc, lr, eps):
+    """The additive AdaGrad updates of the role's replica positions into
+    the delta pool (replica writes land there and flow back through sync
+    rounds): update rows are formed for a chunk's positions only."""
+    g, acc = (x.reshape(-1, x.shape[-1]) for x in (g, acc))
+
+    def add(idx, sh, sl, delta):
+        upd = _adagrad_update(g.at[idx].get(mode="clip"),
+                              acc.at[idx].get(mode="clip"), lr, eps)
+        return delta.at[sh, sl].add(upd, mode="drop")
+
+    with jax.named_scope("adapm_scatter_add"):
+        return _for_replica_chunks(side, add, delta)
 
 
 def writeback_uses_kernel(main, backend: str = None) -> bool:
-    """Which row-mover the replica-free write-back into this pool is
-    compiled with: the Pallas kernel (pallas_kernels
+    """Which row-mover the write-back into this MAIN pool is compiled
+    with, in either variant (the replica variant's delta rows, a
+    side-path chunk at a time, are always XLA's: `_replica_writeback`):
+    the Pallas kernel (pallas_kernels
     .scatter_adagrad_sorted_rows, which forms the AdaGrad update rows
     itself) where the backend (jax's default unless given) is a TPU and
     the pool is ONE float32 shard on the step's device (`[1, slots, L]`:
@@ -347,8 +442,8 @@ def make_device_routed_step(loss_fn: Callable[..., jnp.ndarray],
     are DRAWN in-program: uniform positions into local_index — the Local
     sampling scheme (core/sampling.py LocalSampling) executed on device.
     The draw has the shape `neg_shape` = `[B, N]` and the loss is handed
-    `[B, N, dim]` rows; in between the replica-free variant lays the
-    role out SAMPLE-MAJOR (`[N, B, .]`: keys, routes, gathered rows,
+    `[B, N, dim]` rows; in between the step lays the role out
+    SAMPLE-MAJOR (`[N, B, .]`: keys, routes, gathered rows,
     accumulators, gradients), so that no reshape of its rows is a copy
     whatever N is (`_build_device_routed_body`).
 
@@ -363,15 +458,18 @@ def make_device_routed_step(loss_fn: Callable[..., jnp.ndarray],
     where placement changes (DeviceRoutedRunner._snap_table) and the
     program searches nothing.
 
-    `no_replicas=True` compiles the replica-free specialization: reads touch
-    only the main pool (1/3 of the gather traffic) and updates scatter only
-    into main. Legal exactly while this shard holds zero replicas — the
-    runner re-checks per step and switches variants (HBM bandwidth is the
-    roofline for embedding workloads, so this is a large win whenever the
-    planner hasn't replicated anything here). Its gather is clamped and
-    its out-of-bounds mask covers the embedding columns only
-    (`_route_and_gather`); the replica variant masks whole rows, three
-    times (`_read_rows`).
+    `no_replicas=True` compiles the replica-free specialization: reads
+    touch only the main pool and updates scatter only into main. Legal
+    exactly while this shard holds zero replicas — the runner re-checks
+    per step and switches variants. The replica variant
+    (`no_replicas=False`) is the same data path over main for EVERY
+    position (one clamped gather a role, the out-of-bounds mask on the
+    embedding columns only: `_route_and_gather`) plus a side path that
+    alone touches the cache and delta pools: the positions whose key the
+    worker's shard holds a replica of are compacted and read from
+    `cache + delta`, and written to `delta`, a chunk of `SIDE_ROWS` rows
+    at a time (`_replica_side`), so it pays for replicas at the replica
+    positions and costs the replica-free step plus those chunks.
 
     Pools of several shards get the step as a per-chip program
     (`_PoolProgram`, `_build_device_routed_body`), which counts on the
@@ -384,7 +482,7 @@ def make_device_routed_step(loss_fn: Callable[..., jnp.ndarray],
         return _build_device_routed_body(
             loss_fn, role_class, role_dim, frozen_roles, neg_role,
             neg_shape, no_replicas, neg_alias, axis=axis)
-    # donate the pools only: donating the 4-scalar locstat accumulator
+    # donate the pools only: donating the few-scalar locstat accumulator
     # saves nothing and its aliased buffer has been observed returning
     # stale/garbage counts on the multi-device CPU backend (flaky
     # locality_counts mismatches in test_device_routed)
@@ -477,65 +575,80 @@ def _route_and_gather(pools, tables, keys, roles, role_class, role_dim,
                       no_replicas, axis=None):
     """Route every role's keys and gather their rows: the read half of a
     fused step, shared with the gather-only score program
-    (`make_device_routed_score`). Returns (embs, accs, routes, n_total,
-    n_local): each role's embedding columns and accumulator columns in
-    the shape of its keys (a sampled role's are sample-major, `[N, B]`,
-    in the replica-free variant: `_build_device_routed_body`), and the
-    device-side locality counts
+    (`make_device_routed_score`). Returns (embs, accs, routes, counts):
+    each role's embedding columns and accumulator columns in the shape
+    of its keys (a sampled role's are sample-major, `[N, B]`:
+    `_build_device_routed_body`), its route
+    (main's shard and slot; in the replica variant the compacted replica
+    positions too, a `_ReplicaSide`), and the step's counts (n_total,
+    n_local, n_replica, n_chunks): the device-side locality counts
     (reference coloc_kv_server.h:147-157 prints % accesses served
     locally; Pull/Push record this in Server._route, which a step never
-    visits): a key access is local when this worker's shard owns the row
-    or holds a replica.
+    visits: a key access is local when this worker's shard owns the row
+    or holds a replica), the positions that read a replica, and the
+    side-path chunks those take.
 
     A position whose route is out of bounds (`OOB`: a cold tier row, a
-    padding position) reads as a ZERO embedding. The replica-free
-    variant gathers with `mode="clip"` and zeroes the embedding columns
-    alone, where the loss reads them, so no row-wide mask crosses HBM;
-    the accumulator columns of such a position are whatever row the
-    clamp found, and nobody reads them: the write-back drops the
-    position (its code is -1 in the kernel, `mode="drop"` in XLA's
-    scatter-add).
+    padding position) reads as a ZERO embedding. Either variant gathers
+    main ONCE for all positions with `mode="clip"` and zeroes the
+    embedding columns alone, where the loss reads them, so no row-wide
+    mask crosses HBM; the accumulator columns of such a position are
+    whatever row the clamp found, and nobody reads them: the write-back
+    drops the position (its code is -1 in the kernel, `mode="drop"` in
+    XLA's scatter-add). In the replica variant main's slot is out of
+    bounds at the replica positions (`_route_on_device`); those are
+    compacted (`_replica_side`) and their rows patched in from
+    `cache + delta`, a chunk at a time (`_patch_replica_rows`): the only
+    reads of the two replica pools, `SIDE_ROWS` rows each.
 
     With `axis` (the per-chip step, `_build_device_routed_body`) the
     pools are this chip's blocks and each route is brought onto the chip
     (`_on_this_chip`) before the same gather: the rows and the returned
     routes are the chip's own, zeros and out of bounds for what lies
-    elsewhere. The locality counts come from the global routes, so every
-    chip holds the same."""
+    elsewhere. The counts come from the global routes, so every chip
+    holds the same (the replica positions are the worker chip's: the
+    other chips find none of them and run no chunk)."""
     embs, accs, routes = {}, {}, {}
     n_total = 0
-    n_local = jnp.int32(0)
+    n_local = n_replica = n_chunks = jnp.int32(0)
     shard = tables[3]  # the worker's, an int32 scalar operand
     for r in roles:
         cid = role_class[r]
         main, cache, delta = pools[cid]
         dim = role_dim[r]
         n_total += keys[r].size
-        if no_replicas:
-            owner, slot = tables[:2]
-            with jax.named_scope("adapm_route"):
-                o_sh, o_sl = whole = owner[keys[r]], slot[keys[r]]
-                if axis is not None:
-                    o_sh, o_sl = _on_this_chip((o_sh, o_sl), axis)
+        with jax.named_scope("adapm_route"):
+            if no_replicas:
+                owner, slot = tables[:2]
+                route = whole = owner[keys[r]], slot[keys[r]]
+            else:
+                route = whole = _route_on_device(tables, keys[r])
+            if axis is not None:
+                route = _on_this_chip(whole, axis)
+            o_sh, o_sl = route[:2]
+        with jax.named_scope("adapm_gather"):
+            rows = main.at[o_sh, o_sl].get(mode="clip")
+            valid = _in_bounds(o_sh, main.shape[0]) \
+                & _in_bounds(o_sl, main.shape[1])
             routes[r] = (o_sh, o_sl)
-            with jax.named_scope("adapm_gather"):
-                rows = main.at[o_sh, o_sl].get(mode="clip")
-                valid = _in_bounds(o_sh, main.shape[0]) \
-                    & _in_bounds(o_sl, main.shape[1])
-                embs[r] = jnp.where(valid[..., None], rows[..., :dim], 0)
-            local = whole[0] == shard
-        else:
-            with jax.named_scope("adapm_route"):
-                routes[r] = whole = _route_on_device(tables, keys[r])
-                if axis is not None:
-                    routes[r] = _on_this_chip(whole, axis)
-            with jax.named_scope("adapm_gather"):
-                rows = _read_rows(main, cache, delta, routes[r])
-            embs[r] = rows[..., :dim]
-            local = whole[4] | (whole[0] == shard)  # use_c, o_sh
+            if not no_replicas:
+                side = _replica_side(cache, *route[2:4])
+                rows = _patch_replica_rows(rows, cache, delta, side)
+                valid |= side.here.reshape(valid.shape)
+                routes[r] += (side,)
+            embs[r] = jnp.where(valid[..., None], rows[..., :dim], 0)
+        local = whole[0] == shard
         accs[r] = rows[..., dim:]
+        if not no_replicas:
+            use_c = whole[4]
+            local |= use_c
+            # counted on the global route, like the locality counts: the
+            # worker chip's side path, and the same number on every chip
+            held = jnp.sum(use_c, dtype=jnp.int32)
+            n_replica += held
+            n_chunks += _replica_chunks(held, use_c.size)
         n_local += jnp.sum(local, dtype=jnp.int32)
-    return embs, accs, routes, n_total, n_local
+    return embs, accs, routes, (n_total, n_local, n_replica, n_chunks)
 
 
 def make_device_routed_score(score_fn: Callable[..., jnp.ndarray],
@@ -559,7 +672,7 @@ def make_device_routed_score(score_fn: Callable[..., jnp.ndarray],
 
     def build(axis):
         def score(pools, tables, keys, aux, acc):
-            embs, _, _, _, _ = _route_and_gather(
+            embs, _, _, _ = _route_and_gather(
                 pools, tables, dict(keys), roles, role_class, role_dim,
                 no_replicas, axis)
             if axis is not None:  # per chip: the rows it holds, summed
@@ -604,12 +717,24 @@ def _build_device_routed_body(loss_fn, role_class, role_dim,
         every chip.
 
     Inside the map a chip's main block is one shard, so the write-back
-    kernel applies (`writeback_uses_kernel`) in both variants; the
-    replica variant's delta rows, few and on the worker's chip, keep
-    XLA's scatter-add.
+    kernel applies (`writeback_uses_kernel`) in both variants.
 
-    In the replica-free variant no row-wide array is copied, padded or
-    masked as a whole between the gather and its readers. The sampled
+    The replica variant is the replica-free data path over main for
+    every position, and a side path for the positions that read a
+    replica (`_replica_side`: compacted, walked in chunks of `SIDE_ROWS`
+    by a loop whose count the program reads from its input). Main's
+    write-back is the same call in both variants (the kernel where it
+    applies, else `_adagrad_update` and XLA's scatter-add of row-wide
+    update rows): a replica position's slot in main is out of bounds,
+    so it is skipped there. The delta pool receives the update rows of
+    the replica positions alone, formed a chunk at a time
+    (`_replica_writeback`); they are few, on the worker's chip, and
+    XLA's scatter-add. So no variant that takes the kernel ever holds an
+    `[n, L]` array of update rows, and the cache and delta pools are
+    never indexed by all positions.
+
+    In either variant no row-wide array is copied, padded or masked
+    as a whole between the gather and its readers. The sampled
     role's keys are drawn in `neg_shape` = `[B, N]` (the draw is the
     caller's to mirror) and TRANSPOSED to `[N, B]` before they are
     routed: routes, gathered rows, accumulators and gradients of that
@@ -622,13 +747,12 @@ def _build_device_routed_body(loss_fn, role_class, role_dim,
     Among positions that name ONE row the write-back adds in the order
     of the flattened routes: `(k, b)` for the sampled role. The
     out-of-bounds mask sits on the embedding columns
-    (`_route_and_gather`). The replica variant keeps the batch-major
-    order: it masks and selects whole rows three times over
-    (`_read_rows`), flattens nothing for XLA's scatter-add, and on the
-    chip the view cost it a half-row copy the loss had read in place."""
+    (`_route_and_gather`). The replica variant's side path walks the
+    same flat positions, so a role's replica positions are patched and
+    written in that order too."""
     roles = sorted(role_class)
     trainable = [r for r in roles if r not in frozen_roles]
-    sample_major = no_replicas and neg_role is not None
+    sample_major = neg_role is not None
 
     def batch_major_loss(embs, aux):
         if sample_major:
@@ -661,7 +785,7 @@ def _build_device_routed_body(loss_fn, role_class, role_dim,
             if sample_major:
                 # sample-major from here on; the values at [b, k] stay
                 keys[neg_role] = jnp.moveaxis(keys[neg_role], -1, 0)
-        embs, accs, routes, n_total, n_local = _route_and_gather(
+        embs, accs, routes, counts = _route_and_gather(
             pools, tables, keys, roles, role_class, role_dim, no_replicas,
             axis)
         if axis is not None:
@@ -670,9 +794,13 @@ def _build_device_routed_body(loss_fn, role_class, role_dim,
                     {r: embs[r] for r in roles if r not in drawn}, axis))
         # one step = one (batched) pull op + one push op of the same keys;
         # the op counts local iff every key it touched was local
+        n_total, n_local, n_replica, n_chunks = counts
         all_local = (n_local == n_total).astype(jnp.int32)
+        # the accumulator takes as many of the step's counts as it has
+        # entries (`DeviceRoutedRunner._locstat`)
         locstat = locstat + jnp.stack(
-            [jnp.int32(n_total), n_local, jnp.int32(1), all_local])
+            [jnp.int32(n_total), n_local, jnp.int32(1), all_local,
+             n_replica, n_chunks][:locstat.shape[0]])
         loss, grads = _loss_and_grads(batch_major_loss, embs, trainable,
                                       aux)
         if axis is not None:
@@ -690,21 +818,18 @@ def _build_device_routed_body(loss_fn, role_class, role_dim,
             cid = role_class[r]
             main, cache, delta = new_pools[cid]
             # main's coordinates in either variant (the replica
-            # variant's slot is out of bounds at replica positions, and
-            # its replica slot c_sl at the others)
+            # variant's slot is out of bounds at replica positions)
             o_sh, o_sl = routes[r][:2]
-            kernel = writeback_uses_kernel(main)
-            if kernel:
+            if writeback_uses_kernel(main):
                 main = _kernel_writeback(main, o_sh, o_sl, grads[r],
                                          accs[r], lr, eps)
-            if not (kernel and no_replicas):
+            else:
                 upd = _adagrad_update(grads[r], accs[r], lr, eps)
                 with jax.named_scope("adapm_scatter_add"):
-                    if not kernel:
-                        main = main.at[o_sh, o_sl].add(upd, mode="drop")
-                    if not no_replicas:
-                        _, _, c_sh, c_sl, _ = routes[r]
-                        delta = delta.at[c_sh, c_sl].add(upd, mode="drop")
+                    main = main.at[o_sh, o_sl].add(upd, mode="drop")
+            if not no_replicas:
+                delta = _replica_writeback(delta, routes[r][2], grads[r],
+                                           accs[r], lr, eps)
             new_pools[cid] = (main, cache, delta)
         return tuple(new_pools), locstat, loss
 
@@ -743,8 +868,9 @@ class DeviceRoutedRunner:
     planner rounds, and `prefetch_keys` lets the app upload a future
     step's key batch ahead of its dispatch.
 
-    Locality is recorded by a 4-scalar device accumulator folded into the
-    step program (params seen / params local / steps / all-local steps) and
+    Locality is recorded by a small device accumulator folded into the
+    step program (params seen / params local / steps / all-local steps;
+    on several shards also replica positions / side-path chunks) and
     drained to the host lazily — at `locality_counts()` (which
     Server.locality_summary calls) and often enough that the int32 counters
     cannot wrap. Per-KEY counters (--sys.stats.locality tsv dumps) are
@@ -836,8 +962,15 @@ class DeviceRoutedRunner:
         self._rng_pool: list = []
         self._scalars: Dict[float, jnp.ndarray] = {}
         # device locality accumulator [params, params_local, ops, ops_local]
-        # (int32; drained before it can wrap — see _drain_locstat)
-        self._locstat = server.ctx.put_replicated(np.zeros(4, np.int32))
+        # (int32; drained before it can wrap — see _drain_locstat). On
+        # several shards two entries more: the replica variant's replica
+        # positions and the chunks its side path took for them
+        # (`_for_replica_chunks`). One shard holds no replica and
+        # compiles the replica-free variant alone, on the accumulator
+        # (and so to the program) it always had
+        self._locstat_zero = np.zeros(
+            4 if server.num_shards == 1 else 6, np.int32)
+        self._locstat = server.ctx.put_replicated(self._locstat_zero)
         self._loc_host = np.zeros(4, dtype=np.int64)
         self._drain_every = None  # set on first step (needs params/step)
         server._locality_sources.append(self.locality_counts)
@@ -904,6 +1037,14 @@ class DeviceRoutedRunner:
         self._c_rows_sampled = server.obs.counter(
             "fused.rows_sampled_total", unit="rows", shared=True)
         self._sampled_pending = 0  # since the last drain
+        # the accumulator's last two entries, moved at each drain too:
+        # positions the steps read from (and wrote to) a replica, and
+        # the side-path chunks that took; a chunk a role a step says
+        # `SIDE_ROWS` always sufficed
+        self._c_replica_positions = server.obs.counter(
+            "fused.replica_positions", unit="rows", shared=True)
+        self._c_replica_chunks = server.obs.counter(
+            "fused.replica_chunks", shared=True)
         # the worker's shard as the step's operand (the last of `tables`)
         self._shard_dev = server.ctx.put_replicated(np.int32(shard))
         self._mk_kwargs = dict(
@@ -912,8 +1053,8 @@ class DeviceRoutedRunner:
             neg_shape=neg_shape, neg_alias=self._alias is not None)
         self.step_fn = self._program(make_device_routed_step,
                                      no_replicas=False)
-        # replica-free specialization: 1/3 the gather traffic; selected per
-        # step while this shard holds no replicas
+        # replica-free specialization: no side path; selected per step
+        # while this shard holds no replicas
         self._step_fn_norep = self._program(make_device_routed_step,
                                             no_replicas=True)
         self._rep_version = -1
@@ -1145,13 +1286,15 @@ class DeviceRoutedRunner:
         drains."""
         with self.server._span("fused.locstat_drain", wait=True):
             vals = np.asarray(self._locstat, dtype=np.int64)
-        self._loc_host += vals
+        self._loc_host += vals[:4]
         self._c_rows.inc(int(vals[0]))
         self._c_rows_local.inc(int(vals[1]))
         self._c_rows_sampled.inc(self._sampled_pending)
         self._sampled_pending = 0
-        self._locstat = self.server.ctx.put_replicated(
-            np.zeros(4, np.int32))
+        for c, v in zip((self._c_replica_positions,
+                         self._c_replica_chunks), vals[4:]):
+            c.inc(int(v))
+        self._locstat = self.server.ctx.put_replicated(self._locstat_zero)
         self._c_drains.inc()
 
     def locality_counts(self) -> Dict[str, int]:

@@ -28,15 +28,53 @@ def _make(num_keys=24, L=8):
     return srv, w
 
 
-def test_matches_numpy_adagrad():
+def _replicate_on_shard0(srv, w, monkeypatch):
+    """Replicas on shard 0 of (up to its 8 replica slots) keys that other
+    shards own, and a side-path chunk of 4 positions, so that a role's 16
+    positions can take several chunks."""
+    from adapm_tpu.base import MgmtTechniques
+    from adapm_tpu.ops import fused
+    monkeypatch.setattr(fused, "SIDE_ROWS", 4)
+    srv.opts.techniques = MgmtTechniques.REPLICATION_ONLY
+    remote = np.flatnonzero(srv.ab.owner[:24] != 0)[:8]
+    w.intent(remote, 0, CLOCK_MAX)
+    srv.wait_sync()
+    assert srv.ab.has_replica(remote, 0).all()
+
+
+def _replica_positions_and_chunks(srv, batch, chunk=4):
+    """The host's own count for one step of worker 0: positions whose
+    key shard 0 holds a replica of, and the chunks of `chunk` they take,
+    role by role."""
+    held = [int((srv.ab.cache_slot[0, k] >= 0).sum())
+            for k in batch.values()]
+    return sum(held), sum(-(-h // chunk) for h in held)
+
+
+def _replica_counters(srv):
+    return (srv.obs.find("fused.replica_positions").snap(),
+            srv.obs.find("fused.replica_chunks").snap())
+
+
+@pytest.mark.parametrize("replicas", [False, True])
+def test_matches_numpy_adagrad(replicas, monkeypatch):
     """24 keys x 5 steps against the numpy AdaGrad reference: losses and
-    every row of the table."""
+    every row of the table, and the score program's sum over the last
+    batch. With `replicas` shard 0 holds replicas of eight keys: the
+    steps read them as cache + delta (their own writes), write their
+    updates to delta alone (main moves at the quiesce), and the two
+    counters of the side path read the host's own count."""
     kw = dict(role_class={"a": 0, "b": 0}, role_dim={"a": 4, "b": 4})
     srv, w = _make()
-    dev = DeviceRoutedRunner(srv, _loss, shard=0, **kw)
+    if replicas:
+        _replicate_on_shard0(srv, w, monkeypatch)
+    dev = DeviceRoutedRunner(
+        srv, _loss, shard=0, **kw,
+        score_fn=lambda embs, aux: (embs["a"] * embs["b"]).sum())
     want = srv.read_main(np.arange(24)).reshape(24, 8).copy()
 
     rng = np.random.default_rng(1)
+    held = chunks = 0
     for _ in range(5):
         batch = {"a": rng.integers(0, 24, 16).astype(np.int64),
                  "b": rng.integers(0, 24, 16).astype(np.int64)}
@@ -47,6 +85,19 @@ def test_matches_numpy_adagrad():
         d_dot = 2 * dot[:, None] / len(dot)
         numpy_adagrad(want, 4, batch, {"a": d_dot * b, "b": d_dot * a},
                       0.1)
+        h, c = _replica_positions_and_chunks(srv, batch)
+        held, chunks = held + h, chunks + c
+    assert (held > 20 and chunks > 10) if replicas else held == 0
+    score = float(dev.score(batch, None))
+    assert np.isclose(score, (want[batch["a"], :4]
+                              * want[batch["b"], :4]).sum(), rtol=1e-5)
+    dev.locality_counts()  # the drain moves the counters
+    assert _replica_counters(srv) == (held, chunks)
+    if replicas:
+        main = srv.read_main(np.arange(24)).reshape(24, 8)
+        mine = srv.ab.cache_slot[0, :24] >= 0
+        assert not np.allclose(main[mine], want[mine], atol=1e-6)
+        srv.quiesce()
     got = srv.read_main(np.arange(24)).reshape(24, 8)
     np.testing.assert_allclose(got, want, atol=1e-6, rtol=0)
     srv.shutdown()
@@ -386,14 +437,20 @@ def test_w2v_app_learns_past_its_first_epoch(tmp_path):
     assert last < 0.85 * first, (last, first)
 
 
-def test_run_scan_matches_sequential_steps():
+@pytest.mark.parametrize("replicas", [False, True])
+def test_run_scan_matches_sequential_steps(replicas, monkeypatch):
     """K steps in one lax.scan dispatch (run_scan, VERDICT r3 item 2) must
     produce exactly the same pools and losses as K sequential __call__
-    steps (same RNG pool order, same routing)."""
+    steps (same RNG pool order, same routing); with `replicas` both
+    through the replica variant's side path, several chunks a role, and
+    its counters read the host's count either way."""
     kw = dict(role_class={"a": 0, "b": 0}, role_dim={"a": 4, "b": 4})
-    srv1, _ = _make()
+    srv1, w1 = _make()
+    srv2, w2 = _make()
+    if replicas:
+        _replicate_on_shard0(srv1, w1, monkeypatch)
+        _replicate_on_shard0(srv2, w2, monkeypatch)
     seq = DeviceRoutedRunner(srv1, _loss, shard=0, **kw)
-    srv2, _ = _make()
     scn = DeviceRoutedRunner(srv2, _loss, shard=0, **kw)
 
     rng = np.random.default_rng(7)
@@ -404,11 +461,17 @@ def test_run_scan_matches_sequential_steps():
     scan_losses = np.asarray(scn.run_scan(batches, None, 0.1))
     assert np.allclose(scan_losses, seq_losses, rtol=1e-5), \
         (scan_losses, seq_losses)
+    # locality accounting covers the whole window
+    assert scn.locality_counts() == seq.locality_counts()
+    counted = tuple(map(sum, zip(*(
+        _replica_positions_and_chunks(srv1, b) for b in batches))))
+    assert (counted[0] > 16) is replicas
+    assert _replica_counters(srv1) == _replica_counters(srv2) == counted
+    srv1.quiesce()
+    srv2.quiesce()
     v1 = srv1.read_main(np.arange(24))
     v2 = srv2.read_main(np.arange(24))
     assert np.allclose(v1, v2, atol=1e-5)
-    # locality accounting covers the whole window
-    assert scn.locality_counts() == seq.locality_counts()
     srv1.shutdown()
     srv2.shutdown()
 

@@ -179,6 +179,12 @@ def test_per_chip_step_equals_the_step_over_global_pools(
         assert ((got[i] == start[i]).all(axis=2) == untouched).all()
     for i in set(range(3)) - set(changed):
         assert got[i].tobytes() == start[i].tobytes() == want[i].tobytes()
+    if not no_replicas:
+        # the replicas that the OTHER shards hold (of their own workers)
+        # are no business of this worker's step: only its own shard's
+        # delta block moved
+        moved = (got[2] != start[2]).any(axis=(1, 2))
+        assert moved.tolist() == [s == shard for s in range(S)]
 
 
 def test_per_chip_step_exchanges_the_named_rows_only():

@@ -76,6 +76,7 @@ class _Recorder:
                 "keys": {r: np.asarray(k).astype(np.int64)
                          for r, k in keys.items()},
                 "local": np.asarray(idx)[:int(count)].astype(np.int64),
+                "cache_row": np.asarray(tables[2]),
                 "rng_key": rng_key, "loss": out[2]})
             return out
         return recorded
@@ -155,12 +156,32 @@ def test_relocation_only_four_workers_follow_the_reference():
         run.srv.shutdown()
 
 
-def test_one_worker_beside_replicas_follows_the_reference():
+def _replica_positions_and_chunks(steps, chunk):
+    """The host's own count over recorded steps: positions (named and
+    drawn) whose key the worker's shard held a replica of at the
+    dispatch, and the chunks of `chunk` they take, role by role."""
+    held = chunks = 0
+    for st in steps:
+        pos = np.asarray(jax.random.randint(
+            st["rng_key"], (B, N), 0, len(st["local"])))
+        for k in dict(st["keys"], neg=st["local"][pos]).values():
+            n = int((st["cache_row"][k] >= 0).sum())
+            held, chunks = held + n, chunks - (-n // min(k.size, chunk))
+    return held, chunks
+
+
+@pytest.mark.parametrize("side_rows", [4096, 8])
+def test_one_worker_beside_replicas_follows_the_reference(side_rows,
+                                                          monkeypatch):
     """Techniques all, replica pools small enough to overflow: worker 0
     alone, from a quiesced table in which the other workers hold
     intents on the head (so worker 0's own intents replicate those keys
     and relocate the rest), follows the reference step by step:
-    read-your-writes through cache + delta."""
+    read-your-writes through cache + delta. With a side-path chunk of 8
+    positions the replica positions of a role take several chunks; the
+    side path's two counters read the host's own count either way."""
+    from adapm_tpu.ops import fused
+    monkeypatch.setattr(fused, "SIDE_ROWS", side_rows)
     run = _open("all", cache_slots=16)
     try:
         srv, w0 = run.srv, run.workers[0]
@@ -187,6 +208,11 @@ def test_one_worker_beside_replicas_follows_the_reference():
         assert st.relocations > 0 and st.replicas_created > 0
         assert (srv.ab.owner[keys] != owner0[keys]).any()
         _compare(run, rec, init)
+        run.device_runner(w0.shard).locality_counts()  # the drain
+        held, chunks = _replica_positions_and_chunks(rec.steps, side_rows)
+        assert held > 12 * 8 and (chunks > 12 * 4) is (side_rows == 8)
+        assert srv.obs.find("fused.replica_positions").snap() == held
+        assert srv.obs.find("fused.replica_chunks").snap() == chunks
     finally:
         run.srv.shutdown()
 
@@ -394,6 +420,7 @@ def _counters(srv):
         "fused.route_refresh_total", "fused.route_upload_bytes_total",
         "fused.rows_total", "fused.rows_local_total",
         "fused.rows_sampled_total",
+        "fused.replica_positions", "fused.replica_chunks",
         "sync.relocations_total", "sync.replicas_created_total",
         "sync.replicas_dropped_total", "sync.keys_shipped_total",
         "sync.bytes_shipped_total")}

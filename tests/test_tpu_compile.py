@@ -227,6 +227,15 @@ def _entry_ops(text: str):
                 for dims in _F32.findall(m.group(2))]
 
 
+def _row_copies(ops, rows: int):
+    """The top-level `reshape`, `copy` and `transpose` operations among
+    `ops` (`_entry_ops`) over `rows` rows, whole or half."""
+    return [(name, op, res) for name, op, res in ops
+            if op in ("reshape", "copy", "transpose") and any(
+                dims[-1] in (L // 2, L) and math.prod(dims[:-1]) == rows
+                for dims in res)]
+
+
 @pytest.mark.parametrize("cell", sorted(CELLS))
 def test_step_copies_no_sampled_rows(cell, shape, kernel_cache,
                                      monkeypatch):
@@ -239,11 +248,7 @@ def test_step_copies_no_sampled_rows(cell, shape, kernel_cache,
     compiled, n_roles = _cell_step(cell, shape, monkeypatch)
     ops = list(_entry_ops(compiled.as_text()))
     assert sum(op == "custom-call" for _, op, _ in ops) >= n_roles
-    copies = [(name, op, res) for name, op, res in ops
-              if op in ("reshape", "copy", "transpose") and any(
-                  dims[-1] in (L // 2, L)
-                  and math.prod(dims[:-1]) == B * N for dims in res)]
-    assert not copies, copies
+    assert not _row_copies(ops, B * N)
     masks = [(name, res) for name, op, res in ops
              if op == "fusion" and "select" in name
              and any(dims[-1] == L for dims in res)]
@@ -379,6 +384,50 @@ def test_dlrm_cell_step_fits_beside_the_tables(shape, kernel_cache,
 KV4_SLOTS, KV4_CACHE, KV4_KEYS, KV4_B, KV4_N = \
     1_194_784, 32_768, 4_595_309, 4096, 32
 _ALL_REDUCE = re.compile(r"= (\(.*?\)|\S+) all-reduce(?:-start)?\(")
+_HLO_LINE = re.compile(
+    r"^\s*(?:ROOT )?%(\S+) = (\(.*?\)|\S+) ([a-z][a-z\-]*)\(([^)]*)\)"
+    r"(?:.*?op_name=\"([^\"]*)\")?")
+
+
+def _ops_by_name(text: str):
+    """name -> (opcode, result, operands' names, op_name) of every
+    operation of a compiled program, whatever computation it is in (a
+    `while` body's too)."""
+    out = {}
+    for line in text.split("\n"):
+        m = _HLO_LINE.match(line)
+        if m:
+            name, res, op, operands, op_name = m.groups()
+            out[name] = (op, res, re.findall(r"%([\w.\-]+)", operands),
+                         op_name or "")
+    return out
+
+
+def _rows(result: str, width: int):
+    """Rows of the float32 arrays of `width` columns in an operation's
+    result (`f32[4096,32,2048]` is 131,072 rows of 2,048)."""
+    return [math.prod(dims[:-1]) for dims in (
+        tuple(int(d) for d in found.split(","))
+        for found in _F32.findall(result)) if dims[-1] == width]
+
+
+def _replica_variant_reads_and_writes(text: str):
+    """What the compiled replica variant of the four-shard step does
+    with its pools: (rows of each gather from main, rows of each gather
+    from a replica pool, rows of the widest array that `_adagrad_update`
+    or a concatenation forms)."""
+    ops = _ops_by_name(text)
+    from_main, from_replica_pools, update_rows = [], [], [0]
+    for op, res, operands, op_name in ops.values():
+        if op == "fusion" and op_name.endswith("/gather") and operands:
+            pool = ops.get(operands[0], ("", "", [], ""))[1]
+            if pool.startswith(f"f32[1,{KV4_SLOTS},{L}]"):
+                from_main += _rows(res, L)
+            elif pool.startswith(f"f32[1,{KV4_CACHE},{L}]"):
+                from_replica_pools += _rows(res, L)
+        if "adapm_adagrad" in op_name or "concatenate" in op_name:
+            update_rows += _rows(res, L) + _rows(res, L // 2)
+    return sorted(from_main), sorted(from_replica_pools), max(update_rows)
 
 
 @pytest.mark.parametrize("no_replicas", [True, False])
@@ -392,7 +441,12 @@ def test_four_shard_step_is_a_per_chip_program(no_replicas, topo,
     (131,072 negatives are one call); what is summed over the chips is
     the named roles' `[4096, 1024]` halves and the loss, never an array
     of the negatives' 131,072 rows; the pools stay aliased and the
-    temporaries far under a pool's size."""
+    temporaries far under a pool's size. In the replica variant every
+    role is gathered ONCE from main at all its positions; the cache and
+    delta pools are read by gathers of one side-path chunk
+    (`fused.SIDE_ROWS` rows) and no others; update rows
+    (`_adagrad_update`, a concatenation) exist for a chunk at a time,
+    never for the 131,072 negatives; and all three pools are aliased."""
     import numpy as np
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
@@ -430,6 +484,21 @@ def test_four_shard_step_is_a_per_chip_program(no_replicas, topo,
     pool_bytes = KV4_SLOTS * L * 4
     assert mem.alias_size_in_bytes >= pool_bytes
     assert mem.temp_size_in_bytes < pool_bytes // 2
+    if not no_replicas:
+        from_main, from_replica_pools, update_rows = \
+            _replica_variant_reads_and_writes(text)
+        assert from_main == [KV4_B] * 3 + [KV4_B * KV4_N], from_main
+        # cache and delta, once each for every role's chunk
+        assert from_replica_pools == [fused.SIDE_ROWS] * 8, \
+            from_replica_pools
+        assert 0 < update_rows <= fused.SIDE_ROWS
+        assert mem.alias_size_in_bytes >= \
+            (KV4_SLOTS + 2 * KV4_CACHE) * L * 4
+        # the parent's replica variant read 4.536 GB (v5e compile, PR 36)
+        assert mem.temp_size_in_bytes < pool_bytes // 3
+        # sample-major like the replica-free variant: no top-level copy
+        # of the negatives' rows, whole or half
+        assert not _row_copies(_entry_ops(text), KV4_B * KV4_N)
     live = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
     with capsys.disabled():

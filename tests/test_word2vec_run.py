@@ -3,7 +3,10 @@ both (`W2vRun`), at the sizes of `tests/test_apps.py`'s word2vec tests.
 The epoch loop is the one `run(args)` had before the split, so its mean
 losses are that commit's to the bit (the constants below are what
 95f421c's `run(args)` returned here, on the 8-device CPU mesh and on one
-shard)."""
+shard; the mesh's third epoch was recorded anew in PR 36, whose replica
+variant of the fused step is another program: sample-major, replica rows
+patched in, one ulp of the float32 mean away from `0x1.18bce0p+1`; the
+first two epochs and one shard read as they did)."""
 import json
 
 import numpy as np
@@ -17,7 +20,7 @@ FAST = ["--sys.sync.max_per_sec", "0", "--sys.prefetch", "0"]
 # mesh, and on one shard with subsampling
 PARENT = {
     (): ["0x1.a1d02a0000000p+1", "0x1.2a70720000000p+1",
-         "0x1.18bce00000000p+1"],
+         "0x1.18bce20000000p+1"],
     ("--num_shards", "1", "--sample", "1e-3"): [
         "0x1.4445040000000p+1", "0x1.211aa40000000p+1",
         "0x1.169ae20000000p+1"],

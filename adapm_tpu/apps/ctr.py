@@ -26,6 +26,14 @@ the dispatch, the planner's rounds, the clock. A pass ends with
 `open_run(args)` sets a run up, `train(run)` trains `--epochs` passes on it
 (and can be called again), `run(args)` is both and shuts the server down.
 
+The serving side (`CtrServe`, `open_serve(args)`): an embedding shard of a
+ranking service holds the tables' rows ALONE, `--embedding_dim` floats a
+key (no AdaGrad half, no dense class), behind a `ServePlane`; a request
+is the feature keys of its samples in the layout a training batch has
+(`[members, S]`, member-major) and is answered through
+`ServeSession.lookup_bags` with one sum-pooled vector a sample and table.
+The dense network's forward pass is not part of it.
+
 Run: python -m adapm_tpu.apps.ctr --examples 4096 ...
 """
 from __future__ import annotations
@@ -79,6 +87,29 @@ def generate_synthetic(table_rows, multi_hot_sizes, num_dense: int, n: int,
             y.astype(np.float32))
 
 
+def table_layout(table_rows, hot):
+    """(first key of each table [T + 1], first key of each member's
+    table [M]): the tables' held rows in table order, member m of an
+    example a row of the table whose bag it is in."""
+    first = np.concatenate([[0], np.cumsum(table_rows)]).astype(np.int64)
+    return first, first[np.repeat(np.arange(len(hot)), hot)]
+
+
+def _set_embeddings(w0, rng, n_feat: int, dim: int, scale: float,
+                    state) -> None:
+    """Worker `w0` sets every feature row from the host, a slab at a
+    time: the embedding uniform in +-`scale`, then `state` in as many
+    columns again (a training row's AdaGrad half), or with `state` None
+    the embedding alone (a served row)."""
+    slab = max(1, (1 << 24) // dim)
+    for lo in range(0, n_feat, slab):
+        n = min(slab, n_feat - lo)
+        emb = (rng.random((n, dim), dtype=np.float32) - 0.5) * (2 * scale)
+        if state is not None:
+            emb = np.concatenate([emb, np.full_like(emb, state)], axis=1)
+        w0.set(np.arange(lo, lo + n), emb)
+
+
 class _Batch:
     """A prepared batch: role keys, the step's aux on the device, the
     distinct keys among the role keys (the intent's) and the keys'
@@ -109,9 +140,10 @@ class CtrRun:
             args.dense_row)
         self._loss = make_dlrm_loss(self.layout, self.hot, len(bottom),
                                     args.dcn_layers, len(top))
-        # keys: the tables' rows in table order, then the dense rows
-        self.table_first = np.concatenate(
-            [[0], np.cumsum(self.table_rows)]).astype(np.int64)
+        # keys: the tables' rows in table order, then the dense rows;
+        # member m of an example belongs to table member_table[m]
+        self.table_first, self.member_first = table_layout(
+            self.table_rows, self.hot)
         self.n_feat = int(self.table_first[-1])
         self.n_dense = self.layout.num_rows
         num_keys = self.n_feat + self.n_dense
@@ -128,9 +160,6 @@ class CtrRun:
         assert self.c_feat != self.c_dense, \
             "feature rows and dense rows need different lengths"
         self.dense_keys = np.arange(self.n_feat, num_keys, dtype=np.int64)
-        # member m of an example belongs to table member_table[m]
-        self.member_first = self.table_first[
-            np.repeat(np.arange(len(self.hot)), self.hot)]
         self.epoch = 0      # passes trained so far, over all train() calls
         self.mean_loss = 0.0
         self._programs = {}
@@ -206,13 +235,8 @@ class CtrRun:
         w0 = self.workers[0]
         w0.begin_setup()
         if control.process_id() == 0:
-            slab = max(1, (1 << 24) // self.dim)
-            for lo in range(0, self.n_feat, slab):
-                n = min(slab, self.n_feat - lo)
-                emb = (rng.random((n, self.dim), dtype=np.float32)
-                       - 0.5) * (2 * a.init_scale)
-                w0.set(np.arange(lo, lo + n), np.concatenate(
-                    [emb, np.full_like(emb, a.adagrad_init)], axis=1))
+            _set_embeddings(w0, rng, self.n_feat, self.dim, a.init_scale,
+                            a.adagrad_init)
             r = a.dense_row
             wts = (rng.random((self.n_dense, r), dtype=np.float32) - 0.5) \
                 * (2 * self.layout.row_scale()[:, None])
@@ -325,6 +349,90 @@ def run(args) -> float:
     return mean_loss
 
 
+class CtrServe:
+    """The serving side of the app: a server of the tables' feature keys
+    alone, rows of `--embedding_dim` floats, and (once `open_plane` ran)
+    the `ServePlane` its requests go through."""
+
+    def __init__(self, args):
+        self.args = args
+        self.table_rows = _ints(args.table_rows)
+        self.hot = _ints(args.multi_hot_sizes)
+        assert len(self.table_rows) == len(self.hot), \
+            "one multi-hot size a table"
+        self.dim = args.embedding_dim
+        self.table_first, self.member_first = table_layout(
+            self.table_rows, self.hot)
+        self.n_feat = int(self.table_first[-1])
+        # a table's members are rows member_at[t]:member_at[t + 1] of a
+        # request's [members, S] keys
+        self.member_at = np.concatenate([[0], np.cumsum(self.hot)])
+        self.srv = make_server(args, self.n_feat, self.dim, num_workers=1)
+        self.workers = [self.srv.make_worker(0)]
+        self.plane = None
+
+    def init_model(self) -> None:
+        """Worker 0 sets every row from the host as `CtrRun.init_model`
+        sets the embedding half: uniform in +-`--init_scale`."""
+        from ..parallel import control
+        w0 = self.workers[0]
+        w0.begin_setup()
+        if control.process_id() == 0:
+            _set_embeddings(w0, np.random.default_rng(self.args.seed),
+                            self.n_feat, self.dim, self.args.init_scale,
+                            None)
+            w0.wait_all()
+        w0.end_setup()
+
+    def open_plane(self):
+        """The serve plane over the tables, its bag programs compiled:
+        a coalesced batch is 1..`--sys.serve.max_batch` requests of
+        `--serve_samples` min,max samples each, every sample M member
+        positions in T bags of ONE length class, so one `_gather_pool`
+        program a batch."""
+        from ..serve import ServePlane
+        lo, hi = _ints(self.args.serve_samples)
+        most = hi * self.srv.opts.serve_max_batch
+        M, T = len(self.member_first), len(self.hot)
+        self.plane = ServePlane(self.srv)
+        self.plane.precompile_bags(
+            ((M * s, T * s) for s in range(lo, most + 1)),
+            cid=int(self.srv.ab.key_class[0]), pooling="sum")
+        return self.plane
+
+    def feat_keys(self, members: np.ndarray) -> np.ndarray:
+        """The feature keys of samples `members` [S, M] (table-local
+        row ids), member-major [M, S]: a training batch's layout."""
+        return (np.asarray(members, dtype=np.int64)
+                + self.member_first).T.copy()
+
+    def bag_args(self, keys: np.ndarray):
+        """`lookup_bags`' (tables, bags) of a request's feature keys
+        `keys` [M, S]: table t's S x m_t member keys sample by sample
+        (a bag's members in member order) with offsets 0, m_t, 2 m_t,
+        ... The reply is one [S, dim] matrix a table."""
+        S, at = keys.shape[1], self.member_at
+        tables = [keys[at[t]:at[t + 1]].T.ravel()
+                  for t in range(len(self.hot))]
+        bags = [np.arange(S + 1, dtype=np.int64) * m for m in self.hot]
+        return tables, bags
+
+    def close(self) -> None:
+        if self.plane is not None:
+            self.plane.close()
+        self.srv.shutdown()
+
+
+def open_serve(args) -> CtrServe:
+    """Set-up of the serving side: server, initialized rows, the serve
+    plane with its programs compiled. The caller makes one
+    `plane.session()` a client thread and closes with `close()`."""
+    serve = CtrServe(args)
+    serve.init_model()
+    serve.open_plane()
+    return serve
+
+
 def build_parser() -> argparse.ArgumentParser:
     parser = argparse.ArgumentParser(
         description=__doc__,
@@ -357,6 +465,10 @@ def build_parser() -> argparse.ArgumentParser:
     parser.add_argument("--adagrad_init", type=float, default=0.0,
                         help="AdaGrad's accumulators at the start; the "
                              "damping of the first steps is ADAGRAD_EPS")
+    parser.add_argument("--serve_samples", default="100,700",
+                        help="serving: fewest,most samples of a request "
+                             "(open_serve compiles the bag programs a "
+                             "batch of them can need)")
     add_common_arguments(parser)
     return parser
 

@@ -89,20 +89,23 @@ def _pool_rows(rows, seg, out, pooling):
                      jnp.zeros_like(summed))
 
 
-@partial(jax.jit, static_argnames=("pooling",))
+@partial(jax.jit, static_argnames=("nbags", "pooling"))
 @_scoped("adapm_gather_pool")
 def _gather_pool(main, cache, delta, o_shard, o_slot, c_shard, c_slot,
-                 use_cache, seg, out, *, pooling):
+                 use_cache, seg, *, nbags, pooling):
     """Fused embedding-bag read (ISSUE 16): `_gather`'s member-row read
     followed by the in-program segment reduction — one dispatch per
-    (length class, pooling) instead of gather + host pool. Nothing is
-    donated (the `out` buffer is a fresh host array per call), so the
-    family contributes empty entries to APM005's auto-derived donation
-    map by construction."""
+    (length class, pooling) instead of gather + host pool. The zeroed
+    `[nbags, L]` rows the members reduce into are made HERE: as an
+    operand they were 4-67 MB of host zeros uploaded with every dispatch
+    (0.7 ms of a mean DLRM request, 1.9 of the largest: PERF.md section
+    6, PR 37). Nothing is donated, so the family contributes empty
+    entries to APM005's auto-derived donation map by construction."""
     m = main.at[o_shard, o_slot].get(mode="fill", fill_value=0)
     c = (cache.at[c_shard, c_slot].get(mode="fill", fill_value=0)
          + delta.at[c_shard, c_slot].get(mode="fill", fill_value=0))
     rows = jnp.where(use_cache[:, None], c, m)
+    out = jnp.zeros((nbags, rows.shape[1]), rows.dtype)
     return _pool_rows(rows, seg, out, pooling)
 
 
@@ -448,9 +451,10 @@ class JaxDevicePort(DevicePort):
                     c_slot, use_cache, seg, out, pooling="sum"):
         self.programs += 1
         with _GATE:
+            # `out` fixes the result's shape; its zeros stay on the host
             return _gather_pool(main, cache, delta, o_shard, o_slot,
-                                c_shard, c_slot, use_cache, seg, out,
-                                pooling=pooling)
+                                c_shard, c_slot, use_cache, seg,
+                                nbags=out.shape[0], pooling=pooling)
 
     def scatter_add(self, main, delta, o_shard, o_slot, d_shard,
                     d_slot, vals):

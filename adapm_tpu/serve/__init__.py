@@ -118,6 +118,15 @@ class ServePlane:
         return ServeSession(self, worker=worker, tenant=tenant,
                             priority=priority)
 
+    def precompile_bags(self, sizes, cid: int = 0,
+                        pooling: str = "sum") -> int:
+        """Compile every fused bag program a coalesced batch of `sizes`
+        ((member positions, bags) pairs) can need, before traffic
+        (`LookupBatcher.precompile_bags`; docs/SERVING.md "Bag
+        reads"). Returns how many programs ran."""
+        return self.batcher.precompile_bags(sizes, cid=cid,
+                                            pooling=pooling)
+
     def close(self) -> None:
         """Stop the dispatchers and fail-stop queued requests.
         Idempotent; also called by `Server.shutdown()`."""

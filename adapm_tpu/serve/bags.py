@@ -12,15 +12,32 @@ dispatches `ShardedStore.gather_pool` — gather + segment-reduce in ONE
 device program per (length class, pooling) — and only the pooled
 vectors cross.
 
-Bit-identity contract: the fused program accumulates member rows in
-batch order (`jaxport._pool_rows`, the same `.at[].add` contract the
-coldpath relies on), and `pool_bags_host` below accumulates with
-`np.add.at` in the same member order — the two are bit-identical for
-every batch, which is what lets the batcher pick per dispatch (replica
-snapshot → host pool; locked path → fused device pool; multi-process
-or `--sys.serve.bags 0` → flat union gather + host pool) without the
-choice ever being observable in the returned bits
-(scripts/portdiff_check.py pins this across ports).
+Bit-identity contract: a pooled sum is the float32 sum of the bag's
+member rows IN MEMBER ORDER, a repeated member summed as often as it is
+named. `pool_bags_host` below accumulates with `np.add.at` in that
+order; the fused program's `.at[seg].add` (`jaxport._pool_rows`) is
+XLA's scatter-add, which on the CPU walks the batch in order and on a
+TPU sorts (segment, position) pairs, ties by position, and adds each
+bag's rows in that order: the v5e's replies were equal to the
+member-order sum bit for bit in every run of the serving cell
+(`dlrm-dcnv2-criteo1tb-serve.bags-open`: bags of 1 to 100 members,
+every pooled vector of 65 kept requests a run, half a million, compared
+with `benchmarks/reference/bags_np.py`; PERF.md section 6, PR 37). That
+is what lets the batcher pick per dispatch
+(replica snapshot → host pool; locked path → fused device pool;
+multi-process or `--sys.serve.bags 0` → flat union gather + host pool)
+without the choice being observable in the returned bits
+(scripts/portdiff_check.py pins this across ports on the CPU;
+`tests/test_bags_reference.py` holds the three paths to the reference).
+The order is a property of the compiled scatter, not of its contract:
+the benchmark's cell checks it on the chip in every run.
+
+Before traffic: a coalesced batch's members and bags are padded to
+powers of two and each pair of buckets is one compiled program, so a
+deployment calls `ServePlane.precompile_bags` with the sizes its batches
+can have (`apps/ctr.py CtrServe.open_plane`); `--sys.serve.max_batch`
+bounds a batch in requests, and with it the fullest program's gathered
+rows.
 """
 from __future__ import annotations
 

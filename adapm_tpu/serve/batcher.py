@@ -60,6 +60,10 @@ from ..obs.metrics import BATCH_SIZE_BOUNDS, SERVE_LATENCY_BOUNDS_S
 from .admission import AdmissionQueue, LookupRequest, ServeDegradedError
 from .bags import BagLookupRequest, plan_bag_batch, pool_bags_host
 
+# member positions (and bags) of one coalesced bag batch: powers of four
+# from a single small request to millions of members
+BAG_MEMBER_BOUNDS = tuple(float(4 ** i) for i in range(3, 12))
+
 
 class LookupBatcher:
     """Owns the dispatch logic (drain programs on the per-lane executor
@@ -169,6 +173,23 @@ class LookupBatcher:
                                           shared=True)
         self.c_bag_replica_hits = reg.counter(
             "serve.bag_replica_hits_total", shared=True)
+        # what a coalesced bag batch carried and what its host path
+        # cost (ISSUE 37): every bag batch (the denominator of the
+        # fused share), its member positions and bags as ASKED for
+        # (not the padded buckets), the pooled bytes copied back, and
+        # the two host phases of the fused dispatch
+        self.c_bag_batches = reg.counter("serve.bag_batches_total",
+                                         shared=True)
+        self.h_bag_members = reg.histogram(
+            "serve.bag_batch_members", unit="keys",
+            bounds=BAG_MEMBER_BOUNDS, shared=True)
+        self.h_bag_bags = reg.histogram(
+            "serve.bag_batch_bags", unit="bags",
+            bounds=BAG_MEMBER_BOUNDS, shared=True)
+        self.c_bag_reply_bytes = reg.counter(
+            "serve.bag_reply_bytes_total", unit="bytes", shared=True)
+        self.h_bag_plan = _hist("serve.bag_plan_s")
+        self.h_bag_route = _hist("serve.bag_route_s")
 
     def replica_hit_rate(self) -> float:
         """Fraction of coalesced batches served from the read-only
@@ -531,13 +552,19 @@ class LookupBatcher:
              (`_lookup_union`, which orders remote members through the
              DCN channel correctly) + host pool."""
         srv = self.server
-        allk = np.concatenate([r.keys for r in reqs]) \
-            if len(reqs) > 1 else reqs[0].keys
-        union = np.unique(allk)
+        with srv._span("serve.bag_plan", self.h_bag_plan):
+            allk = np.concatenate([r.keys for r in reqs]) \
+                if len(reqs) > 1 else reqs[0].keys
+            union = np.unique(allk)
+            groups, slices = plan_bag_batch(reqs, srv.ab.key_class)
         if srv.tier is not None:
             srv.tier.note_serve(union)
         after = tuple(f for r in reqs for f in r.after)
-        groups, slices = plan_bag_batch(reqs, srv.ab.key_class)
+        self.c_bag_batches.inc()
+        self.c_keys_unique.inc(len(union))
+        self.h_bag_members.observe(float(len(allk)))
+        self.h_bag_bags.observe(
+            float(sum(g["nbags"] for g in groups.values())))
         pooled = None
         rep = self.replica
         served = rep.try_serve(union) \
@@ -577,10 +604,15 @@ class LookupBatcher:
             if fused:
                 dev, t_enqueued = self._lookup_bags_fused(groups)
                 with srv._span("serve.copy_out"):
-                    pooled = {k: np.asarray(v)[:groups[k]["nbags"]]
-                              for k, v in dev.items()}
+                    # the whole padded bucket crosses; the reply is
+                    # its first nbags rows
+                    host = {k: np.asarray(v) for k, v in dev.items()}
                     t_copied = time.perf_counter()
+                pooled = {k: h[:groups[k]["nbags"]]
+                          for k, h in host.items()}
                 self.c_bag_fused.inc()
+                self.c_bag_reply_bytes.inc(
+                    sum(h.nbytes for h in host.values()))
             else:
                 flat, t_enqueued, t_copied = \
                     self._lookup_union(union, after)
@@ -617,16 +649,44 @@ class LookupBatcher:
                 with dispatch_gate():
                     for gkey, g in groups.items():
                         cid, pooling = gkey
-                        o_sh, o_sl, c_sh, c_sl, use_c, _, _ = \
-                            srv._route(g["keys"], self.shard,
-                                       record=False)
-                        o_sl = np.where(use_c, OOB,
-                                        o_sl).astype(np.int32)
+                        with srv._span("serve.bag_route",
+                                       self.h_bag_route):
+                            o_sh, o_sl, c_sh, c_sl, use_c, _, _ = \
+                                srv._route(g["keys"], self.shard,
+                                           record=False)
+                            o_sl = np.where(use_c, OOB,
+                                            o_sl).astype(np.int32)
                         dev[gkey] = srv.stores[cid].gather_pool(
                             o_sh, o_sl, c_sh, c_sl, use_c, g["seg"],
                             g["nbags"], pooling=pooling)
                 t_enqueued = time.perf_counter()
         return dev, t_enqueued
+
+    def precompile_bags(self, sizes, cid: int = 0,
+                        pooling: str = "sum") -> int:
+        """Compile the fused bag programs of length class `cid` before
+        traffic does. `sizes`: the (member positions, bags) a coalesced
+        bag batch can carry; the store pads both to powers of two and
+        compiles one `_gather_pool` program a pair of buckets, so every
+        pair the sizes fall in runs once here, through the dispatch the
+        batcher itself makes, on members of no bag (segment OOB: the
+        pool drops them). Returns how many programs ran."""
+        from ..core.store import OOB, bucket_size
+        srv = self.server
+        least = srv.stores[cid].bucket_min
+        pairs = sorted({(bucket_size(int(n), least),
+                         bucket_size(max(int(b), 1), least))
+                        for n, b in sizes})
+        key = int(np.argmax(srv.ab.key_class == cid))
+        for n, nb in pairs:
+            dev, _ = self._lookup_bags_fused({(cid, pooling): {
+                "keys": np.full(n, key, dtype=np.int64),
+                "seg": np.full(n, OOB, dtype=np.int32), "nbags": nb}})
+            for v in dev.values():
+                # one program's temporaries at a time (the numpy port
+                # returns finished arrays)
+                getattr(v, "block_until_ready", lambda: None)()
+        return len(pairs)
 
     def _pool_from_flat(self, flat, union, groups):
         """Host-pool each group's bags out of a flat union value buffer

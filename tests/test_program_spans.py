@@ -382,7 +382,7 @@ def _lowered_port_programs():
     lowered = [
         jp._gather.lower(pool, pool, pool, idx, idx, idx, idx, msk),
         jp._gather_pool.lower(pool, pool, pool, idx, idx, idx, idx, msk,
-                              idx, jnp.zeros((2, VL)), pooling="sum"),
+                              idx, nbags=2, pooling="sum"),
         jp._scatter_add.lower(pool, pool, idx, idx, idx, idx, vals),
         jp._sync_replicas.lower(pool, pool, pool, idx, idx, idx, idx),
         jp._sync_replicas_thresholded.lower(pool, pool, pool, idx, idx,

@@ -430,6 +430,50 @@ def _replica_variant_reads_and_writes(text: str):
     return sorted(from_main), sorted(from_replica_pools), max(update_rows)
 
 
+# dlrm-dcnv2-criteo1tb-serve: 26,033,545 slots of 128 floats (one cache
+# slot asked for: the store holds 8), and the coalesced bag batches of 1,
+# 4 (`serve.max_batch`) and 8 requests of 700 samples x 214 members in 26
+# bags, as the store pads them
+BAGS_SLOTS, BAGS_DIM = 26_033_545, 128
+BAGS_BATCHES = [(1, 262_144, 32_768, True), (4, 1_048_576, 131_072, True),
+                (8, 2_097_152, 262_144, False)]
+
+
+@pytest.mark.parametrize("requests, members, bags, fits", BAGS_BATCHES)
+def test_bags_fullest_gather_pool_fits_beside_the_table(
+        requests, members, bags, fits, shape, capsys):
+    """The serve plane's fused bag program (`jaxport._gather_pool`) at
+    the serving cell's table and its fullest buckets: the chip's
+    compiler takes it; its temporaries are the member rows gathered
+    THREE times (from main, cache and delta: a one-shard store reads
+    two pools of 8 slots for every member); the fullest batch the
+    configuration allows (`serve.max_batch` 4) leaves 1.6 GiB beside
+    the 13.33 GB table, and twice that does not fit (the chip refused
+    to load it: RESOURCE_EXHAUSTED, my chip run, PR 37)."""
+    from adapm_tpu.core.store import bucket_size
+    from adapm_tpu.device import jaxport
+    assert members == bucket_size(requests * 700 * 214)
+    assert bags == bucket_size(requests * 700 * 26)
+    i32 = lambda: shape((members,), jnp.int32)  # noqa: E731
+    row = lambda n: shape((1, n, BAGS_DIM), jnp.float32)  # noqa: E731
+    compiled = jaxport._gather_pool.lower(
+        row(BAGS_SLOTS), row(8), row(8), i32(), i32(), i32(), i32(),
+        shape((members,), jnp.bool_), i32(), nbags=bags,
+        pooling="sum").compile()
+    mem = compiled.memory_analysis()
+    live = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    with capsys.disabled():
+        print(f"\ndlrm-dcnv2-criteo1tb-serve v5e compile, {requests} "
+              f"requests ({members} members, {bags} bags): temporaries "
+              f"{mem.temp_size_in_bytes / 1e9:.3f} GB, live "
+              f"{live / 2**30:.2f} GiB of 15.75")
+    gathered = members * BAGS_DIM * 4
+    assert 3 * gathered <= mem.temp_size_in_bytes < \
+        3 * gathered + (256 << 20)
+    assert (live < 14.25 * 2**30) if fits else (live > 15.5 * 2**30)
+
+
 @pytest.mark.parametrize("no_replicas", [True, False])
 def test_four_shard_step_is_a_per_chip_program(no_replicas, topo,
                                                kernel_cache, monkeypatch,

@@ -24,7 +24,8 @@ the reference's addressbook is O(1)/key in C++ (addressbook.h:110-151), and a
 """
 from __future__ import annotations
 
-from typing import List, Sequence
+import collections
+from typing import List, Optional, Sequence
 
 import numpy as np
 
@@ -144,6 +145,20 @@ class Addressbook:
         # Server._topology_mutation's discipline assertion; the initial
         # allocation below is construction, not a mutation
         self.mutations = 0
+        # THE JOURNAL of changed keys: every counted mutation appends the
+        # keys whose owner, slot or cache slot it changed (values are not
+        # kept: a reader takes them from the tables, under the lock that
+        # the mutators run under). A reader of state derived from
+        # placement (ops/fused.py: the device mirrors of these tables,
+        # a worker's local sampling index) keeps a cursor and patches
+        # what it holds by `changed_since(cursor)`. Positions count
+        # entries since construction; the oldest chunks are dropped once
+        # more than `journal_limit` entries are kept, about where a
+        # rebuild from the whole tables is the cheaper anyway
+        self.journal_limit = max(4096, num_keys // 16)
+        self._journal = collections.deque()  # int64 key arrays, oldest first
+        self._journal_start = 0  # position of the first entry kept
+        self._journal_end = 0    # position after the last: the cursor
 
         self.main_alloc = [SlotAllocator(num_shards, m) for m in main_slots]
         self.cache_alloc = [SlotAllocator(num_shards, c) for c in cache_slots]
@@ -194,8 +209,55 @@ class Addressbook:
     def has_replica(self, keys: np.ndarray, shard: int) -> np.ndarray:
         return self.cache_slot[shard, keys] != NO_SLOT
 
+    def holds_replicas(self, shard: int) -> bool:
+        """True if `shard` holds a replica of any key: a cache slot in
+        use in some class's allocator (every replica holds one, and
+        nothing else does); no scan of the table."""
+        return any(a.num_free(shard) < a.slots_per_shard
+                   for a in self.cache_alloc)
+
     def replica_shards(self, key: int) -> np.ndarray:
         return np.nonzero(self.cache_slot[:, key] != NO_SLOT)[0]
+
+    # -- the journal of changed keys ------------------------------------------
+    def _note_changed(self, keys: np.ndarray) -> None:
+        """One counted mutation: its keys join the journal."""
+        self.mutations += 1
+        keys = np.array(keys, dtype=np.int64).ravel()
+        self._journal.append(keys)
+        self._journal_end += len(keys)
+        while self._journal_end - self._journal_start > self.journal_limit:
+            self._journal_start += len(self._journal.popleft())
+
+    def journal_cursor(self) -> int:
+        """Where the journal ends now: what a reader keeps once it has
+        brought its state up to date with the tables."""
+        return self._journal_end
+
+    def changed_since(self, cursor: Optional[int]) -> Optional[np.ndarray]:
+        """The keys of every mutation after `cursor` (a `journal_cursor()`
+        of earlier), with repeats and in no order that matters; None
+        where the journal cannot say: no cursor, entries dropped past it,
+        or the tables rewritten since (`reset_journal`). None asks the
+        reader for a rebuild from the tables."""
+        if cursor is None or cursor < self._journal_start:
+            return None
+        chunks, at = [], self._journal_end
+        for chunk in reversed(self._journal):
+            if at <= cursor:
+                break
+            at -= len(chunk)
+            chunks.append(chunk[max(cursor - at, 0):])
+        if not chunks:
+            return np.empty(0, dtype=np.int64)
+        return np.concatenate(chunks)
+
+    def reset_journal(self) -> None:
+        """The tables were rewritten wholesale (a checkpoint restore): no
+        cursor taken before is answered."""
+        self._journal.clear()
+        self._journal_end += 1
+        self._journal_start = self._journal_end
 
     # -- replica bookkeeping -------------------------------------------------
     def add_replica(self, key: int, shard: int) -> int:
@@ -223,7 +285,7 @@ class Addressbook:
         cs = alloc.alloc_batch(shard, len(keys))
         taken = keys[: len(cs)]
         if len(taken):
-            self.mutations += 1
+            self._note_changed(taken)
         self.cache_slot[shard, taken] = cs
         self.replica_count[taken] += 1
         return cs
@@ -243,7 +305,7 @@ class Addressbook:
         cls = self.key_class[keys]
         assert (cls == cls[0]).all(), \
             "drop_replicas batch must be single-class"
-        self.mutations += 1
+        self._note_changed(keys)
         self.cache_slot[shard, keys] = NO_SLOT
         self.replica_count[keys] -= 1
         self.cache_alloc[int(cls[0])].free_batch(shard, cs)
@@ -258,7 +320,7 @@ class Addressbook:
         assert old_shard != new_shard
         alloc = self.main_alloc[self.key_class[key]]
         new_slot = alloc.alloc(new_shard)
-        self.mutations += 1
+        self._note_changed(key)
         self.owner[key] = new_shard
         self.slot[key] = new_slot
         alloc.free(old_shard, old_slot)
@@ -299,7 +361,7 @@ class Addressbook:
             raise RuntimeError(
                 f"process out of main pool slots while adopting "
                 f"{len(keys) - pos} relocated keys; increase over_alloc")
-        self.mutations += 1
+        self._note_changed(keys)
         self.owner[keys] = sh_out
         self.slot[keys] = sl_out
         self.relocation_counter[keys] += 1
@@ -317,7 +379,7 @@ class Addressbook:
         sl = self.slot[keys]
         assert (sh >= 0).all(), "abandon_batch keys must be locally owned"
         alloc = self.main_alloc[int(cls[0])]
-        self.mutations += 1
+        self._note_changed(keys)
         for s in np.unique(sh):
             alloc.free_batch(int(s), sl[sh == s])
         self.owner[keys] = REMOTE
@@ -338,7 +400,7 @@ class Addressbook:
         new_slots = alloc.alloc_batch(new_shard, len(keys))
         moved = keys[: len(new_slots)]
         if len(moved):
-            self.mutations += 1
+            self._note_changed(moved)
         old_shards = self.owner[moved].astype(np.int64)
         old_slots = self.slot[moved].astype(np.int64)
         assert (old_shards != new_shard).all()

@@ -270,6 +270,19 @@ def _relocate(main, delta, old_shard, old_slot, new_shard, new_slot,
     return main, delta
 
 
+@jax.jit
+@_scoped("adapm_route_patch")
+def _patch_routes(owner, slot, cache_row, patch):
+    """The fused step's three routing tables (ops/fused.py DeviceRouter)
+    with the entries of `patch` set: int32 [4, n], its rows the keys
+    and their owner, slot and cache-row values; a padding key is out of
+    bounds and dropped. The tables are not donated: a step in flight
+    keeps the buffers it was dispatched with."""
+    keys = patch[0]
+    return tuple(t.at[keys].set(v, mode="drop")
+                 for t, v in zip((owner, slot, cache_row), patch[1:]))
+
+
 # ---------------------------------------------------------------------------
 # tiered cold-path programs (host-supplied row overrides + refresh halves)
 # ---------------------------------------------------------------------------
@@ -543,6 +556,11 @@ class JaxDevicePort(DevicePort):
         with _GATE:
             return _relocate(main, delta, old_shard, old_slot,
                              new_shard, new_slot, rc_shard, rc_slot)
+
+    def patch_routes(self, owner, slot, cache_row, patch):
+        self.programs += 1
+        with _GATE:
+            return _patch_routes(owner, slot, cache_row, patch)
 
     # -- tiered cold path + wire ingest --------------------------------------
 

@@ -108,6 +108,14 @@ class DevicePort:
         """Donates (main, delta); returns (main, delta)."""
         raise NotImplementedError
 
+    def patch_routes(self, owner, slot, cache_row, patch):
+        """The fused step's routing tables (ops/fused.py DeviceRouter)
+        with the entries named by `patch` set: int32 [4, n], its rows
+        the keys and their owner, slot and cache-row values; a key out
+        of bounds is padding. Donates nothing (a step in flight keeps
+        the tables it was dispatched with); returns the three tables."""
+        raise NotImplementedError
+
     # -- tiered cold path + wire-row ingest (tier/, ops/dequant twins) -------
 
     def gather_cold(self, main, cache, delta, o_shard, o_row, c_shard,

@@ -548,6 +548,7 @@ def _apply_chain(server, chain: List[Tuple[Dict, Dict]]) -> None:
         ab.owner[:] = aux["owner"]
         ab.slot[:] = aux["slot"]
         ab.cache_slot[:] = aux["cache_slot"]
+        ab.reset_journal()  # tables rewritten: readers rebuild from them
         ab.relocation_counter[:] = aux["relocation_counter"]
         ab.replica_count[:] = (ab.cache_slot >= 0).sum(axis=0)
         server.sync.intent_end[:] = aux["intent_end"]

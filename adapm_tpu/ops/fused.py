@@ -19,13 +19,15 @@ matrix_factorization.cc:695-697): row = [emb (D) | adagrad acc (D)].
 
 Routing (which shard/slot serves each key) is resolved IN the program
 (DeviceRouter): the Addressbook tables (owner, slot, the worker shard's
-cache-slot row) are mirrored into HBM, re-uploaded lazily when the planner
-changes placement (topology_version), and the jitted step resolves routes
+cache-slot row) are mirrored into HBM, brought up to date lazily when the
+planner changes placement (topology_version; patched by the keys the
+addressbook's journal lists as changed, rebuilt from the tables where
+it cannot say), and the jitted step resolves routes
 itself by the policy of `Server._route` (prefer a local replica, else the
 owner row) — per step the host ships only raw keys, and relocation and
 replication decisions made by the planner between steps are picked up with
-the next refresh. Table lookups are trivial device gathers, and placement
-changes are rare relative to steps.
+the next refresh. Table lookups are trivial device gathers, and a placement
+change costs the host by the keys it moved, not by the tables' size.
 
 Negative sampling can run on device too (the `neg_role`/`neg_shape`
 parameters of DeviceRoutedRunner / make_device_routed_step): drawing uniform
@@ -260,14 +262,37 @@ def _adagrad_update(g, acc, lr, eps):
         return jnp.concatenate([upd_emb, g2], axis=-1)
 
 
+# entries one call of the port's patch program takes (`patch_routes`:
+# one compiled shape); a placement change of more keys takes several calls
+PATCH_KEYS = 16384
+
+
+def _changed_keys(server, cursor) -> Optional[np.ndarray]:
+    """The keys whose placement changed since the journal position
+    `cursor` (core/addressbook.py), each once and sorted: what state
+    derived from placement is patched by. None where it has to be
+    rebuilt from the tables: the journal does not reach back to the
+    cursor, or the store is tiered (residency moves what the step reads
+    and is not journalled). Caller holds the server lock."""
+    if server.tier is not None:
+        return None
+    keys = server.ab.changed_since(cursor)
+    return None if keys is None else np.unique(keys)
+
+
 class DeviceRouter:
     """Device mirrors of the Addressbook tables for one worker shard,
-    refreshed lazily on placement changes (Server.topology_version)."""
+    brought up to date lazily on placement changes
+    (Server.topology_version): patched by the keys that changed since
+    (`_patch`), rebuilt from the tables where those are not known
+    (`_refresh`: set-up, a journal trimmed or reset, a tiered store)."""
 
     def __init__(self, server, shard: int):
         self.server = server
         self.shard = shard
         self._version = None   # (topology_version, residency epoch)
+        self._cursor = None    # the journal position the mirrors are at
+                               # (None: not built yet)
         self.owner = None      # [num_keys] int32
         self.slot = None       # [num_keys] int32
         self.cache_row = None  # [num_keys] int32 (this shard's replica slots)
@@ -288,6 +313,16 @@ class DeviceRouter:
                                               shared=True)
         self._h_refresh_work = server.obs.histogram(
             "fused.route_refresh_work_s", shared=True)
+        # of the refreshes, those that patched what was there by the
+        # journal's keys (the mirrors here, a runner's local index),
+        # and the entries they shipped; the wait span `fused.route_patch`
+        # holds each call of the patch program
+        self._c_patch = server.obs.counter("fused.route_patch_total",
+                                           shared=True)
+        self._c_patch_keys = server.obs.counter(
+            "fused.route_patch_keys_total", unit="keys", shared=True)
+        self._h_patch = server.obs.histogram("fused.route_patch_s",
+                                             shared=True)
 
     def _put_counted(self, arr):
         """`put_replicated` of one mirror, its bytes counted once for
@@ -305,10 +340,37 @@ class DeviceRouter:
             return
         with srv._span("fused.route_refresh", self._h_refresh,
                        work=self._h_refresh_work):
-            self._refresh(ver)
+            changed = _changed_keys(srv, self._cursor)
+            if changed is None:
+                self._refresh()
+            else:
+                self._patch(changed)
+            self._version = ver
+            self._cursor = srv.ab.journal_cursor()
         self._c_refresh.inc()
 
-    def _refresh(self, ver):
+    def _patch(self, keys: np.ndarray) -> None:
+        """Set the mirrors' entries of `keys` (sorted, each once) to the
+        addressbook's values of now, read here under the server lock:
+        `PATCH_KEYS` entries a call of the port's program, a padding key
+        out of bounds. The program returns new tables, and every later
+        dispatch is ordered after it as after an upload."""
+        srv = self.server
+        ab = srv.ab
+        for lo in range(0, len(keys), PATCH_KEYS):
+            k = keys[lo:lo + PATCH_KEYS]
+            patch = np.full((4, PATCH_KEYS), OOB, np.int32)
+            patch[:, :len(k)] = (k, ab.owner[k], ab.slot[k],
+                                 ab.cache_slot[self.shard, k])
+            patch = self._put_counted(patch)
+            with srv._span("fused.route_patch", self._h_patch, wait=True):
+                self.owner, self.slot, self.cache_row = \
+                    default_port().patch_routes(
+                        self.owner, self.slot, self.cache_row, patch)
+        self._c_patch.inc()
+        self._c_patch_keys.inc(len(keys))
+
+    def _refresh(self):
         srv = self.server
         ab = srv.ab
         # SNAPSHOTS of the addressbook's tables, taken here under the
@@ -330,7 +392,6 @@ class DeviceRouter:
         self.slot = put(ab.slot if srv.tier is None
                         else srv.tier.compose_slot_table())
         self.cache_row = put(ab.cache_slot[self.shard])
-        self._version = ver
 
     def tables(self):
         self.refresh()
@@ -956,6 +1017,12 @@ class DeviceRoutedRunner:
                 f"{role_class[neg_role]}")
         self._local_index = None  # uniform path: (padded index, count)
         self._li_version = None
+        # the uniform path's sorted index on the host (a view of the
+        # padded array last uploaded, never written again) and the
+        # journal position it is at: what `_patch_local_neg_index`
+        # merges a placement change into. None: not built yet, or the
+        # alias path
+        self._li_host = self._li_cursor = None
         # per-step RNG keys come from a batched split (one tiny device
         # dispatch per 64 steps instead of per step) and device scalars
         # are cached per value
@@ -1057,8 +1124,6 @@ class DeviceRoutedRunner:
         # while this shard holds no replicas
         self._step_fn_norep = self._program(make_device_routed_step,
                                             no_replicas=True)
-        self._rep_version = -1
-        self._has_replicas = True
         self.steps = 0
         if getattr(server, "prefetch", None) is not None:
             server.prefetch.register_refresher(self._prefetch_refresh)
@@ -1086,7 +1151,9 @@ class DeviceRoutedRunner:
         steps', against a slot table that is out of bounds everywhere:
         every gather fills zeros and every write-back is dropped, so the
         pools come back bit for bit, and neither the RNG sequence nor
-        the locality counts move. A runner with a `score_fn` compiles
+        the locality counts move. On several shards the program that
+        patches the router's mirrors compiles here too
+        (`DeviceRouter._patch`). A runner with a `score_fn` compiles
         its score program's variants the same way, on `score_aux`."""
         srv = self.server
         with srv._lock:
@@ -1103,6 +1170,11 @@ class DeviceRoutedRunner:
             fns = [self._step_fn_norep]
             if srv.num_shards > 1:
                 fns.append(self.step_fn)
+                # and the mirrors' patch program at its one shape (one
+                # shard never changes placement), every key padding
+                default_port().patch_routes(
+                    owner, nowhere, no_cache, srv.ctx.put_replicated(
+                        np.full((4, PATCH_KEYS), OOB, np.int32)))
             for fn in fns:
                 pools = tuple((s.main, s.cache, s.delta)
                               for s in srv.stores)
@@ -1126,14 +1198,13 @@ class DeviceRoutedRunner:
 
     def _prefetch_refresh(self) -> None:
         """Called by the prefetch pipeline (under the server lock) after
-        planner rounds: re-stage the device table mirrors, the local
-        sampling index, and the replica-presence flag as soon as the
-        topology settles, so the next dispatch finds them fresh instead
-        of rebuilding + re-uploading them inside its critical section."""
+        planner rounds: bring the device table mirrors and the local
+        sampling index up to date as soon as the topology settles, so
+        the next dispatch finds them fresh instead of refreshing them
+        inside its critical section."""
         self.router.refresh()
         if self.neg_role is not None:
             self._local_neg_index()
-        self._shard_has_replicas()
 
     def _note_step_writes(self, role_keys) -> None:
         """The fused step is a batched Push in PM terms: staged pull
@@ -1308,12 +1379,7 @@ class DeviceRoutedRunner:
         return {"params": p, "params_local": pl, "ops": o, "ops_local": ol}
 
     def _shard_has_replicas(self) -> bool:
-        srv = self.server
-        if self._rep_version != srv.topology_version:
-            self._has_replicas = bool(
-                (srv.ab.cache_slot[self.shard] >= 0).any())
-            self._rep_version = srv.topology_version
-        return self._has_replicas
+        return self.server.ab.holds_replicas(self.shard)
 
     def _local_neg_index(self):
         """The step's `local_index` operand, brought up to date with
@@ -1332,7 +1398,15 @@ class DeviceRoutedRunner:
             router = self.router
             with srv._span("fused.route_refresh", router._h_refresh,
                            work=router._h_refresh_work):
-                self._build_local_neg_index()
+                # (an index in its fallback is the population, not
+                # the local keys: there is nothing to merge into)
+                changed = None \
+                    if self._li_host is None or self._li_fallback \
+                    else _changed_keys(srv, self._li_cursor)
+                if changed is None or \
+                        not self._patch_local_neg_index(changed):
+                    self._build_local_neg_index()
+                self._li_cursor = srv.ab.journal_cursor()
             self._li_version = li_ver
             router._c_refresh.inc()
         return self._local_index
@@ -1345,14 +1419,49 @@ class DeviceRoutedRunner:
         if self._alias is not None:
             self._alias = self._alias[:2] + (self._snap_table(pop, local),)
             return
+        self._set_local_index(pop[local])
+
+    def _set_local_index(self, idx: np.ndarray) -> None:
+        """Upload the sorted local keys `idx` as the step's operand: a
+        FRESH padded array every time, because the device reads an
+        uploaded buffer until its transfer ends."""
         from ..core.store import bucket_size
-        idx = pop[local]
         cap = bucket_size(len(idx), minimum=64)
-        kdt = _key_dtype(srv.num_keys)
+        kdt = _key_dtype(self.server.num_keys)
         padded = np.full(cap, np.iinfo(kdt).max, dtype=kdt)
         padded[: len(idx)] = idx
+        self._li_host = padded[: len(idx)]
         self._local_index = (self.router._put_counted(padded),
                              jnp.int32(len(idx)))
+
+    def _patch_local_neg_index(self, changed: np.ndarray) -> bool:
+        """Bring the uniform path's index up to date by the keys whose
+        placement changed (sorted, each once): those of the sampled
+        population that left the shard are taken out, those that came
+        are merged in, and the index stays sorted: entry for entry what
+        `_build_local_neg_index` builds from the tables, so the step
+        draws the same negatives. False where nothing would be left
+        (the fallback is the full build's to set up)."""
+        idx = self._li_host
+        changed = changed.astype(idx.dtype)
+        pop = self._neg_population
+        if pop is not None:
+            at = np.minimum(np.searchsorted(pop, changed), len(pop) - 1)
+            changed = changed[pop[at] == changed]
+        now = self.server.ab.is_local(changed, self.shard)
+        at = np.searchsorted(idx, changed)
+        was = idx[np.minimum(at, len(idx) - 1)] == changed
+        gone, came = at[was & ~now], changed[~was & now]
+        if len(idx) - len(gone) + len(came) == 0:
+            return False
+        router = self.router
+        router._c_patch.inc()
+        router._c_patch_keys.inc(len(gone) + len(came))
+        if len(gone) or len(came):
+            kept = np.delete(idx, gone)
+            self._set_local_index(
+                np.insert(kept, np.searchsorted(kept, came), came))
+        return True
 
     def _snap_table(self, pop: np.ndarray, local: np.ndarray):
         """The alias path's Local-scheme snap, for every alias position

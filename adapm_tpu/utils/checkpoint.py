@@ -137,6 +137,7 @@ def restore_server(server, path: str) -> None:
         ab.owner[:] = ck["owner"]
         ab.slot[:] = ck["slot"]
         ab.cache_slot[:] = ck["cache_slot"]
+        ab.reset_journal()  # tables rewritten: readers rebuild from them
         ab.relocation_counter[:] = ck["relocation_counter"]
         ab.replica_count[:] = (ab.cache_slot >= 0).sum(axis=0)
         server.sync.intent_end[:] = ck["intent_end"]

@@ -17,6 +17,13 @@ if "xla_force_host_platform_device_count" not in flags:
 
     # 8-virtual-device mesh + the XLA CPU collective watchdog timeouts
     os.environ["XLA_FLAGS"] = " ".join([flags, mesh_flags(8)]).strip()
+# XLA's CPU client runs each device's part of a program on a thread of
+# ONE pool, sized to the cores (here as many as the mesh has devices),
+# and a part sits on its thread until every device has joined its
+# collective: with nothing to spare, `tests/test_apps.py::test_mf_app`
+# hung for good in half the whole runs under six workers (PR 38, the
+# parent's tree too). More threads than devices, as a chip's host has
+os.environ.setdefault("PJRT_NPROC", "32")
 
 from adapm_tpu.utils.compile_cache import enable_compile_cache  # noqa: E402
 

@@ -31,7 +31,8 @@ W = 2 * D          # embedding columns of a row [re | im | AdaGrad]
 LOSS_GAP, NORM_GAP, DIFF_SHARE = 2.5e-6, 5e-6, 2e-5
 
 
-def _open(techniques: str, cache_slots: int = 64, entities: int = E):
+def _open(techniques: str, cache_slots: int = 64, entities: int = E,
+          extra=()):
     args = kge.build_parser().parse_args(
         ["--dim", str(D), "--batch_size", str(B), "--neg_ratio", str(N),
          "--lr", str(LR), "--num_shards", "4", "--num_workers", "4",
@@ -41,7 +42,7 @@ def _open(techniques: str, cache_slots: int = 64, entities: int = E):
          "--synthetic_triples", str(8 * B),
          "--sys.techniques", techniques,
          "--sys.cache_slots_per_shard", str(cache_slots),
-         "--sys.main_over_alloc", "2.0"])
+         "--sys.main_over_alloc", "2.0", *extra])
     return kge.open_run(args)
 
 
@@ -402,7 +403,7 @@ def test_precompile_leaves_the_store_and_nothing_to_compile():
             np.asarray(jax.random.key_data(runner._rng)), rng_before)
         assert runner.locality_counts()["params"] == 0
         programs = [jaxport._relocate, jaxport._replica_create,
-                    jaxport._sync_replicas]
+                    jaxport._sync_replicas, jaxport._patch_routes]
         sizes = [fn._cache_size() for fn in programs]
         rng = np.random.default_rng(10)
         for _ in range(2):
@@ -410,6 +411,7 @@ def test_precompile_leaves_the_store_and_nothing_to_compile():
             kge.train(run)
         st = srv.sync.stats
         assert st.relocations > 0 and st.replicas_created > 0
+        assert srv.obs.find("fused.route_patch_s").snap()["count"] > 0
         assert [fn._cache_size() for fn in programs] == sizes
     finally:
         run.srv.shutdown()
@@ -418,6 +420,7 @@ def test_precompile_leaves_the_store_and_nothing_to_compile():
 def _counters(srv):
     return {n: srv.obs.find(n).snap() for n in (
         "fused.route_refresh_total", "fused.route_upload_bytes_total",
+        "fused.route_patch_total", "fused.route_patch_keys_total",
         "fused.rows_total", "fused.rows_local_total",
         "fused.rows_sampled_total",
         "fused.replica_positions", "fused.replica_chunks",
@@ -457,11 +460,94 @@ def test_a_run_with_relocations_and_replicas_moves_every_counter():
             s.sync_bytes_shipped for s in srv.stores)
         for n, c in h0.items():
             assert srv.obs.find(n).snap()["count"] > c, n
-        # one observation for each rebuild of a mirror or a local index
+        # one observation for each refresh of a mirror or a local index
         assert srv.obs.find("fused.route_refresh_s").snap()["count"] == \
             c1["fused.route_refresh_total"]
+        # and every one of them a patch by the journal's keys, but each
+        # runner's first (its mirrors and its index, built from the
+        # tables at set-up or at its first step)
+        assert c1["fused.route_refresh_total"] \
+            - c1["fused.route_patch_total"] == 2 * len(run.workers)
     finally:
         run.srv.shutdown()
+
+
+def test_a_placement_change_uploads_entries_and_not_tables(monkeypatch):
+    """At 30,000 entities a step's placement change ships the patch's
+    operand and, where it changed, the local index: under a sixth of
+    the three tables and the index that a rebuild uploads (to each of
+    the four devices), which is what every step paid before the
+    journal."""
+    from adapm_tpu.ops import fused
+    monkeypatch.setattr(fused, "PATCH_KEYS", 256)
+    run = _open("all", cache_slots=16, entities=30000,
+                extra=("--sys.prefetch", "0"))   # a round after each step
+    try:
+        srv = run.srv
+        rng = np.random.default_rng(13)
+        run.ds.train = _draw(rng, 8 * B)
+        kge.train(run)           # every runner's first build is behind
+        c0 = _counters(srv)
+        steps0 = srv.obs.find("fused.dispatch_s").snap()["count"]
+        for _ in range(2):
+            run.ds.train = _draw(rng, 8 * B)
+            kge.train(run)
+        c1 = _counters(srv)
+        steps = srv.obs.find("fused.dispatch_s").snap()["count"] - steps0
+        grew = {n: c1[n] - c0[n] for n in c0}
+        assert steps == 16 and grew["sync.relocations_total"] > 0
+        # a step's worker finds placement changed: its mirrors and its
+        # index are refreshed, and both by patches
+        assert grew["fused.route_patch_total"] == \
+            grew["fused.route_refresh_total"] == 2 * steps
+        index, _ = run.device_runner(0)._local_neg_index()
+        rebuilt = 4 * 4 * (3 * srv.num_keys + len(index))
+        assert grew["fused.route_upload_bytes_total"] / steps < rebuilt / 6
+    finally:
+        run.srv.shutdown()
+
+
+def test_patched_run_is_bitwise_the_run_with_every_refresh_rebuilt(
+        monkeypatch):
+    """The same seeded four-worker run twice, relocations and replicas
+    in both: once as shipped, once with the journal answering nothing
+    (every refresh the full rebuild): the same losses, local indexes
+    and pools, bit for bit. The planner's rounds run on the training
+    thread, one after every step, as in the four-chip cell (with the
+    prefetch pipeline they run at their own pace, and no two runs are
+    the same)."""
+    from adapm_tpu.ops import fused
+
+    def outcome(patched):
+        if not patched:
+            monkeypatch.setattr(fused, "_changed_keys", lambda *a: None)
+        run = _open("all", cache_slots=16, extra=("--sys.prefetch", "0"))
+        try:
+            srv = run.srv
+            rec = _Recorder([run.device_runner(w.shard)
+                             for w in run.workers])
+            rng = np.random.default_rng(14)
+            for _ in range(3):
+                run.ds.train = _draw(rng, 8 * B)
+                kge.train(run)
+            rec.remove()
+            st = srv.sync.stats
+            assert st.relocations > 0 and st.replicas_created > 0
+            patches = srv.obs.find("fused.route_patch_total").snap()
+            assert (patches > 3 * 8) if patched else patches == 0
+            losses = np.array([np.asarray(s["loss"]) for s in rec.steps])
+            pools = [np.asarray(p) for st in srv.stores
+                     for p in (st.main, st.cache, st.delta)]
+            return losses, pools, [s["local"] for s in rec.steps]
+        finally:
+            run.srv.shutdown()
+
+    got, want = outcome(True), outcome(False)
+    assert np.array_equal(got[0], want[0])
+    for a, b in zip(got[1], want[1]):
+        assert np.array_equal(a, b)
+    for a, b in zip(got[2], want[2]):
+        assert np.array_equal(a, b)
 
 
 def test_one_shard_never_refreshes_its_routes_after_set_up():
@@ -479,6 +565,7 @@ def test_one_shard_never_refreshes_its_routes_after_set_up():
         assert at_set_up == 2            # the mirrors and the local index
         kge.train(run)
         assert srv.obs.find("fused.route_refresh_total").snap() == at_set_up
+        assert srv.obs.find("fused.route_patch_total").snap() == 0
         assert srv.obs.find("sync.relocations_total").snap() == 0
         assert len(run._step_programs) == 2
     finally:

@@ -42,13 +42,14 @@ TRAIN_SPANS = ("kv.intent", "fused.dispatch", "fused.key_upload",
                "kv.drive_rounds", "kv.advance_clock", "kv.quiesce",
                "app.prepare", "app.pass_end", "app.loss_fetch",
                "app.loss_allreduce", "sync.round",
-               "fused.enqueue", "fused.route_upload", "store.enqueue",
-               "kv.block")
+               "fused.enqueue", "fused.route_upload", "fused.route_patch",
+               "store.enqueue", "kv.block")
 # the wait spans (one blocking call each) and the phases each nests in
 # (the pass end's flush calls the sync programs outside a round)
 WAIT_NESTS_IN = {"fused.enqueue": ("fused.dispatch",),
                  "fused.key_upload": ("fused.dispatch", "app.prepare"),
                  "fused.route_upload": ("fused.route_refresh",),
+                 "fused.route_patch": ("fused.route_refresh",),
                  "store.enqueue": ("sync.round", "kv.quiesce"),
                  "kv.block": ("kv.quiesce",)}
 # every span given a work histogram: (whole, work)
@@ -483,7 +484,8 @@ def test_work_is_the_whole_less_the_waits_beneath_at_any_depth():
 def test_train_step_work_and_waits_close_on_the_whole(ctx):
     """On a live server (rounds inline, one thread): every work
     histogram counts with its whole and never exceeds it; the
-    dispatch's waits are its enqueue, key upload and route uploads; the
+    dispatch's waits are its enqueue, key upload, route uploads and
+    the calls of the routes' patch program; the
     planner round's are the stores' program calls, subtracted from
     `kv.drive_rounds` above it too."""
     s, w = _server(ctx)
@@ -498,13 +500,16 @@ def test_train_step_work_and_waits_close_on_the_whole(ctx):
     assert n == 6
     beneath = sum(_hist(s, name)["sum"] for name in
                   ("fused.enqueue_s", "fused.key_upload_s",
-                   "fused.route_upload_s"))
+                   "fused.route_upload_s", "fused.route_patch_s"))
     assert disp == pytest.approx(beneath, abs=1e-9) and beneath > 0.0
-    # a refresh's waits are its uploads (all of them ran in a dispatch)
+    # a refresh's waits are its uploads and its patch program's calls
+    # (all of them ran in a dispatch)
     refresh, _ = _pair(s, "fused.route_refresh_s",
                        "fused.route_refresh_work_s")
+    assert _hist(s, "fused.route_patch_s")["count"] > 0
     assert refresh == pytest.approx(
-        _hist(s, "fused.route_upload_s")["sum"], abs=1e-9)
+        _hist(s, "fused.route_upload_s")["sum"]
+        + _hist(s, "fused.route_patch_s")["sum"], abs=1e-9)
     # the planner moved rows (8 shards, fresh intents every step): its
     # program calls are the round's waits, two and three spans deep
     stores = _hist(s, "kv.store_enqueue_s")

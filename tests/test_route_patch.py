@@ -1,0 +1,229 @@
+"""State derived from placement is patched by the journal of changed
+keys (core/addressbook.py), never rebuilt while those keys are known: a
+`DeviceRouter`'s three device tables and a `DeviceRoutedRunner`'s local
+sampling index have to equal, bit for bit, what `_refresh` and
+`_build_local_neg_index` build from the tables, after any sequence of
+placement changes, on four shards."""
+import numpy as np
+import pytest
+
+import adapm_tpu
+from adapm_tpu.config import SystemOptions
+from adapm_tpu.core.addressbook import Addressbook
+from adapm_tpu.ops import DeviceRoutedRunner
+
+K, L, S = 400, 8, 4
+
+
+def _loss(embs, aux):
+    return ((embs["a"] * embs["neg"]).sum(-1) ** 2).mean()
+
+
+def _runner(srv, shard, population):
+    return DeviceRoutedRunner(
+        srv, _loss, role_class={"a": 0, "neg": 0},
+        role_dim={"a": 4, "neg": 4}, shard=shard, neg_role="neg",
+        neg_shape=(4, 2), neg_population=population, seed=shard)
+
+
+def _state(runner):
+    """What the next dispatch of `runner` would hand the step: the three
+    tables, the padded local index, its count, the fallback flag."""
+    with runner.server._lock:
+        tables = runner._tables()[:3]
+        index, count = runner._local_neg_index()
+    return ([np.asarray(t) for t in tables], np.asarray(index),
+            int(count), runner._li_fallback)
+
+
+def _from_scratch(twin):
+    """The same from the addressbook's tables alone: a twin runner that
+    forgets what it held."""
+    twin.router._version = twin.router._cursor = None
+    twin._li_version = twin._li_host = None
+    return _state(twin)
+
+
+def _assert_same(got, want):
+    for g, w in zip(got[0], want[0]):
+        assert g.dtype == w.dtype and np.array_equal(g, w)
+    assert got[1].dtype == want[1].dtype and got[1].shape == want[1].shape
+    assert np.array_equal(got[1], want[1])
+    assert got[2:] == want[2:]
+
+
+def _patches(srv):
+    return srv.obs.find("fused.route_patch_total").snap()
+
+
+# the sampled population of each case, and which shard the moves favour
+CASES = {
+    "uniform": (None, None),
+    "subset": (np.arange(3, K, 3), None),
+    # keys that shard 0 owns at set-up alone: the other shards' runners
+    # start with nothing local, and fall back again when all have left
+    "fallback": (np.arange(0, K, S), None),
+    # moves favour shard 0 until its index outgrows its padded capacity
+    "doubling": (None, 0),
+    "restore": (None, None),
+}
+
+
+@pytest.mark.parametrize("patch_keys", [8, None])
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_patched_tables_and_index_equal_the_full_rebuild(case, patch_keys,
+                                                         tmp_path,
+                                                         monkeypatch):
+    """`patch_keys` 8: a patch of the mirrors takes several calls of the
+    program; None: the program's shipped operand, one call."""
+    from adapm_tpu.ops import fused
+    if patch_keys is not None:
+        monkeypatch.setattr(fused, "PATCH_KEYS", patch_keys)
+    population, favoured = CASES[case]
+    srv = adapm_tpu.setup(K, L, num_shards=S, opts=SystemOptions(
+        sync_max_per_sec=0, cache_slots_per_shard=32, main_over_alloc=3.0))
+    try:
+        ab = srv.ab
+        runners = [_runner(srv, s, population) for s in range(S)]
+        twins = [_runner(srv, s, population) for s in range(S)]
+        rng = np.random.default_rng(sorted(CASES).index(case))
+        pop = np.arange(K) if population is None else population
+
+        def check(expect_patch=None):
+            before = _patches(srv)
+            for r, t in zip(runners, twins):
+                _assert_same(_state(r), _from_scratch(t))
+            if expect_patch is not None:
+                assert (_patches(srv) > before) is expect_patch
+
+        check(expect_patch=False)       # set-up: the full build
+        capacities = {len(_state(runners[0])[1])}
+        fallbacks = {_state(r)[3] for r in runners}
+        ckpt = str(tmp_path / "ck.npz")
+        for it in range(40):
+            op = rng.integers(0, 5)
+            keys = np.unique(rng.choice(pop, rng.integers(1, 24)))
+            dest = favoured if favoured is not None and it % 4 else \
+                int(rng.integers(0, S))
+            if op <= 1:
+                srv._relocate_to(keys, dest)
+            elif op == 2:
+                srv._create_replicas(keys, dest)
+            elif op == 3:
+                held = np.flatnonzero(ab.cache_slot[dest] >= 0)
+                held = held[: rng.integers(1, 12)]
+                srv._drop_replicas(held, np.full(len(held), dest))
+            else:   # ownership leaves the process and comes back
+                keys = keys[ab.owner[keys] >= 0]
+                if not len(keys):
+                    continue
+                srv._drop_replicas(
+                    np.tile(keys, S), np.repeat(np.arange(S), len(keys)))
+                with srv._topology_mutation():
+                    ab.abandon_batch(keys)
+                check(expect_patch=True)
+                with srv._topology_mutation():
+                    ab.adopt_batch(keys, dest)
+            if it == 20:    # a burst the journal cannot hold: rebuilt
+                limit, ab.journal_limit = ab.journal_limit, 8
+                for s in range(S):
+                    srv._relocate_to(rng.choice(pop, 16), s)
+                ab.journal_limit = limit
+                check(expect_patch=False)
+            if case == "fallback" and it == 30:
+                # everything the population has leaves shards 1..3
+                srv._drop_replicas(
+                    np.tile(pop, S - 1),
+                    np.repeat(np.arange(1, S), len(pop)))
+                srv._relocate_to(pop, 0)
+            if case == "restore" and it == 10:
+                from adapm_tpu.utils.checkpoint import save_server
+                save_server(srv, ckpt)
+            if case == "restore" and it == 25:
+                from adapm_tpu.utils.checkpoint import restore_server
+                restore_server(srv, ckpt)
+                check(expect_patch=False)   # journal reset: rebuilt
+            check()
+            capacities.add(len(_state(runners[0])[1]))
+            fallbacks |= {_state(r)[3] for r in runners}
+        refreshes = srv.obs.find("fused.route_refresh_total").snap()
+        assert _patches(srv) > 40 and refreshes > _patches(srv)
+        assert srv.obs.find("fused.route_patch_keys_total").snap() > 0
+        assert len(capacities) > 1 or case != "doubling"
+        assert fallbacks == ({False, True} if case == "fallback"
+                             else {False})
+    finally:
+        srv.shutdown()
+
+
+def _mutate(ab, mutator):
+    """One call of `mutator` on keys that are ready for it."""
+    keys = np.array([5, 9, 14])             # homes 1, 1, 2
+    if mutator == "add_replicas":
+        ab.add_replicas(keys, 3)
+    elif mutator == "drop_replicas":
+        ab.drop_replicas(np.array([21, 22]), 0)
+    elif mutator == "relocate":
+        ab.relocate(5, 3)
+    elif mutator == "relocate_batch":
+        ab.relocate_batch(keys, 0)
+    elif mutator == "abandon_batch":
+        ab.abandon_batch(keys)
+    else:
+        ab.adopt_batch(np.array([30, 31]), 2)
+
+
+@pytest.mark.parametrize("mutator", [
+    "add_replicas", "drop_replicas", "relocate", "relocate_batch",
+    "abandon_batch", "adopt_batch"])
+def test_every_mutator_journals_the_entries_it_changes(mutator):
+    """Whatever a mutator changes in the three tables that the fused
+    step mirrors, the journal lists: the keys after the cursor are
+    exactly the keys whose owner, slot or cache slot differ from a
+    snapshot, and the mutation is counted once."""
+    ab = Addressbook(np.zeros(64, np.int32), 4, [64], [8])
+    ab.add_replicas(np.array([21, 22, 23]), 0)   # something to drop
+    ab.abandon_batch(np.array([30, 31]))         # something to adopt
+    before = (ab.owner.copy(), ab.slot.copy(), ab.cache_slot.copy())
+    cursor, counted = ab.journal_cursor(), ab.mutations
+    _mutate(ab, mutator)
+    moved = (ab.owner != before[0]) | (ab.slot != before[1]) \
+        | (ab.cache_slot != before[2]).any(axis=0)
+    assert moved.any()
+    assert sorted(ab.changed_since(cursor)) == list(np.flatnonzero(moved))
+    assert ab.mutations == counted + 1
+
+
+def test_journal_answers_a_cursor_or_says_it_cannot():
+    ab = Addressbook(np.zeros(64, np.int32), 4, [64], [8])
+    assert ab.journal_limit == 4096   # the floor: 64 keys // 16 is less
+    assert ab.changed_since(None) is None
+    c0 = ab.journal_cursor()
+    assert len(ab.changed_since(c0)) == 0
+    ab.relocate(5, 2)
+    ab.add_replicas(np.array([7, 9]), 1)
+    c1 = ab.journal_cursor()
+    ab.relocate_batch(np.array([9, 10, 11]), 0)
+    ab.drop_replicas(np.array([7]), 1)
+    assert sorted(ab.changed_since(c0)) == [5, 7, 7, 9, 9, 10, 11]
+    assert sorted(ab.changed_since(c1)) == [7, 9, 10, 11]
+    assert len(ab.changed_since(ab.journal_cursor())) == 0
+    assert ab.mutations == 4
+    # a cursor inside a chunk: the chunk's tail
+    assert sorted(ab.changed_since(c1 + 1)) == [7, 10, 11]
+    # bounded by entries: the oldest chunks go, and a cursor before the
+    # first kept entry is not answered
+    ab.journal_limit = 4
+    ab.relocate_batch(np.array([20, 22, 24]), 1)
+    assert ab.changed_since(c1) is None
+    c2 = ab.journal_cursor()
+    assert sorted(ab.changed_since(c2 - 3)) == [20, 22, 24]
+    # one mutation larger than the limit leaves nothing to answer from
+    ab.relocate_batch(np.arange(40, 60)[np.arange(40, 60) % 4 != 3], 3)
+    assert ab.changed_since(c2) is None
+    assert len(ab.changed_since(ab.journal_cursor())) == 0
+    # a reset answers no cursor taken before it
+    c3 = ab.journal_cursor()
+    ab.reset_journal()
+    assert ab.changed_since(c3) is None
+    assert len(ab.changed_since(ab.journal_cursor())) == 0

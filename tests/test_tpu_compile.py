@@ -576,6 +576,36 @@ PARENT_LOWERED = {
 _MOSAIC_BODY = re.compile(r'\\22body\\22: \\22[A-Za-z0-9+/=]*\\22')
 
 
+def test_route_patch_is_each_chip_for_itself(topo):
+    """The program that patches a router's three table mirrors
+    (`jaxport._patch_routes`), at the four-shard cell's 4,595,307 keys
+    and `fused.PATCH_KEYS` entries, compiled for the described v5e 2x2
+    with everything replicated: every chip sets the entries of its own
+    copies, nothing crosses the chips, and what it holds beside the
+    three new tables is nothing to speak of."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from adapm_tpu.device import jaxport
+    from adapm_tpu.ops import fused
+    everywhere = NamedSharding(
+        Mesh(np.asarray(topo.devices[:4]), ("kv",)), P())
+    table = jax.ShapeDtypeStruct((KV4_KEYS,), jnp.int32,
+                                 sharding=everywhere)
+    patch = jax.ShapeDtypeStruct((4, fused.PATCH_KEYS), jnp.int32,
+                                 sharding=everywhere)
+    compiled = jaxport._patch_routes.lower(
+        table, table, table, patch).compile()
+    text = compiled.as_text()
+    for collective in ("all-reduce", "all-gather", "all-to-all",
+                       "collective-permute"):
+        assert collective not in text, collective
+    assert all(s == everywhere for s in compiled.output_shardings)
+    mem = compiled.memory_analysis()
+    assert mem.output_size_in_bytes >= 3 * 4 * KV4_KEYS
+    assert mem.temp_size_in_bytes < 4 * KV4_KEYS
+
+
 def _one_chip_lowered(shape, monkeypatch):
     """name -> lowered text (StableHLO, no locations) of the replica-free
     programs the one-chip cells run, at the cells' own sizes, built as on

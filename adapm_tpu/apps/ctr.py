@@ -16,12 +16,17 @@ matrices and multiplies, and AdaGrad writes both classes back.
 Key layout: the tables' held rows in table order, then the dense tensors
 in network order (models/dlrm.py DenseLayout).
 
-A pass is the same `--examples` examples every pass, in batches of
-`--batch_size`, so its batches (keys member-major `[members, B]`, the
-distinct keys of the intent, the keys' and the dense features' upload) are
-prepared once and kept. Per step: the intent `--lookahead` batches ahead,
-the dispatch, the planner's rounds, the clock. A pass ends with
-`quiesce()` and one fetch of the mean of its steps' losses.
+A pass is `--examples` examples in batches of `--batch_size`, the workers
+in turns. A batch (keys member-major `[members, B]`, the distinct keys of
+the intent, the keys' and the dense features' upload) is prepared
+`--lookahead` batches ahead of its step, with its intent, and kept: a pass
+that revisits its examples (`run`, `train` called again) prepares nothing
+twice, and a pass of fresh examples (`set_examples`) prepares each batch
+while the steps before it run. Every batch of a worker's turn gets an
+intent, the first `--lookahead` at the start of the turn. Per step: the
+prepare and intent `--lookahead` batches ahead, the dispatch, the
+planner's rounds, the clock. A pass ends with `quiesce()` and one fetch of
+the mean of its steps' losses.
 
 `open_run(args)` sets a run up, `train(run)` trains `--epochs` passes on it
 (and can be called again), `run(args)` is both and shuts the server down.
@@ -187,16 +192,22 @@ class CtrRun:
         """The examples this run trains on: `members` [n, M] table-local
         row ids (M = sum of the multi-hot sizes, bags in table order),
         dense features `x` [n, num_dense], labels `y` [n]. Partitioned
-        contiguously over all processes' workers; a worker's batches are
-        prepared at its first pass and kept (`_plan`)."""
+        contiguously over all processes' workers, each worker's share
+        cut into its batches; what was prepared of the examples before
+        (`_batch`) is dropped."""
         self.members = np.asarray(members, dtype=np.int64)
         assert self.members.shape[1] == len(self.member_first), \
             self.members.shape
         self.x = np.asarray(x, dtype=np.float32)
         self.y = np.asarray(y, dtype=np.float32)
-        self.by_worker = global_worker_slices(len(self.members),
-                                              self.num_workers)
-        self._plans = {}
+        parts = global_worker_slices(len(self.members), self.num_workers)
+        # worker -> the example indices of each of its batches, and the
+        # batches prepared so far (None: not yet)
+        self._batch_idx = [
+            [mine[idx] for idx in wrap_batches(len(mine),
+                                               self.args.batch_size)]
+            for mine in parts]
+        self._plans = [[None] * len(idxs) for idxs in self._batch_idx]
 
     def device_runner(self, shard: int) -> DeviceRoutedRunner:
         if shard not in self._dev_runners:
@@ -251,25 +262,23 @@ class CtrRun:
         """The feature keys of the examples `idx`, member-major [M, B]."""
         return (self.members[idx] + self.member_first).T.copy()
 
-    def _plan(self, wi: int) -> list:
-        """Worker `wi`'s batches, the same every pass, prepared once: the
-        role keys, their distinct keys, and the uploads of the keys and
-        of the dense features and labels, kept on the device."""
-        if wi not in self._plans:
-            mine = self.by_worker[wi]
-            runner = self.device_runner(self.workers[wi].shard)
+    def _batch(self, wi: int, bi: int) -> _Batch:
+        """Batch `bi` of worker `wi`, prepared at most once for these
+        examples: the role keys, their distinct keys, and the uploads of
+        the keys and of the dense features and labels, kept on the
+        device."""
+        b = self._plans[wi][bi]
+        if b is None:
+            idx = self._batch_idx[wi][bi]
             put = self.srv.ctx.put_replicated
-            plan = self._plans[wi] = []
-            for idx in wrap_batches(len(mine), self.args.batch_size):
-                idx = mine[idx]
-                roles = {"feat": self.feat_keys(idx),
-                         "dense": self.dense_keys}
-                keys = np.concatenate([np.unique(roles["feat"]),
-                                       self.dense_keys])
-                plan.append(_Batch(roles,
-                                   (put(self.x[idx]), put(self.y[idx])),
-                                   keys, runner.prefetch_keys(roles)))
-        return self._plans[wi]
+            roles = {"feat": self.feat_keys(idx), "dense": self.dense_keys}
+            keys = np.concatenate([np.unique(roles["feat"]),
+                                   self.dense_keys])
+            runner = self.device_runner(self.workers[wi].shard)
+            b = self._plans[wi][bi] = _Batch(
+                roles, (put(self.x[idx]), put(self.y[idx])), keys,
+                runner.prefetch_keys(roles))
+        return b
 
     def train_pass(self) -> list:
         """One pass over this process's examples; returns the steps'
@@ -277,15 +286,23 @@ class CtrRun:
         a, srv = self.args, self.srv
         losses = []
         for wi, w in enumerate(self.workers):
-            plan = self._plan(wi)
+            n = len(self._plans[wi])
             runner = self.device_runner(w.shard)
-            for bi, b in enumerate(plan):
-                if bi + a.lookahead < len(plan):
-                    with srv._span("app.prepare", self._h_prepare,
-                                   work=self._h_prepare_work):
-                        nxt = plan[bi + a.lookahead]
-                        fut = w.current_clock + a.lookahead
-                        w.intent(nxt.keys, fut, fut + 1)
+
+            def prepare(bi: int, ahead: int) -> None:
+                # the batch (if these examples' first pass) and its
+                # intent, `ahead` steps before the step that reads it
+                with srv._span("app.prepare", self._h_prepare,
+                               work=self._h_prepare_work):
+                    fut = w.current_clock + ahead
+                    w.intent(self._batch(wi, bi).keys, fut, fut + 1)
+
+            for bi in range(min(a.lookahead, n)):
+                prepare(bi, ahead=bi)
+            for bi in range(n):
+                if bi + a.lookahead < n:
+                    prepare(bi + a.lookahead, ahead=a.lookahead)
+                b = self._batch(wi, bi)
                 self._c_keys.inc(b.roles["feat"].size + self.n_dense)
                 self._c_unique.inc(len(b.keys))
                 losses.append(runner(b.roles, b.aux, a.lr, eps=ADAGRAD_EPS,

@@ -255,7 +255,10 @@ class SystemOptions:
     episode_batches: int = 8
 
     # -- store geometry
-    cache_slots_per_shard: int = 0   # 0 = auto (num_keys // num_shards)
+    # replica (cache + delta) slots a shard holds of EACH length class,
+    # capped at the class's key count (core/store.py); 0 = as many as
+    # the class has keys a shard
+    cache_slots_per_shard: int = 0
     remote_bucket_min: int = 8       # min padded size of the remote op bucket
     # main-pool headroom factor for relocations (slots per shard =
     # keys_per_shard * over_alloc); at memory-bound scale (e.g. a
@@ -830,8 +833,8 @@ class SystemOptions:
                        dest="sys_cache_slots_per_shard", type=int,
                        default=0,
                        help="replica (cache + delta) slots a shard holds "
-                            "per length class; 0 = as many as it has "
-                            "main-pool keys")
+                            "per length class, capped at the class's key "
+                            "count; 0 = as many as it has main-pool keys")
         g.add_argument("--sys.optimistic_routing",
                        dest="sys_optimistic_routing", type=int, default=1)
         g.add_argument("--sys.prefetch", dest="sys_prefetch", type=int,

@@ -216,6 +216,13 @@ class Addressbook:
         return any(a.num_free(shard) < a.slots_per_shard
                    for a in self.cache_alloc)
 
+    def replicas_held(self, cls: int) -> int:
+        """Replicas of class `cls` over all shards: the cache slots its
+        allocator has handed out (as `holds_replicas`, no scan)."""
+        a = self.cache_alloc[cls]
+        return sum(a.slots_per_shard - a.num_free(s)
+                   for s in range(a.num_shards))
+
     def replica_shards(self, key: int) -> np.ndarray:
         return np.nonzero(self.cache_slot[:, key] != NO_SLOT)[0]
 

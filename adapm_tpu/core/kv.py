@@ -71,6 +71,19 @@ class _TopoHandle:
         self.cancelled = True
 
 
+def _class_counter(obs, name: str, unit: str, lengths):
+    """`inc(cid, n)` that moves the counter `name` and, beside it, the
+    counter of length class `cid` alone, named by the class's row length
+    in values (`<name>.len2048`: rows of 2,048)."""
+    total = obs.counter(name, unit=unit)
+    by_class = [obs.counter(f"{name}.len{n}", unit=unit) for n in lengths]
+
+    def inc(cid: int, n: int) -> None:
+        total.inc(n)
+        by_class[cid].inc(n)
+    return inc
+
+
 class Server:
     """Owns the sharded pools, addressbook, planner, and worker registry.
 
@@ -276,8 +289,22 @@ class Server:
         # `store.enqueue`, core/store.py): what a call holds beyond its
         # enqueue is the wait for a free dispatch slot
         self._h_store_enqueue = self.obs.histogram("kv.store_enqueue_s")
-        self._c_sync_bytes = self.obs.counter("sync.bytes_shipped_total",
-                                              unit="bytes")
+        # the wire bytes and replica rows those programs shipped (a
+        # periodic round, a drop's flush, quiesce); relocations demoted
+        # to a replica because the destination's main pool was full, and
+        # replica creations left out because its cache pool was
+        # (`_relocate_to`, `_create_replicas`): a run that counts either
+        # measures a pool size. Each `inc(class id, n)`: the total and
+        # the length class's own (`_class_counter`)
+        lens = self.class_lengths
+        self._c_sync_bytes = _class_counter(
+            self.obs, "sync.bytes_shipped_total", "bytes", lens)
+        self._c_sync_rows = _class_counter(
+            self.obs, "sync.rows_shipped_total", "rows", lens)
+        self._c_demoted = _class_counter(
+            self.obs, "sync.relocations_demoted_total", "keys", lens)
+        self._c_truncated = _class_counter(
+            self.obs, "sync.replicas_truncated_total", "keys", lens)
         # collective wait-time histograms, observed by the (server-less)
         # control plane via observe_global (parallel/control.py) and by
         # Server.barrier below
@@ -1110,6 +1137,8 @@ class Server:
                 for cid, pos in self._group_by_class(todo):
                     cs = ab.add_replicas(todo[pos], shard)
                     ks = todo[pos][: len(cs)]
+                    if len(ks) < len(pos):  # the class's cache pool is full
+                        self._c_truncated(cid, len(pos) - len(ks))
                     if len(ks) == 0:
                         continue
                     c_sl = cs.astype(np.int32)
@@ -1209,7 +1238,8 @@ class Server:
                 shipped = st.sync_bytes_shipped
                 st.sync_replicas(ss, r_cs, o_sh, o_sl,
                                  threshold=threshold, compress=mode)
-                self._c_sync_bytes.inc(st.sync_bytes_shipped - shipped)
+                self._c_sync_bytes(cid, st.sync_bytes_shipped - shipped)
+                self._c_sync_rows(cid, len(ss))
 
     def _drop_replicas(self, keys: np.ndarray,
                        shards: np.ndarray) -> None:
@@ -1296,6 +1326,7 @@ class Server:
                         ab.relocate_batch(ks, dest)
                     if len(moved) < len(ks):  # pool full: demote the rest
                         demoted = np.concatenate((demoted, ks[len(moved):]))
+                        self._c_demoted(cid, len(ks) - len(moved))
                     if len(moved) == 0:
                         continue
                     # a replica at the destination upgrades to owner: its

@@ -165,8 +165,14 @@ class ShardedStore:
         # not produce a pool smaller than the initial allocation
         self.main_slots = _round8(max(per_shard,
                                       math.ceil(per_shard * over_alloc)))
-        self.cache_slots = _round8(max(1, cache_slots_per_shard or
-                                       per_shard))
+        # replica slots a shard: as many as asked for, and never more
+        # than the class has keys (a shard holds at most one replica of
+        # a key, so slots beyond that could not be filled: one
+        # --sys.cache_slots_per_shard sizes every class, and a class of
+        # a few thousand long rows beside one of millions of short ones
+        # would otherwise get the millions); 0 = as many as main holds
+        self.cache_slots = _round8(max(1, min(
+            cache_slots_per_shard or per_shard, num_keys_in_class)))
 
         # -- tiered residency (ISSUE 5 tentpole; adapm_tpu/tier) -----------
         # tier_hot_rows > 0 caps the DEVICE main pool at that many rows

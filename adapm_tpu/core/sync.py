@@ -312,6 +312,11 @@ class SyncManager:
                       fn=lambda: sum(len(t) for t in self.replicas))
             reg.gauge("sync.dirty_fraction",
                       fn=lambda: self._dirty_fraction(None))
+            # per length class, named by the class's row length: the
+            # cache slots its allocator has handed out (no table scan)
+            for cid, n in enumerate(server.class_lengths):
+                reg.gauge(f"sync.replicas_live.len{n}",
+                          fn=lambda cid=cid: server.ab.replicas_held(cid))
             for c in range(self.num_channels):
                 reg.gauge(f"sync.replicas_live.c{c}",
                           fn=lambda c=c: len(self.replicas[c]))

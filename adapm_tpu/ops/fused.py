@@ -632,6 +632,15 @@ def _on_this_chip(route, axis):
             zero, jnp.where(_here(c_sh, axis), c_sl, OOB), use_c)
 
 
+def _classes_counted(role_class) -> list:
+    """The length classes whose replica positions and side-path chunks
+    a step counts one by one beside its totals: those of its roles where
+    they span several (with one class the totals are that class's, and
+    the step's accumulator, and so its program, is what it was)."""
+    classes = sorted(set(role_class.values()))
+    return classes if len(classes) > 1 else []
+
+
 def _route_and_gather(pools, tables, keys, roles, role_class, role_dim,
                       no_replicas, axis=None):
     """Route every role's keys and gather their rows: the read half of a
@@ -642,7 +651,9 @@ def _route_and_gather(pools, tables, keys, roles, role_class, role_dim,
     `_build_device_routed_body`), its route
     (main's shard and slot; in the replica variant the compacted replica
     positions too, a `_ReplicaSide`), and the step's counts (n_total,
-    n_local, n_replica, n_chunks): the device-side locality counts
+    n_local, n_replica, n_chunks, and the last two once more for each
+    length class where the roles span several, `_classes_counted`): the
+    device-side locality counts
     (reference coloc_kv_server.h:147-157 prints % accesses served
     locally; Pull/Push record this in Server._route, which a step never
     visits: a key access is local when this worker's shard owns the row
@@ -672,6 +683,9 @@ def _route_and_gather(pools, tables, keys, roles, role_class, role_dim,
     embs, accs, routes = {}, {}, {}
     n_total = 0
     n_local = n_replica = n_chunks = jnp.int32(0)
+    # class -> [replica positions, chunks], where the roles span several
+    by_class = {cid: [jnp.int32(0), jnp.int32(0)]
+                for cid in _classes_counted(role_class)}
     shard = tables[3]  # the worker's, an int32 scalar operand
     for r in roles:
         cid = role_class[r]
@@ -706,10 +720,16 @@ def _route_and_gather(pools, tables, keys, roles, role_class, role_dim,
             # counted on the global route, like the locality counts: the
             # worker chip's side path, and the same number on every chip
             held = jnp.sum(use_c, dtype=jnp.int32)
+            chunks = _replica_chunks(held, use_c.size)
             n_replica += held
-            n_chunks += _replica_chunks(held, use_c.size)
+            n_chunks += chunks
+            if cid in by_class:
+                by_class[cid][0] += held
+                by_class[cid][1] += chunks
         n_local += jnp.sum(local, dtype=jnp.int32)
-    return embs, accs, routes, (n_total, n_local, n_replica, n_chunks)
+    return embs, accs, routes, (
+        n_total, n_local, n_replica, n_chunks,
+        [n for cid in sorted(by_class) for n in by_class[cid]])
 
 
 def make_device_routed_score(score_fn: Callable[..., jnp.ndarray],
@@ -855,13 +875,13 @@ def _build_device_routed_body(loss_fn, role_class, role_dim,
                     {r: embs[r] for r in roles if r not in drawn}, axis))
         # one step = one (batched) pull op + one push op of the same keys;
         # the op counts local iff every key it touched was local
-        n_total, n_local, n_replica, n_chunks = counts
+        n_total, n_local, n_replica, n_chunks, by_class = counts
         all_local = (n_local == n_total).astype(jnp.int32)
         # the accumulator takes as many of the step's counts as it has
         # entries (`DeviceRoutedRunner._locstat`)
         locstat = locstat + jnp.stack(
             [jnp.int32(n_total), n_local, jnp.int32(1), all_local,
-             n_replica, n_chunks][:locstat.shape[0]])
+             n_replica, n_chunks, *by_class][:locstat.shape[0]])
         loss, grads = _loss_and_grads(batch_major_loss, embs, trainable,
                                       aux)
         if axis is not None:
@@ -1035,8 +1055,11 @@ class DeviceRoutedRunner:
         # (`_for_replica_chunks`). One shard holds no replica and
         # compiles the replica-free variant alone, on the accumulator
         # (and so to the program) it always had
+        # Roles of several length classes: a pair more for each class,
+        # its own replica positions and chunks (`_classes_counted`)
         self._locstat_zero = np.zeros(
-            4 if server.num_shards == 1 else 6, np.int32)
+            4 if server.num_shards == 1
+            else 6 + 2 * len(_classes_counted(role_class)), np.int32)
         self._locstat = server.ctx.put_replicated(self._locstat_zero)
         self._loc_host = np.zeros(4, dtype=np.int64)
         self._drain_every = None  # set on first step (needs params/step)
@@ -1112,6 +1135,16 @@ class DeviceRoutedRunner:
             "fused.replica_positions", unit="rows", shared=True)
         self._c_replica_chunks = server.obs.counter(
             "fused.replica_chunks", shared=True)
+        # the same two for each length class of the roles, named by the
+        # class's row length (`.len2048`: rows of 2,048 values)
+        self._c_replica_by_class = []
+        for cid in sorted(set(role_class.values())):
+            n = server.class_lengths[cid]
+            self._c_replica_by_class += [
+                server.obs.counter(f"fused.replica_positions.len{n}",
+                                   unit="rows", shared=True),
+                server.obs.counter(f"fused.replica_chunks.len{n}",
+                                   shared=True)]
         # the worker's shard as the step's operand (the last of `tables`)
         self._shard_dev = server.ctx.put_replicated(np.int32(shard))
         self._mk_kwargs = dict(
@@ -1362,8 +1395,13 @@ class DeviceRoutedRunner:
         self._c_rows_local.inc(int(vals[1]))
         self._c_rows_sampled.inc(self._sampled_pending)
         self._sampled_pending = 0
-        for c, v in zip((self._c_replica_positions,
-                         self._c_replica_chunks), vals[4:]):
+        # the totals, then each class's pair; roles of one class count
+        # no pair apart: the totals are that class's
+        counts = vals[4:]
+        if len(counts) == 2:
+            counts = np.tile(counts, 2)
+        for c, v in zip([self._c_replica_positions, self._c_replica_chunks]
+                        + self._c_replica_by_class, counts):
             c.inc(int(v))
         self._locstat = self.server.ctx.put_replicated(self._locstat_zero)
         self._c_drains.inc()

@@ -234,22 +234,23 @@ def test_max_runtime_stops_at_the_first_pass_end():
 def test_open_run_compiles_what_train_runs_and_batches_are_kept():
     """`CtrRun.precompile`: the step stands before the first pass and a
     pass adds no program; a pass's batches (keys, their distinct keys,
-    the uploads) are built at the first pass and kept; `set_examples`
-    drops them."""
+    the uploads) are built during the examples' first pass, each
+    --lookahead steps before its own, and kept; `set_examples` drops
+    them."""
     run = ctr.open_run(_args(epochs=2))
     try:
         before = set(run._programs)
         assert before
+        assert run._plans == [[None] * 5]   # 4 B + 5 examples: the tail wraps
         ctr.train(run)
         assert set(run._programs) == before
-        plan = run._plans[0]
-        assert len(plan) == 5           # 4 B + 5 examples: the tail wraps
+        plan = list(run._plans[0])
         assert all(b.staged is not None and b.roles["feat"].shape == (M, B)
                    for b in plan)
         ctr.train(run)
-        assert run._plans[0] is plan
+        assert all(a is b for a, b in zip(run._plans[0], plan))
         run.set_examples(run.members[:B], run.x[:B], run.y[:B])
-        assert run._plans == {}
+        assert run._plans == [[None]]
     finally:
         run.srv.shutdown()
 
@@ -265,12 +266,14 @@ def test_batch_key_counters_and_spans(tmp_path):
         obs = run.srv.obs
         keys = obs.find("app.batch_keys_total").snap()
         uniq = obs.find("app.batch_unique_keys_total").snap()
-        # every dispatched batch is counted: 5 a pass, of which the first
-        # --lookahead have no intent (and no `app.prepare`)
+        # every dispatched batch is counted, 5 a pass, and every one has
+        # its intent (one `app.prepare` each: the first --lookahead at
+        # the start of the worker's turn)
         assert keys == 2 * 5 * (M * B + run.n_dense)
         assert uniq == 2 * sum(len(b.keys) for b in run._plans[0])
         assert 5 * run.n_dense < uniq <= keys
-        assert obs.find("app.prepare_s").snap()["count"] == 6
+        assert obs.find("app.prepare_s").snap()["count"] == 10
+        assert obs.find("kv.intent_s").snap()["count"] == 10
         assert obs.find("app.pass_end_s").snap()["count"] == 2
         doc = json.load(open(run.srv.write_trace()))
         names = {e["name"] for e in doc["traceEvents"] if e["ph"] == "X"}

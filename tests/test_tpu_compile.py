@@ -553,6 +553,80 @@ def test_four_shard_step_is_a_per_chip_program(no_replicas, topo,
     assert live < 15.0 * 2**30
 
 
+# the four-shard CTR cell (dlrm-dcnv2-criteo1tb-kv4): a shard's main and
+# replica slots of the feature class and of the dense class, keys
+CTR4_FEAT, CTR4_DENSE, CTR4_KEYS = (6_763_632, 131_072), (4_160, 15_680), \
+    25_523_124 + 15_676
+
+
+def test_four_shard_ctr_step_walks_two_classes_of_replicas(
+        topo, kernel_cache, monkeypatch, capsys):
+    """The four-shard CTR cell's replica variant at its own sizes,
+    compiled for the described v5e 2x2: two length classes in one
+    per-chip step, each with its main, cache and delta block. The
+    write-back kernel's calls are the one-chip step's five (four for the
+    438,272 feature positions, one for the dense rows); what is summed
+    over the chips is the feature rows' embedding halves and the dense
+    class's, out and back, and the loss; each class's cache and delta
+    pools are read a side-path chunk at a time and all six pools stay
+    aliased; the accumulator has a pair of entries a class beside its
+    six."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from adapm_tpu.models import dlrm
+    from adapm_tpu.ops import fused
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    mesh = Mesh(np.asarray(topo.devices[:4]), ("kv",))
+
+    def shape(dims, dtype, spec=P()):
+        return jax.ShapeDtypeStruct(dims, dtype,
+                                    sharding=NamedSharding(mesh, spec))
+    layout = dlrm.DenseLayout(dlrm.dense_tensors(
+        13, 128, 26, [512, 256, 128], [1024, 1024, 512, 256, 1], 3, 512),
+        1024)
+    hot = [3, 2, 1, 2, 6, 1, 1, 1, 1, 7, 3, 8, 1, 6, 9, 5, 1, 1, 1, 12, 100,
+           27, 10, 3, 1, 1]
+    pools = tuple(tuple(shape((4, n, row), jnp.float32, P("kv"))
+                        for n in (main, cache, cache))
+                  for (main, cache), row in ((CTR4_FEAT, 256),
+                                             (CTR4_DENSE, L)))
+    roles = {"feat": 0, "dense": 1}
+    assert fused._classes_counted(roles) == [0, 1]
+    step = fused.make_device_routed_step(
+        dlrm.make_dlrm_loss(layout, hot, 3, 3, 5), roles,
+        {"feat": 128, "dense": 1024}, (), None, None, False)
+    compiled = step.lower(
+        pools, shape((10,), jnp.int32),
+        tuple(shape((CTR4_KEYS,), jnp.int32) for _ in range(3))
+        + (shape((), jnp.int32),),
+        {"feat": shape((DLRM_M, DLRM_B), jnp.int32),
+         "dense": shape((layout.num_rows,), jnp.int32)},
+        None, None, shape((2,), jnp.uint32),
+        (shape((DLRM_B, 13), jnp.float32), shape((DLRM_B,), jnp.float32)),
+        shape((), jnp.float32), shape((), jnp.float32)).compile()
+    text = compiled.as_text()
+    assert text.count("custom_call_target=\"tpu_custom_call\"") == 5
+    summed = {tuple(int(d) for d in dims.split(","))
+              for res in _ALL_REDUCE.findall(text)
+              for dims in _F32.findall(res)}
+    assert {(layout.num_rows, 1024)} <= summed <= {
+        (layout.num_rows, 1024), (DLRM_M, DLRM_B, 128),
+        (DLRM_M * DLRM_B, 128)}, summed
+    mem = compiled.memory_analysis()
+    pool_bytes = sum((main + 2 * cache) * row * 4 for (main, cache), row
+                     in ((CTR4_FEAT, 256), (CTR4_DENSE, L)))
+    assert mem.alias_size_in_bytes >= pool_bytes
+    live = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    with capsys.disabled():
+        print(f"\ndlrm-dcnv2-criteo1tb-kv4 v5e 2x2 compile, the replica "
+              f"variant: a chip's pools {pool_bytes / 1e9:.3f} GB, "
+              f"temporaries {mem.temp_size_in_bytes / 1e9:.3f} GB, live "
+              f"{live / 2**30:.2f} GiB of 15.75")
+    assert mem.temp_size_in_bytes < 2 << 30 and live < 12.0 * 2**30
+
+
 # sha256 of the lowered text of the one-chip cells' programs as the
 # PARENT of PR 34 lowered them (commit 21ae0c6, this jax): that PR made
 # the fused programs adapt to their pools' shard count and left pools of

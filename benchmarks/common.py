@@ -288,12 +288,21 @@ class Checks:
             ok = bool(value is not None and np.isfinite(value)
                       and value <= limit)
         self.rows.append((name, value, limit, bool(ok)))
-        print(f"check {name}: value={value!r} limit={limit!r} "
-              f"{'ok' if ok else 'NOT OK'}", file=OUT, flush=True)
+        print(self.line(*self.rows[-1]), file=OUT, flush=True)
+
+    @staticmethod
+    def line(name, value, limit, ok) -> str:
+        return (f"check {name}: value={value!r} limit={limit!r} "
+                f"{'ok' if ok else 'NOT OK'}")
 
     @property
     def correct(self) -> bool:
         return bool(self.rows) and all(r[3] for r in self.rows)
+
+    def failed_last(self) -> list:
+        """The rows in the order added, those that failed after the
+        others: the end of what a run prints is what a record keeps."""
+        return sorted(self.rows, key=lambda r: not r[3])
 
 
 def dump(obj) -> str:

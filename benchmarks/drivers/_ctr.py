@@ -378,11 +378,13 @@ class CtrProbe:
                             st.rows[:, :w])
         return losses
 
-    def compare(self, checks, limits: dict, control: str = "") -> None:
+    def compare(self, checks, limits: dict, control: str = ""):
+        """Adds the seven numbers to `checks`; returns (the program's
+        losses, the reference's), or None where steps are missing."""
         if len(self.steps) != self.n_steps:
             checks.add("probe_steps_recorded", len(self.steps),
                        self.n_steps, ok=False)
-            return
+            return None
         program = {cls: {"first": self.after_first[cls],
                          "last": self.after_last[cls]} for cls in CLASSES}
         prog_losses = [rec["loss"] for rec in self.steps]
@@ -410,3 +412,11 @@ class CtrProbe:
                     ("probe_update_diff_share", s.worst("last", diff=True))):
                 checks.add(f"{name}.{cls}", value,
                            limits[f"{name}.{cls}"])
+        # which leaf read the worst first-gradient gap, for the log: a
+        # seed that reads high says where
+        names = {"feat": [f"table{f}" for f in range(n_leaf["feat"])],
+                 "dense": [name for name, _, _ in self.sp["tensors"]]}
+        say("first-gradient gap, worst leaf: " + "; ".join(
+            sums[cls].worst_leaf_text(cls, "first", names[cls])
+            for cls in CLASSES))
+        return prog_losses, losses

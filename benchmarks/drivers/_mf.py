@@ -209,6 +209,9 @@ class MfProbe(Probe):
         super().compare(checks, limits, control)
         if len(self.steps) != self.n_steps:
             return
-        gap = max(abs(p - q) / abs(q) for p, q in
-                  zip(self.pass_losses, self.ref_pass_losses))
-        checks.add("loss_pass_gap", gap, limits["loss_pass_gap"])
+        gaps = [abs(p - q) / abs(q) for p, q in
+                zip(self.pass_losses, self.ref_pass_losses)]
+        checks.add("loss_pass_gap", max(gaps), limits["loss_pass_gap"])
+        say("pass-end loss gaps by probe pass: " + ", ".join(
+            f"{g:.3g} (reference's {q:.6g})"
+            for g, q in zip(gaps, self.ref_pass_losses)))

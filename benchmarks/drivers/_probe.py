@@ -19,6 +19,7 @@ from __future__ import annotations
 
 import numpy as np
 
+from common import say
 from reference import adagrad_np
 
 BLOCK_EXAMPLES = 128     # examples of a step the reference works at a time
@@ -236,6 +237,12 @@ class Probe:
                    limits["probe_update_norm_gap"])
         checks.add("probe_update_diff_share", sums.worst("last", diff=True),
                    limits["probe_update_diff_share"])
+        # which leaf read the worst gap of each, for the log: a seed that
+        # reads high says where
+        say("worst leaf: " + "; ".join(
+            sums.worst_leaf_text(what, which, self.leaf_names)
+            for what, which in (("first gradient", "first"),
+                                ("update", "last"))))
 
 
 class _Keep:
@@ -293,7 +300,20 @@ class _LeafSums:
         reference's norm of the rows' change (with `diff` the norm of the
         two changes' difference), over the reference's norm of that leaf
         or of the median leaf, whichever is larger."""
+        return self.worst_leaf(which, diff)[0]
+
+    def worst_leaf(self, which: str, diff: bool = False):
+        """(`worst`'s value, the leaf that reads it, the reference's norm
+        of that leaf, of the median leaf)."""
         pp, qq, dd = np.sqrt(self.sums[which][:, self.seen[which]])
         gaps = dd if diff else np.abs(pp - qq)
         med = float(np.median(qq))
-        return max(g / max(r, med) for g, r in zip(gaps, qq))
+        shares = [g / max(r, med) for g, r in zip(gaps, qq)]
+        i = int(np.argmax(shares))
+        return (shares[i], int(np.nonzero(self.seen[which])[0][i]),
+                float(qq[i]), med)
+
+    def worst_leaf_text(self, what: str, which: str, names) -> str:
+        gap, leaf, norm, med = self.worst_leaf(which)
+        return (f"{what} {names[leaf]} {gap:.3g} (reference's norm "
+                f"{norm:.3g}, median leaf's {med:.3g})")

@@ -354,8 +354,14 @@ def check(ctx, state, out, checks) -> None:
     limits = ctx.traffic["probe_limits"]
     state["probe"].compare(checks, limits, ctx.control)
     state["turn_probe"].compare(Named(checks, "turn_"), limits, ctx.control)
-    live.compare(Named(checks, "live_"), ctx.traffic["live_probe_limits"],
-                 ctx.control)
+    lived = live.compare(Named(checks, "live_"),
+                         ctx.traffic["live_probe_limits"], ctx.control)
+    if lived:
+        # the live loss gap is relative to a TRAINED loss: say it and the
+        # passes it was trained for beside the reading
+        say(f"live probe after {len(out['losses'])} passes in the window: "
+            f"loss {lived[0][0]!r} against the reference's "
+            f"{lived[1][0]!r}")
 
 
 def close(ctx, state) -> None:

@@ -12,7 +12,9 @@ belongs to it is found by name: its configuration in
 `benchmarks/sources/<kind>.py`. See benchmarks/README.md.
 
 The last line of stdout is one JSON object (`correct`, `attempted`,
-`failed`, `metrics`, `device`, and `breakdown` in a traced run). With
+`failed`, `metrics`, `device`, `breakdown` in a traced run, then `checks`:
+every number compared beside its limit, and `not_ok`, the failed ones,
+where `correct` is false). With
 anything but the TPUs the cell asks for, the exit code is 2 and no result
 is printed. `--rehearse-cpu` is the explicit CPU rehearsal at tiny sizes:
 every line is labelled `platform=cpu`, it prints no metric under a device
@@ -29,6 +31,7 @@ import contextlib  # noqa: E402
 import importlib  # noqa: E402
 import io  # noqa: E402
 import json  # noqa: E402
+import math  # noqa: E402
 import os  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
@@ -108,6 +111,26 @@ def _layer_metrics(ctx, env) -> dict:
         if value is not None:
             out[m["name"]] = {"value": float(value), "unit": m["unit"]}
     return out
+
+
+def with_checks(result: dict, checks) -> dict:
+    """The result line's last keys: `checks`, every number compared
+    beside its limit (name -> [value, limit], those that failed last),
+    and, only where the run is not correct, `not_ok`: the failed ones
+    alone, so that the end of a refused run's line says why."""
+    def pair(value, limit):
+        # a reading that is not finite as a string: the line stays JSON
+        if value is not None and not math.isfinite(value):
+            value = repr(value)
+        return [value, limit]
+    rows = checks.failed_last()
+    result["checks"] = {name: pair(value, limit)
+                        for name, value, limit, _ in rows}
+    failed = {name: result["checks"][name]
+              for name, _, _, ok in rows if not ok}
+    if failed:
+        result["not_ok"] = failed
+    return result
 
 
 def main(argv=None) -> int:
@@ -256,7 +279,13 @@ def main(argv=None) -> int:
                   "attempted": result["attempted"],
                   "failed": result["failed"],
                   "metric_names": sorted(metrics), "device": device}
-    print(common.dump(result), file=common.OUT, flush=True)
+    # every number compared beside its limit once more, as the last lines
+    # of stderr (those that failed last) and at the end of the result line
+    print("".join(f"{label}{ctx.checks.line(*row)}\n"
+                  for row in ctx.checks.failed_last()),
+          end="", file=sys.stderr, flush=True)
+    print(common.dump(with_checks(result, ctx.checks)), file=common.OUT,
+          flush=True)
     return 0
 
 

@@ -3,7 +3,8 @@
 by an independent plain loop: the numpy references, the bytes of a fused
 step, the seeded table's hash, the trace reduction (on a synthetic trace
 with known answers and on the small recorded v5e trace in `traces/`), and
-the consistency of BENCHMARK.json with the files it names.
+the consistency of BENCHMARK.json with the files it names, and what a
+result line says of its checks.
 
     python benchmarks/selfcheck.py        # prints one line per check
 
@@ -263,11 +264,40 @@ def check_benchmark_json():
             HERE, "sources", spec["kind"] + ".py")), spec["kind"]
 
 
+def check_result_line_says_which_check_failed():
+    """`run.with_checks`: a sound run's line ends in `checks` (every
+    number beside its limit) and has no `not_ok`; a refused run's ends in
+    `not_ok`, the failed checks alone, and lists them last in `checks`;
+    a reading that is not finite leaves the line JSON."""
+    import io
+    import run
+    out, common.OUT = common.OUT, io.StringIO()
+    try:
+        sound, refused = common.Checks(), common.Checks()
+        sound.add("a_gap", 1e-7, 3e-7)
+        sound.add("b_count", 5, "> 0", ok=True)
+        refused.add("a_gap", 4e-7, 3e-7)
+        refused.add("b_count", 5, "> 0", ok=True)
+        refused.add("c_gap", float("nan"), 1e-6)
+    finally:
+        common.OUT = out
+    line = run.with_checks({"correct": sound.correct}, sound)
+    assert list(line) == ["correct", "checks"] and line["correct"] is True
+    assert line["checks"] == {"a_gap": [1e-7, 3e-7], "b_count": [5.0, "> 0"]}
+    line = json.loads(common.dump(
+        run.with_checks({"correct": refused.correct}, refused)))
+    assert list(line) == ["correct", "checks", "not_ok"]
+    assert line["correct"] is False
+    assert line["not_ok"] == {"a_gap": [4e-7, 3e-7], "c_gap": ["nan", 1e-6]}
+    assert list(line["checks"]) == ["b_count", "a_gap", "c_gap"]
+
+
 CHECKS = [check_complex_by_hand, check_complex_by_differences,
           check_sgns_by_hand, check_adagrad_by_hand, check_counts_by_hand,
           check_config_step_shapes,
           check_table_hash_by_plain_ints, check_trace_reduction_synthetic,
-          check_trace_reduction_recorded, check_benchmark_json]
+          check_trace_reduction_recorded, check_benchmark_json,
+          check_result_line_says_which_check_failed]
 
 
 def main() -> int:

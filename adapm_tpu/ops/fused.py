@@ -262,9 +262,23 @@ def _adagrad_update(g, acc, lr, eps):
         return jnp.concatenate([upd_emb, g2], axis=-1)
 
 
-# entries one call of the port's patch program takes (`patch_routes`:
-# one compiled shape); a placement change of more keys takes several calls
+# the first rung of the ladder of widths the port's patch program
+# (`patch_routes`) is compiled at: a placement change takes ONE call, at
+# the smallest rung that holds its changed keys
 PATCH_KEYS = 16384
+
+
+def patch_rungs(most: int) -> List[int]:
+    """The ladder up to the first rung that holds `most` keys: from
+    `PATCH_KEYS` by doubling, so the padding is under the keys' own
+    count above the first rung. A refresh takes the last rung of
+    `patch_rungs(its changed keys)`; `precompile` compiles
+    `patch_rungs(ab.journal_limit)`, the most a journal answers with
+    (eight rungs at 25.5 M keys)."""
+    rungs = [PATCH_KEYS]
+    while rungs[-1] < most:
+        rungs.append(2 * rungs[-1])
+    return rungs
 
 
 def _changed_keys(server, cursor) -> Optional[np.ndarray]:
@@ -316,11 +330,13 @@ class DeviceRouter:
         # of the refreshes, those that patched what was there by the
         # journal's keys (the mirrors here, a runner's local index),
         # and the entries they shipped; the wait span `fused.route_patch`
-        # holds each call of the patch program
+        # holds each call of the patch program, and the calls are counted
         self._c_patch = server.obs.counter("fused.route_patch_total",
                                            shared=True)
         self._c_patch_keys = server.obs.counter(
             "fused.route_patch_keys_total", unit="keys", shared=True)
+        self._c_patch_calls = server.obs.counter(
+            "fused.route_patch_calls_total", shared=True)
         self._h_patch = server.obs.histogram("fused.route_patch_s",
                                              shared=True)
 
@@ -351,24 +367,35 @@ class DeviceRouter:
 
     def _patch(self, keys: np.ndarray) -> None:
         """Set the mirrors' entries of `keys` (sorted, each once) to the
-        addressbook's values of now, read here under the server lock:
-        `PATCH_KEYS` entries a call of the port's program, a padding key
-        out of bounds. The program returns new tables, and every later
-        dispatch is ordered after it as after an upload."""
+        addressbook's values of now, read here under the server lock, in
+        ONE call of the port's program, at the smallest rung that holds
+        the keys (`patch_rungs`). The program returns new tables, and
+        every later dispatch is ordered after it as after an upload."""
         srv = self.server
-        ab = srv.ab
-        for lo in range(0, len(keys), PATCH_KEYS):
-            k = keys[lo:lo + PATCH_KEYS]
-            patch = np.full((4, PATCH_KEYS), OOB, np.int32)
-            patch[:, :len(k)] = (k, ab.owner[k], ab.slot[k],
-                                 ab.cache_slot[self.shard, k])
-            patch = self._put_counted(patch)
-            with srv._span("fused.route_patch", self._h_patch, wait=True):
-                self.owner, self.slot, self.cache_row = \
-                    default_port().patch_routes(
-                        self.owner, self.slot, self.cache_row, patch)
+        patch = self._put_counted(
+            self._patch_operand(keys, patch_rungs(len(keys))[-1]))
+        with srv._span("fused.route_patch", self._h_patch, wait=True):
+            self.owner, self.slot, self.cache_row = \
+                default_port().patch_routes(
+                    self.owner, self.slot, self.cache_row, patch)
+        self._c_patch_calls.inc()
         self._c_patch.inc()
         self._c_patch_keys.inc(len(keys))
+
+    def _patch_operand(self, keys: np.ndarray, width: int) -> np.ndarray:
+        """The patch program's operand for `keys`, int32 [4, width]: the
+        keys and their three table values; the padding keys count up
+        from `num_keys`: past the tables, so dropped, and ascending and
+        distinct after the keys, as the program's scatter is promised."""
+        ab = self.server.ab
+        n = len(keys)
+        patch = np.empty((4, width), np.int32)
+        patch[:, :n] = (keys, ab.owner[keys], ab.slot[keys],
+                        ab.cache_slot[self.shard, keys])
+        patch[0, n:] = np.arange(ab.num_keys, ab.num_keys + width - n,
+                                 dtype=np.int32)
+        patch[1:, n:] = OOB
+        return patch
 
     def _refresh(self):
         srv = self.server
@@ -1185,9 +1212,10 @@ class DeviceRoutedRunner:
         every gather fills zeros and every write-back is dropped, so the
         pools come back bit for bit, and neither the RNG sequence nor
         the locality counts move. On several shards the program that
-        patches the router's mirrors compiles here too
-        (`DeviceRouter._patch`). A runner with a `score_fn` compiles
-        its score program's variants the same way, on `score_aux`."""
+        patches the router's mirrors compiles here too, at every rung
+        (`DeviceRouter._patch`, `patch_rungs`). A runner with a
+        `score_fn` compiles its score program's variants the same way,
+        on `score_aux`."""
         srv = self.server
         with srv._lock:
             owner, _, cache_row, shard = self._tables()
@@ -1203,11 +1231,14 @@ class DeviceRoutedRunner:
             fns = [self._step_fn_norep]
             if srv.num_shards > 1:
                 fns.append(self.step_fn)
-                # and the mirrors' patch program at its one shape (one
-                # shard never changes placement), every key padding
-                default_port().patch_routes(
-                    owner, nowhere, no_cache, srv.ctx.put_replicated(
-                        np.full((4, PATCH_KEYS), OOB, np.int32)))
+                # and the mirrors' patch program at every rung a
+                # journal's answer can take (one shard never changes
+                # placement), every key padding
+                no_keys = np.empty(0, np.int64)
+                for n in patch_rungs(srv.ab.journal_limit):
+                    default_port().patch_routes(
+                        owner, nowhere, no_cache, srv.ctx.put_replicated(
+                            self.router._patch_operand(no_keys, n)))
             for fn in fns:
                 pools = tuple((s.main, s.cache, s.delta)
                               for s in srv.stores)

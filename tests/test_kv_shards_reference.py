@@ -421,6 +421,7 @@ def _counters(srv):
     return {n: srv.obs.find(n).snap() for n in (
         "fused.route_refresh_total", "fused.route_upload_bytes_total",
         "fused.route_patch_total", "fused.route_patch_keys_total",
+        "fused.route_patch_calls_total",
         "fused.rows_total", "fused.rows_local_total",
         "fused.rows_sampled_total",
         "fused.replica_positions", "fused.replica_chunks",
@@ -500,6 +501,8 @@ def test_a_placement_change_uploads_entries_and_not_tables(monkeypatch):
         # index are refreshed, and both by patches
         assert grew["fused.route_patch_total"] == \
             grew["fused.route_refresh_total"] == 2 * steps
+        # and the mirrors' by ONE call of the program, whatever changed
+        assert grew["fused.route_patch_calls_total"] == steps
         index, _ = run.device_runner(0)._local_neg_index()
         rebuilt = 4 * 4 * (3 * srv.num_keys + len(index))
         assert grew["fused.route_upload_bytes_total"] / steps < rebuilt / 6

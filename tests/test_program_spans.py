@@ -510,6 +510,14 @@ def test_train_step_work_and_waits_close_on_the_whole(ctx):
     assert refresh == pytest.approx(
         _hist(s, "fused.route_upload_s")["sum"]
         + _hist(s, "fused.route_patch_s")["sum"], abs=1e-9)
+    # the three counters of the patch path: calls of the program (one a
+    # wait span, and ONE a refresh of the mirrors that patched), the
+    # refreshes that patched (mirrors or a local index), their entries
+    calls = s.obs.find("fused.route_patch_calls_total").snap()
+    assert calls == _hist(s, "fused.route_patch_s")["count"]
+    assert calls <= s.obs.find("fused.route_patch_total").snap() \
+        <= s.obs.find("fused.route_refresh_total").snap()
+    assert s.obs.find("fused.route_patch_keys_total").snap() >= calls
     # the planner moved rows (8 shards, fresh intents every step): its
     # program calls are the round's waits, two and three spans deep
     stores = _hist(s, "kv.store_enqueue_s")

@@ -69,16 +69,32 @@ CASES = {
 }
 
 
+def _patch_widths(monkeypatch):
+    """The widths of the operands that the port's patch program is
+    called with from here on, one entry a call."""
+    from adapm_tpu.device import jaxport
+    widths = []
+    call = jaxport.JaxDevicePort.patch_routes
+
+    def patch_routes(self, owner, slot, cache_row, patch):
+        widths.append(patch.shape[1])
+        return call(self, owner, slot, cache_row, patch)
+    monkeypatch.setattr(jaxport.JaxDevicePort, "patch_routes", patch_routes)
+    return widths
+
+
 @pytest.mark.parametrize("patch_keys", [8, None])
 @pytest.mark.parametrize("case", sorted(CASES))
 def test_patched_tables_and_index_equal_the_full_rebuild(case, patch_keys,
                                                          tmp_path,
                                                          monkeypatch):
-    """`patch_keys` 8: a patch of the mirrors takes several calls of the
-    program; None: the program's shipped operand, one call."""
+    """`patch_keys` 8: a first rung of 8, so the seeded bursts land on
+    several rungs of the ladder; None: the shipped first rung, which
+    holds them all. One call of the program a patch either way."""
     from adapm_tpu.ops import fused
     if patch_keys is not None:
         monkeypatch.setattr(fused, "PATCH_KEYS", patch_keys)
+    widths = _patch_widths(monkeypatch)
     population, favoured = CASES[case]
     srv = adapm_tpu.setup(K, L, num_shards=S, opts=SystemOptions(
         sync_max_per_sec=0, cache_slots_per_shard=32, main_over_alloc=3.0))
@@ -149,9 +165,135 @@ def test_patched_tables_and_index_equal_the_full_rebuild(case, patch_keys,
         refreshes = srv.obs.find("fused.route_refresh_total").snap()
         assert _patches(srv) > 40 and refreshes > _patches(srv)
         assert srv.obs.find("fused.route_patch_keys_total").snap() > 0
+        # every call at a rung, and on several where the first is small
+        assert srv.obs.find("fused.route_patch_calls_total").snap() == \
+            len(widths) > 40
+        assert set(widths) <= set(fused.patch_rungs(ab.journal_limit))
+        assert (len(set(widths)) > 2) is (patch_keys is not None)
         assert len(capacities) > 1 or case != "doubling"
         assert fallbacks == ({False, True} if case == "fallback"
                              else {False})
+    finally:
+        srv.shutdown()
+
+
+# a table of 20,000 x 16 keys: the journal holds 20,000 entries, so the
+# ladder is the shipped first rung and one more
+BIG = 320_000
+
+
+@pytest.mark.parametrize("changed", [1, 16_384, 16_385, BIG // 16,
+                                     BIG // 16 + 1])
+def test_a_refresh_is_one_call_at_the_rung_that_holds_its_keys(
+        changed, monkeypatch):
+    """However many keys changed placement since a router's last look,
+    its refresh is ONE call of the port's program, at the smallest rung
+    that holds them, and the mirrors are the addressbook's tables; one
+    key more than the journal holds is a rebuild and no call."""
+    from adapm_tpu.ops import fused
+    widths = _patch_widths(monkeypatch)
+    srv = adapm_tpu.setup(BIG, 2, num_shards=S, opts=SystemOptions(
+        sync_max_per_sec=0, cache_slots_per_shard=32, main_over_alloc=1.5))
+    try:
+        ab = srv.ab
+        assert ab.journal_limit == BIG // 16
+        assert fused.patch_rungs(ab.journal_limit) == [16_384, 32_768]
+        router = fused.DeviceRouter(srv, 1)
+
+        def counters():
+            return [srv.obs.find("fused.route_" + n).snap() for n in (
+                "refresh_total", "patch_total", "patch_calls_total",
+                "patch_keys_total")]
+
+        def look():
+            """The counters' growth over one look at the mirrors, which
+            have to be the addressbook's tables."""
+            before = counters()
+            with srv._lock:
+                owner, slot, cache_row = map(np.asarray, router.tables())
+            assert owner.dtype == slot.dtype == cache_row.dtype == np.int32
+            assert np.array_equal(owner, ab.owner)
+            assert np.array_equal(slot, ab.slot)
+            assert np.array_equal(cache_row, ab.cache_slot[1])
+            return [b - a for a, b in zip(before, counters())]
+
+        assert look() == [1, 0, 0, 0]               # set-up: the build
+        # keys that shard 0 does not own move there, in ONE mutation
+        keys = np.flatnonzero(np.arange(BIG) % S != 0)[:changed]
+        srv._relocate_to(keys, 0)
+        assert (ab.owner[keys] == 0).all()
+        if changed <= ab.journal_limit:
+            assert look() == [1, 1, 1, changed]
+            assert widths == [16_384 if changed <= 16_384 else 32_768]
+        else:
+            assert look() == [1, 0, 0, 0] and widths == []
+        assert look() == [0, 0, 0, 0]               # nothing changed since
+        del widths[:]
+        held = np.arange(BIG - 30, BIG, 4)          # eight of shard 2's
+        srv._create_replicas(held, 1)               # `cache_row` moves too
+        assert (ab.cache_slot[1, held] >= 0).all()
+        assert look() == [1, 1, 1, 8] and widths == [16_384]
+    finally:
+        srv.shutdown()
+
+
+@pytest.mark.parametrize("changed, width", [
+    (0, 16_384), (1, 16_384), (16_384, 16_384), (16_385, 32_768),
+    (BIG // 16, 32_768)])
+def test_patch_operand_keeps_the_promise_made_to_the_scatter(changed,
+                                                             width):
+    """The patch program's scatter is told its keys ascend and none
+    repeats (`jaxport._patch_routes`); a false promise is undefined
+    behaviour, so the operand is held to it here: row 0 strictly
+    ascending over the keys AND the padding, every padding key past the
+    tables (dropped), its values `OOB`, the keys' values the
+    addressbook's."""
+    from adapm_tpu.ops import fused
+    srv = adapm_tpu.setup(BIG, 2, num_shards=S, opts=SystemOptions(
+        sync_max_per_sec=0, cache_slots_per_shard=32, main_over_alloc=1.5))
+    try:
+        ab = srv.ab
+        router = fused.DeviceRouter(srv, 1)
+        # the keys as `_changed_keys` hands them over, spread over the
+        # table: from two keys on the last is the table's last
+        keys = np.unique(np.linspace(0, BIG - 1, changed).astype(np.int64))
+        assert len(keys) == changed
+        assert fused.patch_rungs(changed)[-1] == width
+        patch = router._patch_operand(keys, width)
+        assert patch.dtype == np.int32 and patch.shape == (4, width)
+        assert (np.diff(patch[0].astype(np.int64)) > 0).all()
+        assert np.array_equal(patch[0, :changed], keys)
+        assert (patch[0, changed:] >= ab.num_keys).all()
+        assert (patch[1:, changed:] == fused.OOB).all()
+        assert np.array_equal(patch[1, :changed], ab.owner[keys])
+        assert np.array_equal(patch[2, :changed], ab.slot[keys])
+        assert np.array_equal(patch[3, :changed], ab.cache_slot[1, keys])
+    finally:
+        srv.shutdown()
+
+
+def test_changed_keys_are_sorted_and_distinct_after_repeats():
+    """The other half of the promise: a journal lists a key once for
+    every mutation that touched it, in the order of the mutations, and
+    `_changed_keys` returns each once and ascending."""
+    from adapm_tpu.ops import fused
+    srv = adapm_tpu.setup(K, L, num_shards=S, opts=SystemOptions(
+        sync_max_per_sec=0, cache_slots_per_shard=32, main_over_alloc=3.0))
+    try:
+        ab = srv.ab
+        cursor = ab.journal_cursor()
+        srv._relocate_to(np.array([90, 13, 57]), 0)
+        srv._relocate_to(np.array([57, 13, 201]), 2)
+        srv._create_replicas(np.array([201, 90, 5]), 3)
+        journal = ab.changed_since(cursor)
+        assert len(journal) > len(set(journal.tolist()))     # repeats
+        assert (np.diff(journal) < 0).any()                  # unsorted
+        with srv._lock:
+            keys = fused._changed_keys(srv, cursor)
+        assert keys.tolist() == [5, 13, 57, 90, 201]
+        with srv._lock:
+            assert len(fused._changed_keys(srv, ab.journal_cursor())) == 0
+            assert fused._changed_keys(srv, None) is None
     finally:
         srv.shutdown()
 

@@ -557,6 +557,10 @@ def test_four_shard_step_is_a_per_chip_program(no_replicas, topo,
 # replica slots of the feature class and of the dense class, keys
 CTR4_FEAT, CTR4_DENSE, CTR4_KEYS = (6_763_632, 131_072), (4_160, 15_680), \
     25_523_124 + 15_676
+# a chip's six pools: main, cache and delta of both classes (rows of 256
+# and of L floats)
+CTR4_POOL_BYTES = sum((main + 2 * cache) * row * 4 for (main, cache), row
+                      in ((CTR4_FEAT, 256), (CTR4_DENSE, L)))
 
 
 def test_four_shard_ctr_step_walks_two_classes_of_replicas(
@@ -614,8 +618,7 @@ def test_four_shard_ctr_step_walks_two_classes_of_replicas(
         (layout.num_rows, 1024), (DLRM_M, DLRM_B, 128),
         (DLRM_M * DLRM_B, 128)}, summed
     mem = compiled.memory_analysis()
-    pool_bytes = sum((main + 2 * cache) * row * 4 for (main, cache), row
-                     in ((CTR4_FEAT, 256), (CTR4_DENSE, L)))
+    pool_bytes = CTR4_POOL_BYTES
     assert mem.alias_size_in_bytes >= pool_bytes
     live = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
@@ -650,23 +653,35 @@ PARENT_LOWERED = {
 _MOSAIC_BODY = re.compile(r'\\22body\\22: \\22[A-Za-z0-9+/=]*\\22')
 
 
-def test_route_patch_is_each_chip_for_itself(topo):
+@pytest.mark.parametrize("num_keys, rung", [
+    (KV4_KEYS, "first"), (KV4_KEYS, "last"),
+    (CTR4_KEYS, "first"), (CTR4_KEYS, "last")])
+def test_route_patch_is_each_chip_for_itself(num_keys, rung, topo):
     """The program that patches a router's three table mirrors
-    (`jaxport._patch_routes`), at the four-shard cell's 4,595,307 keys
-    and `fused.PATCH_KEYS` entries, compiled for the described v5e 2x2
-    with everything replicated: every chip sets the entries of its own
-    copies, nothing crosses the chips, and what it holds beside the
-    three new tables is nothing to speak of."""
+    (`jaxport._patch_routes`), at both four-shard hosts' key counts and
+    the first and the last rung of the widths it is called at there
+    (`fused.patch_rungs` of the journal's bound: 16,384 to 2^19 entries
+    at the KGE host's 4.6 M keys, to 2^21 at the click model's 25.5 M),
+    compiled for the described v5e 2x2 with everything
+    replicated: every chip sets the entries of its own copies, nothing
+    crosses the chips, and what it holds beside the three new tables is
+    under one table. At the click model's sizes one call's new tables,
+    operand and temporaries fit beside the pools, the four runners'
+    tables and a step's temporaries on a chip."""
     import numpy as np
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
     from adapm_tpu.device import jaxport
     from adapm_tpu.ops import fused
+    rungs = fused.patch_rungs(max(4096, num_keys // 16))
+    assert rungs[0] == fused.PATCH_KEYS and len(rungs) <= 8
+    assert rungs[-1] == (2 ** 19 if num_keys == KV4_KEYS else 2 ** 21)
+    entries = rungs[0] if rung == "first" else rungs[-1]
     everywhere = NamedSharding(
         Mesh(np.asarray(topo.devices[:4]), ("kv",)), P())
-    table = jax.ShapeDtypeStruct((KV4_KEYS,), jnp.int32,
+    table = jax.ShapeDtypeStruct((num_keys,), jnp.int32,
                                  sharding=everywhere)
-    patch = jax.ShapeDtypeStruct((4, fused.PATCH_KEYS), jnp.int32,
+    patch = jax.ShapeDtypeStruct((4, entries), jnp.int32,
                                  sharding=everywhere)
     compiled = jaxport._patch_routes.lower(
         table, table, table, patch).compile()
@@ -676,8 +691,57 @@ def test_route_patch_is_each_chip_for_itself(topo):
         assert collective not in text, collective
     assert all(s == everywhere for s in compiled.output_shardings)
     mem = compiled.memory_analysis()
-    assert mem.output_size_in_bytes >= 3 * 4 * KV4_KEYS
-    assert mem.temp_size_in_bytes < 4 * KV4_KEYS
+    print(f"route patch at {num_keys} keys, {entries} entries: "
+          f"{mem.temp_size_in_bytes / 1e6:.1f} MB of temporaries beside "
+          f"{mem.output_size_in_bytes / 1e6:.1f} MB of tables")
+    assert mem.output_size_in_bytes >= 3 * 4 * num_keys
+    assert mem.temp_size_in_bytes < 4 * num_keys
+    if num_keys == CTR4_KEYS:
+        # what the configuration holds on a chip whatever the patch does
+        # (benchmarks/configs/dlrm-dcnv2-criteo1tb-kv4.json `memory`):
+        # the pools, four runners' three tables, a step's temporaries
+        # (under 2 GiB: the test of the step above)
+        held = CTR4_POOL_BYTES + 4 * 3 * 4 * num_keys + (2 << 30)
+        call = (mem.output_size_in_bytes + 4 * 4 * entries
+                + mem.temp_size_in_bytes)
+        assert held + call < 15.75 * 2**30, (held, call)
+
+
+def test_precompile_on_several_shards_leaves_no_rung_to_compile():
+    """After `DeviceRoutedRunner.precompile` on four shards (virtual CPU
+    devices: the ladder is the host's, whatever the backend) a patch of
+    any admissible size, from one key to all the journal holds, finds
+    its rung compiled: the port's program gains no entry."""
+    import numpy as np
+
+    import adapm_tpu
+    from adapm_tpu.config import SystemOptions
+    from adapm_tpu.device import jaxport
+    from adapm_tpu.ops import DeviceRoutedRunner, fused
+    num_keys = 16 * 70_000      # a ladder of four rungs, to 131,072
+    srv = adapm_tpu.setup(num_keys, 2, num_shards=4, opts=SystemOptions(
+        sync_max_per_sec=0, cache_slots_per_shard=32, main_over_alloc=1.5))
+    try:
+        rungs = fused.patch_rungs(srv.ab.journal_limit)
+        assert rungs == [16_384, 32_768, 65_536, 131_072]
+        runner = DeviceRoutedRunner(
+            srv, lambda embs, aux: (embs["a"] ** 2).mean(),
+            role_class={"a": 0}, role_dim={"a": 1}, shard=1)
+        runner.precompile({"a": np.zeros(8, np.int64)})
+        compiled = jaxport._patch_routes._cache_size()
+        patches = srv.obs.find("fused.route_patch_calls_total")
+        movable = np.flatnonzero(np.arange(num_keys) % 4 != 0)
+        at = 0
+        for changed in (1, 16_384, 16_385, 65_537, srv.ab.journal_limit):
+            before = patches.snap()
+            srv._relocate_to(movable[at:at + changed], 0)
+            at += changed
+            with srv._lock:
+                runner.router.refresh()
+            assert patches.snap() == before + 1, changed
+        assert jaxport._patch_routes._cache_size() == compiled
+    finally:
+        srv.shutdown()
 
 
 def _one_chip_lowered(shape, monkeypatch):

@@ -108,6 +108,16 @@ class _ReplicaSide(NamedTuple):
     count: jnp.ndarray  # how many there are, an int32 scalar
 
 
+def _compact(here, rows: int):
+    """The flat positions where `here` holds, ascending, then `n`s to
+    whole chunks of `rows` (a slice of the last chunk must not slide
+    back), and their count, an int32 scalar."""
+    n = here.shape[0]
+    order = jnp.sort(jnp.where(here, jax.lax.iota(jnp.int32, n), n))
+    order = jnp.pad(order, (0, -n % rows), constant_values=n)
+    return order, jnp.sum(here, dtype=jnp.int32)
+
+
 def _replica_side(cache, c_sh, c_sl) -> _ReplicaSide:
     """Compact a role's replica positions: those whose replica
     coordinates `(c_sh, c_sl)` lie inside the pool `cache` (and so
@@ -115,13 +125,9 @@ def _replica_side(cache, c_sh, c_sl) -> _ReplicaSide:
     position's slot out of bounds, and `_on_this_chip` those of a
     replica that another chip holds."""
     sh, sl = c_sh.reshape(-1), c_sl.reshape(-1)
-    n = sl.shape[0]
     here = _in_bounds(sh, cache.shape[0]) & _in_bounds(sl, cache.shape[1])
-    order = jnp.sort(jnp.where(here, jax.lax.iota(jnp.int32, n), n))
-    # whole chunks: a slice of the last one must not slide back
-    order = jnp.pad(order, (0, -n % _chunk_rows(n)), constant_values=n)
-    return _ReplicaSide(sh, sl, here, order,
-                        jnp.sum(here, dtype=jnp.int32))
+    return _ReplicaSide(sh, sl, here,
+                        *_compact(here, _chunk_rows(sl.shape[0])))
 
 
 def _replica_chunks(count, n: int):
@@ -180,6 +186,70 @@ def _replica_writeback(delta, side: _ReplicaSide, g, acc, lr, eps):
 
     with jax.named_scope("adapm_scatter_add"):
         return _for_replica_chunks(side, add, delta)
+
+
+# Bytes of one block of the per-chip step's exchange (`_exchange`): the
+# positions of a chunk follow from the role's row length, a static
+# property of the compiled variant: 2,048 positions at the 128 embedding
+# columns of a 1 KB row, 256 at the 1,024 of an 8 KB row. A chunk costs
+# by its positions, off the chip or padding alike, and most of it is
+# the set, XLA's scatter, not the sum: on a v5e 2x2 blocks of 1 MB beat
+# 2, 4, 8 and 16 MB at both row lengths by a tenth at most, and waste
+# the least of the last chunk (`scripts/exchange_probe.py`; PERF.md
+# section 6, PR 43).
+EXCHANGE_BYTES = 1 << 20
+
+
+class _Away(NamedTuple):
+    """The positions of one role named by key whose row lies OFF the
+    worker's chip, compacted: what the per-chip step's exchange sums
+    over the axis (`_exchange`). Computed from the global route, so the
+    same on every chip."""
+    order: jnp.ndarray  # those flat positions ascending, then `n`s
+    count: jnp.ndarray  # how many there are, an int32 scalar
+    rows: int           # positions one chunk takes (static)
+
+    @property
+    def chunks(self):
+        return (self.count + (self.rows - 1)) // self.rows
+
+
+def _away(whole, main, shard, axis, dim: int) -> _Away:
+    """Compact the positions whose row another chip than the worker's
+    holds: the global route `whole` is in bounds (a key that is nowhere
+    and a padding position read zeros on every chip, and a position
+    that reads the worker shard's replica has main's slot out of
+    bounds: `_route_on_device`) and names another shard than `shard`.
+    `main` is a chip's block, `[1, slots, L]`."""
+    sh, sl = whole[0].reshape(-1), whole[1].reshape(-1)
+    n = sl.shape[0]
+    width = jax.lax.axis_size(axis)
+    off = _in_bounds(sh, width) & _in_bounds(sl, main.shape[1]) \
+        & (sh != shard) & (sh != shard - width)
+    rows = min(n, max(1, EXCHANGE_BYTES // (4 * dim)))
+    return _Away(*_compact(off, rows), rows)
+
+
+def _exchange(x, away: _Away, axis):
+    """`x` (a role's `[..., dim]` values by position) with its rows at
+    the positions `away` lists summed over the axis, a chunk at a time:
+    each chip's own rows of the chunk (zeros past the last position),
+    ONE `psum` of the `[rows, dim]` block, and the sum set back at
+    those positions. Every other position keeps the chip's own value.
+    A loop whose count the program reads from its input, the same on
+    every chip: none where every row named lies on the worker's chip."""
+    flat = x.reshape(-1, x.shape[-1])
+
+    def chunk(t, flat):
+        idx = jax.lax.dynamic_slice(away.order, (t * away.rows,),
+                                    (away.rows,))
+        block = jax.lax.psum(
+            flat.at[idx].get(mode="fill", fill_value=0), axis)
+        return flat.at[idx].set(block, mode="drop")
+
+    with jax.named_scope("adapm_exchange"):
+        return jax.lax.fori_loop(0, away.chunks, chunk, flat) \
+            .reshape(x.shape)
 
 
 def writeback_uses_kernel(main, backend: str = None) -> bool:
@@ -669,17 +739,21 @@ def _classes_counted(role_class) -> list:
 
 
 def _route_and_gather(pools, tables, keys, roles, role_class, role_dim,
-                      no_replicas, axis=None):
+                      no_replicas, axis=None, drawn=()):
     """Route every role's keys and gather their rows: the read half of a
     fused step, shared with the gather-only score program
-    (`make_device_routed_score`). Returns (embs, accs, routes, counts):
-    each role's embedding columns and accumulator columns in the shape
-    of its keys (a sampled role's are sample-major, `[N, B]`:
+    (`make_device_routed_score`). Returns (embs, accs, routes, away,
+    counts): each role's embedding columns and accumulator columns in
+    the shape of its keys (a sampled role's are sample-major, `[N, B]`:
     `_build_device_routed_body`), its route
     (main's shard and slot; in the replica variant the compacted replica
-    positions too, a `_ReplicaSide`), and the step's counts (n_total,
-    n_local, n_replica, n_chunks, and the last two once more for each
-    length class where the roles span several, `_classes_counted`): the
+    positions too, a `_ReplicaSide`), with `axis` the positions of each
+    role named by key that lie off the worker's chip (an `_Away`), and
+    the step's counts (n_total,
+    n_local, n_replica, n_chunks, the last two once more for each
+    length class where the roles span several, `_classes_counted`, and
+    the exchange's: the positions off the worker's chip, then each
+    role's positions in the blocks summed for them, whole chunks): the
     device-side locality counts
     (reference coloc_kv_server.h:147-157 prints % accesses served
     locally; Pull/Push record this in Server._route, which a step never
@@ -706,10 +780,16 @@ def _route_and_gather(pools, tables, keys, roles, role_class, role_dim,
     routes are the chip's own, zeros and out of bounds for what lies
     elsewhere. The counts come from the global routes, so every chip
     holds the same (the replica positions are the worker chip's: the
-    other chips find none of them and run no chunk)."""
-    embs, accs, routes = {}, {}, {}
+    other chips find none of them and run no chunk). Then the exchange:
+    of the roles named by key (all but `drawn`) the rows that lie OFF
+    the worker's chip are summed over the axis (`_away`, `_exchange`),
+    after which the worker's chip holds every row its batch names. The
+    other chips hold their own rows and those: what they compute from
+    them the step masks."""
+    embs, accs, routes, away = {}, {}, {}, {}
     n_total = 0
-    n_local = n_replica = n_chunks = jnp.int32(0)
+    n_local = n_replica = n_chunks = n_away = jnp.int32(0)
+    away_rows = {r: jnp.int32(0) for r in roles}  # in whole chunks
     # class -> [replica positions, chunks], where the roles span several
     by_class = {cid: [jnp.int32(0), jnp.int32(0)]
                 for cid in _classes_counted(role_class)}
@@ -739,6 +819,11 @@ def _route_and_gather(pools, tables, keys, roles, role_class, role_dim,
                 valid |= side.here.reshape(valid.shape)
                 routes[r] += (side,)
             embs[r] = jnp.where(valid[..., None], rows[..., :dim], 0)
+        if axis is not None and r not in drawn:
+            away[r] = _away(whole, main, shard, axis, dim)
+            embs[r] = _exchange(embs[r], away[r], axis)
+            n_away += away[r].count
+            away_rows[r] = away[r].chunks * away[r].rows
         local = whole[0] == shard
         accs[r] = rows[..., dim:]
         if not no_replicas:
@@ -754,9 +839,10 @@ def _route_and_gather(pools, tables, keys, roles, role_class, role_dim,
                 by_class[cid][0] += held
                 by_class[cid][1] += chunks
         n_local += jnp.sum(local, dtype=jnp.int32)
-    return embs, accs, routes, (
+    return embs, accs, routes, away, (
         n_total, n_local, n_replica, n_chunks,
-        [n for cid in sorted(by_class) for n in by_class[cid]])
+        [n for cid in sorted(by_class) for n in by_class[cid]],
+        [n_away] + [away_rows[r] for r in roles])
 
 
 def make_device_routed_score(score_fn: Callable[..., jnp.ndarray],
@@ -780,14 +866,18 @@ def make_device_routed_score(score_fn: Callable[..., jnp.ndarray],
 
     def build(axis):
         def score(pools, tables, keys, aux, acc):
-            embs, _, _, _ = _route_and_gather(
+            # per chip: the worker's chip holds every row (the exchange)
+            # and the score is its own, summed with zeros
+            embs = _route_and_gather(
                 pools, tables, dict(keys), roles, role_class, role_dim,
-                no_replicas, axis)
-            if axis is not None:  # per chip: the rows it holds, summed
-                with jax.named_scope("adapm_exchange"):
-                    embs = jax.lax.psum(embs, axis)
+                no_replicas, axis)[0]
             with jax.named_scope("adapm_loss_grad"):
-                return acc + score_fn(embs, aux)
+                got = score_fn(embs, aux)
+                if axis is not None:
+                    with jax.named_scope("adapm_exchange"):
+                        got = jax.lax.psum(jnp.where(
+                            _here(tables[3], axis), got, 0), axis)
+                return acc + got
         return score
 
     return _PoolProgram(build, False)
@@ -804,8 +894,9 @@ def _build_device_routed_body(loss_fn, role_class, role_dim,
     pools of several shards): it is then the PER-CHIP step. `pools` are
     the chip's own `[1, slots, L]` blocks of main, cache and delta;
     everything else is the same on every chip. It is the one-shard step
-    on routes brought onto the chip (`_on_this_chip`), with two sums
-    over the axis between its parts:
+    on routes brought onto the chip (`_on_this_chip`), with an exchange
+    over the axis between its parts, of the rows that lie OFF the
+    worker's chip and of nothing else:
 
       - the sampled role is local by construction (drawn from the
         worker shard's own resident keys: main rows it owns, or its
@@ -815,14 +906,27 @@ def _build_device_routed_body(loss_fn, role_class, role_dim,
         everywhere: clamped or zero-filled gathers, dropped write-backs;
       - the roles named by key are gathered where they lie (the main
         copy on its owner's chip, a replica of the worker's shard on the
-        worker's chip, zeros elsewhere) and ONE sum over the axis gives
-        every chip their embedding columns. Their gradients, formed on
-        the worker's chip, go back through one more sum with zeros from
-        the other chips, and each chip writes back the rows it holds,
-        from the accumulators it gathered itself;
-      - the loss is the worker chip's, summed with zeros likewise. The
-        locality counts are computed from the global routes, alike on
-        every chip.
+        worker's chip, zeros elsewhere). The positions whose row
+        another chip than the worker's holds are compacted from the
+        global route, the same list on every chip (`_away`), and
+        walked in chunks of `EXCHANGE_BYTES` by a loop whose count the
+        program reads from its input: a chunk's rows, one sum of the
+        `[chunk, dim]` block over the axis, set back (`_exchange`).
+        The worker's chip then holds every row its batch names; the
+        other chips hold their own and those, and what they compute
+        from them is masked. The gradients, the worker chip's and
+        zeros on the others, go back by the same walk: every chip then
+        holds the worker's gradient at the positions it may own, and
+        writes back the rows it holds, from the accumulators it
+        gathered itself. A batch whose named rows all lie on the
+        worker's chip (the CTR step's dense role, all replicas or its
+        own) runs no chunk; one whose rows all lie elsewhere sums all
+        its positions, as the whole-array sum that this replaced did
+        for every batch;
+      - the loss is the worker chip's, summed with zeros from the
+        others. The locality counts and the exchange's own (the
+        positions off the chip, the blocks summed) are computed from
+        the global routes, alike on every chip.
 
     Inside the map a chip's main block is one shard, so the write-back
     kernel applies (`writeback_uses_kernel`) in both variants.
@@ -893,33 +997,33 @@ def _build_device_routed_body(loss_fn, role_class, role_dim,
             if sample_major:
                 # sample-major from here on; the values at [b, k] stay
                 keys[neg_role] = jnp.moveaxis(keys[neg_role], -1, 0)
-        embs, accs, routes, counts = _route_and_gather(
+        embs, accs, routes, away, counts = _route_and_gather(
             pools, tables, keys, roles, role_class, role_dim, no_replicas,
-            axis)
-        if axis is not None:
-            with jax.named_scope("adapm_exchange"):
-                embs.update(jax.lax.psum(
-                    {r: embs[r] for r in roles if r not in drawn}, axis))
+            axis, drawn)
         # one step = one (batched) pull op + one push op of the same keys;
         # the op counts local iff every key it touched was local
-        n_total, n_local, n_replica, n_chunks, by_class = counts
+        n_total, n_local, n_replica, n_chunks, by_class, exchanged = counts
         all_local = (n_local == n_total).astype(jnp.int32)
         # the accumulator takes as many of the step's counts as it has
         # entries (`DeviceRoutedRunner._locstat`)
         locstat = locstat + jnp.stack(
             [jnp.int32(n_total), n_local, jnp.int32(1), all_local,
-             n_replica, n_chunks, *by_class][:locstat.shape[0]])
+             n_replica, n_chunks, *by_class, *exchanged]
+            [:locstat.shape[0]])
         loss, grads = _loss_and_grads(batch_major_loss, embs, trainable,
                                       aux)
         if axis is not None:
-            # the worker chip's loss and gradients: zeros from the others
+            # the worker chip's loss, zeros from the others; its
+            # gradients at the positions whose row another chip holds
+            # (a chip keeps its own elsewhere: the worker's are the
+            # step's, the others' zeros, which no route of theirs takes)
             worker = _here(tables[3], axis)
             with jax.named_scope("adapm_exchange"):
-                loss, named = jax.lax.psum(jax.tree_util.tree_map(
-                    lambda x: jnp.where(worker, x, 0),
-                    (loss, {r: grads[r] for r in trainable
-                            if r not in drawn})), axis)
-            grads.update(named)
+                loss = jax.lax.psum(jnp.where(worker, loss, 0), axis)
+            for r in trainable:
+                if r in away:
+                    grads[r] = _exchange(jnp.where(worker, grads[r], 0),
+                                         away[r], axis)
 
         new_pools = list(pools)
         for r in trainable:
@@ -978,7 +1082,8 @@ class DeviceRoutedRunner:
 
     Locality is recorded by a small device accumulator folded into the
     step program (params seen / params local / steps / all-local steps;
-    on several shards also replica positions / side-path chunks) and
+    on several shards also replica positions / side-path chunks, and
+    the exchange's positions and blocks) and
     drained to the host lazily — at `locality_counts()` (which
     Server.locality_summary calls) and often enough that the int32 counters
     cannot wrap. Per-KEY counters (--sys.stats.locality tsv dumps) are
@@ -1083,10 +1188,14 @@ class DeviceRoutedRunner:
         # compiles the replica-free variant alone, on the accumulator
         # (and so to the program) it always had
         # Roles of several length classes: a pair more for each class,
-        # its own replica positions and chunks (`_classes_counted`)
+        # its own replica positions and chunks (`_classes_counted`).
+        # Then the per-chip step's exchange: the positions whose row lay
+        # off the worker's chip, and each role's positions in the
+        # blocks summed for them, whole chunks (`_exchange`)
         self._locstat_zero = np.zeros(
             4 if server.num_shards == 1
-            else 6 + 2 * len(_classes_counted(role_class)), np.int32)
+            else 6 + 2 * len(_classes_counted(role_class))
+            + 1 + len(role_class), np.int32)
         self._locstat = server.ctx.put_replicated(self._locstat_zero)
         self._loc_host = np.zeros(4, dtype=np.int64)
         self._drain_every = None  # set on first step (needs params/step)
@@ -1129,13 +1238,19 @@ class DeviceRoutedRunner:
             "fused.writeback_rows_total", unit="rows", shared=True)
         self._c_wb_kernel_rows = server.obs.counter(
             "fused.writeback_kernel_rows_total", unit="rows", shared=True)
-        # bytes the per-chip steps summed over the mesh's kv axis (the
-        # rows named by key out, their gradients back, the loss): 0 on
-        # one shard, and GSPMD's own collectives are not counted
+        # bytes the per-chip steps summed over the mesh's kv axis: the
+        # exchange's blocks as the step counted them (the rows named by
+        # key that lay off the worker's chip out, their gradients back,
+        # in whole chunks: the padding of a role's last chunk crosses
+        # the wire too), moved at each drain, and the loss's scalar a
+        # step. 0 on one shard, and GSPMD's own collectives are not
+        # counted. And the positions those blocks were summed for
         self._c_exchange = server.obs.counter(
             "fused.exchange_bytes_total", unit="bytes", shared=True)
+        self._c_exchange_positions = server.obs.counter(
+            "fused.exchange_positions", unit="rows", shared=True)
         # a step's (rows written back, those of them in pools the kernel
-        # takes, bytes exchanged); set on first step
+        # takes); set on first step
         self._per_step = None
         # rows the gather-only score program read (`score`)
         self._c_score_rows = server.obs.counter(
@@ -1154,7 +1269,7 @@ class DeviceRoutedRunner:
         self._c_rows_sampled = server.obs.counter(
             "fused.rows_sampled_total", unit="rows", shared=True)
         self._sampled_pending = 0  # since the last drain
-        # the accumulator's last two entries, moved at each drain too:
+        # the accumulator's fifth and sixth entries, moved at each drain too:
         # positions the steps read from (and wrote to) a replica, and
         # the side-path chunks that took; a chunk a role a step says
         # `SIDE_ROWS` always sufficed
@@ -1377,12 +1492,13 @@ class DeviceRoutedRunner:
 
     def _count_step(self, role_keys: Dict[str, np.ndarray],
                     steps: int) -> None:
-        """Count the rows `steps` dispatched steps write back and the
-        bytes they exchange (from the first batch's key shapes, fixed
-        per runner, like the drain interval above)."""
+        """Count the rows `steps` dispatched steps write back (from the
+        first batch's key shapes, fixed per runner, like the drain
+        interval above) and the scalar each per-chip step sums for its
+        loss; the exchange's blocks are the step's own to count
+        (`_drain_locstat`)."""
         if self._per_step is None:
-            named = {r: np.asarray(k).size for r, k in role_keys.items()}
-            rows = dict(named)
+            rows = {r: np.asarray(k).size for r, k in role_keys.items()}
             if self.neg_role is not None:
                 rows[self.neg_role] = int(np.prod(self._neg_shape))
             rows = {r: n for r, n in rows.items()
@@ -1392,19 +1508,16 @@ class DeviceRoutedRunner:
                 main = self.server.stores[self.role_class[r]].main
                 return writeback_uses_kernel(jax.ShapeDtypeStruct(
                     (1,) + main.shape[1:], main.dtype))
-            dim = self._mk_kwargs["role_dim"]
             self._per_step = (
                 sum(rows.values()),
-                sum(n for r, n in rows.items() if takes_kernel(r)),
-                4 + 4 * sum(n * dim[r] * (1 + (r in rows))
-                            for r, n in named.items()))
-        rows, kernel_rows, exchanged = self._per_step
+                sum(n for r, n in rows.items() if takes_kernel(r)))
+        rows, kernel_rows = self._per_step
         self._c_wb_rows.inc(rows * steps)
         if self._one_program_over_shards():
             return  # GSPMD: no kernel, and its collectives are its own
         self._c_wb_kernel_rows.inc(kernel_rows * steps)
         if self.server.num_shards > 1:
-            self._c_exchange.inc(exchanged * steps)
+            self._c_exchange.inc(4 * steps)
 
     def _count_sampled(self, steps: int) -> None:
         """Count the rows `steps` dispatched steps drew from the local
@@ -1428,12 +1541,22 @@ class DeviceRoutedRunner:
         self._sampled_pending = 0
         # the totals, then each class's pair; roles of one class count
         # no pair apart: the totals are that class's
-        counts = vals[4:]
+        roles = sorted(self.role_class)
+        counts, away, blocks = np.split(
+            vals[4:], [-len(roles) - 1, -len(roles)])  # one shard: empty
         if len(counts) == 2:
             counts = np.tile(counts, 2)
         for c, v in zip([self._c_replica_positions, self._c_replica_chunks]
                         + self._c_replica_by_class, counts):
             c.inc(int(v))
+        # the exchange: the positions off the worker's chip, then each
+        # role's positions in the blocks summed for them (whole chunks),
+        # of its `dim` float32 columns, out and (a trained role's) back
+        self._c_exchange_positions.inc(int(away.sum()))
+        dim = self._mk_kwargs["role_dim"]
+        for r, n in zip(roles, blocks):
+            self._c_exchange.inc(
+                int(n) * dim[r] * 4 * (1 + (r not in self.frozen_roles)))
         self._locstat = self.server.ctx.put_replicated(self._locstat_zero)
         self._c_drains.inc()
 

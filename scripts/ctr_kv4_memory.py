@@ -84,7 +84,7 @@ def main(cache: int, over_alloc: float) -> None:
             no_replicas)
         t0 = time.time()
         compiled = step.lower(
-            pools, shape((10,), jnp.int32),
+            pools, shape((13,), jnp.int32),
             tuple(shape((num_keys,), jnp.int32) for _ in range(3))
             + (shape((), jnp.int32),),
             {"feat": shape((sum(HOT), B), jnp.int32),

@@ -62,7 +62,7 @@ def main(cache: int) -> None:
             {r: L // 2 for r in roles}, (), "neg", (B, N), no_replicas)
         t0 = time.time()
         compiled = step.lower(
-            (pool,), shape((4,), jnp.int32),
+            (pool,), shape((11,), jnp.int32),
             tuple(shape((NUM_KEYS,), jnp.int32) for _ in range(3))
             + (shape((), jnp.int32),),
             {r: shape((B,), jnp.int32) for r in roles if r != "neg"},

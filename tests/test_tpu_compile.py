@@ -483,8 +483,10 @@ def test_four_shard_step_is_a_per_chip_program(no_replicas, topo,
     pools of four shards make it the per-chip program): each chip's
     block keeps the write-back kernel's custom call, one for each role
     (131,072 negatives are one call); what is summed over the chips is
-    the named roles' `[4096, 1024]` halves and the loss, never an array
-    of the negatives' 131,072 rows; the pools stay aliased and the
+    the loss and blocks of one exchange chunk (`fused.EXCHANGE_BYTES`:
+    `[1024, 1024]`) of the named roles' halves, never a role's whole
+    `[4096, 1024]` nor an array of the negatives' 131,072 rows; the pools
+    stay aliased and the
     temporaries far under a pool's size. In the replica variant every
     role is gathered ONCE from main at all its positions; the cache and
     delta pools are read by gathers of one side-path chunk
@@ -509,7 +511,7 @@ def test_four_shard_step_is_a_per_chip_program(no_replicas, topo,
         make_kge_loss("complex", 0.0, 0.0), roles,
         {r: L // 2 for r in roles}, (), "neg", (KV4_B, KV4_N), no_replicas)
     compiled = step.lower(
-        (pool,), shape((4,), jnp.int32),
+        (pool,), shape((11,), jnp.int32),  # a runner's: 6, 1 + a role
         tuple(shape((KV4_KEYS,), jnp.int32) for _ in range(3))
         + (shape((), jnp.int32),),
         {r: shape((KV4_B,), jnp.int32) for r in roles if r != "neg"},
@@ -523,7 +525,8 @@ def test_four_shard_step_is_a_per_chip_program(no_replicas, topo,
               for res in _ALL_REDUCE.findall(text)
               for dims in _F32.findall(res)]
     # (the loss, a scalar, has no dims for _F32 to find)
-    assert summed and set(summed) == {(KV4_B, L // 2)}, summed
+    chunk = fused.EXCHANGE_BYTES // (4 * (L // 2))
+    assert chunk < KV4_B and set(summed) == {(chunk, L // 2)}, summed
     mem = compiled.memory_analysis()
     pool_bytes = KV4_SLOTS * L * 4
     assert mem.alias_size_in_bytes >= pool_bytes
@@ -570,11 +573,13 @@ def test_four_shard_ctr_step_walks_two_classes_of_replicas(
     per-chip step, each with its main, cache and delta block. The
     write-back kernel's calls are the one-chip step's five (four for the
     438,272 feature positions, one for the dense rows); what is summed
-    over the chips is the feature rows' embedding halves and the dense
-    class's, out and back, and the loss; each class's cache and delta
+    over the chips is the loss and, out and back, blocks of one
+    exchange chunk of each role (`fused.EXCHANGE_BYTES`: 8,192 feature
+    positions' embedding halves, 1,024 dense rows'), never a role's
+    whole array; each class's cache and delta
     pools are read a side-path chunk at a time and all six pools stay
     aliased; the accumulator has a pair of entries a class beside its
-    six."""
+    six, then the exchange's (the positions, and one a role)."""
     import numpy as np
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
@@ -601,7 +606,7 @@ def test_four_shard_ctr_step_walks_two_classes_of_replicas(
         dlrm.make_dlrm_loss(layout, hot, 3, 3, 5), roles,
         {"feat": 128, "dense": 1024}, (), None, None, False)
     compiled = step.lower(
-        pools, shape((10,), jnp.int32),
+        pools, shape((13,), jnp.int32),
         tuple(shape((CTR4_KEYS,), jnp.int32) for _ in range(3))
         + (shape((), jnp.int32),),
         {"feat": shape((DLRM_M, DLRM_B), jnp.int32),
@@ -614,9 +619,8 @@ def test_four_shard_ctr_step_walks_two_classes_of_replicas(
     summed = {tuple(int(d) for d in dims.split(","))
               for res in _ALL_REDUCE.findall(text)
               for dims in _F32.findall(res)}
-    assert {(layout.num_rows, 1024)} <= summed <= {
-        (layout.num_rows, 1024), (DLRM_M, DLRM_B, 128),
-        (DLRM_M * DLRM_B, 128)}, summed
+    assert summed == {(fused.EXCHANGE_BYTES // (4 * dim), dim)
+                      for dim in (128, 1024)}, summed
     mem = compiled.memory_analysis()
     pool_bytes = CTR4_POOL_BYTES
     assert mem.alias_size_in_bytes >= pool_bytes

@@ -15,7 +15,6 @@
 from __future__ import annotations
 
 import argparse
-import time
 from typing import Optional
 
 import numpy as np
@@ -125,46 +124,222 @@ def wrap_batches(n: int, batch_size: int, rng: Optional[np.random.Generator]
         yield idx
 
 
+class Batch:
+    """A batch as the walk hands it on: role keys, the step's (or the
+    score's) aux, the distinct keys among the role keys (what its intent
+    names; None where no intent is made) and the keys' upload (the
+    `StagedKeys` handle, or None: the dispatch uploads them)."""
+
+    __slots__ = ("roles", "aux", "keys", "staged")
+
+    def __init__(self, roles, aux, keys=None, staged=None):
+        self.roles, self.aux, self.keys, self.staged = \
+            roles, aux, keys, staged
+
+
 class ScanWindow:
-    """The apps' shared --scan_steps dispatch contract (KGE/w2v/MF): a
+    """A worker's dispatches, the apps' shared --scan_steps contract: a
     full K-batch window trains in ONE lax.scan dispatch
     (DeviceRoutedRunner.run_scan) followed by K * sync_rounds_per_step
-    planner rounds; a partial tail window falls back to per-step dispatch
-    (one compiled scan variant per K, and tails are rare). Batches in one
-    window must come from ONE worker shard — flush at worker/block
+    planner rounds; K = 1, and a partial tail window, dispatch step by
+    step, each followed by its rounds (one compiled scan variant per K,
+    and tails are rare). The caller ticks the clock as it adds (a
+    batch, or a sentence), so a window's first batch is dispatched up
+    to K - 1 clocks after it was added: intents end that much later
+    (`AppRun.signal_intent`). One window, one runner: batches of one
+    window must come from ONE worker shard, so flush at worker/block
     boundaries."""
 
-    def __init__(self, server, K: int, sync_rounds_per_step: int,
-                 on_loss=None):
-        self.server = server
+    def __init__(self, server, runner, K: int, sync_rounds_per_step: int,
+                 lr: float, eps: float = 1e-10, on_loss=None):
+        self.server, self.runner = server, runner
         self.K = K
         self.rounds = sync_rounds_per_step
+        self.lr, self.eps = lr, eps
         self.on_loss = on_loss or (lambda loss: None)
-        self.buf: list = []  # (runner, roles, aux)
+        self.buf: list = []  # Batch
 
-    def add(self, runner, roles, aux, lr) -> None:
-        self.buf.append((runner, roles, aux))
+    def add(self, b: Batch) -> None:
+        self.buf.append(b)
         if len(self.buf) == self.K:
-            self.flush(lr)
+            self.flush()
 
-    def flush(self, lr) -> None:
+    def flush(self) -> None:
         if not self.buf:
             return
-        runner = self.buf[0][0]
         if len(self.buf) == self.K and self.K > 1:
-            has_aux = self.buf[0][2] is not None
-            self.on_loss(runner.run_scan(
-                [r for _, r, _ in self.buf],
-                [a for _, _, a in self.buf] if has_aux else None, lr))
+            has_aux = self.buf[0].aux is not None
+            self.on_loss(self.runner.run_scan(
+                [b.roles for b in self.buf],
+                [b.aux for b in self.buf] if has_aux else None,
+                self.lr, eps=self.eps))
             # drive_rounds: inline planner rounds, or delegated to the
             # prefetch pipeline's background thread (SystemOptions
             # .prefetch) so they overlap the in-flight scan window
             self.server.drive_rounds(len(self.buf) * self.rounds)
         else:
-            for rn, roles, aux in self.buf:
-                self.on_loss(rn(roles, aux, lr))
+            for b in self.buf:
+                self.on_loss(self.runner(b.roles, b.aux, self.lr,
+                                         eps=self.eps, staged=b.staged))
                 self.server.drive_rounds(self.rounds)
         self.buf.clear()
+
+
+class AppRun:
+    """What the apps' run objects share (KGE, MF, CTR, word2vec): the
+    server and its workers, the fused runners, the loop's own metrics,
+    the batch walk and the pass loop. An app is a loss and a key layout
+    (`runner_spec`), its batches (`get(bi)` handed to `walk`, or a loop
+    of its own over `window`), a pass (`train_pass`) and a pass end
+    (`pass_end`)."""
+
+    tag = "app"     # the log lines' `[tag]`
+
+    def attach_server(self, args, srv) -> None:
+        """`srv` is the app's `make_server(args, num_keys, value_lengths,
+        num_workers=args.num_workers or None)`: made by the app's module,
+        where a caller that needs other store options than the flags
+        give puts its own (`benchmarks/drivers/_kge.py`)."""
+        self.args = args
+        self.srv = srv
+        self.num_workers = args.num_workers or self.srv.num_shards
+        self.workers = [self.srv.make_worker(i)
+                        for i in range(self.num_workers)]
+        self.epoch = 0      # passes trained so far
+        # --scan_steps K: K batches train in ONE dispatch (ScanWindow)
+        self.K = max(1, getattr(args, "scan_steps", 1))
+        # the workers' device runners are built alike but for their
+        # shard, an operand of the step: they share their compiled
+        # programs (ops/fused.py DeviceRoutedRunner, `programs`)
+        self._programs = {}
+        self._dev_runners = {}      # shard -> DeviceRoutedRunner
+        # host time of the loop's own phases (Server._span; the step's
+        # other phases are bracketed where they live: kv.intent,
+        # fused.dispatch, kv.drive_rounds, kv.advance_clock), and how
+        # many of a batch's keys are distinct
+        obs = self.srv.obs
+        self._h_prepare = obs.histogram("app.prepare_s", shared=True)
+        self._h_pass_end = obs.histogram("app.pass_end_s", shared=True)
+        # the same less the waits for the device beneath them (`work=`)
+        self._h_prepare_work = obs.histogram("app.prepare_work_s",
+                                             shared=True)
+        self._h_pass_end_work = obs.histogram("app.pass_end_work_s",
+                                              shared=True)
+        self._c_keys = obs.counter("app.batch_keys_total", unit="keys",
+                                   shared=True)
+        self._c_unique = obs.counter("app.batch_unique_keys_total",
+                                     unit="keys", shared=True)
+
+    # -- the fused runners -----------------------------------------------------
+
+    def runner_spec(self) -> dict:
+        """`DeviceRoutedRunner`'s keywords for this app: `loss_fn`,
+        `role_class`, `role_dim`, and what else its step needs (the
+        sampler's, `score_fn`); `shard`, `seed` and the shared
+        `programs` are `device_runner`'s unless named here."""
+        raise NotImplementedError
+
+    def device_runner(self, shard: int):
+        """The fused step's runner for the worker on `shard`, built at
+        first use: routing tables (and negative sampling) live on
+        device; one runner per worker shard, all sharing their compiled
+        programs."""
+        if shard not in self._dev_runners:
+            from ..ops import DeviceRoutedRunner
+            spec = dict(shard=shard, seed=self.args.seed + shard,
+                        programs=self._programs)
+            spec.update(self.runner_spec())
+            self._dev_runners[shard] = DeviceRoutedRunner(self.srv, **spec)
+        return self._dev_runners[shard]
+
+    # -- a worker's turn -------------------------------------------------------
+
+    def signal_intent(self, w, b: Batch, start: int, end: int) -> None:
+        """The intent on `b`'s distinct keys for the clocks [start, end),
+        kept K - 1 clocks longer (a window's dispatch delay), and the
+        counts of what it names."""
+        self._c_keys.inc(sum(np.size(k) for k in b.roles.values()))
+        self._c_unique.inc(len(b.keys))
+        w.intent(b.keys, start, end + (self.K - 1))
+
+    def window(self, w, lr: float, eps: float = 1e-10,
+               on_loss=None) -> ScanWindow:
+        """Worker `w`'s dispatches at step size `lr`: `add` a batch,
+        tick the clock, `flush` at the end of its turn."""
+        return ScanWindow(self.srv, self.device_runner(w.shard), self.K,
+                          self.args.sync_rounds_per_step, lr, eps, on_loss)
+
+    def walk(self, w, n: int, get, lr: float, eps: float = 1e-10,
+             on_loss=None) -> None:
+        """Worker `w`'s turn over the batches `get(0..n-1)`, a clock tick
+        a batch. Batch `bi` is prepared `--lookahead` batches (a window,
+        if that is more) before its turn, the first ones at the start:
+        `get(bi)` builds it or hands back one the app kept, its intent
+        names its distinct keys from the clock at which it runs, and a
+        batch that will be dispatched as a single step has its keys
+        uploaded there (`DeviceRoutedRunner.prefetch_keys`), ahead of
+        the dispatch and outside its critical section. Then the turn:
+        the dispatch, the planner's rounds (`ScanWindow`), the clock."""
+        srv, K = self.srv, self.K
+        look = max(self.args.lookahead, K)
+        runner = self.device_runner(w.shard)
+        win = self.window(w, lr, eps, on_loss)
+        ready = {}
+
+        def prepare(bi: int, ahead: int) -> None:
+            with srv._span("app.prepare", self._h_prepare,
+                           work=self._h_prepare_work):
+                b = ready[bi] = get(bi)
+                fut = w.current_clock + ahead
+                self.signal_intent(w, b, fut, fut + 1)
+                if b.staged is None and K == 1:
+                    b.staged = runner.prefetch_keys(b.roles)
+
+        for bi in range(min(look, n)):
+            prepare(bi, ahead=bi)
+        for bi in range(n):
+            if bi + look < n:
+                prepare(bi + look, ahead=look)
+            win.add(ready.pop(bi))
+            w.advance_clock()
+        win.flush()
+
+    # -- the passes ------------------------------------------------------------
+
+    def train_pass(self):
+        """One pass over this process's data; what it returns is handed
+        to `pass_end`."""
+        raise NotImplementedError
+
+    def pass_end(self, out) -> tuple:
+        """After `quiesce()`, inside the `app.pass_end` span: the pass's
+        (loss, extra text for its log line)."""
+        raise NotImplementedError
+
+    def after_pass(self) -> None:
+        """After the pass's log line, outside the span (evaluation,
+        exports); `self.epoch` still counts the passes before it."""
+
+    def train_passes(self) -> None:
+        """`--epochs` passes, each ended by `quiesce()` and the app's
+        pass end; stops at the first pass end after `--max_runtime`.
+        Leaves the server up."""
+        args, srv = self.args, self.srv
+        guard = RuntimeGuard(args.max_runtime)
+        watch = Stopwatch(start=True)
+        for _ in range(args.epochs):
+            out = self.train_pass()
+            with srv._span("app.pass_end", self._h_pass_end,
+                           work=self._h_pass_end_work):
+                srv.quiesce()
+                loss, extra = self.pass_end(out)
+            epoch_report(self.tag, self.epoch, loss, watch, extra)
+            self.after_pass()
+            self.epoch += 1
+            if guard.expired():
+                alog(f"[{self.tag}] max_runtime reached")
+                break
+        alog(f"[{self.tag}]", srv.sync.report())
 
 
 class RuntimeGuard:

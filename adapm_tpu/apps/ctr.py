@@ -45,16 +45,15 @@ from __future__ import annotations
 
 import argparse
 import sys
+from functools import partial
 
 import jax.numpy as jnp
 import numpy as np
 
 from ..models.dlrm import DenseLayout, dense_tensors, make_dlrm_loss
-from ..ops import DeviceRoutedRunner
-from ..utils import Stopwatch, alog
-from .common import (RuntimeGuard, add_common_arguments,
-                     enforce_full_replication, epoch_report,
-                     global_worker_slices, make_server, wrap_batches)
+from .common import (AppRun, Batch, add_common_arguments,
+                     enforce_full_replication, global_worker_slices,
+                     make_server, wrap_batches)
 
 
 # AdaGrad's damping: a position's update is -lr g / sqrt(acc + g*g + eps).
@@ -115,24 +114,14 @@ def _set_embeddings(w0, rng, n_feat: int, dim: int, scale: float,
         w0.set(np.arange(lo, lo + n), emb)
 
 
-class _Batch:
-    """A prepared batch: role keys, the step's aux on the device, the
-    distinct keys among the role keys (the intent's) and the keys'
-    upload."""
-
-    __slots__ = ("roles", "aux", "keys", "staged")
-
-    def __init__(self, roles, aux, keys, staged):
-        self.roles, self.aux, self.keys, self.staged = \
-            roles, aux, keys, staged
-
-
-class CtrRun:
+class CtrRun(AppRun):
     """One training run: the server, its workers and their fused runners,
-    the examples with their keys, and the pass count."""
+    the examples with their keys, and the pass count over all train()
+    calls."""
+
+    tag = "ctr"
 
     def __init__(self, args, data):
-        self.args = args
         self.table_rows = _ints(args.table_rows)
         self.hot = _ints(args.multi_hot_sizes)
         assert len(self.table_rows) == len(self.hot), \
@@ -155,38 +144,16 @@ class CtrRun:
         value_lengths = np.empty(num_keys, dtype=np.int64)
         value_lengths[:self.n_feat] = 2 * self.dim
         value_lengths[self.n_feat:] = 2 * args.dense_row
-        self.srv = make_server(args, num_keys, value_lengths,
-                               num_workers=args.num_workers or None)
-        self.num_workers = args.num_workers or self.srv.num_shards
-        self.workers = [self.srv.make_worker(i)
-                        for i in range(self.num_workers)]
+        self.attach_server(args, make_server(
+            args, num_keys, value_lengths,
+            num_workers=args.num_workers or None))
         kc = self.srv.ab.key_class
         self.c_feat, self.c_dense = int(kc[0]), int(kc[self.n_feat])
         assert self.c_feat != self.c_dense, \
             "feature rows and dense rows need different lengths"
         self.dense_keys = np.arange(self.n_feat, num_keys, dtype=np.int64)
-        self.epoch = 0      # passes trained so far, over all train() calls
         self.mean_loss = 0.0
-        self._programs = {}
-        self._dev_runners = {}
         self.set_examples(*data)
-
-        # host time of the loop's own phases (Server._span; the step's
-        # other phases are bracketed where they live: kv.intent,
-        # fused.dispatch, kv.drive_rounds, kv.advance_clock), and how
-        # many of a batch's keys are distinct
-        obs = self.srv.obs
-        self._h_prepare = obs.histogram("app.prepare_s", shared=True)
-        self._h_pass_end = obs.histogram("app.pass_end_s", shared=True)
-        # the same less the waits for the device beneath them (`work=`)
-        self._h_prepare_work = obs.histogram("app.prepare_work_s",
-                                             shared=True)
-        self._h_pass_end_work = obs.histogram("app.pass_end_work_s",
-                                              shared=True)
-        self._c_keys = obs.counter("app.batch_keys_total", unit="keys",
-                                   shared=True)
-        self._c_unique = obs.counter("app.batch_unique_keys_total",
-                                     unit="keys", shared=True)
 
     def set_examples(self, members, x, y) -> None:
         """The examples this run trains on: `members` [n, M] table-local
@@ -209,15 +176,11 @@ class CtrRun:
             for mine in parts]
         self._plans = [[None] * len(idxs) for idxs in self._batch_idx]
 
-    def device_runner(self, shard: int) -> DeviceRoutedRunner:
-        if shard not in self._dev_runners:
-            self._dev_runners[shard] = DeviceRoutedRunner(
-                self.srv, self._loss,
-                role_class={"feat": self.c_feat, "dense": self.c_dense},
-                role_dim={"feat": self.dim, "dense": self.args.dense_row},
-                shard=shard, seed=self.args.seed + shard,
-                programs=self._programs)
-        return self._dev_runners[shard]
+    def runner_spec(self) -> dict:
+        return dict(
+            loss_fn=self._loss,
+            role_class={"feat": self.c_feat, "dense": self.c_dense},
+            role_dim={"feat": self.dim, "dense": self.args.dense_row})
 
     def precompile(self) -> int:
         """`Server.precompile` with this app's sizes: an intent names at
@@ -262,11 +225,11 @@ class CtrRun:
         """The feature keys of the examples `idx`, member-major [M, B]."""
         return (self.members[idx] + self.member_first).T.copy()
 
-    def _batch(self, wi: int, bi: int) -> _Batch:
-        """Batch `bi` of worker `wi`, prepared at most once for these
-        examples: the role keys, their distinct keys, and the uploads of
-        the keys and of the dense features and labels, kept on the
-        device."""
+    def _batch(self, wi: int, bi: int) -> Batch:
+        """Batch `bi` of worker `wi`, built at most once for these
+        examples: the role keys, their distinct keys, and the upload of
+        the dense features and labels, kept on the device (the walk
+        adds the keys' upload, which is kept too)."""
         b = self._plans[wi][bi]
         if b is None:
             idx = self._batch_idx[wi][bi]
@@ -274,44 +237,27 @@ class CtrRun:
             roles = {"feat": self.feat_keys(idx), "dense": self.dense_keys}
             keys = np.concatenate([np.unique(roles["feat"]),
                                    self.dense_keys])
-            runner = self.device_runner(self.workers[wi].shard)
-            b = self._plans[wi][bi] = _Batch(
-                roles, (put(self.x[idx]), put(self.y[idx])), keys,
-                runner.prefetch_keys(roles))
+            b = self._plans[wi][bi] = Batch(
+                roles, (put(self.x[idx]), put(self.y[idx])), keys)
         return b
 
     def train_pass(self) -> list:
-        """One pass over this process's examples; returns the steps'
-        losses (device scalars)."""
-        a, srv = self.args, self.srv
+        """One pass over this process's examples, the workers in turns
+        (`AppRun.walk`); returns the steps' losses (device scalars)."""
         losses = []
         for wi, w in enumerate(self.workers):
-            n = len(self._plans[wi])
-            runner = self.device_runner(w.shard)
-
-            def prepare(bi: int, ahead: int) -> None:
-                # the batch (if these examples' first pass) and its
-                # intent, `ahead` steps before the step that reads it
-                with srv._span("app.prepare", self._h_prepare,
-                               work=self._h_prepare_work):
-                    fut = w.current_clock + ahead
-                    w.intent(self._batch(wi, bi).keys, fut, fut + 1)
-
-            for bi in range(min(a.lookahead, n)):
-                prepare(bi, ahead=bi)
-            for bi in range(n):
-                if bi + a.lookahead < n:
-                    prepare(bi + a.lookahead, ahead=a.lookahead)
-                b = self._batch(wi, bi)
-                self._c_keys.inc(b.roles["feat"].size + self.n_dense)
-                self._c_unique.inc(len(b.keys))
-                losses.append(runner(b.roles, b.aux, a.lr, eps=ADAGRAD_EPS,
-                                     staged=b.staged))
-                # inline rounds, or delegated to the prefetch pipeline so
-                # planner work overlaps the in-flight step
-                srv.drive_rounds(a.sync_rounds_per_step)
-                w.advance_clock()
+            self.walk(w, len(self._plans[wi]), partial(self._batch, wi),
+                      self.args.lr, eps=ADAGRAD_EPS, on_loss=losses.append)
         return losses
+
+    def pass_end(self, losses) -> tuple:
+        """The mean of the pass's losses, fetched once."""
+        from ..parallel import control
+        with self.srv._span("app.loss_fetch", wait=True):
+            mean_loss = float(jnp.mean(jnp.stack(losses))) \
+                if losses else 0.0
+        self.mean_loss = float(control.allreduce(mean_loss, "mean")[0])
+        return self.mean_loss, ""
 
 
 def open_run(args) -> CtrRun:
@@ -336,26 +282,7 @@ def train(crun: CtrRun) -> float:
     end after `--max_runtime`. Leaves the server up (see open_run) and
     can be called again on the same run. Returns the last pass's mean
     loss."""
-    args, srv = crun.args, crun.srv
-    guard = RuntimeGuard(args.max_runtime)
-    watch = Stopwatch(start=True)
-    from ..parallel import control
-    for _ in range(args.epochs):
-        losses = crun.train_pass()
-        with srv._span("app.pass_end", crun._h_pass_end,
-                       work=crun._h_pass_end_work):
-            srv.quiesce()
-            with srv._span("app.loss_fetch", wait=True):
-                mean_loss = float(jnp.mean(jnp.stack(losses))) \
-                    if losses else 0.0
-            mean_loss = float(control.allreduce(mean_loss, "mean")[0])
-        epoch_report("ctr", crun.epoch, mean_loss, watch)
-        crun.mean_loss = mean_loss
-        crun.epoch += 1
-        if guard.expired():
-            alog("[ctr] max_runtime reached")
-            break
-    alog("[ctr]", srv.sync.report())
+    crun.train_passes()
     return crun.mean_loss
 
 

@@ -25,18 +25,15 @@ from __future__ import annotations
 import argparse
 import os
 import sys
-from typing import Optional
 
 import numpy as np
 
 from ..io import kge as kgeio
 from ..models.kge import make_eval_scores, make_kge_loss
-from ..ops import DeviceRoutedRunner
-from ..utils import Stopwatch, alog
-from .common import (KeyMapper, RuntimeGuard, add_common_arguments,
-                     enforce_full_replication, epoch_report,
-                     global_worker_slices, make_server, wrap_batches,
-                     worker0_init)
+from ..utils import alog
+from .common import (AppRun, Batch, KeyMapper, add_common_arguments,
+                     enforce_full_replication, global_worker_slices,
+                     is_rank0, make_server, wrap_batches, worker0_init)
 
 # eval stats layout: [0:4] object side (mrr_sum, h1, h10, count),
 # [4:8] subject side — separated because the generators/datasets can have
@@ -46,11 +43,12 @@ from .common import (KeyMapper, RuntimeGuard, add_common_arguments,
 EVAL_LEN = 8
 
 
-class KgeRun:
+class KgeRun(AppRun):
     """Holds the server, key layout, and fused runner for one training run."""
 
+    tag = "kge"
+
     def __init__(self, args, ds: kgeio.TripleDataset):
-        self.args = args
         self.ds = ds
         d = args.dim
         E, R = ds.num_entities, ds.num_relations
@@ -74,11 +72,9 @@ class KgeRun:
         self.ent_map = KeyMapper(E, args.enforce_random_keys, seed=args.seed)
         self.rel_map = KeyMapper(R, args.enforce_random_keys,
                                  seed=args.seed + 1)
-        self.srv = make_server(args, num_keys, value_lengths,
-                               num_workers=args.num_workers or None)
-        self.num_workers = args.num_workers or self.srv.num_shards
-        self.workers = [self.srv.make_worker(i)
-                        for i in range(self.num_workers)]
+        self.attach_server(args, make_server(
+            args, num_keys, value_lengths,
+            num_workers=args.num_workers or None))
 
         ab = self.srv.ab
         self.ent_class = int(ab.key_class[0])
@@ -91,35 +87,26 @@ class KgeRun:
         self._pool_eval_topo = -1    # owned-tile cache topology version
         self._pool_eval_n = 0        # this rank's owned-entity count
         self._true_score = None
-        # the workers' device runners are built alike but for their
-        # shard, an operand of the step: they share their compiled
-        # programs (ops/fused.py DeviceRoutedRunner, `programs`)
         self.loss_fn = make_kge_loss(args.model, args.self_adv_temp,
                                      args.l2)
-        self._step_programs = {}
         self.truth_mrr = None    # lowrank generator's ceiling (open_run)
         self.neg_alias = None    # --neg_sampling freq alias table
-        self._dev_runners = {}   # shard -> DeviceRoutedRunner
+        self.result = {}         # what train(run) returns
+        self._rng = None         # a train(run) call's shuffling generator
 
-    def device_runner(self, shard: int) -> DeviceRoutedRunner:
-        """The fused step's runner for the worker on `shard`, built at
-        first use: routing tables and negative sampling (Local scheme,
-        uniform or alias-table freq) live on device; one runner per
-        worker shard, all sharing their compiled programs."""
-        if shard not in self._dev_runners:
-            a = self.args
-            self._dev_runners[shard] = DeviceRoutedRunner(
-                self.srv, self.loss_fn,
-                role_class={"s": self.ent_class, "r": self.rel_class,
-                            "o": self.ent_class, "neg": self.ent_class},
-                role_dim={"s": self.ent_dim, "r": self.rel_dim,
-                          "o": self.ent_dim, "neg": self.ent_dim},
-                shard=shard, neg_role="neg",
-                neg_shape=(a.batch_size, a.neg_ratio),
-                neg_population=self.ekey(np.arange(self.E)),
-                neg_alias=self.neg_alias, seed=a.seed + shard,
-                programs=self._step_programs)
-        return self._dev_runners[shard]
+    def runner_spec(self) -> dict:
+        """Negative sampling lives on device too (Local scheme, uniform
+        or alias-table freq)."""
+        a = self.args
+        return dict(
+            loss_fn=self.loss_fn,
+            role_class={"s": self.ent_class, "r": self.rel_class,
+                        "o": self.ent_class, "neg": self.ent_class},
+            role_dim={"s": self.ent_dim, "r": self.rel_dim,
+                      "o": self.ent_dim, "neg": self.ent_dim},
+            neg_role="neg", neg_shape=(a.batch_size, a.neg_ratio),
+            neg_population=self.ekey(np.arange(self.E)),
+            neg_alias=self.neg_alias)
 
     def precompile(self) -> int:
         """`Server.precompile` with this app's sizes: an intent names at
@@ -217,6 +204,86 @@ class KgeRun:
             w0.wait(w0.set(np.array([key_l]),
                            np.zeros(length, np.float32)))
         self.srv.barrier()
+
+
+    # -- a pass --------------------------------------------------------------
+
+    def train_pass(self) -> list:
+        """One pass over this process's triples, the workers in turns
+        (`AppRun.walk`; kge.cc:1059-1122); returns the dispatches'
+        losses (device scalars; a scan window's are a [K] vector: they
+        stay on the device until the pass ends, a float() per step would
+        serialize host and device)."""
+        args, triples = self.args, self.ds.train
+        # data parallelism over ALL workers of ALL processes
+        # (kge.cc:968-970)
+        parts = global_worker_slices(len(triples), self.num_workers)
+        # per-epoch step size: AdaGrad already decays effective rates, but
+        # an explicit multiplicative schedule helps late-stage ranking
+        # quality on the lowrank harness (tests/test_apps.py
+        # test_kge_lr_decay_beats_constant);
+        # --lr_decay 1.0 = the reference's constant-lr behavior
+        lr_epoch = args.lr * (args.lr_decay ** self.epoch)
+        losses = []
+        for wi, w in enumerate(self.workers):
+            mine = parts[wi]
+            batches = [mine[idx] for idx in
+                       wrap_batches(len(mine), args.batch_size, self._rng)]
+
+            def get(bi: int) -> Batch:
+                # the ONE logical->physical role mapping for a triple
+                # batch
+                t = triples[batches[bi]]  # noqa: B023
+                roles = {"s": self.ekey(t[:, 0]), "r": self.rkey(t[:, 1]),
+                         "o": self.ekey(t[:, 2])}
+                return Batch(roles, None, np.unique(np.concatenate(
+                    [roles["s"], roles["r"], roles["o"]])))
+
+            self.walk(w, len(batches), get, lr_epoch,
+                      on_loss=losses.append)
+        return losses
+
+    def pass_end(self, losses) -> tuple:
+        """The pass's mean loss over all processes."""
+        srv = self.srv
+        with srv._span("app.loss_fetch", wait=True):
+            epoch_loss = float(np.sum([np.asarray(l).sum()
+                                       for l in losses]))
+            nbatches = int(np.sum([np.asarray(l).size for l in losses]))
+        with srv._span("app.loss_allreduce"):
+            # loss aggregation through the PS loss key
+            # (ps_allreduce idiom)
+            total = self.allreduce(
+                self.loss_key_l,
+                np.array([epoch_loss / max(nbatches, 1)]))
+            self.reset_key(self.loss_key_l, 1)
+        self.result["loss"] = float(total[0])
+        return self.result["loss"], ""
+
+    def after_pass(self) -> None:
+        """--eval_every's validation MRR and --checkpoint_every's
+        checkpoint."""
+        args, ds, epoch, result = self.args, self.ds, self.epoch, \
+            self.result
+        if args.eval_every and (epoch + 1) % args.eval_every == 0 and \
+                ds.valid is not None and len(ds.valid):
+            agg = _eval_global(self, ds.valid[:args.eval_triples])
+            cnt = max(float(agg[3]) + float(agg[7]), 1.0)
+            result.update(
+                mrr=(float(agg[0]) + float(agg[4])) / cnt,
+                hits1=(float(agg[1]) + float(agg[5])) / cnt,
+                hits10=(float(agg[2]) + float(agg[6])) / cnt,
+                mrr_o=float(agg[0]) / max(float(agg[3]), 1.0),
+                mrr_s=float(agg[4]) / max(float(agg[7]), 1.0))
+            alog(f"[kge] epoch {epoch}: filtered MRR={result['mrr']:.4f} "
+                 f"(o={result['mrr_o']:.4f} s={result['mrr_s']:.4f}) "
+                 f"Hits@1={result['hits1']:.4f} "
+                 f"Hits@10={result['hits10']:.4f}")
+        if args.checkpoint_every and \
+                (epoch + 1) % args.checkpoint_every == 0 and is_rank0():
+            os.makedirs(args.checkpoint_dir, exist_ok=True)
+            self.checkpoint(os.path.join(
+                args.checkpoint_dir, f"kge_epoch{epoch}.npz"))
 
 
 def _flt_pairs(ab_pairs, flt: dict):
@@ -543,164 +610,19 @@ def open_run(args) -> KgeRun:
 
 def train(run: KgeRun) -> dict:
     """The training loop + evals over an opened run; leaves the server
-    up (see open_run)."""
-    args, ds = run.args, run.ds
-    B, N = args.batch_size, args.neg_ratio
-    srv, workers = run.srv, run.workers
-    device_runner = run.device_runner
-
-    triples = ds.train
-    # data parallelism over ALL workers of ALL processes (kge.cc:968-970)
-    parts = global_worker_slices(len(triples), run.num_workers)
-    rng = np.random.default_rng(args.seed)
-    guard = RuntimeGuard(args.max_runtime)
-    watch = Stopwatch(start=True)
-    result = {}
-    # host time of the loop's own two phases (Server._span; the step's
-    # other phases are bracketed where they live: kv.intent,
-    # fused.dispatch, kv.drive_rounds, kv.advance_clock)
-    h_prepare = srv.obs.histogram("app.prepare_s", shared=True)
-    h_pass_end = srv.obs.histogram("app.pass_end_s", shared=True)
-    # the same less the waits for the device beneath them (`work=`)
-    h_prepare_work = srv.obs.histogram("app.prepare_work_s", shared=True)
-    h_pass_end_work = srv.obs.histogram("app.pass_end_work_s",
-                                        shared=True)
+    up (see open_run). A call numbers its passes from 0 (the step size's
+    decay, --eval_every and the checkpoints' names follow that count)
+    and shuffles from --seed, so a second call repeats the first's
+    batches."""
+    args, ds, srv = run.args, run.ds, run.srv
+    run.epoch = 0
+    run._rng = np.random.default_rng(args.seed)
+    result = run.result = {}
     if run.truth_mrr is not None:
         result["truth_mrr"] = run.truth_mrr
         result["truth_mrr_o"] = ds.truth_mrr_o
         result["truth_mrr_s"] = ds.truth_mrr_s
-
-    for epoch in range(args.epochs):
-        # per-epoch step size: AdaGrad already decays effective rates, but
-        # an explicit multiplicative schedule helps late-stage ranking
-        # quality on the lowrank harness (tests/test_apps.py
-        # test_kge_lr_decay_beats_constant);
-        # --lr_decay 1.0 = the reference's constant-lr behavior
-        lr_epoch = args.lr * (args.lr_decay ** epoch)
-        # losses stay device scalars until epoch end: a float() per step
-        # would serialize host and device
-        epoch_losses = []
-        for wi, w in enumerate(workers):
-            mine = parts[wi]
-            batches = [mine[idx] for idx in
-                       wrap_batches(len(mine), B, rng)]
-            staged = {}  # bi -> (roles, StagedKeys) pre-uploaded batches
-            prepared_hi = -1  # highest batch index already prepared
-
-            def triple_roles(t):
-                # the ONE logical->physical role mapping for a triple
-                # batch (prepare, the scan window and the staged-miss
-                # fallback must all agree)
-                return {"s": run.ekey(t[:, 0]), "r": run.rkey(t[:, 1]),
-                        "o": run.ekey(t[:, 2])}
-
-            def prepare(bi: int, ahead: int) -> None:
-                # the scan-window loop prepares up to lo+look+K ahead; the
-                # tail loop would otherwise re-prepare those indices at the
-                # same fut clock (duplicate intent RPC per epoch tail)
-                nonlocal prepared_hi
-                if bi <= prepared_hi:
-                    return
-                prepared_hi = bi
-                with srv._span("app.prepare", h_prepare,
-                               work=h_prepare_work):
-                    t = triples[batches[bi]]
-                    roles = triple_roles(t)
-                    ks = np.unique(np.concatenate(
-                        [roles["s"], roles["r"], roles["o"]]))
-                    fut = w.current_clock + ahead
-                    w.intent(ks, fut, fut + 1)
-                    if srv.prefetch is not None and K == 1:
-                        # prefetch pipeline on: the batch's key upload
-                        # rides the prepare path
-                        # (DeviceRoutedRunner.prefetch_keys) instead of
-                        # the dispatch critical section
-                        staged[bi] = (roles, device_runner(w.shard)
-                                      .prefetch_keys(roles))
-
-            K = max(1, args.scan_steps)
-            for bi in range(min(max(args.lookahead, K), len(batches))):
-                prepare(bi, ahead=bi)
-            if K > 1:
-                # K-step scan windows (runner.run_scan): ONE dispatch
-                # trains K batches; intents run a window ahead and the K
-                # planner rounds + clock ticks execute while the device
-                # works through the window (VERDICT r3 item 2). The tail
-                # window short of K batches falls back to per-step.
-                look = max(args.lookahead, K)
-                for lo in range(0, len(batches) - len(batches) % K, K):
-                    for bi in range(lo + look,
-                                    min(lo + look + K, len(batches))):
-                        prepare(bi, ahead=bi - lo)
-                    window = [triples[batches[lo + j]] for j in range(K)]
-                    roles = [triple_roles(t) for t in window]
-                    epoch_losses.append(
-                        device_runner(w.shard).run_scan(
-                            roles, None, lr_epoch))
-                    srv.drive_rounds(K * args.sync_rounds_per_step)
-                    for _ in range(K):
-                        w.advance_clock()
-                tail_start = len(batches) - len(batches) % K
-            else:
-                tail_start = 0
-            for bi in range(tail_start, len(batches)):
-                idx = batches[bi]
-                if bi + args.lookahead < len(batches):
-                    prepare(bi + args.lookahead, ahead=args.lookahead)
-                pre = staged.pop(bi, None)
-                if pre is not None:  # keys already on device
-                    roles, stg = pre
-                    loss = device_runner(w.shard)(roles, None, lr_epoch,
-                                                  staged=stg)
-                else:
-                    loss = device_runner(w.shard)(
-                        triple_roles(triples[idx]), None, lr_epoch)
-                epoch_losses.append(loss)
-                srv.drive_rounds(args.sync_rounds_per_step)
-                w.advance_clock()
-        with srv._span("app.pass_end", h_pass_end, work=h_pass_end_work):
-            srv.quiesce()
-            with srv._span("app.loss_fetch", wait=True):
-                # scan windows contribute [K] loss vectors, per-step
-                # path scalars
-                epoch_loss = float(np.sum([np.asarray(l).sum()
-                                           for l in epoch_losses]))
-                nbatches = int(np.sum([np.asarray(l).size
-                                       for l in epoch_losses]))
-            with srv._span("app.loss_allreduce"):
-                # loss aggregation through the PS loss key
-                # (ps_allreduce idiom)
-                total = run.allreduce(
-                    run.loss_key_l,
-                    np.array([epoch_loss / max(nbatches, 1)]))
-                run.reset_key(run.loss_key_l, 1)
-        epoch_report("kge", epoch, float(total[0]), watch)
-        result["loss"] = float(total[0])
-
-        if args.eval_every and (epoch + 1) % args.eval_every == 0 and \
-                ds.valid is not None and len(ds.valid):
-            agg = _eval_global(run, ds.valid[:args.eval_triples])
-            cnt = max(float(agg[3]) + float(agg[7]), 1.0)
-            result.update(
-                mrr=(float(agg[0]) + float(agg[4])) / cnt,
-                hits1=(float(agg[1]) + float(agg[5])) / cnt,
-                hits10=(float(agg[2]) + float(agg[6])) / cnt,
-                mrr_o=float(agg[0]) / max(float(agg[3]), 1.0),
-                mrr_s=float(agg[4]) / max(float(agg[7]), 1.0))
-            alog(f"[kge] epoch {epoch}: filtered MRR={result['mrr']:.4f} "
-                 f"(o={result['mrr_o']:.4f} s={result['mrr_s']:.4f}) "
-                 f"Hits@1={result['hits1']:.4f} "
-                 f"Hits@10={result['hits10']:.4f}")
-        if args.checkpoint_every and \
-                (epoch + 1) % args.checkpoint_every == 0:
-            from .common import is_rank0
-            if is_rank0():
-                os.makedirs(args.checkpoint_dir, exist_ok=True)
-                run.checkpoint(os.path.join(
-                    args.checkpoint_dir, f"kge_epoch{epoch}.npz"))
-        if guard.expired():
-            alog("[kge] max_runtime reached")
-            break
+    run.train_passes()
 
     if ds.test is not None and len(ds.test) and args.eval_every:
         agg = _eval_global(run, ds.test[:args.eval_triples])
@@ -718,7 +640,6 @@ def train(run: KgeRun) -> dict:
     ent = srv.read_main(run.ekey(np.arange(min(run.E, 2048)))).reshape(
         -1, 2 * run.ent_dim)[:, : run.ent_dim]
     result["ent_norm"] = float(np.sqrt((ent * ent).sum(axis=1)).mean())
-    alog("[kge]", srv.sync.report())
     return result
 
 

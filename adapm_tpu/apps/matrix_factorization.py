@@ -39,11 +39,8 @@ from ..device import default_port
 from ..exec import dispatch_gate
 from ..io import mf as mfio
 from ..models.mf import make_mf_loss, mf_sq_error
-from ..ops import DeviceRoutedRunner
-from ..utils import Stopwatch, alog
-from .common import (KeyMapper, RuntimeGuard, ScanWindow,
-                     add_common_arguments, enforce_full_replication,
-                     epoch_report, make_server, wrap_batches,
+from .common import (AppRun, Batch, KeyMapper, add_common_arguments,
+                     enforce_full_replication, make_server, wrap_batches,
                      worker0_init)
 
 _GATE = dispatch_gate()
@@ -77,37 +74,24 @@ def _masked_sq_sum(main, occupied, rank: int):
     return jnp.sum(jnp.where(occupied[..., None], f * f, 0))
 
 
-class _Batch:
-    """A batch as the loops hand it on: role keys, the step's (or the
-    score's) aux, the distinct keys among the role keys (where an intent
-    needs them) and the keys' upload (where one was made)."""
-
-    __slots__ = ("roles", "aux", "keys", "staged")
-
-    def __init__(self, roles, aux, keys=None, staged=None):
-        self.roles, self.aux, self.keys, self.staged = \
-            roles, aux, keys, staged
-
-
-class MfRun:
+class MfRun(AppRun):
     """One training run: the server, its workers and their fused runners,
     the data points with their keys, and what carries over from pass to
     pass (step size, the bold driver's last loss, the shuffling
-    generator, the pass count)."""
+    generator, the pass count over all train() calls)."""
+
+    tag = "mf"
 
     def __init__(self, args, data):
         rows, cols, vals, m, n = data
-        self.args = args
         self.m, self.n, self.rank = m, n, args.rank
         num_keys = m + n
         self.rng = np.random.default_rng(args.seed)
         self.kmap = KeyMapper(num_keys, args.enforce_random_keys,
                               seed=args.seed)
-        self.srv = make_server(args, num_keys, value_lengths=2 * self.rank,
-                               num_workers=args.num_workers or None)
-        self.num_workers = args.num_workers or self.srv.num_shards
-        self.workers = [self.srv.make_worker(i)
-                        for i in range(self.num_workers)]
+        self.attach_server(args, make_server(
+            args, num_keys, 2 * self.rank,
+            num_workers=args.num_workers or None))
         from ..parallel import control
         self.pid = control.process_id()
         self.total_workers = control.num_processes() * self.num_workers
@@ -116,47 +100,13 @@ class MfRun:
         self.lr = args.lr
         self.prev_loss = np.inf
         self.best_loss = np.inf
-        self.epoch = 0      # passes trained so far, over all train() calls
 
-        # --scan_steps K: buffer K batches and train them in ONE lax.scan
-        # dispatch (ScanWindow, the shared app contract; placement frozen
-        # per window). The clock still advances per batch at buffering
-        # time; intent windows are extended by K-1 clocks to cover the
-        # dispatch delay. The window is flushed at every worker/block
-        # boundary (shards must not mix in one window) and before each
-        # barrier/quiesce. lr changes per pass (bold driver), so the
-        # CURRENT lr is passed at every add/flush.
-        self.K = max(1, args.scan_steps)
-        self.scan_win = ScanWindow(self.srv, self.K,
-                                   args.sync_rounds_per_step)
-
-        # routing tables mirrored into HBM, host ships only the raw key
-        # batch per step (ops/fused.py); runners built alike share their
-        # compiled programs
-        self._programs = {}
-        self._dev_runners = {}
         self._sq_sum = default_port().compile(_masked_sq_sum,
                                               static_argnums=2)
         self._occupied = None       # device mask of the pool's live slots
         self._occupied_version = None
-
-        # host time of the loop's own phases (Server._span; the step's
-        # other phases are bracketed where they live: kv.intent,
-        # fused.dispatch, kv.drive_rounds, kv.advance_clock), and how
-        # many of a batch's keys are distinct
-        obs = self.srv.obs
-        self._h_prepare = obs.histogram("app.prepare_s", shared=True)
-        self._h_pass_end = obs.histogram("app.pass_end_s", shared=True)
-        # the same less the waits for the device beneath them (`work=`)
-        self._h_prepare_work = obs.histogram("app.prepare_work_s",
-                                             shared=True)
-        self._h_pass_end_work = obs.histogram("app.pass_end_work_s",
-                                              shared=True)
-        self._h_loss_pass = obs.histogram("app.loss_pass_s", shared=True)
-        self._c_keys = obs.counter("app.batch_keys_total", unit="keys",
-                                   shared=True)
-        self._c_unique = obs.counter("app.batch_unique_keys_total",
-                                     unit="keys", shared=True)
+        self._h_loss_pass = self.srv.obs.histogram("app.loss_pass_s",
+                                                   shared=True)
 
     def set_points(self, rows, cols, vals) -> None:
         """The revealed cells this run trains on. Every point's two keys
@@ -182,15 +132,11 @@ class MfRun:
         self._train_plans = {}
         self._loss_plans = {}
 
-    def device_runner(self, shard: int) -> DeviceRoutedRunner:
-        if shard not in self._dev_runners:
-            a = self.args
-            self._dev_runners[shard] = DeviceRoutedRunner(
-                self.srv, make_mf_loss(a.l2), role_class={"w": 0, "h": 0},
-                role_dim={"w": self.rank, "h": self.rank}, shard=shard,
-                seed=a.seed + shard, programs=self._programs,
-                score_fn=mf_sq_error)
-        return self._dev_runners[shard]
+    def runner_spec(self) -> dict:
+        return dict(loss_fn=make_mf_loss(self.args.l2),
+                    role_class={"w": 0, "h": 0},
+                    role_dim={"w": self.rank, "h": self.rank},
+                    score_fn=mf_sq_error)
 
     def precompile(self) -> int:
         """`Server.precompile` with this app's sizes: an intent names at
@@ -231,40 +177,20 @@ class MfRun:
         """(role keys, observed values) of the points `idx`."""
         return {"w": self.wkey[idx], "h": self.hkey[idx]}, self.vals[idx]
 
-    def prepared(self, idx: np.ndarray) -> "_Batch":
+    def prepared(self, idx: np.ndarray) -> Batch:
         """The batch of the points `idx` with what its intent needs: the
         distinct keys among its 2B."""
         roles, aux = self.batch(idx)
-        return _Batch(roles, aux,
-                      np.unique(np.concatenate([roles["w"], roles["h"]])))
-
-    def signal_intent(self, w, batch: "_Batch", start: int,
-                      end: int) -> None:
-        self._c_keys.inc(len(batch.roles["w"]) + len(batch.roles["h"]))
-        self._c_unique.inc(len(batch.keys))
-        w.intent(batch.keys, start, end + (self.K - 1))
-
-    def train_batch(self, w, roles, aux, staged=None) -> None:
-        runner = self.device_runner(w.shard)
-        if self.K > 1:
-            self.scan_win.add(runner, roles, aux, self.lr)
-            w.advance_clock()
-            return
-        runner(roles, aux, self.lr, staged=staged)
-        # inline rounds, or delegated to the prefetch pipeline so
-        # planner work overlaps the in-flight step
-        self.srv.drive_rounds(self.args.sync_rounds_per_step)
-        w.advance_clock()
+        return Batch(roles, aux,
+                     np.unique(np.concatenate([roles["w"], roles["h"]])))
 
     def _walk(self, wi: int, rng) -> None:
-        """Worker `wi`'s pass over its points in batches of B, intent
-        `--lookahead` batches ahead. Shuffled by `rng`, every batch is
-        prepared where its intent is signalled; unshuffled (columnwise)
-        the pass is the same every time, so its batches (keys, values,
-        distinct keys, and with the prefetch pipeline on their upload)
-        are prepared at the first pass and kept."""
-        srv, a = self.srv, self.args
-        w, mine = self.workers[wi], self.by_worker[wi]
+        """Worker `wi`'s pass over its points in batches of B
+        (`AppRun.walk`). Shuffled by `rng`, every batch is built where
+        it is prepared; unshuffled (columnwise) the pass is the same
+        every time, so its batches (keys, values, distinct keys, the
+        keys' upload) are built at the first pass and kept."""
+        a, mine = self.args, self.by_worker[wi]
         if rng is not None:
             index = list(wrap_batches(len(mine), a.batch_size, rng))
             get = lambda bi: self.prepared(mine[index[bi]])  # noqa: E731
@@ -276,26 +202,7 @@ class MfRun:
                     for idx in wrap_batches(len(mine), a.batch_size)]
             get = self._train_plans[wi].__getitem__
             n = len(self._train_plans[wi])
-        stage = srv.prefetch is not None and self.K == 1
-        ready = {}
-
-        def prepare(bi: int) -> None:
-            with srv._span("app.prepare", self._h_prepare,
-                           work=self._h_prepare_work):
-                b = ready[bi] = get(bi)
-                fut = w.current_clock + a.lookahead
-                self.signal_intent(w, b, fut, fut + 1)
-                if stage and b.staged is None:
-                    b.staged = self.device_runner(w.shard).prefetch_keys(
-                        b.roles)
-
-        for bi in range(n):
-            if bi + a.lookahead < n:
-                prepare(bi + a.lookahead)
-            # the first batches: no intent ran ahead
-            b = ready.pop(bi, None) or get(bi)
-            self.train_batch(w, b.roles, b.aux, b.staged)
-        self.scan_win.flush(self.lr)
+        self.walk(self.workers[wi], n, get, self.lr)
 
     def train_pass(self) -> None:
         """One pass over this process's points in --algorithm's order."""
@@ -328,10 +235,15 @@ class MfRun:
                                 w.current_clock + nb_cur,
                                 w.current_clock + nb_cur + nb_nxt)
                 # fixed batch size B: wrap_batches tiles small blocks so
-                # every fused step has one static shape (one XLA compile)
+                # every fused step has one static shape (one XLA compile).
+                # A window a block (shards must not mix in one, and it
+                # is flushed before the barrier), at the CURRENT lr: the
+                # bold driver changes it from pass to pass
+                win = self.window(w, self.lr)
                 for idx in wrap_batches(len(blk), B, self.rng):
-                    self.train_batch(w, *self.batch(blk[idx]))
-                self.scan_win.flush(self.lr)
+                    win.add(Batch(*self.batch(blk[idx])))
+                    w.advance_clock()
+                win.flush()
             srv.barrier()  # per-subepoch barrier (reference :409-458)
 
     # -- the pass-end loss ---------------------------------------------------
@@ -371,8 +283,8 @@ class MfRun:
                 if n < B:
                     idx = np.concatenate([idx, np.repeat(idx[-1:], B - n)])
                 roles, x = self.batch(idx)
-                plan.append(_Batch(roles, (put(x), put(np.int32(n))), None,
-                                   runner.prefetch_keys(roles)))
+                plan.append(Batch(roles, (put(x), put(np.int32(n))), None,
+                                  runner.prefetch_keys(roles)))
         return self._loss_plans[wi]
 
     def pass_loss(self) -> float:
@@ -404,6 +316,20 @@ class MfRun:
             err, sq = control.allreduce([err, sq], "sum", site="mf_loss")
         return float(err) + a.l2 * float(sq)
 
+    def pass_end(self, out) -> tuple:
+        """The pass-end loss and the bold driver's new step size."""
+        a = self.args
+        loss = self.pass_loss()
+        lr = self.lr
+        # bold driver (reference matrix_factorization.cc): grow on
+        # success, shrink on divergence, compared to the *previous*
+        # pass, so a recovery after one bad pass counts as success
+        self.lr = lr * a.bold_inc if loss <= self.prev_loss \
+            else lr * a.bold_dec
+        self.prev_loss = loss
+        self.best_loss = min(self.best_loss, loss)
+        return loss, f"lr={lr:.4f}"
+
 
 def open_run(args) -> MfRun:
     """Set-up: data, server, initialized factors, compiled programs. The
@@ -422,34 +348,12 @@ def train(mrun: MfRun) -> float:
     loss and the bold driver's new step size; stops at the first pass end
     after `--max_runtime`. Leaves the server up (see open_run) and can be
     called again on the same run. Returns the best pass loss so far."""
-    args, srv = mrun.args, mrun.srv
-    guard = RuntimeGuard(args.max_runtime)
-    watch = Stopwatch(start=True)
-    for _ in range(args.epochs):
-        mrun.train_pass()
-        with srv._span("app.pass_end", mrun._h_pass_end,
-                       work=mrun._h_pass_end_work):
-            srv.quiesce()
-            loss = mrun.pass_loss()
-            lr = mrun.lr
-            # bold driver (reference matrix_factorization.cc): grow on
-            # success, shrink on divergence, compared to the *previous*
-            # pass, so a recovery after one bad pass counts as success
-            mrun.lr = lr * args.bold_inc if loss <= mrun.prev_loss \
-                else lr * args.bold_dec
-        epoch_report("mf", mrun.epoch, loss, watch, extra=f"lr={lr:.4f}")
-        mrun.prev_loss = loss
-        mrun.best_loss = min(mrun.best_loss, loss)
-        mrun.epoch += 1
-        if guard.expired():
-            alog("[mf] max_runtime reached")
-            break
-
+    args = mrun.args
+    mrun.train_passes()
     if args.export_prefix and mrun.pid == 0:
         Wc, Hc = mrun.current_factors()
         mfio.write_dense(args.export_prefix + "W.mma", Wc)
         mfio.write_dense(args.export_prefix + "H.mma", Hc)
-    alog("[mf]", srv.sync.report())
     return float(mrun.best_loss)
 
 

@@ -32,12 +32,9 @@ import numpy as np
 from ..io import text as textio
 from ..models.sgns import (build_alias_table, sgns_loss, subsample_mask,
                            syn0_key, syn1_key)
-from ..ops import DeviceRoutedRunner
-from ..utils import Stopwatch, alog
-from .common import (KeyMapper, RuntimeGuard, ScanWindow,
-                     add_common_arguments, enforce_full_replication,
-                     epoch_report, global_worker_slices, make_server,
-                     worker0_init)
+from .common import (AppRun, Batch, KeyMapper, add_common_arguments,
+                     enforce_full_replication, global_worker_slices,
+                     make_server, worker0_init)
 
 
 def _pairs_for(sent: np.ndarray, sent_idx: int, window: int, seed: int,
@@ -52,14 +49,18 @@ def _pairs_for(sent: np.ndarray, sent_idx: int, window: int, seed: int,
     return textio.skipgram_pairs(sent, window, rng)
 
 
-class W2vRun:
+class W2vRun(AppRun):
     """One training run: the server, its workers and their fused runners,
     the vocabulary with its counts, the tokenised sentences, and what
     carries over from pass to pass (the pass count, the last mean
-    loss)."""
+    loss). The app is SENTENCE-clocked (an intent a sentence, a batch
+    when `--batch_size` pairs have gathered, a clock tick a sentence),
+    so its pass is a buffer loop of its own over `AppRun.window` and not
+    the batch walk."""
+
+    tag = "w2v"
 
     def __init__(self, args, words, counts, sents):
-        self.args = args
         self.words, self.counts = words, counts
         self.total_words = int(counts.sum())
         self.V, self.d = len(counts), args.dim
@@ -68,19 +69,15 @@ class W2vRun:
         num_keys = 2 * self.V
         self.kmap = KeyMapper(num_keys, args.enforce_random_keys,
                               seed=args.seed)
-        self.srv = make_server(args, num_keys, value_lengths=2 * self.d,
-                               num_workers=args.num_workers or None)
-        self.num_workers = args.num_workers or self.srv.num_shards
-        self.workers = [self.srv.make_worker(i)
-                        for i in range(self.num_workers)]
+        self.attach_server(args, make_server(
+            args, num_keys, 2 * self.d,
+            num_workers=args.num_workers or None))
         # negatives drawn IN-PROGRAM from the unigram^0.75 alias table
         # over the syn1 physical keys, with a Local-scheme snap that may
         # only land on other syn1 keys, never syn0 (the reference's
         # negative table, word2vec.cc:125-144, as two O(V) HBM arrays);
         # per step the host ships only the center/context key batch
-        self._dev_runners = {}
         self._neg_alias = build_alias_table(counts)
-        self.epoch = 0      # passes trained so far, over all train() calls
         self.mean_loss = 0.0
         # per-worker contiguous sentence partition over all processes'
         # workers (reference :524-531)
@@ -94,7 +91,6 @@ class W2vRun:
         # before dispatch, so intent windows are extended by a slack
         # estimated from the corpus (otherwise replicas could expire
         # while a batch sits in the window).
-        self.K = max(1, args.scan_steps)
         self.scan_slack = 0
         if self.K > 1:
             probe = [len(self.pairs(si)[0])
@@ -103,18 +99,8 @@ class W2vRun:
             self.scan_slack = int(np.ceil(
                 self.K * args.batch_size / est_pairs)) * 2 + self.K
 
-        # host time of the loop's own phases (Server._span; the step's
-        # other phases are bracketed where they live: kv.intent,
-        # fused.dispatch, kv.drive_rounds, kv.advance_clock), and what a
-        # pass is made of
+        # what a pass is made of
         obs = self.srv.obs
-        self._h_prepare = obs.histogram("app.prepare_s", shared=True)
-        self._h_pass_end = obs.histogram("app.pass_end_s", shared=True)
-        # the same less the waits for the device beneath them (`work=`)
-        self._h_prepare_work = obs.histogram("app.prepare_work_s",
-                                             shared=True)
-        self._h_pass_end_work = obs.histogram("app.pass_end_work_s",
-                                              shared=True)
         self._c_sentences = obs.counter("app.sentences_total",
                                         unit="sentences", shared=True)
         self._c_pairs = obs.counter("app.pairs_total", unit="pairs",
@@ -127,18 +113,22 @@ class W2vRun:
         return _pairs_for(self.sents[si], si, a.window, a.seed,
                           self.counts, self.total_words, a.sample)
 
-    def device_runner(self, shard: int) -> DeviceRoutedRunner:
-        if shard not in self._dev_runners:
-            a, d, V = self.args, self.d, self.V
-            self._dev_runners[shard] = DeviceRoutedRunner(
-                self.srv, sgns_loss,
-                role_class={"center": 0, "ctx": 0, "neg": 0},
-                role_dim={k: d for k in ("center", "ctx", "neg")},
-                shard=shard, neg_role="neg",
-                neg_shape=(a.batch_size, a.negative),
-                neg_population=self.kmap(syn1_key(np.arange(V))),
-                neg_alias=self._neg_alias, seed=a.seed + shard)
-        return self._dev_runners[shard]
+    def runner_spec(self) -> dict:
+        a = self.args
+        return dict(
+            loss_fn=sgns_loss,
+            role_class={"center": 0, "ctx": 0, "neg": 0},
+            role_dim={k: self.d for k in ("center", "ctx", "neg")},
+            neg_role="neg", neg_shape=(a.batch_size, a.negative),
+            neg_population=self.kmap(syn1_key(np.arange(self.V))),
+            neg_alias=self._neg_alias,
+            # every worker's runner compiles its own programs, as ever.
+            # Shared, seven compile stalls leave a pass's first steps,
+            # the ActionTimer (wall clock) acts on other intents, and
+            # the mesh's pinned losses move in the fifth digit
+            # (tests/test_word2vec_run.py; with
+            # --sys.time_intent_actions 0 both give the same bits)
+            programs=None)
 
     def precompile(self) -> int:
         """`Server.precompile` with this app's sizes: an intent names one
@@ -167,11 +157,10 @@ class W2vRun:
         """One pass over this process's sentences; returns the steps'
         losses (device scalars; a scan window's are a [K] vector)."""
         args, srv = self.args, self.srv
-        B, K = args.batch_size, self.K
+        B = args.batch_size
         losses = []
         for wi, w in enumerate(self.workers):
             my = self.slices[wi].tolist()
-            runner = self.device_runner(w.shard)
             # (sent position, centers, contexts) of prepared future
             # sentences
             prepared: deque = deque()
@@ -198,8 +187,7 @@ class W2vRun:
             for pos in range(min(args.readahead, len(my))):
                 prepare(pos, ahead=pos)
 
-            scan_win = ScanWindow(srv, K, args.sync_rounds_per_step,
-                                  on_loss=losses.append)
+            win = self.window(w, args.lr, on_loss=losses.append)
 
             n_buf = 0
             for pos in range(len(my)):
@@ -214,30 +202,39 @@ class W2vRun:
                 while n_buf >= B:
                     cc = np.concatenate(buf_c)
                     xx = np.concatenate(buf_x)
-                    if K > 1:
-                        scan_win.add(runner,
-                                     {"center": cc[:B], "ctx": xx[:B]},
-                                     None, args.lr)
-                    else:
-                        losses.append(runner(
-                            {"center": cc[:B], "ctx": xx[:B]}, None,
-                            args.lr))
-                        # inline rounds, or delegated to the prefetch
-                        # pipeline so planner work overlaps the step
-                        srv.drive_rounds(args.sync_rounds_per_step)
+                    win.add(Batch({"center": cc[:B], "ctx": xx[:B]}, None))
                     buf_c, buf_x = [cc[B:]], [xx[B:]]
                     n_buf -= B
                 w.advance_clock()
-            scan_win.flush(args.lr)  # partial window at worker end
+            win.flush()  # partial window at worker end
             # tail: wrap-pad the remaining pairs into one final batch
+            # (a step of its own, no rounds after it)
             if n_buf > 0:
                 cc = np.concatenate(buf_c)
                 xx = np.concatenate(buf_x)
                 reps = -(-B // len(cc))
-                losses.append(runner(
+                losses.append(self.device_runner(w.shard)(
                     {"center": np.tile(cc, reps)[:B],
                      "ctx": np.tile(xx, reps)[:B]}, None, args.lr))
         return losses
+
+    def pass_end(self, losses) -> tuple:
+        """The mean of the pass's losses."""
+        from ..parallel import control
+        with self.srv._span("app.loss_fetch", wait=True):
+            # scan windows contribute [K] loss vectors, per-step
+            # path scalars
+            mean_loss = float(np.mean(np.concatenate(
+                [np.ravel(np.asarray(l)) for l in losses]))) \
+                if losses else 0.0
+        self.mean_loss = float(control.allreduce(mean_loss, "mean")[0])
+        return self.mean_loss, ""
+
+    def after_pass(self) -> None:
+        from ..parallel import control
+        if self.args.export_prefix and control.process_id() == 0:
+            _export(self.srv, self.kmap, self.words, self.d,
+                    f"{self.args.export_prefix}epoch{self.epoch}.txt")
 
 
 def _load_corpus(args):
@@ -271,33 +268,7 @@ def train(wrun: W2vRun) -> float:
     the mean of its steps' losses; stops at the first pass end after
     `--max_runtime`. Leaves the server up (see open_run) and can be
     called again on the same run. Returns the last pass's mean loss."""
-    args, srv = wrun.args, wrun.srv
-    guard = RuntimeGuard(args.max_runtime)
-    watch = Stopwatch(start=True)
-    from ..parallel import control
-    for _ in range(args.epochs):
-        losses = wrun.train_pass()
-        with srv._span("app.pass_end", wrun._h_pass_end,
-                       work=wrun._h_pass_end_work):
-            srv.quiesce()
-            with srv._span("app.loss_fetch", wait=True):
-                # scan windows contribute [K] loss vectors, per-step
-                # path scalars
-                mean_loss = float(np.mean(np.concatenate(
-                    [np.ravel(np.asarray(l)) for l in losses]))) \
-                    if losses else 0.0
-            mean_loss = float(control.allreduce(mean_loss, "mean")[0])
-        epoch, wrun.mean_loss = wrun.epoch, mean_loss
-        wrun.epoch += 1
-        epoch_report("w2v", epoch, mean_loss, watch)
-        if args.export_prefix and control.process_id() == 0:
-            _export(srv, wrun.kmap, wrun.words, wrun.d,
-                    f"{args.export_prefix}epoch{epoch}.txt")
-        if guard.expired():
-            alog("[w2v] max_runtime reached")
-            break
-
-    alog("[w2v]", srv.sync.report())
+    wrun.train_passes()
     return wrun.mean_loss
 
 

@@ -53,6 +53,26 @@ def _drop_lockorder_sentinel():
 
 
 @pytest.fixture
+def compiled_in_process():
+    """The test's programs are compiled by its own process; none is
+    loaded from jax's persistent compilation cache. On this image's XLA
+    CPU client the 8-shard KGE step of
+    `test_kge_lowrank_reaches_truth_ceiling_fraction`, LOADED from the
+    cache, aborts within a few dozen steps in a collective's rendezvous
+    (`rendezvous.h: Check failed: id < num_threads (8 vs. 8)`, SIGABRT or
+    SIGSEGV, the xdist worker goes down), every time; compiled in
+    process it never has, and a seed's run is then the same to the last
+    digit, alone and beside busy processes (PR 44; ROADMAP C0)."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", True)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture
 def kernel_cache(tmp_path):
     """jax's compilation cache directory, where `ops/writeback.py` keeps
     the exported write-back kernel, pointed at an empty one; yields the

@@ -233,6 +233,38 @@ def test_kge_scan_steps_trains():
     assert result["mrr"] > 0.12, result
 
 
+# the loss of each of three `train(run)` calls of one pass at PR 43's
+# commit (the app's own loop, its K-step window inline), as float.hex():
+# --seed 0 on ONE shard, where an intent moves nothing and no upload's
+# moment can change a value
+KGE_PARENT = {
+    (): ["0x1.7190200000000p+1", "0x1.e5c3360000000p+0",
+         "0x1.8705de0000000p+0"],
+    ("--scan_steps", "4"): ["0x1.7190200000000p+1", "0x1.e5c3360000000p+0",
+                            "0x1.8705dc0000000p+0"],
+}
+
+
+@pytest.mark.parametrize("extra", sorted(KGE_PARENT))
+def test_kge_pass_losses_are_the_parent_s_to_the_bit(extra):
+    """The one batch walk (apps/common.py) trains what the app's own
+    loop trained, per step and in windows of 4 (13 batches a pass:
+    three windows and a single step): the same batches in the same
+    order, the same draws of negatives."""
+    from adapm_tpu.apps import knowledge_graph_embeddings as kge
+    run = kge.open_run(kge.build_parser().parse_args(
+        ["--dim", "8", "--neg_ratio", "2", "--synthetic_entities", "60",
+         "--synthetic_relations", "4", "--synthetic_triples", "400",
+         "--epochs", "1", "--batch_size", "32", "--lr", "0.2",
+         "--eval_every", "0", "--num_shards", "1", "--seed", "0"]
+        + FAST + list(extra)))
+    try:
+        got = [float(kge.train(run)["loss"]).hex() for _ in range(3)]
+    finally:
+        run.srv.shutdown()
+    assert got == KGE_PARENT[extra]
+
+
 @pytest.mark.slow
 @pytest.mark.skipif((__import__("os").cpu_count() or 1) < 4,
                     reason="heavy 8-participant virtual-mesh collectives "
@@ -291,7 +323,7 @@ def test_kge_lr_decay_beats_constant():
 @pytest.mark.skipif((__import__("os").cpu_count() or 1) < 4,
                     reason="20-epoch dim-64 mid-scale run (~30+ CPU-min); "
                            "needs a multi-core host for time")
-def test_kge_midscale_ceiling_fraction():
+def test_kge_midscale_ceiling_fraction(compiled_in_process):
     """Pinned CEILING FRACTION at mid scale (VERDICT r4 item 2's 'not
     just 1.5x-uniform' bar): the round-5 recipe (dim 64 >= 4x the
     generator's dim_truth, lr 0.7 x 0.93/epoch, freq + self-adv 3.0)
@@ -354,12 +386,23 @@ def test_mf_random_keys():
     assert np.isfinite(loss)
 
 
-def test_kge_lowrank_reaches_truth_ceiling_fraction():
+def test_kge_lowrank_reaches_truth_ceiling_fraction(compiled_in_process):
     """--synthetic_mode lowrank draws the KG from a ground-truth ComplEx
     model and reports that model's own filtered MRR as the ceiling; a
     trained model must reach a solid fraction of it (quality evidence on
     a graph that is learnable BY CONSTRUCTION, unlike the adversarial
-    permutation KG)."""
+    permutation KG).
+
+    The run is repeatable (inline planner rounds, `FAST`): ten runs, five
+    of them beside five busy processes, read test MRR 0.388659 of a
+    ceiling of 0.596416 (0.6517) each time, to the last digit (PR 44).
+    What made this test red in the driver's runs of PRs 42 and 43 was
+    not its margin: its fused step LOADED from jax's persistent
+    compilation cache aborts in XLA's CPU rendezvous (conftest.py
+    `compiled_in_process`, which keeps the cache out of this test). The
+    prefetch pipeline's effect on quality is not covered here (`FAST`
+    pins --sys.prefetch 0); tests/test_prefetch.py covers its
+    mechanics."""
     from adapm_tpu.apps import knowledge_graph_embeddings as kge
     args = kge.build_parser().parse_args(
         ["--dim", "32", "--neg_ratio", "4", "--synthetic_entities", "200",
@@ -371,8 +414,10 @@ def test_kge_lowrank_reaches_truth_ceiling_fraction():
     ceiling = result["truth_mrr"]  # the app's own generation run
     assert ceiling > 0.5, f"generator ceiling unexpectedly low: {ceiling}"
     # the ceiling is computed on the TEST split, so compare test MRR;
-    # measured 0.63x of ceiling at this config on the 8-shard test mesh —
-    # 0.45 floor leaves margin for parallel-SGD stochasticity
+    # measured 0.65x of ceiling at this config on the 8-shard test mesh
+    # (PR 44; 0.63x when the floor was set): the 0.45 floor leaves margin
+    # for a change of the step's arithmetic, not for run-to-run spread,
+    # of which there is none
     assert result["test_mrr"] > 0.45 * ceiling, \
         (result["test_mrr"], ceiling)
 

@@ -220,6 +220,20 @@ def test_two_train_calls_of_one_pass_equal_one_call_of_two():
         two.srv.shutdown()
 
 
+def test_pass_losses_are_the_parent_s_to_the_bit():
+    """Three `train(run)` calls of one pass on ONE shard (an intent
+    moves nothing there) with --seed 0: the mean losses of PR 43's
+    commit, whose app had its own loop, as float.hex(). The one batch
+    walk (apps/common.py) trains the same batches in the same order."""
+    run = ctr.open_run(_args("--seed", "0"))
+    try:
+        got = [float(ctr.train(run)).hex() for _ in range(3)]
+    finally:
+        run.srv.shutdown()
+    assert got == ["0x1.5f9fc20000000p-1", "0x1.53d66e0000000p-1",
+                   "0x1.432aa00000000p-1"]
+
+
 def test_max_runtime_stops_at_the_first_pass_end():
     run = ctr.open_run(_args("--max_runtime", "1e-9", epochs=50))
     try:

@@ -365,7 +365,7 @@ def test_four_workers_share_one_compiled_step_a_variant():
     run = _open("all", entities=1400)
     try:
         runners = [run.device_runner(w.shard) for w in run.workers]
-        assert len(run._step_programs) == 2
+        assert len(run._programs) == 2
         for name in ("step_fn", "_step_fn_norep"):
             assert len({id(getattr(r, name)) for r in runners}) == 1
         assert sum(r.steps for r in runners) == 0
@@ -570,6 +570,6 @@ def test_one_shard_never_refreshes_its_routes_after_set_up():
         assert srv.obs.find("fused.route_refresh_total").snap() == at_set_up
         assert srv.obs.find("fused.route_patch_total").snap() == 0
         assert srv.obs.find("sync.relocations_total").snap() == 0
-        assert len(run._step_programs) == 2
+        assert len(run._programs) == 2
     finally:
         run.srv.shutdown()

@@ -68,7 +68,7 @@ def _batches(rng):
 
 
 def _with_distinct(roles):
-    return mf._Batch(roles, None, np.unique(
+    return mf.Batch(roles, None, np.unique(
         np.concatenate([roles["w"], roles["h"]])))
 
 
@@ -88,7 +88,7 @@ def _leaf_gaps(got, want, init, cols):
 
 @pytest.mark.parametrize("shards", [1, 4])
 def test_fused_step_follows_the_reference_step_by_step(shards):
-    """4 steps through the app's own `train_batch` (intent and planner
+    """4 steps as the app's loop takes them (intent and planner
     rounds live on four shards): each loss, the first gradient's norm
     (from the accumulator columns), the update's norm and the share of
     its difference, per leaf. The batch whose column role is one key B
@@ -193,6 +193,39 @@ def test_two_train_calls_of_one_pass_equal_one_call_of_two(algorithm):
         two.srv.shutdown()
 
 
+# the pass loss after each of three `train(run)` calls of one pass at
+# PR 43's commit (every app its own loop), as float.hex(): --seed 0 on
+# ONE shard, where an intent moves nothing and no upload's moment can
+# change a value, two workers (a window is flushed between them)
+PARENT = {
+    ("columnwise",): ["0x1.886c17dc28f5cp+5", "0x1.721ba6e147ae1p+4",
+                      "0x1.59481c28f5c29p+3"],
+    ("columnwise", "--scan_steps", "4"): [
+        "0x1.886c17dc28f5cp+5", "0x1.721ba6e147ae1p+4",
+        "0x1.59481c28f5c29p+3"],
+    ("dsgd",): ["0x1.3d6b7447ae148p+5", "0x1.fb16786666666p+3",
+                "0x1.b6ae26e147ae1p+2"],
+    ("plain",): ["0x1.90f0b21eb851fp+5", "0x1.73a49a7ae147bp+4",
+                 "0x1.52669d70a3d71p+3"],
+}
+
+
+@pytest.mark.parametrize("case", sorted(PARENT))
+def test_pass_losses_are_the_parent_s_to_the_bit(case):
+    """The one batch walk (apps/common.py) trains what the app's own
+    loop trained: the same batches in the same order, pass by pass."""
+    run = mf.open_run(_args("--seed", "0", "--num_workers", "2", *case[1:],
+                            algorithm=case[0]))
+    try:
+        got = []
+        for _ in range(3):
+            mf.train(run)
+            got.append(float(run.prev_loss).hex())
+    finally:
+        run.srv.shutdown()
+    assert got == PARENT[case]
+
+
 def test_bold_driver_halves_the_step_after_a_worse_pass():
     run = mf.open_run(_args())
     try:
@@ -244,28 +277,33 @@ def test_open_run_compiles_what_train_runs():
         run.srv.shutdown()
 
 
-def test_unshuffled_walks_are_prepared_once():
+@pytest.mark.parametrize("prefetch", ["0", "1"])
+def test_unshuffled_walks_are_prepared_once(prefetch):
     """A columnwise pass and the loss walk are the same every pass: their
     batches (keys, values, uploads) are built at the first and kept;
-    `set_points` drops them; a shuffled walk keeps none."""
-    run = mf.open_run(_args("--sys.prefetch", "1", epochs=2))
+    `set_points` drops them; a shuffled walk keeps none. Every batch of
+    a walk is staged (its keys uploaded where it is prepared), the first
+    --lookahead too, with the prefetch pipeline and without it."""
+    run = mf.open_run(_args("--sys.prefetch", prefetch, epochs=2))
     try:
         mf.train(run)
         plan, loss_plan = run._train_plans[0], run._loss_plans[0]
         assert len(plan) == len(loss_plan) == -(-600 // B)
         assert all(b.staged is not None for b in loss_plan)
-        # the first --lookahead batches have no intent, so no upload
-        assert [b.staged is not None for b in plan] == \
-            [False, False] + [True] * (len(plan) - 2)
+        assert all(b.staged is not None and b.staged.matches(b.roles)
+                   for b in plan)
+        uploads = [b.staged for b in plan]
         mf.train(run)
         assert run._train_plans[0] is plan and run._loss_plans[0] is \
             loss_plan
+        assert all(b.staged is u for b, u in zip(plan, uploads))
         rows, cols, vals, _, _ = mf._load_data(run.args)
         run.set_points(rows[:B], cols[:B], vals[:B])
         assert run._train_plans == {} and run._loss_plans == {}
     finally:
         run.srv.shutdown()
-    shuffled = mf.open_run(_args(algorithm="plain"))
+    shuffled = mf.open_run(_args("--sys.prefetch", prefetch,
+                                 algorithm="plain"))
     try:
         mf.train(shuffled)
         assert shuffled._train_plans == {} and len(shuffled._loss_plans) == 1

@@ -21,6 +21,11 @@ FAST = ["--sys.sync.max_per_sec", "0", "--sys.prefetch", "0"]
 PARENT = {
     (): ["0x1.a1d02a0000000p+1", "0x1.2a70720000000p+1",
          "0x1.18bce20000000p+1"],
+    # the mesh with the ActionTimer off (PR 44, read on PR 43's commit):
+    # which round acts on an intent then follows no wall clock
+    ("--sys.time_intent_actions", "0"): [
+        "0x1.a3ef080000000p+1", "0x1.2a8d600000000p+1",
+        "0x1.18d5820000000p+1"],
     ("--num_shards", "1", "--sample", "1e-3"): [
         "0x1.4445040000000p+1", "0x1.211aa40000000p+1",
         "0x1.169ae20000000p+1"],

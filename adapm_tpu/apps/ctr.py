@@ -329,8 +329,9 @@ class CtrServe:
         w0.end_setup()
 
     def open_plane(self):
-        """The serve plane over the tables, its bag programs compiled:
-        a coalesced batch is 1..`--sys.serve.max_batch` requests of
+        """The serve plane over the tables, its bag programs (and, with
+        `--sys.tier 1`, their cold twins and the tier worker's
+        programs) compiled: a coalesced batch is 1..`--sys.serve.max_batch` requests of
         `--serve_samples` min,max samples each, every sample M member
         positions in T bags of ONE length class, so one `_gather_pool`
         program a batch."""
@@ -342,6 +343,9 @@ class CtrServe:
         self.plane.precompile_bags(
             ((M * s, T * s) for s in range(lo, most + 1)),
             cid=int(self.srv.ab.key_class[0]), pooling="sum")
+        # --sys.tier 1: the tier worker's programs (one shard has no
+        # planner programs to compile)
+        self.srv.precompile({})
         return self.plane
 
     def feat_keys(self, members: np.ndarray) -> np.ndarray:

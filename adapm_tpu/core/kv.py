@@ -626,6 +626,19 @@ class Server:
             wait, work = False, None
         return Span(name, hist, self.spans, wait, work)
 
+    @contextlib.contextmanager
+    def _locked(self, name: str, hist=None, wait: bool = False):
+        """The server lock with the WAIT for it in a bracket of its own
+        (`adapm.<name>`, `hist`; `wait`: a wait span): the serve
+        dispatcher's and the tier worker's way in, each of which can
+        wait behind the other."""
+        with self._span(name, hist, wait=wait):
+            self._lock.acquire()
+        try:
+            yield
+        finally:
+            self._lock.release()
+
     def _store_wait(self) -> Span:
         """The bracket the stores put around a planner program's call
         (core/store.py): the wait span `store.enqueue`."""
@@ -968,26 +981,30 @@ class Server:
 
     def _read_owned_bulk(self, keys: np.ndarray) -> np.ndarray:
         """Checkpoint/eval/export-scale read: copy each class pool to host
-        once, then reorder rows with a vectorized fancy index."""
+        once, then reorder rows with a vectorized fancy index. Under the
+        server lock (re-entrant: `read_main` and the service endpoints
+        hold it already): the tier's maintenance worker donates and
+        replaces the pool this reads, and residency moves under it."""
         from ..parallel.pm import _fill_flat, _offsets
         lens = self.value_lengths[keys]
         offs = _offsets(lens)
         out = np.empty(offs[-1], dtype=np.float32)
-        for cid, pos in self._group_by_class(keys):
-            ks = keys[pos]
-            st = self.stores[cid]
-            if st.res is not None:
-                # tiered: read only the REQUESTED rows (cold store fancy
-                # index + one hot-pool-sized overlay readback) — a full
-                # main_host() copy would transiently double host RAM at
-                # the beyond-HBM sizes tiering exists for
-                from ..tier.coldpath import read_main_rows_bulk
-                rows = read_main_rows_bulk(
-                    st, self.ab.owner[ks], self.ab.slot[ks])
-            else:
-                host = np.asarray(st.main)             # [S, slots, L]
-                rows = host[self.ab.owner[ks], self.ab.slot[ks]]
-            _fill_flat(out, offs, lens, pos, rows.ravel())
+        with self._lock:
+            for cid, pos in self._group_by_class(keys):
+                ks = keys[pos]
+                st = self.stores[cid]
+                if st.res is not None:
+                    # tiered: read only the REQUESTED rows (cold store
+                    # fancy index + one hot-pool-sized overlay readback)
+                    # — a full main_host() copy would transiently double
+                    # host RAM at the beyond-HBM sizes tiering exists for
+                    from ..tier.coldpath import read_main_rows_bulk
+                    rows = read_main_rows_bulk(
+                        st, self.ab.owner[ks], self.ab.slot[ks])
+                else:
+                    host = np.asarray(st.main)         # [S, slots, L]
+                    rows = host[self.ab.owner[ks], self.ab.slot[ks]]
+                _fill_flat(out, offs, lens, pos, rows.ravel())
         return out
 
     def _plan_cached(self, kind: str, shard: int, keys: np.ndarray,
@@ -1392,6 +1409,9 @@ class Server:
         per-step variants (DeviceRoutedRunner.precompile). A fourth
         entry is the `score_aux` of a runner that has a score program.
 
+        A tiered server's maintenance programs at the buckets its
+        worker can dispatch (TierManager.precompile).
+
         Returns how many planner programs ran."""
         ran = 0
         if self.num_shards > 1:
@@ -1409,6 +1429,9 @@ class Server:
                         sync_variants=variants)
         for runner, role_keys, aux, *score_aux in steps:
             runner.precompile(role_keys, aux, *score_aux)
+        if self.tier is not None:
+            # the maintenance worker's promotion and demotion programs
+            ran += self.tier.precompile()
         return ran
 
     # -- lifecycle -----------------------------------------------------------

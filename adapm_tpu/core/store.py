@@ -51,6 +51,16 @@ def bucket_size(n: int, minimum: int = 8) -> int:
     return 1 << math.ceil(math.log2(n))
 
 
+def bucket_ladder(top: int, minimum: int = 8) -> list:
+    """Every bucket a batch of 1..`top` rows can be padded to: the
+    floor, then the powers of two above it (what a `precompile` runs)."""
+    sizes, n = set(), 1
+    while n < 2 * max(1, top):
+        sizes.add(bucket_size(min(n, top), minimum))
+        n *= 2
+    return sorted(sizes)
+
+
 def pad_to(arr: np.ndarray, size: int, fill) -> np.ndarray:
     out = np.full((size,) + arr.shape[1:], fill, dtype=arr.dtype)
     out[: arr.shape[0]] = arr
@@ -189,10 +199,17 @@ class ShardedStore:
         self.tier_hot_hits = 0   # owner-served gather entries, hot
         self.tier_cold_hits = 0  # owner-served gather entries, cold
         self.tier_hist = None    # cold-serve latency hist (TierManager)
+        # the bag read's cold staging: its bracket (`serve.cold_stage`)
+        # and the bytes it uploads (TierManager hands both over)
+        self.tier_stage = contextlib.nullcontext
+        self.tier_stage_bytes = None
+        self.stage_ring = None   # its kept staging buffers (coldpath.py)
         dev_main_slots = self.main_slots
         if tier_hot_rows > 0:
+            from ..tier.coldpath import StageRing
             from ..tier.quant import QuantCold
             from ..tier.residency import Residency
+            self.stage_ring = StageRing()
             dev_main_slots = _round8(
                 min(self.main_slots, max(8, tier_hot_rows)))
             self.res = Residency(S, self.main_slots, dev_main_slots)
@@ -559,22 +576,15 @@ class ShardedStore:
         if self.res is not None:
             return 0
 
-        def ladder(top: int):
-            sizes, n = set(), 1
-            while n < 2 * max(1, top):
-                sizes.add(bucket_size(min(n, top), self.bucket_min))
-                n *= 2
-            return sorted(sizes)
-
         ran = 0
-        for b in ladder(moved):
+        for b in bucket_ladder(moved, self.bucket_min):
             sh, oob = np.zeros(b, np.int32), np.full(b, OOB, np.int32)
             self.cache, self.delta = self.port.replica_create(
                 self.main, self.cache, self.delta, sh, oob, sh, oob)
             self.main, self.delta = self.port.relocate(
                 self.main, self.delta, sh, oob, sh, oob, sh, oob)
             ran += 2
-        for b in ladder(synced):
+        for b in bucket_ladder(synced, self.bucket_min):
             sh, oob = np.zeros(b, np.int32), np.full(b, OOB, np.int32)
             for threshold, compress in sync_variants:
                 out = self.port.sync_replicas(

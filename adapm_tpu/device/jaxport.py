@@ -310,17 +310,20 @@ def _gather_cold(main, cache, delta, o_shard, o_row, c_shard, c_slot,
     return jnp.where(use_cache[:, None], c, m)
 
 
-@partial(jax.jit, static_argnames=("pooling",))
+@partial(jax.jit, static_argnames=("nbags", "pooling"))
+@_scoped("adapm_gather_pool_cold")
 def _gather_pool_cold(main, cache, delta, o_shard, o_row, c_shard,
-                      c_slot, use_cache, cold_vals, use_cold, seg, out,
-                      *, pooling):
+                      c_slot, use_cache, cold_vals, use_cold, seg,
+                      *, nbags, pooling):
     """`_gather_pool` with `_gather_cold`'s host-supplied row override
-    for cold owner members."""
+    for cold owner members; the zeroed `[nbags, L]` rows are made here,
+    as `_gather_pool` makes them."""
     m = main.at[o_shard, o_row].get(mode="fill", fill_value=0)
     m = jnp.where(use_cold[:, None], cold_vals, m)
     c = (cache.at[c_shard, c_slot].get(mode="fill", fill_value=0)
          + delta.at[c_shard, c_slot].get(mode="fill", fill_value=0))
     rows = jnp.where(use_cache[:, None], c, m)
+    out = jnp.zeros((nbags, rows.shape[1]), rows.dtype)
     return _pool_rows(rows, seg, out, pooling)
 
 
@@ -604,10 +607,11 @@ class JaxDevicePort(DevicePort):
                          use_cold, seg, out, pooling="sum"):
         self.programs += 1
         with _GATE:
+            # `out` fixes the result's shape; its zeros stay on the host
             return _gather_pool_cold(main, cache, delta, o_shard,
                                      o_row, c_shard, c_slot, use_cache,
-                                     cold_vals, use_cold, seg, out,
-                                     pooling=pooling)
+                                     cold_vals, use_cold, seg,
+                                     nbags=out.shape[0], pooling=pooling)
 
     def gather_pool_cold_wire(self, mode: str, main, cache, delta,
                               o_shard, o_row, c_shard, c_slot,

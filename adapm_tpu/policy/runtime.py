@@ -117,9 +117,12 @@ class PolicyPlane:
         f = core_features(self._server, batch_n)
         f.update(extras)
         verdict = m.veto(f)
-        self.c_consults.inc()
         learned = self.modes[plane] == "learned"
         with self._lock:
+            # the total and the plane's tally move together: `stats()`
+            # reads both under this lock (the tier worker consults while
+            # a snapshot is taken)
+            self.c_consults.inc()
             t = self._tallies[plane]
             t["consults"] += 1
             if learned:
@@ -169,7 +172,10 @@ class PolicyPlane:
                          "batch_window_limited":
                              self._batch_window_limited,
                          "batch_size_limited":
-                             self._batch_size_limited}
+                             self._batch_size_limited,
+                         # over the registry's reading of the same
+                         # counter, taken at another moment
+                         "consults_total": self.c_consults.snap()}
             for p in PLANE_KNOBS:
                 out[f"mode.{p}"] = self.modes[p]
                 t = self._tallies[p]

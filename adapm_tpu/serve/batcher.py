@@ -190,6 +190,15 @@ class LookupBatcher:
             "serve.bag_reply_bytes_total", unit="bytes", shared=True)
         self.h_bag_plan = _hist("serve.bag_plan_s")
         self.h_bag_route = _hist("serve.bag_route_s")
+        # the dispatcher's wait for the server lock (ISSUE 46): pushes,
+        # planner rounds and the tier worker's commits hold the same
+        # lock; inside serve.dispatch_s
+        self.h_lock_wait = _hist("serve.lock_wait_s")
+
+    def _server_lock(self):
+        """The server lock for one dispatch, the wait for it in its own
+        bracket (`adapm.serve.lock_wait`, `serve.lock_wait_s`)."""
+        return self.server._locked("serve.lock_wait", self.h_lock_wait)
 
     def replica_hit_rate(self) -> float:
         """Fraction of coalesced batches served from the read-only
@@ -404,13 +413,6 @@ class LookupBatcher:
         else:
             allk = np.concatenate([r.keys for r in reqs])
         union = np.unique(allk)
-        if srv.tier is not None:
-            # tiered storage: consult residency before planning — bump
-            # the union keys' access scores and queue promotion of the
-            # cold ones, so the device-hot set adapts to serve load (the
-            # gather itself serves cold rows correctly through the cold
-            # path either way; tier.serve_cold_keys counts them)
-            srv.tier.note_serve(union)
         after = tuple(f for r in reqs for f in r.after)
         # read fast path (ISSUE 9): a batch with no cross-process write
         # ordering may be served lock-free from the replica snapshot;
@@ -422,6 +424,14 @@ class LookupBatcher:
         if served is not None:
             flat, t_cutoff = served
             self.c_replica_hits.inc()
+            if srv.tier is not None:
+                # tiered storage: the store never sees a batch the
+                # snapshot answers, so its keys are scored (and its cold
+                # ones queued for promotion) here; a gathered batch is
+                # scored by the gather itself, once (tier/coldpath.py
+                # `_note_access`): the hot set adapts to serve load
+                # either way
+                srv.tier.note_serve(union)
             # lock-free hit: the union's rows were in host memory when
             # the window closed, so dispatch and copy_out are 0 (their
             # stamps collapse onto the dispatch point) and the
@@ -518,7 +528,7 @@ class LookupBatcher:
                 plan = srv._plan_cached(
                     "pull", self.shard, keys, tv,
                     lambda: srv._plan_pull(keys, self.shard))
-            with srv._lock:
+            with self._server_lock():
                 if plan is not None and srv.topology_version != tv:
                     plan = None  # topology moved underneath us: re-plan
                 groups, _, remote = srv._pull(keys, self.shard,
@@ -557,8 +567,6 @@ class LookupBatcher:
                 if len(reqs) > 1 else reqs[0].keys
             union = np.unique(allk)
             groups, slices = plan_bag_batch(reqs, srv.ab.key_class)
-        if srv.tier is not None:
-            srv.tier.note_serve(union)
         after = tuple(f for r in reqs for f in r.after)
         self.c_bag_batches.inc()
         self.c_keys_unique.inc(len(union))
@@ -572,6 +580,8 @@ class LookupBatcher:
         if served is not None:
             flat, t_cutoff = served
             self.c_bag_replica_hits.inc()
+            if srv.tier is not None:
+                srv.tier.note_serve(union)   # as the flat path's hit
             self.c_bag_hostpool.inc()
             pooled = self._pool_from_flat(flat, union, groups)
             t_enqueued = t_copied = t_dispatch
@@ -644,7 +654,7 @@ class LookupBatcher:
         srv = self.server
         from ..core.store import OOB
         with srv._span("serve.dispatch"):
-            with srv._lock:
+            with self._server_lock():
                 dev = {}
                 with dispatch_gate():
                     for gkey, g in groups.items():
@@ -670,13 +680,29 @@ class LookupBatcher:
         compiles one `_gather_pool` program a pair of buckets, so every
         pair the sizes fall in runs once here, through the dispatch the
         batcher itself makes, on members of no bag (segment OOB: the
-        pool drops them). Returns how many programs ran."""
+        pool drops them). A tiered store has a cold twin of each, run
+        too. Returns how many programs ran."""
         from ..core.store import OOB, bucket_size
         srv = self.server
         least = srv.stores[cid].bucket_min
         pairs = sorted({(bucket_size(int(n), least),
                          bucket_size(max(int(b), 1), least))
                         for n, b in sizes})
+        st = srv.stores[cid]
+        if st.res is not None:
+            # a tiered store dispatches one of TWO programs a pair of
+            # buckets, by whether the batch names a cold member
+            # (tier/coldpath.py): both run here, straight at the store
+            # (through `_lookup_bags_fused` a repeated key would be
+            # scored, and queued for promotion, n times)
+            from ..tier.coldpath import precompile_gather_pool
+            for n, nb in pairs:
+                with srv._lock:
+                    with dispatch_gate():
+                        dev = precompile_gather_pool(st, n, nb, pooling)
+                for v in dev:
+                    getattr(v, "block_until_ready", lambda: None)()
+            return 2 * len(pairs)
         key = int(np.argmax(srv.ab.key_class == cid))
         for n, nb in pairs:
             dev, _ = self._lookup_bags_fused({(cid, pooling): {

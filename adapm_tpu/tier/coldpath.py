@@ -41,6 +41,68 @@ import numpy as np
 from ..core.store import OOB, pad_bucket, pad_to
 
 # ---------------------------------------------------------------------------
+# kept staging buffers
+# ---------------------------------------------------------------------------
+
+
+_HELD = object()    # a taken buffer whose reader is not dispatched yet
+
+
+class _Stage:
+    """One kept staging buffer: `buf` is zero but for the rows `at`
+    that its last user wrote; `user` is the result of the program that
+    reads it (None: free)."""
+    __slots__ = ("buf", "at", "user")
+
+    def __init__(self, shape, dtype):
+        # `np.full`: touched now, so a window pays no first-touch fault
+        self.buf = np.full(shape, 0, dtype=dtype)
+        self.at = np.empty(0, dtype=np.int64)
+        self.user = None
+
+    def free(self) -> bool:
+        """No program is reading the buffer. The host->device copy of a
+        numpy operand may still be under way when the call returns (and
+        the CPU client reads the array in place), so a buffer is held
+        until the program's RESULT is ready."""
+        u = self.user
+        return u is None or (u is not _HELD and
+                             getattr(u, "is_ready", lambda: True)())
+
+    def fill(self, at: np.ndarray, rows: np.ndarray) -> np.ndarray:
+        """Clear what the last user wrote, write `rows` at `at`."""
+        self.buf[self.at] = 0
+        self.buf[at] = rows
+        self.at = at
+        return self.buf
+
+
+class StageRing:
+    """The bag read's staged operands (`gather_pool_tiered`): a ring of
+    kept buffers a (shape, dtype), so that a batch pays no `np.zeros`
+    of its bucket (a first touch costs 1 ms a MB on the serving host and
+    a 65,536-member batch stages 32 MB: PERF.md section 6, PR 46). A
+    buffer is held from `take` until the program reading it has
+    finished (`_Stage.user`); the ring grows by one where every buffer
+    of a shape is held, so it is as deep as batches are in flight. The
+    caller holds the server lock."""
+
+    def __init__(self):
+        self._rings = {}
+
+    def take(self, shape, dtype) -> _Stage:
+        ring = self._rings.setdefault((tuple(shape), np.dtype(dtype)), [])
+        for st in ring:
+            if st.free():
+                break
+        else:
+            st = _Stage(shape, dtype)
+            ring.append(st)
+        st.user = _HELD
+        return st
+
+
+# ---------------------------------------------------------------------------
 # residency resolution
 # ---------------------------------------------------------------------------
 
@@ -135,46 +197,114 @@ def gather_pool_tiered(store, o_shard, o_slot, c_shard, c_slot,
     of coming back raw. The pooled result is bit-identical to host-
     pooling `gather_tiered`'s rows — the gather half is the same
     program body, and the segment sum accumulates in batch order on
-    both sides."""
-    o_sh = np.asarray(o_shard, dtype=np.int64).ravel()
-    o_sl = np.asarray(o_slot, dtype=np.int64).ravel()
-    g_row, cold, valid = split_owner(store, o_sh, o_sl)
-    _note_access(store, o_sh, o_sl, cold, valid)
-    n = len(o_sh)
-    a = pad_bucket(n, (o_sh.astype(np.int32), 0), (g_row, OOB),
-                   (c_shard, 0), (c_slot, OOB), (use_cache, False),
-                   minimum=store.bucket_min)
-    b = a[0].shape[0]
-    segb = pad_to(np.asarray(seg, dtype=np.int32), b, OOB)
-    if not cold.any():
+    both sides. The host's share of it (the residency split, the cold
+    rows' read and the staged operand: a DENSE `[bucket, L]` array
+    whatever share of the members is cold, a kept buffer of the
+    store's `StageRing`) is the bracket `adapm.serve.cold_stage`, and
+    `tier.cold_stage_bytes` counts the staged bytes handed to the
+    device."""
+    with store.tier_stage():
+        o_sh = np.asarray(o_shard, dtype=np.int64).ravel()
+        o_sl = np.asarray(o_slot, dtype=np.int64).ravel()
+        g_row, cold, valid = split_owner(store, o_sh, o_sl)
+        _note_access(store, o_sh, o_sl, cold, valid)
+        n = len(o_sh)
+        a = pad_bucket(n, (o_sh.astype(np.int32), 0), (g_row, OOB),
+                       (c_shard, 0), (c_slot, OOB), (use_cache, False),
+                       minimum=store.bucket_min)
+        b = a[0].shape[0]
+        segb = pad_to(np.asarray(seg, dtype=np.int32), b, OOB)
+        any_cold = bool(cold.any())
+        if any_cold:
+            t0 = time.perf_counter()
+            use_cold = np.zeros(b, dtype=bool)
+            use_cold[:n] = cold
+            at = np.flatnonzero(cold)
+            # the cold read BEFORE a buffer is taken, and a buffer whose
+            # fill fails handed back: only `_pool_cold` frees one, and a
+            # buffer left held makes the ring grow by a bucket a failure
+            if store.coldq.mode == "fp32":
+                vals = (store.coldq.read(o_sh[cold], o_sl[cold]),)
+            else:
+                vals = store.coldq.wire(o_sh[cold], o_sl[cold])
+            stages = _stages(store, b)
+            try:
+                staged = sum(st.fill(at, v).nbytes
+                             for st, v in zip(stages, vals))
+            except BaseException:
+                for st in stages:
+                    st.user = None
+                raise
+            if store.tier_stage_bytes is not None:
+                store.tier_stage_bytes.inc(staged)
+    if not any_cold:
         return store.port.gather_pool(store.main, store.cache,
                                       store.delta, *a, segb, out,
                                       pooling=pooling)
-    t0 = time.perf_counter()
-    use_cold = np.zeros(b, dtype=bool)
-    use_cold[:n] = cold
-    mode = store.coldq.mode
-    if mode == "fp32":
-        cold_vals = np.zeros((b, store.value_length),
-                             dtype=np.dtype(store.dtype))
-        cold_vals[:n][cold] = store.coldq.read(o_sh[cold], o_sl[cold])
-        pooled = store.port.gather_pool_cold(
-            store.main, store.cache, store.delta, *a, cold_vals,
-            use_cold, segb, out, pooling=pooling)
-    else:
-        q, s = store.coldq.wire(o_sh[cold], o_sl[cold])
-        qbuf = np.zeros((b, store.value_length), dtype=q.dtype)
-        qbuf[:n][cold] = q
-        sbuf = None
-        if mode != "fp16":
-            sbuf = np.zeros(b, dtype=np.float32)
-            sbuf[:n][cold] = s
-        pooled = store.port.gather_pool_cold_wire(
-            mode, store.main, store.cache, store.delta, *a,
-            qbuf, sbuf, use_cold, segb, out, pooling=pooling)
+    pooled = _pool_cold(store, a, stages, use_cold, segb, out, pooling)
     if store.tier_hist is not None:
         store.tier_hist.observe(time.perf_counter() - t0)
     return pooled
+
+
+def _stages(store, b: int) -> list:
+    """The ring's buffers for the staged operands of one cold batch of
+    bucket `b`: the rows in the cold store's wire type and, in the int8
+    mode, their scales."""
+    ring, mode = store.stage_ring, store.coldq.mode
+    rows = np.dtype(store.dtype) if mode == "fp32" else store.coldq.q.dtype
+    stages = [ring.take((b, store.value_length), rows)]
+    if mode == "int8":
+        stages.append(ring.take((b,), np.float32))
+    return stages
+
+
+def _pool_cold(store, a, stages, use_cold, segb, out, pooling):
+    """Dispatch the cold twin of the bag program on the staged operands
+    `stages`, each held until the program has finished (it never
+    started: free again)."""
+    mode, pooled = store.coldq.mode, None
+    pools = (store.main, store.cache, store.delta)
+    try:
+        if mode == "fp32":
+            pooled = store.port.gather_pool_cold(
+                *pools, *a, stages[0].buf, use_cold, segb, out,
+                pooling=pooling)
+        else:
+            pooled = store.port.gather_pool_cold_wire(
+                mode, *pools, *a, stages[0].buf,
+                stages[1].buf if mode == "int8" else None, use_cold,
+                segb, out, pooling=pooling)
+    finally:
+        for st in stages:
+            st.user = pooled
+    return pooled
+
+
+def precompile_gather_pool(store, members: int, nbags: int,
+                           pooling: str) -> tuple:
+    """Dispatch both bag programs of a tiered store at one pair of
+    buckets (`LookupBatcher.precompile_bags`): the plain one a batch of
+    hot members runs and the cold twin a batch naming a cold member
+    runs; every member out of bounds and of no bag, so both read fill
+    and pool nothing. The cold twin reads the ring's buffers of this
+    bucket, and one more set is made beside them: two batches in flight
+    find theirs touched, and no window pays a first touch. Caller holds
+    the server lock; returns the two results for it to wait on."""
+    b = np.zeros(members, dtype=np.int32)
+    oob = np.full(members, OOB, dtype=np.int32)
+    no = np.zeros(members, dtype=bool)
+    out = np.zeros((nbags, store.value_length),
+                   dtype=np.dtype(store.dtype))
+    plain = store.port.gather_pool(store.main, store.cache, store.delta,
+                                   b, oob, b, oob, no, oob, out,
+                                   pooling=pooling)
+    stages, spare = _stages(store, members), _stages(store, members)
+    for st in spare:
+        st.user = None
+    cold = _pool_cold(store, (b, oob, b, oob, no), stages, no, oob, out,
+                      pooling)
+    return plain, cold
 
 
 def scatter_add_tiered(store, o_shard, o_slot, d_shard, d_slot, vals):
@@ -270,6 +400,13 @@ def sync_replicas_tiered(store, r_shard, r_cslot, o_shard, o_slot,
              store._ef_resid_dev) = out
         else:
             store.main, store.cache, store.delta = out
+        if threshold > 0.0:
+            # the device decided which deltas merged, and the store
+            # leaves the write epochs alone (core/store.py): none of
+            # these owner rows may count as unwritten since its
+            # promotion (tier/promote.py demotes a clean row unread)
+            v = hot & valid
+            store.res.promo_epoch[o_sh[v], g_row[v]] = -1
     if not cold.any():
         return
     t0 = time.perf_counter()

@@ -11,6 +11,27 @@ same bits on either side — so residency changes can never change what a
 Pull/Push/serve lookup returns (the tentpole's bit-identity contract,
 pinned by tests/test_tier.py's storm).
 
+What a move costs (PR 46; PR 45 was its refused first attempt). A
+CLEAN victim is demoted with no readback:
+a promotion leaves the row's cold copy where it was and records the
+slot's write epoch (`ShardedStore.main_epoch`, bumped by every program
+that can change a main row's value) beside the hot row; a victim whose
+epoch has not moved since still equals its cold copy bit for bit, so
+its device row is simply dropped. Only a row WRITTEN while hot is read
+back (float32 rows in a float32 cold store only: a quantized cold
+store parks a remainder at every landing and a narrower pool rounds at
+promotion, so their victims are read back as before). A served table
+is read-only but for acknowledged pushes, so its demotions wait for no
+program. Victim selection examines a WINDOW of the hot pool from the
+shard's clock hand (`_VICTIM_FANOUT` rows a victim asked for, at least
+`_VICTIM_WINDOW_MIN`), not the pool: a maintenance pass costs what it
+moves, whatever the pool holds (20 M rows in the serving tier cell),
+and nothing pool-wide runs under the server lock.
+`tier.victim_rows_examined`, `tier.clean_demotions` and the
+`adapm.tier.*` spans say what a pass did. The READ side's host cost, the
+staged operand of a bag read, is `coldpath.StageRing`'s: kept buffers a
+bucket shape, each held until the program that reads it has finished.
+
 Discipline: mutations run under the server lock and bump the store's
 residency epoch (see residency.py). The maintenance worker computes its
 victim plans OUTSIDE the lock against an epoch snapshot and revalidates
@@ -19,6 +40,7 @@ dispatched (the topology_version discipline applied to residency).
 """
 from __future__ import annotations
 
+import contextlib
 from functools import partial
 
 import numpy as np
@@ -81,15 +103,23 @@ def promote_rows(store, shard: int, slots: np.ndarray) -> int:
                                                     f[1], fv)
     res.dev_row[shard, take] = rows
     res.row_slot[shard, rows] = take
+    # the cold copy stays where it is: while the slot's write epoch
+    # stands, the hot row equals it and demotes without a readback
+    res.promo_epoch[shard, rows] = store.main_epoch[shard, take]
     res.epoch += 1
     return len(take)
 
 
-def demote_rows(store, shard: int, slots: np.ndarray) -> int:
+def demote_rows(store, shard: int, slots: np.ndarray,
+                wait=contextlib.nullcontext) -> int:
     """Demote hot `slots` of `shard` back to the cold store (caller
-    holds the server lock). The readback synchronizes with every
-    enqueued program on the pool (dispatch order), so the landed bits
-    are the row's current authoritative value. Returns rows demoted."""
+    holds the server lock). A victim whose write epoch has not moved
+    since its promotion still equals its cold copy: its device row is
+    dropped and nothing is read. A victim written while hot is read
+    back (inside the `wait` bracket): the readback synchronizes with
+    every enqueued program on the pool (dispatch order), so the landed
+    bits are the row's current authoritative value. Returns rows
+    demoted."""
     res = store.res
     slots = np.unique(np.asarray(slots, dtype=np.int64))
     rows = res.dev_row[shard, slots]
@@ -97,12 +127,25 @@ def demote_rows(store, shard: int, slots: np.ndarray) -> int:
     slots, rows = slots[m], rows[m]
     if len(slots) == 0:
         return 0
-    vals = store.read_hot_rows_at(
-        np.full(len(rows), shard, dtype=np.int32), rows.astype(np.int32))
-    # land the readback in the cold tier's at-rest format; quantized
-    # modes park the sub-grid remainder as the demote's EF residual
-    # (folded back in at the next promote — docs/MEMORY.md contract)
-    store.coldq.set_at(np.full(len(slots), shard), slots, vals)
+    dirty = np.ones(len(slots), dtype=bool)
+    if store.coldq.mode == "fp32" and \
+            np.dtype(store.dtype) == np.float32:
+        # the hot row and its cold copy are the same float32 bits (a
+        # narrower pool rounds at promotion, a quantized cold store at
+        # every landing: both are read back)
+        dirty = store.main_epoch[shard, slots] != \
+            res.promo_epoch[shard, rows]
+    if dirty.any():
+        d_slots, d_rows = slots[dirty], rows[dirty]
+        with wait():
+            vals = store.read_hot_rows_at(
+                np.full(len(d_rows), shard, dtype=np.int32),
+                d_rows.astype(np.int32))
+        # land the readback in the cold tier's at-rest format; quantized
+        # modes park the sub-grid remainder as the demote's EF residual
+        # (folded back in at the next promote — docs/MEMORY.md contract)
+        store.coldq.set_at(np.full(len(d_slots), shard), d_slots, vals)
+    res.clean_demotions += int(len(slots) - dirty.sum())
     res.dev_row[shard, slots] = -1
     res.row_slot[shard, rows] = -1
     res.alloc.free_batch(shard, rows)
@@ -148,40 +191,94 @@ def _count_demotions(server, n: int) -> None:
         server.tier.c_demotions.inc(n)
 
 
+# Victim selection examines this many hot rows for each victim asked
+# for, from the shard's clock hand on, and never fewer than the floor (a
+# pool no larger than the floor is examined whole, as every pool was
+# before PR 46). The window grows only while it has not found `need`
+# evictable rows (a pool full of pinned rows is still examined whole).
+_VICTIM_FANOUT = 8
+_VICTIM_WINDOW_MIN = 1024
+
+
 def _pick_victims(store, shard: int, need: int, min_clock: int,
                   protect: np.ndarray,
                   force: bool = False) -> np.ndarray:
-    """Lowest-score, unpinned hot slots of `shard` (up to `need`), never
-    from `protect` (the batch being made hot right now). `force=True`
-    falls back to PINNED rows (still never `protect`) when unpinned
-    victims alone cannot cover `need` — the fused-step path, where the
-    current batch being hot is a correctness requirement and an older
-    pin is only a performance hint."""
+    """Low-score, unpinned hot slots of `shard` (up to `need`), never
+    from `protect` (the batch being made hot right now): the lowest
+    scores of a WINDOW of the hot pool that starts at the shard's clock
+    hand and holds `_VICTIM_FANOUT` rows a victim asked for, so the
+    work follows `need` and not the pool (every row examined is counted
+    in `res.victim_rows_examined`). `force=True` falls back to PINNED
+    rows (still never `protect`) when unpinned victims alone cannot
+    cover `need` — the fused-step path, where the current batch being
+    hot is a correctness requirement and an older pin is only a
+    performance hint."""
     res = store.res
-    rows = np.nonzero(res.row_slot[shard] >= 0)[0]
-    if len(rows) == 0:
+    if need <= 0:
         return np.empty(0, dtype=np.int64)
-    slots = res.row_slot[shard, rows].astype(np.int64)
-    if len(protect):
-        slots = slots[~np.isin(slots, protect)]
-    unpinned = slots[~res.pinned_mask(shard, slots, min_clock)]
-    cand = unpinned
+    H = res.hot_rows
+    hand = int(res.hand[shard])
+    span = min(H, max(_VICTIM_WINDOW_MIN, _VICTIM_FANOUT * need))
+    unpinned = pinned = np.empty(0, dtype=np.int64)
+    seen = 0
+    while seen < H:
+        w = min(span, H - seen)
+        rows = (hand + seen + np.arange(w)) % H
+        seen += w
+        slots = res.row_slot[shard, rows].astype(np.int64)
+        slots = slots[slots >= 0]
+        if len(protect) and len(slots):
+            slots = slots[~np.isin(slots, protect)]
+        pin = res.pinned_mask(shard, slots, min_clock)
+        unpinned = np.concatenate([unpinned, slots[~pin]])
+        if force:
+            pinned = np.concatenate([pinned, slots[pin]])
+        if len(unpinned) + len(pinned) >= need:
+            break
+    res.hand[shard] = (hand + seen) % H
+    res.victim_rows_examined += seen
     if force and len(unpinned) < need:
-        pinned = slots[res.pinned_mask(shard, slots, min_clock)]
-        cand = np.concatenate([unpinned, pinned])
         # prefer unpinned victims; overflow into pinned by score
-        if len(cand) > need:
-            extra = need - len(unpinned)
+        extra = need - len(unpinned)
+        if extra < len(pinned):
             sc = res.score[shard, pinned]
-            pick = pinned[np.argpartition(sc, extra - 1)[:extra]] \
-                if extra < len(pinned) else pinned
-            return np.concatenate([unpinned, pick])
-        return cand
-    if len(cand) <= need:
-        return cand
-    sc = res.score[shard, cand]
-    idx = np.argpartition(sc, need - 1)[:need]
-    return cand[idx]
+            pinned = pinned[np.argpartition(sc, extra - 1)[:extra]]
+        return np.concatenate([unpinned, pinned])
+    if len(unpinned) <= need:
+        return unpinned
+    sc = res.score[shard, unpinned]
+    return unpinned[np.argpartition(sc, need - 1)[:need]]
+
+
+def _span(server, name: str, wait: bool = False):
+    """The bracket `adapm.tier.<name>` with its `tier.<name>_s`
+    histogram (obs/spans.py; TierManager registers the histograms)."""
+    t = server.tier
+    return server._span("tier." + name,
+                        t.hists[name] if t is not None else None,
+                        wait=wait)
+
+
+def _victims(server, store, shard, need, min_clock, protect,
+             force=False) -> np.ndarray:
+    with _span(server, "pick_victims"):
+        return _pick_victims(store, shard, need, min_clock, protect,
+                             force=force)
+
+
+def _demote(server, store, shard: int, slots: np.ndarray) -> int:
+    """`demote_rows` in its bracket, the readback of the written rows a
+    wait bracket inside it, the rows counted (`tier.demotions`)."""
+    with _span(server, "demote"):
+        n = demote_rows(store, shard, slots, wait=partial(
+            _span, server, "demote_readback", wait=True))
+    _count_demotions(server, n)
+    return n
+
+
+def _promote(server, store, shard: int, slots: np.ndarray) -> int:
+    with _span(server, "promote"):
+        return promote_rows(store, shard, slots)
 
 
 def ensure_hot_rows(server, store, shards: np.ndarray, slots: np.ndarray,
@@ -204,12 +301,11 @@ def ensure_hot_rows(server, store, shards: np.ndarray, slots: np.ndarray,
         if force:
             short = len(cold) - res.alloc.num_free(s)
             if short > 0:
-                victims = _pick_victims(store, s, short, min_clock, sl,
-                                        force=True)
+                victims = _victims(server, store, s, short, min_clock,
+                                   sl, force=True)
                 if len(victims):
-                    _count_demotions(server,
-                                     demote_rows(store, s, victims))
-            got = promote_rows(store, s, cold)
+                    _demote(server, store, s, victims)
+            got = _promote(server, store, s, cold)
             if got < len(cold):
                 raise RuntimeError(
                     f"tier hot pool exhausted on shard {s}: a fused "
@@ -231,12 +327,12 @@ def ensure_hot_rows(server, store, shards: np.ndarray, slots: np.ndarray,
         if len(pc):
             short = len(pc) - res.alloc.num_free(s)
             if short > 0:
-                victims = _pick_victims(store, s, short, min_clock, sl)
+                victims = _victims(server, store, s, short, min_clock,
+                                   sl)
                 n_victims += len(victims)
                 if len(victims):
-                    _count_demotions(server,
-                                     demote_rows(store, s, victims))
-            n += promote_rows(store, s, pc)
+                    _demote(server, store, s, victims)
+            n += _promote(server, store, s, pc)
         if len(uc):
             pol = server.policy
             if pol is not None and pol.active("tier"):
@@ -257,7 +353,7 @@ def ensure_hot_rows(server, store, shards: np.ndarray, slots: np.ndarray,
             over = len(uc) - res.alloc.num_free(s)
             if over > 0:
                 uc = uc[np.argsort(-res.score[s, uc], kind="stable")]
-                victims = _pick_victims(store, s, over, min_clock, sl)
+                victims = _victims(server, store, s, over, min_clock, sl)
                 n_victims += len(victims)
                 if len(victims):
                     victims = victims[np.argsort(
@@ -267,12 +363,10 @@ def ensure_hot_rows(server, store, shards: np.ndarray, slots: np.ndarray,
                         res.score[s, uc[:k]]
                     n_beat = int(beat.sum())
                     if beat.any():
-                        _count_demotions(
-                            server,
-                            demote_rows(store, s, victims[:k][beat]))
+                        _demote(server, store, s, victims[:k][beat])
                 uc = uc[: res.alloc.num_free(s)]
             if len(uc):
-                n += promote_rows(store, s, uc)
+                n += _promote(server, store, s, uc)
         dc = server.decisions
         if dc is not None and (n_pinned or n_unpinned):
             # ISSUE 17: this shard's promotion batch with the
@@ -305,7 +399,12 @@ class PromotionEngine:
     Every mutating batch takes the server lock for revalidation +
     ENQUEUE only (dispatch never — the lock-narrowing rule,
     docs/EXECUTOR.md); candidate scans run outside it and revalidate
-    via the residency epoch. `run_once()` exposes one synchronous pass
+    via the residency epoch. What a pass costs follows what it moves
+    (module docstring): a victim scan examines `_VICTIM_FANOUT` rows a
+    victim from the clock hand, a clean victim is dropped without a
+    readback, and the one pool-wide sweep, the score decay, runs every
+    `_DECAY_EVERY` passes OUTSIDE the lock. `run_once()` exposes one
+    synchronous pass
     for deterministic tests/tooling. A pass that moved rows reschedules
     itself; an idle pass parks (no queued task — the executor worker
     parks on its condvar, pinned by scripts/exec_overlap_check.py)."""
@@ -358,9 +457,27 @@ class PromotionEngine:
                                     delay=delay)
 
     def run_once(self) -> int:
-        """One maintenance pass (see class doc). Safe to call from any
+        """One maintenance pass (see class doc) in its bracket
+        (`adapm.tier.pass`, `tier.pass_s`). Safe to call from any
         thread; takes the server lock internally per batch. Returns the
         number of rows moved (0 = the pass was a no-op)."""
+        with _span(self.server, "pass"):
+            return self._run_once()
+
+    @contextlib.contextmanager
+    def _locked(self):
+        """The server lock for one batch of moves, its two halves
+        apart: the wait for it (`adapm.tier.lock_wait`, a wait bracket:
+        the serve dispatcher holds the same lock for every batch) and
+        the hold (`adapm.tier.commit`, `tier.commit_s`: what every
+        lookup waits behind)."""
+        srv = self.server
+        with srv._locked("tier.lock_wait",
+                         self.manager.hists["lock_wait"], wait=True):
+            with _span(srv, "commit"):
+                yield
+
+    def _run_once(self) -> int:
         srv = self.server
         mgr = self.manager
         moved = 0
@@ -427,20 +544,21 @@ class PromotionEngine:
                     continue
                 # plan outside the lock; revalidate epoch under it
                 epoch = res.epoch
-                victims = _pick_victims(st, s, target - free, min_clock,
-                                        np.empty(0, dtype=np.int64))
+                none = np.empty(0, dtype=np.int64)
+                victims = _victims(srv, st, s, target - free, min_clock,
+                                   none)
                 if len(victims) == 0:
                     continue
-                with srv._lock:
+                with self._locked():
                     if res.epoch != epoch:
                         # residency moved underneath the scan: replan
-                        victims = _pick_victims(
-                            st, s, target - res.alloc.num_free(s),
-                            min_clock, np.empty(0, dtype=np.int64))
-                    n = demote_rows(st, s, victims) if len(victims) else 0
+                        victims = _victims(
+                            srv, st, s, target - res.alloc.num_free(s),
+                            min_clock, none)
+                    n = _demote(srv, st, s, victims) \
+                        if len(victims) else 0
                 if n:
                     moved += n
-                    mgr.c_demotions.inc(n)
                     dc = srv.decisions
                     if dc is not None:
                         # ISSUE 17: headroom-reclaim demotion (outcome
@@ -467,7 +585,7 @@ class PromotionEngine:
             # when inline) re-runs cleanly; the wanted rows stay cold
             # until a commit succeeds — slower, never wrong
             srv.fault.fire("tier.promote")
-        with srv._lock:
+        with self._locked():
             n = ensure_hot_rows(srv, st, sh, sl, min_clock=min_clock)
         if n:
             self.manager.c_promotions.inc(n)

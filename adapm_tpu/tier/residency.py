@@ -15,6 +15,12 @@ cache/delta rows stay fully device-resident: only MAIN rows tier.
                             halved — a decayed-counter CLOCK variant)
     pin_until[S, main_slots] intent-liveness pin: rows pinned hot while
                             any Intent window covering them is active
+    promo_epoch[S, hot_rows] the slot's write epoch (`ShardedStore
+                            .main_epoch`) when the row was promoted: a
+                            victim whose epoch still stands equals its
+                            cold copy and demotes with no readback
+    hand[S]                 the clock hand victim selection starts its
+                            window of the hot pool from (tier/promote.py)
 
 The replacement signal FUSES frequency with the explicit `Intent`
 windows the PM already collects (the paper's lookahead advantage over
@@ -61,6 +67,13 @@ class Residency:
         self.score = np.zeros((num_shards, main_slots), dtype=np.int64)
         self.pin_until = np.full((num_shards, main_slots), NO_PIN,
                                  dtype=np.int64)
+        self.promo_epoch = np.zeros((num_shards, hot_rows), dtype=np.int64)
+        self.hand = np.zeros(num_shards, dtype=np.int64)
+        # what victim selection and demotion did (tier/promote.py;
+        # read by the tier.* gauges): hot rows examined, victims
+        # dropped without a readback
+        self.victim_rows_examined = 0
+        self.clean_demotions = 0
         # bumped on every promote/demote/release batch (under the server
         # lock); consumers revalidate like topology_version
         self.epoch = 0
@@ -101,6 +114,7 @@ class Residency:
         self.alloc = SlotAllocator(self.num_shards, self.hot_rows)
         self.score.fill(0)
         self.pin_until.fill(NO_PIN)
+        self.hand.fill(0)
         self.want.clear()
         self.epoch += 1
 
@@ -142,6 +156,20 @@ class TierManager:
         self.c_demotions = reg.counter("tier.demotions")
         self.c_serve_cold = reg.counter("tier.serve_cold_keys")
         self.h_cold_serve = reg.histogram("tier.cold_serve_s")
+        # the maintenance worker's brackets (tier/promote.py `_span`,
+        # `PromotionEngine._locked`) and the bag read's cold staging
+        # (tier/coldpath.py), each a span `adapm.tier.<name>` too
+        self.hists = {
+            "pass": reg.histogram("tier.pass_s"),
+            "lock_wait": reg.histogram("tier.lock_wait_s"),
+            "commit": reg.histogram("tier.commit_s"),
+            "pick_victims": reg.histogram("tier.pick_victims_s"),
+            "promote": reg.histogram("tier.promote_s"),
+            "demote": reg.histogram("tier.demote_s"),
+            "demote_readback": reg.histogram("tier.demote_readback_s"),
+        }
+        self.h_cold_stage = reg.histogram("tier.cold_stage_s")
+        self.c_cold_stage_bytes = reg.counter("tier.cold_stage_bytes")
         if reg.enabled:
             reg.gauge("tier.epoch", fn=lambda: self.epoch)
             reg.gauge("tier.hot_hits",
@@ -151,6 +179,12 @@ class TierManager:
                       fn=lambda: sum(st.tier_cold_hits
                                      for st in server.stores))
             reg.gauge("tier.hot_hit_rate", fn=self.hot_hit_rate)
+            reg.gauge("tier.victim_rows_examined",
+                      fn=lambda: sum(st.res.victim_rows_examined
+                                     for st in server.stores))
+            reg.gauge("tier.clean_demotions",
+                      fn=lambda: sum(st.res.clean_demotions
+                                     for st in server.stores))
             reg.gauge("tier.hot_rows_used",
                       fn=lambda: sum(st.res.hot_count(s)
                                      for st in server.stores
@@ -176,6 +210,9 @@ class TierManager:
         # hook lets the miss path kick the maintenance worker
         for st in server.stores:
             st.tier_hist = self.h_cold_serve
+            st.tier_stage = lambda: server._span(
+                "serve.cold_stage", self.h_cold_stage)
+            st.tier_stage_bytes = self.c_cold_stage_bytes
             # late-bound on purpose: tests that must not run the worker
             # thread replace engine.kick on the instance
             st.res.kick = lambda e=self.engine: e.kick()
@@ -236,11 +273,14 @@ class TierManager:
         self.engine.kick()
 
     def note_serve(self, keys: np.ndarray) -> None:
-        """Serving-plane feedback (serve/batcher.py consults residency
-        before planning): bump scores for the looked-up keys and queue
-        promotion of the cold ones, so the hot set adapts to serve load
-        as well as training intent. Advisory — runs without the server
-        lock; the worker revalidates coordinates."""
+        """Serving-plane feedback for keys the STORE never saw: a batch
+        answered from the replica snapshot (serve/batcher.py) reaches
+        no gather, so its keys are scored and its cold ones queued for
+        promotion here; a batch that goes through `gather` /
+        `gather_pool` is scored and queued there (tier/coldpath.py
+        `_note_access`), once. Either way the hot set adapts to serve
+        load as well as training intent. Advisory — runs without the
+        server lock; the worker revalidates coordinates."""
         srv = self.server
         ab = srv.ab
         keys = np.asarray(keys, dtype=np.int64).ravel()
@@ -432,6 +472,40 @@ class TierManager:
             return eff
 
     # -- lifecycle -----------------------------------------------------------
+
+    def precompile(self) -> int:
+        """Run the maintenance worker's two programs, the promotion
+        upload and the demotion readback, once at every bucket a pass
+        can dispatch them with (a commit chunk is at most 4 x
+        `--sys.tier.demote_batch` rows), so that none compiles later,
+        under traffic (`Server.precompile` calls this). Every coordinate
+        is out of bounds, as a padded tail's is: the upload writes
+        nothing and the readback reads fill. Returns how many programs
+        ran."""
+        from ..core.store import OOB, bucket_ladder
+        srv = self.server
+        top = 4 * max(1, self.opts.tier_demote_batch)
+        ran = 0
+        for st in srv.stores:
+            mode = st.coldq.mode
+            for b in bucket_ladder(top, st.bucket_min):
+                sh, oob = np.zeros(b, np.int32), np.full(b, OOB, np.int32)
+                with srv._lock:
+                    # full-width rows: an fp32 cold store's upload, a
+                    # quantized one's residual fix-ups
+                    st.main = st.port.write_main_rows(
+                        st.main, sh, oob, st._vals_bucket(
+                            np.empty((0, st.value_length)), b))
+                    if mode != "fp32":
+                        st.main = st.port.write_main_rows_wire(
+                            mode, st.main, sh, oob,
+                            np.zeros((b, st.value_length),
+                                     dtype=st.coldq.q.dtype),
+                            np.zeros(b, np.float32)
+                            if mode == "int8" else None)
+                    st.read_hot_rows_at(sh, oob)
+                ran += 2 + (mode != "fp32")
+        return ran
 
     def maintain(self) -> None:
         """One synchronous maintenance pass (drain promotion wants,

@@ -171,14 +171,19 @@ def test_a_dropped_member_and_a_shifted_offset_are_seen():
         serve.close()
 
 
-def test_the_shares_of_a_row_wise_sharded_table_add_up():
-    """The deployment's cut: 8 shards hold ceil(rows / 8) rows of every
-    table each; a bag's members on shard k pooled there, the 8 partial
-    sums added, give what the reference gives over the whole table,
-    within the bound a float32 sum in another order keeps (exactly for
-    bags of one member)."""
+@pytest.mark.parametrize("shards, tier", [
+    (8, []),        # dlrm-dcnv2-criteo1tb-serve: a v5e-8 host, all in HBM
+    # dlrm-dcnv2-criteo1tb-serve-tier: a v5e-4 host, 40% of a share hot
+    (4, ["--sys.tier", "1", "--sys.tier.hot_rows", "16"])])
+def test_the_shares_of_a_row_wise_sharded_table_add_up(shards, tier):
+    """The deployment's cut: `shards` shards hold ceil(rows / shards)
+    rows of every table each (tiered: the most of them in the host cold
+    store); a bag's members on shard k pooled there, the partial sums
+    added, give what the reference gives over the whole table, within
+    the bound a float32 sum in another order keeps (exactly for bags of
+    one member)."""
     whole = [50, 17, 3, 80, 8]
-    share = [-(-r // 8) for r in whole]
+    share = [-(-r // shards) for r in whole]
     rng = np.random.default_rng(3)
     S = 6
     ids = [rng.integers(0, r, (S, h)) for r, h in zip(whole, HOT)]
@@ -188,12 +193,13 @@ def test_the_shares_of_a_row_wise_sharded_table_add_up():
     ref = bags_np.reply([i.ravel() + first[t] for t, i in enumerate(ids)],
                         bags, DIM, SCALE, SEED)
     total = [np.zeros((S, DIM), np.float32) for _ in HOT]
-    for k in range(8):
+    for k in range(shards):
         serve = ctr.CtrServe(ctr.build_parser().parse_args(
             ["--table_rows", ",".join(map(str, share)),
              "--multi_hot_sizes", ",".join(map(str, HOT)),
              "--embedding_dim", str(DIM), "--serve_samples", "1,8",
-             "--num_shards", "1", "--sys.serve.max_batch", "1"] + FAST))
+             "--num_shards", "1", "--sys.serve.max_batch", "1"] + FAST
+            + tier))
         try:
             # shard k's row j of table t is the whole table's row
             # k * share + j (rows past the table's end hold nothing)

@@ -474,6 +474,59 @@ def test_bags_fullest_gather_pool_fits_beside_the_table(
     assert (live < 14.25 * 2**30) if fits else (live > 15.5 * 2**30)
 
 
+# dlrm-dcnv2-criteo1tb-serve-tier: the hot pool at each candidate cache
+# share of the 51,046,153-key share (the sum of ceil(share x rows) a
+# table, as the store rounds it), and the verdict of the sizing rule the
+# configuration's file states
+TIER_POOLS = [(0.5, 25_523_080, False), (0.4, 20_418_472, True),
+              (0.3, 15_313_856, True)]
+
+
+@pytest.mark.parametrize("share, hot_rows, fits", TIER_POOLS)
+def test_tier_fullest_cold_bag_program_beside_the_hot_pool(
+        share, hot_rows, fits, shape, capsys):
+    """The tiered serving cell's sizing rule: the cold twin of the bag
+    program (`jaxport._gather_pool_cold`: `_gather_pool` with the staged
+    cold rows as one more row-wide operand) at the fullest buckets
+    `serve.max_batch` 4 allows (the `-k bags` cases' second batch),
+    beside the hot pool at each candidate share. The line is the one
+    those cases draw: under 14.25 GiB live fits (14.01 loaded and ran,
+    15.60 was refused by the chip: PR 37), counted with ONE MORE staged
+    operand: the dispatcher uploads the next batch's while this
+    program is in flight. The select of the staged rows fuses into the
+    first gather, so the temporaries are the untiered program's (the
+    member rows three times); the staged operand is 0.54 GB beside the
+    pool. 0.5 is 14.27 GiB alone and 14.77 with the next operand; 0.4
+    (11.83 and 12.33) is the largest share under the line."""
+    from adapm_tpu.device import jaxport
+    requests, members, bags, _ = BAGS_BATCHES[1]
+    assert requests == 4
+    i32 = lambda: shape((members,), jnp.int32)  # noqa: E731
+    flag = lambda: shape((members,), jnp.bool_)  # noqa: E731
+    row = lambda n: shape((1, n, BAGS_DIM), jnp.float32)  # noqa: E731
+    compiled = jaxport._gather_pool_cold.lower(
+        row(hot_rows), row(8), row(8), i32(), i32(), i32(), i32(), flag(),
+        shape((members, BAGS_DIM), jnp.float32), flag(), i32(),
+        nbags=bags, pooling="sum").compile()
+    mem = compiled.memory_analysis()
+    live = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    with capsys.disabled():
+        print(f"\ndlrm-dcnv2-criteo1tb-serve-tier v5e compile, cache share "
+              f"{share} ({hot_rows} hot rows, "
+              f"{hot_rows * BAGS_DIM * 4 / 1e9:.2f} GB): temporaries "
+              f"{mem.temp_size_in_bytes / 1e9:.3f} GB, operands beside "
+              f"the pool "
+              f"{(mem.argument_size_in_bytes - hot_rows * BAGS_DIM * 4) / 1e9:.3f}"
+              f" GB, live {live / 2**30:.2f} GiB of 15.75 "
+              f"({(live + members * BAGS_DIM * 4) / 2**30:.2f} with the "
+              f"next batch's staged operand)")
+    gathered = members * BAGS_DIM * 4
+    assert 3 * gathered <= mem.temp_size_in_bytes < \
+        3 * gathered + (256 << 20)
+    assert (live + gathered < 14.25 * 2**30) == fits
+
+
 @pytest.mark.parametrize("no_replicas", [True, False])
 def test_four_shard_step_is_a_per_chip_program(no_replicas, topo,
                                                kernel_cache, monkeypatch,

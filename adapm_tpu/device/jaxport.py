@@ -272,11 +272,12 @@ def _relocate(main, delta, old_shard, old_slot, new_shard, new_slot,
 
 @jax.jit
 @_scoped("adapm_route_patch")
-def _patch_routes(owner, slot, cache_row, patch):
-    """The fused step's three routing tables (ops/fused.py DeviceRouter)
-    with the entries of `patch` set: int32 [4, n], its rows the keys
-    and their owner, slot and cache-row values; a padding key is out of
-    bounds and dropped. THE CALLER PROMISES that the keys ascend and
+def _patch_routes(place, cache_row, patch):
+    """The fused step's two routing tables (ops/fused.py DeviceRouter:
+    a key's place word and the worker shard's cache row) with the
+    entries of `patch` set: int32 [3, n], its rows the keys and their
+    place and cache-row values; a padding key is out of bounds and
+    dropped. THE CALLER PROMISES that the keys ascend and
     none repeats, padding included, and the scatter is told so: on a
     v5e it then compiles in 0.3 s a width where it took 8 (25.5 M keys)
     and runs 22% faster an entry, to the same tables (PERF.md section
@@ -289,7 +290,7 @@ def _patch_routes(owner, slot, cache_row, patch):
     keys = patch[0]
     return tuple(t.at[keys].set(v, mode="drop", indices_are_sorted=True,
                                 unique_indices=True)
-                 for t, v in zip((owner, slot, cache_row), patch[1:]))
+                 for t, v in zip((place, cache_row), patch[1:]))
 
 
 # ---------------------------------------------------------------------------
@@ -569,10 +570,10 @@ class JaxDevicePort(DevicePort):
             return _relocate(main, delta, old_shard, old_slot,
                              new_shard, new_slot, rc_shard, rc_slot)
 
-    def patch_routes(self, owner, slot, cache_row, patch):
+    def patch_routes(self, place, cache_row, patch):
         self.programs += 1
         with _GATE:
-            return _patch_routes(owner, slot, cache_row, patch)
+            return _patch_routes(place, cache_row, patch)
 
     # -- tiered cold path + wire ingest --------------------------------------
 

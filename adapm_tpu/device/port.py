@@ -108,15 +108,16 @@ class DevicePort:
         """Donates (main, delta); returns (main, delta)."""
         raise NotImplementedError
 
-    def patch_routes(self, owner, slot, cache_row, patch):
-        """The fused step's routing tables (ops/fused.py DeviceRouter)
-        with the entries named by `patch` set: int32 [4, n], its rows
-        the keys and their owner, slot and cache-row values; a key out
-        of bounds is padding. The keys ascend and none repeats, padding
-        included: the caller's promise (`DeviceRouter._patch_operand`
-        keeps it), which a port may hand to its scatter. Donates
-        nothing (a step in flight keeps the tables it was dispatched
-        with); returns the three tables."""
+    def patch_routes(self, place, cache_row, patch):
+        """The fused step's routing tables (ops/fused.py DeviceRouter:
+        a key's place word and the worker shard's cache row) with the
+        entries named by `patch` set: int32 [3, n], its rows the keys
+        and their place and cache-row values; a key out of bounds is
+        padding. The keys ascend and none repeats, padding included:
+        the caller's promise (`DeviceRouter._patch_operand` keeps it),
+        which a port may hand to its scatter. Donates nothing (a step
+        in flight keeps the tables it was dispatched with); returns the
+        two tables."""
         raise NotImplementedError
 
     # -- tiered cold path + wire-row ingest (tier/, ops/dequant twins) -------

@@ -16,6 +16,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from ..ops.fused import decode_place
+
 
 def complex_score(s: jnp.ndarray, r: jnp.ndarray,
                   o: jnp.ndarray) -> jnp.ndarray:
@@ -172,6 +174,15 @@ def make_true_score(model: str):
     return fn
 
 
+def _pool_rows(pool, tables, keys, dim: int):
+    """The first `dim` columns of the main-pool rows of `keys`, routed
+    as the fused step routes them: ONE look-up of the key's place word
+    in the router's mirror (`tables`: `DeviceRouter.tables()`), split
+    into shard and slot for this pool (`ops/fused.py decode_place`)."""
+    sh, sl = decode_place(tables[0][keys], pool.shape[1])
+    return pool[sh, sl, :dim]
+
+
 def make_pool_eval_counts_mp(model: str, ent_dim: int, rel_dim: int,
                              chunk: int):
     """Candidate-partitioned twin of make_pool_eval_counts (VERDICT r4
@@ -201,10 +212,8 @@ def make_pool_eval_counts_mp(model: str, ent_dim: int, rel_dim: int,
     @jax.jit
     def counts(ent_main, tables, ent_keys, nvalid, se, re_, oe, skeys,
                okeys, true_sc):
-        owner, slot, _ = tables
-
         def ent_rows(keys):
-            return ent_main[owner[keys], slot[keys], :ent_dim]
+            return _pool_rows(ent_main, tables, keys, ent_dim)
 
         C = ent_keys.shape[1]
 
@@ -266,15 +275,13 @@ def make_pool_eval_counts(model: str, ent_dim: int, rel_dim: int,
     @jax.jit
     def counts(ent_main, rel_main, tables, ent_keys, nE, skeys, rkeys,
                okeys):
-        owner, slot, _ = tables
-
         def ent_rows(keys):
-            return ent_main[owner[keys], slot[keys], :ent_dim]
+            return _pool_rows(ent_main, tables, keys, ent_dim)
 
         se = ent_rows(skeys)
         oe = ent_rows(okeys)
-        rpool = ent_main if shared_pool else rel_main
-        re_ = rpool[owner[rkeys], slot[rkeys], :rel_dim]
+        re_ = _pool_rows(ent_main if shared_pool else rel_main, tables,
+                         rkeys, rel_dim)
         true_sc = score(se, re_, oe)  # same triple -> same score each side
 
         C = ent_keys.shape[1]

@@ -18,8 +18,10 @@ state inside the value (`param_len = 2*rank = [factor | adagrad]`,
 matrix_factorization.cc:695-697): row = [emb (D) | adagrad acc (D)].
 
 Routing (which shard/slot serves each key) is resolved IN the program
-(DeviceRouter): the Addressbook tables (owner, slot, the worker shard's
-cache-slot row) are mirrored into HBM, brought up to date lazily when the
+(DeviceRouter): the Addressbook tables are mirrored into HBM as two
+words a key (the main copy's PLACE, owner and slot in one int32:
+`place_words`, `decode_place`; the worker shard's cache-slot row),
+brought up to date lazily when the
 planner changes placement (topology_version; patched by the keys the
 addressbook's journal lists as changed, rebuilt from the tables where
 it cannot say), and the jitted step resolves routes
@@ -364,12 +366,48 @@ def _changed_keys(server, cursor) -> Optional[np.ndarray]:
     return None if keys is None else np.unique(keys)
 
 
+def place_bits(n_slots: int) -> int:
+    """The low bits of a place word that hold the slot, for a pool of
+    `n_slots` slots a shard."""
+    return max(int(n_slots) - 1, 0).bit_length()
+
+
+def place_words(owner, slot, bits) -> np.ndarray:
+    """The device mirror's word for each (owner, slot) of the host's
+    tables, int32: `owner << bits | slot` for a live pair (`bits`:
+    `place_bits` of the key's pool, a number or one a key), and `OOB`
+    for every other: a key that another process owns (`REMOTE`,
+    `NO_SLOT`), a tiered store's cold row (`compose_slot_table` hands
+    `OOB` for its slot), padding. A new array, never a view."""
+    owner, slot = np.asarray(owner), np.asarray(slot)
+    live = (owner >= 0) & (slot >= 0) & (slot >> bits == 0)
+    return np.where(live, (owner << bits) | slot, OOB).astype(
+        np.int32, copy=False)
+
+
+def decode_place(word, n_slots: int):
+    """(shard, slot) of place words (`place_words`) for a main pool of
+    `n_slots` slots a shard, static in the program: a shift, a mask and
+    a select, which fuse into whatever reads them. A live pair decodes
+    to itself. `OOB` decodes OUT OF BOUNDS in both: its slot is `OOB`
+    and its shard past the last (`DeviceRouter` asserts that every live
+    word lies under it), so the position reads a zero embedding, its
+    write-back is dropped and no shard counts it local."""
+    bits = place_bits(n_slots)
+    return word >> bits, jnp.where(word == OOB, OOB,
+                                   word & ((1 << bits) - 1))
+
+
 class DeviceRouter:
-    """Device mirrors of the Addressbook tables for one worker shard,
-    brought up to date lazily on placement changes
-    (Server.topology_version): patched by the keys that changed since
-    (`_patch`), rebuilt from the tables where those are not known
-    (`_refresh`: set-up, a journal trimmed or reset, a tiered store)."""
+    """Device mirrors of the Addressbook tables for one worker shard:
+    `place`, a key's main copy as ONE int32 word (`place_words`: owner
+    and slot together, so a program finds both with one look-up), and
+    `cache_row`, this shard's replica slots. Brought up to date lazily
+    on placement changes (Server.topology_version): patched by the keys
+    that changed since (`_patch`), rebuilt from the tables where those
+    are not known (`_refresh`: set-up, a journal trimmed or reset, a
+    tiered store). The host's addressbook keeps `owner` and `slot`
+    apart; only the mirror packs them."""
 
     def __init__(self, server, shard: int):
         self.server = server
@@ -377,9 +415,15 @@ class DeviceRouter:
         self._version = None   # (topology_version, residency epoch)
         self._cursor = None    # the journal position the mirrors are at
                                # (None: not built yet)
-        self.owner = None      # [num_keys] int32
-        self.slot = None       # [num_keys] int32
+        self.place = None      # [num_keys] int32 place words
         self.cache_row = None  # [num_keys] int32 (this shard's replica slots)
+        # the slot bits of each length class's word, from the pool a
+        # step indexes (a tiered store's is its hot pool). Every live
+        # word lies under `OOB`, whose own shard is then past the last
+        bits = [place_bits(st.main.shape[1]) for st in server.stores]
+        assert all(server.num_shards << b <= OOB for b in bits), \
+            "a pool too large for int32 place words"
+        self._bits = np.array(bits, np.int32)
         # what a placement change costs this worker on the host: every
         # re-upload of the mirrors (and of the runner's local sampling
         # index) is one observation; the bytes count every device a
@@ -410,6 +454,14 @@ class DeviceRouter:
         self._h_patch = server.obs.histogram("fused.route_patch_s",
                                              shared=True)
 
+    @property
+    def owner(self):
+        """The place mirror under the name of the table it replaced:
+        None exactly until the mirrors are built, which the
+        benchmark's planted fault reads
+        (benchmarks/tests/_broken_run_kv.py)."""
+        return self.place
+
     def _put_counted(self, arr):
         """`put_replicated` of one mirror, its bytes counted once for
         each device it lands on."""
@@ -422,7 +474,7 @@ class DeviceRouter:
         srv = self.server
         ver = (srv.topology_version,
                srv.tier.epoch if srv.tier is not None else -1)
-        if self._version == ver and self.owner is not None:
+        if self._version == ver and self.place is not None:
             return
         with srv._span("fused.route_refresh", self._h_refresh,
                        work=self._h_refresh_work):
@@ -445,22 +497,30 @@ class DeviceRouter:
         patch = self._put_counted(
             self._patch_operand(keys, patch_rungs(len(keys))[-1]))
         with srv._span("fused.route_patch", self._h_patch, wait=True):
-            self.owner, self.slot, self.cache_row = \
-                default_port().patch_routes(
-                    self.owner, self.slot, self.cache_row, patch)
+            self.place, self.cache_row = default_port().patch_routes(
+                self.place, self.cache_row, patch)
         self._c_patch_calls.inc()
         self._c_patch.inc()
         self._c_patch_keys.inc(len(keys))
 
+    def _words(self, slot: np.ndarray, keys=slice(None)) -> np.ndarray:
+        """The place words of `keys` (of every key unless given), whose
+        slots in the pool a step indexes are `slot`."""
+        ab = self.server.ab
+        bits = self._bits if len(self._bits) == 1 else \
+            self._bits[ab.key_class[keys]]
+        return place_words(ab.owner[keys], slot, bits)
+
     def _patch_operand(self, keys: np.ndarray, width: int) -> np.ndarray:
-        """The patch program's operand for `keys`, int32 [4, width]: the
-        keys and their three table values; the padding keys count up
-        from `num_keys`: past the tables, so dropped, and ascending and
-        distinct after the keys, as the program's scatter is promised."""
+        """The patch program's operand for `keys`, int32 [3, width]: the
+        keys, their place words and their cache rows; the padding keys
+        count up from `num_keys`: past the tables, so dropped, and
+        ascending and distinct after the keys, as the program's scatter
+        is promised."""
         ab = self.server.ab
         n = len(keys)
-        patch = np.empty((4, width), np.int32)
-        patch[:, :n] = (keys, ab.owner[keys], ab.slot[keys],
+        patch = np.empty((3, width), np.int32)
+        patch[:, :n] = (keys, self._words(ab.slot[keys], keys),
                         ab.cache_slot[self.shard, keys])
         patch[0, n:] = np.arange(ab.num_keys, ab.num_keys + width - n,
                                  dtype=np.int32)
@@ -470,40 +530,42 @@ class DeviceRouter:
     def _refresh(self):
         srv = self.server
         ab = srv.ab
-        # SNAPSHOTS of the addressbook's tables, taken here under the
-        # server lock: the planner changes those arrays in place, a
-        # device_put reads its host buffer until the transfer is done,
-        # and with rounds on the prefetch thread the next relocation can
-        # land before that. A step then routed by placement it was not
-        # ordered after, and read a row's old (or still empty) place.
-        # The copy is host work: it is made before `_put_counted`'s
-        # wait span opens
-        put = lambda arr: self._put_counted(np.array(arr))  # noqa: E731
-        self.owner = put(ab.owner)
-        # tiered storage: the step indexes the DEVICE hot pool, so the
-        # slot mirror carries hot-pool ROWS (composed against the
-        # residency map, cached per epoch at the TierManager and shared
-        # by all runners; OOB while cold — fill zeros / drop, never the
-        # negative-index WRAP — and runners pin their batches hot so
-        # the step never actually touches a cold row)
-        self.slot = put(ab.slot if srv.tier is None
-                        else srv.tier.compose_slot_table())
-        self.cache_row = put(ab.cache_slot[self.shard])
+        # what is uploaded is made HERE, under the server lock, and is
+        # nobody else's: the planner changes the addressbook's arrays in
+        # place, a device_put reads its host buffer until the transfer
+        # is done, and with rounds on the prefetch thread the next
+        # relocation can land before that. A step then routed by
+        # placement it was not ordered after, and read a row's old (or
+        # still empty) place. The words are a new array by construction;
+        # the cache row is copied. Both are host work, done before
+        # `_put_counted`'s wait span opens.
+        # Tiered storage: the step indexes the DEVICE hot pool, so the
+        # words carry hot-pool ROWS (composed against the residency
+        # map, cached per epoch at the TierManager and shared by all
+        # runners; `OOB` while cold, so the word is: fill zeros / drop,
+        # never the negative-index WRAP — and runners pin their batches
+        # hot so the step never actually touches a cold row)
+        self.place = self._put_counted(self._words(
+            ab.slot if srv.tier is None else srv.tier.compose_slot_table()))
+        self.cache_row = self._put_counted(
+            np.array(ab.cache_slot[self.shard]))
 
     def tables(self):
         self.refresh()
-        return self.owner, self.slot, self.cache_row
+        return self.place, self.cache_row
 
 
-def _route_on_device(tables, keys):
+def _route_on_device(tables, keys, n_slots: int):
     """In-jit route resolution: the device-side twin of Server._route
-    (and native adapm_route). keys int32/int64 device array; `tables`
+    (and native adapm_route), in TWO look-ups: the key's place word
+    (`decode_place`, for a main pool of `n_slots` slots a shard) and the
+    worker shard's cache row. keys int32/int64 device array; `tables`
     ends in the worker's shard, an int32 scalar operand."""
-    owner, slot, cache_row, shard = tables
-    o_sh = owner[keys]
+    place, cache_row, shard = tables
+    o_sh, o_sl = decode_place(place[keys], n_slots)
     cs = cache_row[keys]
     use_c = cs >= 0
-    g_sl = jnp.where(use_c, OOB, slot[keys])
+    g_sl = jnp.where(use_c, OOB, o_sl)
     c_sh = jnp.full_like(o_sh, shard)
     c_sl = jnp.where(use_c, cs, OOB)
     return (o_sh, g_sl, c_sh, c_sl, use_c)
@@ -583,10 +645,12 @@ def make_device_routed_step(loss_fn: Callable[..., jnp.ndarray],
         step(pools, locstat, tables, keys, local_index, alias, rng_key,
              aux, lr, eps)
           pools       tuple per class of (main, cache, delta)  [donated]
-          tables      (owner, slot, cache_row, shard): the device mirrors
+          tables      (place, cache_row, shard): the device mirrors
                       (key-indexed global arrays, shared by all length
-                      classes; cache_row is the worker shard's) and the
-                      worker's shard as an int32 scalar. The shard is an
+                      classes: a key's main copy as one place word,
+                      `decode_place`; cache_row is the worker shard's)
+                      and the worker's shard as an int32 scalar. The
+                      shard is an
                       OPERAND, so the workers of a server run one
                       compiled step (DeviceRoutedRunner shares it)
           keys        dict role -> device int array (raw PM keys)
@@ -793,18 +857,20 @@ def _route_and_gather(pools, tables, keys, roles, role_class, role_dim,
     # class -> [replica positions, chunks], where the roles span several
     by_class = {cid: [jnp.int32(0), jnp.int32(0)]
                 for cid in _classes_counted(role_class)}
-    shard = tables[3]  # the worker's, an int32 scalar operand
+    shard = tables[-1]  # the worker's, an int32 scalar operand
     for r in roles:
         cid = role_class[r]
         main, cache, delta = pools[cid]
         dim = role_dim[r]
         n_total += keys[r].size
         with jax.named_scope("adapm_route"):
+            # ONE look-up a role for the main copy's shard and slot
             if no_replicas:
-                owner, slot = tables[:2]
-                route = whole = owner[keys[r]], slot[keys[r]]
+                route = whole = decode_place(tables[0][keys[r]],
+                                             main.shape[1])
             else:
-                route = whole = _route_on_device(tables, keys[r])
+                route = whole = _route_on_device(tables, keys[r],
+                                                 main.shape[1])
             if axis is not None:
                 route = _on_this_chip(whole, axis)
             o_sh, o_sl = route[:2]
@@ -876,7 +942,7 @@ def make_device_routed_score(score_fn: Callable[..., jnp.ndarray],
                 if axis is not None:
                     with jax.named_scope("adapm_exchange"):
                         got = jax.lax.psum(jnp.where(
-                            _here(tables[3], axis), got, 0), axis)
+                            _here(tables[-1], axis), got, 0), axis)
                 return acc + got
         return score
 
@@ -1017,7 +1083,7 @@ def _build_device_routed_body(loss_fn, role_class, role_dim,
             # gradients at the positions whose row another chip holds
             # (a chip keeps its own elsewhere: the worker's are the
             # step's, the others' zeros, which no route of theirs takes)
-            worker = _here(tables[3], axis)
+            worker = _here(tables[-1], axis)
             with jax.named_scope("adapm_exchange"):
                 loss = jax.lax.psum(jnp.where(worker, loss, 0), axis)
             for r in trainable:
@@ -1323,7 +1389,7 @@ class DeviceRoutedRunner:
         replica-free variant, and on a server of several shards the
         replica variant too (one shard never holds a replica). Each runs
         once, on a batch shaped like `role_keys` and an `aux` like the
-        steps', against a slot table that is out of bounds everywhere:
+        steps', against a place table that is out of bounds everywhere:
         every gather fills zeros and every write-back is dropped, so the
         pools come back bit for bit, and neither the RNG sequence nor
         the locality counts move. On several shards the program that
@@ -1333,7 +1399,7 @@ class DeviceRoutedRunner:
         on `score_aux`."""
         srv = self.server
         with srv._lock:
-            owner, _, cache_row, shard = self._tables()
+            _, cache_row, shard = self._tables()
             nowhere = srv.ctx.put_replicated(
                 np.full(srv.num_keys, OOB, np.int32))
             no_cache = cache_row if srv.num_shards == 1 else \
@@ -1352,7 +1418,7 @@ class DeviceRoutedRunner:
                 no_keys = np.empty(0, np.int64)
                 for n in patch_rungs(srv.ab.journal_limit):
                     default_port().patch_routes(
-                        owner, nowhere, no_cache, srv.ctx.put_replicated(
+                        nowhere, no_cache, srv.ctx.put_replicated(
                             self.router._patch_operand(no_keys, n)))
             for fn in fns:
                 pools = tuple((s.main, s.cache, s.delta)
@@ -1360,7 +1426,7 @@ class DeviceRoutedRunner:
                 with srv.exec.track("main"), _GATE:
                     pools, _, _ = fn(
                         pools, self._locstat,
-                        (owner, nowhere, no_cache, shard), keys,
+                        (nowhere, no_cache, shard), keys,
                         local_index, self._alias, self._rng, aux,
                         self._scalar(0.0), self._scalar(1e-10))
                     for st, (m, c, d) in zip(srv.stores, pools):
@@ -1372,7 +1438,7 @@ class DeviceRoutedRunner:
             for no_replicas in variants:
                 with srv.exec.track("main"), _GATE:
                     self._score_program(no_replicas)(
-                        pools, (owner, nowhere, no_cache, shard), keys,
+                        pools, (nowhere, no_cache, shard), keys,
                         score_aux, self._scalar(0.0))
 
     def _prefetch_refresh(self) -> None:

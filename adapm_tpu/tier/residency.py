@@ -438,7 +438,9 @@ class TierManager:
     # -- composed device slot mirror (ops/fused.py DeviceRouter) -------------
 
     def compose_slot_table(self) -> np.ndarray:
-        """key -> DEVICE ROW table for the device-routed fused step:
+        """key -> DEVICE ROW table for the device-routed fused step
+        (`DeviceRouter._refresh` packs it with the owner into the
+        mirror's place words; a cold row's word is OOB too):
         `ab.slot` with each locally-owned key's slot replaced by its hot
         row, and OOB while cold. OOB, NOT -1: JAX's `.at[]` modes drop/
         fill only LARGE positive out-of-bounds indices — a negative

@@ -85,7 +85,7 @@ def main(cache: int, over_alloc: float) -> None:
         t0 = time.time()
         compiled = step.lower(
             pools, shape((13,), jnp.int32),
-            tuple(shape((num_keys,), jnp.int32) for _ in range(3))
+            tuple(shape((num_keys,), jnp.int32) for _ in range(2))
             + (shape((), jnp.int32),),
             {"feat": shape((sum(HOT), B), jnp.int32),
              "dense": shape((N_DENSE,), jnp.int32)},

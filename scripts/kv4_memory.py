@@ -63,7 +63,7 @@ def main(cache: int) -> None:
         t0 = time.time()
         compiled = step.lower(
             (pool,), shape((11,), jnp.int32),
-            tuple(shape((NUM_KEYS,), jnp.int32) for _ in range(3))
+            tuple(shape((NUM_KEYS,), jnp.int32) for _ in range(2))
             + (shape((), jnp.int32),),
             {r: shape((B,), jnp.int32) for r in roles if r != "neg"},
             (shape((1 << 21,), jnp.int32), shape((), jnp.int32)), None,

@@ -102,7 +102,8 @@ def main(argv=None) -> int:
              jnp.full((1, n, L // 2), 1e-3, jnp.float32)], axis=-1)
     pools = ((pool(slots, 0.01), pool(cache, 0.01),
               jnp.zeros((1, cache, L), jnp.float32)),)
-    tables = (jnp.asarray(owner), jnp.asarray(slot),
+    tables = (jnp.asarray(fused.place_words(owner, slot,
+                                            fused.place_bits(slots))),
               jnp.asarray(cache_row), jnp.int32(0))
     # the parent of PR 36 has the four-entry accumulator
     stat = jnp.zeros(6 if hasattr(fused, "SIDE_ROWS") else 4, jnp.int32)

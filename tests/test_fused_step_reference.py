@@ -4,9 +4,12 @@ the embedding columns) against a plain `jax.numpy` step written here by
 the formulas the step had before it (whole rows gathered with
 `mode="fill"`, the sampled role batch-major, AdaGrad update rows and
 `.at[].add(mode="drop")`), on tables that send positions out of bounds
-and to shard -1. And the replica variant as it is (every position gathered
-from main, the replica positions compacted and patched in from cache +
-delta a chunk at a time, their update rows alone added to delta) against
+and to shard -1. The step is handed each key's place as ONE word
+(`fused.decode_place`); the plain step the shard and the slot that the
+word stands for, as two tables. And the replica variant as it is (every
+position gathered from main, the replica positions compacted and patched
+in from cache + delta a chunk at a time, their update rows alone added
+to delta) against
 the plain step it was before: main, cache and delta gathered at every
 position and selected, update rows of every position added to both
 pools."""
@@ -37,14 +40,26 @@ def _loss(embs, aux):
 
 
 def _tables(num_keys, rng):
-    """Every key its own slot; a seventh of the keys nowhere (`OOB`: a
-    cold tier row), a fifth on shard -1 (jnp's wrap: shard 0)."""
+    """Every key its own slot of a pool of `num_keys`; a seventh of the
+    keys nowhere (`OOB`: a cold tier row), a fifth on shard -1 (jnp's
+    wrap: shard 0; a word made by hand, the router's own are never
+    negative). Returns the plain step's `owner` and `slot` and the
+    step's `place` words: the pair is what the word decodes to, a live
+    pair itself and nowhere out of bounds in both."""
     owner = np.zeros(num_keys, np.int32)
     slot = rng.permutation(num_keys).astype(np.int32)
     keys = np.arange(num_keys)
-    slot[keys % 7 == 3] = OOB
     owner[keys % 5 == 2] = -1
-    return owner, slot
+    nowhere = keys % 7 == 3
+    bits = fused.place_bits(num_keys)
+    place = np.where(nowhere, OOB, (owner << bits) | slot).astype(np.int32)
+    assert ((place < 0) == (owner == -1) & ~nowhere).all()
+    sh, sl = (np.asarray(x) for x in fused.decode_place(place, num_keys))
+    assert sh.dtype == sl.dtype == np.int32
+    assert np.array_equal(sh[~nowhere], owner[~nowhere])
+    assert np.array_equal(sl[~nowhere], slot[~nowhere])
+    assert (sl[nowhere] == OOB).all() and (sh[nowhere] >= 1).all()
+    return sh, sl, place
 
 
 def _pools(num_keys, L, rng):
@@ -130,7 +145,7 @@ def test_step_equals_the_batch_major_fill_step(case, monkeypatch,
                             functools.partial(fused.writeback_uses_kernel,
                                               backend="tpu"))
     rng = np.random.default_rng(7)
-    owner, slot = _tables(num_keys, rng)
+    owner, slot, place = _tables(num_keys, rng)
     pools = _pools(num_keys, L, rng)
     roles = {"a": 0, "b": 0}
     if N is not None:
@@ -151,8 +166,8 @@ def test_step_equals_the_batch_major_fill_step(case, monkeypatch,
                                           dtype=np.int32)),
                  jnp.asarray(rng.permutation(num_keys).astype(np.int32)))
     shard = jnp.int32(0)
-    tables = (jnp.asarray(owner), jnp.asarray(slot),
-              jnp.full(num_keys, -1, jnp.int32), shard)
+    tables = (jnp.asarray(place), jnp.full(num_keys, -1, jnp.int32), shard)
+    owner_dev, slot_dev = jnp.asarray(owner), jnp.asarray(slot)
     before = jax.jit(_step_as_before, static_argnums=6)
 
     start = np.asarray(pools[0][0])
@@ -178,7 +193,7 @@ def test_step_equals_the_batch_major_fill_step(case, monkeypatch,
         for r, k in ref_keys.items():
             assert (slot[k] == OOB).any() and (owner[k] == -1).any(), r
         want_main, want_stat, want_loss = before(
-            want_main, want_stat, tables[0], tables[1], shard,
+            want_main, want_stat, owner_dev, slot_dev, shard,
             {r: jnp.asarray(k) for r, k in ref_keys.items()}, dim)
         pools, got_stat, got_loss = body(
             pools, got_stat, tables, keys, local_index, alias, rng_key,
@@ -209,7 +224,8 @@ def _replica_step_as_before(pools, locstat, tables, keys, dim):
     side path: three row-wide gathers with `mode="fill"` and a select,
     update rows of EVERY position added to main and to delta (each drops
     the positions that are the other's). `keys` holds every role's keys,
-    the sampled role's as `[B, N]`."""
+    the sampled role's as `[B, N]`; `tables` are the four the step had
+    (owner, slot, cache row, shard)."""
     main, cache, delta = pools
     owner, slot, cache_row, shard = tables
     roles = sorted(keys)
@@ -287,7 +303,7 @@ def test_replica_step_equals_the_three_gather_step(case, monkeypatch,
                             functools.partial(fused.writeback_uses_kernel,
                                               backend="tpu"))
     rng = np.random.default_rng(17)
-    owner, slot = _tables(num_keys, rng)
+    owner, slot, place = _tables(num_keys, rng)
     main = _pools(num_keys, L, rng)[0][0]
     side = rng.normal(size=(2, 1, CACHE, L)).astype(np.float32)
     side[..., dim:] = np.abs(side[..., dim:]) * 1e-3
@@ -331,10 +347,10 @@ def test_replica_step_equals_the_three_gather_step(case, monkeypatch,
         n_held += sum(counts.values())
         n_chunks += sum(-(-c // min(ref_keys[r].size, K))
                         for r, c in counts.items())
-        tables = (jnp.asarray(owner), jnp.asarray(slot),
-                  jnp.asarray(cache_row), shard)
+        tables = (jnp.asarray(place), jnp.asarray(cache_row), shard)
         want_pools, want_stat, want_loss = before(
-            want_pools, want_stat, tables,
+            want_pools, want_stat,
+            (jnp.asarray(owner), jnp.asarray(slot)) + tables[1:],
             {r: jnp.asarray(k) for r, k in ref_keys.items()}, dim)
         pools, got_stat, got_loss = body(
             pools, got_stat, tables, keys, local_index, None, rng_key,
@@ -366,7 +382,7 @@ def test_score_of_out_of_bounds_positions(no_replicas, held, monkeypatch):
     dim = L // 2
     monkeypatch.setattr(fused, "SIDE_ROWS", 4)
     rng = np.random.default_rng(9)
-    owner, slot = _tables(num_keys, rng)
+    owner, slot, place = _tables(num_keys, rng)
     main = _pools(num_keys, L, rng)[0][0]
     cache, delta = (jnp.asarray(rng.normal(size=(1, num_keys, L)).astype(
         np.float32) * scale) for scale in (1.0, 0.1))
@@ -387,8 +403,7 @@ def test_score_of_out_of_bounds_positions(no_replicas, held, monkeypatch):
     cache_row = np.full(num_keys, -1, np.int32)
     cache_row[replicas] = rng.permutation(num_keys)[:len(replicas)]
     aux = jnp.asarray(rng.normal(size=B).astype(np.float32))
-    tables = (jnp.asarray(owner), jnp.asarray(slot),
-              jnp.asarray(cache_row), jnp.int32(0))
+    tables = (jnp.asarray(place), jnp.asarray(cache_row), jnp.int32(0))
     got = score(pools, tables, keys, aux, jnp.float32(0.5))
     rows = {r: np.where(
         (cache_row[k] >= 0)[:, None],

@@ -179,10 +179,12 @@ def test_per_chip_step_equals_the_step_over_global_pools(
     mapped, whole = make(no_replicas, L)
     rep = NamedSharding(_mesh(), P())
     put = lambda x: jax.device_put(x, rep)  # noqa: E731
-    tables = (put(owner), put(slot), put(cache_row[shard]),
-              put(np.int32(shard)))
+    tables = (put(fused.place_words(owner, slot, fused.place_bits(SLOTS))),
+              put(cache_row[shard]), put(np.int32(shard)))
     # the worker's resident keys, as `_local_neg_index` builds them
-    local = np.flatnonzero((owner == shard) | (cache_row[shard] >= 0))
+    # (a key that is nowhere is no shard's: its place word says so)
+    local = np.flatnonzero(((owner == shard) & (slot != OOB))
+                           | (cache_row[shard] >= 0))
     padded = np.full(64, np.iinfo(np.int32).max, np.int32)
     padded[:len(local)] = local
     local_index = (put(padded), put(np.int32(len(local))))
@@ -275,7 +277,7 @@ def test_per_chip_step_exchanges_the_named_rows_only(monkeypatch):
     i32 = lambda *dims: shape(dims, jnp.int32)  # noqa: E731
     pools = tuple(shape((S, n, 8), jnp.float32, P("kv"))
                   for n in (SLOTS, CACHE, CACHE))
-    args = ((pools,), i32(4), (i32(KEYS), i32(KEYS), i32(KEYS), i32()),
+    args = ((pools,), i32(4), (i32(KEYS), i32(KEYS), i32()),
             {"a": i32(B), "b": i32(B)}, (i32(64), i32()), None,
             shape((2,), jnp.uint32), None, shape((), jnp.float32),
             shape((), jnp.float32))

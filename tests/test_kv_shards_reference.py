@@ -77,7 +77,7 @@ class _Recorder:
                 "keys": {r: np.asarray(k).astype(np.int64)
                          for r, k in keys.items()},
                 "local": np.asarray(idx)[:int(count)].astype(np.int64),
-                "cache_row": np.asarray(tables[2]),
+                "cache_row": np.asarray(tables[1]),
                 "rng_key": rng_key, "loss": out[2]})
             return out
         return recorded

@@ -1,9 +1,12 @@
 """State derived from placement is patched by the journal of changed
 keys (core/addressbook.py), never rebuilt while those keys are known: a
-`DeviceRouter`'s three device tables and a `DeviceRoutedRunner`'s local
-sampling index have to equal, bit for bit, what `_refresh` and
+`DeviceRouter`'s two device tables (a key's place as ONE word, and the
+worker shard's cache row) and a `DeviceRoutedRunner`'s local sampling
+index have to equal, bit for bit, what `_refresh` and
 `_build_local_neg_index` build from the tables, after any sequence of
-placement changes, on four shards."""
+placement changes, on four shards. And the word itself: what it decodes
+to is the `(owner[k], slot[k])` of the addressbook for every live pair,
+and out of bounds for every other (`test_place_word_*`)."""
 import numpy as np
 import pytest
 
@@ -27,10 +30,10 @@ def _runner(srv, shard, population):
 
 
 def _state(runner):
-    """What the next dispatch of `runner` would hand the step: the three
+    """What the next dispatch of `runner` would hand the step: the two
     tables, the padded local index, its count, the fallback flag."""
     with runner.server._lock:
-        tables = runner._tables()[:3]
+        tables = runner._tables()[:2]
         index, count = runner._local_neg_index()
     return ([np.asarray(t) for t in tables], np.asarray(index),
             int(count), runner._li_fallback)
@@ -76,9 +79,9 @@ def _patch_widths(monkeypatch):
     widths = []
     call = jaxport.JaxDevicePort.patch_routes
 
-    def patch_routes(self, owner, slot, cache_row, patch):
+    def patch_routes(self, place, cache_row, patch):
         widths.append(patch.shape[1])
-        return call(self, owner, slot, cache_row, patch)
+        return call(self, place, cache_row, patch)
     monkeypatch.setattr(jaxport.JaxDevicePort, "patch_routes", patch_routes)
     return widths
 
@@ -210,8 +213,10 @@ def test_a_refresh_is_one_call_at_the_rung_that_holds_its_keys(
             have to be the addressbook's tables."""
             before = counters()
             with srv._lock:
-                owner, slot, cache_row = map(np.asarray, router.tables())
-            assert owner.dtype == slot.dtype == cache_row.dtype == np.int32
+                place, cache_row = map(np.asarray, router.tables())
+            assert place.dtype == cache_row.dtype == np.int32
+            owner, slot = map(np.asarray, fused.decode_place(
+                place, srv.stores[0].main.shape[1]))
             assert np.array_equal(owner, ab.owner)
             assert np.array_equal(slot, ab.slot)
             assert np.array_equal(cache_row, ab.cache_slot[1])
@@ -247,7 +252,8 @@ def test_patch_operand_keeps_the_promise_made_to_the_scatter(changed,
     behaviour, so the operand is held to it here: row 0 strictly
     ascending over the keys AND the padding, every padding key past the
     tables (dropped), its values `OOB`, the keys' values the
-    addressbook's."""
+    addressbook's: their place words (which decode to its owner and
+    slot) and their cache rows."""
     from adapm_tpu.ops import fused
     srv = adapm_tpu.setup(BIG, 2, num_shards=S, opts=SystemOptions(
         sync_max_per_sec=0, cache_slots_per_shard=32, main_over_alloc=1.5))
@@ -260,14 +266,16 @@ def test_patch_operand_keeps_the_promise_made_to_the_scatter(changed,
         assert len(keys) == changed
         assert fused.patch_rungs(changed)[-1] == width
         patch = router._patch_operand(keys, width)
-        assert patch.dtype == np.int32 and patch.shape == (4, width)
+        assert patch.dtype == np.int32 and patch.shape == (3, width)
         assert (np.diff(patch[0].astype(np.int64)) > 0).all()
         assert np.array_equal(patch[0, :changed], keys)
         assert (patch[0, changed:] >= ab.num_keys).all()
         assert (patch[1:, changed:] == fused.OOB).all()
-        assert np.array_equal(patch[1, :changed], ab.owner[keys])
-        assert np.array_equal(patch[2, :changed], ab.slot[keys])
-        assert np.array_equal(patch[3, :changed], ab.cache_slot[1, keys])
+        owner, slot = map(np.asarray, fused.decode_place(
+            patch[1, :changed], srv.stores[0].main.shape[1]))
+        assert np.array_equal(owner, ab.owner[keys])
+        assert np.array_equal(slot, ab.slot[keys])
+        assert np.array_equal(patch[2, :changed], ab.cache_slot[1, keys])
     finally:
         srv.shutdown()
 
@@ -369,3 +377,204 @@ def test_journal_answers_a_cursor_or_says_it_cannot():
     ab.reset_journal()
     assert ab.changed_since(c3) is None
     assert len(ab.changed_since(ab.journal_cursor())) == 0
+
+
+# -- the place word ---------------------------------------------------------
+
+def _two_classes(num_keys):
+    """Value lengths of two classes of unlike sizes (the first eighth of
+    the keys have rows of 16), so their pools' words split at unlike
+    bits."""
+    return np.where(np.arange(num_keys) < num_keys // 8, 16, 8)
+
+
+@pytest.mark.parametrize("classes", [1, 2])
+@pytest.mark.parametrize("shards", [1, 4, 8])
+def test_place_word_decodes_to_the_addressbooks_route(shards, classes):
+    """Every worker shard's place mirror, decoded with the slots of the
+    key's own pool, is the addressbook's `(owner[k], slot[k])` on every
+    live pair, after relocations, replicas and keys that left the
+    process; a key another process owns (`REMOTE`, `NO_SLOT`) decodes
+    out of bounds in both, where the two tables wrapped it to the last
+    row of the last shard; and the patched mirror is the rebuilt one."""
+    from adapm_tpu.ops import fused
+    from adapm_tpu.base import NO_SLOT, REMOTE
+    num_keys = 640
+    srv = adapm_tpu.setup(
+        num_keys, 8 if classes == 1 else _two_classes(num_keys),
+        num_shards=shards, opts=SystemOptions(
+            sync_max_per_sec=0, cache_slots_per_shard=32,
+            main_over_alloc=3.0))
+    try:
+        ab = srv.ab
+        slots = [st.main.shape[1] for st in srv.stores]
+        assert len(slots) == classes
+        assert len({fused.place_bits(n) for n in slots}) == classes
+        routers = [fused.DeviceRouter(srv, s) for s in range(shards)]
+        assert all(r.owner is None for r in routers)    # not built yet
+        rng = np.random.default_rng(shards * 2 + classes)
+        # (a mutation of ownership across processes takes one class)
+        gone = rng.choice(np.flatnonzero(ab.key_class == classes - 1), 24,
+                          replace=False)
+
+        def check():
+            for s, router in enumerate(routers):
+                with srv._lock:
+                    place, cache_row = map(np.asarray, router.tables())
+                assert router.owner is router.place is not None
+                assert place.dtype == np.int32 and (place >= 0).all()
+                assert np.array_equal(cache_row, ab.cache_slot[s])
+                for cid, n in enumerate(slots):
+                    k = np.flatnonzero(ab.key_class == cid)
+                    sh, sl = map(np.asarray,
+                                 fused.decode_place(place[k], n))
+                    live = ab.owner[k] != REMOTE
+                    assert np.array_equal(sh[live], ab.owner[k][live])
+                    assert np.array_equal(sl[live], ab.slot[k][live])
+                    assert (ab.slot[k][~live] == NO_SLOT).all()
+                    assert (place[k][~live] == fused.OOB).all()
+                    assert (sh[~live] >= shards).all()
+                    assert (sl[~live] == fused.OOB).all()
+
+        check()                                         # the full build
+        for it in range(6):
+            keys = np.unique(rng.choice(num_keys, 40))
+            keys = keys[ab.owner[keys] >= 0]
+            if shards > 1:
+                srv._relocate_to(keys[::2], int(rng.integers(0, shards)))
+                srv._create_replicas(keys[1::2],
+                                     int(rng.integers(0, shards)))
+            if it == 2:     # ownership leaves the process
+                srv._drop_replicas(np.tile(gone, shards), np.repeat(
+                    np.arange(shards), len(gone)))
+                with srv._topology_mutation():
+                    ab.abandon_batch(gone)
+                assert (ab.owner[gone] == REMOTE).all()
+            if it == 4:     # and half of it comes back
+                with srv._topology_mutation():
+                    ab.adopt_batch(gone[::2], shards - 1)
+            check()
+        assert _patches(srv) > 0
+        # a rebuilt mirror is the patched one, to the bit
+        for router in routers:
+            twin = fused.DeviceRouter(srv, router.shard)
+            with srv._lock:
+                for got, want in zip(router.tables(), twin.tables()):
+                    assert np.asarray(got).tobytes() == \
+                        np.asarray(want).tobytes()
+    finally:
+        srv.shutdown()
+
+
+def _check_nowhere(srv, router, kind, no_replicas, rows, rng):
+    """The body of the test below, on a store whose `rows` are known."""
+    import jax
+    import jax.numpy as jnp
+    from adapm_tpu.ops import fused
+    ab = srv.ab
+    num_keys, L = rows.shape
+    dim, B = L // 2, 16
+    every = np.arange(num_keys)
+    place, cache_row = router.tables()
+    if kind == "cold":
+        nowhere = srv.tier.compose_slot_table() == fused.OOB
+        assert 0 < (~nowhere).sum() < nowhere.sum()
+    elif kind == "remote":
+        nowhere = ab.owner < 0
+        assert 0 < nowhere.sum() < (~nowhere).sum()
+    else:
+        nowhere = np.ones(num_keys, bool)
+        patch = router._patch_operand(np.empty(0, np.int64),
+                                      fused.PATCH_KEYS)
+        assert (patch[0] >= num_keys).all()
+        # the program drops every padding key: the tables stand
+        got = srv.stores[0].port.patch_routes(
+            place, cache_row, srv.ctx.put_replicated(patch))
+        for g, w in zip(got, (place, cache_row)):
+            assert np.asarray(g).tobytes() == np.asarray(w).tobytes()
+        place = srv.ctx.put_replicated(
+            np.full(num_keys, patch[1, -1], np.int32))
+    assert (np.asarray(place)[nowhere] == fused.OOB).all()
+    assert (np.asarray(place)[~nowhere] != fused.OOB).all()
+    tables = (place, cache_row, srv.ctx.put_replicated(np.int32(0)))
+    pools = tuple((s.main, s.cache, s.delta) for s in srv.stores)
+    n_slots = pools[0][0].shape[1]
+    lost, live = every[nowhere], every[~nowhere]
+    mixed = rng.choice(lost, B)
+    if len(live):
+        mixed[::2] = rng.choice(live, B // 2, replace=False)
+
+    # the read half: zeros, and not local
+    embs, _, _, _, counts = fused._route_and_gather(
+        pools, tables, {"a": jnp.asarray(mixed.astype(np.int32))},
+        ["a"], {"a": 0}, {"a": dim}, no_replicas)
+    got = np.asarray(embs["a"])
+    here = ~nowhere[mixed]
+    assert not got[~here].any()
+    assert got[here].tobytes() == rows[mixed[here], :dim].tobytes()
+    assert rows[mixed[~here], :dim].all()   # the rows themselves are not
+    assert int(counts[0]) == B
+    assert int(counts[1]) == int((here & (ab.owner[mixed] == 0)).sum())
+
+    # the write half: dropped
+    def loss(embs, aux):   # a gradient of 1 where the row read zero
+        return (embs["a"] ** 2).sum() + embs["a"].sum()
+
+    step = jax.jit(fused._build_device_routed_body(
+        loss, {"a": 0}, {"a": dim}, (), None, None, no_replicas, False))
+    stat = srv.ctx.put_replicated(np.zeros(4, np.int32))
+    start = [np.asarray(x) for x in pools[0]]
+    for batch in (rng.choice(lost, B), mixed):
+        keys = {"a": srv.ctx.put_replicated(batch.astype(np.int32))}
+        out, _, _ = step(pools, stat, tables, keys, None, None,
+                         jax.random.PRNGKey(0), None, jnp.float32(0.1),
+                         jnp.float32(1e-10))
+        moved = (np.asarray(out[0][0]) != start[0]).any(axis=2)
+        sh, sl = map(np.asarray, fused.decode_place(
+            np.asarray(place)[batch[~nowhere[batch]]], n_slots))
+        want = np.zeros_like(moved)
+        want[sh, sl] = True
+        assert np.array_equal(moved, want)
+        assert moved.any() == (batch is mixed and len(live) > 0)
+        for i in (1, 2):    # no replica is held: cache and delta stand
+            assert np.asarray(out[0][i]).tobytes() == start[i].tobytes()
+
+
+@pytest.mark.parametrize("no_replicas", [True, False])
+@pytest.mark.parametrize("kind", ["cold", "remote", "padding"])
+def test_place_word_out_of_bounds_reads_zeros_and_writes_nothing(
+        kind, no_replicas):
+    """What is not a live pair decodes out of bounds and the step treats
+    it so: a tiered store's cold row (`compose_slot_table` hands `OOB`
+    for its slot), a key another process owns, and the padding value of
+    the patch operand (whose padding KEYS, past `num_keys`, the patch
+    program drops). Such a position reads a ZERO embedding, no shard
+    counts it local, and its write-back is dropped: the pools come back
+    to the bit where a batch names nothing else, and with live keys
+    beside it only their rows move."""
+    from adapm_tpu.ops import fused
+    num_keys, L, shards = 384, 8, 4
+    tiered = kind == "cold"
+    srv = adapm_tpu.setup(num_keys, L, num_shards=shards,
+                          opts=SystemOptions(
+                              sync_max_per_sec=0, prefetch=False,
+                              cache_slots_per_shard=32, tier=tiered,
+                              tier_hot_rows=16 if tiered else 0))
+    try:
+        ab = srv.ab
+        rng = np.random.default_rng(5)
+        every = np.arange(num_keys)
+        srv.make_worker(0).set(
+            every, rng.normal(size=(num_keys, L)).astype(np.float32) + 3)
+        rows = np.asarray(srv.read_main(every)).reshape(num_keys, L)
+        router = fused.DeviceRouter(srv, 0)
+        if kind == "cold":
+            srv.tier.promote_keys(np.arange(0, 48))
+        elif kind == "remote":
+            with srv._topology_mutation():
+                ab.abandon_batch(every[every % 5 == 1])
+        # (under the lock to the end: a tier worker's pass waits)
+        with srv._lock:
+            _check_nowhere(srv, router, kind, no_replicas, rows, rng)
+    finally:
+        srv.shutdown()

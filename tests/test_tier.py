@@ -210,11 +210,12 @@ def test_compose_slot_table_cold_is_oob(rng):
     w = srv.make_worker(0)
     w.set(np.arange(E), rng.normal(size=(E, L)).astype(np.float32))
     srv.tier.promote_keys(np.arange(0, 32))
-    eff = srv.tier.compose_slot_table()
-    assert (eff >= 0).all()
     res = srv.stores[0].res
-    rows = res.dev_row[srv.ab.owner[np.arange(E)],
-                       srv.ab.slot[np.arange(E)]]
+    with srv._lock:     # one residency for both reads: the worker waits
+        eff = srv.tier.compose_slot_table()
+        rows = res.dev_row[srv.ab.owner[np.arange(E)],
+                           srv.ab.slot[np.arange(E)]].copy()
+    assert (eff >= 0).all()
     assert (eff[rows < 0] == OOB).all(), "cold rows must mirror as OOB"
     assert np.array_equal(eff[rows >= 0], rows[rows >= 0])
     srv.shutdown()

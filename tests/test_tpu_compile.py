@@ -125,7 +125,7 @@ def test_adagrad_pass_is_in_the_xla_variant_only(kernel, shape,
     lowered = jax.jit(body, donate_argnums=(0,)).lower(
         ((shape((1, keys, row), jnp.float32), small, small),),
         shape((4,), jnp.int32),
-        tuple(shape((keys,), jnp.int32) for _ in range(3))
+        tuple(shape((keys,), jnp.int32) for _ in range(2))
         + (shape((), jnp.int32),),
         {r: shape((B,), jnp.int32) for r in roles if r != "neg"}, None,
         (shape((keys,), jnp.float32), shape((keys,), jnp.int32),
@@ -182,7 +182,7 @@ def _cell_step(cell, shape, monkeypatch):
                   for n in slots)
     compiled = jax.jit(body, donate_argnums=(0,)).lower(
         pools, shape((4,), jnp.int32),
-        tuple(shape((num_keys,), jnp.int32) for _ in range(3))
+        tuple(shape((num_keys,), jnp.int32) for _ in range(2))
         + (shape((), jnp.int32),),
         {r: shape((B,), jnp.int32) for r in roles if r != "neg"},
         # uniform draws search a local index; alias draws read their
@@ -260,10 +260,22 @@ def test_step_copies_no_sampled_rows(cell, shape, kernel_cache,
 MF_SLOTS, MF_KEYS, MF_B = 1_402_504, 1_375_000, 8192
 
 
+@pytest.mark.parametrize("cell", sorted(CELLS))
+def test_step_routes_each_role_by_one_lookup(cell, shape, kernel_cache,
+                                             monkeypatch):
+    """The compiled replica-free step gathers ONE word a position out of
+    a `[num_keys]` table, the key's place (`fused.decode_place`), where
+    it gathered an owner and a slot before PR 47: as many look-ups as
+    roles, and no division to split the word."""
+    compiled, n_roles = _cell_step(cell, shape, monkeypatch)
+    assert _route_lookups(compiled.as_text(), CELLS[cell][1]) == \
+        (n_roles, [])
+
+
 def _mf_operands(shape):
     small = shape((1, 8, L), jnp.float32)
     pools = ((shape((1, MF_SLOTS, L), jnp.float32), small, small),)
-    tables = tuple(shape((MF_KEYS,), jnp.int32) for _ in range(3)) \
+    tables = tuple(shape((MF_KEYS,), jnp.int32) for _ in range(2)) \
         + (shape((), jnp.int32),)
     keys = {r: shape((MF_B,), jnp.int32) for r in ("w", "h")}
     return pools, tables, keys
@@ -340,7 +352,7 @@ def _dlrm_step(shape, monkeypatch):
                    shape((1, 8, row), jnp.float32),
                    shape((1, 8, row), jnp.float32))
                   for n, row in zip(DLRM_SLOTS, (256, L)))
-    tables = tuple(shape((DLRM_KEYS,), jnp.int32) for _ in range(3)) \
+    tables = tuple(shape((DLRM_KEYS,), jnp.int32) for _ in range(2)) \
         + (shape((), jnp.int32),)
     keys = {"feat": shape((DLRM_M, DLRM_B), jnp.int32),
             "dense": shape((layout.num_rows,), jnp.int32)}
@@ -367,6 +379,8 @@ def test_dlrm_cell_step_fits_beside_the_tables(shape, kernel_cache,
     compiled, pool_bytes = _dlrm_step(shape, monkeypatch)
     text = compiled.as_text()
     assert text.count("custom_call_target=\"tpu_custom_call\"") == 5
+    # one look-up of a place word for each of the two roles
+    assert _route_lookups(text, DLRM_KEYS) == (2, [])
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= pool_bytes
     fullest = pool_bytes + mem.temp_size_in_bytes
@@ -401,6 +415,23 @@ def _ops_by_name(text: str):
             out[name] = (op, res, re.findall(r"%([\w.\-]+)", operands),
                          op_name or "")
     return out
+
+
+def _route_lookups(text: str, num_keys: int):
+    """What a compiled program's routing costs: (its gathers out of a
+    `[num_keys]` int32 table, the route mirrors; the divisions among the
+    operations of its `adapm_route` scope, of which there are to be
+    none: a place word is split by a shift and a mask)."""
+    ops = _ops_by_name(text)
+    table = f"s32[{num_keys}]"
+    lookups = [name for name, (op, _, operands, op_name) in ops.items()
+               if op == "fusion" and op_name.endswith("/gather")
+               and operands
+               and ops.get(operands[0], ("", ""))[1].startswith(table)]
+    divisions = [name for name, (op, _, _, op_name) in ops.items()
+                 if op in ("divide", "remainder")
+                 and "adapm_route" in op_name]
+    return len(lookups), divisions
 
 
 def _rows(result: str, width: int):
@@ -565,7 +596,7 @@ def test_four_shard_step_is_a_per_chip_program(no_replicas, topo,
         {r: L // 2 for r in roles}, (), "neg", (KV4_B, KV4_N), no_replicas)
     compiled = step.lower(
         (pool,), shape((11,), jnp.int32),  # a runner's: 6, 1 + a role
-        tuple(shape((KV4_KEYS,), jnp.int32) for _ in range(3))
+        tuple(shape((KV4_KEYS,), jnp.int32) for _ in range(2))
         + (shape((), jnp.int32),),
         {r: shape((KV4_B,), jnp.int32) for r in roles if r != "neg"},
         (shape((1 << 21,), jnp.int32), shape((), jnp.int32)), None,
@@ -580,6 +611,9 @@ def test_four_shard_step_is_a_per_chip_program(no_replicas, topo,
     # (the loss, a scalar, has no dims for _F32 to find)
     chunk = fused.EXCHANGE_BYTES // (4 * (L // 2))
     assert chunk < KV4_B and set(summed) == {(chunk, L // 2)}, summed
+    # a role's route: its place word, and with replicas its cache row
+    assert _route_lookups(text, KV4_KEYS) == \
+        (len(roles) * (1 if no_replicas else 2), [])
     mem = compiled.memory_analysis()
     pool_bytes = KV4_SLOTS * L * 4
     assert mem.alias_size_in_bytes >= pool_bytes
@@ -660,7 +694,7 @@ def test_four_shard_ctr_step_walks_two_classes_of_replicas(
         {"feat": 128, "dense": 1024}, (), None, None, False)
     compiled = step.lower(
         pools, shape((13,), jnp.int32),
-        tuple(shape((CTR4_KEYS,), jnp.int32) for _ in range(3))
+        tuple(shape((CTR4_KEYS,), jnp.int32) for _ in range(2))
         + (shape((), jnp.int32),),
         {"feat": shape((DLRM_M, DLRM_B), jnp.int32),
          "dense": shape((layout.num_rows,), jnp.int32)},
@@ -674,6 +708,7 @@ def test_four_shard_ctr_step_walks_two_classes_of_replicas(
               for dims in _F32.findall(res)}
     assert summed == {(fused.EXCHANGE_BYTES // (4 * dim), dim)
                       for dim in (128, 1024)}, summed
+    assert _route_lookups(text, CTR4_KEYS) == (2 * len(roles), [])
     mem = compiled.memory_analysis()
     pool_bytes = CTR4_POOL_BYTES
     assert mem.alias_size_in_bytes >= pool_bytes
@@ -687,23 +722,28 @@ def test_four_shard_ctr_step_walks_two_classes_of_replicas(
     assert mem.temp_size_in_bytes < 2 << 30 and live < 12.0 * 2**30
 
 
-# sha256 of the lowered text of the one-chip cells' programs as the
-# PARENT of PR 34 lowered them (commit 21ae0c6, this jax): that PR made
-# the fused programs adapt to their pools' shard count and left pools of
-# one shard their program. A PR that means to change a one-chip program
-# records its own text here (`_one_chip_lowered` under `pytest -s` prints
-# what it finds when a hash differs).
+# sha256 of the lowered text of the one-chip cells' programs as PR 47
+# lowered them (this jax). PR 34 first recorded them, of ITS parent
+# (commit 21ae0c6): it made the fused programs adapt to their pools'
+# shard count and left pools of one shard their program. PR 47 changed
+# the one-chip programs BY DESIGN (a role's route is one look-up of a
+# place word and its decode, `fused.decode_place`, where it was two
+# look-ups) and recorded its own text; what that text has to keep is
+# held by `test_one_chip_programs_look_up_one_table_a_role`. A PR that
+# means to change a one-chip program records its own text here
+# (`_one_chip_lowered` under `pytest -s` prints what it finds when a
+# hash differs).
 PARENT_LOWERED = {
     "kge.jit_step":
-        "0a4a4bdc65170f9cecb6a203bc83ee7a540c46a0fa36c0943b4cad4119e848e2",
+        "a890c753527ce726b1bcc7d3d603d9a5f7c853f0c91d82e573e21f6b59ae9647",
     "kge.jit_scan":
-        "36c3fc948c944080f440a2bee735f5926dbe784dc68f477c2e6b00a555e0a083",
+        "11bb09a759bab0de7dc91291dbd6e1fc0a58712b40d6aa30ada20ee14e701fd3",
     "sgns.jit_step":
-        "1382bc8cb88780b4a65dc2d511c37e00f3a97a90f6cfe7aaca84f6bfeeed13a7",
+        "2846f227f03aa63a5bcd1ae655e4e261da990ea11c927c942c927d1604712cd0",
     "mf.jit_step":
-        "6d0aaec54d5563dba0d80188a29424f82a100495384325f387735cfe60231cb8",
+        "241a93ec7493c12d9391bbb3544a8539d5378d1c04effeca4269868df31c80a8",
     "mf.jit_score":
-        "491ab883cabaed67acfe83894c660a1a1b46f3c168db61a54336c6ac13362e8a",
+        "ea6c2bd33d2801bd1178b691207a3f70e093c4c48abc779a225344799474956e",
 }
 
 
@@ -714,14 +754,14 @@ _MOSAIC_BODY = re.compile(r'\\22body\\22: \\22[A-Za-z0-9+/=]*\\22')
     (KV4_KEYS, "first"), (KV4_KEYS, "last"),
     (CTR4_KEYS, "first"), (CTR4_KEYS, "last")])
 def test_route_patch_is_each_chip_for_itself(num_keys, rung, topo):
-    """The program that patches a router's three table mirrors
+    """The program that patches a router's two table mirrors
     (`jaxport._patch_routes`), at both four-shard hosts' key counts and
     the first and the last rung of the widths it is called at there
     (`fused.patch_rungs` of the journal's bound: 16,384 to 2^19 entries
     at the KGE host's 4.6 M keys, to 2^21 at the click model's 25.5 M),
     compiled for the described v5e 2x2 with everything
     replicated: every chip sets the entries of its own copies, nothing
-    crosses the chips, and what it holds beside the three new tables is
+    crosses the chips, and what it holds beside the two new tables is
     under one table. At the click model's sizes one call's new tables,
     operand and temporaries fit beside the pools, the four runners'
     tables and a step's temporaries on a chip."""
@@ -738,10 +778,9 @@ def test_route_patch_is_each_chip_for_itself(num_keys, rung, topo):
         Mesh(np.asarray(topo.devices[:4]), ("kv",)), P())
     table = jax.ShapeDtypeStruct((num_keys,), jnp.int32,
                                  sharding=everywhere)
-    patch = jax.ShapeDtypeStruct((4, entries), jnp.int32,
+    patch = jax.ShapeDtypeStruct((3, entries), jnp.int32,
                                  sharding=everywhere)
-    compiled = jaxport._patch_routes.lower(
-        table, table, table, patch).compile()
+    compiled = jaxport._patch_routes.lower(table, table, patch).compile()
     text = compiled.as_text()
     for collective in ("all-reduce", "all-gather", "all-to-all",
                        "collective-permute"):
@@ -751,15 +790,16 @@ def test_route_patch_is_each_chip_for_itself(num_keys, rung, topo):
     print(f"route patch at {num_keys} keys, {entries} entries: "
           f"{mem.temp_size_in_bytes / 1e6:.1f} MB of temporaries beside "
           f"{mem.output_size_in_bytes / 1e6:.1f} MB of tables")
-    assert mem.output_size_in_bytes >= 3 * 4 * num_keys
+    assert mem.output_size_in_bytes >= 2 * 4 * num_keys
     assert mem.temp_size_in_bytes < 4 * num_keys
     if num_keys == CTR4_KEYS:
         # what the configuration holds on a chip whatever the patch does
         # (benchmarks/configs/dlrm-dcnv2-criteo1tb-kv4.json `memory`):
-        # the pools, four runners' three tables, a step's temporaries
-        # (under 2 GiB: the test of the step above)
-        held = CTR4_POOL_BYTES + 4 * 3 * 4 * num_keys + (2 << 30)
-        call = (mem.output_size_in_bytes + 4 * 4 * entries
+        # the pools, four runners' tables (three each when that file
+        # was written, two since PR 47), a step's temporaries (under
+        # 2 GiB: the test of the step above)
+        held = CTR4_POOL_BYTES + 4 * 2 * 4 * num_keys + (2 << 30)
+        call = (mem.output_size_in_bytes + 3 * 4 * entries
                 + mem.temp_size_in_bytes)
         assert held + call < 15.75 * 2**30, (held, call)
 
@@ -801,11 +841,16 @@ def test_precompile_on_several_shards_leaves_no_rung_to_compile():
         srv.shutdown()
 
 
+_LOWERED = {}  # the lowered texts, for the tests of one worker
+
+
 def _one_chip_lowered(shape, monkeypatch):
     """name -> lowered text (StableHLO, no locations) of the replica-free
     programs the one-chip cells run, at the cells' own sizes, built as on
     a TPU (the write-back kernel, exported): `jit_step` of KGE, SGNS, MF
     and DLRM, KGE's `jit_scan` of 8 steps, MF's `jit_score`."""
+    if _LOWERED:
+        return _LOWERED
     from adapm_tpu.models.kge import make_kge_loss
     from adapm_tpu.models.mf import make_mf_loss, mf_sq_error
     from adapm_tpu.models.sgns import sgns_loss
@@ -818,7 +863,7 @@ def _one_chip_lowered(shape, monkeypatch):
     def pools_tables(slots, num_keys):
         return (tuple((shape((1, n, L), jnp.float32), small, small)
                       for n in slots), shape((4,), jnp.int32),
-                tuple(shape((num_keys,), jnp.int32) for _ in range(3))
+                tuple(shape((num_keys,), jnp.int32) for _ in range(2))
                 + (i32,))
 
     slots, num_keys, B, N, _ = CELLS["kge-wikidata5m"]
@@ -860,15 +905,17 @@ def _one_chip_lowered(shape, monkeypatch):
         pools, tables, keys, (x, i32), f32)
     # the kernel's Mosaic body carries its source's path (the locations
     # of pallas_kernels.py, which no program here changes): left out
-    return {name: _MOSAIC_BODY.sub("", lowered.as_text())
-            for name, lowered in out.items()}
+    _LOWERED.update({name: _MOSAIC_BODY.sub("", lowered.as_text())
+                     for name, lowered in out.items()})
+    return _LOWERED
 
 
 def test_one_chip_programs_lower_as_on_the_parent(shape, kernel_cache,
                                                   monkeypatch, capsys):
     """Pools of one shard bypass the per-chip form entirely: the
-    one-chip cells' programs lower to the text the parent's lowered to,
-    to the character."""
+    one-chip cells' programs lower to the text recorded in
+    `PARENT_LOWERED`, to the character. (PR 47 re-recorded it: the
+    programs' route changed by design, one look-up a role for two.)"""
     import hashlib
     texts = _one_chip_lowered(shape, monkeypatch)
     for name in ("kge.jit_step", "sgns.jit_step", "mf.jit_step"):
@@ -881,3 +928,30 @@ def test_one_chip_programs_lower_as_on_the_parent(shape, kernel_cache,
         with capsys.disabled():
             print("\nlowered one-chip programs:", got)
     assert got == PARENT_LOWERED
+
+
+# program -> (keys of its route mirrors, roles it routes)
+ONE_CHIP_ROUTES = {
+    "kge.jit_step": (CELLS["kge-wikidata5m"][1], 4),
+    "kge.jit_scan": (CELLS["kge-wikidata5m"][1], 4),
+    "sgns.jit_step": (CELLS["w2v-1bw"][1], 3),
+    "mf.jit_step": (MF_KEYS, 2),
+    "mf.jit_score": (MF_KEYS, 2),
+}
+_GATHER_OPERAND = re.compile(
+    r'"stablehlo\.gather"\(.*?: \(tensor<(\d+)xi32>, ')
+
+
+@pytest.mark.parametrize("program", sorted(ONE_CHIP_ROUTES))
+def test_one_chip_programs_look_up_one_table_a_role(program, shape,
+                                                    kernel_cache,
+                                                    monkeypatch):
+    """In the lowered text of each one-chip program every role gathers
+    from exactly ONE `[num_keys]` int32 table, its keys' place words (a
+    scan's body, traced once, stands for its eight steps): a second
+    look-up a role, the `owner[keys]` and `slot[keys]` that PR 47 took
+    out, cannot come back unseen behind a re-recorded hash."""
+    text = _one_chip_lowered(shape, monkeypatch)[program]
+    num_keys, n_roles = ONE_CHIP_ROUTES[program]
+    tables = [int(n) for n in _GATHER_OPERAND.findall(text)]
+    assert tables.count(num_keys) == n_roles, tables

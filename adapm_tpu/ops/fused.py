@@ -267,14 +267,17 @@ def writeback_uses_kernel(main, backend: str = None) -> bool:
     partition under GSPMD, so a program over a global pool of several
     shards never takes it) whose rows the kernel can copy and form: the
     row's two halves, [emb | accumulator], each a whole number of the
-    128 lanes (L a multiple of 256), slots of the 8 rows of a tile;
+    128 lanes (L a multiple of 256), slots of the 8 rows of a tile and
+    no more of them than a code of the kernel names (`writeback
+    .SLOT_MASK`: 2^24, at 1 KB a row the chip's whole HBM);
     `_adagrad_update` and XLA's scatter-add everywhere else. A static
     property of the compiled variant, read from the pool's shape and
     dtype."""
     backend = jax.default_backend() if backend is None else backend
     return (backend == "tpu" and main.ndim == 3
             and main.shape[0] == 1 and main.dtype == jnp.float32
-            and main.shape[2] % 256 == 0 and main.shape[1] % 8 == 0)
+            and main.shape[2] % 256 == 0 and main.shape[1] % 8 == 0
+            and main.shape[1] <= writeback.SLOT_MASK + 1)
 
 
 def _kernel_writeback(main, o_sh, o_sl, g, acc, lr, eps):

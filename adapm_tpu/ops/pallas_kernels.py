@@ -21,14 +21,20 @@ process whose step comes from the compile cache does not import this
 module or Pallas at all; one call for each 131,072 sorted positions,
 because a call's codes are one SMEM operand. It is the first manual-DMA
 kernel here (`make_async_copy` from HBM refs, own semaphores, copies of
-three chunks in flight). What it could NOT be is a row-wise copy: the
-pool's layout is XLA's (8, 128) tiling, where an 8 KB row is 16 pieces
-of 512 B, and Mosaic refuses a one-row slice of a tiled memref ("must be
-aligned to tiling (8)"), in HBM and in VMEM alike. So it moves whole
-8-row groups. Measured on the chip (PERF.md section 6, PR 25): 141-189
-ns a row with the sort and the permutation against 280 for XLA's
-scatter-add, bound by HBM bandwidth at 8 rows moved for each one
-changed.
+three chunks in flight). Its loop over positions decides nothing: what
+a position does (which buffer place it sums into, whether it starts a
+read or a write) is decided for all positions at once in
+`writeback.sort_slots` and rides in the position's code, and a call
+visits only the chunks that hold a valid position (PR 48: the loop's
+branches and carried scalars, not bytes, were 65 ns of the 72 a
+position cost at 1 KB rows; PERF.md section 6). What it could NOT be
+is a row-wise copy: the pool's layout is XLA's (8, 128) tiling, where
+an 8 KB row is 16 pieces of 512 B, and Mosaic refuses a one-row slice
+of a tiled memref ("must be aligned to tiling (8)"), in HBM and in VMEM
+alike. So it moves whole 8-row groups. Measured on the chip (PERF.md
+section 6, PR 25): 141-189 ns a row with the sort and the permutation
+against 280 for XLA's scatter-add, bound by HBM bandwidth at 8 rows
+moved for each one changed.
 
 `gather_rows` and `adagrad_apply` use only the BlockSpec subset (grid
 pipelines + scalar prefetch, compiler-generated double-buffered DMA, no
@@ -47,7 +53,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .writeback import CLOSES, GROUP, OPENS, SLOT_MASK, sorted_slices
+from .writeback import (CLOSES, GROUP, OPENS, SLOT_MASK, TGT_MASK, TGT_SHIFT,
+                        chunk_meta, sorted_slices)
 
 
 def _copy_kernel(idx_ref, blk_ref, o_ref):
@@ -129,21 +136,24 @@ def adagrad_apply(grads: jnp.ndarray, emb: jnp.ndarray, acc: jnp.ndarray,
 # ---------------------------------------------------------------------------
 
 _GROUP_BUFFERS = 3  # chunk c's groups live in buffer c % 3
+_AHEAD = _GROUP_BUFFERS - 1  # chunks whose reads are in flight
 
 
-def _scatter_add_kernel(code_ref, _pool_in, *refs, rows: int, n_chunks: int,
-                        adagrad: bool):
-    """One pass over `n_chunks` chunks of `rows` sorted positions.
+def _scatter_add_kernel(code_ref, meta_ref, _pool_in, *refs, rows: int,
+                        n_chunks: int, adagrad: bool):
+    """One pass over the chunks of `rows` sorted positions that hold a
+    valid one (`meta_ref[n_chunks]` of the call's `n_chunks`: invalid
+    slots sort last, and the tail they fill is not visited).
 
-    code_ref: `sort_slots`'s codes, SMEM (scalar prefetch). `_pool_in` is
-    the same HBM buffer as `pool_hbm` (input_output_aliases), the one
-    name the kernel reads and writes the pool by. A pool of (8, 128)-tiled
-    rows can only be copied in whole groups of 8 rows (Mosaic refuses a
-    one-row slice of a tiled memref), so the unit read, summed into and
-    written back is the 8-row group, 8 * L * 4 contiguous bytes. gbuf:
-    [3, rows, 8, L] VMEM, one group per position that opens a run of its
-    group; ubuf: [2, rows, L], the chunk's update rows; counts: SMEM, the
-    reads and writes started per buffer.
+    code_ref, meta_ref: `sort_slots`'s codes and their `chunk_meta`,
+    SMEM (scalar prefetch). `_pool_in` is the same HBM buffer as
+    `pool_hbm` (input_output_aliases), the one name the kernel reads and
+    writes the pool by. A pool of (8, 128)-tiled rows can only be copied
+    in whole groups of 8 rows (Mosaic refuses a one-row slice of a tiled
+    memref), so the unit read, summed into and written back is the 8-row
+    group, 8 * L * 4 contiguous bytes. gbuf: [3, rows, 8, L] VMEM, one
+    group per position that opens a run of its group; ubuf: [2, rows,
+    L], the chunk's update rows.
 
     Where a chunk's update rows come from is the one thing the kernel's
     two forms differ in (`adagrad`, a static). Plain: `refs` starts with
@@ -157,14 +167,22 @@ def _scatter_add_kernel(code_ref, _pool_in, *refs, rows: int, n_chunks: int,
     Only the first position of a run of one group reads it and only the
     last writes it, so no two copies in flight touch one group. A run
     that crosses a chunk boundary moves its group, as summed so far, to
-    position 0 of the next chunk's buffer."""
+    place 0 of the next chunk's buffer. The loop over a chunk's
+    positions decides none of this: a position's code says where its
+    group lives in the buffer, whether it opens a run (start the read,
+    two chunks ahead) and whether it closes one (start the write), and
+    `meta_ref` how many copies each chunk waits for, so the loop carries
+    nothing from position to position, is unrolled, and its only
+    branches are the starts of copies. A position is summed whether
+    valid or not: the invalid ones of the last visited chunk name a
+    place of the buffer that is never written back."""
     nb = _GROUP_BUFFERS
     if adagrad:
-        g_hbm, acc_hbm, hyper, pool_hbm, gbuf, ubuf, counts, gsem, usem, \
-            wsem = refs
+        g_hbm, acc_hbm, hyper, pool_hbm, gbuf, ubuf, gsem, usem, wsem = refs
         half = ubuf.shape[2] // 2
     else:
-        upd_hbm, pool_hbm, gbuf, ubuf, counts, gsem, usem, wsem = refs
+        upd_hbm, pool_hbm, gbuf, ubuf, gsem, usem, wsem = refs
+    n_visit = meta_ref[n_chunks]
 
     def group_copy(code, b, j, sem, to_pool: bool):
         g0 = pl.multiple_of(code & (SLOT_MASK & ~(GROUP - 1)), GROUP)
@@ -173,8 +191,9 @@ def _scatter_add_kernel(code_ref, _pool_in, *refs, rows: int, n_chunks: int,
         return pltpu.make_async_copy(buf, hbm, sem) if to_pool else \
             pltpu.make_async_copy(hbm, buf, sem)
 
-    def upd_copies(c, s):
-        """Chunk c's copies into ubuf[s], all on usem[s]."""
+    def upd_copies(c):
+        """Chunk c's copies into ubuf[c % 2], all on usem[c % 2]."""
+        s = c % _AHEAD
         at = pl.ds(pl.multiple_of(c * rows, rows), rows)
         if not adagrad:
             return [pltpu.make_async_copy(upd_hbm.at[at], ubuf.at[s],
@@ -183,12 +202,13 @@ def _scatter_add_kernel(code_ref, _pool_in, *refs, rows: int, n_chunks: int,
             src.at[at], ubuf.at[s, :, pl.ds(lane, half)], usem.at[s])
             for src, lane in ((g_hbm, 0), (acc_hbm, half))]
 
-    def form_chunk(c, s):
-        """Wait for chunk c's copies; ubuf[s] then holds its update
+    def form_chunk(c):
+        """Wait for chunk c's copies; ubuf[c % 2] then holds its update
         rows (the AdaGrad form computes them from the two halves, all
         `rows` positions at once, dropped and padding ones too: their
-        rows are finite and never added)."""
-        for copy in upd_copies(c, s):
+        rows are never written back)."""
+        s = c % _AHEAD
+        for copy in upd_copies(c):
             copy.wait()
         if adagrad:
             g = ubuf[s, :, pl.ds(0, half)]
@@ -197,89 +217,90 @@ def _scatter_add_kernel(code_ref, _pool_in, *refs, rows: int, n_chunks: int,
                 ubuf[s, :, pl.ds(half, half)] + g2 + hyper[1])
             ubuf[s, :, pl.ds(half, half)] = g2
 
-    def start_reads(c):
-        b, s = c % nb, c % 2
-        for copy in upd_copies(c, s):
+    def start_read(c, j, go=True):
+        """Position j of chunk c: the read of its group, if it opens a
+        run (a run that continues from the chunk before is moved, not
+        read)."""
+        code = code_ref[c * rows + j]
+        b = c % nb
+
+        @pl.when(go & ((code & OPENS) != 0))
+        def _():
+            group_copy(code, b, j, gsem.at[b], to_pool=False).start()
+
+    def wait_all(count, b, sem):
+        """Wait for `count` group copies on `sem`, reads into gbuf[b] or
+        writes out of it: a DMA semaphore counts bytes, so one wait is
+        good for any number of copies' worth, and the count's bits (at
+        most `rows` copies a chunk) are at most six waits where a wait
+        a copy was up to `rows`. The descriptor is never started: it
+        only says how many bytes."""
+        k = rows
+        while k:
+            @pl.when((count & k) != 0)
+            def _(k=k):
+                some = gbuf.at[b, pl.ds(0, k)]
+                pltpu.make_async_copy(some, some, sem).wait()
+            k //= 2
+
+    def first_reads(c, carry):
+        """The reads of chunk c < 2, before the walk: a loop, not hot."""
+        for copy in upd_copies(c):
             copy.start()
 
-        def row(j, started):
-            code = code_ref[c * rows + j]
-            # (a run that continues from the chunk before is moved here
-            # by compute_and_write, not read)
-            go = (code >= 0) & ((code & OPENS) != 0)
-
-            @pl.when(go)
-            def _():
-                group_copy(code, b, j, gsem.at[b], to_pool=False).start()
-            return started + go.astype(jnp.int32)
-        counts[0, b] = jax.lax.fori_loop(0, rows, row, jnp.int32(0))
-
-    def wait_all(count, b, sem, to_pool: bool):
-        def one(_, carry):
-            group_copy(jnp.int32(0), b, 0, sem, to_pool=to_pool).wait()
+        def one(j, carry):
+            start_read(c, j)
             return carry
-        jax.lax.fori_loop(0, count, one, None)
+        return jax.lax.fori_loop(0, rows, one, carry)
+    jax.lax.fori_loop(0, jnp.minimum(_AHEAD, n_visit), first_reads, None)
 
-    def compute_and_write(c, tgt_prev):
-        """Sum chunk c's update rows into their groups, in sorted order,
-        and start the write of each group whose run ends here. Returns
-        the buffer position of the group the chunk's last run sums into
-        (the next chunk may continue it)."""
-        b, s = c % nb, c % 2
+    def chunk(c, carry):
+        b, s, base = c % nb, c % _AHEAD, c * rows
+        form_chunk(c)
+        wait_all(meta_ref[c] & 255, b, gsem.at[b])
 
-        def row(j, carry):
-            tgt, written = carry
-            code = code_ref[c * rows + j]
-            valid = code >= 0
-            tgt = jnp.where((code & OPENS) != 0, j, tgt)
-
-            @pl.when(valid)
-            def _():
-                sub = code & (GROUP - 1)
-                gbuf[b, tgt, pl.ds(sub, 1), :] = (
-                    gbuf[b, tgt, pl.ds(sub, 1), :]
-                    + ubuf[s, pl.ds(j, 1), :])
-            done = valid & ((code & CLOSES) != 0)
-
-            @pl.when(done)
-            def _():
-                group_copy(code, b, tgt, wsem.at[b], to_pool=True).start()
-            return tgt, written + done.astype(jnp.int32)
-
-        # position 0 continues the previous chunk's last run: take over
-        # its group as summed so far
-        first = code_ref[c * rows]
-
-        @pl.when((first >= 0) & ((first & OPENS) == 0))
+        # place 0 continues the previous chunk's last run: take over its
+        # group as summed so far
+        @pl.when((code_ref[base] & OPENS) == 0)
         def _():
-            gbuf[b, 0] = gbuf[(c + nb - 1) % nb, tgt_prev]
-        tgt, written = jax.lax.fori_loop(
-            0, rows, row, (jnp.int32(0), jnp.int32(0)))
-        counts[1, b] = written
-        return tgt
+            gbuf[b, 0] = gbuf[(c + nb - 1) % nb,
+                              (code_ref[base - 1] >> TGT_SHIFT) & TGT_MASK]
 
-    start_reads(0)
-    if n_chunks > 1:
-        start_reads(1)
-
-    def chunk(c, tgt_prev):
-        b = c % nb
-        form_chunk(c, c % 2)
-        wait_all(counts[0, b], b, gsem.at[b], to_pool=False)
-        tgt = compute_and_write(c, tgt_prev)
-
+        # chunk c + 2 reads into the buffer of chunk c - 1
         @pl.when(c >= 1)
         def _():
             pb = (c + nb - 1) % nb
-            wait_all(counts[1, pb], pb, wsem.at[pb], to_pool=True)
+            wait_all(meta_ref[c - 1] >> 8, pb, wsem.at[pb])
+        ahead = c + _AHEAD
+        go = ahead < n_visit
+        ahead = jnp.where(go, ahead, c)  # codes that exist, flags unread
 
-        @pl.when(c + 2 < n_chunks)
+        def position(j):
+            code = code_ref[base + j]
+            sub, tgt = code & (GROUP - 1), (code >> TGT_SHIFT) & TGT_MASK
+            gbuf[b, tgt, pl.ds(sub, 1), :] = (
+                gbuf[b, tgt, pl.ds(sub, 1), :] + ubuf[s, pl.ds(j, 1), :])
+
+            @pl.when((code & CLOSES) != 0)
+            def _():
+                group_copy(code, b, tgt, wsem.at[b], to_pool=True).start()
+            start_read(ahead, j, go)
+
+        for j in range(rows):  # unrolled: 5 ns a position on a v5e
+            position(j)
+
+        # ubuf[s] is summed: chunk c + 2's update rows may land there
+        @pl.when(go)
         def _():
-            start_reads(c + 2)
-        return tgt
-    jax.lax.fori_loop(0, n_chunks, chunk, jnp.int32(0))
-    lb = (n_chunks - 1) % nb
-    wait_all(counts[1, lb], lb, wsem.at[lb], to_pool=True)
+            for copy in upd_copies(ahead):
+                copy.start()
+        return carry
+    jax.lax.fori_loop(0, n_visit, chunk, None)
+
+    @pl.when(n_visit > 0)
+    def _():
+        lb = (n_visit - 1) % nb
+        wait_all(meta_ref[n_visit - 1] >> 8, lb, wsem.at[lb])
 
 
 def _sorted_rows_call(pool, codes, *operands, chunk_rows: int,
@@ -297,7 +318,7 @@ def _sorted_rows_call(pool, codes, *operands, chunk_rows: int,
                           n_chunks=codes.shape[0] // chunk_rows,
                           adagrad=adagrad),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
+            num_scalar_prefetch=2,
             grid=(1,),
             in_specs=[hbm, hbm, hbm, pl.BlockSpec(memory_space=pltpu.SMEM)]
             if adagrad else [hbm, hbm],
@@ -305,18 +326,17 @@ def _sorted_rows_call(pool, codes, *operands, chunk_rows: int,
             scratch_shapes=[
                 pltpu.VMEM((_GROUP_BUFFERS, chunk_rows, GROUP, L),
                            pool.dtype),
-                pltpu.VMEM((2, chunk_rows, L), pool.dtype),
-                pltpu.SMEM((2, _GROUP_BUFFERS), jnp.int32),
+                pltpu.VMEM((_AHEAD, chunk_rows, L), pool.dtype),
                 pltpu.SemaphoreType.DMA((_GROUP_BUFFERS,)),
-                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.SemaphoreType.DMA((_AHEAD,)),
                 pltpu.SemaphoreType.DMA((_GROUP_BUFFERS,))]),
         out_shape=jax.ShapeDtypeStruct(pool.shape, pool.dtype),
-        input_output_aliases={1: 0},
+        input_output_aliases={2: 0},
         compiler_params=pltpu.CompilerParams(
-            vmem_limit_bytes=(_GROUP_BUFFERS * GROUP + 2) * chunk_rows
+            vmem_limit_bytes=(_GROUP_BUFFERS * GROUP + _AHEAD) * chunk_rows
             * L * 4 + (4 << 20)),
         interpret=interpret,
-    )(codes, pool, *operands)
+    )(codes, chunk_meta(codes, chunk_rows), pool, *operands)
 
 
 # apm-lint: disable=APM008 Pallas TPU kernel (backend-specific by

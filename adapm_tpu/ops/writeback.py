@@ -5,7 +5,10 @@
 
 The slots are sorted here, in plain XLA, and cut into slices of at most
 `MAX_POSITIONS` sorted positions, one kernel call each (the kernel keeps
-a call's codes in SMEM, so its size must not grow with the batch). The
+a call's codes in SMEM, so its size must not grow with the batch). What
+the kernel does at a position is decided here as well, vector work over
+all positions at once where the kernel's scalar loop paid 65 ns a
+position for it (`sort_slots`'s codes, `chunk_meta`). The
 kernel itself is always taken as a `jax.export.Exported`: traced and
 lowered once for its sizes and kept beside the compiled programs in
 jax's persistent compilation cache directory, so a later process that
@@ -25,7 +28,13 @@ import jax.numpy as jnp
 
 INVALID_SLOT = jnp.iinfo(jnp.int32).max
 GROUP = 8          # rows of one (8, 128) float32 tile: the kernel's unit
-SLOT_MASK = (1 << 29) - 1  # a code: slot | OPENS | CLOSES, or -1
+# A code, one int32 a sorted position, holds everything the kernel's loop
+# would otherwise decide: the slot (24 bits: 2^24 slots of at least 1 KB
+# are the 16 GB of a v5e's HBM), the chunk position of the group buffer
+# the position sums into (5 bits), whether it opens or closes a run of
+# positions in one 8-row group, and in the sign that its slot is invalid
+SLOT_MASK = (1 << 24) - 1
+TGT_SHIFT, TGT_MASK = 24, 31
 OPENS, CLOSES = 1 << 29, 1 << 30
 # sorted positions one kernel call takes: its codes are one SMEM operand
 # (scalar prefetch), 512 KB of the v5e's 1 MB at this many; twice as many
@@ -36,22 +45,32 @@ MAX_POSITIONS = 1 << 17
 def chunk_rows_for(row_length: int) -> int:
     """Positions a chunk: 32 at rows of up to 2048 floats (three group
     buffers of 2 MB; on the chip 16, 32 and 64 ran alike), fewer for
-    longer rows so that the buffers stay a few MB of VMEM."""
-    return max(GROUP, min(32, (1 << 16) // row_length // GROUP * GROUP))
+    longer rows so that the buffers stay a few MB of VMEM. Never more
+    than a code's 5 bits of buffer position name."""
+    return max(GROUP, min(TGT_MASK + 1,
+                          (1 << 16) // row_length // GROUP * GROUP))
 
 
 def sort_slots(slots: jnp.ndarray, n_slots: int, chunk_rows: int = 32,
                slice_positions: int = MAX_POSITIONS):
     """(codes, perm) for `scatter_add_sorted_rows`: the flattened slots
     sorted stably and padded to whole chunks, and the batch position of
-    each. A code is the slot with two flags above it: the position opens
-    (bit 29) or closes (bit 30) a run of positions in one 8-row group,
-    the unit the kernel copies. Every `slice_positions` positions a run
-    is closed and opened anew, so each such slice can be one call of the
-    kernel. Slots outside the pool (negative ones wrap first, as jnp
-    indexing does) sort last and carry the code -1, as padding positions
-    do; their `perm` is clamped to the batch."""
-    assert n_slots <= SLOT_MASK, n_slots
+    each. A code is the slot with the kernel's decisions above it, made
+    here for all positions at once: the position opens (bit 29) or
+    closes (bit 30) a run of positions in one 8-row group, the unit the
+    kernel copies, and (bits 24-28) the place in its chunk's group
+    buffer of the group it sums into: the chunk position of the run's
+    opener, 0 where the run continues from the chunk before. Every
+    `slice_positions` positions a run is closed and opened anew, so each
+    such slice can be one call of the kernel. Slots outside the pool
+    (negative ones wrap first, as jnp indexing does) sort last, so the
+    valid positions of every slice are a prefix of it, and carry a
+    NEGATIVE code, as padding positions do: no flag, row 0 of the buffer
+    place of their own chunk position, which no run has (the kernel sums
+    a chunk's positions without asking, and never writes that place
+    back); their `perm` is clamped to the batch."""
+    assert n_slots <= SLOT_MASK + 1 and chunk_rows <= TGT_MASK + 1, \
+        (n_slots, chunk_rows)
     n = slots.shape[0]
     slots = slots.astype(jnp.int32)
     slots = jnp.where(slots < 0, slots + n_slots, slots)
@@ -67,14 +86,34 @@ def sort_slots(slots: jnp.ndarray, n_slots: int, chunk_rows: int = 32,
     none = jnp.full((1,), -1, jnp.int32)
     opens = group != jnp.concatenate([none, group[:-1]])
     closes = group != jnp.concatenate([group[1:], none])
+    at = jax.lax.iota(jnp.int32, n + pad)
     if n + pad > slice_positions:
-        at = jax.lax.iota(jnp.int32, n + pad) % slice_positions
-        opens |= at == 0
-        closes |= at == slice_positions - 1
-    codes = jnp.where(slot_sorted == INVALID_SLOT, -1,
-                      slot_sorted | jnp.where(opens, OPENS, 0)
+        opens |= at % slice_positions == 0
+        closes |= at % slice_positions == slice_positions - 1
+    at %= chunk_rows
+    tgt = jax.lax.cummax(jnp.where(opens, at, 0).reshape(-1, chunk_rows),
+                         axis=1).reshape(-1)
+    codes = jnp.where(slot_sorted == INVALID_SLOT,
+                      jnp.iinfo(jnp.int32).min | (at << TGT_SHIFT),
+                      slot_sorted | (tgt << TGT_SHIFT)
+                      | jnp.where(opens, OPENS, 0)
                       | jnp.where(closes, CLOSES, 0))
     return codes, jnp.minimum(perm, n - 1)
+
+
+def chunk_meta(codes: jnp.ndarray, chunk_rows: int) -> jnp.ndarray:
+    """What the kernel needs to know of a call's chunks before it walks
+    them, int32 [chunks + 1]: of each chunk the groups it reads (its
+    positions that open a run) and, 8 bits up, the groups it writes
+    (those that close one): the copies the kernel waits for; and in the
+    last word the chunks that hold a valid position at all, a prefix
+    (`sort_slots`): the kernel visits no other, so a call whose slice
+    is all dropped or padding does one loop test."""
+    flags = codes.reshape(-1, chunk_rows)
+    count = functools.partial(jnp.sum, axis=1, dtype=jnp.int32)
+    return jnp.concatenate([
+        count((flags & OPENS) != 0) | (count((flags & CLOSES) != 0) << 8),
+        -(-jnp.sum(codes >= 0, dtype=jnp.int32)[None] // chunk_rows)])
 
 
 def sorted_slices(slots: jnp.ndarray, n_slots: int, chunk_rows: int,

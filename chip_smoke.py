@@ -211,7 +211,8 @@ def part_kernels(ctx) -> None:
     """The Pallas kernels through the installed Mosaic compiler at the
     store's row width (adagrad also with a row count that is not a
     multiple of the block: the pl.cdiv edge; the write-back kernel at
-    the benchmark's row width, where the fused step uses it)."""
+    the benchmark's row widths, where the fused step uses it: 8 KB rows,
+    L = 2048, and the CTR feature rows' 1 KB, L = 256)."""
     import jax.numpy as jnp
     import numpy as np
 
@@ -253,7 +254,8 @@ def part_kernels(ctx) -> None:
     # more rows than one kernel call takes (three calls on the pool)
     for N, Lw, n, per_call in (
             [(256, 128, 200, None), (256, 128, 200, 64)] if interpret else
-            [(4096, 2048, 3000, None), (4096, 128, 300_000, None)]):
+            [(4096, 2048, 3000, None), (4096, 256, 3000, None),
+             (4096, 128, 300_000, None)]):
         p = 1.0 / np.arange(1, N + 1)
         slots = rng.permutation(N)[rng.choice(N, n, p=p / p.sum())] \
             .astype(np.int32)
@@ -275,31 +277,32 @@ def part_kernels(ctx) -> None:
               f"equal to np.add.at")
     # its AdaGrad form, what the fused step runs: the update rows
     # [-lr g rsqrt(acc + g^2 + eps) | g^2] formed inside the kernel
-    N, Lw, n = (256, 256, 200) if interpret else (4096, 2048, 3000)
-    H = Lw // 2
-    slots = rng.integers(0, N + 8, n).astype(np.int32)  # some dropped
-    pool = np.abs(rng.normal(size=(N, Lw))).astype(np.float32)
-    g = rng.normal(size=(n, H)).astype(np.float32)
-    acc = np.abs(rng.normal(size=(n, H))).astype(np.float32)
-    got = np.asarray(scatter_adagrad_rows(
-        jnp.asarray(pool), jnp.asarray(slots), jnp.asarray(g),
-        jnp.asarray(acc), lr, eps, interpret=interpret))
-    keep = slots < N
-    g2 = g * g
-    upd = np.concatenate([-np.float32(lr) * g / np.sqrt(
-        acc + g2 + np.float32(eps)), g2], axis=1)
-    mag = pool.copy()
-    np.add.at(mag, slots[keep], np.abs(upd[keep]))
-    np.add.at(pool, slots[keep], upd[keep])
-    _check(got[:, H:].tobytes() == pool[:, H:].tobytes(),
-           "scatter_adagrad_rows: the accumulator halves differ from "
-           "np.add.at of g*g")
-    worst = float((np.abs(got - pool) / np.spacing(mag)).max())
-    _check(worst <= 4, f"scatter_adagrad_rows: embedding halves differ "
-           f"from numpy by {worst:.1f} ulp of the summed magnitudes")
-    print(f"  scatter_adagrad_rows interpret={interpret} L={Lw} n={n}: "
-          f"accumulators bitwise equal, embeddings within {worst:.1f} ulp "
-          f"of numpy's 1/sqrt")
+    for N, Lw, n in ([(256, 256, 200)] if interpret else
+                     [(4096, 2048, 3000), (4096, 256, 3000)]):
+        H = Lw // 2
+        slots = rng.integers(0, N + 8, n).astype(np.int32)  # some dropped
+        pool = np.abs(rng.normal(size=(N, Lw))).astype(np.float32)
+        g = rng.normal(size=(n, H)).astype(np.float32)
+        acc = np.abs(rng.normal(size=(n, H))).astype(np.float32)
+        got = np.asarray(scatter_adagrad_rows(
+            jnp.asarray(pool), jnp.asarray(slots), jnp.asarray(g),
+            jnp.asarray(acc), lr, eps, interpret=interpret))
+        keep = slots < N
+        g2 = g * g
+        upd = np.concatenate([-np.float32(lr) * g / np.sqrt(
+            acc + g2 + np.float32(eps)), g2], axis=1)
+        mag = pool.copy()
+        np.add.at(mag, slots[keep], np.abs(upd[keep]))
+        np.add.at(pool, slots[keep], upd[keep])
+        _check(got[:, H:].tobytes() == pool[:, H:].tobytes(),
+               "scatter_adagrad_rows: the accumulator halves differ from "
+               "np.add.at of g*g")
+        worst = float((np.abs(got - pool) / np.spacing(mag)).max())
+        _check(worst <= 4, f"scatter_adagrad_rows: embedding halves differ "
+               f"from numpy by {worst:.1f} ulp of the summed magnitudes")
+        print(f"  scatter_adagrad_rows interpret={interpret} L={Lw} n={n}: "
+              f"accumulators bitwise equal, embeddings within {worst:.1f} ulp "
+              f"of numpy's 1/sqrt")
 
 
 def part_contract(ctx) -> None:

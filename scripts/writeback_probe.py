@@ -12,8 +12,23 @@ slots with `unique_indices=True` too; (d) the gather of the same rows;
 permutation of the 8 KB update rows and the kernel's plain form); and
 `scatter_adagrad_rows` (`kernel_adagrad_R<rows>`, ISSUE 29: the sort, the
 permutation of the two 4 KB halves and the kernel's AdaGrad form, which
-is what the fused step runs). One line a reading:
-`probe <name> <draw>: <ms> ms, <ns> ns a row`. TPU only.
+is what the fused step runs); and the kernel ALONE
+(`kernel_alone_R<rows>`, `kernel_alone_adagrad_R<rows>`: its calls on
+codes sorted and operands permuted beforehand, which is what a step's
+`_scatter_adagrad_sorted_rows` custom calls are). One line a reading:
+`probe <name> <draw>: <ms> ms, <ns> ns a position, <ns> ns a row`: a
+POSITION is one slot of the batch, landed or not; a ROW is a distinct
+pool row the batch changes (none where every slot is outside the pool).
+
+`--draw` names the draws (comma-separated): `uniform`, `zipf`, `unique`
+(all positions distinct), `invalid` (every slot outside the pool: the
+kernel's price for a position it does nothing with) and `ctr`, the CTR
+cell's own member draw (`benchmarks/configs/dlrm-dcnv2-criteo1tb.json`:
+a batch of 2,048 examples of 214 members, Zipf over each table's rows;
+`--n` of its 438,272 sorted positions, a window from the middle, which
+is what one kernel call of the cell sees; about 30% of them distinct).
+At the CTR cell's shape: `--slots 6508400 --row 256 --n 131072 --draw
+ctr,unique,invalid --only kernel_alone`. TPU only.
 """
 from __future__ import annotations
 
@@ -41,6 +56,9 @@ def main(argv=None) -> int:
     ap.add_argument("--kernel-rows", default=[32],
                     type=lambda v: [int(x) for x in v.split(",")],
                     help="chunk_rows to time the kernel at: 16,32,64")
+    ap.add_argument("--draw", default="uniform,zipf,unique",
+                    help="comma-separated draws: uniform, zipf, unique, "
+                         "invalid, ctr")
     ap.add_argument("--only", default="",
                     help="comma-separated reading names (default: all)")
     args = ap.parse_args(argv)
@@ -54,7 +72,7 @@ def main(argv=None) -> int:
         print(f"writeback_probe.py: no TPU ({dev.platform})",
               file=sys.stderr)
         return 2
-    from adapm_tpu.ops import pallas_kernels
+    from adapm_tpu.ops import pallas_kernels, writeback
     scatter_add_rows = functools.partial(pallas_kernels.scatter_add_rows,
                                          interpret=cpu)
     scatter_adagrad_rows = functools.partial(
@@ -62,13 +80,20 @@ def main(argv=None) -> int:
     N, L, n = args.slots, args.row, args.n
     only = set(filter(None, args.only.split(",")))
     rng = np.random.default_rng(25)
-    p = 1.0 / np.arange(1, N + 1)
-    draws = {
-        "uniform": rng.integers(0, N, n).astype(np.int32),
-        "zipf": rng.permutation(N)[
-            rng.choice(N, n, p=p / p.sum())].astype(np.int32),
-        "unique": rng.permutation(N)[:n].astype(np.int32),
+
+    def zipf():
+        p = 1.0 / np.arange(1, N + 1)
+        return rng.permutation(N)[rng.choice(N, n, p=p / p.sum())]
+
+    makers = {
+        "uniform": lambda: rng.integers(0, N, n),
+        "zipf": zipf,
+        "unique": lambda: rng.permutation(N)[:n],
+        "invalid": lambda: N + rng.integers(0, N, n),
+        "ctr": lambda: _ctr_slots(rng, N, n),
     }
+    draws = {d: makers[d]().astype(np.int32)
+             for d in filter(None, args.draw.split(","))}
     print(f"platform={dev.platform} device={dev.device_kind} "
           f"pool=f32[1,{N},{L}] n={n}")
     pool = jnp.zeros((1, N, L), jnp.float32)
@@ -80,7 +105,7 @@ def main(argv=None) -> int:
 
     def timed(name, draw, fn, *xs, on_pool=True):
         """ms a call of fn(pool, *xs) -> pool (the pool donated) or of
-        fn(*xs) -> a value; per row of xs[0]."""
+        fn(*xs) -> a value; per position of xs[0] and per distinct row."""
         nonlocal pool
         if only and not any(name.startswith(o) for o in only):
             return
@@ -99,8 +124,11 @@ def main(argv=None) -> int:
                 out = call()
             jax.block_until_ready(out)
             ms = (time.perf_counter() - t0) / args.reps * 1e3
+            rows = distinct[draw]
             print(f"{tag}probe {name} {draw}: {ms:.3f} ms, "
-                  f"{ms * 1e6 / xs[0].shape[0]:.1f} ns a row", flush=True)
+                  f"{ms * 1e6 / xs[0].shape[0]:.1f} ns a position, "
+                  + (f"{ms * 1e6 / rows:.1f} ns a row" if rows else
+                     "no row lands"), flush=True)
         except Exception as e:  # one reading failing must not lose the rest
             print(f"{tag}probe {name} {draw}: FAILED {type(e).__name__}: "
                   f"{str(e)[:300]}", flush=True)
@@ -111,21 +139,24 @@ def main(argv=None) -> int:
     # the kernel against XLA on a pool small enough to hold twice
     k = min(n, 8192)
     small = jnp.ones((1, min(N, 65_536), L), jnp.float32)
-    sl = jnp.asarray(draws["zipf"][:k] % small.shape[1])
+    sl = jnp.asarray(next(iter(draws.values()))[:k] % small.shape[1])
     want = xla_add(small, sl, upd[:k])
     got = scatter_add_rows(small[0], sl, upd[:k])[None]
-    print(f"{tag}check kernel against XLA, zipf n={k}: max abs difference "
+    print(f"{tag}check kernel against XLA, n={k}: max abs difference "
           f"{float(jnp.max(jnp.abs(got - want))):.3g}", flush=True)
     g2 = g[:k] * g[:k]
     want = xla_add(small, sl, jnp.concatenate(
         [-lr * g[:k] * jax.lax.rsqrt(acc[:k] + g2 + eps), g2], axis=-1))
     got = scatter_adagrad_rows(small[0], sl, g[:k], acc[:k], lr, eps)[None]
-    print(f"{tag}check AdaGrad kernel against XLA, zipf n={k}: max abs "
+    print(f"{tag}check AdaGrad kernel against XLA, n={k}: max abs "
           f"difference {float(jnp.max(jnp.abs(got - want))):.3g}", flush=True)
     del small, want, got
 
+    distinct = {d: len(np.unique(s[s < N])) for d, s in draws.items()}
     for draw, sl_np in draws.items():
         sl = jnp.asarray(sl_np)
+        print(f"{tag}draw {draw}: {n} positions, {distinct[draw]} distinct "
+              f"rows ({distinct[draw] / n:.1%})", flush=True)
         order = np.argsort(sl_np, kind="stable").astype(np.int32)
         sl_sorted, perm = jnp.asarray(sl_np[order]), jnp.asarray(order)
         timed("a_scatter_add", draw, xla_add, sl, upd)
@@ -160,7 +191,54 @@ def main(argv=None) -> int:
                   lambda m, s, g_, a, rows=rows: scatter_adagrad_rows(
                       m[0], s, g_, a, lr, eps, chunk_rows=rows)[None],
                   sl, g, acc)
+            # the kernel alone: codes sorted and operands permuted once,
+            # outside the timed program
+            slices = writeback.sorted_slices(sl, N, rows)
+            codes = [c for c, _ in slices]
+            for form, kernel, xs in (
+                    ("", pallas_kernels.scatter_add_sorted_rows, (upd,)),
+                    ("_adagrad", functools.partial(
+                        pallas_kernels.scatter_adagrad_sorted_rows,
+                        lr=lr, eps=eps), (g, acc))):
+                ops = [[x[perm] for x in xs] for _, perm in slices]
+
+                def alone(m, _counted, codes, ops, kernel=kernel,
+                          rows=rows):  # `timed` counts xs[0]'s positions
+                    m = m[0]
+                    for c, o in zip(codes, ops):
+                        m = kernel(m, c, *o, chunk_rows=rows, interpret=cpu)
+                    return m[None]
+                timed(f"kernel_alone{form}_R{rows}", draw, alone, xs[0],
+                      codes, ops)
     return 0
+
+
+def _ctr_slots(rng, n_slots: int, n: int):
+    """`n` of the CTR cell's positions as one kernel call sees them: a
+    batch's members (Zipf over each table's rows by a fixed permutation,
+    as `benchmarks/drivers/_ctr.py draw_examples`), as slots of a pool
+    that holds the tables one after the other, sorted, a window of `n`
+    from the middle, in a random order."""
+    import json
+    with open(os.path.join(os.path.dirname(os.path.abspath(__file__)), "..",
+                           "benchmarks", "configs",
+                           "dlrm-dcnv2-criteo1tb.json")) as f:
+        cfg = json.load(f)
+    first = np.concatenate([[0], np.cumsum(cfg["table_rows"])])
+    assert first[-1] <= n_slots, (first[-1], n_slots)
+    slots = []
+    for t, (rows, hot) in enumerate(zip(cfg["table_rows"],
+                                        cfg["multi_hot_sizes"])):
+        w = 1.0 / np.arange(1, rows + 1) ** cfg["assumed"]["zipf_exponent"]
+        cdf = np.cumsum(w) / w.sum()
+        r = np.minimum(np.searchsorted(
+            cdf, rng.random(cfg["batch_size"] * hot), side="right"),
+            rows - 1)
+        slots.append(first[t] + rng.permutation(rows)[r])
+    slots = np.sort(np.concatenate(slots))
+    assert n <= len(slots), (n, len(slots))
+    lo = (len(slots) - n) // 2
+    return rng.permutation(slots[lo:lo + n])
 
 
 if __name__ == "__main__":

@@ -84,6 +84,30 @@ def _scatter_case(name):
         return N, R, s, 16
     if name == "many_calls_chunk_not_a_divisor":  # calls of 24, not 30
         return N, 24, rng.integers(0, N, 100), 30
+    # the codes of PR 48: the kernel visits the chunks that hold a valid
+    # position and sums every position of those without asking
+    if name == "all_invalid":  # no chunk visited: the pool as it was
+        return N, R, np.array([N, N + 9, 2**31 - 2, -N - 1] * 5)
+    if name == "many_calls_all_invalid":
+        return N, R, np.full(40, N + 1), 16
+    if name == "valid_end_mid_chunk":
+        # sorted: 40, 40, 41, then 11 positions in the pool's LAST group,
+        # then 9 dropped: chunk 1 is visited for 6 valid positions, and
+        # its 2 invalid ones sum into buffer places that are never
+        # written back
+        return N, R, rng.permutation(np.array(
+            [40, 40, 41, 56, 57, 58, 59, 63, 63, 60, 61, 62, 63, 56]
+            + [N + 4] * 9))
+    if name == "run_opens_mid_chunk_crosses_chunk_and_call":
+        # sorted: [2]*3, then 29 times slot 29: its run opens at chunk
+        # place 3, continues at place 0 of chunk 1, is cut by the call's
+        # end after 16 positions and by the next after 32
+        return N, R, rng.permutation(np.array([2] * 3 + [29] * 29 + [30])), \
+            16
+    if name == "row_named_more_often_than_a_chunk_holds":
+        s = rng.integers(0, N, 60)
+        s[rng.permutation(60)[:27]] = 41  # 27 > 3 chunks of 8
+        return N, R, s
     raise KeyError(name)
 
 
@@ -92,7 +116,10 @@ SCATTER_CASES = [
     "run_ends_on_chunk_boundary", "n_not_a_multiple_of_chunk",
     "out_of_range_dropped", "n_smaller_than_chunk", "one_group_many_rows",
     "many_calls_run_cut_by_each", "many_calls_group_cut_between_rows",
-    "many_calls_dropped_tail", "many_calls_chunk_not_a_divisor"]
+    "many_calls_dropped_tail", "many_calls_chunk_not_a_divisor",
+    "all_invalid", "many_calls_all_invalid", "valid_end_mid_chunk",
+    "run_opens_mid_chunk_crosses_chunk_and_call",
+    "row_named_more_often_than_a_chunk_holds"]
 
 
 @pytest.mark.parametrize("case", SCATTER_CASES)
@@ -231,6 +258,84 @@ def test_sorted_slices_are_whole_calls():
     assert sorted(seen) == list(range(190))
     # the batch's order within a slot survives the cut (stable sort)
     assert [p for p in seen if p < 90] == list(range(90))
+
+
+@pytest.mark.parametrize("case, visited", [
+    ("all_invalid", [0]),
+    ("many_calls_all_invalid", [0, 0, 0]),
+    ("valid_end_mid_chunk", [2]),          # 14 valid of 24 positions
+    ("many_calls_dropped_tail", [2, 2, 0]),  # 28 valid, 20 dropped
+    ("no_duplicates", [3]),                # every chunk
+    ("n_not_a_multiple_of_chunk", [3]),    # 21 valid, padded to 24
+])
+def test_invalid_tail_is_not_visited(case, visited):
+    """The valid positions of a call are a prefix of it (invalid slots
+    sort last), and the kernel is told how many chunks hold one: the
+    last word of `chunk_meta`. The words before it count each chunk's
+    copies: the runs it opens and closes."""
+    from adapm_tpu.ops.writeback import (CLOSES, OPENS, chunk_meta,
+                                         sorted_slices)
+    N, R, slots, per_call = (*_scatter_case(case), None)[:4]
+    slices = sorted_slices(jnp.asarray(slots, dtype=jnp.int32), N, R,
+                           per_call)
+    assert len(slices) == len(visited)
+    for (codes, _), chunks in zip(slices, visited):
+        meta, codes = np.asarray(chunk_meta(codes, R)), np.asarray(codes)
+        assert meta[-1] == chunks == -(-int((codes >= 0).sum()) // R)
+        assert (codes[:(codes >= 0).sum()] >= 0).all()
+        by_chunk = codes.reshape(-1, R)
+        assert (meta[:-1] & 255 == ((by_chunk & OPENS) != 0).sum(1)).all()
+        assert (meta[:-1] >> 8 == ((by_chunk & CLOSES) != 0).sum(1)).all()
+        # an invalid position opens and closes nothing
+        assert not (by_chunk[by_chunk < 0] & (OPENS | CLOSES)).any()
+
+
+@pytest.mark.parametrize("case", [
+    "one_slot_half_the_batch", "run_opens_mid_chunk_crosses_chunk_and_call",
+    "valid_end_mid_chunk", "many_calls_chunk_not_a_divisor"])
+def test_codes_carry_the_place_of_the_runs_group(case):
+    """Bits 24-28 of a code are the kernel's `tgt`, decided in
+    `sort_slots`: the chunk position of the last position at or before
+    it that opens a run, 0 where none does (the run continues from the
+    chunk before, whose group the kernel moves to place 0); an invalid
+    position names its own place, where no run's group lives."""
+    from adapm_tpu.ops.writeback import (OPENS, SLOT_MASK, TGT_MASK,
+                                         TGT_SHIFT, sorted_slices)
+    N, R, slots, per_call = (*_scatter_case(case), None)[:4]
+    slots = np.asarray(slots, dtype=np.int32)
+    seen_mid_chunk_run = False
+    for codes, perm in sorted_slices(jnp.asarray(slots), N, R, per_call):
+        codes, perm = np.asarray(codes), np.asarray(perm)
+        tgt = (codes >> TGT_SHIFT) & TGT_MASK
+        for c in range(0, len(codes), R):
+            place = 0
+            for j in range(R):
+                code = codes[c + j]
+                if code < 0:
+                    assert tgt[c + j] == j and code & SLOT_MASK == 0
+                    continue
+                if code & OPENS:
+                    place = j
+                assert tgt[c + j] == place
+                assert code & SLOT_MASK == slots[perm[c + j]]
+                seen_mid_chunk_run |= place > 0 and not code & OPENS
+    assert seen_mid_chunk_run
+
+
+@pytest.mark.parametrize("n_slots, fits", [
+    (1 << 24, True), ((1 << 24) + 8, False), (1 << 29, False)])
+def test_slots_beyond_a_codes_bits_are_refused(n_slots, fits):
+    """A code has 24 bits of slot: the static rule keeps a larger pool
+    on XLA's scatter, and `sort_slots` refuses it outright."""
+    from adapm_tpu.ops import fused, writeback
+    pool = jax.ShapeDtypeStruct((1, n_slots, 256), jnp.float32)
+    assert fused.writeback_uses_kernel(pool, backend="tpu") is fits
+    slots = jnp.zeros((8,), jnp.int32)
+    if fits:
+        writeback.sort_slots(slots, n_slots, 8)
+    else:
+        with pytest.raises(AssertionError):
+            writeback.sort_slots(slots, n_slots, 8)
 
 
 def test_exported_kernel_is_kept_read_back_and_remade(kernel_cache,

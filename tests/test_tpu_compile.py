@@ -47,39 +47,61 @@ def _no_compile_cache():
     compilation_cache.reset_cache()
 
 
-@pytest.mark.parametrize("adagrad, n_slots, n", [
-    (False, 1_172_432, 131_072),   # the plain form at the KGE cell's shape
+@pytest.mark.parametrize("adagrad, n_slots, n, row", [
+    (False, 1_172_432, 131_072, L),  # the plain form at the KGE cell's shape
     # the AdaGrad form at every size the one-chip training cells call it
-    (True, 1_172_432, 131_072), (True, 1_172_432, 4096),  # negs; s, r, o
-    (True, 1_618_688, 40_960), (True, 1_618_688, 8192),   # noise; ctr, ctx
+    (True, 1_172_432, 131_072, L), (True, 1_172_432, 4096, L),  # negs; s, r, o
+    (True, 1_618_688, 40_960, L), (True, 1_618_688, 8192, L),  # noise; ctr, ctx
+    # the CTR cell's feature rows of 1 KB: 438,272 sorted positions are
+    # three calls of 131,072 and one of 45,056
+    (True, 6_508_400, 131_072, 256), (True, 6_508_400, 45_056, 256),
 ])
-def test_writeback_kernel_compiles_in_place(adagrad, n_slots, n, shape,
+def test_writeback_kernel_compiles_in_place(adagrad, n_slots, n, row, shape,
                                             kernel_cache):
     """The manual-DMA write-back as the step takes it (exported once
     under its form's name, read back from the cache directory by the
     next process), in its plain form (the rows given) and its AdaGrad
     form (the update rows formed in VMEM from two half-row operands,
-    `lr` and `eps` SMEM operands): Mosaic takes it, and the donated
-    pool is updated in place (no pool-sized copy)."""
+    `lr` and `eps` SMEM operands), at rows of 8 KB and of 1 KB: Mosaic
+    takes it, and the donated pool is updated in place (no pool-sized
+    copy)."""
     from adapm_tpu.ops import writeback
-    made = writeback.exported_kernel(n_slots, L, n, 32, adagrad=adagrad)
+    rows = writeback.chunk_rows_for(row)
+    made = writeback.exported_kernel(n_slots, row, n, rows, adagrad=adagrad)
     (kept,) = kernel_cache.iterdir()
     assert kept.name.startswith("scatter_adagrad_sorted_rows-" if adagrad
                                 else "scatter_add_sorted_rows-")
     writeback.exported_kernel.cache_clear()  # as a later process
-    read = writeback.exported_kernel(n_slots, L, n, 32, adagrad=adagrad)
+    read = writeback.exported_kernel(n_slots, row, n, rows, adagrad=adagrad)
     assert read is not made
     assert read.mlir_module_serialized == made.mlir_module_serialized
     assert [f.name for f in kernel_cache.iterdir()] == [kept.name]
     f32 = lambda *dims: shape(dims, jnp.float32)  # noqa: E731
-    operands = (f32(n, L // 2), f32(n, L // 2), f32(), f32()) if adagrad \
-        else (f32(n, L),)
+    operands = (f32(n, row // 2), f32(n, row // 2), f32(), f32()) \
+        if adagrad else (f32(n, row),)
     compiled = jax.jit(read.call, donate_argnums=(0,)).lower(
-        f32(n_slots, L), shape((n,), jnp.int32), *operands).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+        f32(n_slots, row), shape((n,), jnp.int32), *operands).compile()
+    assert compiled.as_text().count(
+        "custom_call_target=\"tpu_custom_call\"") == 1
     mem = compiled.memory_analysis()
-    assert mem.alias_size_in_bytes == n_slots * L * 4
+    assert mem.alias_size_in_bytes == n_slots * row * 4
     assert mem.temp_size_in_bytes < (64 << 20)
+
+
+def _kernel_calls(text: str) -> int:
+    """The write-back kernel's custom calls in a compiled program's
+    text. The benchmark's `writeback_kernel_device_ms` sums the
+    operations of opcode `custom-call` on the XLA Ops line of a
+    `jit_step`, its top-level operations: besides the kernel's a
+    compiled step holds there only the compiler's own `ConcatBitcast`
+    (slices of one buffer seen as one array: no data moves)."""
+    entry = text[text.index("\nENTRY "):]
+    targets = re.findall(r" custom-call\(.*?custom_call_target=\"(\w+)\"",
+                         entry[:entry.index("\n}")])
+    assert set(targets) <= {"tpu_custom_call", "ConcatBitcast"}, set(targets)
+    assert targets.count("tpu_custom_call") == text.count(
+        "custom_call_target=\"tpu_custom_call\"")
+    return targets.count("tpu_custom_call")
 
 
 @pytest.mark.parametrize("n_slots, row, batch, calls", [
@@ -204,7 +226,7 @@ def test_step_with_kernel_has_no_pool_sized_temporary(cell, shape,
     the temporaries do not grow by more than 64 MB over the parent's."""
     slots, _, _, _, parent_temp = CELLS[cell]
     compiled, n_roles = _cell_step(cell, shape, monkeypatch)
-    assert compiled.as_text().count("tpu_custom_call") >= n_roles
+    assert _kernel_calls(compiled.as_text()) >= n_roles
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= sum(slots) * L * 4
     assert mem.temp_size_in_bytes <= parent_temp + (64 << 20)
@@ -305,7 +327,7 @@ def test_mf_cell_programs_fit_beside_the_table(shape, kernel_cache,
     step = jax.jit(body, donate_argnums=(0,)).lower(
         pools, shape((4,), jnp.int32), tables, keys, None, None,
         shape((2,), jnp.uint32), x, f32, f32).compile()
-    assert step.as_text().count("tpu_custom_call") >= 2
+    assert _kernel_calls(step.as_text()) >= 2
     mem = step.memory_analysis()
     assert mem.alias_size_in_bytes >= pool_bytes
     assert mem.temp_size_in_bytes < 1 << 30
@@ -378,7 +400,7 @@ def test_dlrm_cell_step_fits_beside_the_tables(shape, kernel_cache,
     under 15.0 GiB."""
     compiled, pool_bytes = _dlrm_step(shape, monkeypatch)
     text = compiled.as_text()
-    assert text.count("custom_call_target=\"tpu_custom_call\"") == 5
+    assert _kernel_calls(text) == 5
     # one look-up of a place word for each of the two roles
     assert _route_lookups(text, DLRM_KEYS) == (2, [])
     mem = compiled.memory_analysis()
@@ -604,7 +626,7 @@ def test_four_shard_step_is_a_per_chip_program(no_replicas, topo,
         shape((), jnp.float32)).compile()
     assert list(step._forms) == [mesh]
     text = compiled.as_text()
-    assert text.count("custom_call_target=\"tpu_custom_call\"") == 4
+    assert _kernel_calls(text) == 4
     summed = [tuple(int(d) for d in dims.split(","))
               for res in _ALL_REDUCE.findall(text)
               for dims in _F32.findall(res)]
@@ -702,7 +724,7 @@ def test_four_shard_ctr_step_walks_two_classes_of_replicas(
         (shape((DLRM_B, 13), jnp.float32), shape((DLRM_B,), jnp.float32)),
         shape((), jnp.float32), shape((), jnp.float32)).compile()
     text = compiled.as_text()
-    assert text.count("custom_call_target=\"tpu_custom_call\"") == 5
+    assert _kernel_calls(text) == 5
     summed = {tuple(int(d) for d in dims.split(","))
               for res in _ALL_REDUCE.findall(text)
               for dims in _F32.findall(res)}
@@ -722,26 +744,32 @@ def test_four_shard_ctr_step_walks_two_classes_of_replicas(
     assert mem.temp_size_in_bytes < 2 << 30 and live < 12.0 * 2**30
 
 
-# sha256 of the lowered text of the one-chip cells' programs as PR 47
+# sha256 of the lowered text of the one-chip cells' programs as PR 48
 # lowered them (this jax). PR 34 first recorded them, of ITS parent
 # (commit 21ae0c6): it made the fused programs adapt to their pools'
 # shard count and left pools of one shard their program. PR 47 changed
 # the one-chip programs BY DESIGN (a role's route is one look-up of a
 # place word and its decode, `fused.decode_place`, where it was two
 # look-ups) and recorded its own text; what that text has to keep is
-# held by `test_one_chip_programs_look_up_one_table_a_role`. A PR that
-# means to change a one-chip program records its own text here
+# held by `test_one_chip_programs_look_up_one_table_a_role`. PR 48
+# changed the four programs that write back through the kernel (not
+# `mf.jit_score`, which writes nothing), again by design and outside
+# the Mosaic body too: `sort_slots` packs the kernel's decisions into
+# the codes (a running maximum a chunk, the buffer place in bits 24-28)
+# and the exported call counts each chunk's copies and the chunks to
+# visit (`writeback.chunk_meta`, a second scalar-prefetch operand). A
+# PR that means to change a one-chip program records its own text here
 # (`_one_chip_lowered` under `pytest -s` prints what it finds when a
 # hash differs).
 PARENT_LOWERED = {
     "kge.jit_step":
-        "a890c753527ce726b1bcc7d3d603d9a5f7c853f0c91d82e573e21f6b59ae9647",
+        "9ef64f8f5cccc02f527d35e6e4604d792d4fc051bb2e912f765eddf444945e4f",
     "kge.jit_scan":
-        "11bb09a759bab0de7dc91291dbd6e1fc0a58712b40d6aa30ada20ee14e701fd3",
+        "a742a1995cbf5a5f1130d3370e96d2ca6db3f704b655cf2d47128a8ce43aa21b",
     "sgns.jit_step":
-        "2846f227f03aa63a5bcd1ae655e4e261da990ea11c927c942c927d1604712cd0",
+        "3e5a5cc4ae46496c368c28355f63ec5c3d6eaf311b48c113d818c6d34559e99c",
     "mf.jit_step":
-        "241a93ec7493c12d9391bbb3544a8539d5378d1c04effeca4269868df31c80a8",
+        "211bf4c549e6b6604f37bfded997342e796970b2ba66992a18256084de70e59c",
     "mf.jit_score":
         "ea6c2bd33d2801bd1178b691207a3f70e093c4c48abc779a225344799474956e",
 }
@@ -915,7 +943,10 @@ def test_one_chip_programs_lower_as_on_the_parent(shape, kernel_cache,
     """Pools of one shard bypass the per-chip form entirely: the
     one-chip cells' programs lower to the text recorded in
     `PARENT_LOWERED`, to the character. (PR 47 re-recorded it: the
-    programs' route changed by design, one look-up a role for two.)"""
+    programs' route changed by design, one look-up a role for two.
+    PR 48 re-recorded the four that write back: the kernel's codes and
+    its `chunk_meta` operand are made in plain XLA, outside the Mosaic
+    body that the comparison leaves out.)"""
     import hashlib
     texts = _one_chip_lowered(shape, monkeypatch)
     for name in ("kge.jit_step", "sgns.jit_step", "mf.jit_step"):
